@@ -1,0 +1,743 @@
+#!/usr/bin/env python
+"""Does the system still start on the chip? One process, one TPU v5e.
+
+Drives the main path once through the entry points a user calls, at
+Llama-2-7B's published widths (hidden 4096, ffn 11008, 32 heads x 128, vocab
+32000; bf16 compute). No width is cut; depth is cut as far as 16 GB forces
+and is printed. Weights and requests come from ``--seed``.
+
+  train  ``Trainer`` (the ``examples/train_llama.py --model 7b --layers 2
+         --seq-len 2048 --batch-size 4`` path, attention ``auto``) takes 3
+         steps. Step 1's loss and grad-norm are compared with the same
+         seeded model under ``attention_impl="xla"``.
+  serve  a ``ServingEngine`` with its defaults (paged KV, page 16,
+         ``paged_attention="auto"``, decode chunk 8, prefix cache on)
+         answers 8 requests whose prompts span 128-2048 tokens, 32 new
+         tokens each; then a short pass on the row-cache layout. Every
+         emitted token is checked against a plain reference: a cache-free
+         full forward of the same weights with ``attention_impl="xla"`` in
+         fp32 under ``jax.default_matmul_precision("highest")``,
+         teacher-forced on the emitted tokens. Logits are compared, not
+         tokens: the reference logit of every emitted token must be within
+         the printed tolerance of the reference maximum at its position.
+
+``--chips 4`` runs only the four-chip path and what it is compared with: a
+tp=4 + sequence-parallel train step against the same seeded step on one
+device of the same process, and ``ServingEngine(tp=4)`` against the
+mesh-free engine on the same requests.
+
+Every phase also asserts which implementation ``"auto"`` resolved to (the
+program ledger's ``resolved`` record) and that the Pallas kernels are in the
+compiled programs (``tpu_custom_call`` in ``lower().compile().as_text()``).
+
+Exit code 0 and a last line ``{"ok": true, "device": {...}}`` only if every
+phase passed on a TPU. Without an accelerator it exits non-zero and prints
+no result. The compile cache goes where ``JAX_COMPILATION_CACHE_DIR`` says,
+or to ``.jax_cache`` next to this file.
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import gc
+import json
+import math
+import os
+import sys
+import time
+from typing import Dict, List, Sequence, Tuple
+
+KERNEL = "tpu_custom_call"
+
+
+@dataclasses.dataclass(frozen=True)
+class TrainSize:
+    """What the train phase runs. The defaults are the chip run; the CPU
+    rehearsal (tests/test_chip_smoke.py) passes a tiny one."""
+
+    config: object = None  # LlamaConfig; None = llama2_7b at ``layers``
+    layers: int = 2
+    batch: int = 4
+    seq: int = 2048
+    steps: int = 3
+    # bf16 compute, flash vs einsum accumulation order: |dloss| and the
+    # relative grad-norm difference stay well inside these
+    loss_tol: float = 2e-2
+    gnorm_rtol: float = 2e-2
+    # a randomly initialised model predicts ~uniformly: loss ~ ln(vocab),
+    # plus ~var/2 for logits of about unit variance
+    loss_ln_vocab_tol: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class ServeSize:
+    """What the serve phase runs (defaults: the chip run)."""
+
+    config: object = None  # LlamaConfig; None = llama2_7b at ``layers``
+    layers: int = 10
+    max_seq_len: int = 2560
+    slots: int = 8
+    prompt_lens: Tuple[int, ...] = (150, 300, 520, 700, 1000, 1300, 1700, 2040)
+    new_tokens: int = 32
+    row_slots: int = 2
+    row_prompt_lens: Tuple[int, ...] = (140, 260)
+    row_new_tokens: int = 16
+    # reference-logit gap allowed between an emitted token and the
+    # reference argmax at its position: bf16 serving against an fp32
+    # reference. Logits of a random-init model have a standard deviation
+    # near 1 and a median top-1/top-2 margin of 0.03-0.3; the largest gap
+    # seen on the chip is 0.009 (tp=4, 8 layers), a wrong token costs > 4
+    logit_tol: float = 0.1
+
+
+def log(msg: str) -> None:
+    print(f"chip_smoke: {msg}", flush=True)
+
+
+def _llama(size, **over):
+    from neuronx_distributed_tpu.models.llama import llama2_7b
+
+    if size.config is not None:
+        return dataclasses.replace(size.config, **over)
+    return llama2_7b(num_layers=size.layers, **over)
+
+
+def _ledger_kernels(ledger, names: Sequence[str],
+                    compiled: bool = True) -> Dict[str, bool]:
+    """``{program: a Pallas kernel is in it}`` for the ledger's programs
+    ``names`` (every compiled signature of each), re-lowered from the
+    signatures the ledger captured. ``compiled=False`` reads the lowered
+    text instead of compiling again."""
+    out = {}
+    for name, info in ledger.programs().items():
+        if name not in names:
+            continue
+        for i, variant in enumerate(info.variants):
+            lowered = variant.lower()
+            text = lowered.compile().as_text() if compiled else lowered.as_text()
+            out[f"{name}#{i}" if len(info.variants) > 1 else name] = KERNEL in text
+    return out
+
+
+def _hot_programs(engine) -> List[str]:
+    """The decode chunk and every prefill bucket the engine compiled."""
+    return ["decode_chunk"] + [
+        n for n in engine.programs.programs() if n.startswith("prefill[")
+    ]
+
+
+def _bytes_on(tree, device) -> int:
+    import jax
+
+    return sum(
+        shard.data.nbytes
+        for leaf in jax.tree.leaves(tree)
+        for shard in leaf.addressable_shards
+        if shard.device == device
+    )
+
+
+# --- train ---------------------------------------------------------------------
+
+
+def _train_steps(size: TrainSize, seed: int, devices, *, tp: int, sp: bool,
+                 steps: int):
+    """``steps`` Trainer steps on a (tp, dp=1) mesh over ``devices``.
+    Returns the trainer and per-step (loss, grad_norm, wall_s)."""
+    import jax
+
+    from neuronx_distributed_tpu.models.llama import LlamaForCausalLM
+    from neuronx_distributed_tpu.parallel import mesh as mesh_lib
+    from neuronx_distributed_tpu.trainer import OptimizerConfig
+    from neuronx_distributed_tpu.trainer.data import SyntheticTokens
+    from neuronx_distributed_tpu.trainer.loop import Callback, Trainer
+
+    mesh_lib.destroy_model_parallel()
+    mesh_lib.initialize_model_parallel(
+        tensor_model_parallel_size=tp, devices=list(devices)
+    )
+    cfg = _llama(size, max_seq_len=size.seq, sequence_parallel=sp)
+    model = LlamaForCausalLM(cfg, attention_impl="auto")
+    rows: List[Tuple[float, float, float]] = []
+
+    class Capture(Callback):
+        def on_train_start(self, trainer):
+            self.t = time.perf_counter()
+
+        def on_step_end(self, trainer, metrics):
+            # float() waits for the step: the wall below is a finished step
+            loss, gnorm = float(metrics["loss"]), float(metrics["grad_norm"])
+            now = time.perf_counter()
+            rows.append((loss, gnorm, now - self.t))
+            self.t = now
+
+    trainer = Trainer(
+        model=model, optimizer_config=OptimizerConfig(), callbacks=[Capture()]
+    )
+    data = SyntheticTokens(cfg.vocab_size, size.batch, size.seq, seed=seed)
+    trainer.fit(data, jax.random.PRNGKey(seed), steps)
+    return trainer, cfg, rows
+
+
+def _xla_reference_step(size: TrainSize, seed: int, devices):
+    """Step-1 loss and grad-norm of the same seeded model and batch with
+    ``attention_impl="xla"`` — no optimizer, so it fits beside nothing."""
+    import jax
+    from functools import partial
+
+    from neuronx_distributed_tpu.models.llama import LlamaForCausalLM
+    from neuronx_distributed_tpu.parallel import mesh as mesh_lib
+    from neuronx_distributed_tpu.parallel.grads import clip_grad_norm
+    from neuronx_distributed_tpu.trainer.data import SyntheticTokens
+    from neuronx_distributed_tpu.trainer.trainer import (
+        default_loss_fn,
+        initialize_parallel_model,
+        shard_batch,
+    )
+
+    mesh_lib.destroy_model_parallel()
+    mesh_lib.initialize_model_parallel(devices=list(devices))
+    cfg = _llama(size, max_seq_len=size.seq)
+    model = LlamaForCausalLM(cfg, attention_impl="xla")
+    batch = next(iter(
+        SyntheticTokens(cfg.vocab_size, size.batch, size.seq, seed=seed)
+    ))
+    params, _ = initialize_parallel_model(
+        model, jax.random.PRNGKey(seed), batch["input_ids"]
+    )
+
+    @jax.jit
+    def step(params, batch):
+        loss, grads = jax.value_and_grad(partial(default_loss_fn, model))(
+            params, batch
+        )
+        return loss, clip_grad_norm(grads, 1.0)[1]
+
+    loss, gnorm = step(params, shard_batch(batch))
+    return float(loss), float(gnorm)
+
+
+def _close(a: float, b: float, *, atol: float = 0.0, rtol: float = 0.0) -> bool:
+    return math.isfinite(a) and math.isfinite(b) and (
+        abs(a - b) <= atol + rtol * abs(b)
+    )
+
+
+def train_phase(size: TrainSize, seed: int, devices) -> Dict[str, bool]:
+    """3 Trainer steps on one device, attention ``auto``; step 1 against the
+    ``xla`` reference. Returns the named checks."""
+    trainer, cfg, rows = _train_steps(
+        size, seed, devices[:1], tp=1, sp=False, steps=size.steps
+    )
+    entry = trainer.programs.snapshot()["by_program"]["train_step"]
+    resolved = trainer.programs.resolved.get("attention")
+    log(
+        f"train: widths hidden={cfg.hidden_size} ffn={cfg.intermediate_size} "
+        f"heads={cfg.num_heads}x{cfg.head_dim_} vocab={cfg.vocab_size} "
+        f"depth={cfg.num_layers} layers, batch={size.batch} seq={size.seq}, "
+        f"attention auto -> {resolved}"
+    )
+    log(
+        f"train: compile_s={entry['compile_wall_s']:.1f} "
+        f"compiles={entry['compiles']} step_wall_s="
+        f"{[round(r[2], 3) for r in rows]} (step 1 includes the compile) "
+        f"loss={[round(r[0], 4) for r in rows]} "
+        f"grad_norm={[round(r[1], 4) for r in rows]}"
+    )
+    kernels = _ledger_kernels(trainer.programs, ["train_step"])
+    log(f"train: {KERNEL} in compiled program: {kernels}")
+    trainer.state = None  # free params + Adam before the reference
+    ref_loss, ref_gnorm = _xla_reference_step(size, seed, devices[:1])
+    loss, gnorm = rows[0][0], rows[0][1]
+    log(
+        f"train: step 1 vs xla reference: loss {loss:.5f} vs {ref_loss:.5f} "
+        f"(|d|={abs(loss - ref_loss):.2e}, tol {size.loss_tol:g}); grad_norm "
+        f"{gnorm:.5f} vs {ref_gnorm:.5f} (rel "
+        f"{abs(gnorm - ref_gnorm) / max(abs(ref_gnorm), 1e-30):.2e}, tol "
+        f"{size.gnorm_rtol:g}); ln(vocab)={math.log(cfg.vocab_size):.4f}"
+    )
+    return {
+        "train_steps_taken": len(rows) == size.steps,
+        "train_finite": all(math.isfinite(v) for r in rows for v in r[:2]),
+        "train_one_compile": entry["compiles"] == 1,
+        "train_loss_near_ln_vocab": _close(
+            loss, math.log(cfg.vocab_size), atol=size.loss_ln_vocab_tol
+        ),
+        "train_loss_matches_xla": _close(loss, ref_loss, atol=size.loss_tol),
+        "train_gnorm_matches_xla": _close(gnorm, ref_gnorm, rtol=size.gnorm_rtol),
+        "train_resolved_flash": resolved == "flash",
+        "kernel_train_step": all(kernels.values()) and bool(kernels),
+    }
+
+
+def tp_train_phase(size: TrainSize, seed: int, devices) -> Dict[str, bool]:
+    """One tp=4 + sequence-parallel step over all ``devices`` against the
+    same seeded step on one of them (init is tp-degree invariant)."""
+    from neuronx_distributed_tpu.observability.hbm import tree_nbytes
+
+    solo, cfg, solo_rows = _train_steps(
+        size, seed, devices[:1], tp=1, sp=False, steps=1
+    )
+    solo_bytes = tree_nbytes(solo.state.params)
+    solo.state = None
+    tp = len(devices)
+    trainer, _, rows = _train_steps(size, seed, devices, tp=tp, sp=True, steps=1)
+    params = trainer.state.params
+    per_device = [_bytes_on(params, d) for d in devices]
+    kernels = _ledger_kernels(trainer.programs, ["train_step"])
+    (loss, gnorm, wall), (loss1, gnorm1, wall1) = rows[0], solo_rows[0]
+    log(
+        f"tp{tp} train: depth={cfg.num_layers} layers batch={size.batch} "
+        f"seq={size.seq} sp=on zero1=on; loss {loss:.5f} vs one-device "
+        f"{loss1:.5f} (|d|={abs(loss - loss1):.2e}, tol {size.loss_tol:g}); "
+        f"grad_norm {gnorm:.5f} vs {gnorm1:.5f} (rel "
+        f"{abs(gnorm - gnorm1) / max(abs(gnorm1), 1e-30):.2e}, tol "
+        f"{size.gnorm_rtol:g}); first-step wall {wall:.1f}s vs {wall1:.1f}s "
+        "(both include the compile)"
+    )
+    log(
+        f"tp{tp} train: param bytes per device {per_device} of {solo_bytes} "
+        f"on one device; {KERNEL} in compiled program: {kernels}"
+    )
+    trainer.state = None
+    return {
+        "tp_train_loss_matches_one_device": _close(loss, loss1, atol=size.loss_tol),
+        "tp_train_gnorm_matches_one_device": _close(
+            gnorm, gnorm1, rtol=size.gnorm_rtol
+        ),
+        # norms and the like replicate; the matmul weights — nearly all of
+        # the bytes — must be split, not parked on device 0
+        "tp_train_params_split": max(per_device) < 0.3 * solo_bytes
+        and min(per_device) > 0.2 * solo_bytes,
+        "kernel_tp_train_step": all(kernels.values()) and bool(kernels),
+    }
+
+
+# --- serve ---------------------------------------------------------------------
+
+
+def _prompts(lens: Sequence[int], vocab: int, seed: int):
+    import numpy as np
+
+    rng = np.random.RandomState(seed)
+    return [rng.randint(1, vocab, size=n).astype(np.int32) for n in lens]
+
+
+def _serve(engine, prompts, new_tokens: int, seed: int):
+    """Submit every prompt (greedy), run to completion, return the requests
+    and the wall. Raises if the engine did not answer every request."""
+    import jax
+
+    from neuronx_distributed_tpu.inference import GenerationConfig
+    from neuronx_distributed_tpu.serving import RequestState
+
+    gcfg = GenerationConfig(max_new_tokens=new_tokens, temperature=0.0)
+    t0 = time.perf_counter()
+    reqs = [
+        engine.submit(p, gcfg, key=jax.random.PRNGKey(seed + i))
+        for i, p in enumerate(prompts)
+    ]
+    engine.run()
+    wall = time.perf_counter() - t0
+    for r in reqs:
+        if r.state is not RequestState.DONE or len(r.tokens) != new_tokens:
+            raise RuntimeError(
+                f"request {r.rid}: state={r.state.value} "
+                f"tokens={len(r.tokens)}/{new_tokens} error={r.error!r} "
+                f"(engine health {engine.health().value}, halt "
+                f"{engine.halt_reason!r})"
+            )
+    return reqs, wall
+
+
+class PlainReference:
+    """Cache-free full forward of the same weights: ``attention_impl="xla"``,
+    fp32 compute, matmul precision ``highest``; one program at a fixed padded
+    length (right padding cannot reach earlier positions of a causal
+    model)."""
+
+    def __init__(self, cfg, params, length: int):
+        import jax
+        import jax.numpy as jnp
+
+        from neuronx_distributed_tpu.models.llama import LlamaForCausalLM
+
+        model = LlamaForCausalLM(
+            dataclasses.replace(cfg, dtype=jnp.float32, max_seq_len=length),
+            attention_impl="xla",
+        )
+        self.length = length
+        self.params = params
+
+        @jax.jit
+        def gaps(params, ids, positions, tokens):
+            with jax.default_matmul_precision("highest"):
+                logits = model.apply(params, ids)[0].astype(jnp.float32)
+            rows = logits[positions]                       # (n, vocab)
+            top2 = jax.lax.top_k(rows, 2)[0]
+
+            def gap(toks):
+                chosen = jnp.take_along_axis(rows, toks[:, None], axis=1)[:, 0]
+                return top2[:, 0] - chosen
+
+            # every token swapped for its successor id: what a wrong answer
+            # costs (never equal to the emitted token, whatever the stream)
+            wrong = (tokens + 1) % rows.shape[1]
+            return gap(tokens), gap(wrong), top2[:, 0] - top2[:, 1]
+
+        self._gaps = gaps
+
+    def gap(self, prompt, tokens) -> Tuple[float, float, float]:
+        """``(gap, control, margin)``: the largest gap, over the emitted
+        tokens, between the reference maximum at a position and the
+        reference logit of the token the system emitted there; the same
+        for every token swapped for its successor id (a deliberately wrong
+        answer — the check must be able to fail); and the reference's
+        median top-1/top-2 margin (how easy the argmax is to flip)."""
+        import numpy as np
+
+        p, n = len(prompt), len(tokens)
+        ids = np.zeros((1, self.length), np.int32)
+        ids[0, :p] = prompt
+        ids[0, p:p + n] = tokens
+        # token i was sampled from the logits at position p - 1 + i
+        positions = np.arange(p - 1, p - 1 + n, dtype=np.int32)
+        g, control, margin = (
+            np.asarray(a) for a in
+            self._gaps(self.params, ids, positions, np.asarray(tokens, np.int32))
+        )
+        return float(g.max()), float(control.max()), float(np.median(margin))
+
+
+def _reference_check(tag: str, ref: PlainReference, reqs, prompts,
+                     tol: float) -> bool:
+    rows = [ref.gap(p, r.tokens) for p, r in zip(prompts, reqs)]
+    gaps, controls, margins = zip(*rows)
+    log(
+        f"{tag}: reference-logit gap per request "
+        f"{[round(g, 4) for g in gaps]} (max {max(gaps):.4f}, tol {tol:g}); "
+        f"control (every token swapped for another) min {min(controls):.3f}; "
+        f"reference top-1/top-2 margin, median per request "
+        f"{[round(m, 3) for m in margins]}"
+    )
+    return all(math.isfinite(g) and g <= tol for g in gaps) and all(
+        c > tol for c in controls
+    )
+
+
+def _serve_model(size: ServeSize, seed: int):
+    import jax
+    import jax.numpy as jnp
+
+    from neuronx_distributed_tpu.models.llama import LlamaForCausalLM
+
+    over = dict(max_seq_len=size.max_seq_len, scan_layers=False, remat=False)
+    if size.config is None:
+        over["param_dtype"] = jnp.bfloat16  # serving weights: bf16 in HBM
+    cfg = _llama(size, **over)
+    model = LlamaForCausalLM(cfg, attention_impl="auto")
+    params = jax.jit(model.init)(
+        jax.random.PRNGKey(seed), jnp.zeros((1, 8), jnp.int32)
+    )
+    return cfg, model, params
+
+
+_FAULT_EVENTS = (
+    "dispatch_failure", "recovery", "prefill_failure", "page_exhausted",
+    "quarantine", "page_quarantine", "halt",
+)
+_FAULT_COUNTERS = (
+    "preemptions", "dispatch_retries", "recoveries", "prefill_failures",
+    "quarantines", "page_quarantines", "failed", "timed_out",
+)
+
+
+def _engine_report(tag: str, engine, cfg, reqs, wall: float,
+                   n_tokens: int) -> Dict[str, bool]:
+    """Log what the engine did and return its cleanliness checks: the
+    engine's fault tolerance recovers from a failed dispatch or prefill and
+    still answers — here any such event is a failure of the run, and the
+    continuous-batching invariant (ONE decode program) must hold."""
+    from neuronx_distributed_tpu.serving import EngineHealth
+
+    snap = engine.programs.snapshot(analyze=False)
+    m = engine.metrics.snapshot(analyze_programs=False)
+    log(
+        f"{tag}: depth={cfg.num_layers} layers max_seq_len={cfg.max_seq_len} "
+        f"slots={engine.num_slots} resolved={snap['resolved']} "
+        f"programs={snap['totals']['programs']} compile_s="
+        f"{snap['totals']['compile_wall_s']:.1f} "
+        f"decode_compilations={engine.decode_compilations} "
+        f"prefill_compilations={engine.prefill_compilations}"
+    )
+    decode_wall = m["decode_dispatch_s"] + m["decode_readback_s"]
+    log(
+        f"{tag}: {len(reqs)} requests, prompts "
+        f"{[engine.metrics.request_snapshot(r.rid)['prompt_len'] for r in reqs]}"
+        f" -> {n_tokens} tokens each in {wall:.1f}s wall (compiles included); "
+        f"prefills={m['prefills']} chunks={m['chunks']} decode_tokens="
+        f"{m['decode_tokens']} decode_wall_s={decode_wall:.2f} (first chunk "
+        "includes its compile)"
+    )
+    recompiled = {
+        name: {"compiles": e["compiles"], "signatures": e["variants"]}
+        for name, e in snap["by_program"].items() if e["compiles"] > 1
+    }
+    if recompiled:
+        log(f"{tag}: programs compiled more than once: {recompiled}")
+    counters = {k: m[k] for k in _FAULT_COUNTERS}
+    events = [
+        e for e in (engine.flight.events() if engine.flight else [])
+        if e.get("kind") in _FAULT_EVENTS
+    ]
+    log(f"{tag}: health={engine.health().value} fault counters {counters}")
+    for e in events:
+        log(f"{tag}: fault event {e}")
+    return {
+        "clean_run": engine.health() is EngineHealth.OK
+        and not any(counters.values()) and not events,
+        "one_decode_program": engine.decode_compilations == 1,
+    }
+
+
+def serve_phase(size: ServeSize, seed: int) -> Dict[str, bool]:
+    """The default (paged, fused on a TPU) engine, then the row-cache pass,
+    each against the plain reference."""
+    from flax.core import meta
+
+    from neuronx_distributed_tpu.observability.hbm import tree_nbytes
+    from neuronx_distributed_tpu.parallel import mesh as mesh_lib
+    from neuronx_distributed_tpu.serving import ServingEngine
+
+    # a mesh-free engine: the train phase's global mesh must be gone (under
+    # a live mesh the kernels wrap themselves in manual regions over it, the
+    # chunk's outputs come back typed with that mesh, and the next dispatch
+    # retraces — 3 decode compiles instead of 1, seen on the chip)
+    mesh_lib.destroy_model_parallel()
+    cfg, model, params = _serve_model(size, seed)
+    weights = tree_nbytes(meta.unbox(params))
+    engine = ServingEngine(model, params, num_slots=size.slots, kv_page_size=16)
+    prompts = _prompts(size.prompt_lens, cfg.vocab_size, seed)
+    reqs, wall = _serve(engine, prompts, size.new_tokens, seed)
+    paged_run = _engine_report("serve", engine, cfg, reqs, wall, size.new_tokens)
+    log(
+        f"serve: weights {weights / 2**30:.2f} GiB + KV pool "
+        f"{engine.cache.nbytes / 2**30:.2f} GiB "
+        f"({engine.cache.alloc.capacity} pages of 16)"
+    )
+    resolved = dict(engine.programs.resolved)
+    kernels = _ledger_kernels(engine.programs, _hot_programs(engine))
+    log(f"serve: {KERNEL} in compiled programs: {kernels}")
+    engine.cache.check()  # the page-leak invariant, on the way out
+    engine = None  # free the pool before the reference and the row pass
+    gc.collect()
+
+    longest = max(size.prompt_lens) + size.new_tokens
+    ref = PlainReference(cfg, meta.unbox(params), -(-longest // 128) * 128)
+    paged_ok = _reference_check("serve", ref, reqs, prompts, size.logit_tol)
+
+    row_engine = ServingEngine(model, params, num_slots=size.row_slots)
+    row_prompts = _prompts(size.row_prompt_lens, cfg.vocab_size, seed + 1)
+    row_reqs, row_wall = _serve(
+        row_engine, row_prompts, size.row_new_tokens, seed
+    )
+    row_run = _engine_report(
+        "serve[row]", row_engine, cfg, row_reqs, row_wall, size.row_new_tokens
+    )
+    row_resolved = dict(row_engine.programs.resolved)
+    row_kernels = _ledger_kernels(row_engine.programs, ["decode_chunk"])
+    log(f"serve[row]: {KERNEL} in compiled programs: {row_kernels}")
+    row_ok = _reference_check(
+        "serve[row]", ref, row_reqs, row_prompts, size.logit_tol
+    )
+    return {
+        **{f"serve_paged_{k}": v for k, v in paged_run.items()},
+        **{f"serve_row_{k}": v for k, v in row_run.items()},
+        "serve_paged_matches_reference": paged_ok,
+        "serve_row_matches_reference": row_ok,
+        "serve_several_prefill_buckets": sum(
+            n.startswith("prefill[") for n in kernels
+        ) >= 2,
+        "serve_resolved_fused": resolved == {
+            "attention": "flash", "decode_attention": "paged_fused",
+            "paged_attention": "fused",
+        },
+        "serve_row_resolved_flash_decode": row_resolved == {
+            "attention": "flash", "decode_attention": "flash_decode",
+            "paged_attention": "none",
+        },
+        "kernel_serve_programs": all(kernels.values()) and bool(kernels),
+        "kernel_serve_row_decode": all(row_kernels.values())
+        and bool(row_kernels),
+    }
+
+
+def tp_serve_phase(size: ServeSize, seed: int, devices) -> Dict[str, bool]:
+    """``ServingEngine(tp=len(devices))`` against the mesh-free engine on
+    the same requests; both against the plain reference."""
+    import jax
+    from flax.core import meta
+
+    from neuronx_distributed_tpu.observability.hbm import tree_nbytes
+    from neuronx_distributed_tpu.parallel import mesh as mesh_lib
+    from neuronx_distributed_tpu.serving import ServingEngine
+
+    tp = len(devices)
+    mesh_lib.destroy_model_parallel()
+    cfg, model, params = _serve_model(size, seed)
+    prompts = _prompts(size.prompt_lens, cfg.vocab_size, seed)
+    solo = ServingEngine(model, params, num_slots=size.slots, kv_page_size=16)
+    solo_reqs, solo_wall = _serve(solo, prompts, size.new_tokens, seed)
+    solo_run = _engine_report(
+        "serve[one device]", solo, cfg, solo_reqs, solo_wall, size.new_tokens
+    )
+    solo = None
+    gc.collect()
+
+    engine = ServingEngine(
+        model, params, num_slots=size.slots, kv_page_size=16, tp=tp
+    )
+    reqs, wall = _serve(engine, prompts, size.new_tokens, seed)
+    tp_run = _engine_report(
+        f"serve[tp{tp}]", engine, cfg, reqs, wall, size.new_tokens
+    )
+    resolved = dict(engine.programs.resolved)
+    total = tree_nbytes(meta.unbox(params))
+    per_device = [_bytes_on(engine._params, d) for d in devices]
+    pool = engine.cache.cache["pool"]
+    kv = [
+        leaf for path, leaf in jax.tree_util.tree_flatten_with_path(pool)[0]
+        if getattr(path[-1], "key", None) in ("k", "v")
+    ]
+    kv_heads = {leaf.addressable_shards[0].data.shape[-2] for leaf in kv}
+    kv_devices = {len(leaf.sharding.device_set) for leaf in kv}
+    log(
+        f"serve[tp{tp}]: param bytes per device {per_device} of {total}; KV "
+        f"pool leaves hold {sorted(kv_heads)} of {cfg.num_kv_heads} kv heads "
+        f"per device over {sorted(kv_devices)} devices"
+    )
+    # lowered, not compiled: the ledger's signatures carry no shardings, so
+    # a compile here would build a second, replicated program per entry
+    kernels = _ledger_kernels(
+        engine.programs, _hot_programs(engine), compiled=False
+    )
+    log(f"serve[tp{tp}]: {KERNEL} in lowered programs: {kernels}")
+    engine.cache.check()
+    engine = None
+    gc.collect()
+    mesh_lib.destroy_model_parallel()
+
+    same = sum(
+        a == b for r, s in zip(reqs, solo_reqs) for a, b in zip(r.tokens, s.tokens)
+    )
+    log(
+        f"serve[tp{tp}]: {same}/{len(reqs) * size.new_tokens} tokens equal "
+        "to the mesh-free engine's (bf16: compared by logits below)"
+    )
+    longest = max(size.prompt_lens) + size.new_tokens
+    ref = PlainReference(cfg, meta.unbox(params), -(-longest // 128) * 128)
+    solo_ok = _reference_check(
+        "serve[one device]", ref, solo_reqs, prompts, size.logit_tol
+    )
+    tp_ok = _reference_check(
+        f"serve[tp{tp}]", ref, reqs, prompts, size.logit_tol
+    )
+    return {
+        **{f"tp_serve_one_device_{k}": v for k, v in solo_run.items()},
+        **{f"tp_serve_{k}": v for k, v in tp_run.items()},
+        "tp_serve_one_device_matches_reference": solo_ok,
+        "tp_serve_matches_reference": tp_ok,
+        "tp_serve_resolved_fused": resolved == {
+            "attention": "flash", "decode_attention": "paged_fused",
+            "paged_attention": "fused",
+        },
+        "tp_serve_params_split": max(per_device) < 0.3 * total
+        and min(per_device) > 0.2 * total,
+        "tp_serve_kv_split": kv_heads == {cfg.num_kv_heads // tp}
+        and kv_devices == {tp},
+        "kernel_tp_serve_programs": all(kernels.values()) and bool(kernels),
+    }
+
+
+# --- entry ---------------------------------------------------------------------
+
+# four chips: the tp path and its one-device counterpart, nothing else — and
+# smaller than the one-chip run (the mesh-free engine must fit ONE chip, and
+# every second here is charged four times)
+TP_SERVE = ServeSize(
+    layers=8, slots=4, prompt_lens=(300, 500, 700, 1000), new_tokens=16,
+)
+
+
+def one_chip(seed: int, devices, train: TrainSize = TrainSize(),
+             serve: ServeSize = ServeSize()) -> Dict[str, bool]:
+    """The default run: train, then serve, in one process on one device."""
+    return {**train_phase(train, seed, devices), **serve_phase(serve, seed)}
+
+
+def four_chips(seed: int, devices, train: TrainSize = TrainSize(),
+               serve: ServeSize = TP_SERVE) -> Dict[str, bool]:
+    """``--chips 4``: the tp paths and what they are compared with."""
+    return {
+        **tp_train_phase(train, seed, devices),
+        **tp_serve_phase(serve, seed, devices),
+    }
+
+
+def parse_args(argv=None):
+    p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    p.add_argument("--chips", type=int, default=1, choices=(1, 4),
+                   help="1 (default): train + serve on one chip. 4: only the "
+                        "tp=4 train step and tp=4 engine and their "
+                        "one-device counterparts")
+    p.add_argument("--seed", type=int, default=0)
+    return p.parse_args(argv)
+
+
+def main(argv=None) -> int:
+    args = parse_args(argv)
+    import jax
+
+    devices = jax.devices()
+    if devices[0].platform != "tpu":
+        print(
+            f"chip_smoke: needs a TPU, found platform "
+            f"{devices[0].platform!r} — no result", file=sys.stderr,
+        )
+        return 2
+    if len(devices) != args.chips:
+        print(
+            f"chip_smoke: --chips {args.chips} but JAX reports "
+            f"{len(devices)} devices — no result", file=sys.stderr,
+        )
+        return 2
+
+    from neuronx_distributed_tpu.inference import aot
+
+    cache = aot.enable_persistent_cache(
+        os.path.join(os.path.dirname(os.path.abspath(__file__)), ".jax_cache")
+    )
+    device = {
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+    }
+    log(f"device {device}, jax {jax.__version__}, compile cache {cache}")
+    t0 = time.perf_counter()
+    run = one_chip if args.chips == 1 else four_chips
+    checks = run(args.seed, devices)
+    failed = sorted(name for name, ok in checks.items() if not ok)
+    log(
+        f"{len(checks) - len(failed)}/{len(checks)} checks passed in "
+        f"{time.perf_counter() - t0:.0f}s"
+        + (f"; FAILED: {failed}" if failed else "")
+    )
+    if failed:
+        return 1
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

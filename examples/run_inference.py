@@ -143,6 +143,14 @@ def main(argv=None):
 
     from flax.core import meta
 
+    # compile cache: where JAX_COMPILATION_CACHE_DIR says, else the fixed
+    # <checkout>/.jax_cache (a path that moves never hits)
+    from neuronx_distributed_tpu.inference import aot
+
+    aot.enable_persistent_cache(
+        os.path.join(_repo_root, ".jax_cache"), min_compile_time_secs=0.5
+    )
+
     from neuronx_distributed_tpu.inference.generate import (
         GenerationConfig,
         generate,

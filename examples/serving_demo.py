@@ -118,6 +118,17 @@ if _repo_root not in sys.path:
 
 def parse_args(argv=None):
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    p.add_argument("--model", default="tiny",
+                   choices=["tiny", "7b", "llama3-8b"],
+                   help="model preset (tiny = the 4-layer CPU test config; "
+                        "the real widths need a TPU — cut depth with "
+                        "--layers to fit one chip's HBM)")
+    p.add_argument("--layers", type=int, default=None,
+                   help="override the preset's layer count")
+    p.add_argument("--attention", default="auto",
+                   choices=["auto", "flash", "xla"],
+                   help="attention implementation (auto = the Pallas "
+                        "kernels on a TPU, the XLA einsum elsewhere)")
     p.add_argument("--requests", type=int, default=8)
     p.add_argument("--slots", type=int, default=4)
     p.add_argument("--max-new-tokens", type=int, default=12)
@@ -271,8 +282,9 @@ def parse_args(argv=None):
                    choices=["auto", "gather", "fused"],
                    help="paged decode transport: 'fused' streams K/V "
                         "straight from pool pages through the paged "
-                        "flash-decode kernel on TPU (bit-identical gather "
-                        "fallback elsewhere)")
+                        "flash-decode kernel (a TPU kernel: asking for it "
+                        "elsewhere fails); auto = fused on a TPU, gather "
+                        "elsewhere")
     p.add_argument("--replicas", type=int, default=0,
                    help="serve through a ReplicaRouter over this many "
                         "engine replicas (queue-depth + page-pressure "
@@ -311,6 +323,29 @@ def parse_args(argv=None):
                         "is (re)written at the end of every run")
     p.add_argument("--force-cpu-devices", type=int, default=None)
     return p.parse_args(argv)
+
+
+def build_model(args):
+    """``(cfg, model)`` for the demo's ``--model``/``--layers``/
+    ``--attention`` flags — the presets
+    ``examples/run_inference.py`` takes. Real widths serve bf16 weights;
+    the tiny default keeps the fp32 CPU-test config."""
+    import jax.numpy as jnp
+
+    from neuronx_distributed_tpu.models import llama as llama_lib
+
+    over = {}
+    if args.layers is not None:
+        over["num_layers"] = args.layers
+    if args.model == "tiny":
+        cfg = llama_lib.tiny_llama(**over)
+    else:
+        preset = {"7b": llama_lib.llama2_7b,
+                  "llama3-8b": llama_lib.llama3_8b}[args.model]
+        # the fused paged transport pairs pool leaves with layers by name
+        cfg = preset(scan_layers=False, remat=False,
+                     param_dtype=jnp.bfloat16, **over)
+    return cfg, llama_lib.LlamaForCausalLM(cfg, attention_impl=args.attention)
 
 
 def _engine_layout(args):
@@ -573,10 +608,9 @@ def _run_router(args, cfg, model, params):
 def main(argv=None):
     args = parse_args(argv)
     if args.force_cpu_devices:
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={args.force_cpu_devices}"
-        )
+        from neuronx_distributed_tpu.utils.platform import force_cpu_devices
+
+        force_cpu_devices(args.force_cpu_devices)
     elif args.tp > 1:
         # the CPU fan-out dryrun_multichip uses — a TP mesh needs devices
         os.environ["XLA_FLAGS"] = (
@@ -587,16 +621,20 @@ def main(argv=None):
     import jax
     import numpy as np
 
-    from neuronx_distributed_tpu.inference import GenerationConfig
-    from neuronx_distributed_tpu.models.llama import (
-        LlamaForCausalLM,
-        tiny_llama,
+    # compile cache: where JAX_COMPILATION_CACHE_DIR says, else the fixed
+    # <checkout>/.jax_cache (a path that moves never hits)
+    from neuronx_distributed_tpu.inference import aot
+
+    aot.enable_persistent_cache(
+        os.path.join(_repo_root, ".jax_cache"), min_compile_time_secs=0.5
     )
+
+    from neuronx_distributed_tpu.inference import GenerationConfig
+    from neuronx_distributed_tpu.models.llama import LlamaForCausalLM
     from neuronx_distributed_tpu.serving import FaultInjector, ServingEngine
     from neuronx_distributed_tpu.utils.timeline import Timeline
 
-    cfg = tiny_llama()
-    model = LlamaForCausalLM(cfg, attention_impl="xla")
+    cfg, model = build_model(args)
     rng = np.random.RandomState(args.seed)
     init_ids = rng.randint(1, cfg.vocab_size, size=(1, 8)).astype(np.int32)
     params = jax.jit(model.init)(jax.random.PRNGKey(1), init_ids)
@@ -633,8 +671,11 @@ def main(argv=None):
         params, draft_params = early_exit_draft_params(
             params, cfg.num_layers, args.draft_layers, eps=0.02
         )
+        import dataclasses
+
         draft_model = LlamaForCausalLM(
-            tiny_llama(num_layers=args.draft_layers), attention_impl="xla"
+            dataclasses.replace(cfg, num_layers=args.draft_layers),
+            attention_impl=args.attention,
         )
 
     injector = None

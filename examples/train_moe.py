@@ -155,6 +155,14 @@ def main(argv=None):
 
     import jax
 
+    # compile cache: where JAX_COMPILATION_CACHE_DIR says, else the fixed
+    # <checkout>/.jax_cache (a path that moves never hits)
+    from neuronx_distributed_tpu.inference import aot
+
+    aot.enable_persistent_cache(
+        os.path.join(_repo_root, ".jax_cache"), min_compile_time_secs=0.5
+    )
+
     from neuronx_distributed_tpu.models.mixtral import MixtralForCausalLM
     from neuronx_distributed_tpu.parallel import mesh as mesh_lib
     from neuronx_distributed_tpu.trainer import OptimizerConfig

@@ -14,7 +14,9 @@ rung of the fallback ladder:
 1. **Persistent compilation cache** (:func:`enable_persistent_cache`) —
    the ONE owner of ``jax_compilation_cache_dir`` wiring, used by the
    engine, builder, trainer, bench children, and the test suite. Keyed by
-   XLA on the optimized HLO; namespaced per host-CPU fingerprint
+   XLA on the optimized HLO. A directory placed from outside
+   (``JAX_COMPILATION_CACHE_DIR``) is left alone; the default one is
+   namespaced per host-CPU fingerprint on the CPU backend only
    (utils/platform.py — a foreign XLA:CPU entry can SIGILL). Makes every
    RE-compile of a known program a disk hit.
 2. **Serialized executables** (:func:`save_executable` /
@@ -73,6 +75,7 @@ MANIFEST_NAME = "manifest.json"
 XLA_SUBDIR = "xla"  # persistent-compile-cache subdir inside an AOT dir
 ARTIFACT_SUFFIX = ".aotx"
 DISABLE_ENV = "NXD_TPU_PERSISTENT_CACHE"  # "0"/"off"/"false" disables
+PLACED_ENV = "JAX_COMPILATION_CACHE_DIR"  # set => the dir is not ours to move
 
 _FORMAT = 1
 _CACHE_DIR: Optional[str] = None
@@ -97,57 +100,61 @@ def enable_persistent_cache(
     min_compile_time_secs: float = 0.0,
     host_scoped: bool = True,
 ) -> Optional[str]:
-    """Point jax's persistent compilation cache at ``path`` (the ONE
-    owner of this wiring — engine, builder, trainer, bench children, and
-    conftest all route here). Returns the resolved directory, or None
-    when disabled via ``NXD_TPU_PERSISTENT_CACHE=0``.
+    """Turn on jax's persistent compilation cache (the ONE owner of this
+    wiring — engine, builder, trainer, examples, ``chip_smoke.py`` and
+    conftest all route here). Returns the directory in use, or None when
+    disabled via ``NXD_TPU_PERSISTENT_CACHE=0``.
 
-    ``host_scoped=True`` namespaces by the host-CPU fingerprint
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set the cache was placed from
+    outside: jax reads the variable itself, and this function neither sets
+    another directory nor appends anything to that one — ``path`` is
+    ignored. Otherwise the cache goes to ``path``; on the CPU backend, with
+    ``host_scoped=True``, under a host-CPU fingerprint namespace
     (utils/platform.py) — a foreign XLA:CPU AOT entry can SIGILL, so a
-    moved cache must go cold, not lethal. ``min_compile_time_secs``
-    defaults to 0 (cache everything) — right for small AOT bundles where
-    the next process replays every program — but bulk consumers should
-    set a floor: disk round-tripping a sub-second program costs more
-    than its compile (conftest pins 0.5 off measurement).
+    moved cache must go cold, not lethal. On an accelerator the path is
+    used as given: the fingerprint folds in the kernel release and
+    microcode, which differ on every machine, so a namespaced TPU cache
+    would never hit.
+
+    ``min_compile_time_secs`` defaults to 0 (cache everything) — right
+    for small AOT bundles where the next process replays every program —
+    but bulk consumers should set a floor: disk round-tripping a
+    sub-second program costs more than its compile (conftest pins 0.5 off
+    measurement).
 
     Safe to call mid-process even after compiles have run: jax memoizes
     the cache-enabled check on first use, so the cache object is reset
-    (fail-soft) when the directory actually changes. Idempotent for a
-    repeated identical path."""
+    when the directory actually changes. Idempotent for a repeated
+    identical path."""
     global _CACHE_DIR
     if os.environ.get(DISABLE_ENV, "1").strip().lower() in (
         "0", "off", "false", "no",
     ):
         return None
-    if host_scoped:
+    import jax
+
+    placed = os.environ.get(PLACED_ENV)
+    if placed:
+        resolved = placed
+    elif host_scoped and jax.default_backend() == "cpu":
         from neuronx_distributed_tpu.utils.platform import host_cache_dir
 
         resolved = host_cache_dir(path)
     else:
         resolved = path
         os.makedirs(resolved, exist_ok=True)
-    import jax
-
-    already = _CACHE_DIR == resolved
-    try:
+    if not placed:
         jax.config.update("jax_compilation_cache_dir", resolved)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs",
-            float(min_compile_time_secs),
-        )
-    except Exception:
-        return None
-    if not already:
-        try:
-            # drop the memoized "is the cache in use" check so a dir set
-            # AFTER the process's first compile still takes effect
-            from jax.experimental.compilation_cache import (
-                compilation_cache as cc,
-            )
+    jax.config.update(
+        "jax_persistent_cache_min_compile_time_secs",
+        float(min_compile_time_secs),
+    )
+    if _CACHE_DIR != resolved:
+        # drop the memoized "is the cache in use" check so a dir set
+        # AFTER the process's first compile still takes effect
+        from jax.experimental.compilation_cache import compilation_cache
 
-            cc.reset_cache()
-        except Exception:
-            pass
+        compilation_cache.reset_cache()
     _CACHE_DIR = resolved
     return resolved
 

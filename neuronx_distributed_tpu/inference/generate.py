@@ -141,9 +141,9 @@ def chunked_decode_step(decode_model, chunk_size: int, max_seq_len: int,
     view; ``"fused"`` routes every decode-attention call through
     ``kernels/flash_decode.paged_flash_decode_attention`` — the block
     table rides the kernel's scalar prefetch and K/V stream straight from
-    the physical pool pages on TPU, while the kernel's gather fallback
-    keeps every other backend bit-identical to ``"gather"``. Fused mode
-    does not speak quantized pools (the in-kernel page stream is float)."""
+    the physical pool pages. It is the compiled kernel or nothing: off the
+    TPU it runs only where a test interprets it. Fused mode does not speak
+    quantized pools (the in-kernel page stream is float)."""
     from neuronx_distributed_tpu.inference.utils import unwrap_logits
     from neuronx_distributed_tpu.modules.attention import (
         cache_cursor,

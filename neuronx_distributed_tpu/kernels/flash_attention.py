@@ -53,6 +53,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from neuronx_distributed_tpu.kernels.backend import interpret_mode
+
 NEG_INF = -1e30
 
 
@@ -121,8 +123,8 @@ def _fwd_kernel(q_off_ref, k_off_ref, qseg_ref, kseg_ref, qmin_ref, qmax_ref,
             cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1) + j * block_k + k_off
             s = jnp.where(rows >= cols, s, NEG_INF)
         if segments:
-            qs = qseg_ref[0, :][:, None]               # (BQ, 1)
-            ks = kseg_ref[0, :][None, :]               # (1, BK)
+            qs = qseg_ref[0]                           # (BQ, 1)
+            ks = kseg_ref[0]                           # (1, BK)
             s = jnp.where(qs == ks, s, NEG_INF)
         m_prev = m_scr[:]                              # (BQ, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -156,22 +158,38 @@ _DUMMY = functools.partial(jnp.zeros, (1, 1), jnp.int32)
 def _seg_operands(q_seg, k_seg, block_q, block_k):
     """Build the 6 segment operands (q/k seg arrays + 4 SMEM range arrays);
     dummies when segments are off (the static flag keeps kernels from ever
-    reading them)."""
+    reading them). The query ids go in as a COLUMN (B, S, 1) and the key ids
+    as a ROW (B, 1, Sk): a (1, block) tile of a (B, S) array is not a block
+    Mosaic can tile (the second-to-last block dim must be a multiple of 8 or
+    the whole axis), and the kernels want exactly these two orientations for
+    the (BQ, 1) == (1, BK) broadcast compare anyway."""
     if q_seg is None:
         return (_DUMMY(), _DUMMY(), _DUMMY(), _DUMMY(), _DUMMY(), _DUMMY())
     qmn, qmx = _seg_block_ranges(q_seg, block_q)
     kmn, kmx = _seg_block_ranges(k_seg, block_k)
-    return (q_seg.astype(jnp.int32), k_seg.astype(jnp.int32), qmn, qmx, kmn, kmx)
+    return (
+        q_seg.astype(jnp.int32)[:, :, None], k_seg.astype(jnp.int32)[:, None, :],
+        qmn, qmx, kmn, kmx,
+    )
 
 
 def _seg_specs(segments, block_q, block_k, qmap, kmap):
     """BlockSpecs for the 6 segment operands. ``qmap``/``kmap`` map the grid
-    to the (batch, q-block)/(batch, k-block) index of the (1, block) tile."""
+    to the (batch, q-block)/(batch, k-block) index of the segment tile."""
     if not segments:
         return [_SMEM_SPEC] * 6
+
+    def q_col(*grid):
+        b, i = qmap(*grid)
+        return (b, i, 0)
+
+    def k_row(*grid):
+        b, j = kmap(*grid)
+        return (b, 0, j)
+
     return [
-        pl.BlockSpec((1, block_q), qmap),
-        pl.BlockSpec((1, block_k), kmap),
+        pl.BlockSpec((1, block_q, 1), q_col),
+        pl.BlockSpec((1, 1, block_k), k_row),
         _SMEM_SPEC, _SMEM_SPEC, _SMEM_SPEC, _SMEM_SPEC,
     ]
 
@@ -289,8 +307,8 @@ def _dkdv_kernel(q_off_ref, k_off_ref, qseg_ref, kseg_ref, qmin_ref, qmax_ref,
             cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1) + j * block_k + k_off
             s = jnp.where(rows >= cols, s, NEG_INF)
         if segments:
-            qs = qseg_ref[0, :][:, None]
-            ks = kseg_ref[0, :][None, :]
+            qs = qseg_ref[0]
+            ks = kseg_ref[0]
             s = jnp.where(qs == ks, s, NEG_INF)
         # guard: fully-masked rows carry lse ≈ -inf; exp(s - lse) would
         # overflow at masked entries — zero them explicitly
@@ -353,8 +371,8 @@ def _dq_kernel(q_off_ref, k_off_ref, qseg_ref, kseg_ref, qmin_ref, qmax_ref,
             cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1) + j * block_k + k_off
             s = jnp.where(rows >= cols, s, NEG_INF)
         if segments:
-            qs = qseg_ref[0, :][:, None]
-            ks = kseg_ref[0, :][None, :]
+            qs = qseg_ref[0]
+            ks = kseg_ref[0]
             s = jnp.where(qs == ks, s, NEG_INF)
         p = jnp.where(s > NEG_INF / 2, jnp.exp(s - lse), 0.0)
         dp = jax.lax.dot_general(
@@ -613,8 +631,7 @@ def flash_attention(
     hkv = k.shape[2]
     if h % hkv != 0:
         raise ValueError(f"q heads {h} not a multiple of kv heads {hkv}")
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+    interpret = interpret_mode(interpret)
     bq = block_q or _pick_block(s)
     bk = block_k or _pick_block(k.shape[1])
     q_seg = segment_ids

@@ -37,20 +37,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from neuronx_distributed_tpu.kernels.backend import interpret_mode
 from neuronx_distributed_tpu.kernels.flash_attention import (
     _SMEM_SPEC,
     _pick_block,
 )
 
 NEG_INF = -1e30
-
-# jax<0.5 spelling compat: CompilerParams was TPUCompilerParams. The alias
-# lets the PAGED kernel's interpret-mode tests (the non-TPU CI proof of the
-# fused block-index-map path) run on old containers where the other kernel
-# tests are env-triaged; modern jax resolves the first name.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
 
 
 # --- paged KV: block-table gather/scatter -------------------------------------
@@ -260,7 +253,7 @@ def _decode_kernel(pos_ref, bound_ref, valid_ref, q_ref, k_ref, v_ref,
         )
         s = jnp.where(rows >= cols, s, NEG_INF)
         if use_valid:
-            ok = valid_ref[0, :][None, :] != 0          # (1, BL)
+            ok = valid_ref[0, 0] != 0                   # (1, BL)
             s = jnp.where(ok, s, NEG_INF)
         m_prev = m_scr[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -282,6 +275,18 @@ def _decode_kernel(pos_ref, bound_ref, valid_ref, q_ref, k_ref, v_ref,
         )
 
 
+def _valid_tiles(kv_valid, block, index_map):
+    """``kv_valid`` (B, L) as (B, L/block, 1, block) int32 plus the BlockSpec
+    that hands the kernel one (1, block) row per grid step. A (1, block) tile
+    of the 2-D (B, L) array is not a block Mosaic can tile (its
+    second-to-last dim is neither a multiple of 8 nor the whole axis); with
+    the block index lifted into its own axis the tile's last two dims ARE
+    the array's."""
+    b, l = kv_valid.shape
+    tiles = kv_valid.astype(jnp.int32).reshape(b, l // block, 1, block)
+    return tiles, pl.BlockSpec((1, 1, 1, block), index_map)
+
+
 def _flash_decode_call(q, k, v, pos, kv_valid, l_off, interpret, block_l):
     """q (B, Hkv, R, D) rows; k/v (B, Hkv, L, D) cache slice starting at
     global slot ``l_off``; pos (R,) global slot positions. Returns
@@ -295,8 +300,7 @@ def _flash_decode_call(q, k, v, pos, kv_valid, l_off, interpret, block_l):
         kv_valid = jnp.zeros((1, 1), jnp.int32)
         vspec = _SMEM_SPEC
     else:
-        kv_valid = kv_valid.astype(jnp.int32)
-        vspec = pl.BlockSpec((1, bl), lambda b_, h_, j: (b_, j))
+        kv_valid, vspec = _valid_tiles(kv_valid, bl, lambda b_, h_, j: (b_, j, 0, 0))
     bound = jnp.max(pos) + 1 - l_off
     out, lse = pl.pallas_call(
         functools.partial(
@@ -325,7 +329,7 @@ def _flash_decode_call(q, k, v, pos, kv_valid, l_off, interpret, block_l):
             pltpu.VMEM((r, 1), jnp.float32),
             pltpu.VMEM((r, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -364,8 +368,7 @@ def flash_decode_attention(
     hkv = k_cache.shape[2]
     group = h // hkv
     L = k_cache.shape[1]
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+    interpret = interpret_mode(interpret)
 
     # (B, S, H, D) → (B, Hkv, R=G·S, D): fold the GQA group into rows so one
     # kernel invocation serves every q head of a kv head
@@ -440,7 +443,7 @@ def flash_decode_attention(
         return replicated_over_tp()
 
     def per_rank(a, k_, v_, p_, kv):
-        rank = mesh_lib.compat_axis_index(mesh_lib.TP_AXIS)
+        rank = jax.lax.axis_index(mesh_lib.TP_AXIS)
         l_off = rank * (L // tp)
         o, lse = _flash_decode_call(a, k_, v_, p_, kv, l_off, interpret, block_l)
         # exp-weighted merge over the tp axis: partials with lse≈-inf (rows
@@ -474,14 +477,22 @@ def flash_decode_attention(
 # PHYSICAL pool pages directly — page j of slot b arrives from pool page
 # ``block_table[b, j]``, no logical copy ever exists. Same online-softmax
 # math as `_decode_kernel`, one page per sequential grid step. The gather
-# path stays the non-TPU fallback (and the numerics golden: streams are
-# pinned identical in tests/kernels/test_flash_decode.py, interpret mode).
+# transport is the numerics golden: outputs are pinned identical in
+# tests/kernels/test_flash_decode.py (interpret mode).
+#
+# One grid step streams a WHOLE page — every kv head of it — as one
+# contiguous (page_size, Hkv, D) block, and the kernel walks the heads
+# inside. A per-head block (1, page_size, 1, D) would slice one row out of
+# the pool's (Hkv, D) tiles, which Mosaic cannot tile (refused at compile
+# for the v5e at Llama-2-7B geometry); the whole-page block's last two dims
+# are the array's, and the page arrives in a single DMA.
 
 
 def _paged_decode_kernel(bt_ref, pos_ref, bound_ref, valid_ref, q_ref,
                          k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
-                         acc_scr, *, page_size, num_pages_log, use_valid):
-    j = pl.program_id(2)  # logical page (sequential; physical via bt_ref)
+                         acc_scr, *, page_size, num_pages_log, num_kv_heads,
+                         use_valid):
+    j = pl.program_id(1)  # logical page (sequential; physical via bt_ref)
 
     @pl.when(j == 0)
     def _init():
@@ -494,39 +505,109 @@ def _paged_decode_kernel(bt_ref, pos_ref, bound_ref, valid_ref, q_ref,
 
     @pl.when(run)
     def _body():
-        q = q_ref[0, 0].astype(jnp.float32)            # (R, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)      # (ps, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)      # (ps, D)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * (1.0 / (q.shape[-1] ** 0.5))               # (R, ps)
         rows = pos_ref[0, :][:, None]                  # (R, 1) slot positions
         cols = (
-            jax.lax.broadcasted_iota(jnp.int32, (q.shape[0], page_size), 1)
+            jax.lax.broadcasted_iota(
+                jnp.int32, (rows.shape[0], page_size), 1
+            )
             + j * page_size
         )
-        s = jnp.where(rows >= cols, s, NEG_INF)
-        if use_valid:
-            ok = valid_ref[0, :][None, :] != 0          # (1, ps)
-            s = jnp.where(ok, s, NEG_INF)
-        m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        ref = jnp.where(m_new > NEG_INF / 2, m_new, 0.0)
-        p = jnp.exp(s - ref)
-        alpha = jnp.exp(m_prev - ref)
-        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        m_scr[:] = m_new
+        for h in range(num_kv_heads):
+            q = q_ref[0, h].astype(jnp.float32)        # (R, D)
+            k = k_ref[0, :, h, :].astype(jnp.float32)  # (ps, D)
+            v = v_ref[0, :, h, :].astype(jnp.float32)  # (ps, D)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * (1.0 / (q.shape[-1] ** 0.5))           # (R, ps)
+            s = jnp.where(rows >= cols, s, NEG_INF)
+            if use_valid:
+                ok = valid_ref[0, 0] != 0               # (1, ps)
+                s = jnp.where(ok, s, NEG_INF)
+            m_prev = m_scr[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            ref = jnp.where(m_new > NEG_INF / 2, m_new, 0.0)
+            p = jnp.exp(s - ref)
+            alpha = jnp.exp(m_prev - ref)
+            l_scr[h] = l_scr[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            m_scr[h] = m_new
 
     @pl.when(j == num_pages_log - 1)
     def _finish():
         l = l_scr[:]
-        o_ref[0, 0] = (acc_scr[:] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-        lse_ref[0, 0] = jnp.where(
+        o_ref[0] = (acc_scr[:] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        lse_ref[0] = jnp.where(
             l > 0, m_scr[:] + jnp.log(jnp.maximum(l, 1e-30)), NEG_INF
         )
+
+
+def _paged_decode_call(qt, k_pool, v_pool, block_table, rows_pos, kv_valid,
+                       page_size, interpret):
+    """qt (B, Hkv, R, D) rows; pools (P, page_size, Hkv, D); block_table
+    (B, n_log); rows_pos (R,) slot positions; kv_valid (B, L) or None.
+    Returns out (B, Hkv, R, D)."""
+    b, hkv, r, d = qt.shape
+    n_log = block_table.shape[1]
+    use_valid = kv_valid is not None
+    if kv_valid is None:
+        kv_valid = jnp.zeros((1, 1), jnp.int32)
+        vspec = _SMEM_SPEC
+    else:
+        kv_valid, vspec = _valid_tiles(
+            kv_valid, page_size, lambda b_, j, bt: (b_, j, 0, 0)
+        )
+    # THE fusion: logical page j of slot b_ streams straight from physical
+    # pool page bt[b_, j] — no gathered copy in HBM
+    page_spec = pl.BlockSpec(
+        (1, page_size, hkv, d), lambda b_, j, bt: (bt[b_, j], 0, 0, 0)
+    )
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,  # the block table, read by the k/v index maps
+        grid=(b, n_log),
+        in_specs=[
+            pl.BlockSpec((1, r), lambda b_, j, bt: (0, 0)),       # pos
+            _SMEM_SPEC,                                            # bound
+            vspec,                                                 # kv_valid
+            pl.BlockSpec((1, hkv, r, d), lambda b_, j, bt: (b_, 0, 0, 0)),
+            page_spec,
+            page_spec,
+        ],
+        out_specs=[
+            pl.BlockSpec((1, hkv, r, d), lambda b_, j, bt: (b_, 0, 0, 0)),
+            pl.BlockSpec((1, hkv, r, 1), lambda b_, j, bt: (b_, 0, 0, 0)),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((hkv, r, 1), jnp.float32),
+            pltpu.VMEM((hkv, r, 1), jnp.float32),
+            pltpu.VMEM((hkv, r, d), jnp.float32),
+        ],
+    )
+    out, _ = pl.pallas_call(
+        functools.partial(
+            _paged_decode_kernel, page_size=page_size,
+            num_pages_log=n_log, num_kv_heads=hkv, use_valid=use_valid,
+        ),
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((b, hkv, r, d), qt.dtype),
+            jax.ShapeDtypeStruct((b, hkv, r, 1), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+        ),
+        interpret=interpret,
+    )(
+        block_table.astype(jnp.int32),
+        rows_pos.reshape(1, r),
+        jnp.asarray(jnp.max(rows_pos) + 1, jnp.int32).reshape((1,)),
+        kv_valid,
+        qt, k_pool, v_pool,
+    )
+    return out
 
 
 def paged_flash_decode_attention(
@@ -551,90 +632,57 @@ def paged_flash_decode_attention(
     choices differ only in fp accumulation order, ~1e-7) — without ever
     materializing the gathered logical view in HBM.
 
-    Off-TPU (and not ``interpret``) this routes through the gather
-    fallback — the exact transport the serving chunk uses today — so the
-    function is safe to call on any backend."""
+    This IS the kernel on every backend: off the TPU it runs only
+    interpreted (``interpret=`` / the tests' session switch) and otherwise
+    fails to lower — it never turns into the gather reference.
+
+    Under a mesh the call sits in a manual region (Mosaic calls cannot be
+    auto-partitioned): batch over the data axes, heads over tp when both
+    head counts divide it, replicated over tp otherwise."""
+    from neuronx_distributed_tpu.parallel import mesh as mesh_lib
+
     b, s, h, d = q.shape
     hkv = k_pool.shape[2]
     group = h // hkv
-    n_log = block_table.shape[1]
-    L = n_log * page_size
-    if interpret is None:
-        interpret = False
-    on_tpu = jax.devices()[0].platform == "tpu"
-    if not (on_tpu or interpret):
-        # non-TPU fallback: materialize the logical view (the serving
-        # chunk's gather transport) and run the reference decode math
-        from neuronx_distributed_tpu.modules.attention import (
-            decode_attention,
-        )
-
-        k_log = paged_gather_leaf(k_pool, block_table, page_size)
-        v_log = paged_gather_leaf(v_pool, block_table, page_size)
-        return decode_attention(q, k_log, v_log, q_pos, kv_valid=kv_valid)
+    interpret = interpret_mode(interpret)
 
     qt = jnp.swapaxes(q, 1, 2).reshape(b, hkv, group, s, d).reshape(
         b, hkv, group * s, d
     )
     q_pos = q_pos[None] if q_pos.ndim == 0 else q_pos
     rows_pos = jnp.tile(q_pos.astype(jnp.int32), (group,))  # (R,)
-    r = group * s
-    use_valid = kv_valid is not None
-    if kv_valid is None:
-        kv_valid = jnp.zeros((1, 1), jnp.int32)
-        vspec = _SMEM_SPEC
+
+    if not mesh_lib.model_parallel_is_initialized():
+        out = _paged_decode_call(
+            qt, k_pool, v_pool, block_table, rows_pos, kv_valid, page_size,
+            interpret,
+        )
     else:
-        kv_valid = kv_valid.astype(jnp.int32)
-        vspec = pl.BlockSpec((1, page_size), lambda b_, h_, j, bt: (b_, j))
-    bound = jnp.max(rows_pos) + 1
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,  # the block table, read by the k/v index maps
-        grid=(b, hkv, n_log),
-        in_specs=[
-            pl.BlockSpec((1, r), lambda b_, h_, j, bt: (0, 0)),   # pos
-            _SMEM_SPEC,                                            # bound
-            vspec,                                                 # kv_valid
-            pl.BlockSpec((1, 1, r, d), lambda b_, h_, j, bt: (b_, h_, 0, 0)),
-            # THE fusion: logical page j of slot b_ streams straight from
-            # physical pool page bt[b_, j] — no gathered copy in HBM
-            pl.BlockSpec(
-                (1, page_size, 1, d), lambda b_, h_, j, bt: (bt[b_, j], 0, h_, 0)
+        from jax.sharding import PartitionSpec as P
+
+        mesh = mesh_lib.get_mesh()
+        dp = mesh.shape[mesh_lib.EDP_AXIS] * mesh.shape[mesh_lib.EP_AXIS]
+        tp = mesh.shape[mesh_lib.TP_AXIS]
+        bspec = mesh_lib.DATA_AXES if (dp > 1 and b % dp == 0) else None
+        hspec = (
+            mesh_lib.TP_AXIS
+            if (tp > 1 and h % tp == 0 and hkv % tp == 0) else None
+        )
+        if kv_valid is None:
+            kv_valid = jnp.ones(
+                (b, block_table.shape[1] * page_size), jnp.int32
+            )
+        rows = P(bspec, hspec, None, None)
+        pool = P(None, None, hspec, None)
+        fn = mesh_lib.manual_shard_map(
+            lambda a, k_, v_, bt, p_, kv: _paged_decode_call(
+                a, k_, v_, bt, p_, kv, page_size, interpret
             ),
-            pl.BlockSpec(
-                (1, page_size, 1, d), lambda b_, h_, j, bt: (bt[b_, j], 0, h_, 0)
-            ),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, r, d), lambda b_, h_, j, bt: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, 1, r, 1), lambda b_, h_, j, bt: (b_, h_, 0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((r, 1), jnp.float32),
-            pltpu.VMEM((r, 1), jnp.float32),
-            pltpu.VMEM((r, d), jnp.float32),
-        ],
-    )
-    out, _ = pl.pallas_call(
-        functools.partial(
-            _paged_decode_kernel, page_size=page_size,
-            num_pages_log=n_log, use_valid=use_valid,
-        ),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, hkv, r, d), q.dtype),
-            jax.ShapeDtypeStruct((b, hkv, r, 1), jnp.float32),
-        ],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(
-        block_table.astype(jnp.int32),
-        rows_pos.reshape(1, r),
-        jnp.asarray(bound, jnp.int32).reshape((1,)),
-        kv_valid,
-        qt, k_pool, v_pool,
-    )
+            in_specs=(rows, pool, pool, P(bspec, None), P(None),
+                      P(bspec, None)),
+            out_specs=rows,
+        )
+        out = fn(qt, k_pool, v_pool, block_table, rows_pos, kv_valid)
     return jnp.swapaxes(
         out.reshape(b, hkv, group, s, d).reshape(b, h, s, d), 1, 2
     ).astype(q.dtype)

@@ -48,6 +48,10 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from neuronx_distributed_tpu.kernels.backend import (
+    interpret_mode,
+    resolve_attention_impl,
+)
 from neuronx_distributed_tpu.parallel import mesh as mesh_lib
 from neuronx_distributed_tpu.utils.logger import get_logger
 
@@ -125,7 +129,7 @@ def _ring_flash_fwd_pass(q, k, v, q_seg, k_seg, axis_name, bq, bk, interpret):
     from neuronx_distributed_tpu.kernels.flash_attention import _flash_fwd
 
     cp = lax.axis_size(axis_name)
-    rank = mesh_lib.compat_axis_index(axis_name)
+    rank = lax.axis_index(axis_name)
     b, s_loc, h, d = q.shape
     qt = jnp.swapaxes(q, 1, 2)  # (B, H, S, D)
     segs = q_seg is not None
@@ -191,7 +195,7 @@ def _ring_flash_bwd_rule(axis_name, bq, bk, interpret, res, g):
 
     q, k, v, q_seg, k_seg, out, lse = res
     cp = lax.axis_size(axis_name)
-    rank = mesh_lib.compat_axis_index(axis_name)
+    rank = lax.axis_index(axis_name)
     b, s_loc, h, d = q.shape
     segs = q_seg is not None
     ks0 = k_seg if segs else jnp.zeros((b, s_loc), jnp.int32)
@@ -273,8 +277,7 @@ def ring_flash_attention(
     (B, S_local): packed-document isolation, key segments ride the ring."""
     from neuronx_distributed_tpu.kernels.flash_attention import _pick_block
 
-    if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+    interpret = interpret_mode(interpret)
     s_loc = q.shape[1]
     bq = bk = _pick_block(s_loc, 256)
     return _ring_flash(q, k, v, q_seg, k_seg, axis_name, bq, bk, interpret)
@@ -299,7 +302,7 @@ def ring_attention(
     documents per-document isolation at ring scale. Returns
     (B, S_local, H, D)."""
     cp = lax.axis_size(axis_name)
-    rank = mesh_lib.compat_axis_index(axis_name)
+    rank = lax.axis_index(axis_name)
     b, s_loc, h, d = q.shape
     hkv = k.shape[2]
     g = h // hkv
@@ -378,8 +381,7 @@ def ring_attention_sharded(
     dp = mesh.shape[mesh_lib.EDP_AXIS] * mesh.shape[mesh_lib.EP_AXIS]
     tp = mesh.shape[mesh_lib.TP_AXIS]
     cp = mesh.shape[mesh_lib.CP_AXIS]
-    if impl == "auto":
-        impl = "flash" if jax.devices()[0].platform == "tpu" else "xla"
+    impl = resolve_attention_impl(impl)
     if impl == "flash" and not causal:
         impl = "xla"  # the kernel ring is causal-only; xla blocks are exact
     pad = (-s) % cp if cp > 1 else 0

@@ -22,6 +22,10 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from neuronx_distributed_tpu.kernels.backend import (
+    interpret_mode,
+    resolve_attention_impl,
+)
 from neuronx_distributed_tpu.parallel import mesh as mesh_lib
 from neuronx_distributed_tpu.utils.logger import get_logger
 
@@ -54,10 +58,7 @@ def ulysses_attention(
         return lax.all_to_all(x, axis_name, split_axis=1, concat_axis=2, tiled=True)
 
     q, k, v = scatter_heads(q), scatter_heads(k), scatter_heads(v)
-    if inner_impl == "auto":
-        inner_impl = (
-            "flash" if jax.devices()[0].platform == "tpu" else "xla"
-        )
+    inner_impl = resolve_attention_impl(inner_impl)
     if inner_impl == "flash":
         from neuronx_distributed_tpu.kernels.flash_attention import (
             _flash_attention_bhsd,
@@ -67,10 +68,10 @@ def ulysses_attention(
         # the kernel serves GQA natively — K/V stay at their (scattered)
         # Hkv/cp head count, no HBM replication
         bq = bk = _pick_block(q.shape[1], 512)
-        interpret = jax.devices()[0].platform != "tpu"
         out = _flash_attention_bhsd(
             jnp.swapaxes(q, 1, 2), jnp.swapaxes(k, 1, 2),
-            jnp.swapaxes(v, 1, 2), None, None, causal, bq, bk, interpret,
+            jnp.swapaxes(v, 1, 2), None, None, causal, bq, bk,
+            interpret_mode(None),
         )
         out = jnp.swapaxes(out, 1, 2)
     else:

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 from jax.sharding import PartitionSpec as P
 
+from neuronx_distributed_tpu.kernels import backend
 from neuronx_distributed_tpu.modules.qkv_linear import GQAQKVColumnParallelLinear
 from neuronx_distributed_tpu.parallel import mesh as mesh_lib
 from neuronx_distributed_tpu.parallel.layers import RowParallelLinear
@@ -131,10 +132,7 @@ def attention_op(q, k, v, causal: bool = True, impl: str = "auto",
             return ring_attention_sharded(
                 q, k, v, causal=causal, impl=ring_impl, segment_ids=q_seg
             )
-        if cp == 1 and (
-            impl == "flash"  # explicit: interpret-mode on CPU (kernel tests)
-            or (impl == "auto" and jax.devices()[0].platform == "tpu")
-        ):
+        if cp == 1 and backend.resolve_attention_impl(impl) == "flash":
             from neuronx_distributed_tpu.kernels.flash_attention import flash_attention
 
             return flash_attention(
@@ -144,14 +142,10 @@ def attention_op(q, k, v, causal: bool = True, impl: str = "auto",
         return xla_attention(
             q, k, v, causal=causal, segment_ids=q_seg, kv_segment_ids=k_seg
         )
-    if impl == "auto":
-        if cp > 1:
-            # sequence sharded over cp → ring attention (reference long-seq
-            # path: CP groups + NKI ring kernel, parallel_state.py:678,
-            # kernels/ring_attention_kernel.py)
-            impl = "ring"
-        else:
-            impl = "flash" if jax.devices()[0].platform == "tpu" else "xla"
+    # "auto" with the sequence sharded over cp → ring attention (reference
+    # long-seq path: CP groups + NKI ring kernel, parallel_state.py:678,
+    # kernels/ring_attention_kernel.py)
+    impl = backend.resolve_attention_impl(impl, cp)
     if impl == "flash":
         from neuronx_distributed_tpu.kernels.flash_attention import flash_attention
 
@@ -608,10 +602,8 @@ def scatter_cache_window(paged, logical, page_size: int, start_col,
 # scope holds the pools in the same order — scatters the chunk's write
 # window (the in-chunk columns the pool has not seen yet; pre-window columns
 # rewrite their own bytes, so shared CoW pages stay bit-stable) and attends
-# straight off the pool. On TPU that is the fused kernel; elsewhere
-# ``paged_flash_decode_attention`` falls back to gather + this very
-# function, making the fused mode BIT-identical to the gather transport
-# (pinned in tests/serving/test_multichip.py).
+# straight off the pool through the fused kernel (compiled on the TPU,
+# interpreted only in tests — it never degrades to the gather transport).
 
 _FUSED_PAGED_STACK: list = []
 
@@ -626,7 +618,7 @@ class fused_paged_attention_scope:
     def __init__(self, pools, tables, page_size: int, page0, n_win: int):
         self.frame = {
             "pools": pools, "tables": tables, "page_size": page_size,
-            "page0": page0, "n_win": n_win, "idx": 0, "busy": False,
+            "page0": page0, "n_win": n_win, "idx": 0,
         }
 
     def __enter__(self):
@@ -697,13 +689,9 @@ def _fused_paged_decode(frame, q, k_cache, v_cache, q_pos, kv_valid):
     v_pool = paged_scatter_window_leaf(
         v_pool, v_cache, bt, frame["page0"], frame["n_win"], ps
     )
-    frame["busy"] = True  # the off-TPU fallback re-enters decode_attention
-    try:
-        return paged_flash_decode_attention(
-            q, k_pool, v_pool, bt, q_pos, kv_valid=kv_valid, page_size=ps
-        )
-    finally:
-        frame["busy"] = False
+    return paged_flash_decode_attention(
+        q, k_pool, v_pool, bt, q_pos, kv_valid=kv_valid, page_size=ps
+    )
 
 
 def cache_fingerprint(cache):
@@ -725,6 +713,16 @@ def cache_fingerprint(cache):
 FLASH_DECODE_MIN_CONTEXT = 1024
 
 
+def resolve_decode_impl(cache_len: int) -> str:
+    """What :func:`decode_attention` runs for a cache of ``cache_len``
+    columns outside a fused-paged scope: ``"flash_decode"`` (the Pallas
+    kernel — long caches on the TPU) or ``"einsum"``. Kept as a function so
+    the serving engine records the same answer the trace takes."""
+    if cache_len >= FLASH_DECODE_MIN_CONTEXT and backend.on_tpu():
+        return "flash_decode"
+    return "einsum"
+
+
 def decode_attention(q, k_cache, v_cache, q_pos, mask=None, kv_valid=None):
     """Attention of q (B, S, H, D) rows at positions ``q_pos`` (S,) against
     the full cache (B, L, Hkv, D), each row masked at its own position — the
@@ -741,19 +739,12 @@ def decode_attention(q, k_cache, v_cache, q_pos, mask=None, kv_valid=None):
     Inside a :class:`fused_paged_attention_scope` (the serving chunk's
     ``paged_attention="fused"`` transport, ISSUE 14) the call attends the
     PAGED POOL directly through ``paged_flash_decode_attention`` instead of
-    the materialized view passed in — bit-identical off TPU (the kernel's
-    fallback is gather + this function), fused on it."""
+    the materialized view passed in."""
     if _FUSED_PAGED_STACK and mask is None:
-        frame = _FUSED_PAGED_STACK[-1]
-        if not frame["busy"]:
-            return _fused_paged_decode(
-                frame, q, k_cache, v_cache, q_pos, kv_valid
-            )
-    if (
-        mask is None
-        and k_cache.shape[1] >= FLASH_DECODE_MIN_CONTEXT
-        and jax.devices()[0].platform == "tpu"
-    ):
+        return _fused_paged_decode(
+            _FUSED_PAGED_STACK[-1], q, k_cache, v_cache, q_pos, kv_valid
+        )
+    if mask is None and resolve_decode_impl(k_cache.shape[1]) == "flash_decode":
         from neuronx_distributed_tpu.kernels.flash_decode import (
             flash_decode_attention,
         )

@@ -86,7 +86,7 @@ def _sharded_blockwise_mlp(mesh, ep_ax, tp_ax, E_l: int, ep: int, glu: bool,
     def sharded_mlp(x, token_idx, ws, sizes, gate_, up_, down_):
         T = x.shape[0]
         N = token_idx.shape[0]
-        ep_rank = mesh_lib.compat_axis_index(ep_ax) if ep > 1 else 0
+        ep_rank = jax.lax.axis_index(ep_ax) if ep > 1 else 0
         local_sizes = jax.lax.dynamic_slice_in_dim(sizes, ep_rank * E_l, E_l)
         offsets = jnp.concatenate(
             [jnp.zeros((1,), sizes.dtype), jnp.cumsum(sizes)]
@@ -146,7 +146,7 @@ def _sharded_blockwise_mlp_manual(mesh, edp_ax, ep_ax, tp_ax, E: int,
         sizes = jnp.bincount(flat_e, length=E).astype(jnp.int32)
         ws = top_w.reshape(-1)[order].astype(x.dtype)
         N = token_idx.shape[0]
-        ep_rank = mesh_lib.compat_axis_index(ep_ax) if ep > 1 else 0
+        ep_rank = jax.lax.axis_index(ep_ax) if ep > 1 else 0
         local_sizes = jax.lax.dynamic_slice_in_dim(sizes, ep_rank * E_l, E_l)
         offsets = jnp.concatenate(
             [jnp.zeros((1,), sizes.dtype), jnp.cumsum(sizes)]
@@ -192,7 +192,7 @@ def _sharded_blockwise_mlp_rolled(mesh, ep_ax, tp_ax, E_l: int, ep: int,
 
     def sharded_mlp(xs_, sizes, gate_, up_, down_):
         N = xs_.shape[0]
-        ep_rank = mesh_lib.compat_axis_index(ep_ax) if ep > 1 else 0
+        ep_rank = jax.lax.axis_index(ep_ax) if ep > 1 else 0
         local_sizes = jax.lax.dynamic_slice_in_dim(sizes, ep_rank * E_l, E_l)
         offsets = jnp.concatenate(
             [jnp.zeros((1,), sizes.dtype), jnp.cumsum(sizes)]

@@ -25,7 +25,7 @@ Design constraints (all load-bearing):
   ``memory_analysis`` needing an AOT compile the caller did not opt into
   (``memory_analysis=True`` pays one extra XLA compile per signature; the
   jit dispatch cache and the AOT cache do not share, measured on this
-  jax), no ``peak_memory_in_bytes`` on old jaxlib, unknown device peaks —
+  jax), unknown device peaks —
   reports the literal string ``"unavailable"`` (:data:`UNAVAILABLE`),
   never a crash and never a silently-wrong number.
 * **Accumulation over double-counting.** ``wrap()`` with an existing name
@@ -422,8 +422,7 @@ class ProgramInfo:
         )
 
 
-# memory_analysis() field mapping (CompiledMemoryStats attribute names);
-# peak is absent on this container's jaxlib — it stays UNAVAILABLE there
+# memory_analysis() field mapping (CompiledMemoryStats attribute names)
 _MEMORY_ATTRS = (
     ("peak_bytes", "peak_memory_in_bytes"),
     ("argument_bytes", "argument_size_in_bytes"),
@@ -562,6 +561,10 @@ class ProgramLedger:
         self._clock = clock
         self._prewarm_depth = 0
         self._records: "OrderedDict[str, _ProgramRecord]" = OrderedDict()
+        # implementation choices the owner resolved by platform ("auto" →
+        # the kernel or the reference it actually traces), written once by
+        # the engine/trainer and exported verbatim in snapshot()["resolved"]
+        self.resolved: Dict[str, str] = {}
         self.peaks = device_peaks()
         name = self._name
         self._fam_dispatch = view.family(
@@ -891,7 +894,8 @@ class ProgramLedger:
 
     def snapshot(self, analyze: bool = True,
                  include_timing: bool = True) -> dict:
-        """``{"device", "by_program", "totals"}`` — the full ledger.
+        """``{"device", "resolved", "by_program", "totals"}`` — the full
+        ledger.
         ``analyze=False`` skips any not-yet-run cost analysis (halt paths:
         no tracing on an error path); ``include_timing=False`` drops every
         wall-clock-derived field, leaving a projection that is
@@ -921,7 +925,12 @@ class ProgramLedger:
             "peak_hbm_bytes_per_s": self.peaks["hbm_bytes_per_s"],
             "peak_source": self.peaks["source"],
         }
-        return {"device": device, "by_program": programs, "totals": totals}
+        return {
+            "device": device,
+            "resolved": dict(self.resolved),
+            "by_program": programs,
+            "totals": totals,
+        }
 
     def halt_summary(self, top: int = 6) -> dict:
         """Flat top-N program table for halt post-mortems: scalars only,

@@ -26,7 +26,7 @@ from neuronx_distributed_tpu.parallel import mesh as mesh_lib
 def _local_then_global_topk(x, k, axis_name):
     """Inside shard_map: x (..., V_local) → exact global (values, indices)."""
     tp = lax.axis_size(axis_name)
-    rank = mesh_lib.compat_axis_index(axis_name)
+    rank = jax.lax.axis_index(axis_name)
     v_loc = x.shape[-1]
     vals, idx = lax.top_k(x, k)  # local candidates
     idx = idx + rank * v_loc  # globalize indices

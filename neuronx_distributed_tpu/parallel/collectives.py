@@ -99,9 +99,7 @@ def broadcast(x, axis_name: AxisName, root: int = 0):
 
 
 def axis_index(axis_name: AxisName):
-    from neuronx_distributed_tpu.parallel import mesh as mesh_lib
-
-    return mesh_lib.compat_axis_index(axis_name)
+    return lax.axis_index(axis_name)
 
 
 def axis_size(axis_name: AxisName) -> int:

@@ -432,7 +432,7 @@ def _vocab_parallel_lookup(mesh, axis: str):
 
     def local_lookup(table_l, ids_):
         per = table_l.shape[0]
-        lo = mesh_lib.compat_axis_index(axis) * per
+        lo = jax.lax.axis_index(axis) * per
         local_ids = ids_ - lo
         ok = (local_ids >= 0) & (local_ids < per)
         rows = jnp.take(table_l, jnp.clip(local_ids, 0, per - 1), axis=0)

@@ -27,7 +27,6 @@ from neuronx_distributed_tpu.parallel.mesh import (  # noqa: F401
     CP_AXIS,
     EP_AXIS,
     TP_AXIS,
-    compat_axis_index as axis_index,
 )
 
 
@@ -38,7 +37,7 @@ def _norm_dim(dim: int, ndim: int) -> int:
 def _local_slice(x, axis_name: str, dim: int):
     """Take this rank's chunk of a replicated tensor along ``dim``."""
     n = lax.axis_size(axis_name)
-    idx = axis_index(axis_name)
+    idx = lax.axis_index(axis_name)
     dim = _norm_dim(dim, x.ndim)
     if x.shape[dim] % n != 0:
         raise ValueError(f"dim {dim} size {x.shape[dim]} not divisible by axis size {n}")

@@ -131,19 +131,15 @@ def _build_device_grid(
     n = int(np.prod(shape))
     if n != len(devices):
         raise ValueError(f"mesh shape {tuple(shape)} needs {n} devices, have {len(devices)}")
-    try:
+    if devices and getattr(devices[0], "platform", "") == "tpu":
+        # on the chip a failed topology-aware mesh is a fault, not a cue to
+        # reshape in enumeration order (tp would silently leave its nearest
+        # ICI neighbours) — let it raise
         from jax.experimental import mesh_utils
 
         return mesh_utils.create_device_mesh(tuple(shape), devices=devices)
-    except Exception as e:  # non-TPU topologies / virtual device sets
-        if devices and getattr(devices[0], "platform", "") == "tpu":
-            logger.warning(
-                "topology-aware device mesh failed (%s); falling back to "
-                "enumeration-order reshape — tp axis may not map to nearest "
-                "ICI neighbours",
-                e,
-            )
-        return np.asarray(devices, dtype=object).reshape(tuple(shape))
+    # virtual / CPU device sets carry no topology to map onto
+    return np.asarray(devices, dtype=object).reshape(tuple(shape))
 
 
 def _build_hybrid_device_grid(
@@ -369,112 +365,24 @@ def mesh_device_counts() -> dict:
 
 
 def ctx_abstract_mesh():
-    """The tracing context's AbstractMesh (``jax.sharding.get_abstract_mesh``)
-    — or an EMPTY AbstractMesh on jax versions that predate the API
-    (< 0.5, where no context mesh is trackable; top-level tracing on new
-    jax returns the same empty sentinel). Every caller branches on
-    ``.empty`` and only touches ``manual_axes``/``are_all_axes_auto`` on a
-    non-empty mesh, so the fallback is exact for the code paths that can
-    exist on the old version."""
-    import jax as _jax
-
-    get = getattr(_jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        return get()
-    return _jax.sharding.AbstractMesh(())
-
-
-# Trace-time stack of {axis: rank scalar} frames published by
-# compat_shard_map's partial-manual fallback (jax < 0.5 only; see
-# compat_axis_index).
-_COMPAT_RANK_FRAMES: list = []
-
-
-def compat_axis_index(axis):
-    """``lax.axis_index`` that also works inside PARTIAL-manual regions on
-    jax < 0.5, where its PartitionId lowering is rejected by the SPMD
-    partitioner ("PartitionId instruction is not supported for SPMD
-    partitioning"). There :func:`compat_shard_map` threads a sharded rank
-    iota into the region and publishes it here for the duration of the
-    trace — the zero1 explicit-update rank_arrays trick, generalized. On
-    new jax (or fully-manual regions) this IS ``lax.axis_index``."""
-    import jax as _jax
-
-    for frame in reversed(_COMPAT_RANK_FRAMES):
-        if axis in frame:
-            return frame[axis]
-    return _jax.lax.axis_index(axis)
+    """The tracing context's AbstractMesh (empty at top level). Every caller
+    branches on ``.empty`` and only touches ``manual_axes``/
+    ``are_all_axes_auto`` on a non-empty mesh."""
+    return jax.sharding.get_abstract_mesh()
 
 
 def compat_shard_map(fn, mesh, in_specs, out_specs, axis_names=None,
                      check_vma=False):
-    """``jax.shard_map`` with a fallback to ``jax.experimental.shard_map``
-    for jax < 0.5 (this container's 0.4.37): ``axis_names`` (the claimed
-    manual axes) maps to the old API's complement ``auto`` set and
-    ``check_vma`` to ``check_rep``. Partial-manual regions additionally
-    get a sharded rank iota threaded in per manual axis, served through
-    :func:`compat_axis_index` (old XLA cannot partition the PartitionId op
-    ``lax.axis_index`` lowers to there). Semantics are identical on both —
-    every explicit-SPMD region in the repo routes through here."""
-    import jax as _jax
-
-    if hasattr(_jax, "shard_map"):
-        kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check_vma)
-        if axis_names is not None:
-            kwargs["axis_names"] = axis_names
-        return _jax.shard_map(fn, **kwargs)
-    from jax.experimental.shard_map import shard_map as _esm
-
-    manual = set(axis_names) if axis_names is not None else set(mesh.axis_names)
-    auto = frozenset(mesh.axis_names) - manual
-    if not auto:
-        return _esm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                    check_rep=check_vma)
-    if PP_AXIS in manual:
-        # The pipeline engines' pp-manual/rest-auto programs (ppermute
-        # chains under scan) hard-ABORT this jaxlib's XLA:CPU compiler —
-        # not a catchable Python error, a process SIGABRT that would take
-        # the whole test run down. Fail the trace cleanly instead.
-        raise RuntimeError(
-            "pipeline-parallel shard_map regions require jax >= 0.5 "
-            "(this jax's partial-manual CollectivePermute lowering "
-            "crashes XLA); run with pp=1 on this installation"
-        )
-    if not isinstance(in_specs, (tuple, list)) or isinstance(in_specs, P):
-        # P is a tuple subclass — tuple(in_specs) would silently explode a
-        # broadcast spec into its entries when we prepend the rank iota
-        raise TypeError(
-            "compat_shard_map's jax<0.5 partial-manual fallback needs an "
-            "explicit per-argument in_specs tuple"
-        )
-    rank_axes = sorted(manual)
-    rank_specs = tuple(P(a) for a in rank_axes)
-
-    def wrapped(rank_args, *args):
-        _COMPAT_RANK_FRAMES.append(
-            {a: r[0] for a, r in zip(rank_axes, rank_args)}
-        )
-        try:
-            return fn(*args)
-        finally:
-            _COMPAT_RANK_FRAMES.pop()
-
-    inner = _esm(
-        wrapped, mesh=mesh,
-        in_specs=(rank_specs,) + tuple(in_specs),
-        out_specs=out_specs, check_rep=check_vma, auto=auto,
-    )
-
-    def call(*args):
-        import jax.numpy as _jnp
-
-        ranks = tuple(
-            _jnp.arange(mesh.shape[a], dtype=_jnp.int32) for a in rank_axes
-        )
-        return inner(ranks, *args)
-
-    return call
+    """``jax.shard_map`` with this repo's defaults: ``check_vma`` off (the
+    collective-reduction outputs of the explicit-SPMD regions do not carry
+    varying-manual-axes types) and ``axis_names`` (the claimed manual axes)
+    passed only when given. Every explicit-SPMD region in the repo routes
+    through here or :func:`manual_shard_map` (graftlint GL04)."""
+    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                  check_vma=check_vma)
+    if axis_names is not None:
+        kwargs["axis_names"] = axis_names
+    return jax.shard_map(fn, **kwargs)
 
 
 def manual_shard_map(fn, in_specs, out_specs):
@@ -488,8 +396,6 @@ def manual_shard_map(fn, in_specs, out_specs):
     context's AbstractMesh with only the remaining axes. Shared by the flash
     and ring attention wrappers, blockwise MoE, and the distributed topk.
     """
-    import jax as _jax
-
     mesh = get_mesh()
     ctx_mesh = ctx_abstract_mesh()
     target = mesh if ctx_mesh.empty else ctx_mesh
@@ -500,7 +406,7 @@ def manual_shard_map(fn, in_specs, out_specs):
     # ``lax.axis_index`` lowers into a manual_computation that re-binds the
     # PARENT's axes — "operates on axis 'pp' which is already bound" (hit by
     # cp×pp ring attention, round 5). Under an outer jit this inlines.
-    return _jax.jit(
+    return jax.jit(
         compat_shard_map(
             fn,
             mesh=target,

@@ -53,15 +53,10 @@ QMAX = 127.0  # int8 symmetric clamp bound (quantization/config.py contract)
 
 def _axis_size(axis_name) -> int:
     """STATIC size of a bound mesh axis (the ring hop count is a python
-    loop, so it must be a python int). jax >= 0.5 spells it
-    ``lax.axis_size``; older jax exposes the frame (or, older still, the
-    bare size) via ``jax.core.axis_frame``."""
-    if hasattr(lax, "axis_size"):
-        # graftlint: ok[GL02] axis_size is STATIC trace-time metadata (a
-        # python int under shard_map), not a device value — no transfer
-        return int(lax.axis_size(axis_name))
-    frame = jax.core.axis_frame(axis_name)
-    return int(getattr(frame, "size", frame))
+    loop, so it must be a python int)."""
+    # graftlint: ok[GL02] axis_size is STATIC trace-time metadata (a
+    # python int under shard_map), not a device value — no transfer
+    return int(lax.axis_size(axis_name))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -131,7 +126,7 @@ def quantized_all_reduce(
     chunk_elems = -(-n // (n_ranks * block_size)) * block_size
     flat = jnp.pad(flat, (0, n_ranks * chunk_elems - n))
     chunks = flat.reshape(n_ranks, chunk_elems)
-    rank = mesh_lib.compat_axis_index(axis_name)
+    rank = jax.lax.axis_index(axis_name)
     fwd = [(i, (i + 1) % n_ranks) for i in range(n_ranks)]
 
     # phase 1 — ring reduce-scatter, dequant-add-requant per hop: at step t

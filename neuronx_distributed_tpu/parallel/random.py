@@ -32,5 +32,5 @@ def model_parallel_base_key(key: jax.Array) -> jax.Array:
 def fold_in_axes(key: jax.Array, *axis_names: str) -> jax.Array:
     """Per-rank key inside ``shard_map``: folds each mesh axis index in turn."""
     for name in axis_names:
-        key = jax.random.fold_in(key, mesh_lib.compat_axis_index(name))
+        key = jax.random.fold_in(key, jax.lax.axis_index(name))
     return key

@@ -185,29 +185,31 @@ class ServingPartitioner:
 
     # --- params -------------------------------------------------------------
 
-    def shard_params(self, params):
-        """Place a params pytree per its ``nn.Partitioned`` metadata
-        (boxed trees are unboxed — the metadata has done its job once the
-        placement is committed). Unannotated leaves replicate."""
+    def param_shardings(self, params):
+        """``(unboxed values, NamedSharding tree)`` for a params pytree per
+        its ``nn.Partitioned`` metadata. Unannotated leaves replicate. Needs
+        only shapes, so it also serves abstract (``ShapeDtypeStruct``)
+        trees — the compile-without-a-chip rehearsal."""
         from flax.core import meta
 
         specs = nn.get_partition_spec(params)
         values = meta.unbox(params)
         leaves, treedef = jax.tree_util.tree_flatten(values)
         spec_leaves = treedef.flatten_up_to(specs)
-        placed = [
-            jax.device_put(
-                leaf,
-                NamedSharding(
-                    self.mesh,
-                    self._fit_spec(
-                        spec if isinstance(spec, P) else P(), leaf.shape
-                    ),
-                ),
+        shardings = [
+            NamedSharding(
+                self.mesh,
+                self._fit_spec(spec if isinstance(spec, P) else P(), leaf.shape),
             )
             for leaf, spec in zip(leaves, spec_leaves)
         ]
-        return jax.tree_util.tree_unflatten(treedef, placed)
+        return values, jax.tree_util.tree_unflatten(treedef, shardings)
+
+    def shard_params(self, params):
+        """Place a params pytree per :meth:`param_shardings` (boxed trees
+        are unboxed — the metadata has done its job once the placement is
+        committed)."""
+        return jax.device_put(*self.param_shardings(params))
 
     # --- KV / state ---------------------------------------------------------
 
@@ -227,20 +229,25 @@ class ServingPartitioner:
             return P(*spec)
         return P()
 
-    def place_kv(self, tree):
-        """Commit a cache collection (row layout or paged pool pytree) to
-        the mesh: kv-head-axis sharding where it divides, replicated
-        elsewhere. Applied once at allocation — the donated programs then
-        keep the layout for free."""
+    def kv_shardings(self, tree):
+        """NamedSharding tree for a cache collection (row layout or paged
+        pool pytree): kv-head-axis sharding where it divides, replicated
+        elsewhere. Needs only shapes."""
         from neuronx_distributed_tpu.modules.attention import cache_leaf_name
 
-        def put(path, leaf):
+        def sharding(path, leaf):
             spec = self._fit_spec(
                 self.kv_spec(cache_leaf_name(path), leaf.ndim), leaf.shape
             )
-            return jax.device_put(leaf, NamedSharding(self.mesh, spec))
+            return NamedSharding(self.mesh, spec)
 
-        return jax.tree_util.tree_map_with_path(put, tree)
+        return jax.tree_util.tree_map_with_path(sharding, tree)
+
+    def place_kv(self, tree):
+        """Commit a cache collection to the mesh per :meth:`kv_shardings`.
+        Applied once at allocation — the donated programs then keep the
+        layout for free."""
+        return jax.device_put(tree, self.kv_shardings(tree))
 
     def replicate(self, tree):
         """Commit a pytree fully replicated over the mesh (slot state,

@@ -146,7 +146,7 @@ class PipelineEngine:
         stage_fn = self._make_stage_fn(layer_apply)
 
         def pipelined(layers_local, embed_params, batch):
-            rank = mesh_lib.compat_axis_index(mesh_lib.PP_AXIS)
+            rank = jax.lax.axis_index(mesh_lib.PP_AXIS)
             layers_local = jax.tree.map(lambda a: a[0], layers_local)  # drop stage dim
             # Embed all M microbatches once, OUTSIDE the tick loop: the loop
             # otherwise pays M+S-1 embedding fwd (and bwd) passes per stage for
@@ -235,7 +235,7 @@ class PipelineEngine:
 @dataclasses.dataclass
 class OneFOneBEngine(PipelineEngine):
     """Explicitly-scheduled synchronous 1F1B runtime, with interleaved
-    (virtual-pipeline) chunks at ``num_chunks > 1`` (VERDICT.md missing #2/#6;
+    (virtual-pipeline) chunks at ``num_chunks > 1`` (review round 3, missing #2/#6;
     reference ``pipeline/model.py:1737`` ``_exec_schedule`` over
     ``Train1F1BSchedule`` / ``TrainInterleavedSchedule``, virtual chunks via
     ``get_current_stage`` model.py:1053).
@@ -398,7 +398,7 @@ class OneFOneBEngine(PipelineEngine):
         )
 
         def pipelined(layers_local, head_params, embedded, batch):
-            rank = mesh_lib.compat_axis_index(mesh_lib.PP_AXIS)
+            rank = jax.lax.axis_index(mesh_lib.PP_AXIS)
             layers_local = jax.tree.map(lambda a: a[:, 0], layers_local)  # (C, Lc, ...)
             is_last = rank == S - 1
             is_first = rank == 0
@@ -612,7 +612,7 @@ class OneFOneBEngine(PipelineEngine):
         stage_fn = self._make_stage_fn(self.layer_apply)
 
         def pipelined(layers_local, embedded):
-            rank = mesh_lib.compat_axis_index(mesh_lib.PP_AXIS)
+            rank = jax.lax.axis_index(mesh_lib.PP_AXIS)
             layers_local = jax.tree.map(lambda a: a[:, 0], layers_local)
             is_last = rank == S - 1
             is_first = rank == 0

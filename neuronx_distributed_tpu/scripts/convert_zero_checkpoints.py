@@ -6,7 +6,7 @@ degree, :55-179).
 
 The reference needs this tool because its checkpoints are per-rank shard
 files whose layout bakes in the DP degree. This framework's checkpoints are
-GLOBAL logical arrays (orbax/tensorstore): any (dp, tp, pp, ep) relayout
+GLOBAL logical arrays (orbax/tensorstore): any (dp, tp, pp, ep) re-layout
 happens at load time by restoring against ``NamedSharding`` targets
 (``trainer.checkpoint.load_checkpoint(items_target=...)``), so the
 merge/re-shard operations are identity transforms by construction. What

@@ -10,9 +10,9 @@ it):
   modules whose sync counts are performance contracts (PR 2/PR 5).
 * **GL03 recompile-hazard** — uncommitted long-lived scalars, module-level
   jit objects, mutable closure capture under jit (PR 4/PR 5).
-* **GL04 compat-layer-bypass** — raw ``shard_map``/``axis_index``/
-  ``get_abstract_mesh`` outside ``parallel/mesh.py`` (hard-SIGABRTs old
-  XLA; PR 5).
+* **GL04 explicit-SPMD seam bypass** — raw ``shard_map``/
+  ``get_abstract_mesh`` outside ``parallel/mesh.py`` (the one seam that
+  carries ``check_vma``/``axis_names`` and nested-region handling; PR 5).
 * **GL05 nondeterminism** — unseeded/wall-clock RNG in library code
   (breaks bit-identical chaos/resume; PR 3/PR 5).
 * **GL06 sharding-spec drift** — trailing-``None`` ``PartitionSpec``s at

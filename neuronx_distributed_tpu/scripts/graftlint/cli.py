@@ -24,7 +24,7 @@ def _build_parser() -> argparse.ArgumentParser:
         prog="graftlint",
         description=(
             "Repo-native static analysis enforcing the donation, host-sync, "
-            "recompile, compat-layer, determinism, sharding-spec, "
+            "recompile, explicit-SPMD seam, determinism, sharding-spec, "
             "trace-scope, hold-pairing and metrics-label invariants the hot "
             "paths depend on (rules GL01-GL09; see --explain RULE)."
         ),

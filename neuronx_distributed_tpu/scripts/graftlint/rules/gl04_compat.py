@@ -1,4 +1,4 @@
-"""GL04 — compat-layer bypass."""
+"""GL04 — explicit-SPMD seam bypass."""
 
 from __future__ import annotations
 
@@ -9,25 +9,26 @@ from neuronx_distributed_tpu.scripts.graftlint.analysis import AliasMap
 from neuronx_distributed_tpu.scripts.graftlint.core import SourceFile, Violation
 
 RULE = "GL04"
-TITLE = "compat-layer bypass"
+TITLE = "explicit-SPMD seam bypass"
 
 EXPLAIN = """\
-GL04 compat-layer bypass
+GL04 explicit-SPMD seam bypass
 
-Incident: PR 5's jax<0.5 compat layer exists because this container's XLA
-hard-SIGABRTs (not a catchable error — the process dies) on the lowering of
-raw `jax.experimental.shard_map` partial-manual regions and on the
-PartitionId op `lax.axis_index` emits there, and old jax lacks
-`jax.sharding.get_abstract_mesh` entirely. Every explicit-SPMD entry point
-must therefore route through parallel/mesh.py:
+Every drop from GSPMD into an explicit-SPMD region goes through ONE seam,
+parallel/mesh.py:
 
     jax.(experimental.)shard_map   -> mesh.compat_shard_map / manual_shard_map
-    lax.axis_index                 -> mesh.compat_axis_index
     jax.sharding.get_abstract_mesh -> mesh.ctx_abstract_mesh
 
-A raw call works on the code path a test happens to take and SIGABRTs the
-whole run on another — which is why this is a lint rule, not a code review
-note. parallel/mesh.py itself is the one exempt module (it IS the layer).
+The seam carries what a raw call gets wrong one code path at a time:
+`check_vma` off for the regions' collective-reduction outputs, the claimed
+`axis_names`, and — in manual_shard_map — the context's AbstractMesh with
+only the not-yet-manual axes when a region nests inside another (a Pallas
+kernel under the pipeline engine's pp region). A raw call works on the path
+a test happens to take and fails to lower on another, which is why this is
+a lint rule, not a code review note. parallel/mesh.py itself is the one
+exempt module (it IS the seam). `lax.axis_index` is plain JAX and is not
+policed.
 """
 
 _EXEMPT_SUFFIX = "parallel/mesh.py"
@@ -37,7 +38,6 @@ _BANNED_PATHS = {
     "jax.shard_map": "use mesh.compat_shard_map (or mesh.manual_shard_map)",
     "jax.experimental.shard_map": "use mesh.compat_shard_map",
     "jax.experimental.shard_map.shard_map": "use mesh.compat_shard_map",
-    "jax.lax.axis_index": "use mesh.compat_axis_index",
     "jax.sharding.get_abstract_mesh": "use mesh.ctx_abstract_mesh",
 }
 
@@ -51,7 +51,7 @@ def check(src: SourceFile) -> List[Violation]:
     def flag(node: ast.AST, what: str, fix: str) -> None:
         out.append(src.violation(
             RULE, node,
-            f"raw {what} bypasses the jax<0.5 compat layer — {fix} "
+            f"raw {what} bypasses the explicit-SPMD seam — {fix} "
             "(parallel/mesh.py)",
         ))
 

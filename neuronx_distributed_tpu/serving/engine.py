@@ -233,9 +233,11 @@ from neuronx_distributed_tpu.inference.spec_decode import (
     speculative_decode_chunk,
 )
 from neuronx_distributed_tpu.inference.utils import unwrap_logits
+from neuronx_distributed_tpu.kernels import backend
 from neuronx_distributed_tpu.modules.attention import (
     cache_fingerprint,
     extract_cache_prefix,
+    resolve_decode_impl,
     seed_cache_prefix,
 )
 from neuronx_distributed_tpu.observability.flight_recorder import FlightRecorder
@@ -594,10 +596,12 @@ class ServingEngine:
             )
         # fused paged attention (ISSUE 14, the PR 12 leftover): "fused"
         # streams K/V straight from the physical pool pages through
-        # paged_flash_decode_attention's scalar-prefetch block table on
-        # TPU; its gather fallback keeps every other backend bit-identical
-        # to the "gather" transport. "auto" = fused exactly where the
-        # kernel is real (TPU, plain chunk, float pool), gather elsewhere.
+        # paged_flash_decode_attention's scalar-prefetch block table. It IS
+        # the kernel wherever it is asked for — off the TPU it only runs
+        # interpreted, in tests — and never degrades to the gather
+        # transport. "auto" = fused exactly where the kernel compiles (TPU,
+        # plain chunk, float pool), gather elsewhere; what it resolved to
+        # is recorded in ``programs.resolved`` below.
         if paged_attention not in ("auto", "gather", "fused"):
             raise ValueError(
                 f"unknown paged_attention {paged_attention!r} "
@@ -613,9 +617,7 @@ class ServingEngine:
         )
         if paged_attention == "auto":
             paged_attention = (
-                "fused"
-                if _fusable and jax.devices()[0].platform == "tpu"
-                else "gather"
+                "fused" if _fusable and backend.on_tpu() else "gather"
             )
         elif paged_attention == "fused" and not _fusable:
             raise ValueError(
@@ -805,6 +807,20 @@ class ServingEngine:
         self.cache.register_programs(self.programs)
         if self.draft_cache is not None:
             self.draft_cache.register_programs(self.programs, prefix="draft_")
+        # what every platform-dependent "auto" resolved to on THIS device —
+        # the snapshot a run asserts its kernels from (chip_smoke.py)
+        self.programs.resolved.update(
+            attention=backend.resolve_attention_impl(
+                getattr(model, "attention_impl", "auto")
+            ),
+            decode_attention=(
+                "paged_fused" if self.paged_attention == "fused"
+                else resolve_decode_impl(max_seq_len)
+            ),
+            paged_attention=(
+                self.paged_attention if kv_page_size is not None else "none"
+            ),
+        )
         self._profile_dir = profile_dir
         self._profiling = False
         # host-side slot bookkeeping (scheduling only — the decode-visible
@@ -895,7 +911,7 @@ class ServingEngine:
             "suffix_prefill",
             self._comms_scoped(jax.jit(suffix_prefill_step(self._decode_model))),
         )
-        # per-engine lambda wrappers: in this jax (0.4.37), _cache_size()
+        # per-engine lambda wrappers: _cache_size()
         # is SHARED between jax.jit wrappers of the same function object
         # (two jax.jit(f) both read 1 after either is called — verified),
         # so jitting the module-level helpers directly would cross-pollute

@@ -1024,6 +1024,17 @@ class Trainer:
                 timeline=tl,
             )
         self.programs = self.program_ledger
+        impl = getattr(self.model, "attention_impl", None)
+        if impl is not None:
+            # what "auto" resolved to on THIS device and mesh — the
+            # snapshot a run asserts its kernels from (chip_smoke.py)
+            from neuronx_distributed_tpu.kernels.backend import (
+                resolve_attention_impl,
+            )
+
+            self.programs.resolved["attention"] = resolve_attention_impl(
+                impl, mesh_lib.get_context_parallel_size()
+            )
         inj = self.fault_injector
         first = sample_batch if sample_batch is not None else next(data_iter)
         optimizer = make_optimizer(self.optimizer_config)
@@ -1034,14 +1045,6 @@ class Trainer:
                 "num_microbatches instead"
             )
         guard_cfg = self.anomaly_guard if self.pipeline is None else None
-        if self.pipeline is not None and not hasattr(jax, "shard_map"):
-            # fail fast with the compat gate's message instead of burning
-            # dispatch retries on a deterministic trace-time error
-            raise RuntimeError(
-                "pipeline parallelism requires jax >= 0.5 (this jax's "
-                "partial-manual CollectivePermute lowering crashes XLA); "
-                "run with pp=1 on this installation"
-            )
         if self.pipeline is not None:
             self.state, train_step, engine = self.pipeline.build_state_and_step(
                 self.model, optimizer, rng_key, first["input_ids"],
@@ -1218,14 +1221,6 @@ class Trainer:
                     # (ISSUE 20): never donate restored garbage
                     on_corrupt=self._on_checkpoint_corrupt,
                 )
-                if not hasattr(jax, "shard_map"):
-                    # jax < 0.5 only: a persistent-cache-deserialized CPU
-                    # executable corrupts the heap when it DONATES buffers
-                    # that tensorstore materialized (reproduced: resume +
-                    # warm compilation cache + first dispatch). Re-own the
-                    # restored trees in fresh XLA buffers — jnp.copy is
-                    # bit-exact, so resume stays bit-identical.
-                    items = jax.tree.map(jnp.copy, items)
                 self.state = self.state.replace(
                     params=items["model"], opt_state=items["optimizer"]
                 )

@@ -124,7 +124,7 @@ def test_tree_fingerprint_64bit_folds_high_and_low():
     (dropped by a naive truncation) changes the fingerprint."""
     import jax.experimental
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         base = np.arange(4, dtype=np.int64)
         high = base.copy()
         high[0] ^= 1 << 40

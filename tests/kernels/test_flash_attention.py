@@ -1,5 +1,7 @@
-"""Flash attention kernel vs XLA einsum golden (interpret mode on CPU;
-the same kernels compile on TPU — exercised by bench.py)."""
+"""Flash attention kernel vs XLA einsum golden, interpreted on the CPU through
+conftest's session switch (kernels/backend.py). That the same kernels COMPILE
+for the chip is tests/kernels/test_tpu_compile.py's question; that they run
+there is chip_smoke.py's."""
 
 import jax
 import jax.numpy as jnp

@@ -212,22 +212,34 @@ def test_paged_kernel_null_pages_never_attend():
     assert np.array_equal(np.asarray(out), np.asarray(out2))
 
 
-def test_paged_kernel_non_tpu_fallback_is_gather_path():
-    """interpret=None off-TPU routes through the gather fallback (the
-    serving chunk's exact transport) — same numbers as the explicit
-    gather + einsum golden."""
+def test_paged_kernel_never_becomes_the_gather_path(monkeypatch):
+    """Off the TPU, and without the tests' explicit interpretation request,
+    the fused entry FAILS to lower — it never swaps itself for the gather
+    reference (which used to make ``paged_attention="fused"`` a silent
+    no-op on every backend but one)."""
+    from neuronx_distributed_tpu.kernels import backend
     from neuronx_distributed_tpu.kernels.flash_decode import (
         paged_flash_decode_attention,
-        paged_gather_leaf,
     )
 
     ps = 16
     q, kp, vp, bt, valid, pos = _paged_setup(jax.random.PRNGKey(3))
+    monkeypatch.setattr(backend, "INTERPRET", False)
+    with pytest.raises(ValueError, match="interpret mode"):
+        paged_flash_decode_attention(q, kp, vp, bt, pos, valid, page_size=ps)
+    # the explicit argument still interprets (what every kernel test asks)
     out = paged_flash_decode_attention(
-        q, kp, vp, bt, pos, valid, page_size=ps
+        q, kp, vp, bt, pos, valid, page_size=ps, interpret=True
     )
-    ref = decode_attention(
-        q, paged_gather_leaf(kp, bt, ps), paged_gather_leaf(vp, bt, ps),
-        pos, kv_valid=valid,
-    )
-    assert np.array_equal(np.asarray(out), np.asarray(ref))
+    assert np.isfinite(np.asarray(out)).all()
+
+
+def test_row_cache_kernel_raises_uninterpreted_off_tpu(monkeypatch):
+    from neuronx_distributed_tpu.kernels import backend
+
+    q, kc, vc, pos = _setup(jax.random.PRNGKey(4), 1, 4, 4, idx=100)
+    monkeypatch.setattr(backend, "INTERPRET", False)
+    with pytest.raises(ValueError, match="interpret mode"):
+        flash_decode_attention(q, kc, vc, pos)
+
+

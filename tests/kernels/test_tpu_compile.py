@@ -1,0 +1,341 @@
+"""The chip's compiler, asked without the chip (on-chip-measurement guide §2.3).
+
+libtpu compiles for a DESCRIBED v5e topology from this CPU process, so what
+Mosaic refuses — a block it cannot tile, a kernel it cannot partition, a
+program that does not fit the HBM — fails here, at no chip time. Interpret
+mode cannot see any of that: every kernel below had passed its interpret-mode
+tests while three of the four families were refused at Llama-2-7B geometry.
+
+The kernel compiles are tier-1 (a second or two each). The whole-program
+compiles — the train step, the engine's decode chunk and a prefill bucket,
+on one described chip and on the 2x2 mesh — are ``slow``: they are the
+rehearsal to run before a chip call is spent, not part of every test run.
+
+Nothing runs, so nothing here says anything about results or speed.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from neuronx_distributed_tpu.kernels import backend
+from neuronx_distributed_tpu.kernels.flash_attention import flash_attention
+from neuronx_distributed_tpu.kernels.flash_decode import (
+    flash_decode_attention,
+    paged_flash_decode_attention,
+)
+from neuronx_distributed_tpu.models.llama import LlamaForCausalLM, llama2_7b
+from neuronx_distributed_tpu.parallel import mesh as mesh_lib
+
+# Llama-2-7B head geometry (models/llama.py llama2_7b) and its GQA(8) sibling
+H, D = 32, 128
+KERNEL = "tpu_custom_call"
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """The described (not attached) v5e 2x2 topology; skip where libtpu
+    cannot describe one."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no libtpu / no such topology on this install
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+
+
+@pytest.fixture(autouse=True)
+def _compile_for_the_chip(monkeypatch):
+    """Kernels uninterpreted, platform choices taken as on the TPU, and the
+    persistent cache off (a described-device executable is written to it but
+    cannot be read back without a chip — every later run would warn)."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(backend, "INTERPRET", False)
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _one_chip(topo):
+    sharding = SingleDeviceSharding(topo.devices[0])
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    return spec
+
+
+def _compiled_text(fn, *args):
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+# --- the kernels alone ---------------------------------------------------------
+
+
+@pytest.mark.parametrize("hkv", [32, 8], ids=["mha", "gqa8"])
+def test_flash_fwd_bwd_compiles(topo, hkv):
+    s = _one_chip(topo)
+    q, kv = s((4, 2048, H, D)), s((4, 2048, hkv, D))
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    assert KERNEL in _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+
+
+def test_flash_with_segment_ids_compiles(topo):
+    """Packed documents (``--data packed:``) and every padded prefill."""
+    s = _one_chip(topo)
+    q, seg = s((4, 2048, H, D)), s((4, 2048), jnp.int32)
+
+    def loss(q, k, v, seg):
+        out = flash_attention(q, k, v, segment_ids=seg)
+        return out.astype(jnp.float32).sum()
+
+    assert KERNEL in _compiled_text(
+        jax.grad(loss, argnums=(0, 1, 2)), q, q, q, seg
+    )
+
+
+@pytest.mark.parametrize("hkv", [32, 8], ids=["mha", "gqa8"])
+def test_flash_decode_with_kv_valid_compiles(topo, hkv):
+    """The row-cache decode path at context >= FLASH_DECODE_MIN_CONTEXT —
+    always called with ``kv_valid`` by the engine."""
+    s = _one_chip(topo)
+    q, cache = s((8, 1, H, D)), s((8, 4096, hkv, D))
+    pos, valid = s((1,), jnp.int32), s((8, 4096), jnp.bool_)
+    assert KERNEL in _compiled_text(
+        flash_decode_attention, q, cache, cache, pos, valid
+    )
+
+
+@pytest.mark.parametrize("hkv", [32, 8], ids=["mha", "gqa8"])
+def test_paged_decode_compiles(topo, hkv):
+    """The engine's default decode transport on a TPU: page 16."""
+    s = _one_chip(topo)
+    q, pool = s((8, 1, H, D)), s((2048, 16, hkv, D))
+    table, pos = s((8, 256), jnp.int32), s((1,), jnp.int32)
+    valid = s((8, 4096), jnp.bool_)
+
+    def fn(q, k, v, table, pos, valid):
+        return paged_flash_decode_attention(
+            q, k, v, table, pos, valid, page_size=16
+        )
+
+    assert KERNEL in _compiled_text(fn, q, pool, pool, table, pos, valid)
+
+
+def test_mixtral_width_blockwise_moe_compiles(topo):
+    """One Mixtral-8x7B-width expert layer forward on the dropless
+    blockwise path: ``jax.lax.ragged_dot``, which the TPU compiler turns
+    into its own Mosaic grouped-matmul kernel."""
+    from neuronx_distributed_tpu.modules.moe.expert_mlps import ExpertMLPs
+
+    s = _one_chip(topo)
+    tokens, experts, k = 4096, 8, 2
+    layer = ExpertMLPs(
+        num_experts=experts, hidden_size=4096, intermediate_size=14336,
+        top_k=k, strategy="blockwise", dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16,
+    )
+    x = s((tokens, 4096))
+    top_e, top_w = s((tokens, k), jnp.int32), s((tokens, k))
+    params = jax.tree.map(
+        lambda a: s(a.shape, a.dtype),
+        jax.eval_shape(
+            layer.init, jax.random.PRNGKey(0),
+            *(jax.ShapeDtypeStruct(a.shape, a.dtype) for a in (x, top_e, top_w)),
+        ),
+    )
+    assert KERNEL in _compiled_text(layer.apply, params, x, top_e, top_w)
+
+
+# --- whole programs (slow: the rehearsal before a chip call) -------------------
+
+
+def _abstract(tree, shardings):
+    return jax.tree.map(
+        lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+        tree, shardings,
+    )
+
+
+def _train_step_compiled(topo, n_devices, *, tp, sp, layers=2, batch=4,
+                         seq=2048):
+    """The Trainer's own step (``build_train_step`` with the anomaly guard,
+    as ``Trainer.fit`` builds it) over abstract state on described devices."""
+    from flax.core import meta
+
+    from neuronx_distributed_tpu.optim.zero1 import zero1_shardings_for_opt_state
+    from neuronx_distributed_tpu.parallel.sharding import param_shardings
+    from neuronx_distributed_tpu.trainer import OptimizerConfig, make_optimizer
+    from neuronx_distributed_tpu.trainer.trainer import (
+        AnomalyGuardConfig,
+        TrainState,
+        build_train_step,
+    )
+
+    mesh_lib.initialize_model_parallel(
+        tensor_model_parallel_size=tp, devices=topo.devices[:n_devices]
+    )
+    mesh = mesh_lib.get_mesh()
+    cfg = llama2_7b(num_layers=layers, max_seq_len=seq, sequence_parallel=sp)
+    model = LlamaForCausalLM(cfg, attention_impl="auto")
+    ids = jax.ShapeDtypeStruct((batch, seq), jnp.int32)
+    boxed = jax.eval_shape(model.init, jax.random.PRNGKey(0), ids)
+    p_sh = param_shardings(boxed)
+    params = _abstract(meta.unbox(boxed), p_sh)
+    opt_cfg = OptimizerConfig()
+    optimizer = make_optimizer(opt_cfg)
+    opt_shapes = jax.eval_shape(optimizer.init, params)
+    s_sh = zero1_shardings_for_opt_state(
+        opt_shapes, params, jax.tree.map(lambda s: s.spec, p_sh), mesh=mesh,
+        enabled=opt_cfg.zero1,
+    )
+    repl = NamedSharding(mesh, P())
+    scalar = lambda dt: jax.ShapeDtypeStruct((), dt, sharding=repl)  # noqa: E731
+    state = TrainState(
+        step=scalar(jnp.int32), params=params,
+        opt_state=_abstract(opt_shapes, s_sh),
+        guard={"gnorm_ema": scalar(jnp.float32),
+               "good_steps": scalar(jnp.int32), "skips": scalar(jnp.int32)},
+    )
+    data = NamedSharding(mesh, P(mesh_lib.DATA_AXES, mesh_lib.CP_AXIS))
+    tok = jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=data)
+    step = build_train_step(
+        model, optimizer, p_sh, s_sh,
+        max_grad_norm=opt_cfg.max_grad_norm,
+        anomaly_guard=AnomalyGuardConfig(),
+    )
+    batch_abs = {"input_ids": tok, "labels": tok,
+                 "loss_mask": jax.ShapeDtypeStruct(
+                     (batch, seq), jnp.float32, sharding=data)}
+    return step.lower(state, batch_abs).compile()
+
+
+def _engine_programs(topo, n_devices, *, layers=8, slots=8, seq=4096,
+                     page=16, bucket=512):
+    """``(engine, lower_decode, lower_prefill)`` for a default-configured
+    engine over abstract params on described devices. With ``n_devices`` > 1
+    the global tp mesh is up and every operand carries the placement the TP
+    engine's partitioner would commit — the engine's programs take their
+    sharding from their operands, so this IS the tp program."""
+    from neuronx_distributed_tpu.parallel.sharding import (
+        ServingPartitioner,
+        serving_mesh,
+    )
+    from neuronx_distributed_tpu.serving import ServingEngine
+    from neuronx_distributed_tpu.serving.paging import PagedCacheManager
+
+    cfg = llama2_7b(
+        num_layers=layers, max_seq_len=seq, scan_layers=False, remat=False,
+        param_dtype=jnp.bfloat16,
+    )
+    model = LlamaForCausalLM(cfg, attention_impl="auto")
+    boxed = jax.eval_shape(
+        model.init, jax.random.PRNGKey(0),
+        jax.ShapeDtypeStruct((1, 8), jnp.int32),
+    )
+    if n_devices > 1:
+        part = ServingPartitioner(
+            serving_mesh(n_devices, devices=topo.devices[:n_devices])
+        )
+        values, p_sh = part.param_shardings(boxed)
+        params = _abstract(values, p_sh)
+        kv_shardings = part.kv_shardings
+        repl = NamedSharding(part.mesh, P())
+    else:
+        from flax.core import meta
+
+        repl = SingleDeviceSharding(topo.devices[0])
+        params = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=repl),
+            meta.unbox(boxed),
+        )
+        kv_shardings = lambda tree: jax.tree.map(lambda _: repl, tree)  # noqa: E731
+    engine = ServingEngine(
+        model, params, num_slots=slots, kv_page_size=page,
+    )
+    put = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=repl)  # noqa: E731
+    prefill = engine._prefill_fn(bucket)
+    ids = jax.ShapeDtypeStruct((1, bucket), jnp.int32, sharding=repl)
+    mask = jax.ShapeDtypeStruct((1, bucket), jnp.bool_, sharding=repl)
+
+    def lower_prefill():
+        return prefill.lower(params, ids, mask)
+
+    row = jax.eval_shape(lambda p, i, m: prefill(p, i, m)[1], params, ids, mask)
+
+    def pool_of(row):
+        mgr = PagedCacheManager(slots, seq, page)
+        mgr.allocate_from(row)
+        return mgr.cache
+
+    paged = jax.eval_shape(pool_of, row)
+    paged = _abstract(paged, kv_shardings(paged))
+    state = jax.tree.map(put, jax.eval_shape(engine._fresh_slot_state))
+
+    def lower_decode():
+        return engine._decode_chunk.lower(params, paged, state)
+
+    return engine, lower_decode, lower_prefill
+
+
+def _fits(compiled, hbm_bytes=16 * 1024**3):
+    m = compiled.memory_analysis()
+    live = (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert live < hbm_bytes, f"program needs {live / 2**30:.1f} GiB per device"
+    return live
+
+
+@pytest.mark.slow
+def test_train_step_compiles_at_7b_widths(topo):
+    compiled = _train_step_compiled(topo, 1, tp=1, sp=False)
+    assert KERNEL in compiled.as_text()
+    _fits(compiled)
+
+
+@pytest.mark.slow
+def test_engine_programs_compile_at_7b_widths(topo):
+    engine, lower_decode, lower_prefill = _engine_programs(topo, 1)
+    assert engine.programs.resolved == {
+        "attention": "flash", "decode_attention": "paged_fused",
+        "paged_attention": "fused",
+    }
+    for lower in (lower_decode, lower_prefill):
+        compiled = lower().compile()
+        assert KERNEL in compiled.as_text()
+        _fits(compiled)
+
+
+@pytest.mark.slow
+def test_tp4_train_step_compiles_and_is_split_four_ways(topo):
+    one = _fits(_train_step_compiled(topo, 1, tp=1, sp=False))
+    mesh_lib.destroy_model_parallel()
+    compiled = _train_step_compiled(topo, 4, tp=4, sp=True)
+    text = compiled.as_text()
+    assert KERNEL in text and "all-reduce" in text
+    # per-device bytes: a 4-way split state is well under half the solo one
+    assert _fits(compiled) < one / 2
+
+
+@pytest.mark.slow
+def test_tp4_engine_programs_compile(topo):
+    _, lower_decode, lower_prefill = _engine_programs(topo, 4)
+    for lower in (lower_decode, lower_prefill):
+        compiled = lower().compile()
+        assert KERNEL in compiled.as_text()
+        _fits(compiled)

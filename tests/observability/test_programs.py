@@ -1,6 +1,5 @@
 """Device-efficiency observability (ISSUE 12): the compiled-program ledger,
-HBM accounting, and their graceful degradation on this container (CPU,
-jax 0.4.37).
+HBM accounting, and their graceful degradation on the CPU backend.
 
 Pins, in order of load-bearing-ness:
 
@@ -73,9 +72,7 @@ def test_cost_analysis_schema_on_this_container():
 
 def test_memory_analysis_opt_in_pins_container_gaps():
     """memory_analysis=True pays one AOT compile per signature and gets
-    real argument/output/temp/alias bytes on this CPU; peak_bytes is
-    UNAVAILABLE here (this jaxlib's CompiledMemoryStats has no peak) —
-    the per-field degradation, pinned."""
+    real argument/output/temp/alias/peak bytes on this CPU."""
     led = ProgramLedger(memory_analysis=True)
     f = led.wrap("mm", jax.jit(lambda x: jnp.tanh(x @ x)))
     f(jnp.ones((32, 32)))
@@ -84,7 +81,7 @@ def test_memory_analysis_opt_in_pins_container_gaps():
     assert isinstance(mem["output_bytes"], int) and mem["output_bytes"] > 0
     assert isinstance(mem["temp_bytes"], int)
     assert isinstance(mem["alias_bytes"], int)
-    assert mem["peak_bytes"] == UNAVAILABLE
+    assert isinstance(mem["peak_bytes"], int) and mem["peak_bytes"] > 0
 
 
 def test_recompile_accumulates_never_double_counts():
@@ -381,7 +378,7 @@ def test_model_builder_trace_records_aot_programs():
     assert isinstance(e["flops_per_dispatch"], float)
     # memory analysis rode the already-compiled executable for free
     assert isinstance(e["memory"]["argument_bytes"], int)
-    assert e["memory"]["peak_bytes"] == UNAVAILABLE  # no peak on this jaxlib
+    assert isinstance(e["memory"]["peak_bytes"], int)
 
 
 def test_trainer_ledger_and_halt_extras(tmp_path):

@@ -596,7 +596,9 @@ def test_gl04_raw_shard_map_import(tmp_path):
     assert "GL04" in rules_of(v)
 
 
-def test_gl04_raw_axis_index(tmp_path):
+def test_gl04_raw_axis_index_is_plain_jax(tmp_path):
+    # lax.axis_index needed a wrapper only on jax < 0.5; with that support
+    # gone the rule polices the shard_map seam and nothing else
     v = lint(tmp_path, """\
         from jax import lax
 
@@ -604,7 +606,7 @@ def test_gl04_raw_axis_index(tmp_path):
             rank = lax.axis_index(axis_name)
             return x + rank
     """)
-    assert "GL04" in rules_of(v)
+    assert "GL04" not in rules_of(v)
 
 
 def test_gl04_get_abstract_mesh(tmp_path):
@@ -629,8 +631,8 @@ def test_gl04_mesh_module_exempt_and_compat_clean(tmp_path):
     v = lint(tmp_path, """\
         from neuronx_distributed_tpu.parallel import mesh as mesh_lib
 
-        def ring_step(x, axis_name):
-            return x + mesh_lib.compat_axis_index(axis_name)
+        def region(fn, mesh, specs):
+            return mesh_lib.compat_shard_map(fn, mesh, specs, specs)
     """)
     assert [x for x in v if x.rule == "GL04"] == []
 
@@ -674,10 +676,10 @@ def test_gl05_seeded_rng_clean(tmp_path):
 
 def test_pragma_suppresses_with_reason(tmp_path):
     v = lint(tmp_path, """\
-        from jax import lax
+        import jax
 
-        def f(x, axis):
-            return x + lax.axis_index(axis)  # graftlint: ok[GL04] fixture: compat verified by hand
+        def f():
+            return jax.sharding.get_abstract_mesh()  # graftlint: ok[GL04] fixture: seam verified by hand
     """)
     assert v == []
 
@@ -700,10 +702,10 @@ def test_pragma_own_line_covers_multiline_statement(tmp_path):
 
 def test_pragma_missing_reason_is_gl00_and_does_not_suppress(tmp_path):
     v = lint(tmp_path, """\
-        from jax import lax
+        import jax
 
-        def f(x, axis):
-            return x + lax.axis_index(axis)  # graftlint: ok[GL04]
+        def f():
+            return jax.sharding.get_abstract_mesh()  # graftlint: ok[GL04]
     """)
     assert "GL00" in rules_of(v)
     assert "GL04" in rules_of(v)  # the naked pragma suppresses nothing
@@ -711,10 +713,10 @@ def test_pragma_missing_reason_is_gl00_and_does_not_suppress(tmp_path):
 
 def test_pragma_wrong_rule_does_not_suppress(tmp_path):
     v = lint(tmp_path, """\
-        from jax import lax
+        import jax
 
-        def f(x, axis):
-            return x + lax.axis_index(axis)  # graftlint: ok[GL05] wrong rule id
+        def f():
+            return jax.sharding.get_abstract_mesh()  # graftlint: ok[GL05] wrong rule id
     """)
     assert "GL04" in rules_of(v)
 
@@ -729,13 +731,13 @@ def _write(tmp_path, code):
 
 
 BAD_TWO = textwrap.dedent("""\
-    from jax import lax
+    import jax
 
     def f(x, a):
-        return x + lax.axis_index(a)
+        return x + jax.sharding.get_abstract_mesh()
 
     def g(x, a):
-        return x - lax.axis_index(a)
+        return x - jax.sharding.get_abstract_mesh()
 """)
 
 
@@ -754,14 +756,14 @@ def test_baseline_ratchet(tmp_path):
     assert len(rep.diff.grandfathered) == 2 and rep.diff.new == []
 
     # 3. a NEW violation fails even though the old two are baselined
-    _write(tmp_path, BAD_TWO + "\n\ndef h(x, a):\n    return lax.axis_index(a)\n")
+    _write(tmp_path, BAD_TWO + "\n\ndef h(x, a):\n    return jax.sharding.get_abstract_mesh()\n")
     rep = runner.run([str(f)], root=str(tmp_path), baseline_path=bl)
     assert rep.failed and len(rep.diff.new) == 1
     assert len(rep.diff.grandfathered) == 2
 
     # 4. fixing a violation leaves a STALE entry — the run fails until the
     #    baseline is regenerated (the ratchet can only shrink explicitly)
-    _write(tmp_path, BAD_TWO.replace("x - lax.axis_index(a)", "x - 1"))
+    _write(tmp_path, BAD_TWO.replace("x - jax.sharding.get_abstract_mesh()", "x - 1"))
     rep = runner.run([str(f)], root=str(tmp_path), baseline_path=bl)
     assert rep.failed
     assert len(rep.diff.stale) == 1 and rep.diff.new == []
@@ -936,7 +938,7 @@ def _cli(args, capsys):
 
 def test_cli_report_format_and_exit_codes(tmp_path, capsys):
     bad = tmp_path / "bad.py"
-    bad.write_text("from jax import lax\n\ndef f(a):\n    return lax.axis_index(a)\n")
+    bad.write_text("import jax\n\ndef f(a):\n    return jax.sharding.get_abstract_mesh()\n")
     rc, out, _ = _cli([str(bad), "--no-baseline"], capsys)
     assert rc == 1
     # clickable path:line:col convention
@@ -958,7 +960,7 @@ def test_cli_report_format_and_exit_codes(tmp_path, capsys):
 
 def test_cli_write_baseline_roundtrip(tmp_path, capsys):
     bad = tmp_path / "bad.py"
-    bad.write_text("from jax import lax\n\ndef f(a):\n    return lax.axis_index(a)\n")
+    bad.write_text("import jax\n\ndef f(a):\n    return jax.sharding.get_abstract_mesh()\n")
     bl = tmp_path / "bl.json"
     rc, _, _ = _cli([str(bad), "--baseline", str(bl), "--write-baseline"], capsys)
     assert rc == 0 and bl.exists()
@@ -974,7 +976,7 @@ def test_write_baseline_partial_scope_preserves_out_of_scope_debt(tmp_path):
     b_dir = tmp_path / "b"
     a_dir.mkdir()
     b_dir.mkdir()
-    bad = "from jax import lax\n\ndef f(x):\n    return lax.axis_index(x)\n"
+    bad = "import jax\n\ndef f(x):\n    return jax.sharding.get_abstract_mesh()\n"
     (a_dir / "mod_a.py").write_text(bad)
     (b_dir / "mod_b.py").write_text(bad)
     bl = str(tmp_path / "bl.json")
@@ -1012,7 +1014,7 @@ def test_python_dash_m_entry_point(tmp_path):
     """The documented invocation — `python -m
     neuronx_distributed_tpu.scripts.graftlint` — works end to end."""
     bad = tmp_path / "bad.py"
-    bad.write_text("from jax import lax\n\ndef f(a):\n    return lax.axis_index(a)\n")
+    bad.write_text("import jax\n\ndef f(a):\n    return jax.sharding.get_abstract_mesh()\n")
     r = subprocess.run(
         [sys.executable, "-m", "neuronx_distributed_tpu.scripts.graftlint",
          str(bad), "--no-baseline"],

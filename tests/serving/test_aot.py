@@ -48,9 +48,11 @@ def _restore_persistent_cache():
     to their bundle dir; put the suite's cache back after each test so
     the rest of tier-1 keeps its disk hits."""
     prev = aot.persistent_cache_dir()
+    floor = jax.config.jax_persistent_cache_min_compile_time_secs
     yield
     if prev and aot.persistent_cache_dir() != prev:
         aot.enable_persistent_cache(prev, host_scoped=False)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", floor)
 
 
 @pytest.fixture(scope="module")
@@ -251,6 +253,41 @@ def test_persistent_cache_env_opt_out(monkeypatch, tmp_path):
     monkeypatch.setenv(aot.DISABLE_ENV, "0")
     assert aot.enable_persistent_cache(str(tmp_path / "c")) is None
     assert aot.persistent_cache_dir() == prev  # untouched, not cleared
+
+
+def test_cache_placed_from_outside_is_left_alone(monkeypatch, tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set the directory is not ours to move:
+    no other ``jax_compilation_cache_dir`` is set — for ANY caller, they all
+    route through this one function — and nothing is appended to or created
+    under the placed one (no ``host-<digest>`` namespace)."""
+    placed, asked = tmp_path / "placed", tmp_path / "asked"
+    placed.mkdir()
+    monkeypatch.setenv(aot.PLACED_ENV, str(placed))
+    configured = jax.config.jax_compilation_cache_dir
+    monkeypatch.setattr(aot, "_CACHE_DIR", aot.persistent_cache_dir())
+    assert aot.enable_persistent_cache(str(asked)) == str(placed)
+    assert aot.persistent_cache_dir() == str(placed)
+    assert jax.config.jax_compilation_cache_dir == configured  # never touched
+    assert not asked.exists() and list(placed.iterdir()) == []
+    # the engine's and the trainer's AOT entry points ask for their own
+    # bundle dir — same owner, same answer
+    assert aot.enable_persistent_cache(
+        str(tmp_path / "bundle" / aot.XLA_SUBDIR)
+    ) == str(placed)
+    assert not (tmp_path / "bundle").exists()
+
+
+def test_default_cache_is_host_scoped_on_cpu_only(monkeypatch, tmp_path):
+    """Unset, the cache goes to the path given: under a host-CPU
+    fingerprint namespace on the CPU backend (a foreign XLA:CPU AOT entry
+    can SIGILL), as given on an accelerator (the fingerprint differs on
+    every machine, so a namespaced TPU cache would never hit)."""
+    monkeypatch.delenv(aot.PLACED_ENV, raising=False)
+    on_cpu = aot.enable_persistent_cache(str(tmp_path / "a"))
+    assert os.path.dirname(on_cpu) == str(tmp_path / "a")
+    assert os.path.basename(on_cpu).startswith("host-")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert aot.enable_persistent_cache(str(tmp_path / "b")) == str(tmp_path / "b")
 
 
 def test_encode_materialize_roundtrip_pedigrees():

@@ -272,9 +272,12 @@ def test_host_sync_budgets_unchanged_with_router(setup):
 def test_fused_paged_attention_bit_identical(setup):
     """ISSUE 14 satellite (the PR 12 leftover): paged_attention='fused'
     routes the chunk's attention through paged_flash_decode_attention —
-    off-TPU the kernel's gather fallback makes it the EXACT gather
-    transport, so streams (greedy and sampled, prefix hits included) are
-    bit-identical and decode_compilations stays 1."""
+    here the INTERPRETED kernel (conftest's switch; it no longer turns into
+    the gather transport off the TPU). Its online softmax differs from the
+    gather path's einsum only in fp32 rounding, which this tiny model's
+    streams (greedy and sampled, prefix hits included) do not feel;
+    decode_compilations stays 1. Bit-identity with gather-then-kernel is
+    pinned at the kernel level (tests/kernels/test_flash_decode.py)."""
     cfg, model, params = setup
     rng = np.random.RandomState(5)
     prompts = [

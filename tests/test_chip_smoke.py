@@ -1,0 +1,82 @@
+"""CPU rehearsal of ``chip_smoke.py``: its phases, imported, at a tiny size,
+on 1 and on 4 virtual devices, with the Pallas kernels interpreted.
+
+The platform is the only thing this rehearsal cannot have. So, from HERE and
+not through an option of the script: ``backend.on_tpu`` is patched to take
+every branch the program takes on the chip (``auto`` → flash, fused paged
+decode, flash-decode), the kernels run interpreted (conftest's session
+switch), ``main()``'s platform assertion is not reached, and the checks named
+``kernel_*`` — "a compiled Pallas kernel is in the program", which only a TPU
+lowering can make true — are expected False. Every other check must pass.
+"""
+
+import importlib.util
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from neuronx_distributed_tpu.kernels import backend
+from neuronx_distributed_tpu.models.llama import LlamaConfig
+
+_PATH = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "chip_smoke.py"
+)
+_spec = importlib.util.spec_from_file_location("chip_smoke", _PATH)
+chip_smoke = importlib.util.module_from_spec(_spec)
+sys.modules["chip_smoke"] = chip_smoke  # dataclasses resolve their module by name
+_spec.loader.exec_module(chip_smoke)
+
+# Llama-2-7B's structure (MHA, gated MLP, remat + scanned layers for
+# training) shrunk to test size; 1024 cache columns so the row-cache pass
+# crosses FLASH_DECODE_MIN_CONTEXT as the chip run does
+TINY = LlamaConfig(
+    vocab_size=256, hidden_size=64, intermediate_size=128, num_layers=2,
+    num_heads=8, num_kv_heads=8, max_seq_len=1024, dtype=jnp.float32,
+)
+TRAIN = chip_smoke.TrainSize(config=TINY, batch=4, seq=128, steps=3)
+# 12 new tokens = 2 decode chunks: a decode program that compiles twice
+# (the engine's one-program invariant) shows here, not only on the chip
+SERVE = chip_smoke.ServeSize(
+    config=TINY, max_seq_len=1024, slots=4, prompt_lens=(20, 40, 70, 130),
+    new_tokens=12, row_slots=2, row_prompt_lens=(12, 30), row_new_tokens=12,
+    logit_tol=1e-3,  # fp32 serving against the fp32 reference
+)
+
+
+@pytest.fixture(autouse=True)
+def _as_on_the_chip(monkeypatch):
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+
+
+def _assert_only_kernel_checks_fail(checks):
+    failed = {name for name, ok in checks.items() if not ok}
+    kernel = {name for name in checks if name.startswith("kernel_")}
+    assert kernel, "every phase reports on its compiled kernels"
+    assert failed == kernel, (
+        f"checks that failed: {sorted(failed - kernel)}; kernel checks that "
+        f"passed without a TPU lowering: {sorted(kernel - failed)}"
+    )
+
+
+def test_one_chip_run_rehearsal():
+    """Train then serve in ONE process, exactly as ``main()`` runs them (the
+    train phase's global mesh must not leak into the mesh-free engine)."""
+    _assert_only_kernel_checks_fail(
+        chip_smoke.one_chip(0, jax.devices()[:1], TRAIN, SERVE)
+    )
+
+
+def test_four_chip_run_rehearsal():
+    _assert_only_kernel_checks_fail(
+        chip_smoke.four_chips(0, jax.devices()[:4], TRAIN, SERVE)
+    )
+
+
+def test_main_refuses_without_a_tpu(capsys):
+    """No accelerator: non-zero exit and no result line."""
+    assert chip_smoke.main([]) != 0
+    out = capsys.readouterr()
+    assert out.out == "" and "needs a TPU" in out.err

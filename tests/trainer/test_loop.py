@@ -60,6 +60,12 @@ def test_trainer_fit_runs_and_loss_decreases(tmp_path):
     assert rec.losses[-1] < rec.losses[0]
     assert "throughput_seq_s" in metrics and metrics["throughput_seq_s"] > 0
     assert (tmp_path / "trace.json").exists()
+    # the attention implementation the step traced is on the ledger (what
+    # "auto" resolves to is pinned in tests/kernels/test_backend.py and the
+    # chip_smoke rehearsal)
+    assert trainer.programs.snapshot(analyze=False)["resolved"] == {
+        "attention": "xla"
+    }
 
 
 def test_trainer_checkpoint_callback(tmp_path):
