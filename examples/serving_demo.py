@@ -220,10 +220,10 @@ def parse_args(argv=None):
                         "request: submit -> admission -> prefill -> "
                         "decode chunks -> retire) — open in ui.perfetto.dev")
     p.add_argument("--profile", default=None, metavar="DIR",
-                   help="capture a jax.profiler device trace of decode "
-                        "chunks [2, 5) into DIR (open with TensorBoard/"
-                        "XProf) — the device-level truth to pair with "
-                        "--trace's host-side view")
+                   help="capture a jax.profiler trace of the serving loop "
+                        "into DIR (observability.profile_window; open with "
+                        "TensorBoard/XProf): device ops and the engine's "
+                        "nxd.step* spans on one clock")
     p.add_argument("--programs", action="store_true",
                    help="print the compiled-program ledger (dispatches, "
                         "compiler-reported FLOPs/bytes, roofline) and the "
@@ -754,7 +754,6 @@ def main(argv=None):
         paged_attention=args.paged_attention,
         fault_injector=injector,
         timeline=timeline,
-        profile_dir=args.profile,
     )
     aot_dir = None
     if args.prewarm or args.aot_cache:
@@ -829,19 +828,22 @@ def main(argv=None):
             print(f"r{i} rejected: {e} (queue depth {e.queue_depth})")
             return None
 
+    from neuronx_distributed_tpu.observability import profile_window
+
     upfront = min(args.slots, args.requests)
     reqs = [r for i in range(upfront) if (r := make_request(i)) is not None]
     i = upfront
-    while frontend.has_work or i < args.requests:
-        frontend.step()
-        if i < args.requests:
-            req = make_request(i)
-            if req is not None:
-                reqs.append(req)
-            i += 1
-        if not frontend.has_work and i >= args.requests:
-            break
-    frontend.run()
+    with profile_window(args.profile):
+        while frontend.has_work or i < args.requests:
+            frontend.step()
+            if i < args.requests:
+                req = make_request(i)
+                if req is not None:
+                    reqs.append(req)
+                i += 1
+            if not frontend.has_work and i >= args.requests:
+                break
+        frontend.run()
 
     prefix_desc = (
         "off" if args.no_prefix_cache
@@ -951,9 +953,8 @@ def main(argv=None):
               "(open in ui.perfetto.dev; request flows in the 'request' "
               "category)")
     if args.profile:
-        print(f"device profile dir: {args.profile} (captures decode "
-              "chunks [2, 5) — a run short enough to finish in under 3 "
-              "chunks records nothing)")
+        print(f"profiler trace dir: {args.profile} (the serving loop: "
+              "device ops and nxd.step* spans)")
     return snap
 
 

@@ -102,8 +102,10 @@ def parse_args(argv=None):
                     help="like --timeline, spelled as the observability "
                          "knob (open in ui.perfetto.dev)")
     io.add_argument("--profile", default=None, metavar="DIR",
-                    help="capture a jax.profiler device trace of steps "
-                         "[2, 5) into DIR (open with TensorBoard/XProf)")
+                    help="capture a jax.profiler trace of fit() into DIR "
+                         "(observability.profile_window; open with "
+                         "TensorBoard/XProf): device ops and the "
+                         "nxd.train.* spans on one clock")
     io.add_argument("--programs", action="store_true",
                     help="print the compiled-program ledger (dispatches, "
                          "compiler-reported FLOPs, per-step roofline) and "
@@ -369,7 +371,6 @@ def main(argv=None):
         callbacks=callbacks,
         pipeline=pipeline,
         timeline=Timeline(trace_path) if trace_path else None,
-        profile_dir=args.profile,
         fault_injector=injector,
         # chaos-demo warmup: under --inject-fault the spike detector arms
         # after 2 good steps so a spike at the default --fault-at 2 is
@@ -395,15 +396,17 @@ def main(argv=None):
         not args.no_zero1, batch_size, seq_len, args.steps,
     )
     t0 = time.perf_counter()
+    from neuronx_distributed_tpu.observability import profile_window
     from neuronx_distributed_tpu.trainer.loop import TrainerHalted
 
     try:
-        metrics = trainer.fit(
-            data,
-            jax.random.PRNGKey(args.seed),
-            args.steps,
-            resume_from=args.ckpt_dir if args.resume else None,
-        )
+        with profile_window(args.profile):
+            metrics = trainer.fit(
+                data,
+                jax.random.PRNGKey(args.seed),
+                args.steps,
+                resume_from=args.ckpt_dir if args.resume else None,
+            )
     except TrainerHalted as e:
         print(
             f"HALTED at step {trainer.step}: {e.reason} "

@@ -165,6 +165,13 @@ def attention_op(q, k, v, causal: bool = True, impl: str = "auto",
     return xla_attention(q, k, v, causal=causal)
 
 
+# The named scope a trace reader finds device ops by (PERF.md section 3): the
+# paged path's gather of the logical (slots x row) K/V view and its
+# write-back into the pool. Compile-time metadata only; it may not enclose a
+# Pallas call.
+KV_VIEW_SCOPE = "kv_view"
+
+
 def prefill_positions(padding_mask: jax.Array) -> jax.Array:
     """RoPE positions for a (possibly left-)padded prompt (B, S): restart at
     each row's first VALID token, so padded slots never shift the rotary
@@ -526,11 +533,12 @@ def gather_cache_pages(paged, page_size: int):
             continue  # transport metadata — dropped from the logical view
         if name in _PAGED_LEAVES:
             scale = pool_scale_sibling(pool, path, name)
-            leaf = (
-                paged_gather_leaf_dequant(leaf, scale, bt, page_size)
-                if scale is not None
-                else paged_gather_leaf(leaf, bt, page_size)
-            )
+            with jax.named_scope(KV_VIEW_SCOPE):
+                leaf = (
+                    paged_gather_leaf_dequant(leaf, scale, bt, page_size)
+                    if scale is not None
+                    else paged_gather_leaf(leaf, bt, page_size)
+                )
         items.append((keys, leaf))
     return _rebuild_tree(items)
 
@@ -582,10 +590,9 @@ def scatter_cache_window(paged, logical, page_size: int, start_col,
         q, s = quantize_page_block(vals)
         return paged_scatter_vals(pool_leaf, q if base == name else s, idx)
 
-    return {
-        "pages": bt,
-        "pool": jax.tree_util.tree_map_with_path(fn, pool),
-    }
+    with jax.named_scope(KV_VIEW_SCOPE):
+        new_pool = jax.tree_util.tree_map_with_path(fn, pool)
+    return {"pages": bt, "pool": new_pool}
 
 
 # --- fused paged decode attention (ISSUE 14) ----------------------------------
@@ -683,12 +690,15 @@ def _fused_paged_decode(frame, q, k_cache, v_cache, q_pos, kv_valid):
     # the window rewrite their own bytes, so the scatter is idempotent on
     # shared pages and the pool equals the logical view wherever kv_valid
     # holds
-    k_pool = paged_scatter_window_leaf(
-        k_pool, k_cache, bt, frame["page0"], frame["n_win"], ps
-    )
-    v_pool = paged_scatter_window_leaf(
-        v_pool, v_cache, bt, frame["page0"], frame["n_win"], ps
-    )
+    # the scope ends BEFORE the kernel: a Pallas kernel is named after the
+    # scope it is called in, and trace readers find it by that name
+    with jax.named_scope(KV_VIEW_SCOPE):
+        k_pool = paged_scatter_window_leaf(
+            k_pool, k_cache, bt, frame["page0"], frame["n_win"], ps
+        )
+        v_pool = paged_scatter_window_leaf(
+            v_pool, v_cache, bt, frame["page0"], frame["n_win"], ps
+        )
     return paged_flash_decode_attention(
         q, k_pool, v_pool, bt, q_pos, kv_valid=kv_valid, page_size=ps
     )

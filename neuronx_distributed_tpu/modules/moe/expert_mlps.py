@@ -312,10 +312,14 @@ class ExpertMLPs(nn.Module):
         x = x.astype(self.dtype)
         gate = None if gate is None else gate.astype(self.dtype)
         up, down = up.astype(self.dtype), down.astype(self.dtype)
+        # the dense strategies fold dispatch and combine into their einsums:
+        # one scope; the sparse ones below name their three phases
         if strategy == "all_experts":
-            return self._all_experts(x, top_e, top_w, gate, up, down)
+            with jax.named_scope("moe.experts"):
+                return self._all_experts(x, top_e, top_w, gate, up, down)
         if strategy == "capacity_factor":
-            return self._capacity_factor(x, top_e, top_w, gate, up, down)
+            with jax.named_scope("moe.experts"):
+                return self._capacity_factor(x, top_e, top_w, gate, up, down)
         if strategy == "blockwise":
             return self._blockwise(x, top_e, top_w, gate, up, down)
         if strategy == "selective":
@@ -328,15 +332,20 @@ class ExpertMLPs(nn.Module):
         """Per-token gathered expert weights — the decode path. For T tokens,
         gathers (T, k, H, I) weight slices and runs per-token einsums; memory
         is bounded by T·k weight slices, so this is gated on small T."""
-        up_g = jnp.take(up, top_e, axis=0)  # (T, k, H, I)
-        h = jnp.einsum("th,tkhi->tki", x, up_g)
-        if self.glu_mlp:
-            g = jnp.einsum("th,tkhi->tki", x, jnp.take(gate, top_e, axis=0))
-            h = _act(self.hidden_act)(g) * h
-        else:
-            h = _act(self.hidden_act)(h)
-        y = jnp.einsum("tki,tkih->tkh", h, jnp.take(down, top_e, axis=0))
-        return jnp.einsum("tkh,tk->th", y, top_w.astype(y.dtype))
+        with jax.named_scope("moe.dispatch"):
+            up_g = jnp.take(up, top_e, axis=0)  # (T, k, H, I)
+            gate_g = jnp.take(gate, top_e, axis=0) if self.glu_mlp else None
+            down_g = jnp.take(down, top_e, axis=0)
+        with jax.named_scope("moe.experts"):
+            h = jnp.einsum("th,tkhi->tki", x, up_g)
+            if self.glu_mlp:
+                g = jnp.einsum("th,tkhi->tki", x, gate_g)
+                h = _act(self.hidden_act)(g) * h
+            else:
+                h = _act(self.hidden_act)(h)
+            y = jnp.einsum("tki,tkih->tkh", h, down_g)
+        with jax.named_scope("moe.combine"):
+            return jnp.einsum("tkh,tk->th", y, top_w.astype(y.dtype))
 
     # --- strategy: all experts (reference expert_mlps.py:179) -----------------
 
@@ -438,11 +447,12 @@ class ExpertMLPs(nn.Module):
                     gate if gate is not None else up, up, down,
                 )
 
-        flat_e = top_e.reshape(-1)
-        order = jnp.argsort(flat_e, stable=True)  # expert-sorted slot ids
-        token_idx = order // k
-        group_sizes = jnp.bincount(flat_e, length=E).astype(jnp.int32)
-        ws = top_w.reshape(-1)[order].astype(x.dtype)
+        with jax.named_scope("moe.dispatch"):
+            flat_e = top_e.reshape(-1)
+            order = jnp.argsort(flat_e, stable=True)  # expert-sorted slot ids
+            token_idx = order // k
+            group_sizes = jnp.bincount(flat_e, length=E).astype(jnp.int32)
+            ws = top_w.reshape(-1)[order].astype(x.dtype)
 
         if tp > 1 or ep > 1:
             # Grouped (ragged) matmuls cannot be auto-partitioned by GSPMD, so
@@ -473,11 +483,19 @@ class ExpertMLPs(nn.Module):
                 self.glu_mlp,
                 self.hidden_act,
             )
-            contrib = smapped(
-                x, token_idx, ws, group_sizes,
-                gate if gate is not None else up, up, down,
+            with jax.named_scope("moe.experts"):
+                contrib = smapped(
+                    x, token_idx, ws, group_sizes,
+                    gate if gate is not None else up, up, down,
+                )
+            with jax.named_scope("moe.combine"):
+                return contrib.sum(axis=(0, 1))
+        with jax.named_scope("moe.dispatch"):
+            xs = x[token_idx]
+        with jax.named_scope("moe.experts"):
+            ys = _grouped_mlp(xs, gate, up, down, group_sizes,
+                              glu=self.glu_mlp, act=self.hidden_act)
+        with jax.named_scope("moe.combine"):
+            return jnp.zeros((T, H), ys.dtype).at[token_idx].add(
+                ys * ws[:, None]
             )
-            return contrib.sum(axis=(0, 1))
-        ys = _grouped_mlp(x[token_idx], gate, up, down, group_sizes,
-                          glu=self.glu_mlp, act=self.hidden_act)
-        return jnp.zeros((T, H), ys.dtype).at[token_idx].add(ys * ws[:, None])

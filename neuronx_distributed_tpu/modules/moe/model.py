@@ -86,7 +86,10 @@ class MoE(nn.Module):
             param_dtype=self.param_dtype,
             name="router",
         )
-        route = router(tokens, deterministic=deterministic)
+        # named scopes (with the modules' own: this block is ``moe``) so a
+        # device trace can be cut into router / dispatch / experts / combine
+        with jax.named_scope("moe.router"):
+            route = router(tokens, deterministic=deterministic)
 
         out = ExpertMLPs(
             num_experts=self.num_experts,

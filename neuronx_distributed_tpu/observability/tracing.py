@@ -1,4 +1,28 @@
-"""Request-scoped tracing: one connected Perfetto flow per request.
+"""Spans and request-scoped flows: what the program says about its own time.
+
+Two things live here. :func:`span` is the ONE way the serving engine and the
+trainer record a duration; :class:`RequestTracer` links the events of one
+request into a Perfetto flow.
+
+``span(name, timeline, **stats)`` is a context manager with two sinks:
+
+* a ``jax.profiler.TraceAnnotation(name, **stats)``. It is recorded only
+  while a profiler session is open (``observability.profile_window`` or any
+  ``jax.profiler.start_trace``), so the session IS the on-switch, and the
+  span lies on the device trace's clock by construction: it can be laid
+  against the ``XLA Ops`` line of the same ``.xplane.pb``. With no session
+  the annotation costs under a microsecond and records nothing.
+* the caller's :class:`~neuronx_distributed_tpu.utils.timeline.Timeline`,
+  if it has one that is enabled: a Chrome ``X`` event of the same name with
+  the stats as its ``args``.
+
+Stats are host scalars the caller already owns. One known only at the
+span's end (``ttft_us``, ``delivered``) is added with ``set_metadata``
+before the span closes. The names the engine and the trainer emit are
+listed in ``SERVE_SPANS`` / ``TRAIN_SPANS`` below: they are a contract with
+whoever reads a trace (PERF.md section 3, ``perfbench/program_spans.py``).
+
+Request-scoped tracing: one connected Perfetto flow per request.
 
 The serving engine's :class:`~neuronx_distributed_tpu.utils.timeline.
 Timeline` events were global — a Perfetto view showed prefill/decode spans
@@ -24,9 +48,81 @@ from __future__ import annotations
 
 from typing import Optional
 
+from jax.profiler import TraceAnnotation
+
 from neuronx_distributed_tpu.utils.timeline import Timeline
 
-__all__ = ["RequestTracer"]
+__all__ = ["RequestTracer", "SERVE_SPANS", "TRAIN_SPANS", "span"]
+
+# Timeline category of every span
+SPAN_CATEGORY = "nxd"
+
+# ServingEngine.step(): siblings on the calling thread, parent by containment
+STEP = "nxd.step"
+STEP_REAP = "nxd.step.reap"
+STEP_PREEMPT = "nxd.step.preempt"
+STEP_ADMIT = "nxd.step.admit"
+STEP_PREFILL = "nxd.step.prefill"
+STEP_FIRST_TOKEN = "nxd.step.prefill.first_token"
+STEP_DISPATCH = "nxd.step.decode.dispatch"
+STEP_READBACK = "nxd.step.decode.readback"
+STEP_EMIT = "nxd.step.decode.emit"
+STEP_HEALTH = "nxd.step.health"
+SERVE_SPANS = (
+    STEP, STEP_REAP, STEP_PREEMPT, STEP_ADMIT, STEP_PREFILL, STEP_FIRST_TOKEN,
+    STEP_DISPATCH, STEP_READBACK, STEP_EMIT, STEP_HEALTH,
+)
+
+# Trainer.fit: TRAIN_STEP is a StepTraceAnnotation around one iteration
+TRAIN_STEP = "nxd.train.step"
+TRAIN_FETCH = "nxd.train.fetch"
+TRAIN_DISPATCH = "nxd.train.dispatch"
+TRAIN_READBACK = "nxd.train.readback"
+TRAIN_CALLBACKS = "nxd.train.callbacks"
+TRAIN_SPANS = (
+    TRAIN_STEP, TRAIN_FETCH, TRAIN_DISPATCH, TRAIN_READBACK, TRAIN_CALLBACKS,
+)
+
+
+class _TimelineSpan:
+    """A span with both sinks: the profiler's annotation and an ``X`` event
+    on the timeline (which carries the stats as its ``args``)."""
+
+    __slots__ = ("_annotation", "_timeline", "_name", "_stats")
+
+    def __init__(self, annotation, timeline: Timeline, name: str, stats: dict):
+        self._annotation = annotation
+        self._timeline = timeline
+        self._name = name
+        self._stats = stats
+
+    def __enter__(self):
+        self._annotation.__enter__()
+        self._timeline.mark_event_start(self._name, SPAN_CATEGORY)
+        return self
+
+    def set_metadata(self, **stats) -> None:
+        self._annotation.set_metadata(**stats)
+        self._stats.update(stats)
+
+    def __exit__(self, *exc):
+        self._timeline.mark_event_end(
+            self._name, SPAN_CATEGORY, args=self._stats
+        )
+        return self._annotation.__exit__(*exc)
+
+
+def span(name: str, timeline: Optional[Timeline] = None, *,
+         annotation=TraceAnnotation, **stats):
+    """The one span primitive (module docstring). Returns a context manager
+    with ``set_metadata(**stats)``; with no enabled ``timeline`` that is the
+    bare profiler annotation, so an uninstrumented run pays for nothing
+    else. ``annotation`` is the profiler class to enter
+    (``jax.profiler.StepTraceAnnotation`` for a training step)."""
+    ann = annotation(name, **stats)
+    if timeline is None or not timeline.enabled:
+        return ann
+    return _TimelineSpan(ann, timeline, name, stats)
 
 # flow category: one namespace for request-lifecycle flows so trace
 # processors can select them structurally

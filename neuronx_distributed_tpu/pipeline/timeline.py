@@ -8,8 +8,8 @@ into one XLA program — there are no host-visible per-task boundaries. The
 honest equivalent, provided here, renders the engine's schedule (the exact
 cycle tables the runtime asserts against) as a chrome-trace, calibrated by
 the measured step time: per-rank rows, one slice per forward/backward slot
-per cycle. For true device-level timing, pair it with ``jax.profiler`` traces
-(Trainer ``profile_dir``)."""
+per cycle. For true device-level timing, pair it with a ``jax.profiler`` trace
+(``observability.profile_window`` around ``Trainer.fit``)."""
 
 from __future__ import annotations
 

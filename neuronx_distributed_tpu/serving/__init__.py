@@ -71,8 +71,9 @@ export, log-bucketed latency histograms incl. TTFT/TPOT percentiles);
 with a ``Timeline`` attached every request renders as one connected
 Perfetto flow (submit → admission → prefill → decode chunks → retire);
 a ``FlightRecorder`` auto-dumps a redacted post-mortem on ``HALTED``
-(``flight_dir=``); ``profile_dir=`` captures a ``jax.profiler`` trace of
-decode chunks [2, 5). All of it adds ZERO device→host syncs on the hot
+(``flight_dir=``); every phase of ``step()`` is a ``nxd.step*`` span on the
+profiler's clock while a ``jax.profiler`` session is open
+(``observability.profile_window`` around the run). All of it adds ZERO device→host syncs on the hot
 path (tests/serving/test_host_sync.py pins the budgets).
 
 Robustness contract (chaos-tested in ``tests/serving/test_faults.py``):

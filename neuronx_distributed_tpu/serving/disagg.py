@@ -280,7 +280,7 @@ class DisaggregatedServer:
 
     # --- the serving loop ----------------------------------------------------
 
-    def _coupled_fallback(self, req: Request, now: float) -> None:
+    def _coupled_fallback(self, req: Request) -> None:
         """Prefill ``req`` on the DECODE engine's own coupled path — the
         exact program a non-disaggregated engine runs, so the stream is
         bit-identical; no free slot right now just requeues it."""
@@ -290,9 +290,7 @@ class DisaggregatedServer:
         if self.engine.cache.free_slots == 0:
             self.engine.scheduler.requeue_front([req])
             return
-        self.engine._prefill_into_slot(
-            req, self.engine.cache.acquire(), now
-        )
+        self.engine._prefill_into_slot(req, self.engine.cache.acquire())
 
     def _release(self, staged: Optional[StagedContext], pool) -> None:
         if staged is not None and staged.page_ids:
@@ -333,7 +331,7 @@ class DisaggregatedServer:
                 # — release the pages and fall back to coupled prefill
                 self.stats["handoff_failures"] += 1
                 self._release(staged, self.engine.cache)
-                self._coupled_fallback(req, now)
+                self._coupled_fallback(req)
                 continue
             if admitted:
                 self.stats["handoffs"] += 1
@@ -375,7 +373,7 @@ class DisaggregatedServer:
                     pass
                 if not self.workers:
                     self.engine.external_prefill = False
-                self._coupled_fallback(req, now)
+                self._coupled_fallback(req)
                 continue
             if not self.shared_pool:
                 # distinct pools: explicit device transfer — charged to
@@ -394,7 +392,7 @@ class DisaggregatedServer:
                 except Exception:
                     self._release(staged, worker.pool)
                     self.stats["handoff_failures"] += 1
-                    self._coupled_fallback(req, now)
+                    self._coupled_fallback(req)
                     continue
                 self._release(staged, worker.pool)
                 try:
@@ -407,7 +405,7 @@ class DisaggregatedServer:
                     )
                 except Exception:
                     self.stats["handoff_failures"] += 1
-                    self._coupled_fallback(req, now)
+                    self._coupled_fallback(req)
                     continue
                 self.stats["imported_contexts"] += 1
             self.stats["prefills"] += 1

@@ -155,8 +155,11 @@ with everything enabled):
   redacted JSON post-mortem the moment the engine HALTs — including, under
   multi-tenant load, per-tenant queue depths and the SLO attainment state
   (who was being starved when it died).
-* ``profile_dir=`` captures a ``jax.profiler`` device trace of decode
-  chunks [2, 5).
+* Every phase of ``step()`` is a ``nxd.step*`` span
+  (``observability.tracing.span``): recorded on the profiler's clock while a
+  ``jax.profiler`` session is open (``observability.profile_window`` around
+  the run), and on the ``timeline`` if the engine has one. Off both, a span
+  costs under a microsecond and no sync.
 * Device efficiency (ISSUE 12): every jitted program the engine (and its
   cache/paging managers) dispatches registers in ``self.programs`` — a
   :class:`~neuronx_distributed_tpu.observability.programs.ProgramLedger`
@@ -247,6 +250,7 @@ from neuronx_distributed_tpu.observability.programs import (
     per_instance,
     weak_reader,
 )
+from neuronx_distributed_tpu.observability import tracing
 from neuronx_distributed_tpu.observability.tracing import RequestTracer
 from neuronx_distributed_tpu.serving.cache_manager import (
     PrefixCache,
@@ -499,7 +503,6 @@ class ServingEngine:
         program_ledger=None,
         flight_recorder="auto",
         flight_dir: Optional[str] = None,
-        profile_dir: Optional[str] = None,
         time_fn: Callable[[], float] = time.monotonic,
         sleep_fn: Callable[[float], None] = time.sleep,
     ):
@@ -821,8 +824,6 @@ class ServingEngine:
                 self.paged_attention if kv_page_size is not None else "none"
             ),
         )
-        self._profile_dir = profile_dir
-        self._profiling = False
         # host-side slot bookkeeping (scheduling only — the decode-visible
         # per-slot state lives on device in self._state)
         self._active = np.zeros((num_slots,), bool)
@@ -1960,10 +1961,6 @@ class ServingEngine:
         self._halt_reason = reason
         if self.timeline is not None:
             self.timeline.instant("halted", "serving", args={"reason": reason})
-        if self._profiling:
-            # never leave a device trace open across a halt — the window
-            # below [2, 5) can only close here once the loop stops
-            self._stop_profile()
         self._sync_health()
         # post-mortem: the flight ring (recent transitions/faults) plus the
         # metrics snapshot, written atomically BEFORE control returns to the
@@ -2277,25 +2274,33 @@ class ServingEngine:
                 self._steps_seen, self._params
             )
         self._steps_seen += 1
+        with self._span(tracing.STEP):
+            self._step()
+        return self.has_work
+
+    def _span(self, name: str, **stats):
+        """A phase of ``step()``: the one span primitive, bound to this
+        engine's timeline (``observability/tracing.py``)."""
+        return tracing.span(name, self.timeline, **stats)
+
+    def _step(self) -> None:
         now = self._now()
-        self._reap_cancelled(now)
-        self._shed_expired(now)
+        with self._span(tracing.STEP_REAP):
+            self._reap_cancelled(now)
+            self._shed_expired(now)
+            wall = any(self._active) and (
+                self.cache.cursor + self._round_cols > self.max_seq_len
+            )
+            if not wall and not any(self._active) and self.cache.cursor > 0:
+                self._rewind_drained()
         # one more dispatch needs _round_cols columns (gamma per
         # speculative round, 1 per plain step); preempt-and-rewind when the
         # wall is closer than that. Speculation spends columns faster than
         # tokens (rejected drafts leave gap columns), so this wall can
         # arrive earlier than the token-based admission projected — the
         # preemption machinery keeps streams bit-identical either way
-        if any(self._active) and (
-            self.cache.cursor + self._round_cols > self.max_seq_len
-        ):
+        if wall:
             self._preempt_all()
-        if not any(self._active) and self.cache.cursor > 0:
-            # drained: rewind the shared cursor so the next wave starts at
-            # column 0 (storage reused, nothing reallocated)
-            self.cache.reset()
-            if self.draft_cache is not None:
-                self.draft_cache.reset()
         # SLO-driven preemption (ISSUE 16): when the slot set is full and
         # an under-attaining tenant's work is waiting, the policy may
         # nominate victims (FIFO never does) — vacated through the same
@@ -2303,19 +2308,27 @@ class ServingEngine:
         # bit-identical and the freed slots admit below in THIS step
         victims = self.policy.victims(now)
         if victims:
-            self._preempt_victims(victims, now)
+            with self._span(tracing.STEP_PREEMPT):
+                self._preempt_victims(victims, now)
         self._admit(now)
         if not self._halted and any(self._active):
             self._decode()
-        if self.timeline is not None:
-            self.timeline.counter("slots_active", int(self._active.sum()), "serving")
-            self.timeline.counter("queue_depth", self.scheduler.queued, "serving")
-        if self._profiling and not self.has_work:
-            # a short run can drain before the [2, 5) window's closing
-            # chunk — flush the device trace rather than dropping it
-            self._stop_profile()
-        self._sync_health()
-        return self.has_work
+        with self._span(tracing.STEP_HEALTH):
+            if self.timeline is not None:
+                self.timeline.counter(
+                    "slots_active", int(self._active.sum()), "serving"
+                )
+                self.timeline.counter(
+                    "queue_depth", self.scheduler.queued, "serving"
+                )
+            self._sync_health()
+
+    def _rewind_drained(self) -> None:
+        """Drained: rewind the shared cursor so the next wave starts at
+        column 0 (storage reused, nothing reallocated)."""
+        self.cache.reset()
+        if self.draft_cache is not None:
+            self.draft_cache.reset()
 
     def run(self, max_steps: int = 1_000_000) -> Dict[int, Request]:
         """Step until idle (or HALTED); returns every request this engine
@@ -2386,6 +2399,21 @@ class ServingEngine:
             return  # the disaggregation server owns admission
         if self.cache.free_slots == 0 or self.scheduler.queued == 0:
             return
+        with self._span(tracing.STEP_ADMIT):
+            selected = self._select(now)
+        for idx, req in enumerate(selected):  # longest-prefill-first
+            self._prefill_into_slot(req, self.cache.acquire())
+            if self._halted:
+                # a prefill-failure halt mid-batch: the rest of this round
+                # was already popped from the queue — put it back intact
+                rest = selected[idx + 1:]
+                if rest:
+                    self.scheduler.requeue_front(rest)
+                break
+
+    def _select(self, now: float) -> List[Request]:
+        """Admission without the prefills: prefetch, the fit projection and
+        the scheduler's selection for the free slots."""
         # tiered KV (ISSUE 19): start host->device prefetches for queued
         # requests whose prefix match is host-resident BEFORE selection —
         # the async import dispatch overlaps the current chunk's device
@@ -2520,19 +2548,10 @@ class ServingEngine:
                     req.context_ids
                 )
 
-        selected = self.scheduler.select(
+        return self.scheduler.select(
             self.cache.free_slots, self._in_flight_tokens(), fits,
             prefill_cost=cost, now=now,
         )
-        for idx, req in enumerate(selected):  # longest-prefill-first
-            self._prefill_into_slot(req, self.cache.acquire(), now)
-            if self._halted:
-                # a prefill-failure halt mid-batch: the rest of this round
-                # was already popped from the queue — put it back intact
-                rest = selected[idx + 1:]
-                if rest:
-                    self.scheduler.requeue_front(rest)
-                break
 
     def _prefill_fn(self, padded_len: int):
         fn = self._prefill_fns.get(padded_len)
@@ -2570,7 +2589,23 @@ class ServingEngine:
             self._draft_prefill_fns[padded_len] = fn
         return fn
 
-    def _prefill_into_slot(self, req: Request, slot: int, now: float) -> None:
+    def _prefill_into_slot(self, req: Request, slot: int) -> None:
+        """One request's prefill and slot binding. The request's clock
+        starts HERE: its queue wait ends when its own prefill starts, not
+        when the step that admitted it did."""
+        now = self._now()
+        stats = {}
+        if req.admit_time is None and req.submit_time is not None:
+            # a request that comes back after a preemption waited once
+            stats["queue_wait_us"] = int(1e6 * (now - req.submit_time))
+        with self._span(
+            tracing.STEP_PREFILL, rid=req.rid,
+            prompt_tokens=len(req.context_ids),
+            decoding_slots=int(self._active.sum()), **stats,
+        ) as sp:
+            self._prefill_bind(req, slot, now, sp)
+
+    def _prefill_bind(self, req: Request, slot: int, now: float, sp) -> None:
         ctx = req.context_ids
         p = len(ctx)
         target = None
@@ -2586,12 +2621,9 @@ class ServingEngine:
             )
         self.tracer.step(req.rid, "admission", args={"slot": slot})
         plan = self._plan_prefix_reuse(ctx, p, padded)
-        self.tracer.step(
-            req.rid, "prefix_lookup",
-            args={"matched": plan[1] if plan is not None else 0},
-        )
-        if self.timeline is not None:
-            self.timeline.mark_event_start("prefill", "serving")
+        reused = plan[1] if plan is not None else 0
+        self.tracer.step(req.rid, "prefix_lookup", args={"matched": reused})
+        sp.set_metadata(padded=padded, reused=reused)
         call = self._prefill_calls
         self._prefill_calls += 1
         t0 = self._clock()
@@ -2656,13 +2688,11 @@ class ServingEngine:
             # after a hot swap, real OOM) must not silently fail 100% of
             # traffic while health() reads OK
             if self.timeline is not None:
-                self.timeline.mark_event_end(
-                    "prefill", "serving", args={"rid": req.rid, "error": str(e)}
-                )
                 self.timeline.instant(
                     f"prefill_failure r{req.rid}", "serving",
                     args={"error": str(e)[:200]},
                 )
+            now = self._now()
             self.cache.free(slot)
             req.state = RequestState.FAILED
             req.error = f"prefill failed: {e}"
@@ -2687,19 +2717,10 @@ class ServingEngine:
         self.metrics.record_prefill_wall(
             self._clock() - t0, kind="suffix" if plan is not None else "full"
         )
-        if self.timeline is not None:
-            self.timeline.mark_event_end(
-                "prefill", "serving",
-                args={
-                    "rid": req.rid, "padded": padded,
-                    "reused": plan[1] if plan is not None else 0,
-                },
-            )
         self.tracer.step(
             req.rid,
             "suffix_prefill" if plan is not None else "full_prefill",
-            args={"padded": padded,
-                  "reused": plan[1] if plan is not None else 0},
+            args={"padded": padded, "reused": reused},
         )
         if self._page_size is None:
             self._remember_prefix(
@@ -2753,14 +2774,17 @@ class ServingEngine:
                     m_shared // self._page_size
                 )
             self._remember_prefix_paged(ctx, p, slot, matched=m_shared)
-        self._bind_slot(req, slot, logits, now)
+        self._bind_slot(req, slot, logits, now, sp)
 
-    def _bind_slot(self, req: Request, slot: int, logits, now: float) -> None:
+    def _bind_slot(self, req: Request, slot: int, logits, now: float,
+                   sp=None) -> None:
         """The admission tail shared by coupled prefill and the
-        disaggregated page-table handoff: record the admit, sample the
-        first token off ``logits`` (fresh requests only — one explicit
-        device_get of the token+key pair), and activate the slot's
-        device-resident state."""
+        disaggregated page-table handoff: record the admit (``now``: when
+        this request's prefill, or its handoff, started), sample the first
+        token off ``logits`` (fresh requests only — one explicit device_get
+        of the token+key pair), and activate the slot's device-resident
+        state. The first token is stamped AFTER that readback, which is
+        where the host waits for the prefill: TTFT includes the prefill."""
         self.metrics.record_admit(req, now)
         if req.admit_time is None:
             req.admit_time = now
@@ -2773,14 +2797,18 @@ class ServingEngine:
             # tests/serving/test_host_sync.py pins the count at 1)
             carry, sub = jax.random.split(jnp.asarray(req.key))
             temp, topk, topp = _config_sentinels(req.config)
-            # graftlint: ok[GL02] the admission path's single documented
-            # sync: first token + advanced request key in one readback
-            tok0_h, carry_h = jax.device_get(
-                (self._first_token(logits, sub, temp, topk, topp), carry)
-            )
+            with self._span(tracing.STEP_FIRST_TOKEN, rid=req.rid):
+                # graftlint: ok[GL02] the admission path's single documented
+                # sync: first token + advanced request key in one readback
+                tok0_h, carry_h = jax.device_get(
+                    (self._first_token(logits, sub, temp, topk, topp), carry)
+                )
             tok0 = int(tok0_h)
             req.key = np.asarray(carry_h, np.uint32)
             self.tracer.step(req.rid, "first_token")
+            now = self._now()
+            if sp is not None and req.submit_time is not None:
+                sp.set_metadata(ttft_us=int(1e6 * (now - req.submit_time)))
             self._emit_token(req, tok0, now, first=True)
             if req.state is RequestState.CANCELLED:
                 # the on_token callback cancelled on the FIRST token (while
@@ -3153,36 +3181,6 @@ class ServingEngine:
 
     # --- decode -------------------------------------------------------------
 
-    def _maybe_profile(self) -> None:
-        """``profile_dir`` knob: capture a ``jax.profiler`` device trace of
-        decode chunks [2, 5) — past the compile/warmup chunks, bounded so
-        an unattended server never accumulates an unbounded trace (the
-        trainer's ``profile_dir`` profiles steps [2, 5) the same way)."""
-        if self._profile_dir is None:
-            return
-        chunks = self.metrics.chunks  # successful chunks so far
-        if not self._profiling and chunks == 2:
-            try:
-                jax.profiler.start_trace(self._profile_dir)
-            except Exception as e:
-                # a profiler that cannot start (another trace already
-                # active, unwritable dir) must cost the serving loop its
-                # profile, never its requests — disable and move on
-                self._profile_dir = None
-                if self.flight is not None:
-                    self.flight.record("profile_start_failed", error=str(e))
-                return
-            self._profiling = True
-        elif self._profiling and chunks >= 5:
-            self._stop_profile()
-
-    def _stop_profile(self) -> None:
-        self._profiling = False
-        try:
-            jax.profiler.stop_trace()
-        except Exception:
-            pass  # a failed stop must never take the serving loop down
-
     def _decode(self) -> None:
         """One fused decode chunk: dispatch the donated jitted scan, then a
         SINGLE host synchronization for the whole token block. Between here
@@ -3225,53 +3223,63 @@ class ServingEngine:
         survived (streams bit-identical — the fallback is the very program
         the spec-off engine runs), then preempts to resync the draft cache;
         consumed buffers route through full dispatch recovery."""
-        tl = self.timeline
         active_at_dispatch = int(self._active.sum())
-        self._maybe_profile()
-        if tl is not None:
-            tl.mark_event_start("decode_dispatch", "serving")
         t0 = self._clock()
-        cache_in = self.cache.take()
-        draft_in = self.draft_cache.take()
-        attempt = self._dispatch_attempts
-        self._dispatch_attempts += 1
-        dparams = self._draft_params
-        try:
-            if self._faults is not None:
-                self._faults.on_dispatch(attempt)
-                self._faults.on_spec_dispatch(attempt)
-                dparams = self._faults.on_spec_params(attempt, dparams)
-            (new_cache, new_draft, self._state, toks, counts, accepts,
-             used, key_snap) = self._spec_chunk(
-                self._params, dparams, cache_in, draft_in, self._state
-            )
-        except BaseException as e:
-            if tl is not None:
-                tl.mark_event_end("decode_dispatch", "serving")
-            if not isinstance(e, Exception):
+        fault = None
+        with self._span(tracing.STEP_DISPATCH, active=active_at_dispatch):
+            cache_in = self.cache.take()
+            draft_in = self.draft_cache.take()
+            attempt = self._dispatch_attempts
+            self._dispatch_attempts += 1
+            dparams = self._draft_params
+            try:
+                if self._faults is not None:
+                    self._faults.on_dispatch(attempt)
+                    self._faults.on_spec_dispatch(attempt)
+                    dparams = self._faults.on_spec_params(attempt, dparams)
+                (new_cache, new_draft, self._state, toks, counts, accepts,
+                 used, key_snap) = self._spec_chunk(
+                    self._params, dparams, cache_in, draft_in, self._state
+                )
+            except Exception as e:
+                fault = e
+            except BaseException:
                 # KeyboardInterrupt/SystemExit are the operator's, not
                 # faults: restore both references and re-raise
                 self.cache.restore(cache_in)
                 self.draft_cache.restore(draft_in)
                 raise
-            self._spec_fallback(cache_in, draft_in, e)
+        if fault is not None:
+            self._spec_fallback(cache_in, draft_in, fault)
             return
         t1 = self._clock()
         self._consecutive_dispatch_failures = 0
         self._chunks_since_failure += 1
-        if tl is not None:
-            tl.mark_event_end("decode_dispatch", "serving")
-            tl.mark_event_start("decode_readback", "serving")
-        # THE one host sync per speculative chunk: the ragged (rounds,
-        # slots, gamma) token block, per-round per-slot counts + accepted
-        # draft lengths, the executed round count, and the post-chunk key
-        # snapshot — whatever the per-slot acceptance pattern emitted
-        # graftlint: ok[GL02] THE one per-chunk sync of the fused
-        # speculative decode contract (pinned in test_host_sync.py)
-        toks, counts, accepts, used, chunk_keys = jax.device_get(
-            (toks, counts, accepts, used, key_snap)
-        )
+        with self._span(tracing.STEP_READBACK) as sp:
+            # THE one host sync per speculative chunk: the ragged (rounds,
+            # slots, gamma) token block, per-round per-slot counts +
+            # accepted draft lengths, the executed round count, and the
+            # post-chunk key snapshot — whatever the per-slot acceptance
+            # pattern emitted
+            # graftlint: ok[GL02] THE one per-chunk sync of the fused
+            # speculative decode contract (pinned in test_host_sync.py)
+            toks, counts, accepts, used, chunk_keys = jax.device_get(
+                (toks, counts, accepts, used, key_snap)
+            )
+            sp.set_metadata(steps=int(used))  # executed rounds
         t2 = self._clock()
+        with self._span(tracing.STEP_EMIT) as sp:
+            self._emit_spec_chunk(
+                sp, new_cache, new_draft, toks, counts, accepts, used,
+                chunk_keys, active_at_dispatch, t0, t1, t2,
+            )
+
+    def _emit_spec_chunk(self, sp, new_cache, new_draft, toks, counts,
+                         accepts, used, chunk_keys, active_at_dispatch,
+                         t0, t1, t2) -> None:
+        """What follows the speculative chunk's readback: validation, the
+        key mirror, every token to its stream, retirement, the metrics."""
+        tl = self.timeline
         readback = self._readbacks
         self._readbacks += 1
         if self._faults is not None:
@@ -3289,18 +3297,6 @@ class ServingEngine:
         )
         # paged: page-poison victims leave self._active before the unpack
         self._apply_page_poison(readback)
-        emitted = int(
-            sum(
-                int(counts[:, s].sum())
-                for s in np.flatnonzero(self._active)
-                if int(s) not in bad
-            )
-        )
-        if tl is not None:
-            tl.mark_event_end(
-                "decode_readback", "serving",
-                args={"tokens": emitted, "rounds": used},
-            )
         now = self._now()
         delivered = 0
         spec_accepts = []
@@ -3350,6 +3346,7 @@ class ServingEngine:
             dispatch_s=t1 - t0, readback_s=t2 - t1,
             spec_accepts=spec_accepts, gamma=self.gamma,
         )
+        sp.set_metadata(delivered=delivered)
         # roofline feed: the chunk's measured wall (already host floats off
         # the single readback) against the ledgered program cost — a
         # compile-polluted first chunk is skipped so MFU never averages in
@@ -3410,51 +3407,59 @@ class ServingEngine:
     def _decode_plain(self) -> None:
         """The non-speculative fused chunk (the pre-ISSUE-9 `_decode` body;
         also the speculative engine's fallback program)."""
-        tl = self.timeline
         active_at_dispatch = int(self._active.sum())
-        self._maybe_profile()
-        if tl is not None:
-            tl.mark_event_start("decode_dispatch", "serving")
         t0 = self._clock()
-        cache_in = self.cache.take()
-        attempt = self._dispatch_attempts
-        self._dispatch_attempts += 1
-        try:
-            if self._faults is not None:
-                self._faults.on_dispatch(attempt)
-            (new_cache, self._state, toks, counts, used,
-             key_snap) = self._nonspec_chunk()(
-                self._params, cache_in, self._state
-            )
-        except BaseException as e:
-            if tl is not None:
-                tl.mark_event_end("decode_dispatch", "serving")
-            if not isinstance(e, Exception):
+        fault = None
+        with self._span(tracing.STEP_DISPATCH, active=active_at_dispatch):
+            cache_in = self.cache.take()
+            attempt = self._dispatch_attempts
+            self._dispatch_attempts += 1
+            try:
+                if self._faults is not None:
+                    self._faults.on_dispatch(attempt)
+                (new_cache, self._state, toks, counts, used,
+                 key_snap) = self._nonspec_chunk()(
+                    self._params, cache_in, self._state
+                )
+            except Exception as e:
+                fault = e
+            except BaseException:
                 # KeyboardInterrupt/SystemExit are the operator's, not
                 # faults: restore the reference (a consumed buffer fails
                 # loudly on next use) and re-raise
                 self.cache.restore(cache_in)
                 raise
-            self._recover_dispatch(cache_in, e)
+        if fault is not None:
+            self._recover_dispatch(cache_in, fault)
             return
         t1 = self._clock()
         self._consecutive_dispatch_failures = 0
         self._chunks_since_failure += 1
-        if tl is not None:
-            tl.mark_event_end("decode_dispatch", "serving")
-            tl.mark_event_start("decode_readback", "serving")
-        # THE one host sync per chunk: the (chunk, slots) token block, the
-        # per-slot valid-prefix lengths, the executed step count — and the
-        # post-chunk key SNAPSHOT (frozen at each slot's finish step). The
-        # snapshot is a chunk OUTPUT, not the state leaf: device_get on the
-        # leaf would cache a host value on it and silently demote the next
-        # chunk's keys donation to a copy
-        # graftlint: ok[GL02] THE one per-chunk sync of the fused decode
-        # contract (tests/serving/test_decode_chunking.py pins it at 1)
-        toks, counts, used, chunk_keys = jax.device_get(
-            (toks, counts, used, key_snap)
-        )
+        with self._span(tracing.STEP_READBACK) as sp:
+            # THE one host sync per chunk: the (chunk, slots) token block,
+            # the per-slot valid-prefix lengths, the executed step count —
+            # and the post-chunk key SNAPSHOT (frozen at each slot's finish
+            # step). The snapshot is a chunk OUTPUT, not the state leaf:
+            # device_get on the leaf would cache a host value on it and
+            # silently demote the next chunk's keys donation to a copy
+            # graftlint: ok[GL02] THE one per-chunk sync of the fused decode
+            # contract (tests/serving/test_decode_chunking.py pins it at 1)
+            toks, counts, used, chunk_keys = jax.device_get(
+                (toks, counts, used, key_snap)
+            )
+            sp.set_metadata(steps=int(used))
         t2 = self._clock()
+        with self._span(tracing.STEP_EMIT) as sp:
+            self._emit_chunk(
+                sp, new_cache, toks, counts, used, chunk_keys,
+                active_at_dispatch, t0, t1, t2,
+            )
+
+    def _emit_chunk(self, sp, new_cache, toks, counts, used, chunk_keys,
+                    active_at_dispatch, t0, t1, t2) -> None:
+        """What follows the chunk's readback: validation, the key mirror,
+        every token to its stream, retirement, the metrics."""
+        tl = self.timeline
         readback = self._readbacks
         self._readbacks += 1
         if self._faults is not None:
@@ -3475,18 +3480,6 @@ class ServingEngine:
         # mapping it — victims leave self._active here, so the emit loop
         # below never touches their (discarded) readback columns
         self._apply_page_poison(readback)
-        emitted = int(
-            sum(
-                int(counts[s])
-                for s in np.flatnonzero(self._active)
-                if int(s) not in bad
-            )
-        )
-        if tl is not None:
-            tl.mark_event_end(
-                "decode_readback", "serving",
-                args={"tokens": emitted, "steps": used},
-            )
         now = self._now()
         delivered = 0
         for slot in np.flatnonzero(self._active):
@@ -3521,6 +3514,7 @@ class ServingEngine:
             delivered, used, self.cache.cursor, active_at_dispatch,
             dispatch_s=t1 - t0, readback_s=t2 - t1,
         )
+        sp.set_metadata(delivered=delivered)
         # roofline feed (see _decode_spec): measured chunk wall, compile
         # chunks excluded
         if not self._decode_chunk.last_call_compiled:
@@ -3761,24 +3755,25 @@ class ServingEngine:
         (keeping its generated tokens and its host-mirrored key), rewind the
         cache, and let admission re-prefill their contexts. Token streams
         are unaffected — resume replays the exact context the request had."""
-        preempted = self._vacate_active()
-        for req in preempted:
-            req.preemptions += 1
-            self.metrics.record_preemption(req)
-            self.tracer.step(req.rid, "preempt",
-                             args={"tokens": len(req.tokens)})
-        self.scheduler.requeue_front(preempted)
-        # ONE device reset invalidates every row — per-slot free() dispatches
-        # here would be N redundant full-cache programs; only the host
-        # free-list needs per-slot bookkeeping
-        self.cache.release_all_slots()
-        self.cache.reset()
-        if self.draft_cache is not None:
-            self.draft_cache.release_all_slots()
-            self.draft_cache.reset()
-        # every slot is empty now; re-admission re-uploads each row, so a
-        # fresh zero state is cheaper than N per-slot clears
-        self._state = self._fresh_slot_state()
+        with self._span(tracing.STEP_PREEMPT):
+            preempted = self._vacate_active()
+            for req in preempted:
+                req.preemptions += 1
+                self.metrics.record_preemption(req)
+                self.tracer.step(req.rid, "preempt",
+                                 args={"tokens": len(req.tokens)})
+            self.scheduler.requeue_front(preempted)
+            # ONE device reset invalidates every row — per-slot free()
+            # dispatches here would be N redundant full-cache programs; only
+            # the host free-list needs per-slot bookkeeping
+            self.cache.release_all_slots()
+            self.cache.reset()
+            if self.draft_cache is not None:
+                self.draft_cache.release_all_slots()
+                self.draft_cache.reset()
+            # every slot is empty now; re-admission re-uploads each row, so a
+            # fresh zero state is cheaper than N per-slot clears
+            self._state = self._fresh_slot_state()
         if self.timeline is not None:
             self.timeline.instant(
                 f"preempt x{len(preempted)}", "serving"

@@ -76,6 +76,7 @@ from neuronx_distributed_tpu.trainer.trainer import (
     make_optimizer,
     shard_batch,
 )
+from neuronx_distributed_tpu.observability import tracing
 from neuronx_distributed_tpu.utils.logger import get_logger
 from neuronx_distributed_tpu.utils.retry import RetryPolicy
 from neuronx_distributed_tpu.utils.timeline import Timeline
@@ -303,11 +304,6 @@ class Trainer:
     # reference's NxDPPModel wrap inside initialize_parallel_model
     # (trainer/trainer.py:147).
     pipeline: Optional[Any] = None
-    # jax.profiler trace directory (reference aux: the neuron-profiler hooks,
-    # SURVEY §5 tracing/profiling — device-level truth to pair with the
-    # schedule-derived pipeline timeline). Profiles steps [2, 5) of fit().
-    profile_dir: Optional[str] = None
-
     # --- fault tolerance ----------------------------------------------------
     # On-device anomaly guard config (None disables; pipeline adapters build
     # their own step, so the guard covers the monolithic path only).
@@ -1285,7 +1281,6 @@ class Trainer:
         for cb in self.callbacks:
             self._safe_callback(cb, "on_train_start", self)
         metrics = {}
-        profiling = False
         self._fit_t0 = time.perf_counter()
         self._step_wall_t0 = time.perf_counter()
         orig_handlers = self._install_signal_handlers()
@@ -1299,98 +1294,113 @@ class Trainer:
                     if self._preempt_signum is not None:
                         self._graceful_preempt()
                         break
-                    if pending is not None:
-                        batch = pending
-                        pending = None
-                        # the probe batch is now entering training; from here
-                        # _mid_step/_data_state_prepull carry the truth
-                        self._pending_untrained = False
-                    else:
-                        if self._data_source is not None:
-                            self._data_state_prepull = self._data_source.state()
-                        batch = next(data_iter)
-                    # the batch has left the iterator: from here until the
-                    # dispatch lands, any exit (corrupt_batch raising, profiler
-                    # failure, dispatch halt) must checkpoint the PRE-pull
-                    # cursor or resume would silently skip this batch
-                    self._mid_step = True
-                    if inj is not None:
-                        batch = inj.corrupt_batch(self.step, batch)
-                    if self.profile_dir is not None:
-                        if self.steps_run == 2 and not profiling:
-                            jax.profiler.start_trace(self.profile_dir)
-                            profiling = True
-                        elif self.steps_run == 5 and profiling:
-                            jax.profiler.stop_trace()
-                            profiling = False
-                    prepared = prepare(batch)
-                    if (
-                        self._sentinel is not None
-                        and self._sentinel.wants_pre_copy(self.step)
+                    # one iteration = one step event on the profiler's
+                    # clock (and the timeline), its phases inside it
+                    with tracing.span(
+                        tracing.TRAIN_STEP, tl,
+                        annotation=jax.profiler.StepTraceAnnotation,
+                        step_num=self.step,
                     ):
-                        # canary mode: retain the pre-step state + batch so
-                        # post_dispatch can re-execute this exact step
-                        self._sentinel.pre_dispatch(self.state, prepared)
-                    with tl.event("train_step"):
-                        self.state, metrics = self._dispatch(
-                            train_step, prepared
+                        with tracing.span(tracing.TRAIN_FETCH, tl):
+                            if pending is not None:
+                                batch = pending
+                                pending = None
+                                # the probe batch is now entering training;
+                                # from here _mid_step/_data_state_prepull
+                                # carry the truth
+                                self._pending_untrained = False
+                            else:
+                                if self._data_source is not None:
+                                    self._data_state_prepull = (
+                                        self._data_source.state()
+                                    )
+                                batch = next(data_iter)
+                            # the batch has left the iterator: from here until
+                            # the dispatch lands, any exit (corrupt_batch
+                            # raising, dispatch halt) must checkpoint the
+                            # PRE-pull cursor or resume would silently skip
+                            # this batch
+                            self._mid_step = True
+                            if inj is not None:
+                                batch = inj.corrupt_batch(self.step, batch)
+                            prepared = prepare(batch)
+                        if (
+                            self._sentinel is not None
+                            and self._sentinel.wants_pre_copy(self.step)
+                        ):
+                            # canary mode: retain the pre-step state + batch
+                            # so post_dispatch can re-execute this exact step
+                            self._sentinel.pre_dispatch(self.state, prepared)
+                        with tracing.span(tracing.TRAIN_DISPATCH, tl):
+                            self.state, metrics = self._dispatch(
+                                train_step, prepared
+                            )
+                        if inj is not None and hasattr(inj, "on_state"):
+                            # chaos (ISSUE 20): scheduled silent bit flips land
+                            # on the live state here — after dispatch, before
+                            # the sentinel's check — so detection latency is
+                            # measured from the step the corruption struck
+                            self.state = inj.on_state(self.step, self.state)
+                        self._mid_step = False
+                        self.step += 1
+                        self.steps_run += 1
+                        self.tokens_seen += batch_tokens
+                        # per-step roofline feed: the inter-step wall (host
+                        # clock the loop already owns — dispatch is async, so
+                        # steady-state iteration time IS the step wall). The
+                        # first iteration and any compile-bearing step are
+                        # skipped so MFU never averages in trace+compile time
+                        now_wall = time.perf_counter()
+                        if self.steps_run > 1 and not getattr(
+                            train_step, "last_call_compiled", True
+                        ):
+                            self.programs.observe_wall(
+                                "train_step", now_wall - self._step_wall_t0
+                            )
+                        self._step_wall_t0 = now_wall
+                        # budget-check the PREVIOUS step's guard flags now that
+                        # this step is dispatched — the readback overlaps
+                        # device compute
+                        with tracing.span(tracing.TRAIN_READBACK, tl):
+                            self._account_guard()
+                        metrics = dict(metrics)
+                        metrics["throughput_seq_s"] = meter.update()
+                        metrics["dispatch_retries"] = self.dispatch_retries
+                        metrics["emergency_checkpoints"] = (
+                            self.emergency_checkpoints
                         )
-                    if inj is not None and hasattr(inj, "on_state"):
-                        # chaos (ISSUE 20): scheduled silent bit flips land on
-                        # the live state here — after dispatch, before the
-                        # sentinel's check — so detection latency is measured
-                        # from the step the corruption actually struck
-                        self.state = inj.on_state(self.step, self.state)
-                    self._mid_step = False
-                    self.step += 1
-                    self.steps_run += 1
-                    self.tokens_seen += batch_tokens
-                    # per-step roofline feed: the inter-step wall (host clock
-                    # the loop already owns — dispatch is async, so steady-state
-                    # iteration time IS the step wall). The first iteration and
-                    # any compile-bearing step are skipped so MFU never
-                    # averages in trace+compile time
-                    now_wall = time.perf_counter()
-                    if self.steps_run > 1 and not getattr(
-                        train_step, "last_call_compiled", True
-                    ):
-                        self.programs.observe_wall(
-                            "train_step", now_wall - self._step_wall_t0
-                        )
-                    self._step_wall_t0 = now_wall
-                    # budget-check the PREVIOUS step's guard flags now that this
-                    # step is dispatched — the readback overlaps device compute
-                    self._account_guard()
-                    metrics = dict(metrics)
-                    metrics["throughput_seq_s"] = meter.update()
-                    metrics["dispatch_retries"] = self.dispatch_retries
-                    metrics["emergency_checkpoints"] = self.emergency_checkpoints
-                    metrics["callback_errors"] = self.callback_errors
-                    # a rollback inside _account_guard discarded this step —
-                    # its metrics/flags describe state that no longer exists
-                    sdc_rolled = self._drop_pending_guard
-                    self._drop_pending_guard = False
-                    if guard_cfg is not None and not sdc_rolled:
-                        self._pending_guard = (
-                            self.step - 1,
-                            metrics["good_step"],
-                            metrics["anomaly_skips"],
-                        )
-                    if (
-                        self._sentinel is not None
-                        and not sdc_rolled
-                        and self._sentinel.is_check_step(self.step - 1)
-                    ):
-                        # stage this check's fingerprint scalars; they ride
-                        # the NEXT _account_guard's single device_get
-                        self._pending_integrity = self._sentinel.post_dispatch(
-                            train_step, self.state, self.step,
-                            self._data_source.state()
-                            if self._data_source is not None else None,
-                            self.tokens_seen,
-                        )
-                    for cb in self.callbacks:
-                        self._safe_callback(cb, "on_step_end", self, metrics)
+                        metrics["callback_errors"] = self.callback_errors
+                        # a rollback inside _account_guard discarded this step
+                        # — its metrics/flags describe state that no longer
+                        # exists
+                        sdc_rolled = self._drop_pending_guard
+                        self._drop_pending_guard = False
+                        if guard_cfg is not None and not sdc_rolled:
+                            self._pending_guard = (
+                                self.step - 1,
+                                metrics["good_step"],
+                                metrics["anomaly_skips"],
+                            )
+                        if (
+                            self._sentinel is not None
+                            and not sdc_rolled
+                            and self._sentinel.is_check_step(self.step - 1)
+                        ):
+                            # stage this check's fingerprint scalars; they
+                            # ride the NEXT _account_guard's single device_get
+                            self._pending_integrity = (
+                                self._sentinel.post_dispatch(
+                                    train_step, self.state, self.step,
+                                    self._data_source.state()
+                                    if self._data_source is not None else None,
+                                    self.tokens_seen,
+                                )
+                            )
+                        with tracing.span(tracing.TRAIN_CALLBACKS, tl):
+                            for cb in self.callbacks:
+                                self._safe_callback(
+                                    cb, "on_step_end", self, metrics
+                                )
                     if self._preempt_signum is not None:
                         self._graceful_preempt()
                         break
@@ -1417,8 +1427,6 @@ class Trainer:
             # save_on_end path) don't double-count the elapsed wall
             self._fit_t0 = time.perf_counter()
             self._restore_signal_handlers(orig_handlers)
-            if profiling:
-                jax.profiler.stop_trace()
         for cb in self.callbacks:
             self._safe_callback(cb, "on_train_end", self)
         tl.save()

@@ -111,5 +111,7 @@ def sample_per_row(
 ) -> jax.Array:
     """Vectorized :func:`sample_row`: logits (B, V), keys (B, 2), per-row
     (B,) config arrays → (B,) int32 tokens. The serving engine's shared
-    decode step samples every slot with its own request's config here."""
-    return jax.vmap(sample_row)(logits, keys, temperature, top_k, top_p)
+    decode step samples every slot with its own request's config here.
+    Traced under the named scope ``sample`` (a trace reader's handle)."""
+    with jax.named_scope("sample"):
+        return jax.vmap(sample_row)(logits, keys, temperature, top_k, top_p)

@@ -1,0 +1,96 @@
+"""The names a trace reader finds things by (ISSUE 24), pinned on the
+programs of the benchmark's rehearsal configurations: the jitted programs,
+the scope a Pallas kernel is named after, and the named scopes on the model
+step. A refactor that renames one silently empties a per-layer metric
+(``perfbench/layer_metrics``: ``decode_step_dev_ms`` finds ``jit_chunk_fn``,
+``paged_decode_roofline`` the kernel ``attn._cached_attention``,
+``kv_view_dev_share_pct`` the scope ``kv_view``, ...)."""
+
+import importlib
+import json
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from neuronx_distributed_tpu.inference import GenerationConfig
+from neuronx_distributed_tpu.serving import ServingEngine
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+TINY = os.path.join(ROOT, "tests", "benchmark", "data", "configs")
+OP_NAME = re.compile(r'op_name="([^"]+)"')
+LOCATION = re.compile(r'loc\("([^"]+)"')
+KERNEL_CALL = re.compile(r'loc\("([^"]*)/pallas_call"')
+
+
+def _programs(config_name):
+    """``{"decode_chunk" | "prefill": (lowered text with locations, optimized
+    HLO text)}`` of a rehearsal configuration's engine, on the fused paged
+    path the chip runs (its kernel interpreted, as everywhere in this suite)."""
+    with open(os.path.join(TINY, config_name + ".json")) as f:
+        config = json.load(f)
+    family = importlib.import_module(f"perfbench.families.{config['family']}")
+    serving = config["serving"]
+    model = family.build(config["model"], runner="serve", max_seq_len=int(serving["max_seq_len"]))
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    engine = ServingEngine(model, params, num_slots=int(serving["num_slots"]),
+                           kv_page_size=int(serving["kv_page_size"]), paged_attention="fused")
+    engine.submit(np.arange(1, 20, dtype=np.int32), GenerationConfig(max_new_tokens=3, temperature=0.0))
+    engine.run()
+    out = {}
+    for name, info in engine.programs.programs().items():
+        key = name.split("[")[0]                   # prefill[<bucket>]
+        if key in ("decode_chunk", "prefill"):
+            lowered = info.variants[0].lower()
+            out[key] = (lowered.as_text(debug_info=True), lowered.compile().as_text())
+    return out
+
+
+def _traced(program):
+    """Scope components in the program as traced (its locations)."""
+    return {part for path in LOCATION.findall(program[0]) for part in path.split("/")}
+
+
+def _optimized(program):
+    """Scope components that survive into the optimized HLO's ``op_name``s
+    (a fusion keeps one instruction's, so fewer than were traced)."""
+    return {part for path in OP_NAME.findall(program[1]) for part in path.split("/")}
+
+
+@pytest.fixture(scope="module")
+def codegen():
+    return _programs("codegen2-7b-serve")
+
+
+@pytest.fixture(scope="module")
+def mixtral():
+    return _programs("mixtral-8x7b-serve")
+
+
+def test_programs_keep_their_jit_names(codegen, mixtral):
+    for programs in (codegen, mixtral):
+        assert re.search(r"HloModule (\S+?),", programs["decode_chunk"][1]).group(1) == "jit_chunk_fn"
+        assert re.search(r"HloModule (\S+?),", programs["prefill"][1]).group(1) == "jit_fn"
+
+
+def test_a_kernel_is_still_called_in_the_attention_scope(codegen, mixtral):
+    """XLA names a Pallas call after the innermost scope it is traced in:
+    no new scope may come between ``attn._cached_attention`` and the call."""
+    for programs in (codegen, mixtral):
+        callers = {path.rsplit("/", 1)[-1] for path in KERNEL_CALL.findall(programs["decode_chunk"][0])}
+        assert callers == {"attn._cached_attention"}
+
+
+def test_named_scopes_reach_the_optimized_hlo(codegen, mixtral):
+    for programs in (codegen, mixtral):
+        assert {"kv_view", "sample", "lm_head", "attn"} <= _traced(programs["decode_chunk"])
+        assert {"kv_view", "sample", "lm_head"} <= _optimized(programs["decode_chunk"])
+    assert "mlp" in _optimized(codegen["decode_chunk"])
+    moe = {"moe", "moe.router", "moe.dispatch", "moe.experts", "moe.combine"}
+    assert moe <= _optimized(mixtral["decode_chunk"])
+    # the rehearsal's four experts prefill through the dense strategy, whose
+    # einsums hold dispatch and combine: one scope
+    assert {"moe", "moe.router", "moe.experts"} <= _optimized(mixtral["prefill"])

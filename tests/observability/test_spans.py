@@ -1,0 +1,166 @@
+"""The program's own spans (ISSUE 24): ``observability.tracing.span`` and
+what ``ServingEngine.step()`` and ``Trainer.fit`` emit through it, read back
+from a profiler trace recorded by a process of its own (``_span_driver.py``)."""
+
+import glob
+import json
+import os
+import statistics
+import subprocess
+import sys
+
+import jax
+import numpy as np
+import pytest
+
+from neuronx_distributed_tpu.inference import GenerationConfig
+from neuronx_distributed_tpu.models.llama import LlamaForCausalLM, tiny_llama
+from neuronx_distributed_tpu.observability import tracing
+from neuronx_distributed_tpu.serving import ServingEngine
+from neuronx_distributed_tpu.utils.timeline import Timeline
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(os.path.dirname(HERE))
+CHILDREN = tuple(n for n in tracing.SERVE_SPANS if n not in (tracing.STEP, tracing.STEP_FIRST_TOKEN))
+
+
+def _host_events(trace_dir):
+    """``{name: [(start ns, end ns, stats, thread)]}`` of the nxd.* events."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True)
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("nxd."):
+                    a = int(ev.start_ns)
+                    out.setdefault(ev.name, []).append(
+                        (a, a + int(ev.duration_ns), dict(ev.stats), line.name))
+    return out
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    out = str(tmp_path_factory.mktemp("spans"))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=ROOT)
+    env.pop("XLA_FLAGS", None)
+    done = subprocess.run([sys.executable, os.path.join(HERE, "_span_driver.py"), out],
+                          cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
+    assert done.returncode == 0, done.stderr[-3000:]
+    with open(os.path.join(out, "facts.json")) as f:
+        facts = json.load(f)
+    return facts, _host_events(os.path.join(out, "trace")), _host_events(os.path.join(out, "empty"))
+
+
+def _inside(parent, events):
+    a, b, _, thread = parent
+    return [e for e in events if e[3] == thread and a <= e[0] and e[1] <= b]
+
+
+def test_every_name_of_the_contract_is_in_the_host_plane(traced):
+    facts, events, _ = traced
+    assert facts["on"]["preemptions"] > 0          # the scenario reaches the wall
+    assert set(tracing.SERVE_SPANS) <= set(events)
+    assert set(tracing.TRAIN_SPANS) <= set(events)
+    assert len(events[tracing.STEP_PREFILL]) == facts["on"]["prefills"]
+    assert len(events[tracing.STEP_READBACK]) == facts["on"]["chunks"]
+    assert len(events[tracing.TRAIN_STEP]) == 3
+    assert [e[2]["step_num"] for e in events[tracing.TRAIN_STEP]] == [0, 1, 2]
+
+
+def test_children_nest_inside_the_step_and_tile_it(traced):
+    _, events, _ = traced
+    steps = events[tracing.STEP]
+    for name in tracing.SERVE_SPANS[1:]:
+        for e in events[name]:
+            assert sum(1 for s in steps if s[3] == e[3] and s[0] <= e[0] and e[1] <= s[1]) == 1, name
+    for e in events[tracing.STEP_FIRST_TOKEN]:     # and the first token inside its prefill
+        assert len([p for p in events[tracing.STEP_PREFILL] if p[0] <= e[0] and e[1] <= p[1]]) == 1
+    # siblings do not overlap; in a decode-only step they cover the step
+    covered = []
+    for step in steps:
+        kids = sorted(k for name in CHILDREN for k in _inside(step, events[name]))
+        for left, right in zip(kids, kids[1:]):
+            assert left[1] <= right[0]
+        decode_only = (_inside(step, events[tracing.STEP_READBACK])
+                       and not _inside(step, events[tracing.STEP_PREFILL]))
+        if decode_only:
+            covered.append(sum(k[1] - k[0] for k in kids) / (step[1] - step[0]))
+    assert len(covered) >= 3
+    assert statistics.median(covered) >= 0.95, covered
+    # the trainer's phases lie inside their step
+    for name in tracing.TRAIN_SPANS[1:]:
+        for e in events[name]:
+            assert any(s[0] <= e[0] and e[1] <= s[1] for s in events[tracing.TRAIN_STEP]), name
+
+
+def test_stats_are_host_ints_and_rid_links_a_request(traced):
+    facts, events, _ = traced
+    for name, rows in events.items():
+        for _, _, stats, _ in rows:
+            for key, value in stats.items():
+                assert isinstance(value, int), (name, key, value)
+    first = {e[2]["rid"] for e in events[tracing.STEP_FIRST_TOKEN]}
+    assert first == set(facts["on"]["rids"])       # a fresh request samples one first token
+    prefills = events[tracing.STEP_PREFILL]
+    assert {e[2]["rid"] for e in prefills} == first
+    for _, _, stats, _ in prefills:
+        assert {"rid", "prompt_tokens", "padded", "reused", "decoding_slots"} <= set(stats)
+    fresh = [s for _, _, s, _ in prefills if "ttft_us" in s]
+    assert len(fresh) == len(first)                # a re-admission has no TTFT and waited once
+    assert all(s["ttft_us"] >= s["queue_wait_us"] >= 0 for s in fresh)
+    assert any(s["decoding_slots"] > 0 for _, _, s, _ in prefills)
+    # a chunk's executed steps and the tokens that reached a stream
+    assert all(e[2]["steps"] > 0 for e in events[tracing.STEP_READBACK])
+    assert all(e[2]["active"] > 0 for e in events[tracing.STEP_DISPATCH])
+    assert sum(e[2]["delivered"] for e in events[tracing.STEP_EMIT]) == \
+        sum(len(t) for t in facts["on"]["tokens"]) - len(first)
+    # what nothing reads is not emitted
+    for name in (tracing.STEP, tracing.STEP_REAP, tracing.STEP_PREEMPT, tracing.STEP_ADMIT, tracing.STEP_HEALTH):
+        assert all(e[2] == {} for e in events[name]), name
+
+def test_no_session_no_event_and_no_extra_sync(traced):
+    facts, _, empty = traced
+    assert empty == {}                             # spans emitted with no session open went nowhere
+    off, on = facts["off"], facts["on"]
+    assert off["tokens"] == on["tokens"]
+    # the pinned budget (tests/serving/test_host_sync.py): one sync a submit,
+    # one a fresh request's first token, one a chunk; the session adds none
+    assert off["syncs"] == on["syncs"] == 3 + 3 + on["chunks"]
+
+
+def test_span_without_a_timeline_is_the_bare_annotation():
+    assert type(tracing.span("x", None, a=1)) is jax.profiler.TraceAnnotation
+    assert type(tracing.span("x", Timeline(None), a=1)) is jax.profiler.TraceAnnotation
+    step = tracing.span("x", None, annotation=jax.profiler.StepTraceAnnotation, step_num=3)
+    assert type(step) is jax.profiler.StepTraceAnnotation
+    with tracing.span("x", None, a=1) as sp:
+        sp.set_metadata(b=2)                       # no session: a no-op, not an error
+
+
+def test_timeline_gets_the_same_names_and_stats(tmp_path):
+    """The second sink: with a Timeline the engine's phases are Chrome ``X``
+    events under the names of the contract, stats as args, end-of-span stats
+    included."""
+    path = tmp_path / "tl.json"
+    tl = Timeline(str(path))
+    model = LlamaForCausalLM(tiny_llama(), attention_impl="xla")
+    params = model.init(jax.random.PRNGKey(1), np.ones((1, 8), np.int32))
+    engine = ServingEngine(model, params, num_slots=2, timeline=tl)
+    req = engine.submit(np.asarray([1, 2, 3], np.int32),
+                        GenerationConfig(max_new_tokens=4, temperature=0.0))
+    engine.run()
+    tl.save()
+    events = [e for e in json.loads(path.read_text())["traceEvents"] if e["ph"] == "X"]
+    names = {e["name"] for e in events}
+    assert names <= set(tracing.SERVE_SPANS)
+    assert set(tracing.SERVE_SPANS) - names == {tracing.STEP_PREEMPT}
+    assert all(e["cat"] == tracing.SPAN_CATEGORY for e in events)
+    (prefill,) = [e for e in events if e["name"] == tracing.STEP_PREFILL]
+    assert prefill["args"]["rid"] == req.rid and prefill["args"]["ttft_us"] >= 0
+    assert prefill["args"]["padded"] >= 3 and prefill["args"]["reused"] == 0
+    emits = [e for e in events if e["name"] == tracing.STEP_EMIT]
+    assert sum(e["args"]["delivered"] for e in emits) == 3

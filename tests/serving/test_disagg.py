@@ -148,7 +148,6 @@ def test_prefills_per_step_bounds_prefill_between_chunks(setup):
 
 
 @pytest.mark.chaos
-@pytest.mark.slow
 def test_handoff_failure_falls_back_to_coupled_prefill(setup):
     """``FaultInjector.fail_handoff``: the page-table transfer fails →
     staged pages release (leak-checked by the conftest invariant), the

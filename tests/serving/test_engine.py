@@ -413,10 +413,9 @@ def test_on_token_streaming_callback(setup):
 
 
 def test_timeline_wiring(setup, tmp_path):
-    """With a Timeline attached, the engine emits prefill plus
-    dispatch/readback decode duration events (readback carrying the
-    per-chunk token count as args) and occupancy counters into valid
-    Chrome-trace JSON."""
+    """With a Timeline attached, the engine's ``nxd.step*`` spans (prefill,
+    decode dispatch/readback/emit; the emit carrying the chunk's delivered
+    tokens as args) and occupancy counters land in valid Chrome-trace JSON."""
     import json
 
     from neuronx_distributed_tpu.utils.timeline import Timeline
@@ -433,9 +432,11 @@ def test_timeline_wiring(setup, tmp_path):
     tl.save()
     events = json.loads(trace.read_text())["traceEvents"]
     names = {e["name"] for e in events}
-    assert "decode_dispatch" in names and "prefill" in names
+    assert "nxd.step.decode.dispatch" in names and "nxd.step.prefill" in names
     assert "slots_active" in names  # counter track
-    readbacks = [e for e in events if e["name"] == "decode_readback"]
+    readbacks = [e for e in events if e["name"] == "nxd.step.decode.readback"]
     assert readbacks  # the one host sync per chunk is a first-class span
-    assert sum(e["args"]["tokens"] for e in readbacks) == 3  # 4 - first
+    assert all(e["args"]["steps"] > 0 for e in readbacks)
+    emits = [e for e in events if e["name"] == "nxd.step.decode.emit"]
+    assert sum(e["args"]["delivered"] for e in emits) == 3  # 4 - first
     assert "chunk_tokens" in names  # per-chunk counter track

@@ -292,7 +292,7 @@ def test_prefix_timeline_events(setup, tmp_path):
     assert misses and misses[0]["args"]["prompt"] == len(prompt)
     assert hits and hits[0]["args"]["matched"] == len(prompt) - 1
     # prefill spans carry the reused-token count
-    prefills = [e for e in events if e["name"] == "prefill"]
+    prefills = [e for e in events if e["name"] == "nxd.step.prefill"]
     assert any(
         e.get("args", {}).get("reused", 0) > 0 for e in prefills
     )
