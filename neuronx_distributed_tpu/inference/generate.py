@@ -141,11 +141,18 @@ def chunked_decode_step(decode_model, chunk_size: int, max_seq_len: int,
     view; ``"fused"`` routes every decode-attention call through
     ``kernels/flash_decode.paged_flash_decode_attention`` — the block
     table rides the kernel's scalar prefetch and K/V stream straight from
-    the physical pool pages. It is the compiled kernel or nothing: off the
-    TPU it runs only where a test interprets it. Fused mode does not speak
-    quantized pools (the in-kernel page stream is float)."""
+    the physical pool pages. The pool is then loop-carried state of the
+    scan, beside the logical cache: each executed step scatters its write
+    window into the carried pool (in place — a pool the scan only closed
+    over was copied whole before every such scatter) and attends it, and
+    on the chunk's exit the carried pool, current through the last
+    executed step, IS the output pool; nothing is scattered a second time.
+    It is the compiled kernel or nothing: off the TPU it runs only where a
+    test interprets it. Fused mode does not speak quantized pools (the
+    in-kernel page stream is float)."""
     from neuronx_distributed_tpu.inference.utils import unwrap_logits
     from neuronx_distributed_tpu.modules.attention import (
+        adopt_kv_pool_pairs,
         cache_cursor,
         fused_paged_attention_scope,
         gather_cache_pages,
@@ -163,41 +170,59 @@ def chunked_decode_step(decode_model, chunk_size: int, max_seq_len: int,
         )
 
     def chunk_fn(params, cache, state):
-        if page_size is not None:
-            paged = cache
-            start = cache_cursor(paged)
-            logical = gather_cache_pages(paged, page_size)
-            if paged_attention == "fused":
-                pools = ordered_kv_pool_pairs(paged["pool"])
-                n_log = paged["pages"].shape[1]
-                n_win = min((chunk_size - 1) // page_size + 2, n_log)
-                with fused_paged_attention_scope(
-                    pools, paged["pages"], page_size,
-                    start // page_size, n_win,
-                ):
-                    out = _row_chunk(params, logical, state)
-            else:
-                out = _row_chunk(params, logical, state)
+        if page_size is None:
+            return _row_chunk(params, cache, state)[0]
+        paged = cache
+        start = cache_cursor(paged)
+        logical = gather_cache_pages(paged, page_size)
+        if paged_attention != "fused":
+            out, _ = _row_chunk(params, logical, state)
             return (
                 scatter_cache_window(
                     paged, out[0], page_size, start, chunk_size
                 ),
             ) + out[1:]
-        return _row_chunk(params, cache, state)
+        n_log = paged["pages"].shape[1]
+        n_win = min((chunk_size - 1) // page_size + 2, n_log)
+        # the view's raw gathers are dead once the view stands: what the
+        # compiler hoists out of the scan (a layout copy per weight) waits
+        # for that and takes their space, not 32 MiB a weight beside them
+        params, logical = jax.lax.optimization_barrier((params, logical))
+        out, pools = _row_chunk(
+            params, logical, state,
+            pools=ordered_kv_pool_pairs(paged["pool"]),
+            window=(paged["pages"], page_size, start // page_size, n_win),
+        )
+        # the carried pool is current through the last executed step
+        return (adopt_kv_pool_pairs(paged, out[0], pools),) + out[1:]
 
-    def _row_chunk(params, cache, state):
+    def _row_chunk(params, cache, state, pools=(), window=None):
+        """The row-per-slot chunk on a logical cache: ``(outputs, pools)``.
+        Fused mode hands in the page pool (``pools``, every layer's
+        ``(k, v)`` leaves) and its write ``window``: the pool rides the
+        scan's carry beside the logical cache, and each live step scatters
+        its window into it in place and attends it. Elsewhere ``pools`` is
+        empty and adds no leaf to the carry."""
         temp, topk, topp = state["temp"], state["topk"], state["topp"]
         eos = state["eos"]
         allowed = jnp.clip(max_seq_len - cache_cursor(cache), 0, chunk_size)
 
-        def live(carry):
-            cache, tok, keys, remaining, done = carry
-            split = jax.vmap(jax.random.split)(keys)
-            carry_keys, subs = split[:, 0], split[:, 1]
-            out, variables = decode_model.apply(
+        def apply(cache, tok, done):
+            return decode_model.apply(
                 {**params, "cache": cache}, tok[:, None],
                 padding_mask=decode_write_mask(done), mutable=["cache"],
             )
+
+        def live(carry):
+            cache, tok, keys, remaining, done, pools = carry
+            split = jax.vmap(jax.random.split)(keys)
+            carry_keys, subs = split[:, 0], split[:, 1]
+            if window is None:
+                out, variables = apply(cache, tok, done)
+            else:
+                with fused_paged_attention_scope(pools, *window) as frame:
+                    out, variables = apply(cache, tok, done)
+                pools = frame["pools"]
             nxt = sample_per_row(
                 unwrap_logits(out)[:, -1], subs, temp, topk, topp
             )
@@ -211,7 +236,8 @@ def chunked_decode_step(decode_model, chunk_size: int, max_seq_len: int,
             tok = jnp.where(emit, nxt, tok)
             keys = jnp.where(emit[:, None], carry_keys, keys)
             return (
-                (variables["cache"], tok, keys, remaining, done | finished),
+                (variables["cache"], tok, keys, remaining, done | finished,
+                 pools),
                 (nxt, emit),
             )
 
@@ -225,16 +251,22 @@ def chunked_decode_step(decode_model, chunk_size: int, max_seq_len: int,
             return jax.lax.cond(run, live, frozen, carry)
 
         done0 = jnp.logical_not(state["active"])
-        carry0 = (cache, state["tok"], state["keys"], state["remaining"], done0)
-        (cache, tok, keys, remaining, done), (toks, emits) = jax.lax.scan(
-            step, carry0, jnp.arange(chunk_size, dtype=jnp.int32)
+        carry0 = (
+            cache, state["tok"], state["keys"], state["remaining"], done0,
+            pools,
+        )
+        (cache, tok, keys, remaining, done, pools), (toks, emits) = (
+            jax.lax.scan(
+                step, carry0, jnp.arange(chunk_size, dtype=jnp.int32)
+            )
         )
         counts = emits.astype(jnp.int32).sum(0)
         new_state = dict(
             state, tok=tok, keys=keys, remaining=remaining,
             active=jnp.logical_not(done),
         )
-        return cache, new_state, toks, counts, jnp.max(counts), keys.copy()
+        out = cache, new_state, toks, counts, jnp.max(counts), keys.copy()
+        return out, pools
 
     return chunk_fn
 

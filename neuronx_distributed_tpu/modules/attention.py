@@ -604,28 +604,36 @@ def scatter_cache_window(paged, logical, page_size: int, start_col,
 # table rides scalar prefetch and the kernel streams each slot's PHYSICAL
 # pool pages directly. The trace-scope below is how the serving chunk routes
 # attention through that kernel without touching the flax modules: while a
-# scope is active, every ``decode_attention`` call consumes the next
-# attention layer's (k, v) pool pair — layers call in execution order, the
-# scope holds the pools in the same order — scatters the chunk's write
-# window (the in-chunk columns the pool has not seen yet; pre-window columns
-# rewrite their own bytes, so shared CoW pages stay bit-stable) and attends
+# scope is active, every ``decode_attention`` call takes the next attention
+# layer's (k, v) pool pair — layers call in execution order, the scope names
+# them in the same order — scatters the chunk's write window into it
+# (the in-chunk columns the pool has not seen yet; pre-window columns
+# rewrite their own bytes, so shared CoW pages stay bit-stable), attends
 # straight off the pool through the fused kernel (compiled on the TPU,
-# interpreted only in tests — it never degrades to the gather transport).
+# interpreted only in tests — it never degrades to the gather transport)
+# and leaves the UPDATED pair in the scope's frame. The chunk builder
+# enters the scope once per traced decode step with the pools its scan
+# CARRIES and reads the step's pools back off the frame, so the pool is
+# loop-carried state that XLA scatters into in place (PR 25: closed over,
+# it was copied whole before every step's scatter).
 
 _FUSED_PAGED_STACK: list = []
 
 
 class fused_paged_attention_scope:
     """Trace-scope carrying the paged pool into the decode attention calls
-    traced inside it. ``pools`` is a list of per-attention-layer
-    ``(k_pool, v_pool)`` leaves in model execution order; ``page0``/
+    traced inside it. ``pools`` maps every attention layer to its
+    ``(k_pool, v_pool)`` leaves (:func:`ordered_kv_pool_pairs`); ``page0``/
     ``n_win`` bound the chunk's write window (the columns the pool does not
-    hold yet)."""
+    hold yet). ``__enter__`` returns the frame: after the model apply its
+    ``"pools"`` hold each layer's pair as that layer's window scatter left
+    it."""
 
     def __init__(self, pools, tables, page_size: int, page0, n_win: int):
         self.frame = {
-            "pools": pools, "tables": tables, "page_size": page_size,
-            "page0": page0, "n_win": n_win, "idx": 0,
+            "pools": dict(pools), "order": _execution_order(pools),
+            "tables": tables, "page_size": page_size, "page0": page0,
+            "n_win": n_win, "idx": 0,
         }
 
     def __enter__(self):
@@ -636,16 +644,14 @@ class fused_paged_attention_scope:
         _FUSED_PAGED_STACK.pop()
 
 
-def ordered_kv_pool_pairs(pool):
-    """Per-attention-layer ``(k, v)`` pool leaf pairs in MODEL EXECUTION
-    order — natural sort of the tree paths, so ``layers_10`` follows
-    ``layers_9`` (lexicographic flatten order would interleave them and
-    hand layer 2 another layer's pages). The one ordering assumption of
-    the fused transport: sequential-layer models name their layers with
-    their execution index, which every family in this repo does."""
+def _execution_order(layers):
+    """Layer tree paths (key tuples) in MODEL EXECUTION order — natural
+    sort, so ``layers_10`` follows ``layers_9`` (lexicographic flatten order
+    would interleave them and hand layer 2 another layer's pages). The one
+    ordering assumption of the fused transport: sequential-layer models
+    name their layers with their execution index, which every family in
+    this repo does."""
     import re
-
-    from neuronx_distributed_tpu.utils.tree import path_keys
 
     def natural(keys):
         return tuple(
@@ -656,6 +662,16 @@ def ordered_kv_pool_pairs(pool):
             )
             for k in keys
         )
+
+    return sorted(layers, key=natural)
+
+
+def ordered_kv_pool_pairs(pool):
+    """``{layer: (k, v)}``: every attention layer's pool leaf pair under the
+    layer's tree path (its key tuple), in model execution order
+    (:func:`_execution_order`) — what the fused chunk carries through its
+    scan."""
+    from neuronx_distributed_tpu.utils.tree import path_keys
 
     nodes = {}
     for path, leaf in jax.tree_util.tree_flatten_with_path(pool)[0]:
@@ -668,10 +684,30 @@ def ordered_kv_pool_pairs(pool):
                 "(the in-kernel page stream is float) — use the gather "
                 "transport with kv_quant"
             )
-    return [
-        (nodes[parent]["k"], nodes[parent]["v"])
-        for parent in sorted(nodes, key=natural)
-    ]
+    return {
+        layer: tuple(nodes[layer][name] for name in _PAGED_LEAVES)
+        for layer in _execution_order(nodes)
+    }
+
+
+def adopt_kv_pool_pairs(paged, logical, pairs):
+    """The paged pytree (same treedef) whose k/v pool leaves are ``pairs``
+    (``{layer: (k, v)}`` as :func:`ordered_kv_pool_pairs` keys it — the
+    fused chunk's carried pools, current through its last executed step)
+    and whose ``index``/``kv_valid`` (logical, per-slot) are adopted from
+    ``logical``, as :func:`scatter_cache_window` adopts them."""
+    from neuronx_distributed_tpu.utils.tree import path_keys
+
+    def fn(path, _):
+        *layer, name = path_keys(path)
+        if name in _PAGED_LEAVES:
+            return pairs[tuple(layer)][_PAGED_LEAVES.index(name)]
+        return cache_node_at(logical, path)
+
+    return {
+        "pages": paged["pages"],
+        "pool": jax.tree_util.tree_map_with_path(fn, paged["pool"]),
+    }
 
 
 def _fused_paged_decode(frame, q, k_cache, v_cache, q_pos, kv_valid):
@@ -680,16 +716,16 @@ def _fused_paged_decode(frame, q, k_cache, v_cache, q_pos, kv_valid):
         paged_scatter_window_leaf,
     )
 
-    pools = frame["pools"]
-    i = frame["idx"] % len(pools)
+    pools, order = frame["pools"], frame["order"]
+    layer = order[frame["idx"] % len(order)]
     frame["idx"] += 1
-    k_pool, v_pool = pools[i]
+    k_pool, v_pool = pools[layer]
     ps, bt = frame["page_size"], frame["tables"]
     # bring the pool current through THIS step: scatter the chunk window
-    # from the logical view (which the model just wrote) — columns before
-    # the window rewrite their own bytes, so the scatter is idempotent on
-    # shared pages and the pool equals the logical view wherever kv_valid
-    # holds
+    # from the logical view (which the model just wrote) into the carried
+    # pool — columns before the window rewrite their own bytes, so the
+    # scatter is idempotent on shared pages and the pool equals the logical
+    # view wherever kv_valid holds
     # the scope ends BEFORE the kernel: a Pallas kernel is named after the
     # scope it is called in, and trace readers find it by that name
     with jax.named_scope(KV_VIEW_SCOPE):
@@ -699,6 +735,7 @@ def _fused_paged_decode(frame, q, k_cache, v_cache, q_pos, kv_valid):
         v_pool = paged_scatter_window_leaf(
             v_pool, v_cache, bt, frame["page0"], frame["n_win"], ps
         )
+    pools[layer] = (k_pool, v_pool)  # trace-time: the step's carry-out
     return paged_flash_decode_attention(
         q, k_pool, v_pool, bt, q_pos, kv_valid=kv_valid, page_size=ps
     )

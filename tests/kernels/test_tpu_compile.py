@@ -14,7 +14,9 @@ rehearsal to run before a chip call is spent, not part of every test run.
 Nothing runs, so nothing here says anything about results or speed.
 """
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -227,8 +229,10 @@ def _train_step_compiled(topo, n_devices, *, tp, sp, layers=2, batch=4,
 
 def _engine_programs(topo, n_devices, *, layers=8, slots=8, seq=4096,
                      page=16, bucket=512):
-    """``(engine, lower_decode, lower_prefill)`` for a default-configured
-    engine over abstract params on described devices. With ``n_devices`` > 1
+    """``(engine, lower_decode, lower_prefill, pool_shards)`` for a
+    default-configured engine over abstract params on described devices;
+    ``pool_shards`` are the shapes of the bf16 page-pool leaves as ONE
+    device holds them (``(2049, 16, 32, 128)``). With ``n_devices`` > 1
     the global tp mesh is up and every operand carries the placement the TP
     engine's partitioner would commit — the engine's programs take their
     sharding from their operands, so this IS the tp program."""
@@ -290,7 +294,85 @@ def _engine_programs(topo, n_devices, *, layers=8, slots=8, seq=4096,
     def lower_decode():
         return engine._decode_chunk.lower(params, paged, state)
 
-    return engine, lower_decode, lower_prefill
+    pool_shards = [
+        a.sharding.shard_shape(a.shape)
+        for a in jax.tree.leaves(paged["pool"]) if a.ndim == 4
+    ]
+    return engine, lower_decode, lower_prefill, pool_shards
+
+
+def _computations(hlo_text):
+    """``{name: [instruction lines]}`` of a compiled module's text."""
+    comps, name = {}, None
+    for line in hlo_text.splitlines():
+        head = re.match(r"(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            comps[name].append(line)
+    return comps
+
+
+_CALLED = re.compile(
+    r"(?:body|condition|calls|to_apply|true_computation|false_computation)"
+    r"=%?([\w.\-]+)|branch_computations=\{([^}]*)\}"
+)
+
+
+def _copies_inside_loops(hlo_text, shapes):
+    """``copy`` instructions of one of ``shapes`` in any computation a
+    ``while`` body reaches (its conditional's branches included): what runs
+    once per decode STEP, against once per chunk."""
+    comps = _computations(hlo_text)
+    todo = [
+        body
+        for lines in comps.values() for line in lines if " while(" in line
+        for body in re.findall(r"body=%?([\w.\-]+)", line)
+    ]
+    assert todo, "the decode program holds no while loop"
+    reached = set()
+    while todo:
+        name = todo.pop()
+        if name in reached or name not in comps:
+            continue
+        reached.add(name)
+        for line in comps[name]:
+            for one, many in _CALLED.findall(line):
+                todo.extend(
+                    [one] if one
+                    else [c.strip().lstrip("%") for c in many.split(",")]
+                )
+    found = []
+    for name in reached:
+        for line in comps[name]:
+            made = re.search(r"= (\w+\[[\d,]*\])\S* copy\(", line)
+            if made and made.group(1) in shapes:
+                found.append(f"{name}: {line.strip()[:100]}")
+    return found
+
+
+def _assert_pool_carried(compiled, pool_shards):
+    """PR 25: the fused chunk carries its page pool through the scan, so the
+    window scatter of every step and layer writes the carried buffer. Closed
+    over, each pool leaf was copied whole inside the loop — once per layer,
+    per K and V, per step — before its scatter. The program's temporaries
+    stay what they were: the logical K/V view twice (``jnp.take``'s gather
+    and its fill-mode select) and nothing else of size — the weights' layout
+    copies, hoisted out of the scan, take the dead gathers' space."""
+    shapes = {"bf16[%s]" % ",".join(map(str, s)) for s in pool_shards}
+    copies = _copies_inside_loops(compiled.as_text(), shapes)
+    assert not copies, (
+        f"{len(copies)} whole-pool copies per decode step: " + "; ".join(copies[:3])
+    )
+    view_twice = 2 * sum(2 * math.prod(s) for s in pool_shards)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 1.02 * view_twice, (
+        f"{temp / 2**30:.2f} GiB of temporaries beside a K/V view of "
+        f"2 x {view_twice / 2**31:.2f} GiB"
+    )
 
 
 def _fits(compiled, hbm_bytes=16 * 1024**3):
@@ -310,7 +392,9 @@ def test_train_step_compiles_at_7b_widths(topo):
 
 @pytest.mark.slow
 def test_engine_programs_compile_at_7b_widths(topo):
-    engine, lower_decode, lower_prefill = _engine_programs(topo, 1)
+    engine, lower_decode, lower_prefill, pool_shards = _engine_programs(
+        topo, 1
+    )
     assert engine.programs.resolved == {
         "attention": "flash", "decode_attention": "paged_fused",
         "paged_attention": "fused",
@@ -319,6 +403,8 @@ def test_engine_programs_compile_at_7b_widths(topo):
         compiled = lower().compile()
         assert KERNEL in compiled.as_text()
         _fits(compiled)
+        if lower is lower_decode:
+            _assert_pool_carried(compiled, pool_shards)
 
 
 @pytest.mark.slow
@@ -334,8 +420,10 @@ def test_tp4_train_step_compiles_and_is_split_four_ways(topo):
 
 @pytest.mark.slow
 def test_tp4_engine_programs_compile(topo):
-    _, lower_decode, lower_prefill = _engine_programs(topo, 4)
+    _, lower_decode, lower_prefill, pool_shards = _engine_programs(topo, 4)
     for lower in (lower_decode, lower_prefill):
         compiled = lower().compile()
         assert KERNEL in compiled.as_text()
         _fits(compiled)
+        if lower is lower_decode:
+            _assert_pool_carried(compiled, pool_shards)
