@@ -21,6 +21,19 @@ and is printed. Weights and requests come from ``--seed``.
          tokens: the reference logit of every emitted token must be within
          the printed tolerance of the reference maximum at its position.
 
+  mla    DeepSeek-V2-Lite at its published widths and the benchmark
+         configuration's depth (``perfbench/configs/deepseek-v2-lite-serve
+         .json``), a ``ServingEngine`` of 32,768-column slots: prompts of
+         24,576, 8,192 and 2,048 seeded tokens are prefilled (materialised
+         attention through the flash kernel) and 32 tokens decoded through
+         the paged LATENT cache (absorbed attention through the paged latent
+         kernel). Against ``perfbench/references/deepseek_v2.py`` (float32,
+         ``highest``, materialised form, no cache, the whole context in query
+         blocks): the system's prefill logits at every position of the two
+         shorter prompts and at the last 256 of the longest, and the
+         reference's logit of every decoded token. ``--only mla`` runs this
+         phase alone.
+
 ``--chips 4`` runs only the four-chip path and what it is compared with: a
 tp=4 + sequence-parallel train step against the same seeded step on one
 device of the same process, and ``ServingEngine(tp=4)`` against the
@@ -315,6 +328,35 @@ def tp_train_phase(size: TrainSize, seed: int, devices) -> Dict[str, bool]:
 
 
 # --- serve ---------------------------------------------------------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class MlaSize:
+    """What the MLA phase runs (defaults: the chip run, the published
+    widths at the benchmark configuration's depth)."""
+
+    model: object = None          # published config.json keys; None = the benchmark configuration's
+    max_seq_len: int = 32768
+    slots: int = 4
+    prompt_lens: Tuple[int, ...] = (24576, 8192, 2048)
+    tail: int = 256               # positions of the longest prompt compared in prefill logits
+    new_tokens: int = 32
+    # How far the system's prefill logits may lie from the reference's, bf16
+    # serving against float32, over the positions compared (the largest
+    # |difference| over the vocabulary at each). ``logit_tol`` bounds the
+    # worst position, ``typical_tol`` the median one: a lower precision
+    # moves EVERY position, so the median is where it shows first. The
+    # phase proves on the chip that the pair can fail: the reference with
+    # its latent and rotated key rounded to float8 (the nearest precision
+    # below the configuration's bfloat16), and with one rope channel
+    # dropped, must each lie outside them (``mla_*_is_caught``). Readings
+    # in PERF.md section 6. Routing flips matter little here (the sixth and
+    # seventh expert weigh ~0.03 each beside two shared experts), so no
+    # position is excused for a router near-tie.
+    logit_tol: float = 0.5
+    typical_tol: float = 0.08
+    gap_tol: float = 0.1          # a decoded token's reference-logit gap, as ServeSize.logit_tol
+    near_tie: float = 0.05        # ... and its excuse, as the benchmark's own check (judge_gaps)
 
 
 def _prompts(lens: Sequence[int], vocab: int, seed: int):
@@ -670,10 +712,129 @@ TP_SERVE = ServeSize(
 )
 
 
+# --- multi-head latent attention ------------------------------------------------------
+
+
+def mla_phase(size: MlaSize, seed: int) -> Dict[str, bool]:
+    """DeepSeek-V2-Lite through the default engine (module docstring)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from flax.core import meta
+
+    from neuronx_distributed_tpu.inference.generate import serving_clones
+    from neuronx_distributed_tpu.parallel import mesh as mesh_lib
+    from neuronx_distributed_tpu.serving import ServingEngine
+    from perfbench.families import deepseek_v2 as family
+    from perfbench.references import common
+    from perfbench.references.deepseek_v2 import Reference
+
+    mesh_lib.destroy_model_parallel()
+    published = size.model
+    if published is None:
+        path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                            "perfbench", "configs", "deepseek-v2-lite-serve.json")
+        with open(path) as f:
+            published = json.load(f)["model"]
+    model = family.build(published, runner="serve", max_seq_len=size.max_seq_len)
+    if size.model is not None:    # the CPU rehearsal serves in float32
+        model = model.clone(config=dataclasses.replace(model.config, dtype=jnp.float32))
+    params = jax.jit(model.init)(jax.random.PRNGKey(seed), jnp.zeros((1, 8), jnp.int32))
+    jax.block_until_ready(params)
+    engine = ServingEngine(model, params, num_slots=size.slots, kv_page_size=16)
+    prompts = _prompts(size.prompt_lens, int(published["vocab_size"]), seed + 7)
+    reqs, wall = _serve(engine, prompts, size.new_tokens, seed)
+    resolved = dict(engine.programs.resolved)
+    kernels = _ledger_kernels(engine.programs, _hot_programs(engine))
+    per_token = engine.metrics.snapshot()["kv_bytes_per_token_layer"]
+    log(f"mla: {len(reqs)} requests, prompts {list(size.prompt_lens)} + {size.new_tokens} tokens in "
+        f"{wall:.1f}s; resolved {resolved}; {KERNEL} in compiled programs: {kernels}; cache "
+        f"{per_token:g} B a token a layer, pool {engine.cache.nbytes / 2**30:.2f} GiB")
+    engine.cache.check()
+    engine = None
+    gc.collect()
+
+    prefill, _ = serving_clones(model)
+
+    @jax.jit
+    def prefill_rows(params, ids, lo):
+        logits = prefill.apply(params, ids, mutable=["cache"])[0][0]
+        return jax.lax.dynamic_slice_in_dim(logits[0], lo, min(size.tail, ids.shape[1]), axis=0)
+
+    ref = Reference(published, meta.unbox(params))
+    dtype = jnp.dtype(model.config.dtype).itemsize
+    want_bytes = (int(published["kv_lora_rank"]) + int(published["qk_rope_head_dim"])) * dtype
+    worst_gap, worst_diff, worst_median, ok, caught = 0.0, 0.0, 0.0, True, {}
+    for prompt, req in zip(prompts, reqs):
+        p, n = len(prompt), len(req.tokens)
+        ids = np.concatenate([prompt, np.asarray(req.tokens, np.int32)])[None]
+        hidden, margins = ref._hidden(ids)            # the whole context, once
+        head = lambda rows: np.asarray(ref._head(      # noqa: E731
+            ref.p["model"]["final_norm"], ref.p["lm_head"], hidden[:, rows])[0], np.float32)
+        # decoded tokens: token i was sampled from the logits at position p - 1 + i
+        rows = head(np.arange(p - 1, p - 1 + n))
+        toks = np.asarray(req.tokens)
+        gaps = rows.max(1) - rows[np.arange(n), toks]
+        wrong = rows.max(1) - rows[np.arange(n), (toks + 1) % rows.shape[1]]
+        router = np.asarray(margins[0, p - 1:p - 1 + n])
+        fine, over, excused = common.judge_gaps(gaps, router, size.gap_tol, size.near_tie)
+        ok = ok and fine and wrong.min() > size.gap_tol
+        worst_gap = max(worst_gap, float(gaps[router >= size.near_tie].max(initial=0.0)))
+        # prefill logits: every position of the shorter prompts, the tail of the longest
+        starts = range(0, p, size.tail) if p < max(size.prompt_lens) else [p - size.tail]
+        blocks = [max(min(lo, p - size.tail), 0) for lo in starts]
+        mine = [np.asarray(prefill_rows(params, prompt[None], lo), np.float32) for lo in blocks]
+        theirs = [head(np.arange(lo, lo + m.shape[0])) for lo, m in zip(blocks, mine)]
+        diffs = np.concatenate([np.abs(m - t).max(1) for m, t in zip(mine, theirs)])
+        median, worst = float(np.median(diffs)), float(diffs.max())
+        ok = ok and worst <= size.logit_tol and median <= size.typical_tol
+        worst_diff, worst_median = max(worst_diff, worst), max(worst_median, median)
+        log(f"mla: prompt {p}: decoded tokens' largest reference-logit gap {gaps.max():.4f} ({over} of {n} "
+            f"fail {size.gap_tol:g}, {excused} excused by a router margin under {size.near_tie:g}; wrong "
+            f"tokens' smallest gap {wrong.min():.3f}); prefill logits at {len(diffs)} positions, |system - "
+            f"reference|: median {median:.4f}, 99th percentile {np.percentile(diffs, 99):.4f}, largest {worst:.4f}")
+        if p == min(size.prompt_lens):
+            # the comparison must be able to fail: the reference itself, a
+            # precision lower and a mechanism short, against the plain one
+            broken = meta.unbox(params)
+            broken = jax.tree_util.tree_map_with_path(
+                lambda path, a: a.at[:, -1].set(0) if "kv_a_proj" in str(path) else a, broken)
+            for name, other in (("float8 latent", Reference(published, meta.unbox(params), jnp.float8_e4m3fn)),
+                                ("dropped rope channel", Reference(published, broken))):
+                h2, _ = other._hidden(ids)
+                rows2 = [np.asarray(other._head(other.p["model"]["final_norm"], other.p["lm_head"],
+                                                h2[:, lo:lo + m.shape[0]])[0], np.float32)
+                         for lo, m in zip(blocks, mine)]
+                d2 = np.concatenate([np.abs(r - t).max(1) for r, t in zip(rows2, theirs)])
+                caught[name] = float(d2.max()) > size.logit_tol or float(np.median(d2)) > size.typical_tol
+                log(f"mla: control, the reference with a {name} against the plain reference at {len(d2)} "
+                    f"positions: median {np.median(d2):.4f}, 99th percentile {np.percentile(d2, 99):.4f}, largest "
+                    f"{d2.max():.4f}: {'outside' if caught[name] else 'INSIDE'} the tolerances")
+    log(f"mla: prefill logits against the reference: largest difference {worst_diff:.4f} (tolerance "
+        f"{size.logit_tol:g}), largest median {worst_median:.4f} ({size.typical_tol:g}); largest decoded-token gap "
+        f"outside router near-ties {worst_gap:.4f} ({size.gap_tol:g})")
+    return {
+        "mla_matches_reference": ok,
+        "mla_resolved_latent_fused": resolved == {
+            "attention": "flash", "decode_attention": "paged_latent_fused",
+            "paged_attention": "fused",
+        },
+        "mla_cache_is_latent_sized": per_token == want_bytes,
+        "mla_float8_latent_is_caught": caught.get("float8 latent", False),
+        "mla_dropped_rope_channel_is_caught": caught.get("dropped rope channel", False),
+        "kernel_mla_programs": all(kernels.values()) and bool(kernels),
+    }
+
+
 def one_chip(seed: int, devices, train: TrainSize = TrainSize(),
-             serve: ServeSize = ServeSize()) -> Dict[str, bool]:
-    """The default run: train, then serve, in one process on one device."""
-    return {**train_phase(train, seed, devices), **serve_phase(serve, seed)}
+             serve: ServeSize = ServeSize(), mla: MlaSize = MlaSize(),
+             only: str = "all") -> Dict[str, bool]:
+    """The default run: train, then serve, then the MLA model, in one
+    process on one device; ``only="mla"``: that phase alone."""
+    if only == "mla":
+        return mla_phase(mla, seed)
+    return {**train_phase(train, seed, devices), **serve_phase(serve, seed),
+            **mla_phase(mla, seed)}
 
 
 def four_chips(seed: int, devices, train: TrainSize = TrainSize(),
@@ -692,6 +853,8 @@ def parse_args(argv=None):
                         "tp=4 train step and tp=4 engine and their "
                         "one-device counterparts")
     p.add_argument("--seed", type=int, default=0)
+    p.add_argument("--only", default="all", choices=("all", "mla"),
+                   help="one chip: every phase (default) or the MLA phase alone")
     return p.parse_args(argv)
 
 
@@ -725,8 +888,10 @@ def main(argv=None) -> int:
     }
     log(f"device {device}, jax {jax.__version__}, compile cache {cache}")
     t0 = time.perf_counter()
-    run = one_chip if args.chips == 1 else four_chips
-    checks = run(args.seed, devices)
+    if args.chips == 1:
+        checks = one_chip(args.seed, devices, only=args.only)
+    else:
+        checks = four_chips(args.seed, devices)
     failed = sorted(name for name, ok in checks.items() if not ok)
     log(
         f"{len(checks) - len(failed)}/{len(checks)} checks passed in "

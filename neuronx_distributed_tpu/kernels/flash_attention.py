@@ -204,6 +204,7 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int, interpret: boo
     equal-segment mask (packed documents / padding)."""
     b, h, s, d = q.shape
     hkv, sk = k.shape[1], k.shape[2]
+    dv = v.shape[3]  # the value head size may differ from q/k's (MLA: 192/128)
     group = h // hkv
     nq, nk = s // block_q, sk // block_k
     scale = 1.0 / (d ** 0.5)
@@ -227,20 +228,20 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int, interpret: boo
             ),
             pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
             pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, i, j: (b_, h_ // group, j, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, i, j: (b_, h_ // group, j, 0)),
+            pl.BlockSpec((1, 1, block_k, dv), lambda b_, h_, i, j: (b_, h_ // group, j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
+            pl.BlockSpec((1, 1, block_q, dv), lambda b_, h_, i, j: (b_, h_, i, 0)),
             pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, i, j: (b_, h_, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
+            jax.ShapeDtypeStruct((b, h, s, dv), q.dtype),
             jax.ShapeDtypeStruct((b, h, s, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
@@ -392,6 +393,7 @@ def _flash_dkdv(q, k, v, g, lse, delta, causal, block_q, block_k, interpret,
                 q_off=None, k_off=None, q_seg=None, k_seg=None):
     b, h, s, d = q.shape
     hkv, sk = k.shape[1], k.shape[2]
+    dv = v.shape[3]
     group = h // hkv
     nq, nk = s // block_q, sk // block_k
     scale = 1.0 / (d ** 0.5)
@@ -421,14 +423,14 @@ def _flash_dkdv(q, k, v, g, lse, delta, causal, block_q, block_k, interpret,
             ),
             pl.BlockSpec((1, 1, block_q, d), qmap),  # q
             pl.BlockSpec((1, 1, block_k, d), kmap),  # k
-            pl.BlockSpec((1, 1, block_k, d), kmap),  # v
-            pl.BlockSpec((1, 1, block_q, d), qmap),  # do
+            pl.BlockSpec((1, 1, block_k, dv), kmap),  # v
+            pl.BlockSpec((1, 1, block_q, dv), qmap),  # do
             pl.BlockSpec((1, 1, block_q, 1), qmap),  # lse
             pl.BlockSpec((1, 1, block_q, 1), qmap),  # delta
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_k, d), kmap),
-            pl.BlockSpec((1, 1, block_k, d), kmap),
+            pl.BlockSpec((1, 1, block_k, dv), kmap),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(k.shape, k.dtype),
@@ -436,7 +438,7 @@ def _flash_dkdv(q, k, v, g, lse, delta, causal, block_q, block_k, interpret,
         ],
         scratch_shapes=[
             pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
@@ -460,8 +462,11 @@ def _flash_dq(q, k, v, g, lse, delta, causal, block_q, block_k, interpret,
     scale = 1.0 / (d ** 0.5)
     dyn = q_off is not None or k_off is not None
     segments = q_seg is not None
+    dv = v.shape[3]
     qspec = pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, x, y: (b_, h_, x, 0))
     kspec = pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, x, y: (b_, h_ // group, y, 0))
+    vspec = pl.BlockSpec((1, 1, block_k, dv), lambda b_, h_, x, y: (b_, h_ // group, y, 0))
+    dospec = pl.BlockSpec((1, 1, block_q, dv), lambda b_, h_, x, y: (b_, h_, x, 0))
     rowspec = pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, x, y: (b_, h_, x, 0))
     dq = pl.pallas_call(
         functools.partial(
@@ -478,7 +483,7 @@ def _flash_dq(q, k, v, g, lse, delta, causal, block_q, block_k, interpret,
                 lambda b_, h_, x, y: (b_, x),
                 lambda b_, h_, x, y: (b_, y),
             ),
-            qspec, kspec, kspec, qspec, rowspec, rowspec,
+            qspec, kspec, vspec, dospec, rowspec, rowspec,
         ],
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),

@@ -686,3 +686,217 @@ def paged_flash_decode_attention(
     return jnp.swapaxes(
         out.reshape(b, hkv, group, s, d).reshape(b, h, s, d), 1, 2
     ).astype(q.dtype)
+
+
+# --- paged LATENT decode (MLA, absorbed form) -----------------------------------
+#
+# Multi-head latent attention caches, per token, ONE latent row (``d_c``
+# values, 512) and ONE rotated key (``d_r`` values, 64), shared by every
+# head. In the absorbed form the ``H`` query heads (``W_uk`` folded into
+# them) all score against that one row, ``q_c . c + q_r . k_r``, and the
+# values are the latent row itself. So a page of the latent pool is read from
+# HBM ONCE and serves the scores and the values of all heads: the block that
+# went through ``q k^T`` goes through ``p v`` from VMEM.
+#
+# The two pool leaves are the ``k`` and ``k_pe`` leaves of a one-head cache,
+# ``(P, page_size, 1, d)``; the kernel sees them without the unit head axis,
+# so that a page block's last two dims are ``(page_size, d)``. Two leaves
+# and not one of 576: a bf16 array whose minor dim is not a multiple of 128
+# is laid out by XLA with another dim minor (the PAGE index, for a pool), and
+# no kernel can stream pages out of that; 512 keeps the latent, eight ninths
+# of the bytes, in whole tiles.
+#
+# One grid step takes ``G`` pages (``G`` BlockSpecs on each pool operand,
+# each with its own block-table lookup): at 16 tokens a page, a step of one
+# page is a 16-column matmul and a third of a microsecond of step overhead
+# for 18 KB of cache. Steps past the shared cursor, and steps whose pages are
+# all unmapped (block table 0: a slot whose context is shorter than the
+# cursor), neither compute nor fetch: their index maps repeat the block the
+# pipeline already holds.
+
+
+def _latent_group(n_log: int, preferred: int = 16) -> int:
+    g = min(preferred, n_log)
+    while n_log % g != 0:
+        g -= 1
+    return g
+
+
+def _paged_latent_kernel(bt_ref, bound_ref, pos_ref, valid_ref, qc_ref,
+                         qr_ref, *rest, page_size, group, num_steps, scale,
+                         use_valid):
+    c_refs, r_refs = rest[:group], rest[group:2 * group]
+    o_ref, m_scr, l_scr, acc_scr = rest[2 * group:]
+    b = pl.program_id(0)
+    j = pl.program_id(1)  # group of logical pages (sequential)
+    block = group * page_size
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    mapped = bt_ref[b, j * group] != 0
+    for g in range(1, group):
+        mapped = mapped | (bt_ref[b, j * group + g] != 0)
+    run = (j * block < bound_ref[0]) & mapped
+
+    @pl.when(run)
+    def _body():
+        # operands stay in their storage type (bf16 on the chip: the MXU's
+        # own), accumulation is float32
+        c = jnp.concatenate([r[0] for r in c_refs], axis=0)    # (G*ps, d_c)
+        kr = jnp.concatenate([r[0] for r in r_refs], axis=0)   # (G*ps, d_r)
+        dims = (((1,), (1,)), ((), ()))
+        s = (
+            jax.lax.dot_general(qc_ref[0], c, dims,
+                                preferred_element_type=jnp.float32)
+            + jax.lax.dot_general(qr_ref[0], kr, dims,
+                                  preferred_element_type=jnp.float32)
+        ) * scale                                      # (R, G*ps)
+        rows = pos_ref[0, :][:, None]                  # (R, 1) slot positions
+        cols = (
+            jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + j * block
+        )
+        s = jnp.where(rows >= cols, s, NEG_INF)
+        if use_valid:
+            s = jnp.where(valid_ref[0, 0] != 0, s, NEG_INF)  # (1, G*ps)
+        m_prev = m_scr[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        ref = jnp.where(m_new > NEG_INF / 2, m_new, 0.0)
+        p = jnp.exp(s - ref)
+        alpha = jnp.exp(m_prev - ref)
+        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        # the values are the latent block already in VMEM
+        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+            p.astype(c.dtype), c, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_scr[:] = m_new
+
+    @pl.when(j == num_steps - 1)
+    def _finish():
+        o_ref[0] = (
+            acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)
+        ).astype(o_ref.dtype)
+
+
+def _paged_latent_decode_call(qc, qr, c_pool, r_pool, block_table, rows_pos,
+                              kv_valid, page_size, scale, interpret):
+    """qc (B, R, d_c), qr (B, R, d_r) absorbed query rows; pools (P,
+    page_size, d_c) and (P, page_size, d_r); block_table (B, n_log);
+    rows_pos (R,); kv_valid (B, L) or None. Returns (B, R, d_c)."""
+    b, r, d_c = qc.shape
+    d_r = qr.shape[2]
+    n_log = block_table.shape[1]
+    group = _latent_group(n_log)
+    steps = n_log // group
+    block = group * page_size
+    use_valid = kv_valid is not None
+    if kv_valid is None:
+        kv_valid = jnp.zeros((1, 1), jnp.int32)
+        vspec = _SMEM_SPEC
+    else:
+        kv_valid, vspec = _valid_tiles(
+            kv_valid, block, lambda b_, j, bt, bound: (b_, j, 0, 0)
+        )
+
+    def page_spec(g, d):
+        def index(b_, j, bt, bound):
+            # past the cursor nothing is fetched: repeat the last block held
+            last = jnp.maximum(bound[0] - 1, 0) // block
+            return (bt[b_, jnp.minimum(j, last) * group + g], 0, 0)
+
+        return pl.BlockSpec((1, page_size, d), index)
+
+    def rows_spec(d):
+        return pl.BlockSpec((1, r, d), lambda b_, j, bt, bound: (b_, 0, 0))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,  # block table and cursor bound: the index maps read both
+        grid=(b, steps),
+        in_specs=[
+            pl.BlockSpec((1, r), lambda b_, j, bt, bound: (0, 0)),      # pos
+            vspec,                                                       # kv_valid
+            rows_spec(d_c), rows_spec(d_r),
+            *[page_spec(g, d_c) for g in range(group)],
+            *[page_spec(g, d_r) for g in range(group)],
+        ],
+        out_specs=rows_spec(d_c),
+        scratch_shapes=[
+            pltpu.VMEM((r, 1), jnp.float32),
+            pltpu.VMEM((r, 1), jnp.float32),
+            pltpu.VMEM((r, d_c), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(
+            _paged_latent_kernel, page_size=page_size, group=group,
+            num_steps=steps, scale=scale, use_valid=use_valid,
+        ),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, r, d_c), qc.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+        ),
+        interpret=interpret,
+    )(
+        block_table.astype(jnp.int32),
+        jnp.asarray(jnp.max(rows_pos) + 1, jnp.int32).reshape((1,)),
+        rows_pos.reshape(1, r),
+        kv_valid,
+        qc, qr, *([c_pool] * group), *([r_pool] * group),
+    )
+
+
+def paged_latent_decode_attention(
+    q_c: jax.Array,
+    q_r: jax.Array,
+    c_pool: jax.Array,
+    r_pool: jax.Array,
+    block_table: jax.Array,
+    q_pos: jax.Array,
+    kv_valid: Optional[jax.Array] = None,
+    *,
+    scale: float,
+    page_size: int = 16,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """Absorbed multi-head latent attention straight off the page pool.
+    ``q_c`` (B, S, H, d_c): the query heads with ``W_uk`` folded in; ``q_r``
+    (B, S, H, d_r): their rotated part; rows at slot positions ``q_pos``
+    (S,). ``c_pool`` (P, page_size, 1, d_c) holds each token's ONE latent row,
+    ``r_pool`` (P, page_size, 1, d_r) its one rotated key. Scores ``(q_c . c +
+    q_r . k_r) * scale``, values the latent row. Returns (B, S, H, d_c), to
+    be taken through ``W_uv`` by the caller. Block table and ``kv_valid`` as
+    :func:`paged_flash_decode_attention`; like it, this is the kernel or
+    nothing (interpreted only in tests).
+
+    No mesh: the latent row has no head axis to shard, and the serving
+    engine refuses tensor parallelism for a latent-cache model."""
+    from neuronx_distributed_tpu.parallel import mesh as mesh_lib
+
+    if mesh_lib.model_parallel_is_initialized():
+        raise NotImplementedError(
+            "paged latent decode attention has no sharded form: run a "
+            "latent-cache (MLA) model without a model-parallel mesh"
+        )
+    b, s, h, d_c = q_c.shape
+    d_r = q_r.shape[3]
+    for pool, d in ((c_pool, d_c), (r_pool, d_r)):
+        if pool.ndim != 4 or pool.shape[2] != 1 or pool.shape[3] != d:
+            raise ValueError(
+                f"latent pool leaf must be (P, page_size, 1, {d}), "
+                f"got {pool.shape}"
+            )
+    # (B, S, H, d) -> (B, R = H*S, d), head-major like the GQA group fold
+    fold = lambda q: jnp.swapaxes(q, 1, 2).reshape(b, h * s, q.shape[3])  # noqa: E731
+    flat = lambda pool: pool.reshape(pool.shape[:2] + pool.shape[3:])     # noqa: E731
+    q_pos = q_pos[None] if q_pos.ndim == 0 else q_pos
+    rows_pos = jnp.tile(q_pos.astype(jnp.int32), (h,))  # (R,)
+    out = _paged_latent_decode_call(
+        fold(q_c), fold(q_r), flat(c_pool), flat(r_pool), block_table,
+        rows_pos, kv_valid, page_size, scale, interpret_mode(interpret),
+    )
+    return jnp.swapaxes(out.reshape(b, h, s, d_c), 1, 2).astype(q_c.dtype)

@@ -39,6 +39,13 @@ from neuronx_distributed_tpu.models.mixtral import (
     mixtral_8x7b,
     tiny_mixtral,
 )
+from neuronx_distributed_tpu.models.deepseek_v2 import (
+    DeepseekV2Config,
+    DeepseekV2ForCausalLM,
+    DeepseekV2Model,
+    deepseek_v2_lite,
+    tiny_deepseek_v2,
+)
 from neuronx_distributed_tpu.models.vit import (
     ViTConfig,
     ViTForImageClassification,
@@ -56,4 +63,6 @@ __all__ = [
     "DbrxConfig", "DbrxForCausalLM", "dbrx_base", "tiny_dbrx",
     "ViTConfig", "ViTForImageClassification", "vit_base_patch16", "tiny_vit",
     "CodeGenConfig", "CodeGenForCausalLM", "codegen25_7b", "tiny_codegen",
+    "DeepseekV2Config", "DeepseekV2ForCausalLM", "DeepseekV2Model",
+    "deepseek_v2_lite", "tiny_deepseek_v2",
 ]

@@ -73,7 +73,7 @@ def xla_attention(q, k, v, causal: bool = True, mask: Optional[jax.Array] = None
         scores = jnp.where(smask[:, None, None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v.astype(jnp.float32))
-    return out.reshape(b, sq, h, d).astype(q.dtype)
+    return out.reshape(b, sq, h, v.shape[3]).astype(q.dtype)
 
 
 def attention_op(q, k, v, causal: bool = True, impl: str = "auto",
@@ -201,17 +201,24 @@ class KVCache:
     Variables: ``k``/``v`` (B, L, Hkv, D), ``index`` () int32 write cursor,
     ``kv_valid`` (B, L) bool — prefill records the padding mask, decode
     appends per-step validity, so padded prompt slots stay masked for the
-    whole generation without the caller re-supplying the mask."""
+    whole generation without the caller re-supplying the mask.
 
-    def __init__(self, module, b, max_seq_len, hkv, d, dtype):
+    ``leaves`` names the per-token storage leaves and their ``(heads,
+    width)``; the writes take one array per leaf, in that order.
+    :class:`LatentKVCache` is the other kind."""
+
+    def __init__(self, module, b, max_seq_len, hkv, d, dtype, leaves=None):
         self.max_seq_len = max_seq_len
         self.b = b
-        self.k = module.variable(
-            "cache", "k", jnp.zeros, (b, max_seq_len, hkv, d), dtype
+        leaves = leaves or {"k": (hkv, d), "v": (hkv, d)}
+        self.leaves = tuple(
+            module.variable(
+                "cache", name, jnp.zeros, (b, max_seq_len, h, w), dtype
+            )
+            for name, (h, w) in leaves.items()
         )
-        self.v = module.variable(
-            "cache", "v", jnp.zeros, (b, max_seq_len, hkv, d), dtype
-        )
+        for name, leaf in zip(leaves, self.leaves):
+            setattr(self, name, leaf)  # .k/.v, or a latent cache's .k/.k_pe
         self.index = module.variable(
             "cache", "index", lambda: jnp.zeros((), jnp.int32)
         )
@@ -222,8 +229,8 @@ class KVCache:
     def prefill_write(self, k, v, padding_mask=None):
         """Write the prompt K/V at slot 0 and record its validity."""
         b, s = k.shape[0], k.shape[1]
-        self.k.value = jax.lax.dynamic_update_slice(self.k.value, k, (0, 0, 0, 0))
-        self.v.value = jax.lax.dynamic_update_slice(self.v.value, v, (0, 0, 0, 0))
+        for leaf, new in zip(self.leaves, (k, v)):
+            leaf.value = jax.lax.dynamic_update_slice(leaf.value, new, (0, 0, 0, 0))
         self.index.value = jnp.asarray(s, jnp.int32)
         valid = (
             padding_mask.astype(jnp.bool_)
@@ -253,8 +260,8 @@ class KVCache:
         rows pass False so their filler tokens never become attendable)."""
         b, s = k.shape[0], k.shape[1]
         cur = self.index.value
-        self.k.value = jax.lax.dynamic_update_slice(self.k.value, k, (0, cur, 0, 0))
-        self.v.value = jax.lax.dynamic_update_slice(self.v.value, v, (0, cur, 0, 0))
+        for leaf, new in zip(self.leaves, (k, v)):
+            leaf.value = jax.lax.dynamic_update_slice(leaf.value, new, (0, cur, 0, 0))
         self.index.value = cur + s
         if padding_mask is not None:
             if padding_mask.shape != (b, s):
@@ -269,13 +276,43 @@ class KVCache:
         self.valid.value = jax.lax.dynamic_update_slice(self.valid.value, new_valid, (0, cur))
 
 
+class LatentKVCache(KVCache):
+    """The cache of multi-head LATENT attention (MLA): per token ONE latent
+    row ``c`` of ``d_latent`` values (after its RMSNorm) and ONE rotated key
+    ``k_pe`` of ``d_rope`` values, both shared by every head, and nothing
+    per head: 512 + 64 = 576 values a token a layer at DeepSeek-V2's widths,
+    against ``H * (192 + 128)`` for materialised keys and values.
+
+    Leaves: ``k`` (B, L, 1, d_latent), the latent row, which in the absorbed
+    form is the key's content part AND the value; ``k_pe`` (B, L, 1,
+    d_rope). They are one-head leaves of the contract above, so every walker
+    of the cache tree (:data:`PAGED_LEAVES`) handles them as it handles
+    ``k``/``v``. Two leaves and not one of 576: see
+    ``kernels/flash_decode.py`` (a bf16 leaf whose width is no multiple of
+    128 gets a layout no kernel can stream pages from). The writes take
+    ``(c, k_pe)`` where :class:`KVCache`'s take ``(k, v)``."""
+
+    def __init__(self, module, b, max_seq_len, d_latent, d_rope, dtype):
+        super().__init__(
+            module, b, max_seq_len, 1, d_latent, dtype,
+            leaves={"k": (1, d_latent), "k_pe": (1, d_rope)},
+        )
+
+
 # --- cache-collection slot helpers (serving) ----------------------------------
 #
 # The continuous-batching engine (serving/) owns ONE cache collection whose
 # batch rows are request SLOTS. These helpers operate on the raw collection
 # tree (outside a flax apply), classified by leaf name — the same contract
 # KVCache declares: k/v (..., B, L, Hkv, D), kv_valid (..., B, L), index
-# scalar cursor (nn.scan stacks a leading layer axis on each).
+# scalar cursor (nn.scan stacks a leading layer axis on each); a latent
+# cache's per-token leaves are k/k_pe (..., B, L, 1, d).
+
+# THE names of the per-token storage leaves, (..., B, L, heads, width): what a
+# page pool pages, a prefix block copies and a fingerprint hashes. Every
+# walker of a cache tree classifies by this tuple.
+PAGED_LEAVES = ("k", "v", "k_pe")
+
 
 def cache_leaf_name(path) -> str:
     """Terminal key of a cache-collection tree path (DictKey or str)."""
@@ -287,7 +324,7 @@ def cache_batch_axis(name: str, ndim: int):
     """Batch(slot)-axis index of a cache leaf, or None for the shared
     ``index`` cursor. Leading layer axes from nn.scan stacking shift the
     batch axis right, so classify from the TRAILING dims."""
-    if name in ("k", "v"):
+    if name in PAGED_LEAVES:
         return ndim - 4
     if name == "kv_valid":
         return ndim - 2
@@ -308,6 +345,33 @@ def reset_cache_slot(cache, slot):
         return jax.lax.dynamic_update_slice_in_dim(leaf, zero, slot, ax)
 
     return jax.tree_util.tree_map_with_path(fn, cache)
+
+
+def cache_bytes_per_token_layer(cache) -> float:
+    """Bytes ONE token holds in ONE attention layer of a cache tree (a row
+    collection or a paged ``{"pages", "pool"}`` pytree), from the allocated
+    per-token storage leaves (:data:`PAGED_LEAVES`; a quantized pool's scale
+    siblings count at their share of a page), averaged over the layers:
+    ``2 * Hkv * D * itemsize`` for a ``k``/``v`` cache, ``(d_latent + d_rope)
+    * itemsize`` for a latent one (1152 at DeepSeek-V2's widths in bf16;
+    twice the latent would read 2176)."""
+    import math
+
+    tree = cache["pool"] if isinstance(cache, dict) and "pool" in cache else cache
+    total, layers = 0.0, set()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
+        name = cache_leaf_name(path)
+        base = pool_scale_base(name)
+        if base is None and name not in PAGED_LEAVES:
+            continue
+        lead = math.prod(leaf.shape[:-4])
+        per_token = math.prod(leaf.shape[-2:]) * leaf.dtype.itemsize
+        if base is not None:  # (..., P, 1, Hkv, 1): one scale a page
+            per_token /= cache_node_at(tree, path[:-1])[base].shape[-3]
+        total += lead * per_token
+        layers.add((tuple(str(k) for k in path[:-1]), lead))
+    n_layers = sum(lead for _, lead in layers)
+    return total / n_layers if n_layers else 0.0
 
 
 def cache_cursor(cache):
@@ -452,7 +516,6 @@ def invalidate_cache_window(cache, start, keep):
     return jax.tree_util.tree_map_with_path(fn, cache)
 
 
-_PAGED_LEAVES = ("k", "v")
 _SCALE_SUFFIX = "_scale"  # quantized-pool sibling leaves: k_scale / v_scale
 
 
@@ -473,7 +536,7 @@ def pool_scale_base(name: str):
     naming rule every pool walker classifies by."""
     if name.endswith(_SCALE_SUFFIX):
         base = name[: -len(_SCALE_SUFFIX)]
-        if base in _PAGED_LEAVES:
+        if base in PAGED_LEAVES:
             return base
     return None
 
@@ -531,7 +594,7 @@ def gather_cache_pages(paged, page_size: int):
         name = keys[-1]
         if pool_scale_base(name) is not None:
             continue  # transport metadata — dropped from the logical view
-        if name in _PAGED_LEAVES:
+        if name in PAGED_LEAVES:
             scale = pool_scale_sibling(pool, path, name)
             with jax.named_scope(KV_VIEW_SCOPE):
                 leaf = (
@@ -576,7 +639,7 @@ def scatter_cache_window(paged, logical, page_size: int, start_col,
     def fn(path, pool_leaf):
         name = cache_leaf_name(path)
         base = pool_scale_base(name) or name
-        if base not in _PAGED_LEAVES:
+        if base not in PAGED_LEAVES:
             # index / kv_valid: logical IS the storage
             return cache_node_at(logical, path[:-1])[name]
         lg = cache_node_at(logical, path[:-1])[base]
@@ -670,13 +733,14 @@ def ordered_kv_pool_pairs(pool):
     """``{layer: (k, v)}``: every attention layer's pool leaf pair under the
     layer's tree path (its key tuple), in model execution order
     (:func:`_execution_order`) — what the fused chunk carries through its
-    scan."""
+    scan. A latent-cache layer's pair is ``(k, k_pe)``: the leaves a layer
+    holds, in :data:`PAGED_LEAVES` order."""
     from neuronx_distributed_tpu.utils.tree import path_keys
 
     nodes = {}
     for path, leaf in jax.tree_util.tree_flatten_with_path(pool)[0]:
         keys = tuple(path_keys(path))
-        if keys[-1] in _PAGED_LEAVES:
+        if keys[-1] in PAGED_LEAVES:
             nodes.setdefault(keys[:-1], {})[keys[-1]] = leaf
         elif pool_scale_base(keys[-1]) is not None:
             raise ValueError(
@@ -685,7 +749,9 @@ def ordered_kv_pool_pairs(pool):
                 "transport with kv_quant"
             )
     return {
-        layer: tuple(nodes[layer][name] for name in _PAGED_LEAVES)
+        layer: tuple(
+            nodes[layer][name] for name in PAGED_LEAVES if name in nodes[layer]
+        )
         for layer in _execution_order(nodes)
     }
 
@@ -700,8 +766,9 @@ def adopt_kv_pool_pairs(paged, logical, pairs):
 
     def fn(path, _):
         *layer, name = path_keys(path)
-        if name in _PAGED_LEAVES:
-            return pairs[tuple(layer)][_PAGED_LEAVES.index(name)]
+        if name in PAGED_LEAVES:
+            held = [n for n in PAGED_LEAVES if n in cache_node_at(paged["pool"], layer)]
+            return pairs[tuple(layer)][held.index(name)]
         return cache_node_at(logical, path)
 
     return {
@@ -710,16 +777,19 @@ def adopt_kv_pool_pairs(paged, logical, pairs):
     }
 
 
-def _fused_paged_decode(frame, q, k_cache, v_cache, q_pos, kv_valid):
+def _fused_paged_decode(frame, q, caches, q_pos, kv_valid, latent_scale=None):
+    """``caches``: the layer's logical leaves as its cache kind orders them,
+    ``(k, v)`` or a latent cache's ``(c, k_pe)``; for the latter ``q`` is the
+    absorbed pair ``(q_c, q_r)`` and ``latent_scale`` the softmax scale."""
     from neuronx_distributed_tpu.kernels.flash_decode import (
         paged_flash_decode_attention,
+        paged_latent_decode_attention,
         paged_scatter_window_leaf,
     )
 
     pools, order = frame["pools"], frame["order"]
     layer = order[frame["idx"] % len(order)]
     frame["idx"] += 1
-    k_pool, v_pool = pools[layer]
     ps, bt = frame["page_size"], frame["tables"]
     # bring the pool current through THIS step: scatter the chunk window
     # from the logical view (which the model just wrote) into the carried
@@ -729,15 +799,20 @@ def _fused_paged_decode(frame, q, k_cache, v_cache, q_pos, kv_valid):
     # the scope ends BEFORE the kernel: a Pallas kernel is named after the
     # scope it is called in, and trace readers find it by that name
     with jax.named_scope(KV_VIEW_SCOPE):
-        k_pool = paged_scatter_window_leaf(
-            k_pool, k_cache, bt, frame["page0"], frame["n_win"], ps
+        pair = tuple(
+            paged_scatter_window_leaf(
+                pool, cache, bt, frame["page0"], frame["n_win"], ps
+            )
+            for pool, cache in zip(pools[layer], caches)
         )
-        v_pool = paged_scatter_window_leaf(
-            v_pool, v_cache, bt, frame["page0"], frame["n_win"], ps
+    pools[layer] = pair  # trace-time: the step's carry-out
+    if latent_scale is not None:
+        return paged_latent_decode_attention(
+            *q, *pair, bt, q_pos, kv_valid=kv_valid, scale=latent_scale,
+            page_size=ps,
         )
-    pools[layer] = (k_pool, v_pool)  # trace-time: the step's carry-out
     return paged_flash_decode_attention(
-        q, k_pool, v_pool, bt, q_pos, kv_valid=kv_valid, page_size=ps
+        q, *pair, bt, q_pos, kv_valid=kv_valid, page_size=ps
     )
 
 
@@ -789,7 +864,7 @@ def decode_attention(q, k_cache, v_cache, q_pos, mask=None, kv_valid=None):
     the materialized view passed in."""
     if _FUSED_PAGED_STACK and mask is None:
         return _fused_paged_decode(
-            _FUSED_PAGED_STACK[-1], q, k_cache, v_cache, q_pos, kv_valid
+            _FUSED_PAGED_STACK[-1], q, (k_cache, v_cache), q_pos, kv_valid
         )
     if mask is None and resolve_decode_impl(k_cache.shape[1]) == "flash_decode":
         from neuronx_distributed_tpu.kernels.flash_decode import (
@@ -811,6 +886,46 @@ def decode_attention(q, k_cache, v_cache, q_pos, mask=None, kv_valid=None):
     )
     out = num / jnp.maximum(l, 1e-20)[..., None]
     return jnp.swapaxes(out.reshape(b, h, s, d), 1, 2).astype(q.dtype)
+
+
+def latent_decode_attention(q_c, q_r, c_cache, r_cache, q_pos, scale,
+                            mask=None, kv_valid=None):
+    """ABSORBED multi-head latent attention of query rows at positions
+    ``q_pos`` (S,) against a latent cache: ``q_c`` (B, S, H, d_c) are the
+    heads' content queries with ``W_uk`` folded in, ``q_r`` (B, S, H, d_r)
+    their rotated part; ``c_cache`` (B, L, 1, d_c) and ``r_cache`` (B, L, 1,
+    d_r) the :class:`LatentKVCache` leaves. Scores ``(q_c . c + q_r . k_pe)
+    * scale``, positional/``kv_valid``/``mask`` masking as
+    :func:`decode_attention`, softmax in float32, values the latent rows:
+    returns (B, S, H, d_c) for the caller's ``W_uv``.
+
+    Inside a :class:`fused_paged_attention_scope` it attends the page pool
+    through ``paged_latent_decode_attention`` (the kernel, or nothing);
+    elsewhere this einsum, on every platform (there is no row-cache latent
+    kernel: the engine records ``decode_attention: "einsum"``)."""
+    if _FUSED_PAGED_STACK and mask is None:
+        return _fused_paged_decode(
+            _FUSED_PAGED_STACK[-1], (q_c, q_r), (c_cache, r_cache), q_pos,
+            kv_valid, latent_scale=scale,
+        )
+    c = c_cache[:, :, 0].astype(jnp.float32)           # (B, L, d_c)
+    s = (
+        jnp.einsum("bshd,bld->bhsl", q_c.astype(jnp.float32), c)
+        + jnp.einsum("bshd,bld->bhsl", q_r.astype(jnp.float32),
+                     r_cache[:, :, 0].astype(jnp.float32))
+    ) * scale
+    q_pos = q_pos[None] if q_pos.ndim == 0 else q_pos
+    if mask is None:
+        mask = q_pos[:, None] >= jnp.arange(c.shape[1])[None]   # (S, L)
+    ok = mask[None, None]
+    if kv_valid is not None:
+        ok = ok & kv_valid[:, None, None, :]
+    s = jnp.where(ok, s, -1e30)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    p = jnp.where(ok, jnp.exp(s - m), 0.0)
+    out = jnp.einsum("bhsl,bld->bshd", p, c)
+    denom = jnp.swapaxes(p.sum(-1), 1, 2)[..., None]   # (B, S, H, 1)
+    return (out / jnp.maximum(denom, 1e-20)).astype(q_c.dtype)
 
 
 class ParallelSelfAttention(nn.Module):
@@ -925,12 +1040,15 @@ class ParallelSelfAttention(nn.Module):
 
 
 class ParallelMLP(nn.Module):
-    """Plain 2-layer MLP: CPL → activation → RPL (BERT/NeoX/ViT FFN)."""
+    """Plain 2-layer MLP: CPL → activation → RPL (BERT/NeoX/ViT FFN).
+    ``glu`` gates it: ``down(act(gate(x)) * up(x))`` (SwiGLU with
+    ``activation="silu"``: DeepSeek-V2's dense layer and shared experts)."""
 
     hidden_size: int
     intermediate_size: int
     activation: str = "gelu"
     use_bias: bool = True
+    glu: bool = False
     sequence_parallel_enabled: bool = False
     dtype: Dtype = jnp.float32
     param_dtype: Dtype = jnp.float32
@@ -954,7 +1072,12 @@ class ParallelMLP(nn.Module):
         y = ColumnParallelLinear(
             self.hidden_size, self.intermediate_size, name="up", **common
         )(x)
-        y = act(y)
+        if self.glu:
+            y = act(ColumnParallelLinear(
+                self.hidden_size, self.intermediate_size, name="gate", **common
+            )(x)) * y
+        else:
+            y = act(y)
         return RowParallelLinear(
             self.intermediate_size, self.hidden_size, name="down", **common
         )(y)

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 from jax.sharding import PartitionSpec as P
 
+from neuronx_distributed_tpu.modules.attention import ParallelMLP
 from neuronx_distributed_tpu.modules.moe.expert_mlps import ExpertMLPs
 from neuronx_distributed_tpu.modules.moe.loss_function import (
     load_balancing_loss_func,
@@ -59,6 +60,14 @@ class MoE(nn.Module):
     # weight-only serving quantization of the EXPERT weights (the router
     # stays float — reference keeps router math in fp32)
     quantization_config: Optional[Any] = None
+    # top-k softmax affinities renormalised over the k chosen (Mixtral) or
+    # left as they are (DeepSeek-V2, ``norm_topk_prob`` false), then scaled
+    normalize_top_k_affinities: bool = True
+    routed_scaling_factor: float = 1.0
+    # shared experts (DeepSeek): ONE gated MLP of this width (the model's
+    # ``n_shared_experts * moe_intermediate_size``) that every token goes
+    # through, added to the routed sum; None: no such branch
+    shared_intermediate_size: Optional[int] = None
 
     @nn.compact
     def __call__(
@@ -75,6 +84,9 @@ class MoE(nn.Module):
         if self.token_shuffle and not deterministic:
             tokens, perm = shuffle_tokens(tokens, self.make_rng("token_shuffle"))
 
+        router_options = {}
+        if not self.normalize_top_k_affinities:
+            router_options["normalize_top_k_affinities"] = False
         router = make_router(
             self.router_kind,
             hidden_size=self.hidden_size,
@@ -85,12 +97,16 @@ class MoE(nn.Module):
             dtype=self.dtype,
             param_dtype=self.param_dtype,
             name="router",
+            **router_options,
         )
         # named scopes (with the modules' own: this block is ``moe``) so a
         # device trace can be cut into router / dispatch / experts / combine
         with jax.named_scope("moe.router"):
             route = router(tokens, deterministic=deterministic)
 
+        top_w = route.top_w
+        if self.routed_scaling_factor != 1.0:
+            top_w = top_w * self.routed_scaling_factor
         out = ExpertMLPs(
             num_experts=self.num_experts,
             hidden_size=self.hidden_size,
@@ -104,7 +120,16 @@ class MoE(nn.Module):
             param_dtype=self.param_dtype,
             quantization_config=self.quantization_config,
             name="experts",
-        )(tokens, route.top_e, route.top_w)
+        )(tokens, route.top_e, top_w)
+
+        if self.shared_intermediate_size is not None:
+            with jax.named_scope("moe.shared"):
+                out = out + ParallelMLP(
+                    self.hidden_size, self.shared_intermediate_size,
+                    activation=self.hidden_act, use_bias=False, glu=True,
+                    dtype=self.dtype, param_dtype=self.param_dtype,
+                    name="shared",
+                )(tokens).astype(out.dtype)
 
         if perm is not None:
             out = unshuffle_tokens(out, perm)

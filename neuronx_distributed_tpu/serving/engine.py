@@ -238,6 +238,7 @@ from neuronx_distributed_tpu.inference.spec_decode import (
 from neuronx_distributed_tpu.inference.utils import unwrap_logits
 from neuronx_distributed_tpu.kernels import backend
 from neuronx_distributed_tpu.modules.attention import (
+    cache_bytes_per_token_layer,
     cache_fingerprint,
     extract_cache_prefix,
     resolve_decode_impl,
@@ -328,7 +329,12 @@ def _bucket(p: int, max_seq_len: int, remaining: int, floor: int = 8) -> int:
     b = max(floor, 1 << max(p - 1, 0).bit_length())
     b = min(b, max_seq_len)
     if b < p or b + remaining > max_seq_len:
-        b = p
+        # ... to the next multiple of 512 where that still fits: an odd
+        # length leaves the flash kernel no block to tile by (a
+        # 20,566-token prompt in a 32,768 row would get blocks of 2)
+        b = -(-p // 512) * 512
+        if b + remaining > max_seq_len:
+            b = p
     return b
 
 
@@ -586,6 +592,18 @@ class ServingEngine:
 
         if mesh is not None and tp is None:
             tp = int(mesh.mesh.shape["tp"]) if hasattr(mesh, "mesh") else None
+        # a latent (MLA) cache holds ONE row a token for all heads
+        # (modules/attention.py LatentKVCache): nothing to shard over tp
+        latent = getattr(
+            getattr(model, "config", None), "kv_cache_kind", "kv"
+        ) == "latent"
+        if latent and (tp or 1) > 1:
+            raise ValueError(
+                "ServingEngine(tp>1) does not serve a latent-cache (MLA) "
+                "model: its cache has no head axis to shard and the paged "
+                "latent decode kernel has no sharded form — serve it on one "
+                "chip (tp=None)"
+            )
         self.tp = tp
         self._partitioner = None
         if tp is not None:
@@ -817,7 +835,9 @@ class ServingEngine:
                 getattr(model, "attention_impl", "auto")
             ),
             decode_attention=(
-                "paged_fused" if self.paged_attention == "fused"
+                ("paged_latent_fused" if latent else "paged_fused")
+                if self.paged_attention == "fused"
+                else "einsum" if latent  # latent_decode_attention's only other path
                 else resolve_decode_impl(max_seq_len)
             ),
             paged_attention=(
@@ -2278,6 +2298,16 @@ class ServingEngine:
             self._step()
         return self.has_work
 
+    def _kv_bytes_per_token_layer(self) -> int:
+        """Bytes a token holds per attention layer, from the allocated
+        cache leaves (whole bytes: a span's stats are host ints); the
+        ``serving_kv_bytes_per_token_layer`` gauge carries the same."""
+        if not self.metrics.kv_bytes_per_token_layer:
+            self.metrics.record_kv_bytes(
+                cache_bytes_per_token_layer(self.cache.cache)
+            )
+        return int(round(self.metrics.kv_bytes_per_token_layer))
+
     def _span(self, name: str, **stats):
         """A phase of ``step()``: the one span primitive, bound to this
         engine's timeline (``observability/tracing.py``)."""
@@ -3226,7 +3256,10 @@ class ServingEngine:
         active_at_dispatch = int(self._active.sum())
         t0 = self._clock()
         fault = None
-        with self._span(tracing.STEP_DISPATCH, active=active_at_dispatch):
+        with self._span(
+            tracing.STEP_DISPATCH, active=active_at_dispatch,
+            kv_bytes_per_token_layer=self._kv_bytes_per_token_layer(),
+        ):
             cache_in = self.cache.take()
             draft_in = self.draft_cache.take()
             attempt = self._dispatch_attempts
@@ -3410,7 +3443,10 @@ class ServingEngine:
         active_at_dispatch = int(self._active.sum())
         t0 = self._clock()
         fault = None
-        with self._span(tracing.STEP_DISPATCH, active=active_at_dispatch):
+        with self._span(
+            tracing.STEP_DISPATCH, active=active_at_dispatch,
+            kv_bytes_per_token_layer=self._kv_bytes_per_token_layer(),
+        ):
             cache_in = self.cache.take()
             attempt = self._dispatch_attempts
             self._dispatch_attempts += 1

@@ -594,6 +594,7 @@ class FaultInjector:
 
         from neuronx_distributed_tpu.integrity.chaos import flip_array_bit
         from neuronx_distributed_tpu.modules.attention import (
+            PAGED_LEAVES,
             cache_leaf_name,
             pool_scale_base,
         )
@@ -603,7 +604,7 @@ class FaultInjector:
         leaves = [leaf for _, leaf in flat]
         for i, (path, leaf) in enumerate(flat):
             name = cache_leaf_name(path)
-            if (pool_scale_base(name) or name) not in ("k", "v"):
+            if (pool_scale_base(name) or name) not in PAGED_LEAVES:
                 continue
             pax = leaf.ndim - 4
             host = np.array(jax.device_get(leaf))

@@ -221,6 +221,10 @@ class ServingMetrics:
         self._g_cursor = self.view.gauge(
             "serving_cursor_high_water", help="highest shared cache cursor seen"
         )
+        self._g_kv_bytes = self.view.gauge(
+            "serving_kv_bytes_per_token_layer",
+            help="bytes one token holds in one attention layer's cache",
+        )
         self._g_health = self.view.gauge(
             "serving_health", help="0=ok 1=degraded 2=draining 3=halted"
         )
@@ -270,6 +274,9 @@ class ServingMetrics:
         self.view.gauge("serving_num_slots").set(num_slots)
         self.health = "ok"  # engine-owned mirror of ServingEngine.health()
         self.cursor_high_water = 0
+        # bytes a token holds per attention layer, from the allocated cache
+        # leaves (0 until the first admission allocates them)
+        self.kv_bytes_per_token_layer = 0.0
         # device-efficiency ledgers (ISSUE 12): attached weakly by the
         # engine so snapshot() can carry "programs"/"hbm" without a kept
         # metrics object pinning a retired engine's ledgers
@@ -579,6 +586,10 @@ class ServingMetrics:
         """Single-step accounting — the chunk-size-1 special case."""
         self.record_decode_chunk(active_slots, 1, cursor, active_slots)
 
+    def record_kv_bytes(self, per_token_layer: float) -> None:
+        self.kv_bytes_per_token_layer = float(per_token_layer)
+        self._g_kv_bytes.set(per_token_layer)
+
     def record_decode_chunk(
         self,
         tokens: int,
@@ -775,6 +786,7 @@ class ServingMetrics:
             ),
             "health": self.health,
             "cursor_high_water": self.cursor_high_water,
+            "kv_bytes_per_token_layer": self.kv_bytes_per_token_layer,
             "mean_occupancy": self.mean_occupancy,
             "mean_ttft": _mean(ttfts),
             "max_ttft": max(ttfts) if ttfts else 0.0,

@@ -53,6 +53,7 @@ import numpy as np
 
 from neuronx_distributed_tpu.modules.attention import (
     _SCALE_SUFFIX,
+    PAGED_LEAVES,
     cache_batch_axis,
     cache_leaf_name,
     cache_node_at,
@@ -303,7 +304,7 @@ class PagedCacheManager:
             def fn(path, pool_leaf):
                 name = cache_leaf_name(path)
                 base = pool_scale_base(name) or name
-                if base in ("k", "v"):
+                if base in PAGED_LEAVES:
                     pages = row_pages(path, base)
                     if pool_scale_sibling(pool_in, path, base) is not None:
                         q, s = quantize_page_block(pages)
@@ -343,7 +344,7 @@ class PagedCacheManager:
                 name = keys[-1]
                 if pool_scale_base(name) is not None:
                     continue  # transport metadata — not part of a row
-                if name in ("k", "v"):
+                if name in PAGED_LEAVES:
                     scale = pool_scale_sibling(pool, path, name)
                     block = (
                         paged_read_pages_leaf_dequant(leaf, scale, ids, ps)
@@ -386,7 +387,7 @@ class PagedCacheManager:
             def fn(path, pool_leaf):
                 name = cache_leaf_name(path)
                 base = pool_scale_base(name) or name
-                if base not in ("k", "v"):
+                if base not in PAGED_LEAVES:
                     return pool_leaf
                 row_leaf = cache_node_at(row, path[:-1])[base]
                 r_ax = row_leaf.ndim - 4
@@ -417,7 +418,7 @@ class PagedCacheManager:
             def fn(path, leaf):
                 name = cache_leaf_name(path)
                 base = pool_scale_base(name) or name
-                if base in ("k", "v"):
+                if base in PAGED_LEAVES:
                     return leaf
                 ax = cache_batch_axis(name, leaf.ndim)
                 if name == "kv_valid":
@@ -451,7 +452,7 @@ class PagedCacheManager:
             def fn(path, pool_leaf):
                 name = cache_leaf_name(path)
                 base = pool_scale_base(name) or name
-                if base not in ("k", "v"):
+                if base not in PAGED_LEAVES:
                     return pool_leaf
                 block = cache_node_at(blocks, path[:-1])[name]
                 return paged_write_pages_leaf(pool_leaf, block, ids)
@@ -527,7 +528,7 @@ class PagedCacheManager:
             # sits 4 from the end either way (leading axes are nn.scan
             # layer stacking); scales are real per-page HBM, so plan()
             # capacity math must charge them
-            if name in ("k", "v") or pool_scale_base(name) is not None:
+            if name in PAGED_LEAVES or pool_scale_base(name) is not None:
                 pages_ax = max(int(leaf.shape[leaf.ndim - 4]), 1)
                 total += int(leaf.nbytes) // pages_ax
         return total
@@ -737,7 +738,7 @@ class PagedCacheManager:
             keys = tuple(path_keys(path))
             name = keys[-1]
             ax = cache_batch_axis(name, leaf.ndim)
-            if name in ("k", "v"):
+            if name in PAGED_LEAVES:
                 lead = leaf.shape[:ax]
                 tail = leaf.shape[ax + 2:]  # (Hkv, D)
                 if self.kv_quant is not None:
@@ -786,7 +787,7 @@ class PagedCacheManager:
         def fn(path, leaf):
             name = cache_leaf_name(path)
             base = pool_scale_base(name) or name
-            if base in ("k", "v"):
+            if base in PAGED_LEAVES:
                 pax = leaf.ndim - 4
                 shape = list(leaf.shape)
                 shape[pax] = self.alloc.num_pages
@@ -1018,7 +1019,7 @@ class PagedCacheManager:
         )[0]:
             keys = tuple(path_keys(path))
             base = pool_scale_base(keys[-1]) or keys[-1]
-            if base in ("k", "v"):
+            if base in PAGED_LEAVES:
                 pax = leaf.ndim - 4
                 items.append((keys, jnp.take(leaf, ids, axis=pax)))
         return ExportedContext(
@@ -1073,7 +1074,7 @@ class PagedCacheManager:
         )[0]:
             keys = tuple(path_keys(path))
             base = pool_scale_base(keys[-1]) or keys[-1]
-            if base in ("k", "v"):
+            if base in PAGED_LEAVES:
                 pax = leaf.ndim - 4
                 items.append((keys, jnp.take(leaf, dev_ids, axis=pax)))
         # the spill's ONE sync: every leaf's gathered block rides a single

@@ -167,6 +167,7 @@ def pool_pages_fingerprint(pool_tree, page_ids):
     hashes whatever page it aliases; the CALLER masks padded positions
     out of the comparison)."""
     from neuronx_distributed_tpu.modules.attention import (
+        PAGED_LEAVES,
         cache_leaf_name,
         pool_scale_base,
     )
@@ -176,7 +177,7 @@ def pool_pages_fingerprint(pool_tree, page_ids):
     flat_leaves, _ = jax.tree_util.tree_flatten_with_path(pool_tree)
     for path, leaf in flat_leaves:
         name = cache_leaf_name(path)
-        if (pool_scale_base(name) or name) not in ("k", "v"):
+        if (pool_scale_base(name) or name) not in PAGED_LEAVES:
             continue
         pax = leaf.ndim - 4
         pages = jnp.take(leaf, page_ids, axis=pax)

@@ -1,0 +1,117 @@
+"""The paged ABSORBED-decode kernel (multi-head latent attention) against
+plain ``jnp`` on a scattered page pool: page boundaries, ragged contexts,
+invalid columns, unmapped pages, the cursor bound (interpret mode on CPU)."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from neuronx_distributed_tpu.kernels.flash_decode import (
+    paged_gather_leaf,
+    paged_latent_decode_attention,
+)
+from neuronx_distributed_tpu.modules.attention import latent_decode_attention
+
+PS, D_C, D_R = 16, 32, 8
+SCALE = 0.21
+
+
+def _pool(rng, b, n_log, lens):
+    """A pool whose pages are dealt out of order; slot ``i`` maps the pages
+    covering ``lens[i]`` columns, the rest stay on the null page 0."""
+    pages = 1 + b * n_log
+    c_pool = rng.standard_normal((pages, PS, 1, D_C)).astype(np.float32)
+    r_pool = rng.standard_normal((pages, PS, 1, D_R)).astype(np.float32)
+    ids = rng.permutation(np.arange(1, pages))
+    table = np.zeros((b, n_log), np.int32)
+    for i, n in enumerate(lens):
+        need = -(-n // PS)
+        table[i, :need] = ids[i * n_log:i * n_log + need]
+    return jnp.asarray(c_pool), jnp.asarray(r_pool), jnp.asarray(table)
+
+
+def _golden(q_c, q_r, c_pool, r_pool, table, pos, valid):
+    c = paged_gather_leaf(c_pool, table, PS)
+    r = paged_gather_leaf(r_pool, table, PS)
+    s = (jnp.einsum("bshd,bld->bhsl", q_c, c[:, :, 0])
+         + jnp.einsum("bshd,bld->bhsl", q_r, r[:, :, 0])) * SCALE
+    ok = (pos[:, None] >= jnp.arange(c.shape[1])[None])[None, None] & valid[:, None, None, :]
+    p = jax.nn.softmax(jnp.where(ok, s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhsl,bld->bshd", p, c[:, :, 0])
+
+
+@pytest.mark.parametrize("n_log,lens,h,s", [
+    (8, (100, 37), 4, 1),          # ragged contexts, one group of 8 pages
+    (48, (700, 16, 333), 16, 1),   # three groups of 16 pages; a one-page slot
+    (6, (90, 80), 4, 3),           # a multi-token step: each row at its own position
+])
+def test_matches_jnp_across_pages(n_log, lens, h, s):
+    rng = np.random.default_rng(n_log)
+    b, cur = len(lens), max(lens)
+    c_pool, r_pool, table = _pool(rng, b, n_log, lens)
+    q_c = jnp.asarray(rng.standard_normal((b, s, h, D_C)), jnp.float32)
+    q_r = jnp.asarray(rng.standard_normal((b, s, h, D_R)), jnp.float32)
+    pos = jnp.asarray(cur - s + np.arange(s), jnp.int32)
+    valid = np.zeros((b, n_log * PS), bool)
+    for i, n in enumerate(lens):   # contexts END at the shared cursor, as the engine lays them out
+        valid[i, :n] = True
+    valid[0, 3:9] = False          # an invalid stretch inside a mapped page
+    valid = jnp.asarray(valid)
+    out = paged_latent_decode_attention(
+        q_c, q_r, c_pool, r_pool, table, pos, valid, scale=SCALE, page_size=PS)
+    want = _golden(q_c, q_r, c_pool, r_pool, table, pos, valid)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
+    # and the einsum the model runs outside the fused scope agrees with both
+    einsum = latent_decode_attention(
+        q_c, q_r, paged_gather_leaf(c_pool, table, PS), paged_gather_leaf(r_pool, table, PS),
+        pos, SCALE, kv_valid=valid)
+    np.testing.assert_allclose(np.asarray(einsum), np.asarray(want), atol=2e-5)
+
+
+def test_null_pages_and_columns_past_the_cursor_contribute_nothing():
+    """Poison everything the kernel must not read INTO the result: the null
+    page and mapped columns past the cursor hold huge values."""
+    rng = np.random.default_rng(5)
+    c_pool, r_pool, table = _pool(rng, 2, 32, (200, 40))
+    c_pool = c_pool.at[0].set(1e4)
+    q_c = jnp.asarray(rng.standard_normal((2, 1, 4, D_C)), jnp.float32)
+    q_r = jnp.asarray(rng.standard_normal((2, 1, 4, D_R)), jnp.float32)
+    valid = np.zeros((2, 32 * PS), bool)
+    valid[0, :200], valid[1, :40] = True, True
+    pos = jnp.asarray([150], jnp.int32)      # the cursor sits INSIDE slot 0's mapped pages
+    out = paged_latent_decode_attention(
+        q_c, q_r, c_pool, r_pool, table, pos, jnp.asarray(valid), scale=SCALE, page_size=PS)
+    want = _golden(q_c, q_r, c_pool, r_pool, table, pos, jnp.asarray(valid))
+    assert np.isfinite(np.asarray(out)).all() and np.abs(np.asarray(out)).max() < 10
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
+
+
+def test_bf16_operands_stay_bf16_and_agree_within_rounding():
+    rng = np.random.default_rng(6)
+    c_pool, r_pool, table = _pool(rng, 2, 16, (250, 130))
+    q_c = jnp.asarray(rng.standard_normal((2, 1, 8, D_C)), jnp.bfloat16)
+    q_r = jnp.asarray(rng.standard_normal((2, 1, 8, D_R)), jnp.bfloat16)
+    valid = np.zeros((2, 16 * PS), bool)
+    valid[0, :250], valid[1, :130] = True, True
+    pos = jnp.asarray([249], jnp.int32)
+    args = (table, pos, jnp.asarray(valid))
+    out = paged_latent_decode_attention(
+        q_c, q_r, c_pool.astype(jnp.bfloat16), r_pool.astype(jnp.bfloat16), *args,
+        scale=SCALE, page_size=PS)
+    assert out.dtype == jnp.bfloat16 and out.shape == (2, 1, 8, D_C)
+    want = _golden(q_c.astype(jnp.float32), q_r.astype(jnp.float32),
+                   c_pool.astype(jnp.bfloat16).astype(jnp.float32),
+                   r_pool.astype(jnp.bfloat16).astype(jnp.float32), *args)
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(want), atol=3e-2)
+
+
+def test_refuses_a_per_head_pool_and_a_mesh():
+    rng = np.random.default_rng(7)
+    c_pool, r_pool, table = _pool(rng, 1, 4, (20,))
+    q_c = jnp.zeros((1, 1, 2, D_C))
+    q_r = jnp.zeros((1, 1, 2, D_R))
+    with pytest.raises(ValueError, match="latent pool leaf"):
+        paged_latent_decode_attention(
+            q_c, q_r, jnp.zeros((5, PS, 2, D_C)), r_pool, table, jnp.asarray([3]),
+            scale=SCALE, page_size=PS)

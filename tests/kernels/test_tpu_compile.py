@@ -139,6 +139,65 @@ def test_paged_decode_compiles(topo, hkv):
     assert KERNEL in _compiled_text(fn, q, pool, pool, table, pos, valid)
 
 
+# DeepSeek-V2-Lite's attention geometry (models/deepseek_v2.py): 16 heads, a
+# latent of 512 and a rotated key of 64 a token, q.k over 192, values of 128
+MLA = dict(h=16, d_c=512, d_r=64, d_qk=192, d_v=128)
+
+
+def test_paged_latent_decode_compiles(topo):
+    """The absorbed-decode kernel at the benchmark cell's size: 8 slots of
+    32,768 columns, page 16, 16 heads against ONE row of 512 + 64. Neither
+    pool leaf may be copied whole to be fed to the kernel but the rotated
+    key's (a ninth of the bytes: its 64-wide layout has the page index
+    minor, ``kernels/flash_decode.py``)."""
+    from neuronx_distributed_tpu.kernels.flash_decode import (
+        paged_latent_decode_attention,
+    )
+
+    s = _one_chip(topo)
+    slots, seq, pages = 8, 32768, 8 * 32768 // 16 + 1
+    q_c, q_r = s((slots, 1, MLA["h"], MLA["d_c"])), s((slots, 1, MLA["h"], MLA["d_r"]))
+    c_pool, r_pool = s((pages, 16, 1, MLA["d_c"])), s((pages, 16, 1, MLA["d_r"]))
+    table, pos = s((slots, seq // 16), jnp.int32), s((1,), jnp.int32)
+    valid = s((slots, seq), jnp.bool_)
+
+    def fn(q_c, q_r, c_pool, r_pool, table, pos, valid):
+        return paged_latent_decode_attention(
+            q_c, q_r, c_pool, r_pool, table, pos, valid, scale=0.1147,
+            page_size=16,
+        )
+
+    text = _compiled_text(fn, q_c, q_r, c_pool, r_pool, table, pos, valid)
+    assert KERNEL in text
+    latent = "bf16[%d,16,1,%d]" % (pages, MLA["d_c"])
+    assert not [ln for ln in text.splitlines() if " copy(" in ln and latent in ln]
+
+
+@pytest.mark.parametrize("seq", [2048, 20992, 32768])
+def test_flash_prefill_with_a_narrower_value_head_compiles(topo, seq):
+    """MLA's materialised prefill: q and k of 192, v of 128, with the
+    padding mask every engine prefill carries; 20,608 is the cell's longest
+    prompt rounded up to a multiple of 512, the flash kernel's block."""
+    s = _one_chip(topo)
+    qk = s((1, seq, MLA["h"], MLA["d_qk"]))
+    v, seg = s((1, seq, MLA["h"], MLA["d_v"])), s((1, seq), jnp.int32)
+
+    def fn(q, k, v, seg):
+        return flash_attention(q, k, v, segment_ids=seg)
+
+    assert KERNEL in _compiled_text(fn, qk, qk, v, seg)
+
+
+def test_flash_backward_with_a_narrower_value_head_compiles(topo):
+    s = _one_chip(topo)
+    qk, v = s((2, 2048, MLA["h"], MLA["d_qk"])), s((2, 2048, MLA["h"], MLA["d_v"]))
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v).astype(jnp.float32).sum()
+
+    assert KERNEL in _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), qk, qk, v)
+
+
 def test_mixtral_width_blockwise_moe_compiles(topo):
     """One Mixtral-8x7B-width expert layer forward on the dropless
     blockwise path: ``jax.lax.ragged_dot``, which the TPU compiler turns
@@ -228,11 +287,12 @@ def _train_step_compiled(topo, n_devices, *, tp, sp, layers=2, batch=4,
 
 
 def _engine_programs(topo, n_devices, *, layers=8, slots=8, seq=4096,
-                     page=16, bucket=512):
+                     page=16, bucket=512, model=None):
     """``(engine, lower_decode, lower_prefill, pool_shards)`` for a
     default-configured engine over abstract params on described devices;
     ``pool_shards`` are the shapes of the bf16 page-pool leaves as ONE
-    device holds them (``(2049, 16, 32, 128)``). With ``n_devices`` > 1
+    device holds them (``(2049, 16, 32, 128)``). ``model``: another model
+    than Llama-2-7B's, built for ``seq``. With ``n_devices`` > 1
     the global tp mesh is up and every operand carries the placement the TP
     engine's partitioner would commit — the engine's programs take their
     sharding from their operands, so this IS the tp program."""
@@ -243,11 +303,12 @@ def _engine_programs(topo, n_devices, *, layers=8, slots=8, seq=4096,
     from neuronx_distributed_tpu.serving import ServingEngine
     from neuronx_distributed_tpu.serving.paging import PagedCacheManager
 
-    cfg = llama2_7b(
-        num_layers=layers, max_seq_len=seq, scan_layers=False, remat=False,
-        param_dtype=jnp.bfloat16,
-    )
-    model = LlamaForCausalLM(cfg, attention_impl="auto")
+    if model is None:
+        cfg = llama2_7b(
+            num_layers=layers, max_seq_len=seq, scan_layers=False,
+            remat=False, param_dtype=jnp.bfloat16,
+        )
+        model = LlamaForCausalLM(cfg, attention_impl="auto")
     boxed = jax.eval_shape(
         model.init, jax.random.PRNGKey(0),
         jax.ShapeDtypeStruct((1, 8), jnp.int32),
@@ -427,3 +488,54 @@ def test_tp4_engine_programs_compile(topo):
         _fits(compiled)
         if lower is lower_decode:
             _assert_pool_carried(compiled, pool_shards)
+
+
+def _deepseek_v2_lite(layers, seq):
+    from neuronx_distributed_tpu.models.deepseek_v2 import (
+        DeepseekV2ForCausalLM,
+        deepseek_v2_lite,
+    )
+
+    return DeepseekV2ForCausalLM(
+        deepseek_v2_lite(
+            num_layers=layers, max_seq_len=seq, param_dtype=jnp.bfloat16,
+            expert_strategy="blockwise",
+        ),
+        attention_impl="auto",
+    )
+
+
+@pytest.mark.slow
+def test_deepseek_v2_lite_engine_programs_compile_and_fit(topo):
+    """The benchmark configuration's programs (``perfbench/configs/
+    deepseek-v2-lite-serve.json``: its depth, 8 slots of 32,768, page 16):
+    the fused decode chunk with the LATENT pool
+    carried and the longest prompt's prefill, both with Pallas kernels and
+    inside the chip's memory; the decode program's temporaries held to what
+    the logical view needs (twice, the D1 debt) plus the experts' layout
+    copies."""
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    with open(os.path.join(root, "perfbench", "configs", "deepseek-v2-lite-serve.json")) as f:
+        config = json.load(f)
+    seq = int(config["serving"]["max_seq_len"])
+    engine, lower_decode, lower_prefill, pool_shards = _engine_programs(
+        topo, 1, slots=int(config["serving"]["num_slots"]), seq=seq,
+        bucket=20992, model=_deepseek_v2_lite(int(config["model"]["num_hidden_layers"]), seq),
+    )
+    assert engine.programs.resolved == {
+        "attention": "flash", "decode_attention": "paged_latent_fused",
+        "paged_attention": "fused",
+    }
+    assert sorted(set(pool_shards)) == [(16385, 16, 1, 64), (16385, 16, 1, 512)]
+    decode = lower_decode().compile()
+    assert KERNEL in decode.as_text()
+    assert _fits(decode, 15 * 1024**3)
+    shapes = {"bf16[%s]" % ",".join(map(str, s)) for s in pool_shards}
+    assert not _copies_inside_loops(decode.as_text(), shapes)
+    view_twice = 2 * sum(2 * math.prod(s) for s in pool_shards)
+    assert decode.memory_analysis().temp_size_in_bytes < 1.25 * view_twice
+    prefill = lower_prefill().compile()
+    assert KERNEL in prefill.as_text()
+    assert _fits(prefill, 15 * 1024**3)

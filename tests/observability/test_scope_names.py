@@ -94,3 +94,28 @@ def test_named_scopes_reach_the_optimized_hlo(codegen, mixtral):
     # the rehearsal's four experts prefill through the dense strategy, whose
     # einsums hold dispatch and combine: one scope
     assert {"moe", "moe.router", "moe.experts"} <= _optimized(mixtral["prefill"])
+
+
+@pytest.fixture(scope="module")
+def deepseek():
+    return _programs("deepseek-v2-lite-serve")
+
+
+def test_the_latent_attention_programs_carry_their_names(deepseek):
+    """``mla_block_dev_share_pct`` finds the scopes ``mla.compress``,
+    ``mla.absorb`` (decode) and ``mla.expand`` (prefill), ``moe_block_dev_share_pct``
+    the shared experts under ``moe``; both MLA kernels (the paged latent one
+    in decode, flash in prefill on the chip) are called from
+    ``attn._cached_attention`` with no scope between."""
+    decode, prefill = deepseek["decode_chunk"], deepseek["prefill"]
+    assert re.search(r"HloModule (\S+?),", decode[1]).group(1) == "jit_chunk_fn"
+    callers = {path.rsplit("/", 1)[-1] for path in KERNEL_CALL.findall(decode[0])}
+    assert callers == {"attn._cached_attention"}
+    assert {"mla.compress", "mla.absorb", "attn", "kv_view", "moe", "moe.shared"} <= _traced(decode)
+    assert {"mla.compress", "mla.absorb", "moe", "moe.shared", "moe.experts"} <= _optimized(decode)
+    assert "mla.expand" not in _traced(decode)             # decode never expands W_kv_b over the cache
+    assert {"mla.compress", "mla.expand", "moe.shared"} <= _optimized(prefill)
+    assert "mla.absorb" not in _traced(prefill)
+    # the attention call itself sits outside the mla.* scopes, in the method
+    assert any(path.endswith("attn._cached_attention/pallas_call") or "attn._cached_attention" in path
+               for path in LOCATION.findall(prefill[0]))

@@ -61,11 +61,35 @@ def _assert_only_kernel_checks_fail(checks):
     )
 
 
+def _tiny_mla():
+    import json
+
+    path = os.path.join(os.path.dirname(_PATH), "tests", "benchmark", "data",
+                        "configs", "deepseek-v2-lite-serve.json")
+    with open(path) as f:
+        return json.load(f)["model"]
+
+
+# DeepSeek-V2's structure at the CPU stand-in's size; float32, so the
+# tolerances are the rounding of a float32 matmul's summation order
+MLA = chip_smoke.MlaSize(
+    model=_tiny_mla(), max_seq_len=512, slots=2, prompt_lens=(300, 128, 40),
+    tail=32, new_tokens=12, logit_tol=1e-3, typical_tol=1e-4, gap_tol=1e-3,
+)
+
+
 def test_one_chip_run_rehearsal():
-    """Train then serve in ONE process, exactly as ``main()`` runs them (the
-    train phase's global mesh must not leak into the mesh-free engine)."""
+    """Train, serve, then the MLA model in ONE process, exactly as ``main()``
+    runs them (the train phase's global mesh must not leak into the
+    mesh-free engines)."""
     _assert_only_kernel_checks_fail(
-        chip_smoke.one_chip(0, jax.devices()[:1], TRAIN, SERVE)
+        chip_smoke.one_chip(0, jax.devices()[:1], TRAIN, SERVE, MLA)
+    )
+
+
+def test_mla_phase_alone_rehearsal():
+    _assert_only_kernel_checks_fail(
+        chip_smoke.one_chip(0, jax.devices()[:1], TRAIN, SERVE, MLA, only="mla")
     )
 
 
