@@ -141,12 +141,17 @@ def chunked_decode_step(decode_model, chunk_size: int, max_seq_len: int,
     view; ``"fused"`` routes every decode-attention call through
     ``kernels/flash_decode.paged_flash_decode_attention`` — the block
     table rides the kernel's scalar prefetch and K/V stream straight from
-    the physical pool pages. The pool is then loop-carried state of the
-    scan, beside the logical cache: each executed step scatters its write
-    window into the carried pool (in place — a pool the scan only closed
-    over was copied whole before every such scatter) and attends it, and
-    on the chunk's exit the carried pool, current through the last
-    executed step, IS the output pool; nothing is scattered a second time.
+    the physical pool pages. Nothing attends a view there, so the chunk
+    builds none: its cache's per-token leaves hold only the chunk's write
+    WINDOW (``modules/attention.fused_chunk_window``: ``(chunk_size - 1)
+    // page_size + 2`` pages a slot from the cursor's page), in which the
+    model's decode write stages each new token; ``index``/``kv_valid`` stay
+    logical and whole. The pool is loop-carried state of the scan beside
+    it: each executed step scatters the window into the carried pool (in
+    place — a pool the scan only closed over was copied whole before every
+    such scatter) and attends it, and on the chunk's exit the carried
+    pool, current through the last executed step, IS the output pool;
+    nothing is scattered a second time.
     It is the compiled kernel or nothing: off the TPU it runs only where a
     test interprets it. Fused mode does not speak quantized pools (the
     in-kernel page stream is float)."""
@@ -154,6 +159,7 @@ def chunked_decode_step(decode_model, chunk_size: int, max_seq_len: int,
     from neuronx_distributed_tpu.modules.attention import (
         adopt_kv_pool_pairs,
         cache_cursor,
+        fused_chunk_window,
         fused_paged_attention_scope,
         gather_cache_pages,
         ordered_kv_pool_pairs,
@@ -169,40 +175,51 @@ def chunked_decode_step(decode_model, chunk_size: int, max_seq_len: int,
             "(expected 'gather' or 'fused')"
         )
 
+    def stage(paged, start):
+        """``(staged, page0)``: the collection a paged chunk's scan carries
+        and the model decodes on, and the first page of the fused frame's
+        window (None elsewhere). The ``gather`` transport decodes on the
+        whole logical view; the fused one on its write window alone."""
+        if paged_attention != "fused":
+            return gather_cache_pages(paged, page_size), None
+        return fused_chunk_window(paged, page_size, start, chunk_size)
+
     def chunk_fn(params, cache, state):
         if page_size is None:
             return _row_chunk(params, cache, state)[0]
         paged = cache
         start = cache_cursor(paged)
-        logical = gather_cache_pages(paged, page_size)
+        staged, page0 = stage(paged, start)
         if paged_attention != "fused":
-            out, _ = _row_chunk(params, logical, state)
+            out, _ = _row_chunk(params, staged, state)
             return (
                 scatter_cache_window(
                     paged, out[0], page_size, start, chunk_size
                 ),
             ) + out[1:]
-        n_log = paged["pages"].shape[1]
-        n_win = min((chunk_size - 1) // page_size + 2, n_log)
-        # the view's raw gathers are dead once the view stands: what the
-        # compiler hoists out of the scan (a layout copy per weight) waits
-        # for that and takes their space, not 32 MiB a weight beside them
-        params, logical = jax.lax.optimization_barrier((params, logical))
         out, pools = _row_chunk(
-            params, logical, state,
+            params, staged, state,
             pools=ordered_kv_pool_pairs(paged["pool"]),
-            window=(paged["pages"], page_size, start // page_size, n_win),
+            window=(paged["pages"], page_size, page0),
         )
         # the carried pool is current through the last executed step
         return (adopt_kv_pool_pairs(paged, out[0], pools),) + out[1:]
 
+    # what the program materialises outside the pool (the row layout decodes
+    # on its own storage), for whoever accounts for it from the traced
+    # shapes: the engine's ``kv_view_bytes``
+    chunk_fn.staged = lambda cache: (
+        {} if page_size is None else stage(cache, cache_cursor(cache))[0]
+    )
+
     def _row_chunk(params, cache, state, pools=(), window=None):
         """The row-per-slot chunk on a logical cache: ``(outputs, pools)``.
         Fused mode hands in the page pool (``pools``, every layer's
-        ``(k, v)`` leaves) and its write ``window``: the pool rides the
-        scan's carry beside the logical cache, and each live step scatters
-        its window into it in place and attends it. Elsewhere ``pools`` is
-        empty and adds no leaf to the carry."""
+        ``(k, v)`` leaves) and the frame of its write ``window``, which is
+        all ``cache``'s per-token leaves then hold: the pool rides the
+        scan's carry beside it, and each live step scatters the window
+        into it in place and attends it. Elsewhere ``pools`` is empty and
+        adds no leaf to the carry."""
         temp, topk, topp = state["temp"], state["topk"], state["topp"]
         eos = state["eos"]
         allowed = jnp.clip(max_seq_len - cache_cursor(cache), 0, chunk_size)

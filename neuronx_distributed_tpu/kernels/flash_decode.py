@@ -136,6 +136,40 @@ def paged_scatter_window_leaf(pool: jax.Array, logical: jax.Array,
     return paged_scatter_vals(pool, vals, idx)
 
 
+def paged_gather_window_leaf(pool: jax.Array, block_table: jax.Array,
+                             page0: jax.Array, n_win: int) -> jax.Array:
+    """The ``n_win`` logical pages from page ``page0`` of every slot, read
+    from the pool through the block table: (..., B, n_win * page_size, Hkv,
+    D). What the fused decode chunk stages its new tokens in: the columns it
+    may write and no others (``page0`` traced and already within
+    ``[0, n_log - n_win]``). Block-table ids are pool pages by construction,
+    so the take clips instead of filling."""
+    pax = pool.ndim - 4
+    b = block_table.shape[0]
+    ids = jax.lax.dynamic_slice(block_table, (0, page0), (b, n_win))
+    out = jnp.take(pool, ids, axis=pax, mode="clip")
+    # (..., B, n_win, page_size, Hkv, D) -> merge the page axes
+    return out.reshape(out.shape[:pax] + (b, -1) + out.shape[pax + 3:])
+
+
+def paged_scatter_window_pages(pool: jax.Array, window: jax.Array,
+                               block_table: jax.Array,
+                               page0: jax.Array) -> jax.Array:
+    """Write a window leaf as :func:`paged_gather_window_leaf` gathered it
+    (all of it) back into the pool, at the physical pages the block table
+    maps its logical pages to. Columns the chunk has not written carry the
+    bytes they were gathered with, so shared copy-on-write pages stay
+    bit-stable."""
+    pax = pool.ndim - 4
+    b, page_size = block_table.shape[0], pool.shape[pax + 1]
+    n_win = window.shape[pax + 1] // page_size
+    ids = jax.lax.dynamic_slice(block_table, (0, page0), (b, n_win))
+    vals = window.reshape(
+        window.shape[:pax] + (b * n_win, page_size) + window.shape[pax + 2:]
+    )
+    return paged_scatter_vals(pool, vals, ids.reshape(-1))
+
+
 def paged_write_pages_leaf(pool: jax.Array, pages: jax.Array,
                            page_ids: jax.Array) -> jax.Array:
     """Scatter explicit page blocks into the pool: ``pages`` (..., n,

@@ -167,8 +167,9 @@ def attention_op(q, k, v, causal: bool = True, impl: str = "auto",
 
 # The named scope a trace reader finds device ops by (PERF.md section 3): the
 # paged path's gather of the logical (slots x row) K/V view and its
-# write-back into the pool. Compile-time metadata only; it may not enclose a
-# Pallas call.
+# write-back into the pool (``gather`` transport), or the fused transport's
+# gather of its write window and each step's window scatter. Compile-time
+# metadata only; it may not enclose a Pallas call.
 KV_VIEW_SCOPE = "kv_view"
 
 
@@ -260,8 +261,11 @@ class KVCache:
         rows pass False so their filler tokens never become attendable)."""
         b, s = k.shape[0], k.shape[1]
         cur = self.index.value
+        # inside a fused paged frame the per-token leaves hold the chunk's
+        # write window alone; ``index`` and ``kv_valid`` stay whole
+        col = cur - _fused_window_origin() if _FUSED_PAGED_STACK else cur
         for leaf, new in zip(self.leaves, (k, v)):
-            leaf.value = jax.lax.dynamic_update_slice(leaf.value, new, (0, cur, 0, 0))
+            leaf.value = jax.lax.dynamic_update_slice(leaf.value, new, (0, col, 0, 0))
         self.index.value = cur + s
         if padding_mask is not None:
             if padding_mask.shape != (b, s):
@@ -372,6 +376,19 @@ def cache_bytes_per_token_layer(cache) -> float:
         layers.add((tuple(str(k) for k in path[:-1]), lead))
     n_layers = sum(lead for _, lead in layers)
     return total / n_layers if n_layers else 0.0
+
+
+def per_token_leaf_bytes(tree) -> int:
+    """Bytes of a cache tree's per-token storage leaves
+    (:data:`PAGED_LEAVES`), from their shapes: arrays or what
+    ``jax.eval_shape`` hands back."""
+    import math
+
+    return sum(
+        math.prod(leaf.shape) * leaf.dtype.itemsize
+        for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]
+        if cache_leaf_name(path) in PAGED_LEAVES
+    )
 
 
 def cache_cursor(cache):
@@ -660,25 +677,29 @@ def scatter_cache_window(paged, logical, page_size: int, start_col,
 
 # --- fused paged decode attention (ISSUE 14) ----------------------------------
 #
-# The serving chunk's paged transport gathers the whole logical K/V view
-# before the model ever attends — on TPU that is an HBM round-trip of the
-# full mapped cache per chunk that ``kernels/flash_decode.
-# paged_flash_decode_attention`` (PR 12) exists to eliminate: the block
-# table rides scalar prefetch and the kernel streams each slot's PHYSICAL
-# pool pages directly. The trace-scope below is how the serving chunk routes
-# attention through that kernel without touching the flax modules: while a
-# scope is active, every ``decode_attention`` call takes the next attention
-# layer's (k, v) pool pair — layers call in execution order, the scope names
-# them in the same order — scatters the chunk's write window into it
-# (the in-chunk columns the pool has not seen yet; pre-window columns
-# rewrite their own bytes, so shared CoW pages stay bit-stable), attends
-# straight off the pool through the fused kernel (compiled on the TPU,
-# interpreted only in tests — it never degrades to the gather transport)
-# and leaves the UPDATED pair in the scope's frame. The chunk builder
-# enters the scope once per traced decode step with the pools its scan
-# CARRIES and reads the step's pools back off the frame, so the pool is
-# loop-carried state that XLA scatters into in place (PR 25: closed over,
-# it was copied whole before every step's scatter).
+# The ``gather`` transport materializes the whole logical K/V view before the
+# model ever attends: on TPU an HBM round-trip of the full mapped cache per
+# chunk that ``kernels/flash_decode.paged_flash_decode_attention`` (PR 12)
+# exists to eliminate: the block table rides scalar prefetch and the kernel
+# streams each slot's PHYSICAL pool pages directly. The trace-scope below is
+# how the serving chunk routes attention through that kernel without
+# touching the flax modules. Nothing attends a view there, so the chunk
+# builds none: its cache holds, per per-token leaf, only the chunk's write
+# WINDOW (:func:`fused_chunk_window`, ``n_win`` pages a slot), in which the
+# model's decode write stages each new token (``KVCache.decode_write`` finds
+# the column from the active frame). While a scope is active, every
+# ``decode_attention`` call takes the next attention layer's (k, v) pool
+# pair (layers call in execution order, the scope names them in the same
+# order), scatters the window into it (the in-chunk columns the pool has
+# not seen yet; the window's other columns rewrite their own bytes, so
+# shared CoW pages stay bit-stable), attends straight off the pool through
+# the fused kernel (compiled on the TPU, interpreted only in tests: it never
+# degrades to the gather transport) and leaves the UPDATED pair in the
+# scope's frame. The chunk builder enters the scope once per traced decode
+# step with the pools its scan CARRIES and reads the step's pools back off
+# the frame, so the pool is loop-carried state that XLA scatters into in
+# place (PR 25: closed over, it was copied whole before every step's
+# scatter).
 
 _FUSED_PAGED_STACK: list = []
 
@@ -686,17 +707,18 @@ _FUSED_PAGED_STACK: list = []
 class fused_paged_attention_scope:
     """Trace-scope carrying the paged pool into the decode attention calls
     traced inside it. ``pools`` maps every attention layer to its
-    ``(k_pool, v_pool)`` leaves (:func:`ordered_kv_pool_pairs`); ``page0``/
-    ``n_win`` bound the chunk's write window (the columns the pool does not
-    hold yet). ``__enter__`` returns the frame: after the model apply its
-    ``"pools"`` hold each layer's pair as that layer's window scatter left
-    it."""
+    ``(k_pool, v_pool)`` leaves (:func:`ordered_kv_pool_pairs`); ``page0``
+    is the first logical page of the chunk's write window (the columns the
+    pool does not hold yet), which is all the cache's per-token leaves hold
+    (:func:`fused_chunk_window`). ``__enter__`` returns the frame: after the
+    model apply its ``"pools"`` hold each layer's pair as that layer's
+    window scatter left it."""
 
-    def __init__(self, pools, tables, page_size: int, page0, n_win: int):
+    def __init__(self, pools, tables, page_size: int, page0):
         self.frame = {
             "pools": dict(pools), "order": _execution_order(pools),
             "tables": tables, "page_size": page_size, "page0": page0,
-            "n_win": n_win, "idx": 0,
+            "idx": 0,
         }
 
     def __enter__(self):
@@ -705,6 +727,42 @@ class fused_paged_attention_scope:
 
     def __exit__(self, *exc):
         _FUSED_PAGED_STACK.pop()
+
+
+def _fused_window_origin():
+    """Logical column of the window leaves' column 0 in the active frame."""
+    frame = _FUSED_PAGED_STACK[-1]
+    return frame["page0"] * frame["page_size"]
+
+
+def fused_chunk_window(paged, page_size: int, start_col, chunk_size: int):
+    """``(cache, page0)`` for a fused decode chunk over the paged pytree:
+    ``cache`` is the collection the model decodes on, its per-token leaves
+    (:data:`PAGED_LEAVES`) holding ONLY the chunk's write window, ``(...,
+    B, n_win * page_size, heads, width)``: the ``n_win = (chunk_size - 1)
+    // page_size + 2`` logical pages a ``chunk_size``-column window can
+    overlap wherever it starts, from page ``page0`` = that of ``start_col``
+    (the traced entry cursor), held inside the row as
+    :func:`scatter_cache_window` holds its own, gathered through the block
+    table from those pages alone. ``index``/``kv_valid`` are the pool
+    tree's, logical and whole."""
+    from neuronx_distributed_tpu.kernels.flash_decode import (
+        paged_gather_window_leaf,
+    )
+
+    bt = paged["pages"]
+    n_log = bt.shape[1]
+    n_win = min((chunk_size - 1) // page_size + 2, n_log)
+    page0 = jnp.clip(start_col // page_size, 0, n_log - n_win)
+
+    def fn(path, leaf):
+        if cache_leaf_name(path) not in PAGED_LEAVES:
+            return leaf
+        return paged_gather_window_leaf(leaf, bt, page0, n_win)
+
+    with jax.named_scope(KV_VIEW_SCOPE):
+        cache = jax.tree_util.tree_map_with_path(fn, paged["pool"])
+    return cache, page0
 
 
 def _execution_order(layers):
@@ -777,33 +835,37 @@ def adopt_kv_pool_pairs(paged, logical, pairs):
     }
 
 
-def _fused_paged_decode(frame, q, caches, q_pos, kv_valid, latent_scale=None):
-    """``caches``: the layer's logical leaves as its cache kind orders them,
+def _fused_paged_decode(frame, q, caches, q_pos, kv_valid, latent_scale=None,
+                        mask=None):
+    """``caches``: the layer's window leaves as its cache kind orders them,
     ``(k, v)`` or a latent cache's ``(c, k_pe)``; for the latter ``q`` is the
     absorbed pair ``(q_c, q_r)`` and ``latent_scale`` the softmax scale."""
+    if mask is not None:
+        raise ValueError(
+            "a tree mask replaces the positional mask the paged kernels "
+            "implement, and a fused paged frame holds no K/V view for the "
+            "einsum to attend: use the gather transport"
+        )
     from neuronx_distributed_tpu.kernels.flash_decode import (
         paged_flash_decode_attention,
         paged_latent_decode_attention,
-        paged_scatter_window_leaf,
+        paged_scatter_window_pages,
     )
 
     pools, order = frame["pools"], frame["order"]
     layer = order[frame["idx"] % len(order)]
     frame["idx"] += 1
     ps, bt = frame["page_size"], frame["tables"]
-    # bring the pool current through THIS step: scatter the chunk window
-    # from the logical view (which the model just wrote) into the carried
-    # pool — columns before the window rewrite their own bytes, so the
-    # scatter is idempotent on shared pages and the pool equals the logical
-    # view wherever kv_valid holds
+    # bring the pool current through THIS step: scatter the window (in which
+    # the model just staged its token) into the carried pool. Its other
+    # columns rewrite the bytes they were gathered with, so the scatter is
+    # idempotent on shared pages
     # the scope ends BEFORE the kernel: a Pallas kernel is named after the
     # scope it is called in, and trace readers find it by that name
     with jax.named_scope(KV_VIEW_SCOPE):
         pair = tuple(
-            paged_scatter_window_leaf(
-                pool, cache, bt, frame["page0"], frame["n_win"], ps
-            )
-            for pool, cache in zip(pools[layer], caches)
+            paged_scatter_window_pages(pool, window, bt, frame["page0"])
+            for pool, window in zip(pools[layer], caches)
         )
     pools[layer] = pair  # trace-time: the step's carry-out
     if latent_scale is not None:
@@ -862,9 +924,10 @@ def decode_attention(q, k_cache, v_cache, q_pos, mask=None, kv_valid=None):
     ``paged_attention="fused"`` transport, ISSUE 14) the call attends the
     PAGED POOL directly through ``paged_flash_decode_attention`` instead of
     the materialized view passed in."""
-    if _FUSED_PAGED_STACK and mask is None:
+    if _FUSED_PAGED_STACK:
         return _fused_paged_decode(
-            _FUSED_PAGED_STACK[-1], q, (k_cache, v_cache), q_pos, kv_valid
+            _FUSED_PAGED_STACK[-1], q, (k_cache, v_cache), q_pos, kv_valid,
+            mask=mask,
         )
     if mask is None and resolve_decode_impl(k_cache.shape[1]) == "flash_decode":
         from neuronx_distributed_tpu.kernels.flash_decode import (
@@ -903,10 +966,10 @@ def latent_decode_attention(q_c, q_r, c_cache, r_cache, q_pos, scale,
     through ``paged_latent_decode_attention`` (the kernel, or nothing);
     elsewhere this einsum, on every platform (there is no row-cache latent
     kernel: the engine records ``decode_attention: "einsum"``)."""
-    if _FUSED_PAGED_STACK and mask is None:
+    if _FUSED_PAGED_STACK:
         return _fused_paged_decode(
             _FUSED_PAGED_STACK[-1], (q_c, q_r), (c_cache, r_cache), q_pos,
-            kv_valid, latent_scale=scale,
+            kv_valid, latent_scale=scale, mask=mask,
         )
     c = c_cache[:, :, 0].astype(jnp.float32)           # (B, L, d_c)
     s = (

@@ -241,6 +241,8 @@ from neuronx_distributed_tpu.modules.attention import (
     cache_bytes_per_token_layer,
     cache_fingerprint,
     extract_cache_prefix,
+    gather_cache_pages,
+    per_token_leaf_bytes,
     resolve_decode_impl,
     seed_cache_prefix,
 )
@@ -879,6 +881,11 @@ class ServingEngine:
         # a failed speculative dispatch ever needs the non-speculative
         # fallback
         if draft_model is not None:
+            # the speculative chunk decodes on both models' whole views
+            self._stage = lambda cache: (
+                {} if kv_page_size is None
+                else gather_cache_pages(cache, kv_page_size)
+            )
             self._spec_chunk = jax.jit(
                 speculative_decode_chunk(
                     self._decode_model, self._draft_decode_model,
@@ -898,17 +905,16 @@ class ServingEngine:
             self._decode_chunk = None
         else:
             self._spec_chunk = None
-            self._decode_chunk = jax.jit(
-                chunked_decode_step(
-                    self._decode_model, decode_chunk_size, max_seq_len,
-                    page_size=kv_page_size,
-                    paged_attention=(
-                        self.paged_attention
-                        if kv_page_size is not None else "gather"
-                    ),
+            chunk = chunked_decode_step(
+                self._decode_model, decode_chunk_size, max_seq_len,
+                page_size=kv_page_size,
+                paged_attention=(
+                    self.paged_attention
+                    if kv_page_size is not None else "gather"
                 ),
-                donate_argnums=(1, 2),
             )
+            self._stage = chunk.staged
+            self._decode_chunk = jax.jit(chunk, donate_argnums=(1, 2))
             self._decode_chunk = self.programs.wrap(
                 "decode_chunk", self._comms_scoped(self._decode_chunk)
             )
@@ -2308,6 +2314,19 @@ class ServingEngine:
             )
         return int(round(self.metrics.kv_bytes_per_token_layer))
 
+    def _kv_view_bytes(self) -> int:
+        """Bytes of per-token leaves the decode program materialises
+        outside the page pool per chunk, from the shapes it traces: the
+        ``gather`` transport's logical view (the speculative chunk's two),
+        the fused transport's write window, nothing for the row layout;
+        the ``serving_kv_view_bytes`` gauge carries the same."""
+        if self.metrics.kv_view_bytes is None:
+            self.metrics.record_kv_view_bytes(sum(
+                per_token_leaf_bytes(jax.eval_shape(self._stage, mgr.cache))
+                for mgr in (self.cache, self.draft_cache) if mgr is not None
+            ))
+        return self.metrics.kv_view_bytes
+
     def _span(self, name: str, **stats):
         """A phase of ``step()``: the one span primitive, bound to this
         engine's timeline (``observability/tracing.py``)."""
@@ -3259,6 +3278,7 @@ class ServingEngine:
         with self._span(
             tracing.STEP_DISPATCH, active=active_at_dispatch,
             kv_bytes_per_token_layer=self._kv_bytes_per_token_layer(),
+            kv_view_bytes=self._kv_view_bytes(),
         ):
             cache_in = self.cache.take()
             draft_in = self.draft_cache.take()
@@ -3446,6 +3466,7 @@ class ServingEngine:
         with self._span(
             tracing.STEP_DISPATCH, active=active_at_dispatch,
             kv_bytes_per_token_layer=self._kv_bytes_per_token_layer(),
+            kv_view_bytes=self._kv_view_bytes(),
         ):
             cache_in = self.cache.take()
             attempt = self._dispatch_attempts

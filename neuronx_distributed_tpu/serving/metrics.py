@@ -225,6 +225,11 @@ class ServingMetrics:
             "serving_kv_bytes_per_token_layer",
             help="bytes one token holds in one attention layer's cache",
         )
+        self._g_kv_view_bytes = self.view.gauge(
+            "serving_kv_view_bytes",
+            help="bytes of per-token cache leaves the decode program "
+                 "materialises outside the page pool per chunk",
+        )
         self._g_health = self.view.gauge(
             "serving_health", help="0=ok 1=degraded 2=draining 3=halted"
         )
@@ -277,6 +282,10 @@ class ServingMetrics:
         # bytes a token holds per attention layer, from the allocated cache
         # leaves (0 until the first admission allocates them)
         self.kv_bytes_per_token_layer = 0.0
+        # bytes of per-token leaves the decode program materialises outside
+        # the page pool per chunk (None until the first dispatch reads the
+        # program's shapes; 0 for the row layout, which has no pool)
+        self.kv_view_bytes = None
         # device-efficiency ledgers (ISSUE 12): attached weakly by the
         # engine so snapshot() can carry "programs"/"hbm" without a kept
         # metrics object pinning a retired engine's ledgers
@@ -590,6 +599,10 @@ class ServingMetrics:
         self.kv_bytes_per_token_layer = float(per_token_layer)
         self._g_kv_bytes.set(per_token_layer)
 
+    def record_kv_view_bytes(self, nbytes: int) -> None:
+        self.kv_view_bytes = int(nbytes)
+        self._g_kv_view_bytes.set(nbytes)
+
     def record_decode_chunk(
         self,
         tokens: int,
@@ -787,6 +800,7 @@ class ServingMetrics:
             "health": self.health,
             "cursor_high_water": self.cursor_high_water,
             "kv_bytes_per_token_layer": self.kv_bytes_per_token_layer,
+            "kv_view_bytes": self.kv_view_bytes,
             "mean_occupancy": self.mean_occupancy,
             "mean_ttft": _mean(ttfts),
             "max_ttft": max(ttfts) if ttfts else 0.0,

@@ -265,14 +265,17 @@ def test_tensor_parallel_serving_refuses_a_latent_cache_model(setup):
 # prefill and paged decode chunk, both transports, taken on the PARENT commit
 # of the PR that added the latent cache (4823593): what that PR generalised
 # (KVCache's leaves, the pool pairing, the fused decode, the flash kernel's
-# value head size) must leave these programs exactly as they were.
+# value head size) must leave these programs exactly as they were. The two
+# ``decode.fused`` digests were taken anew by the PR that made the fused chunk
+# gather its write window instead of the logical view (PR 27); that PR left
+# the other four, prefill and the ``gather`` chunk, as they are.
 PARENT_PROGRAMS = {
     "mixtral.prefill": "e0d71476f6d38b847454df722dd626fe5ecbef1e0635893022a29220a2e62e69",
     "mixtral.decode.gather": "f83929d86aba6a010d0dd0b09b39dc77a2821abaed89b52de60775bd11382d30",
-    "mixtral.decode.fused": "9337c1382161d8e1a25b428b607f2ffa4cfd3c17ee3d082f16fe1b5c7d8cdcd9",
+    "mixtral.decode.fused": "dd56949aa1be557b65126e73c20de4195b99c9a186241977a99428cb3102c0df",
     "codegen.prefill": "2ea437e83f3d502ceb35b1052f326594d3425b7c623598af85f683630941d54a",
     "codegen.decode.gather": "62e88bb2a8cef0f633d4819199e6bf72c6007083536647d5145b86413f3e75bc",
-    "codegen.decode.fused": "b4d408142cdd981f1bddf48dd1ea139bfa3fbd1d813431bdf8cc8c3f3814a1b2",
+    "codegen.decode.fused": "b1c3be63e8f332d846e594f8575d781edab479adc3cb67be2985b64a5b4ad818",
 }
 
 
