@@ -644,8 +644,7 @@ def main(argv=None):
     if args.replicas > 1:
         if args.disaggregate:
             raise SystemExit(
-                "--replicas and --disaggregate are separate demos — pick "
-                "one (the bench composes them)"
+                "--replicas and --disaggregate are separate demos — pick one"
             )
         return _run_router(args, cfg, model, params)
 
@@ -663,9 +662,8 @@ def main(argv=None):
         # norm/head), with the target's LATER layers eps-scaled so draft
         # and target actually agree — the synthetic-acceptance dial
         # (random tiny-model weights would accept ~nothing and show
-        # speculation at its worst, which is the bench's job, not the
-        # demo's). eps=0.02 gives ~0.8 per-round acceptance on GREEDY
-        # slots; the demo's mixed workload also carries sampled requests,
+        # speculation at its worst, which is not the demo's job).
+        # eps=0.02 gives ~0.8 per-round acceptance on GREEDY slots; the demo's mixed workload also carries sampled requests,
         # which accept nothing BY DESIGN (one exactly-sampled token per
         # round) and dilute the headline rate
         params, draft_params = early_exit_draft_params(

@@ -13,7 +13,7 @@ rung of the fallback ladder:
 
 1. **Persistent compilation cache** (:func:`enable_persistent_cache`) —
    the ONE owner of ``jax_compilation_cache_dir`` wiring, used by the
-   engine, builder, trainer, bench children, and the test suite. Keyed by
+   engine, builder, trainer, examples, and the test suite. Keyed by
    XLA on the optimized HLO. A directory placed from outside
    (``JAX_COMPILATION_CACHE_DIR``) is left alone; the default one is
    namespaced per host-CPU fingerprint on the CPU backend only
@@ -62,13 +62,13 @@ __all__ = [
     "UnportableError",
     "XLA_SUBDIR",
     "call_signature",
+    "compile_serializable",
     "enable_persistent_cache",
     "load_executable",
     "materialize_call",
     "persistent_cache_dir",
     "prewarm_programs",
     "save_executable",
-    "serializable_compiles",
 ]
 
 MANIFEST_NAME = "manifest.json"
@@ -453,25 +453,29 @@ def _skew_header() -> dict:
     }
 
 
-@contextlib.contextmanager
-def serializable_compiles():
-    """Run compiles whose results will feed :func:`save_executable` with
-    the persistent disk cache BYPASSED. An XLA:CPU executable that was
-    LOADED from the disk cache serializes WITHOUT its jitted object code —
-    the payload round-trips in-process but deserializes in a fresh process
-    to ``INTERNAL: Symbols not found`` (measured on this jax/jaxlib). A
-    fresh compile embeds the code; the bypass costs one real compile per
-    saved program, paid once at save time."""
+def compile_serializable(lowered):
+    """Compile ``lowered`` afresh for :func:`save_executable`, with the
+    persistent disk cache BYPASSED and whatever this process compiled
+    before. An XLA:CPU executable that was LOADED from the disk cache
+    serializes WITHOUT its jitted object code — the payload round-trips
+    in-process but fails in a fresh process (``Function ... not found``;
+    a program with a sort does not serialize at all) — while a fresh
+    compile embeds the code: one real compile per saved program, paid
+    once at save time. Turning the cache off is not enough: ``jit.lower``
+    hands back the lowering the dispatch built, and jax memoises that
+    lowering's executable (on the lowering, and again by its module in
+    ``pxla._cached_compilation``), so a plain ``lowered.compile()``
+    returns the executable the traffic ran with. A compiler option is
+    part of both memo keys: naming one at its default value changes
+    nothing in the program and reaches the compiler."""
     import jax
 
-    try:
-        prev = bool(jax.config.jax_enable_compilation_cache)
-    except AttributeError:  # knob absent on this jax: nothing to bypass
-        yield
-        return
+    prev = bool(jax.config.jax_enable_compilation_cache)
     jax.config.update("jax_enable_compilation_cache", False)
     try:
-        yield
+        return lowered.compile(
+            compiler_options={"xla_embed_ir_in_executable": False}
+        )
     finally:
         jax.config.update("jax_enable_compilation_cache", prev)
 
@@ -483,7 +487,13 @@ def save_executable(dirpath: str, name: str, signature: str, compiled) -> str:
     from jax.experimental import serialize_executable as se
 
     payload, in_tree, out_tree = se.serialize(compiled)
-    header = dict(_skew_header(), name=name, signature=signature)
+    # the ids of the devices it was compiled for: ``deserialize_and_load``
+    # loads onto every device of the backend unless told, and a one-device
+    # program loaded onto eight then wants eight shards of each argument
+    devices = [d.id for d in compiled.runtime_executable().local_devices()]
+    header = dict(
+        _skew_header(), name=name, signature=signature, devices=devices
+    )
     blob = pickle.dumps(
         (header, payload, in_tree, out_tree),
         protocol=pickle.HIGHEST_PROTOCOL,
@@ -525,9 +535,14 @@ def load_executable(dirpath: str, name: str, signature: str):
                 f"host wants {expect!r}"
             )
     try:
+        import jax
         from jax.experimental import serialize_executable as se
 
-        return se.deserialize_and_load(payload, in_tree, out_tree)
+        by_id = {d.id: d for d in jax.devices()}
+        return se.deserialize_and_load(
+            payload, in_tree, out_tree,
+            execution_devices=[by_id[i] for i in header["devices"]],
+        )
     except Exception as e:
         raise SkewError(
             f"deserialize failed for {name}@{signature}: "
@@ -624,8 +639,6 @@ def prewarm_programs(
                 flight.record(event, **kw)
             except Exception:
                 pass
-
-    import contextlib
 
     scope = ledger.prewarming() if ledger is not None else contextlib.nullcontext()
     with scope:

@@ -165,12 +165,8 @@ class ModelBuilder:
                     t0 = time.perf_counter()
                     lowered = jitted.lower(*args)
                     if aot is not None:
-                        # this executable will be serialized: bypass the
-                        # disk cache so the payload embeds its object code
-                        # (a cache-hit executable cannot cross processes —
-                        # aot.serializable_compiles)
-                        with aot.serializable_compiles():
-                            compiled = lowered.compile()
+                        # this executable will be serialized
+                        compiled = aot.compile_serializable(lowered)
                     else:
                         compiled = lowered.compile()
                     wall = time.perf_counter() - t0

@@ -65,9 +65,8 @@ class SentinelConfig:
 
     ``check_every`` — steps between integrity checks. Detection latency
     is bounded by it; so is overhead (one fingerprint reduction per check
-    in vote mode, one extra train step per check in canary mode — at the
-    default 16 that is <2% and ~6% respectively on the CPU proxy, see
-    ``bench.py --child-integrity``). ``mode`` — ``auto`` resolves to
+    in vote mode, one extra train step per check in canary mode; not
+    measured on the chip). ``mode`` — ``auto`` resolves to
     ``vote`` when the mesh has dp >= 2 replicas, else ``canary``."""
 
     check_every: int = 16

@@ -77,8 +77,9 @@ def _sharded_blockwise_mlp(mesh, ep_ax, tp_ax, E_l: int, ep: int, glu: bool,
     and scatter-adds its weighted outputs straight onto the (T, H) combine
     buffer. One gather + one scatter, both unavoidable in any dropless MoE;
     the rolls are gone and the stacked output shrinks from (N, H) to (T, H)
-    rows (N = k·T). Timed against the legacy roll formulation by bench.py's
-    parallel proxy (``extras.parallel_proxy.blockwise_ep``)."""
+    rows (N = k·T). Pinned against the unsharded golden by
+    ``tests/modules/test_moe.py::test_blockwise_ep_sharded_matches_golden``;
+    not measured on the chip (no cell runs ep > 1)."""
     axes = tuple(a for a in (ep_ax, tp_ax) if a)
     wspec_col = P(ep_ax, None, tp_ax)
     wspec_row = P(ep_ax, tp_ax, None)
@@ -173,45 +174,6 @@ def _sharded_blockwise_mlp_manual(mesh, edp_ax, ep_ax, tp_ax, E: int,
             in_specs=(tok_spec, tok_spec, tok_spec, wspec_col, wspec_col,
                       wspec_row),
             out_specs=tok_spec,
-            axis_names=set(axes),
-            check_vma=False,
-        )
-    )
-
-
-@functools.lru_cache(maxsize=None)
-def _sharded_blockwise_mlp_rolled(mesh, ep_ax, tp_ax, E_l: int, ep: int,
-                                  glu: bool, act: str):
-    """LEGACY double-roll EP alignment — kept ONLY as the baseline for the
-    bench proxy's timed comparison against the local-offset-gather path
-    above (VERDICT r3 next #10 'Done = a timed comparison'); no production
-    caller."""
-    axes = tuple(a for a in (ep_ax, tp_ax) if a)
-    wspec_col = P(ep_ax, None, tp_ax)
-    wspec_row = P(ep_ax, tp_ax, None)
-
-    def sharded_mlp(xs_, sizes, gate_, up_, down_):
-        N = xs_.shape[0]
-        ep_rank = jax.lax.axis_index(ep_ax) if ep > 1 else 0
-        local_sizes = jax.lax.dynamic_slice_in_dim(sizes, ep_rank * E_l, E_l)
-        offsets = jnp.concatenate(
-            [jnp.zeros((1,), sizes.dtype), jnp.cumsum(sizes)]
-        )
-        start = offsets[ep_rank * E_l]
-        n_local = local_sizes.sum()
-        xs_rolled = jnp.roll(xs_, -start, axis=0)
-        y = _grouped_mlp(xs_rolled, gate_, up_, down_, local_sizes,
-                         glu=glu, act=act)
-        valid = (jnp.arange(N) < n_local)[:, None]
-        y = jnp.roll(jnp.where(valid, y, 0), start, axis=0)
-        return y[None, None]
-
-    return jax.jit(
-        mesh_lib.compat_shard_map(
-            sharded_mlp,
-            mesh=mesh,
-            in_specs=(P(), P(), wspec_col, wspec_col, wspec_row),
-            out_specs=P(ep_ax, tp_ax, None, None),
             axis_names=set(axes),
             check_vma=False,
         )

@@ -528,7 +528,7 @@ class ProgramLedger:
     lazily-resolved flops/MFU gauges) into the shared metrics surface; with
     neither, the ledger owns a private registry so ``snapshot()`` always
     works. ``memory_analysis=True`` opts into one extra AOT compile per
-    signature to obtain ``memory_analysis()`` numbers (bench/builder
+    signature to obtain ``memory_analysis()`` numbers (builder
     contexts); the default keeps those fields UNAVAILABLE with zero extra
     compiles. Export gauges hold only weak references to the ledger — a
     registry an operator keeps alive never pins retired programs."""

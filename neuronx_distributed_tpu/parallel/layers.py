@@ -504,7 +504,7 @@ class ParallelEmbedding(nn.Module):
         # under ZeRO-1 the table arrives with H sharded over (edp, ep, cp),
         # and inside the region that sharding collides with the (B, S)-
         # sharded mask of the where() — the SPMD partitioner resolved it by
-        # involuntary full rematerialization (MULTICHIP_r04.json CP phase)
+        # involuntary full rematerialization (seen in a cp=2 CPU dry run)
         table = constrain(table, P(self.axis))
         return _vocab_parallel_lookup(
             mesh if ctx_mesh.empty else ctx_mesh, self.axis

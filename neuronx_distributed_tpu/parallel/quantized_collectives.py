@@ -175,8 +175,9 @@ def comm_bytes(n_elems: int, n_ranks: int, block_size: int = 256,
                fp_bytes: int = 4,
                scale_granularity: str = "block") -> dict:
     """Wire-byte accounting of one all-reduce of ``n_elems`` elements over
-    ``n_ranks`` — the EQuARX claim as arithmetic, reported by
-    ``bench.py --child-quant``. Both phases of the ring move
+    ``n_ranks`` — the EQuARX claim as arithmetic, pinned by
+    ``tests/parallel/test_quantized_collectives.py::test_comm_bytes_accounting``
+    and, off the lowered IR, by graftverify's GV03 table. Both phases of the ring move
     ``(N-1)/N · n`` elements per rank; the quantized payload is 1 byte per
     element plus 4 scale bytes per block (blockwise) or per hop (the
     abs-max fallback's single scalar)."""

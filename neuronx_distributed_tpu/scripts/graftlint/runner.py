@@ -1,5 +1,5 @@
 """graftlint orchestration: collect files, run rules, apply pragmas and the
-baseline ratchet. Importable API (the tier-1 test and bench.py call
+baseline ratchet. Importable API (the tier-1 test calls
 :func:`run`) — the CLI in ``cli.py`` is a thin shell over it."""
 
 from __future__ import annotations

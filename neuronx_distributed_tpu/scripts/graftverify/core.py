@@ -54,8 +54,9 @@ nothing in the repo caught it until graftverify.
 Check: every flattened argument declared donated (``Lowered.args_info``)
 that pjit KEEPS must materialize in the lowered StableHLO as either a
 ``tf.aliasing_output`` attribute (jax paired it at lowering — the
-mesh-free path) or ``jax.buffer_donor = true`` (a mesh program: pairing
-is deferred to XLA because output shardings are compile-time — the
+mesh-free path) or ``jax.buffer_donor = true`` (pairing is deferred to
+XLA: a mesh program, whose output shardings are compile-time, or a donor
+that matches an unpaired output in size but not in aval — the
 declaration provably reached the IR). A donated-but-UNUSED arg is pruned
 by pjit (keep_unused=False): freed, never copied, counted separately. A
 kept, used, unmarked donation is the dropped-donation bug; the finding
@@ -84,7 +85,7 @@ target names a python/host callback. Sharding markers (``Sharding``,
 GV03 collective wire-byte ratchet
 
 The EQuARX quantized all-reduce path (PAPERS.md arXiv 2506.17615) claims a
-~3.94x wire-byte reduction per decode step. A bench can only observe it;
+~3.94x wire-byte reduction per decode step. A timed run can only observe it;
 the lowered IR can PIN it: every collective op (all_reduce, all_gather,
 reduce_scatter, collective_permute, all_to_all) is enumerated with its
 element count, element bytes, and a per-rank ring-model wire-byte figure.

@@ -200,12 +200,14 @@ def donation_table(lowered) -> dict:
     attribute in the StableHLO (the aliases jax computed at lowering).
     ``deferred`` — kept positions carrying ``jax.buffer_donor = true``:
     under a mesh jax cannot pair donors with outputs until the compiler
-    fixes shardings, so it forwards the donation to XLA verbatim — the
-    declaration provably REACHED the IR; the pairing itself is
-    compile-time (the one check lowering alone cannot close).
+    fixes shardings, and mesh-free it finds no output of the donor's aval
+    but an unpaired one of its SIZE; either way it forwards the donation
+    to XLA verbatim — the declaration provably REACHED the IR; the
+    pairing itself is compile-time (the one check lowering alone cannot
+    close).
     ``dropped`` — declared, KEPT, and neither aliased nor deferred: the
     donated buffer is read but its bytes are silently copied every
-    dispatch (dtype/layout mismatch against every output) — the
+    dispatch (no output of its aval or its size is left to take it) — the
     HBM-doubling bug GV01 catches.
 
     MLIR argument j is flat position ``sorted(kept_var_idx)[j]`` —

@@ -1,7 +1,7 @@
 """graftverify orchestration: enumerate ledgered programs, lower, check,
 ratchet.
 
-The importable API (tests, bench.py and the CLI all call :func:`verify`)
+The importable API (the tests and the CLI both call :func:`verify`)
 mirrors graftlint's runner: a run produces a report whose findings are
 graftlint ``Violation``s, diffed against the checked-in
 ``graftverify_baseline.json`` with the SAME fingerprint ratchet (new
@@ -92,7 +92,7 @@ class ProgramAudit:
 @dataclasses.dataclass
 class VerifyReport:
     """One run's outcome, shaped like graftlint's Report: post-waiver
-    findings plus the audit data the byte tables and bench extras read."""
+    findings plus the audit data the byte tables read."""
 
     findings: List[Violation]
     suppressed: List[Violation]
@@ -118,7 +118,7 @@ class VerifyReport:
             counts[v.rule] = counts.get(v.rule, 0) + 1
         return dict(sorted(counts.items()))
 
-    # --- aggregates (bench extras / CLI summary) -----------------------------
+    # --- aggregates (CLI summary) ---------------------------------------------
 
     def stats(self) -> dict:
         donations_declared = 0

@@ -63,7 +63,8 @@ Layers:
   arrivals, chat vs long-doc length mixes) materialized as a
   byte-identical arrival tape, replayed through the engine on a
   :class:`VirtualClock` so the per-tenant TTFT/TPOT/goodput/attainment
-  report is reproducible to the byte (``bench.py --child-traffic``).
+  report is reproducible to the byte
+  (``tests/serving/test_traffic.py::test_same_seed_identical_slo_report``).
 
 Observability (ISSUE 8, ``neuronx_distributed_tpu/observability``): the
 metrics above live in a shared ``MetricsRegistry`` (Prometheus/JSON

@@ -4,9 +4,9 @@ A coupled engine runs prefill INSIDE its serving loop: at a chunk boundary
 it admits a whole selection round, paying every prefill's wall before the
 next decode chunk dispatches. Under bursty arrivals that is exactly when a
 burst lands — steady-state decoders stall behind a queue of prompt
-prefills, and TPOT p99 inflates with the arrival rate (measured by
-``bench.py --child-multichip`` replaying the ISSUE 11 bursty tape through
-both topologies).
+prefills, and TPOT p99 inflates with the arrival rate (not measured on
+the chip: no cell runs a disaggregated server; the bound itself is pinned
+by ``tests/serving/test_disagg.py``).
 
 This module splits the two phases:
 

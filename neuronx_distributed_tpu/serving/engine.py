@@ -508,7 +508,6 @@ class ServingEngine:
         registry=None,
         engine_label: Optional[str] = None,
         slo=None,
-        program_ledger=None,
         flight_recorder="auto",
         flight_dir: Optional[str] = None,
         time_fn: Callable[[], float] = time.monotonic,
@@ -817,15 +816,11 @@ class ServingEngine:
         # below registers in a ProgramLedger — dispatch counts, compile
         # wall, compiler-reported FLOPs/bytes (lazy cost analysis at
         # export, never on the hot path) and roofline telemetry off the
-        # chunk walls the loop already measures. Pass program_ledger= to
-        # share one ledger (e.g. bench's memory_analysis=True instance);
-        # the default rides the engine's labeled metrics view
-        self.programs = (
-            program_ledger if program_ledger is not None
-            else ProgramLedger(
-                view=self.metrics.view, prefix="serving",
-                subsystem="serving", timeline=timeline,
-            )
+        # chunk walls the loop already measures. It rides the engine's
+        # labeled metrics view
+        self.programs = ProgramLedger(
+            view=self.metrics.view, prefix="serving",
+            subsystem="serving", timeline=timeline,
         )
         self.cache.register_programs(self.programs)
         if self.draft_cache is not None:
@@ -2233,7 +2228,7 @@ class ServingEngine:
         per-program ``lower().compile()`` each serialization needs runs
         with the disk cache BYPASSED — a cache-loaded executable
         serializes without its object code and cannot cross a process
-        boundary (aot.serializable_compiles). Per-program failures are
+        boundary (aot.compile_serializable). Per-program failures are
         skipped and reported, never raised."""
         from neuronx_distributed_tpu.inference import aot
 
@@ -2264,11 +2259,7 @@ class ServingEngine:
                     if lowered is None:
                         report["skipped"][key] = "signature not captured"
                         continue
-                    # bypass the disk cache for THIS compile: a cache-hit
-                    # executable serializes without object code and fails
-                    # cross-process (see aot.serializable_compiles)
-                    with aot.serializable_compiles():
-                        compiled = lowered.compile()
+                    compiled = aot.compile_serializable(lowered)
                     aot.save_executable(
                         cache_dir, name, var.signature, compiled
                     )

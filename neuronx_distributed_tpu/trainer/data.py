@@ -219,8 +219,7 @@ class SyntheticTokens:
     """Seeded infinite random-token batches with the ``state()/restore()``
     exact-resume protocol (O(1) restore: batch ``i`` is drawn from
     ``SeedSequence([seed, i])``, so the cursor is just ``i``). The hermetic
-    stand-in for a tokenized corpus in examples, bench children, and chaos
-    tests.
+    stand-in for a tokenized corpus in examples and chaos tests.
 
     ``emit_mask`` attaches an all-ones ``loss_mask`` — numerically the
     plain mean loss, but its presence lets the chaos

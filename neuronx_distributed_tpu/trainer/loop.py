@@ -324,12 +324,6 @@ class Trainer:
     # when the run halts. None = fit() builds one whose dumps land next to
     # the checkpoints (memory-only when no checkpoint dir is known).
     flight_recorder: Optional[Any] = None
-    # Compiled-program ledger (observability/programs.py, ISSUE 12): the
-    # train/eval steps register through it — dispatch counts, compile
-    # wall, compiler-reported FLOPs/bytes, per-step roofline off the
-    # inter-step wall the loop already measures. None = fit() builds one
-    # (sharing a MetricsCallback's registry when one is attached).
-    program_ledger: Optional[Any] = None
     # Install SIGTERM/SIGINT graceful-preemption handlers during fit()
     # (main thread only; a second signal falls through to the original
     # handler).
@@ -1002,7 +996,7 @@ class Trainer:
         # re-fits ACCUMULATE (a rebuilt train step wraps the same record);
         # rides a MetricsCallback's registry when one is attached so the
         # per-step achieved-FLOPs/MFU gauges share the scrape surface
-        if self.program_ledger is None:
+        if getattr(self, "programs", None) is None:
             from neuronx_distributed_tpu.observability.callback import (
                 MetricsCallback,
             )
@@ -1015,11 +1009,10 @@ class Trainer:
                 if isinstance(cb, MetricsCallback):
                     reg = cb.registry
                     break
-            self.program_ledger = ProgramLedger(
+            self.programs = ProgramLedger(
                 registry=reg, prefix="train", subsystem="trainer",
                 timeline=tl,
             )
-        self.programs = self.program_ledger
         impl = getattr(self.model, "attention_impl", None)
         if impl is not None:
             # what "auto" resolved to on THIS device and mesh — the

@@ -13,6 +13,17 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 _flags = os.environ.get("XLA_FLAGS", "")
 if "--xla_force_host_platform_device_count" not in _flags:
     os.environ["XLA_FLAGS"] = (_flags + " --xla_force_host_platform_device_count=8").strip()
+# The CPU client sizes its thread pools by the host's cores (8 here), and a
+# collective of the virtual mesh BLOCKS its pool thread until every
+# participant has joined. One program with two independent collectives in
+# flight on 8 devices (a pp=2 x tp=2 1f1b step: the stages' collective-permute
+# beside a tp pair's all-gather) can so hold every thread in a rendezvous
+# while the participants that would complete one wait for a thread: XLA aborts
+# the process after 40 s ("Termination timeout ... Exiting to ensure a
+# consistent program state"). Seen once in the driver's tier-1 run of PR 27;
+# under 14 processes of test_combinatorial_matrix.py beside a six-worker run,
+# 4 of 28 runs with pools of 8 and none of 28 with pools of 32 (PR 28).
+os.environ.setdefault("PJRT_NPROC", "32")
 
 import jax  # noqa: E402
 

@@ -66,14 +66,16 @@ def test_clean_donation_aliases_and_counts():
 
 
 def test_injected_dropped_donation_flags_gv01():
-    """The acceptance fixture: a donated leaf whose dtype matches NO
-    output — XLA silently drops the alias, graftverify must flag it."""
+    """The acceptance fixture: a donated leaf that NO output can take —
+    jax silently drops the donation, graftverify must flag it."""
     led = ProgramLedger()
 
     def f(state, x):
-        # state["c"] is int32 and USED, but every output is float32:
-        # the donation cannot alias and the buffer is copied each dispatch
-        return state["a"] + x, state["c"].astype(jnp.float32) * 2
+        # state["c"] is int32[8] and USED, but every output is float32[4].
+        # jax pairs a donor with an output of its aval, else hands it to
+        # XLA (``jax.buffer_donor``) when an unpaired output has its SIZE;
+        # 8 elements match neither, so the buffer is copied each dispatch
+        return state["a"] + x, state["c"][:4].astype(jnp.float32) * 2
 
     fn = led.wrap("bad", jax.jit(f, donate_argnums=(0,)))
     import warnings
@@ -82,7 +84,7 @@ def test_injected_dropped_donation_flags_gv01():
         warnings.simplefilter("ignore")  # jax's own dropped-donation note
         fn(
             {"a": jnp.zeros((4,), jnp.float32),
-             "c": jnp.zeros((4,), jnp.int32)},
+             "c": jnp.zeros((8,), jnp.int32)},
             jnp.ones((4,), jnp.float32),
         )
     rep = verify_nb(led)
@@ -182,7 +184,7 @@ def test_baseline_ratchet_add_then_stale(tmp_path):
     led = ProgramLedger()
 
     def f(state, x):
-        return state["a"] + x, state["c"].astype(jnp.float32)
+        return state["a"] + x, state["c"][:4].astype(jnp.float32)
 
     fn = led.wrap("bad", jax.jit(f, donate_argnums=(0,)))
     import warnings
@@ -191,7 +193,7 @@ def test_baseline_ratchet_add_then_stale(tmp_path):
         warnings.simplefilter("ignore")
         fn(
             {"a": jnp.zeros((4,), jnp.float32),
-             "c": jnp.zeros((4,), jnp.int32)},
+             "c": jnp.zeros((8,), jnp.int32)},
             jnp.ones((4,), jnp.float32),
         )
     bl = tmp_path / "gv_baseline.json"
@@ -208,7 +210,7 @@ def test_baseline_ratchet_add_then_stale(tmp_path):
     ))
     fixed(
         {"a": jnp.zeros((4,), jnp.float32),
-         "c": jnp.zeros((4,), jnp.int32)},
+         "c": jnp.zeros((8,), jnp.int32)},
         jnp.ones((4,), jnp.float32),
     )
     rep3 = gv_runner.verify({"t": led2}, baseline_path=str(bl))
@@ -223,7 +225,7 @@ def test_baseline_scopes_do_not_cross_contaminate(tmp_path):
     led = ProgramLedger()
 
     def f(state, x):
-        return state["a"] + x, state["c"].astype(jnp.float32)
+        return state["a"] + x, state["c"][:4].astype(jnp.float32)
 
     fn = led.wrap("bad", jax.jit(f, donate_argnums=(0,)))
     import warnings
@@ -232,7 +234,7 @@ def test_baseline_scopes_do_not_cross_contaminate(tmp_path):
         warnings.simplefilter("ignore")
         fn(
             {"a": jnp.zeros((4,), jnp.float32),
-             "c": jnp.zeros((4,), jnp.int32)},
+             "c": jnp.zeros((8,), jnp.int32)},
             jnp.ones((4,), jnp.float32),
         )
     bl = tmp_path / "gv_baseline.json"

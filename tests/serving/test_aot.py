@@ -14,7 +14,7 @@ The acceptance pins live here:
   the cache-loaded-executable bug: an XLA:CPU executable loaded from the
   persistent disk cache serializes WITHOUT its object code and
   deserializes cross-process to ``Symbols not found`` — ``save_aot``
-  must bypass the disk cache per compile (aot.serializable_compiles).
+  must compile afresh what it serializes (aot.compile_serializable).
 * **Fallback ladder** — a corrupt artifact degrades deserialize → replay
   with a ``SkewError`` recorded on the flight recorder, never a crash;
   header skew (foreign jax version) raises :class:`SkewError` from
@@ -231,7 +231,7 @@ def test_two_engines_capture_independent_manifests(tiny_model):
     its own portable decode-chunk entry, and replays into a third."""
     cfg, model, params = tiny_model
     e1 = _fresh_engine(model, params)
-    _drive(e1, cfg, n_req=1)
+    cold = _drive(e1, cfg, n_req=1)
     e2 = _fresh_engine(model, params)
     _drive(e2, cfg, n_req=1)
     for eng in (e1, e2):
@@ -243,9 +243,11 @@ def test_two_engines_capture_independent_manifests(tiny_model):
     assert "decode_chunk" in rep["replayed"]
     assert not rep["skipped"], rep["skipped"]
     before = _program_cache_sizes(e3, m2.names())
-    _drive(e3, cfg, n_req=1)
+    warm = _drive(e3, cfg, n_req=1)
     assert _program_cache_sizes(e3, m2.names()) == before
     assert e3.decode_compilations == 1
+    # a replay-prewarmed engine serves the cold engine's streams
+    assert [r.tokens for r in warm] == [r.tokens for r in cold]
 
 
 def test_persistent_cache_env_opt_out(monkeypatch, tmp_path):

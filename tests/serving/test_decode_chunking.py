@@ -369,42 +369,30 @@ def test_chunk_metrics_accounting(setup):
     assert engine.metrics.cursor_high_water == 8 + 4  # bucket(3) + used
 
 
-@pytest.mark.slow
 def test_chunked_throughput_beats_single_step(setup):
-    """Bench-style (excluded from tier-1): a sustained decode workload at
-    chunk=8 must not lose decode throughput vs chunk=1 — the chunk
-    amortizes dispatch+sync host work 8-fold. Lenient bound: CPU-backend
-    compute noise must not flake CI."""
-    import time
-
+    """A sustained decode workload at chunk=8 emits the same tokens as at
+    chunk=1 with at most a quarter of the decode dispatches per emitted
+    token (each dispatch is one host sync: what the chunk amortizes,
+    counted off the engine's program ledger; how much wall that buys is
+    the chip's to say, ``tpot_mean_ms`` in every serve cell)."""
     cfg, model, params = setup
     gcfg = GenerationConfig(max_new_tokens=48, temperature=0.8, top_k=20)
     prompts = [
         np.asarray([3 + i, 5, 7, 11], np.int32) for i in range(4)
     ]
-    rates = {}
+    per_token = {}
     for chunk in (1, 8):
         engine = ServingEngine(
             model, params, num_slots=4, decode_chunk_size=chunk
         )
-        for i, p in enumerate(prompts):  # warmup: compile everything
-            engine.submit(
-                p, GenerationConfig(max_new_tokens=4, temperature=0.8, top_k=20),
-                key=jax.random.PRNGKey(i),
-            )
-        engine.run()
-        t0 = time.perf_counter()
-        for i, p in enumerate(prompts):
+        reqs = [
             engine.submit(p, gcfg, key=jax.random.PRNGKey(10 + i))
+            for i, p in enumerate(prompts)
+        ]
         engine.run()
-        wall = time.perf_counter() - t0
-        m = engine.metrics
-        rates[chunk] = (m.decode_tokens, wall)
-    tok1, wall1 = rates[1]
-    tok8, wall8 = rates[8]
-    assert tok8 >= tok1  # same streams; chunking may run a few extra steps
-    # throughput: generous 0.7x floor absorbs CI noise; the bench.py child
-    # reports the honest speedup on real hardware
-    assert (tok8 / wall8) > 0.7 * (tok1 / wall1), (
-        f"chunk=8 {tok8 / wall8:.1f} tok/s vs chunk=1 {tok1 / wall1:.1f}"
-    )
+        emitted = sum(len(r.tokens) for r in reqs)
+        assert emitted == len(prompts) * gcfg.max_new_tokens
+        per_token[chunk] = (
+            engine.programs.dispatches("decode_chunk") / emitted
+        )
+    assert per_token[8] <= per_token[1] / 4, per_token

@@ -183,10 +183,13 @@ def test_replay_requires_the_virtual_clock(setup):
         replay(engine, [], VirtualClock())
 
 
-def test_same_seed_identical_slo_report(setup):
+@pytest.mark.parametrize("scheduling", ["fifo", "slo"])
+def test_same_seed_identical_slo_report(setup, scheduling):
     """THE determinism pin: same seed ⇒ byte-identical tape AND an
     identical SLO report across two in-process replays — wall-clock or
-    dict-order leaks anywhere in the pipeline fail here."""
+    dict-order leaks anywhere in the pipeline fail here, under the FIFO
+    scheduler and under the SLO policy (which reads the tracker it
+    reports from)."""
     cfg, model, params = setup
     tapes = [
         generate_tape(_tenants("bursty"), duration_s=3.0, seed=9,
@@ -194,8 +197,8 @@ def test_same_seed_identical_slo_report(setup):
         for _ in range(2)
     ]
     assert tape_bytes(tapes[0]) == tape_bytes(tapes[1])
-    r1 = _replay_once(model, params, cfg, tapes[0])
-    r2 = _replay_once(model, params, cfg, tapes[1])
+    r1 = _replay_once(model, params, cfg, tapes[0], scheduling=scheduling)
+    r2 = _replay_once(model, params, cfg, tapes[1], scheduling=scheduling)
     assert json.dumps(r1, sort_keys=True) == json.dumps(r2, sort_keys=True)
     # keys deterministic AND ordered deterministically (insertion order
     # is tenant-sorted, so even non-sort_keys serialization matches)
