@@ -30,6 +30,7 @@ on one head's cache scan instead of idling (or replicating KV in HBM).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -740,80 +741,171 @@ def paged_flash_decode_attention(
 # no kernel can stream pages out of that; 512 keeps the latent, eight ninths
 # of the bytes, in whole tiles.
 #
-# One grid step takes ``G`` pages (``G`` BlockSpecs on each pool operand,
-# each with its own block-table lookup): at 16 tokens a page, a step of one
-# page is a 16-column matmul and a third of a microsecond of step overhead
-# for 18 KB of cache. Steps past the shared cursor, and steps whose pages are
-# all unmapped (block table 0: a slot whose context is shorter than the
-# cursor), neither compute nor fetch: their index maps repeat the block the
-# pipeline already holds.
+# The kernel fetches its pages itself. Both pool leaves stay in HBM
+# (``memory_space=pl.ANY``); the grid is one step a slot, and inside it one
+# loop walks the blocks of ``LATENT_BLOCK_TOKENS`` tokens that THIS slot maps:
+# from the first block holding a mapped page to the last one at or before the
+# cursor (``span``, computed from the block table outside the kernel), not the
+# whole row. A block is one async copy a page a leaf, issued back to back
+# into one half of a two-block VMEM buffer; block ``i + 1``'s copies are in
+# flight while block ``i`` is waited on and multiplied. A block inside the
+# span whose pages are all unmapped (``live`` 0) is neither fetched nor
+# computed; a slot that maps nothing fetches nothing and writes zeros. The
+# last block of a row whose pages do not fill it re-fetches the row's last
+# page into the spare rows: their columns lie past every row position.
+#
+# Why not a BlockSpec a page (the form this replaced): at 8 slots of 32,768
+# columns that was 1024 grid steps a call, and the pipeline's bookkeeping for
+# 32 page operands on every one of them, mapped or not, took 0.97 of the
+# call's 1.12 ms; this form takes 0.24 (v5e, PERF.md §6, PR 29). What holds
+# it now is issuing the copies (two a page of 16 tokens), which does not
+# overlap the multiply.
+
+# Tokens a block. Swept on the v5e at DeepSeek-V2-Lite's geometry (8 slots,
+# 16 heads, contexts of 3k-21k ending at a cursor of 22,000; PERF.md §6,
+# PR 29). Two blocks of 1024 x (512 + 64 padded to 128) bf16 are 2.5 MB of VMEM.
+LATENT_BLOCK_TOKENS = 1024
+
+# Pages a trip of the loop that issues (or waits for) a block's copies: the
+# trip's copies are unrolled, the trips are not. Rolled up page by page the
+# call takes 0.32 ms, unrolled whole 0.25 (and 0.35 s to trace and lower in
+# every process, not 0.14), 16 a trip 0.23-0.24 (v5e, PERF.md §6, PR 29).
+_PAGES_A_TRIP = 16
 
 
-def _latent_group(n_log: int, preferred: int = 16) -> int:
-    g = min(preferred, n_log)
-    while n_log % g != 0:
-        g -= 1
-    return g
+def _hbm_lanes(width: int, interpret: bool) -> Optional[int]:
+    """The lanes a page copy of a ``width``-wide leaf names, where they are
+    not the leaf's own. The chip lays an array out in tiles of 128 lanes, so
+    a leaf of 64 is held padded to 128, and Mosaic slices an HBM operand in
+    whole tiles only: it refuses ``pool.at[page]`` of a 64-wide leaf ("slice
+    shape must be aligned to tiling (128), but is 64": its own view of the
+    operand is 128 wide). So the copy names the tile's lanes, padding
+    included, and the kernel reads the first ``width`` of the block it lands
+    in. Interpreted, an array has no padding, and neither has the copy."""
+    if interpret or width % 128 == 0:
+        return None
+    return -(-width // 128) * 128
 
 
-def _paged_latent_kernel(bt_ref, bound_ref, pos_ref, valid_ref, qc_ref,
-                         qr_ref, *rest, page_size, group, num_steps, scale,
-                         use_valid):
-    c_refs, r_refs = rest[:group], rest[group:2 * group]
-    o_ref, m_scr, l_scr, acc_scr = rest[2 * group:]
+def _block_page_copies(leaves, sem, page_id, page_size, group, wait=False):
+    """Issue one block's page copies, or with ``wait`` wait for them: for
+    each page ``g`` of the block's ``group`` and each of ``leaves`` (``(pool
+    ref, block ref, lanes)``: a ``(P, page_size, ...)`` pool left in HBM, the
+    VMEM block of ``group * page_size`` rows it lands in, :func:`_hbm_lanes`
+    of its width), one async copy of pool page ``page_id(g)`` to rows ``[g *
+    page_size, (g + 1) * page_size)``, all signalling ``sem``. A wait names
+    the same bytes whatever the page was."""
+    trip = math.gcd(group, _PAGES_A_TRIP)
+
+    def some(t, carry):
+        for k in range(trip):
+            g = t * trip + k
+            rows = pl.ds(pl.multiple_of(g * page_size, page_size), page_size)
+            pid = 0 if wait else page_id(g)
+            for pool_ref, buf_ref, lanes in leaves:
+                if lanes is None:
+                    src, dst = pool_ref.at[pid], buf_ref.at[rows]
+                else:
+                    width = pl.ds(0, lanes)
+                    src, dst = pool_ref.at[pid, :, width], buf_ref.at[rows, width]
+                copy = pltpu.make_async_copy(src, dst, sem)
+                copy.wait() if wait else copy.start()
+        return carry
+
+    jax.lax.fori_loop(0, group // trip, some, 0)
+
+
+def _paged_latent_kernel(bt_ref, live_ref, span_ref, pos_ref, valid_ref,
+                         qc_ref, qr_ref, c_hbm, r_hbm, o_ref, c_buf, r_buf,
+                         sems, m_scr, l_scr, acc_scr, *, page_size, group,
+                         r_lanes, scale, use_valid):
     b = pl.program_id(0)
-    j = pl.program_id(1)  # group of logical pages (sequential)
+    lo, hi = span_ref[b, 0], span_ref[b, 1]   # this slot's own blocks
+    n_log, n_blocks = bt_ref.shape[1], live_ref.shape[1]
     block = group * page_size
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    def copies(i, wait=False):
+        def page_id(g):
+            page = i * group + g
+            if n_log % group:  # the row's last block: spare rows repeat its last page
+                page = jnp.minimum(page, n_log - 1)
+            return bt_ref[b, page]
 
-    mapped = bt_ref[b, j * group] != 0
-    for g in range(1, group):
-        mapped = mapped | (bt_ref[b, j * group + g] != 0)
-    run = (j * block < bound_ref[0]) & mapped
+        slot = i % 2
+        leaves = ((c_hbm, c_buf.at[slot], None), (r_hbm, r_buf.at[slot], r_lanes))
+        _block_page_copies(leaves, sems.at[slot], page_id, page_size, group, wait)
 
-    @pl.when(run)
-    def _body():
-        # operands stay in their storage type (bf16 on the chip: the MXU's
-        # own), accumulation is float32
-        c = jnp.concatenate([r[0] for r in c_refs], axis=0)    # (G*ps, d_c)
-        kr = jnp.concatenate([r[0] for r in r_refs], axis=0)   # (G*ps, d_r)
-        dims = (((1,), (1,)), ((), ()))
-        s = (
-            jax.lax.dot_general(qc_ref[0], c, dims,
-                                preferred_element_type=jnp.float32)
-            + jax.lax.dot_general(qr_ref[0], kr, dims,
-                                  preferred_element_type=jnp.float32)
-        ) * scale                                      # (R, G*ps)
-        rows = pos_ref[0, :][:, None]                  # (R, 1) slot positions
-        cols = (
-            jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + j * block
-        )
-        s = jnp.where(rows >= cols, s, NEG_INF)
-        if use_valid:
-            s = jnp.where(valid_ref[0, 0] != 0, s, NEG_INF)  # (1, G*ps)
-        m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        ref = jnp.where(m_new > NEG_INF / 2, m_new, 0.0)
-        p = jnp.exp(s - ref)
-        alpha = jnp.exp(m_prev - ref)
-        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        # the values are the latent block already in VMEM
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p.astype(c.dtype), c, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[:] = m_new
+    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    @pl.when(j == num_steps - 1)
-    def _finish():
-        o_ref[0] = (
-            acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)
-        ).astype(o_ref.dtype)
+    def step(i, carry):
+        # block i + 1 is issued before block i is waited on; the trip before
+        # the span's first block only issues it
+        nxt = jnp.minimum(i + 1, n_blocks - 1)
+
+        @pl.when((i + 1 < hi) & (live_ref[b, nxt] != 0))
+        def _prefetch():
+            copies(nxt)
+
+        @pl.when((i >= lo) & (live_ref[b, jnp.maximum(i, 0)] != 0))
+        def _body():
+            copies(i, wait=True)
+            slot = i % 2
+            # operands stay in their storage type (bf16 on the chip: the MXU's
+            # own), accumulation is float32
+            c = c_buf[slot]                                # (T, d_c)
+            kr = r_buf[slot][:, :qr_ref.shape[2]]          # (T, d_r)
+            dims = (((1,), (1,)), ((), ()))
+            s = (
+                jax.lax.dot_general(qc_ref[0], c, dims,
+                                    preferred_element_type=jnp.float32)
+                + jax.lax.dot_general(qr_ref[0], kr, dims,
+                                      preferred_element_type=jnp.float32)
+            ) * scale                                      # (R, T)
+            rows = pos_ref[0, :][:, None]                  # (R, 1) slot positions
+            cols = (
+                jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + i * block
+            )
+            s = jnp.where(rows >= cols, s, NEG_INF)
+            if use_valid:
+                s = jnp.where(valid_ref[0, pl.ds(i, 1), :] != 0, s, NEG_INF)
+            m_prev = m_scr[:]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            ref = jnp.where(m_new > NEG_INF / 2, m_new, 0.0)
+            p = jnp.exp(s - ref)
+            alpha = jnp.exp(m_prev - ref)
+            l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
+            # the values are the latent block already in VMEM
+            acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+                p.astype(c.dtype), c, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            m_scr[:] = m_new
+
+        return carry
+
+    jax.lax.fori_loop(lo - 1, hi, step, 0)
+    o_ref[0] = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)).astype(o_ref.dtype)
+
+
+def _latent_block_walk(block_table, bound, group, page_size):
+    """What the kernel walks, from the block table: ``live`` (B, n_blocks)
+    int32, 1 where a block of ``group`` pages holds a mapped page that
+    starts before the cursor ``bound``; ``span`` (B, 2) int32, each slot's
+    first live block and one past its last (0, 0 for a slot with none)."""
+    b, n_log = block_table.shape
+    n_blocks = pl.cdiv(n_log, group)
+    mapped = (block_table != 0) & (
+        jnp.arange(n_log, dtype=jnp.int32)[None, :] * page_size < bound
+    )
+    mapped = jnp.pad(mapped, ((0, 0), (0, n_blocks * group - n_log)))
+    live = mapped.reshape(b, n_blocks, group).any(axis=2)
+    idx = jnp.arange(n_blocks, dtype=jnp.int32)[None, :]
+    lo = jnp.min(jnp.where(live, idx, n_blocks), axis=1)
+    hi = jnp.max(jnp.where(live, idx + 1, 0), axis=1)
+    span = jnp.stack([jnp.minimum(lo, hi), hi], axis=1)
+    return live.astype(jnp.int32), span.astype(jnp.int32)
 
 
 def _paged_latent_decode_call(qc, qr, c_pool, r_pool, block_table, rows_pos,
@@ -824,41 +916,44 @@ def _paged_latent_decode_call(qc, qr, c_pool, r_pool, block_table, rows_pos,
     b, r, d_c = qc.shape
     d_r = qr.shape[2]
     n_log = block_table.shape[1]
-    group = _latent_group(n_log)
-    steps = n_log // group
+    group = min(LATENT_BLOCK_TOKENS // page_size, n_log)
     block = group * page_size
+    block_table = block_table.astype(jnp.int32)
+    live, span = _latent_block_walk(
+        block_table, jnp.max(rows_pos) + 1, group, page_size
+    )
+    n_blocks = live.shape[1]
+    r_lanes = _hbm_lanes(d_r, interpret)
     use_valid = kv_valid is not None
     if kv_valid is None:
         kv_valid = jnp.zeros((1, 1), jnp.int32)
         vspec = _SMEM_SPEC
     else:
-        kv_valid, vspec = _valid_tiles(
-            kv_valid, block, lambda b_, j, bt, bound: (b_, j, 0, 0)
-        )
-
-    def page_spec(g, d):
-        def index(b_, j, bt, bound):
-            # past the cursor nothing is fetched: repeat the last block held
-            last = jnp.maximum(bound[0] - 1, 0) // block
-            return (bt[b_, jnp.minimum(j, last) * group + g], 0, 0)
-
-        return pl.BlockSpec((1, page_size, d), index)
+        # a slot's whole row, one block a sublane: the loop picks row ``i``
+        kv_valid = jnp.pad(
+            kv_valid.astype(jnp.int32),
+            ((0, 0), (0, n_blocks * block - kv_valid.shape[1])),
+        ).reshape(b, n_blocks, block)
+        vspec = pl.BlockSpec((1, n_blocks, block), lambda b_, *_: (b_, 0, 0))
 
     def rows_spec(d):
-        return pl.BlockSpec((1, r, d), lambda b_, j, bt, bound: (b_, 0, 0))
+        return pl.BlockSpec((1, r, d), lambda b_, *_: (b_, 0, 0))
 
+    hbm = pl.BlockSpec(memory_space=pl.ANY)   # a pool leaf, whole and unblocked
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # block table and cursor bound: the index maps read both
-        grid=(b, steps),
+        num_scalar_prefetch=3,  # block table, live blocks, each slot's span
+        grid=(b,),
         in_specs=[
-            pl.BlockSpec((1, r), lambda b_, j, bt, bound: (0, 0)),      # pos
+            pl.BlockSpec((1, r), lambda b_, *_: (0, 0)),                 # pos
             vspec,                                                       # kv_valid
             rows_spec(d_c), rows_spec(d_r),
-            *[page_spec(g, d_c) for g in range(group)],
-            *[page_spec(g, d_r) for g in range(group)],
+            hbm, hbm,
         ],
         out_specs=rows_spec(d_c),
         scratch_shapes=[
+            pltpu.VMEM((2, block, d_c), c_pool.dtype),
+            pltpu.VMEM((2, block, r_lanes or d_r), r_pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
             pltpu.VMEM((r, 1), jnp.float32),
             pltpu.VMEM((r, 1), jnp.float32),
             pltpu.VMEM((r, d_c), jnp.float32),
@@ -867,21 +962,16 @@ def _paged_latent_decode_call(qc, qr, c_pool, r_pool, block_table, rows_pos,
     return pl.pallas_call(
         functools.partial(
             _paged_latent_kernel, page_size=page_size, group=group,
-            num_steps=steps, scale=scale, use_valid=use_valid,
+            r_lanes=r_lanes, scale=scale, use_valid=use_valid,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, r, d_c), qc.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("parallel",),
         ),
         interpret=interpret,
-    )(
-        block_table.astype(jnp.int32),
-        jnp.asarray(jnp.max(rows_pos) + 1, jnp.int32).reshape((1,)),
-        rows_pos.reshape(1, r),
-        kv_valid,
-        qc, qr, *([c_pool] * group), *([r_pool] * group),
-    )
+    )(block_table, live, span, rows_pos.reshape(1, r), kv_valid,
+      qc, qr, c_pool, r_pool)
 
 
 def paged_latent_decode_attention(
@@ -906,6 +996,14 @@ def paged_latent_decode_attention(
     be taken through ``W_uv`` by the caller. Block table and ``kv_valid`` as
     :func:`paged_flash_decode_attention`; like it, this is the kernel or
     nothing (interpreted only in tests).
+
+    The pool leaves are not copied, gathered or blocked: the kernel reads
+    each slot's pages out of HBM itself, ``LATENT_BLOCK_TOKENS`` tokens at a
+    time, and only between the first and the last block in which the block
+    table maps a page at or before the last row's position. A block there
+    with no mapped page is skipped; a slot that maps nothing returns zeros.
+    Inside a fetched block an unmapped page reads the null page 0, which
+    only ``kv_valid`` keeps out of the result.
 
     No mesh: the latent row has no head axis to shard, and the serving
     engine refuses tensor parallelism for a latent-cache model."""

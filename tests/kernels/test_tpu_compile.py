@@ -149,8 +149,12 @@ def test_paged_latent_decode_compiles(topo):
     32,768 columns, page 16, 16 heads against ONE row of 512 + 64. Neither
     pool leaf may be copied whole to be fed to the kernel but the rotated
     key's (a ninth of the bytes: its 64-wide layout has the page index
-    minor, ``kernels/flash_decode.py``)."""
+    minor, ``kernels/flash_decode.py``). The kernel fetches pages itself:
+    each pool leaf is ONE operand of the call, not one a page of a block, and
+    its two blocks in flight and its accumulators sit well inside the 16 MiB
+    of VMEM a kernel may scope on a v5e (Mosaic refuses the compile past it)."""
     from neuronx_distributed_tpu.kernels.flash_decode import (
+        LATENT_BLOCK_TOKENS,
         paged_latent_decode_attention,
     )
 
@@ -168,9 +172,19 @@ def test_paged_latent_decode_compiles(topo):
         )
 
     text = _compiled_text(fn, q_c, q_r, c_pool, r_pool, table, pos, valid)
-    assert KERNEL in text
     latent = "bf16[%d,16,1,%d]" % (pages, MLA["d_c"])
     assert not [ln for ln in text.splitlines() if " copy(" in ln and latent in ln]
+    (call,) = [ln for ln in text.splitlines() if "custom-call(" in ln and KERNEL in ln]
+    operands = call[call.index("custom-call(") + len("custom-call("):]
+    operands = re.sub(r"/\*.*?\*/", "", operands[:operands.index(")")]).split(",")
+    # block table, live blocks, spans | positions, kv_valid, q_c, q_r | the two pool leaves
+    assert len(operands) == 9, operands
+    assert len(set(op.strip() for op in operands)) == 9, operands
+    # two blocks of T tokens in flight (the 64-wide leaf in tiles of 128 lanes),
+    # float32 accumulators for 16 rows, a slot's kv_valid row double-buffered
+    vmem = (2 * LATENT_BLOCK_TOKENS * (MLA["d_c"] + 128) * 2
+            + MLA["h"] * (MLA["d_c"] + 2) * 4 + 2 * seq * 4)
+    assert vmem < 16 * 1024**2 / 4
 
 
 @pytest.mark.parametrize("seq", [2048, 20992, 32768])
