@@ -34,6 +34,22 @@ and is printed. Weights and requests come from ``--seed``.
          reference's logit of every decoded token. ``--only mla`` runs this
          phase alone.
 
+  dsa    Keye-VL-2.0's language model at its published widths and the
+         benchmark configuration's depth (``perfbench/configs/keye-vl2-30b-a3b
+         -serve.json``): first the three decode kernels alone at the serve
+         cell's shapes (8 slots whose contexts end at a shared cursor) against
+         float32 ``jnp``, each with its time against its bytes, and the
+         selection, ``top_k`` against a bisection. Then a ``ServingEngine`` of
+         32,768-column slots: prompts of 24,576, 8,192 and 2,048 tokens are
+         prefilled (the learned mask, the byte-masked flash kernel) and 32
+         tokens decoded through the paged INDEXED cache (index scores,
+         ``top_k``, the sparse kernel). Against ``perfbench/references/
+         keye_vl2.py``: prefill logits and the reference's logit of every
+         decoded token, as ``mla``; the share of layer 0's selected columns
+         that differ from the reference's and how near the threshold they lie;
+         and two controls that must fail: index keys in float8, and 1024 kept.
+         ``--only dsa`` runs this phase alone.
+
 ``--chips 4`` runs only the four-chip path and what it is compared with: a
 tp=4 + sequence-parallel train step against the same seeded step on one
 device of the same process, and ``ServingEngine(tp=4)`` against the
@@ -357,6 +373,47 @@ class MlaSize:
     typical_tol: float = 0.08
     gap_tol: float = 0.1          # a decoded token's reference-logit gap, as ServeSize.logit_tol
     near_tie: float = 0.05        # ... and its excuse, as the benchmark's own check (judge_gaps)
+
+
+@dataclasses.dataclass(frozen=True)
+class DsaSize:
+    """What the sparse-attention phase runs (defaults: the chip run, the
+    published widths at the benchmark configuration's depth)."""
+
+    model: object = None          # published config.json keys; None = the benchmark configuration's
+    max_seq_len: int = 32768
+    slots: int = 4
+    prompt_lens: Tuple[int, ...] = (24576, 8192, 2048)
+    tail: int = 256               # positions of the longest prompt compared in prefill logits
+    new_tokens: int = 32
+    # the kernels alone: valid contexts of the slots (the longdocs_closed
+    # tape's quantiles + 128 decoded), ending at this shared cursor
+    kernel_contexts: Tuple[int, ...] = (5840, 8030, 9730, 11400, 13440, 15800, 19300, 24700)
+    kernel_cursor: int = 27000
+    kernel_calls: int = 20
+    kernel_prefill: int = 12288   # the prompt whose learned mask is built both ways (the tape's median)
+    kernel_tol: float = 2e-2      # |kernel - float32 jnp| on bf16 inputs: rounding of the output type
+    # Limits of the comparison with the reference (readings: PERF.md section
+    # 6). As MlaSize: ``logit_tol`` the worst position's largest |difference|
+    # over the vocabulary, ``typical_tol`` the median position's; and
+    # ``selected_tol``: the share of layer 0's selected columns (rows past
+    # ``topk``) that are not the reference's. With 128 experts top-8
+    # renormalised and no shared expert a router flip moves a position's
+    # logits by up to ~1.2 (the float32 reference against ITSELF with 1% of
+    # its selected columns swapped reads 1.23), so the worst position bounds
+    # only garbage; the median holds precision (the system 0.048-0.067, 1024
+    # kept 0.61) and the selection holds the index keys' (the system 0.18%
+    # of columns, float8 index keys 1.12%, 1024 kept 25%).
+    logit_tol: float = 2.0
+    typical_tol: float = 0.12
+    selected_tol: float = 0.005
+    # a differing column's index score lies within this of the row's
+    # threshold, in units of the row's root-mean-square score
+    index_near_tie: float = 0.1
+    # the decoded tokens' gap and the router near-tie that excuses one: the
+    # benchmark configuration's (its ``reference_check.why`` has the readings)
+    gap_tol: float = 0.06
+    near_tie: float = 0.035
 
 
 def _prompts(lens: Sequence[int], vocab: int, seed: int):
@@ -826,15 +883,313 @@ def mla_phase(size: MlaSize, seed: int) -> Dict[str, bool]:
     }
 
 
+
+# --- learned sparse attention ---------------------------------------------------------
+
+
+def _median_call_ms(fn, args, calls: int, repeats: int = 5) -> float:
+    """Median over ``repeats`` of the wall of ONE program that calls ``fn``
+    ``calls`` times (each call on inputs that depend on the last result, so
+    none is folded away), over ``calls``."""
+    import jax
+    import jax.numpy as jnp
+
+    def many(*a):
+        def body(carry, _):
+            out = fn(*[x + carry.astype(x.dtype) if i == 0 and jnp.issubdtype(x.dtype, jnp.floating) else x
+                       for i, x in enumerate(a)])
+            return (jnp.sum(jax.tree.leaves(out)[0].astype(jnp.float32)) * 0).astype(jnp.float32), None
+        return jax.lax.scan(body, jnp.zeros((), jnp.float32), None, length=calls)[0]
+
+    run = jax.jit(many)
+    jax.block_until_ready(run(*args))
+    walls = []
+    for _ in range(repeats):
+        t0 = time.perf_counter()
+        jax.block_until_ready(run(*args))
+        walls.append(time.perf_counter() - t0)
+    return 1e3 * sorted(walls)[len(walls) // 2] / calls
+
+
+def dsa_kernels(size: DsaSize, published: dict, seed: int, dtype) -> Dict[str, bool]:
+    """The three decode kernels alone at the cell's shapes against float32
+    ``jnp``, with their times against their bytes; the selection, ``top_k``
+    against the bisection."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from neuronx_distributed_tpu.kernels.flash_decode import (
+        paged_gather_leaf,
+        paged_index_scores,
+        paged_sparse_decode_attention,
+    )
+    from neuronx_distributed_tpu.kernels.flash_attention import sparse_keep_mask_kernel
+    from neuronx_distributed_tpu.modules.attention import (
+        _masked_gqa_attention,
+        index_scores,
+        sparse_keep_mask,
+        topk_mask,
+    )
+
+    sa = published["sa_config"]
+    h, hkv, d = (int(published[k]) for k in ("num_attention_heads", "num_key_value_heads", "head_dim"))
+    h_i, d_i, keep = int(sa["indexer_num_heads"]), int(sa["indexer_head_dim"]), int(sa["topk"])
+    page, length = 16, size.max_seq_len
+    ctx, cur = list(size.kernel_contexts), size.kernel_cursor
+    b, n_log = len(ctx), length // page
+    rng = np.random.default_rng(seed)
+    table = np.zeros((b, n_log), np.int32)
+    valid = np.zeros((b, length), bool)
+    ids = rng.permutation(np.arange(1, b * n_log + 1))
+    for i, n in enumerate(ctx):            # contexts END at the shared cursor
+        lo, hi = (cur + 1 - n) // page, cur // page + 1
+        table[i, lo:hi] = ids[i * n_log + lo:i * n_log + hi]
+        valid[i, cur + 1 - n:cur + 1] = True
+    key = jax.random.split(jax.random.PRNGKey(seed), 6)
+    pages = b * n_log + 1
+    k_pool = jax.random.normal(key[0], (pages, page, hkv, d), dtype)
+    v_pool = jax.random.normal(key[1], (pages, page, hkv, d), dtype)
+    i_pool = jax.random.normal(key[2], (pages, page, 1, d_i), dtype)
+    q = jax.random.normal(key[3], (b, 1, h, d), dtype)
+    q_idx = jax.random.normal(key[4], (b, 1, h_i, d_i), dtype)
+    w_idx = jax.random.normal(key[5], (b, 1, h_i), dtype)
+    table, valid, pos = jnp.asarray(table), jnp.asarray(valid), jnp.asarray([cur], jnp.int32)
+    f32 = lambda a: a.astype(jnp.float32)    # noqa: E731
+
+    # every array is an ARGUMENT: closed over, a pool would be a constant of the program
+    score = lambda qi, w, pool: paged_index_scores(qi, w, pool, table, pos, valid, page_size=page)   # noqa: E731
+    got = jax.jit(score)(q_idx, w_idx, i_pool)
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(lambda qi, w, pool: index_scores(
+            f32(qi), f32(w), f32(paged_gather_leaf(pool, table, page)[:, :, 0])))(q_idx, w_idx, i_pool)[:, 0]
+    ok_cols = np.asarray(valid)
+    score_err = float(np.abs(np.asarray(got)[ok_cols] - np.asarray(want)[ok_cols]).max())
+    score_ok = score_err <= size.kernel_tol * float(np.abs(np.asarray(want)[ok_cols]).max()) and bool(
+        np.isneginf(np.asarray(got)[~ok_cols]).all())
+    score_ms = _median_call_ms(score, (q_idx, w_idx, i_pool), size.kernel_calls)
+    score_bytes = sum(ctx) * d_i * 2
+
+    top = lambda s: jax.lax.top_k(s, keep)   # noqa: E731
+    vals, cols = jax.jit(top)(got)
+    top_ms = _median_call_ms(top, (got,), size.kernel_calls)
+    bisect = lambda s: topk_mask(s, s > -jnp.inf, keep)   # noqa: E731
+    same = bool((np.asarray(jax.jit(bisect)(got)).sum(1) == np.minimum(ctx, keep)).all()) and all(
+        set(np.asarray(cols[i]).tolist()) == set(np.flatnonzero(np.asarray(jax.jit(bisect)(got))[i]).tolist())
+        for i in range(b))
+    bisect_ms = _median_call_ms(bisect, (got,), size.kernel_calls)
+
+    n_sel = jnp.sum(vals > -jnp.inf, axis=1).astype(jnp.int32)
+    attend = lambda qq, kp, vp: paged_sparse_decode_attention(qq, kp, vp, table, cols, n_sel, page_size=page)   # noqa: E731
+    out = jax.jit(attend)(q, k_pool, v_pool)
+    keep_mask = np.zeros((b, 1, length), bool)
+    for i in range(b):
+        keep_mask[i, 0, np.asarray(cols[i])[: int(n_sel[i])]] = True
+    with jax.default_matmul_precision("highest"):
+        ref = jax.jit(lambda qq, kp, vp: _masked_gqa_attention(
+            f32(qq), f32(paged_gather_leaf(kp, table, page)), f32(paged_gather_leaf(vp, table, page)),
+            jnp.asarray(keep_mask)))(q, k_pool, v_pool)
+    attend_err = float(np.abs(np.asarray(f32(out)) - np.asarray(ref)).max())
+    attend_ms = _median_call_ms(attend, (q, k_pool, v_pool), size.kernel_calls)
+    attend_bytes = int(sum(min(n, keep) for n in ctx)) * 2 * hkv * d * 2
+    # the prefill's learned mask: one kernel against the einsum + bisection
+    sp = size.kernel_prefill
+    pk = jax.random.split(jax.random.PRNGKey(seed + 1), 3)
+    pq = jax.random.normal(pk[0], (1, sp, h_i, d_i), dtype)
+    pw = jax.random.normal(pk[1], (1, sp, h_i), dtype)
+    pkey = jax.random.normal(pk[2], (1, sp, d_i), dtype)
+    all_valid = jnp.ones((1, sp), bool)
+    rows_pos = jnp.arange(sp, dtype=jnp.int32)[None]
+    fused = lambda a, w_, k_: sparse_keep_mask_kernel(a, w_, k_, all_valid, keep)   # noqa: E731
+    plain = lambda a, w_, k_: sparse_keep_mask(a, w_, k_, rows_pos, all_valid, keep, jnp.int8)   # noqa: E731
+    m_fused, m_plain = jax.jit(fused)(pq, pw, pkey), jax.jit(plain)(pq, pw, pkey)
+    mask_differ = float(jnp.mean((m_fused != m_plain).astype(jnp.float32)) * sp / (2.0 * min(keep, sp)))
+    fused_ms = _median_call_ms(fused, (pq, pw, pkey), 3, repeats=3)
+    plain_ms = _median_call_ms(plain, (pq, pw, pkey), 3, repeats=3)
+    log(f"dsa kernels: the learned mask of a {sp}-token prompt, {keep} kept: one kernel {fused_ms:.1f} ms, the einsum "
+        f"and bisection {plain_ms:.1f} ms; {100 * mask_differ:.4f}% of the kept columns differ (summation order)")
+    log(f"dsa kernels: {b} slots, contexts {ctx} ({sum(ctx)} tokens) ending at cursor {cur}, page {page}, "
+        f"{jnp.dtype(dtype).name}; index scores {score_ms:.3f} ms a call for {score_bytes / 1e6:.1f} MB "
+        f"({score_bytes / score_ms / 1e6:.1f} GB/s), max |kernel - float32 jnp| {score_err:.5f}; selection of "
+        f"{keep} of {length}: top_k {top_ms:.3f} ms, bisection mask {bisect_ms:.3f} ms (the same sets: {same}); "
+        f"sparse attention {attend_ms:.3f} ms a call for {attend_bytes / 1e6:.1f} MB "
+        f"({attend_bytes / attend_ms / 1e6:.1f} GB/s, {int(n_sel.sum())} tokens), max |kernel - float32 jnp| "
+        f"{attend_err:.5f}")
+    return {
+        "dsa_index_kernel_matches_jnp": score_ok,
+        "dsa_bisection_selects_what_top_k_selects": same,
+        "dsa_sparse_kernel_matches_jnp": attend_err <= size.kernel_tol,
+        "dsa_mask_kernel_keeps_the_einsums_columns": mask_differ <= size.selected_tol,
+    }
+
+
+def dsa_phase(size: DsaSize, seed: int) -> Dict[str, bool]:
+    """Keye-VL-2.0's language model through the default engine (module
+    docstring)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from flax.core import meta
+
+    from neuronx_distributed_tpu.models.keye_vl2 import KeyeVL2Model, mrope_angles, rotate
+    from neuronx_distributed_tpu.modules.attention import sparse_keep_mask
+    from neuronx_distributed_tpu.parallel import mesh as mesh_lib
+    from neuronx_distributed_tpu.serving import ServingEngine
+    from perfbench.families import keye_vl2 as family
+    from perfbench.references import common
+    from perfbench.references.keye_vl2 import Reference
+
+    mesh_lib.destroy_model_parallel()
+    published = size.model
+    if published is None:
+        path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                            "perfbench", "configs", "keye-vl2-30b-a3b-serve.json")
+        with open(path) as f:
+            published = json.load(f)["model"]
+    model = family.build(published, runner="serve", max_seq_len=size.max_seq_len)
+    if size.model is not None:    # the CPU rehearsal serves in float32
+        model = model.clone(config=dataclasses.replace(model.config, dtype=jnp.float32))
+    cfg = model.config
+    checks = dsa_kernels(size, published, seed, cfg.dtype)
+    params = jax.jit(model.init)(jax.random.PRNGKey(seed), jnp.zeros((1, 8), jnp.int32))
+    jax.block_until_ready(params)
+    engine = ServingEngine(model, params, num_slots=size.slots, kv_page_size=16)
+    prompts = _prompts(size.prompt_lens, int(published["vocab_size"]), seed + 7)
+    reqs, wall = _serve(engine, prompts, size.new_tokens, seed)
+    resolved = dict(engine.programs.resolved)
+    kernels = _ledger_kernels(engine.programs, _hot_programs(engine))
+    per_token = engine.metrics.snapshot()["kv_bytes_per_token_layer"]
+    log(f"dsa: {len(reqs)} requests, prompts {list(size.prompt_lens)} + {size.new_tokens} tokens in "
+        f"{wall:.1f}s; resolved {resolved}; {KERNEL} in compiled programs: {kernels}; cache "
+        f"{per_token:g} B a token a layer, pool {engine.cache.nbytes / 2**30:.2f} GiB")
+    engine.cache.check()
+    engine = None
+    gc.collect()
+
+    backbone = KeyeVL2Model(cfg, model.attention_impl, mode="prefill")
+
+    @jax.jit
+    def prefill_rows(params, ids, lo):
+        """The system's prefill logits at ``tail`` positions from ``lo``."""
+        hidden = backbone.apply({"params": params["params"]["model"]}, ids, mutable=["cache"])[0][0]
+        rows = jax.lax.dynamic_slice_in_dim(hidden[0], lo, min(size.tail, ids.shape[1]), axis=0)
+        return rows @ meta.unbox(params)["params"]["lm_head"]["kernel"]
+
+    @jax.jit
+    def layer0_keep(params, ids):
+        """The mask the SYSTEM's first layer keeps, from its own projections."""
+        _, state = backbone.apply(
+            {"params": params["params"]["model"]}, ids, mutable=["cache", "intermediates"],
+            capture_intermediates=lambda mdl, _: mdl.name in ("idx_q_proj", "idx_k_norm", "idx_w_proj")
+            and "layers_0" in "/".join(mdl.path))
+        got = state["intermediates"]["layers_0"]["attn"]
+        b, s = ids.shape
+        pos = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
+        ang = mrope_angles(pos, cfg.indexer_head_dim, cfg.rope_theta)
+        q_idx = rotate(got["idx_q_proj"]["__call__"][0].reshape(b, s, cfg.indexer_num_heads, -1), ang)
+        k_idx = rotate(got["idx_k_norm"]["__call__"][0][:, :, None, :], ang)[:, :, 0]
+        return sparse_keep_mask(q_idx, got["idx_w_proj"]["__call__"][0], k_idx, pos,
+                                jnp.ones((b, s), bool), cfg.index_topk)
+
+    ref = Reference(published, meta.unbox(params))
+    want_bytes = (2 * cfg.num_kv_heads * cfg.head_dim + cfg.indexer_head_dim) * jnp.dtype(cfg.dtype).itemsize
+    keep = cfg.index_topk
+    worst_gap, worst_diff, worst_median, ok, caught = 0.0, 0.0, 0.0, True, {}
+    differing = near = None
+    for prompt, req in zip(prompts, reqs):
+        p, n = len(prompt), len(req.tokens)
+        ids = np.concatenate([prompt, np.asarray(req.tokens, np.int32)])[None]
+        hidden, router, index, _ = ref._hidden(ids)            # the whole context, once
+        head = lambda rows, r=ref, hid=hidden: np.asarray(r._head(      # noqa: E731
+            r.p["model"]["final_norm"], r.p["lm_head"], hid[:, rows])[0], np.float32)
+        rows = head(np.arange(p - 1, p - 1 + n))
+        toks = np.asarray(req.tokens)
+        gaps = rows.max(1) - rows[np.arange(n), toks]
+        wrong = rows.max(1) - rows[np.arange(n), (toks + 1) % rows.shape[1]]
+        margins = np.asarray(router[0, p - 1:p - 1 + n])
+        fine, over, excused = common.judge_gaps(gaps, margins, size.gap_tol, size.near_tie)
+        ok = ok and fine and wrong.min() > size.gap_tol
+        worst_gap = max(worst_gap, float(gaps[margins >= size.near_tie].max(initial=0.0)))
+        starts = range(0, p, size.tail) if p < max(size.prompt_lens) else [p - size.tail]
+        blocks = [max(min(lo, p - size.tail), 0) for lo in starts]
+        mine = [np.asarray(prefill_rows(params, prompt[None], lo), np.float32) for lo in blocks]
+        theirs = [head(np.arange(lo, lo + m.shape[0])) for lo, m in zip(blocks, mine)]
+        diffs = np.concatenate([np.abs(m - t).max(1) for m, t in zip(mine, theirs)])
+        median, worst = float(np.median(diffs)), float(diffs.max())
+        ok = ok and worst <= size.logit_tol and median <= size.typical_tol
+        worst_diff, worst_median = max(worst_diff, worst), max(worst_median, median)
+        log(f"dsa: prompt {p}: decoded tokens' largest reference-logit gap {gaps.max():.4f} ({over} of {n} "
+            f"fail {size.gap_tol:g}, {excused} excused by a router margin under {size.near_tie:g}; wrong "
+            f"tokens' smallest gap {wrong.min():.3f}); narrowest index margin at those positions "
+            f"{float(np.asarray(index[0, p - 1:p - 1 + n]).min()):.5f}; prefill logits at {len(diffs)} positions, "
+            f"|system - reference|: median {median:.4f}, 99th percentile {np.percentile(diffs, 99):.4f}, "
+            f"largest {worst:.4f}")
+        if p == sorted(size.prompt_lens)[len(size.prompt_lens) // 2]:
+            # the middle prompt: selection is at work and an S x S mask fits.
+            # The system's layer-0 sets against the reference's, then the
+            # controls: the reference itself, a precision lower in its index
+            # keys, and half the columns kept, against the plain one
+            sel, scores = ref.selected_and_scores(prompt[None])[0]
+            sparse_rows = np.arange(p) >= keep
+
+            def share_differing(other):
+                return float((other[0][sparse_rows] != sel[0][sparse_rows]).sum() / (2.0 * keep * sparse_rows.sum()))
+
+            mine_keep = np.asarray(layer0_keep(params, prompt[None]))
+            differing = share_differing(mine_keep)
+            causal = np.tril(np.ones((p, p), bool))
+            rms = np.sqrt((np.where(causal, scores[0], 0.0) ** 2).sum(1) / causal.sum(1))
+            thr = np.where(sel[0], scores[0], np.inf).min(1)
+            off = (mine_keep[0] != sel[0]) & sparse_rows[:, None]
+            near = float((np.abs(scores[0] - thr[:, None]) / rms[:, None])[off].max(initial=0.0))
+            log(f"dsa: prompt {p}, layer 0: {100 * differing:.3f}% of the selected columns differ from the "
+                f"reference's (rows past {keep}); the farthest of them lies {near:.5f} of the row's rms score from "
+                f"the row's threshold (near-tie margin {size.index_near_tie:g}, limit {100 * size.selected_tol:g}%)")
+            for name, other in (("float8 index keys", Reference(published, meta.unbox(params), jnp.float8_e4m3fn)),
+                                ("1024 columns kept", Reference(published, meta.unbox(params), topk=keep // 2))):
+                h2 = other._hidden(ids)[0]
+                rows2 = [np.asarray(other._head(other.p["model"]["final_norm"], other.p["lm_head"],
+                                                h2[:, lo:lo + m.shape[0]])[0], np.float32)
+                         for lo, m in zip(blocks, mine)]
+                d2 = np.concatenate([np.abs(r - t).max(1) for r, t in zip(rows2, theirs)])
+                share = share_differing(other.selected(prompt[None])[0])
+                caught[name] = (float(d2.max()) > size.logit_tol or float(np.median(d2)) > size.typical_tol
+                                or share > size.selected_tol)
+                log(f"dsa: control, the reference with {name} against the plain reference: {100 * share:.3f}% of "
+                    f"layer 0's selected columns differ; logits at {len(d2)} positions: median {np.median(d2):.4f}, "
+                    f"99th percentile {np.percentile(d2, 99):.4f}, largest {d2.max():.4f}: "
+                    f"{'outside' if caught[name] else 'INSIDE'} the limits")
+    log(f"dsa: prefill logits against the reference: largest difference {worst_diff:.4f} (tolerance "
+        f"{size.logit_tol:g}), largest median {worst_median:.4f} ({size.typical_tol:g}); largest decoded-token gap "
+        f"outside router near-ties {worst_gap:.4f} ({size.gap_tol:g})")
+    return {
+        **checks,
+        "dsa_matches_reference": ok,
+        "dsa_selects_the_references_columns": differing is not None and differing <= size.selected_tol
+        and near <= size.index_near_tie,
+        "dsa_resolved_sparse_fused": resolved == {
+            "attention": "flash", "decode_attention": "paged_sparse_fused",
+            "paged_attention": "fused",
+        },
+        "dsa_cache_is_indexed_sized": per_token == want_bytes,
+        "dsa_float8_index_keys_are_caught": caught.get("float8 index keys", False),
+        "dsa_1024_kept_is_caught": caught.get("1024 columns kept", False),
+        "kernel_dsa_programs": all(kernels.values()) and bool(kernels),
+    }
+
+
 def one_chip(seed: int, devices, train: TrainSize = TrainSize(),
              serve: ServeSize = ServeSize(), mla: MlaSize = MlaSize(),
-             only: str = "all") -> Dict[str, bool]:
-    """The default run: train, then serve, then the MLA model, in one
-    process on one device; ``only="mla"``: that phase alone."""
+             only: str = "all", dsa: DsaSize = DsaSize()) -> Dict[str, bool]:
+    """The default run: train, then serve, then the MLA model, then the
+    sparse-attention model, in one process on one device; ``only="mla"`` or
+    ``"dsa"``: that phase alone."""
     if only == "mla":
         return mla_phase(mla, seed)
+    if only == "dsa":
+        return dsa_phase(dsa, seed)
     return {**train_phase(train, seed, devices), **serve_phase(serve, seed),
-            **mla_phase(mla, seed)}
+            **mla_phase(mla, seed), **dsa_phase(dsa, seed)}
 
 
 def four_chips(seed: int, devices, train: TrainSize = TrainSize(),
@@ -853,8 +1208,9 @@ def parse_args(argv=None):
                         "tp=4 train step and tp=4 engine and their "
                         "one-device counterparts")
     p.add_argument("--seed", type=int, default=0)
-    p.add_argument("--only", default="all", choices=("all", "mla"),
-                   help="one chip: every phase (default) or the MLA phase alone")
+    p.add_argument("--only", default="all", choices=("all", "mla", "dsa"),
+                   help="one chip: every phase (default), the MLA phase or the "
+                        "sparse-attention (dsa) phase alone")
     return p.parse_args(argv)
 
 

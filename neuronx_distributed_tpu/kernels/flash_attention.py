@@ -649,3 +649,221 @@ def flash_attention(
     vt = jnp.swapaxes(v, 1, 2)
     out = _sharded_kernel_call(qt, kt, vt, q_seg, k_seg, causal, bq, bk, interpret)
     return jnp.swapaxes(out, 1, 2)
+
+
+# --- masked forward: causal attention under a learned one-byte mask -------------
+#
+# Prefill of a sparse-attention model (an indexer picks, per query row, the
+# ``topk`` keys it may read: ``modules/attention.sparse_prefill_attention``).
+# The mask arrives as ONE byte a (query, key) pair, causality and padding
+# already folded in, and is read tile by tile beside K and V: every causal
+# tile is multiplied and masked (dense work, sparse result). One grid step
+# serves the whole GQA group of a kv head, so a K, V and mask tile leave HBM
+# once for its ``G`` query heads. Forward only: serving.
+
+
+def _masked_fwd_kernel(keep_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
+                       acc_scr, *, scale, block_q, block_k, num_k_blocks, group):
+    i = pl.program_id(2)  # q block
+    j = pl.program_id(3)  # k block
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(j * block_k <= i * block_q + block_q - 1)   # tiles above the diagonal keep nothing
+    def _body():
+        keep = keep_ref[0] != 0                        # (BQ, BK)
+        k = k_ref[0, 0]                                # (BK, D), storage type: the MXU's own
+        v = v_ref[0, 0]
+        for g in range(group):
+            s = jax.lax.dot_general(
+                q_ref[0, 0, g], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale                                  # (BQ, BK)
+            s = jnp.where(keep, s, NEG_INF)
+            m_prev = m_scr[g]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            ref = jnp.where(m_new > NEG_INF / 2, m_new, 0.0)
+            p = jnp.where(keep, jnp.exp(s - ref), 0.0)
+            alpha = jnp.exp(m_prev - ref)
+            l_scr[g] = l_scr[g] * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc_scr[g] = acc_scr[g] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            m_scr[g] = m_new
+
+    @pl.when(j == num_k_blocks - 1)
+    def _finish():
+        o_ref[0, 0] = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)).astype(o_ref.dtype)
+
+
+def masked_flash_attention(q, k, v, keep, block_q: int = 512, block_k: int = 512,
+                           interpret: Optional[bool] = None):
+    """Attention of q (B, S, H, D) over k/v (B, S, Hkv, D) where ``keep`` (B,
+    S, S) int8 is nonzero (rows queries, columns keys; CAUSAL: nothing above
+    the diagonal may be kept, those tiles are not read). Softmax in float32
+    over the kept keys of ``q . k / sqrt(D)``; a row that keeps nothing
+    returns zeros. (B, S, H, Dv)."""
+    b, s, h, d = q.shape
+    hkv, dv = k.shape[2], v.shape[3]
+    group = h // hkv
+    bq, bk = _pick_block(s, block_q), _pick_block(s, block_k)
+    nq, nk = s // bq, s // bk
+    qt = jnp.swapaxes(q, 1, 2).reshape(b, hkv, group, s, d)
+    kt, vt = jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)
+
+    def last(i):  # the last k block a q block reads: later steps name it again, and fetch nothing
+        return (i * bq + bq - 1) // bk
+
+    out = pl.pallas_call(
+        functools.partial(
+            _masked_fwd_kernel, scale=1.0 / (d ** 0.5), block_q=bq, block_k=bk,
+            num_k_blocks=nk, group=group,
+        ),
+        grid=(b, hkv, nq, nk),
+        in_specs=[
+            pl.BlockSpec((1, bq, bk), lambda b_, h_, i, j: (b_, i, jnp.minimum(j, last(i)))),
+            pl.BlockSpec((1, 1, group, bq, d), lambda b_, h_, i, j: (b_, h_, 0, i, 0)),
+            pl.BlockSpec((1, 1, bk, d), lambda b_, h_, i, j: (b_, h_, jnp.minimum(j, last(i)), 0)),
+            pl.BlockSpec((1, 1, bk, dv), lambda b_, h_, i, j: (b_, h_, jnp.minimum(j, last(i)), 0)),
+        ],
+        out_specs=pl.BlockSpec((1, 1, group, bq, dv), lambda b_, h_, i, j: (b_, h_, 0, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, group, s, dv), q.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((group, bq, 1), jnp.float32),
+            pltpu.VMEM((group, bq, 1), jnp.float32),
+            pltpu.VMEM((group, bq, dv), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+        ),
+        interpret=interpret_mode(interpret),
+    )(keep, qt, kt, vt)
+    return jnp.swapaxes(out.reshape(b, h, s, dv), 1, 2)
+
+
+# --- the learned mask of a prefill: index scores, thresholds, one byte a pair -----
+#
+# For each query row the ``topk`` best-scoring causal keys (``modules/attention
+# .topk_mask``'s set: ties to the lower position), as the byte mask
+# :func:`masked_flash_attention` reads. One grid step holds ``block_q`` query
+# rows: it scores them against every key tile at or before their diagonal
+# (``sum_j w_j relu(q_j . k)``, a head at a time), keeps the scores in VMEM as
+# order-preserving int32 keys, finds each row's ``k``-th largest by bisection
+# over the key's bits (32 counting passes over VMEM, not HBM), then the tie
+# cutoff by bisection over positions, and writes the mask. No float score
+# ever reaches HBM; the XLA form (the float32 einsum and ``topk_mask``) writes
+# and reads a (rows, keys) float32 array once a head and once a bisection pass.
+
+_INT_MIN = -(2 ** 31)
+
+
+def _keep_mask_kernel(q_ref, w_ref, kt_ref, valid_ref, o_ref, keys_scr, *,
+                      block_q, block_k, topk, num_heads):
+    i = pl.program_id(1)
+    rows = i * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+    n_live = (i * block_q + block_q - 1) // block_k + 1     # key tiles at or before the diagonal
+
+    def tile(j):
+        return pl.ds(pl.multiple_of(j * block_k, block_k), block_k)
+
+    def cols(j):
+        return j * block_k + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+
+    o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+    def score(j, n_ok):
+        kt = kt_ref[0, :, tile(j)]                             # (d_i, BK)
+        acc = jnp.zeros((block_q, block_k), jnp.float32)
+        for h in range(num_heads):
+            s = jax.lax.dot_general(
+                q_ref[0, h], kt, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            acc = acc + jnp.maximum(s, 0.0) * w_ref[0, h]
+        acc = jnp.where(acc == 0, 0.0, acc)                    # -0.0 ties with 0.0
+        ok = (cols(j) <= rows) & (valid_ref[0, :, tile(j)] != 0)
+        bits = jax.lax.bitcast_convert_type(acc, jnp.int32)
+        # signed order of the int32 IS the float's: negative floats reversed
+        key = bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
+        keys_scr[:, tile(j)] = jnp.where(ok, key, _INT_MIN)   # below every float's key
+        return n_ok + jnp.sum(ok.astype(jnp.int32), axis=1, keepdims=True)
+
+    n_ok = jax.lax.fori_loop(0, n_live, score, jnp.zeros((block_q, 1), jnp.int32))
+    k = jnp.minimum(n_ok, topk)
+
+    def count(pred):
+        """Per row, over the live tiles, the columns where ``pred(keys, cols)``."""
+        def one(j, n):
+            return n + jnp.sum(pred(keys_scr[:, tile(j)], cols(j)).astype(jnp.int32),
+                               axis=1, keepdims=True)
+        return jax.lax.fori_loop(0, n_live, one, jnp.zeros((block_q, 1), jnp.int32))
+
+    # the k-th largest key, bit by bit from the sign down
+    thr = jnp.where(count(lambda key, _: key >= 0) >= k, 0, _INT_MIN).astype(jnp.int32)
+
+    def value_bit(step, thr):
+        cand = thr | jnp.left_shift(jnp.int32(1), 30 - step)
+        return jnp.where(count(lambda key, _: key >= cand) >= k, cand, thr)
+
+    thr = jax.lax.fori_loop(0, 31, value_bit, thr)
+    need = k - count(lambda key, _: key > thr)                 # ties to keep, lowest positions first
+
+    def position_bit(step, last):
+        cand = last | jnp.left_shift(jnp.int32(1), 15 - step)
+        held = count(lambda key, col: (key == thr) & (col < cand))
+        return jnp.where(held < need, cand, last)
+
+    # the largest position bound that still holds fewer than ``need`` ties:
+    # ties at columns <= it are the ``need`` lowest
+    last = jax.lax.fori_loop(0, 16, position_bit, jnp.zeros((block_q, 1), jnp.int32))
+
+    def write(j, carry):
+        key = keys_scr[:, tile(j)]
+        keep = (key > thr) | ((key == thr) & (cols(j) <= last) & (need > 0))
+        o_ref[0, :, tile(j)] = keep.astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, n_live, write, 0)
+
+
+def sparse_keep_mask_kernel(q_idx, w_idx, k_idx, k_valid, topk: int,
+                            block_q: int = 64, interpret: Optional[bool] = None):
+    """The learned mask of a prompt as one byte a pair, for
+    :func:`masked_flash_attention`: ``q_idx`` (B, S, H_i, d_i) index queries,
+    ``w_idx`` (B, S, H_i) their weights, ``k_idx`` (B, S, d_i) index keys,
+    ``k_valid`` (B, S) True at valid (non-padding) keys. Row ``t`` keeps the
+    ``min(valid causal keys, topk)`` keys ``s <= t`` of largest ``sum_j w[t,
+    j] relu(q_idx[t, j] . k_idx[s])``, ties to the lower position. (B, S, S)
+    int8. ``S`` a multiple of 512 and under 65,536."""
+    b, s, h_i, d_i = q_idx.shape
+    block_k = _pick_block(s, 2048)
+    if s % block_q or block_k < 512 or s >= 2 ** 16:
+        raise ValueError(f"a prompt of {s} tokens has no tiling here")
+    return pl.pallas_call(
+        functools.partial(_keep_mask_kernel, block_q=block_q, block_k=block_k,
+                          topk=int(topk), num_heads=h_i),
+        grid=(b, s // block_q),
+        in_specs=[
+            pl.BlockSpec((1, h_i, block_q, d_i), lambda b_, i: (b_, 0, i, 0)),
+            pl.BlockSpec((1, h_i, block_q, 1), lambda b_, i: (b_, 0, i, 0)),
+            pl.BlockSpec((1, d_i, s), lambda b_, i: (b_, 0, 0)),
+            pl.BlockSpec((1, 1, s), lambda b_, i: (b_, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, block_q, s), lambda b_, i: (b_, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, s, s), jnp.int8),
+        scratch_shapes=[pltpu.VMEM((block_q, s), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=64 * 1024 * 1024,
+        ),
+        interpret=interpret_mode(interpret),
+    )(
+        jnp.moveaxis(q_idx, 2, 1),                                   # (B, H_i, S, d_i)
+        jnp.moveaxis(w_idx, 2, 1)[..., None].astype(jnp.float32),   # (B, H_i, S, 1)
+        jnp.swapaxes(k_idx, 1, 2),                                   # (B, d_i, S)
+        k_valid.astype(jnp.int32)[:, None, :],
+    )

@@ -1032,3 +1032,322 @@ def paged_latent_decode_attention(
         rows_pos, kv_valid, page_size, scale, interpret_mode(interpret),
     )
     return jnp.swapaxes(out.reshape(b, h, s, d_c), 1, 2).astype(q_c.dtype)
+
+
+# --- paged SPARSE decode (a learned indexer beside GQA) --------------------------
+#
+# A sparse-attention indexer caches, beside each token's K and V, ONE index key
+# of ``d_i`` values (64) shared by its ``H_i`` index heads. A decode step scores
+# every token the slot holds, ``I[s] = sum_j w_j relu(q_j . k_idx[s])``, keeps
+# the ``topk`` largest and attends those alone. Three pieces:
+#
+# * :func:`paged_index_scores`: the score of every column of the blocks a slot
+#   maps, straight off the paged ``k_idx`` leaf: the latent kernel's block walk
+#   and page copies (:func:`_latent_block_walk`, :func:`_block_page_copies`)
+#   over one leaf of 64 lanes (padded to 128 in HBM: :func:`_hbm_lanes`); 128
+#   bytes a token. Invalid, future and unmapped columns read ``-inf``.
+# * the selection, ``jax.lax.top_k`` over the row (the caller's).
+# * :func:`paged_sparse_decode_attention`: the selected columns' K and V, and
+#   nothing else, fetched a TOKEN at a time (one async copy of ``(Hkv, D)`` a
+#   leaf) through the block table into a two-chunk VMEM buffer; online softmax
+#   over chunks of ``SPARSE_CHUNK_TOKENS``, the GQA group's query rows of each
+#   kv head against that head's rows of the chunk.
+
+# Tokens a chunk of the sparse kernel: 2 chunks x 2 leaves x 256 x (Hkv, D).
+SPARSE_CHUNK_TOKENS = 256
+# Token copies a trip of the issuing (or waiting) loop, unrolled.
+_TOKENS_A_TRIP = 8
+
+
+def _paged_index_kernel(bt_ref, live_ref, span_ref, bound_ref, valid_ref,
+                        q_ref, w_ref, k_hbm, o_ref, k_buf, sems, *, page_size,
+                        group, lanes):
+    b = pl.program_id(0)
+    lo, hi = span_ref[b, 0], span_ref[b, 1]
+    n_log, n_blocks = bt_ref.shape[1], live_ref.shape[1]
+    block = group * page_size
+    d = q_ref.shape[2]
+
+    def copies(i, wait=False):
+        def page_id(g):
+            page = i * group + g
+            if n_log % group:
+                page = jnp.minimum(page, n_log - 1)
+            return bt_ref[b, page]
+
+        slot = i % 2
+        _block_page_copies(((k_hbm, k_buf.at[slot], lanes),), sems.at[slot],
+                           page_id, page_size, group, wait)
+
+    o_ref[...] = jnp.full(o_ref.shape, -jnp.inf, o_ref.dtype)
+
+    def step(i, carry):
+        nxt = jnp.minimum(i + 1, n_blocks - 1)
+
+        @pl.when((i + 1 < hi) & (live_ref[b, nxt] != 0))
+        def _prefetch():
+            copies(nxt)
+
+        @pl.when((i >= lo) & (live_ref[b, jnp.maximum(i, 0)] != 0))
+        def _body():
+            copies(i, wait=True)
+            k = k_buf[i % 2][:, :d]                            # (T, d_i)
+            s = jax.lax.dot_general(
+                q_ref[0], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )                                                  # (H_i, T)
+            row = jnp.sum(jnp.maximum(s, 0.0) * w_ref[0], axis=0, keepdims=True)
+            cols = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1) + i * block
+            ok = (cols <= bound_ref[0]) & (valid_ref[0, pl.ds(i, 1), :] != 0)
+            o_ref[0, pl.ds(i, 1), :] = jnp.where(ok, row, -jnp.inf)
+
+        return carry
+
+    jax.lax.fori_loop(lo - 1, hi, step, 0)
+
+
+def paged_index_scores(
+    q_idx: jax.Array,
+    w_idx: jax.Array,
+    kidx_pool: jax.Array,
+    block_table: jax.Array,
+    q_pos: jax.Array,
+    kv_valid: jax.Array,
+    page_size: int = 16,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """Index scores of ONE query row a slot against every column the slot
+    maps: ``q_idx`` (B, 1, H_i, d_i) rotated index queries, ``w_idx`` (B, 1,
+    H_i) their weights, ``kidx_pool`` (P, page_size, 1, d_i) the paged index
+    keys, ``q_pos`` the row's slot position (scalar or (1,)), ``kv_valid`` (B,
+    L) bool. Returns (B, L) float32: ``sum_j w_j relu(q_j . k[s])`` at valid
+    columns ``s <= q_pos`` of mapped blocks, ``-inf`` elsewhere. The kernel
+    or nothing (interpreted only in tests); no mesh, as the latent kernel."""
+    from neuronx_distributed_tpu.parallel import mesh as mesh_lib
+
+    if mesh_lib.model_parallel_is_initialized():
+        raise NotImplementedError(
+            "the paged index-score kernel has no sharded form: serve an "
+            "indexed-cache model without a model-parallel mesh"
+        )
+    b, s, h_i, d_i = q_idx.shape
+    if s != 1:
+        raise ValueError(f"one query row a slot, got {s}")
+    if kidx_pool.ndim != 4 or kidx_pool.shape[2] != 1 or kidx_pool.shape[3] != d_i:
+        raise ValueError(
+            f"index-key pool leaf must be (P, page_size, 1, {d_i}), got {kidx_pool.shape}")
+    interpret = interpret_mode(interpret)
+    n_log = block_table.shape[1]
+    group = min(LATENT_BLOCK_TOKENS // page_size, n_log)
+    block = group * page_size
+    block_table = block_table.astype(jnp.int32)
+    bound = jnp.reshape(q_pos, (-1,))[:1].astype(jnp.int32)
+    live, span = _latent_block_walk(block_table, bound[0] + 1, group, page_size)
+    n_blocks = live.shape[1]
+    lanes = _hbm_lanes(d_i, interpret)
+    length = kv_valid.shape[1]
+    valid = jnp.pad(
+        kv_valid.astype(jnp.int32), ((0, 0), (0, n_blocks * block - length)),
+    ).reshape(b, n_blocks, block)
+    per_slot = lambda *shape: pl.BlockSpec((1,) + shape, lambda b_, *_: (b_, 0, 0))  # noqa: E731
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,  # block table, live blocks, spans, the row's position
+        grid=(b,),
+        in_specs=[
+            per_slot(n_blocks, block), per_slot(h_i, d_i), per_slot(h_i, 1),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=per_slot(n_blocks, block),
+        scratch_shapes=[
+            pltpu.VMEM((2, block, lanes or d_i), kidx_pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_paged_index_kernel, page_size=page_size, group=group,
+                          lanes=lanes),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, n_blocks, block), jnp.float32),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        interpret=interpret,
+    )(block_table, live, span, bound, valid, q_idx[:, 0],
+      w_idx[:, 0, :, None].astype(jnp.float32),
+      kidx_pool.reshape(kidx_pool.shape[:2] + kidx_pool.shape[3:]))
+    return out.reshape(b, n_blocks * block)[:, :length]
+
+
+def _scatter_pages_kernel(ids_ref, window_ref, pool_hbm, out_hbm, sem):
+    del pool_hbm  # aliased to ``out_hbm``: the pages not named keep their bytes
+    i = pl.program_id(0)
+    copy = pltpu.make_async_copy(window_ref.at[0], out_hbm.at[ids_ref[i]], sem)
+    copy.start()
+    copy.wait()
+
+
+def paged_scatter_window_pages_dma(pool: jax.Array, window: jax.Array,
+                                   block_table: jax.Array, page0: jax.Array,
+                                   interpret: Optional[bool] = None) -> jax.Array:
+    """:func:`paged_scatter_window_pages` as a kernel: one copy a window
+    page into the pool, which is aliased to the result and otherwise left
+    where it is. For a pool leaf whose ``(heads, width)`` XLA would lay out
+    otherwise than the sparse kernel reads it (4 kv heads of 128 in bf16: a
+    tile of ``(page, width)``): with every user of the carried pool inside
+    the decode scan a kernel of ONE layout, no step converts it (the XLA
+    scatter made each step copy every layer's K and V pool whole: 256 MiB a
+    leaf at the serve cell's size). Single layer ``(P, page, Hkv, D)``."""
+    b, page_size = block_table.shape[0], pool.shape[1]
+    n_win = window.shape[1] // page_size
+    ids = jax.lax.dynamic_slice(block_table.astype(jnp.int32), (0, page0), (b, n_win)).reshape(-1)
+    vals = window.reshape((b * n_win, page_size) + window.shape[2:])
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b * n_win,),
+        in_specs=[
+            pl.BlockSpec((1,) + vals.shape[1:], lambda i, ids_: (i, 0, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[pltpu.SemaphoreType.DMA(())],
+    )
+    return pl.pallas_call(
+        _scatter_pages_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        input_output_aliases={2: 0},   # operands count the scalar prefetch: ids, window, pool
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=interpret_mode(interpret),
+    )(ids, vals, pool)
+
+
+def _sparse_decode_kernel(tok_ref, n_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
+                          v_buf, sems, m_scr, l_scr, acc_scr, *, chunk,
+                          num_kv_heads, page_size, scale):
+    b = pl.program_id(0)
+    n = n_ref[b]
+    n_chunks = (n + chunk - 1) // chunk
+
+    def copies(c, wait=False):
+        slot = c % 2
+
+        def some(t, carry):
+            for u in range(_TOKENS_A_TRIP):
+                j = t * _TOKENS_A_TRIP + u
+                # a token's pool row: (page, offset); the pool keeps its four
+                # dims (flattening the first two is a whole-pool copy on the chip)
+                tok = 0 if wait else tok_ref[b, c * chunk + j]
+                page, off = tok // page_size, tok % page_size
+                for hbm, buf in ((k_hbm, k_buf), (v_hbm, v_buf)):
+                    copy = pltpu.make_async_copy(
+                        hbm.at[page, off], buf.at[slot, j], sems.at[slot])
+                    copy.wait() if wait else copy.start()
+            return carry
+
+        jax.lax.fori_loop(0, chunk // _TOKENS_A_TRIP, some, 0)
+
+    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(n_chunks > 0)
+    def _first():
+        copies(0)
+
+    def step(c, carry):
+        @pl.when(c + 1 < n_chunks)
+        def _prefetch():
+            copies(c + 1)
+
+        copies(c, wait=True)
+        slot = c % 2
+        rows = q_ref.shape[2]
+        ok = jax.lax.broadcasted_iota(jnp.int32, (rows, chunk), 1) + c * chunk < n
+        for h in range(num_kv_heads):
+            k = k_buf[slot, :, h, :]                           # (T, D)
+            v = v_buf[slot, :, h, :]
+            s = jax.lax.dot_general(
+                q_ref[0, h], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale                                          # (G, T)
+            s = jnp.where(ok, s, NEG_INF)
+            m_prev = m_scr[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            ref = jnp.where(m_new > NEG_INF / 2, m_new, 0.0)
+            p = jnp.where(ok, jnp.exp(s - ref), 0.0)
+            alpha = jnp.exp(m_prev - ref)
+            l_scr[h] = l_scr[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            m_scr[h] = m_new
+        return carry
+
+    jax.lax.fori_loop(0, n_chunks, step, 0)
+    o_ref[0] = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)).astype(o_ref.dtype)
+
+
+def paged_sparse_decode_attention(
+    q: jax.Array,
+    k_pool: jax.Array,
+    v_pool: jax.Array,
+    block_table: jax.Array,
+    sel_cols: jax.Array,
+    n_sel: jax.Array,
+    page_size: int = 16,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """GQA decode attention over SELECTED columns only: ``q`` (B, 1, H, D);
+    ``k_pool``/``v_pool`` (P, page_size, Hkv, D); ``sel_cols`` (B, K) int32
+    logical columns of each slot, of which the first ``n_sel`` (B,) count (a
+    ``top_k``'s order: the valid ones first); ``block_table`` (B, n_log).
+    Softmax over those columns of ``q_h . k_g(h) / sqrt(D)``, times ``v``:
+    (B, 1, H, D). Only the selected tokens' K and V leave HBM: ``n_sel``
+    copies of ``(Hkv, D)`` a leaf a slot, rounded up to the chunk. The kernel
+    or nothing (interpreted only in tests); no mesh."""
+    from neuronx_distributed_tpu.parallel import mesh as mesh_lib
+
+    if mesh_lib.model_parallel_is_initialized():
+        raise NotImplementedError(
+            "the paged sparse decode kernel has no sharded form: serve an "
+            "indexed-cache model without a model-parallel mesh"
+        )
+    b, s, h, d = q.shape
+    if s != 1:
+        raise ValueError(f"one query row a slot, got {s}")
+    hkv = k_pool.shape[2]
+    g = h // hkv
+    k_sel = sel_cols.shape[1]
+    chunk = min(SPARSE_CHUNK_TOKENS, -(-k_sel // _TOKENS_A_TRIP) * _TOKENS_A_TRIP)
+    padded = -(-k_sel // chunk) * chunk
+    n_sel = jnp.minimum(n_sel.astype(jnp.int32), k_sel)
+    cols = sel_cols.astype(jnp.int32)
+    page = jnp.take_along_axis(block_table.astype(jnp.int32), cols // page_size, axis=1)
+    tok = page * page_size + cols % page_size
+    # unselected entries (and the chunk's padding) read token 0 of the null page
+    tok = jnp.where(jnp.arange(k_sel)[None, :] < n_sel[:, None], tok, 0)
+    tok = jnp.pad(tok, ((0, 0), (0, padded - k_sel)))
+    rows = pl.BlockSpec((1, hkv, g, d), lambda b_, *_: (b_, 0, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,  # the selected tokens' pool rows, their count
+        grid=(b,),
+        in_specs=[rows, hbm, hbm],
+        out_specs=rows,
+        scratch_shapes=[
+            pltpu.VMEM((2, chunk, hkv, d), k_pool.dtype),
+            pltpu.VMEM((2, chunk, hkv, d), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((hkv, g, 1), jnp.float32),
+            pltpu.VMEM((hkv, g, 1), jnp.float32),
+            pltpu.VMEM((hkv, g, d), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_sparse_decode_kernel, chunk=chunk, num_kv_heads=hkv,
+                          page_size=page_size, scale=1.0 / (d ** 0.5)),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        interpret=interpret_mode(interpret),
+    )(tok, n_sel, q.reshape(b, hkv, g, d), k_pool, v_pool)
+    return out.reshape(b, 1, h, d)

@@ -46,6 +46,13 @@ from neuronx_distributed_tpu.models.deepseek_v2 import (
     deepseek_v2_lite,
     tiny_deepseek_v2,
 )
+from neuronx_distributed_tpu.models.keye_vl2 import (
+    KeyeVL2Config,
+    KeyeVL2ForCausalLM,
+    KeyeVL2Model,
+    keye_vl2_30b_a3b,
+    tiny_keye_vl2,
+)
 from neuronx_distributed_tpu.models.vit import (
     ViTConfig,
     ViTForImageClassification,
@@ -65,4 +72,6 @@ __all__ = [
     "CodeGenConfig", "CodeGenForCausalLM", "codegen25_7b", "tiny_codegen",
     "DeepseekV2Config", "DeepseekV2ForCausalLM", "DeepseekV2Model",
     "deepseek_v2_lite", "tiny_deepseek_v2",
+    "KeyeVL2Config", "KeyeVL2ForCausalLM", "KeyeVL2Model",
+    "keye_vl2_30b_a3b", "tiny_keye_vl2",
 ]

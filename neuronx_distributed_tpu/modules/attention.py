@@ -173,6 +173,20 @@ def attention_op(q, k, v, causal: bool = True, impl: str = "auto",
 KV_VIEW_SCOPE = "kv_view"
 
 
+# Named scopes of the sparse-attention block (an indexer beside GQA, below),
+# a trace reader's contract (PERF.md section 3): the index projections,
+# LayerNorm, rotary and the ``k_idx`` write; the index scores; the selection;
+# attention over the selected. A Pallas kernel called inside one is named
+# after it.
+DSA_INDEX_SCOPE = "dsa.index"
+DSA_SCORE_SCOPE = "dsa.score"
+DSA_SELECT_SCOPE = "dsa.select"
+DSA_ATTEND_SCOPE = "dsa.attend"
+# the fused chunk's per-step write of the K and V window pages into the pool
+# (a kernel, named after this scope)
+DSA_WRITE_SCOPE = "dsa.write"
+
+
 def prefill_positions(padding_mask: jax.Array) -> jax.Array:
     """RoPE positions for a (possibly left-)padded prompt (B, S): restart at
     each row's first VALID token, so padded slots never shift the rotary
@@ -206,7 +220,8 @@ class KVCache:
 
     ``leaves`` names the per-token storage leaves and their ``(heads,
     width)``; the writes take one array per leaf, in that order.
-    :class:`LatentKVCache` is the other kind."""
+    :class:`LatentKVCache` and :class:`IndexedKVCache` are the other
+    kinds."""
 
     def __init__(self, module, b, max_seq_len, hkv, d, dtype, leaves=None):
         self.max_seq_len = max_seq_len
@@ -229,8 +244,17 @@ class KVCache:
 
     def prefill_write(self, k, v, padding_mask=None):
         """Write the prompt K/V at slot 0 and record its validity."""
-        b, s = k.shape[0], k.shape[1]
-        for leaf, new in zip(self.leaves, (k, v)):
+        self._prefill_write((k, v), padding_mask)
+
+    def decode_write(self, k, v, padding_mask=None):
+        """Append a decode step's K/V at the cursor; ``padding_mask`` (B, s)
+        marks the INCOMING tokens' validity (ragged batched decode: finished
+        rows pass False so their filler tokens never become attendable)."""
+        self._decode_write((k, v), padding_mask)
+
+    def _prefill_write(self, news, padding_mask):
+        b, s = news[0].shape[0], news[0].shape[1]
+        for leaf, new in zip(self.leaves, news):
             leaf.value = jax.lax.dynamic_update_slice(leaf.value, new, (0, 0, 0, 0))
         self.index.value = jnp.asarray(s, jnp.int32)
         valid = (
@@ -255,16 +279,13 @@ class KVCache:
         rope_pos = nvalid[:, None] + jnp.arange(s, dtype=jnp.int32)[None]
         return pos, rope_pos
 
-    def decode_write(self, k, v, padding_mask=None):
-        """Append a decode step's K/V at the cursor; ``padding_mask`` (B, s)
-        marks the INCOMING tokens' validity (ragged batched decode: finished
-        rows pass False so their filler tokens never become attendable)."""
-        b, s = k.shape[0], k.shape[1]
+    def _decode_write(self, news, padding_mask):
+        b, s = news[0].shape[0], news[0].shape[1]
         cur = self.index.value
         # inside a fused paged frame the per-token leaves hold the chunk's
         # write window alone; ``index`` and ``kv_valid`` stay whole
         col = cur - _fused_window_origin() if _FUSED_PAGED_STACK else cur
-        for leaf, new in zip(self.leaves, (k, v)):
+        for leaf, new in zip(self.leaves, news):
             leaf.value = jax.lax.dynamic_update_slice(leaf.value, new, (0, col, 0, 0))
         self.index.value = cur + s
         if padding_mask is not None:
@@ -303,6 +324,34 @@ class LatentKVCache(KVCache):
         )
 
 
+class IndexedKVCache(KVCache):
+    """The cache of GQA attention with a learned sparse-attention INDEXER
+    beside it: per token the K and V of every kv head, as :class:`KVCache`,
+    plus ONE index key ``k_idx`` of ``d_index`` values (after its LayerNorm
+    and rotary) shared by all index heads: ``2 * Hkv * D + d_index`` values
+    a token a layer (2048 + 128 = 2176 bytes at Keye-VL-2.0's widths in
+    bf16). A decode step scores every cached index key against the step's
+    index queries and attends only the ``topk`` best columns
+    (:func:`indexed_decode_attention`).
+
+    Leaves ``k``, ``v`` (B, L, Hkv, D) and ``k_idx`` (B, L, 1, d_index): a
+    third per-token leaf of the contract above (:data:`PAGED_LEAVES`), so
+    every walker handles it as it handles ``k_pe``. The writes take ``(k, v,
+    k_idx)``."""
+
+    def __init__(self, module, b, max_seq_len, hkv, d, d_index, dtype):
+        super().__init__(
+            module, b, max_seq_len, hkv, d, dtype,
+            leaves={"k": (hkv, d), "v": (hkv, d), "k_idx": (1, d_index)},
+        )
+
+    def prefill_write(self, k, v, k_idx, padding_mask=None):
+        self._prefill_write((k, v, k_idx), padding_mask)
+
+    def decode_write(self, k, v, k_idx, padding_mask=None):
+        self._decode_write((k, v, k_idx), padding_mask)
+
+
 # --- cache-collection slot helpers (serving) ----------------------------------
 #
 # The continuous-batching engine (serving/) owns ONE cache collection whose
@@ -310,12 +359,15 @@ class LatentKVCache(KVCache):
 # tree (outside a flax apply), classified by leaf name — the same contract
 # KVCache declares: k/v (..., B, L, Hkv, D), kv_valid (..., B, L), index
 # scalar cursor (nn.scan stacks a leading layer axis on each); a latent
-# cache's per-token leaves are k/k_pe (..., B, L, 1, d).
+# cache's per-token leaves are k/k_pe (..., B, L, 1, d), an indexed cache's
+# k/v and k_idx (..., B, L, 1, d_index).
 
 # THE names of the per-token storage leaves, (..., B, L, heads, width): what a
 # page pool pages, a prefix block copies and a fingerprint hashes. Every
-# walker of a cache tree classifies by this tuple.
-PAGED_LEAVES = ("k", "v", "k_pe")
+# walker of a cache tree classifies by this tuple, and a layer's leaves are
+# handed around in ITS order: (k, v), a latent cache's (k, k_pe), an indexed
+# cache's (k, v, k_idx).
+PAGED_LEAVES = ("k", "v", "k_pe", "k_idx")
 
 
 def cache_leaf_name(path) -> str:
@@ -358,7 +410,9 @@ def cache_bytes_per_token_layer(cache) -> float:
     siblings count at their share of a page), averaged over the layers:
     ``2 * Hkv * D * itemsize`` for a ``k``/``v`` cache, ``(d_latent + d_rope)
     * itemsize`` for a latent one (1152 at DeepSeek-V2's widths in bf16;
-    twice the latent would read 2176)."""
+    twice the latent would read 2176), ``(2 * Hkv * D + d_index) * itemsize``
+    for an indexed one (2176 at Keye-VL-2.0's widths: 4 kv heads of 128 and
+    one index key of 64; 2304 would be the index key padded to 128 lanes)."""
     import math
 
     tree = cache["pool"] if isinstance(cache, dict) and "pool" in cache else cache
@@ -835,26 +889,39 @@ def adopt_kv_pool_pairs(paged, logical, pairs):
     }
 
 
-def _fused_paged_decode(frame, q, caches, q_pos, kv_valid, latent_scale=None,
-                        mask=None):
-    """``caches``: the layer's window leaves as its cache kind orders them,
-    ``(k, v)`` or a latent cache's ``(c, k_pe)``; for the latter ``q`` is the
-    absorbed pair ``(q_c, q_r)`` and ``latent_scale`` the softmax scale."""
+def _next_fused_layer(frame):
+    """The attention layer whose turn it is in the active frame (layers call
+    in execution order)."""
+    order = frame["order"]
+    layer = order[frame["idx"] % len(order)]
+    frame["idx"] += 1
+    return layer
+
+
+def _refuse_tree_mask(mask):
     if mask is not None:
         raise ValueError(
             "a tree mask replaces the positional mask the paged kernels "
             "implement, and a fused paged frame holds no K/V view for the "
             "einsum to attend: use the gather transport"
         )
+
+
+def _fused_paged_decode(frame, q, caches, q_pos, kv_valid, latent_scale=None,
+                        mask=None):
+    """``caches``: the layer's window leaves as its cache kind orders them,
+    ``(k, v)`` or a latent cache's ``(c, k_pe)``; for the latter ``q`` is the
+    absorbed pair ``(q_c, q_r)`` and ``latent_scale`` the softmax scale. (An
+    indexed cache's decode is :func:`_fused_sparse_decode`.)"""
+    _refuse_tree_mask(mask)
     from neuronx_distributed_tpu.kernels.flash_decode import (
         paged_flash_decode_attention,
         paged_latent_decode_attention,
         paged_scatter_window_pages,
     )
 
-    pools, order = frame["pools"], frame["order"]
-    layer = order[frame["idx"] % len(order)]
-    frame["idx"] += 1
+    pools = frame["pools"]
+    layer = _next_fused_layer(frame)
     ps, bt = frame["page_size"], frame["tables"]
     # bring the pool current through THIS step: scatter the window (in which
     # the model just staged its token) into the carried pool. Its other
@@ -876,6 +943,43 @@ def _fused_paged_decode(frame, q, caches, q_pos, kv_valid, latent_scale=None,
     return paged_flash_decode_attention(
         q, *pair, bt, q_pos, kv_valid=kv_valid, page_size=ps
     )
+
+
+def _fused_sparse_decode(frame, q, q_idx, w_idx, caches, q_pos, kv_valid, topk):
+    """An indexed cache's step in the active frame: ``caches`` the layer's
+    window leaves ``(k, v, k_idx)``. The windows go into the carried pools
+    (the K and V pools through a KERNEL, :func:`~neuronx_distributed_tpu.
+    kernels.flash_decode.paged_scatter_window_pages_dma`: every user of those
+    pools inside the decode scan is then a kernel of one layout; the 64-wide
+    index keys as a latent cache's rotated key goes), then the three sparse
+    kernels run off the pools, each called in ITS scope and named after it."""
+    from neuronx_distributed_tpu.kernels.flash_decode import (
+        paged_index_scores,
+        paged_scatter_window_pages,
+        paged_scatter_window_pages_dma,
+        paged_sparse_decode_attention,
+    )
+
+    pools = frame["pools"]
+    layer = _next_fused_layer(frame)
+    ps, bt, page0 = frame["page_size"], frame["tables"], frame["page0"]
+    k_pool, v_pool, idx_pool = pools[layer]
+    with jax.named_scope(KV_VIEW_SCOPE):
+        idx_pool = paged_scatter_window_pages(idx_pool, caches[2], bt, page0)
+    with jax.named_scope(DSA_WRITE_SCOPE):
+        k_pool = paged_scatter_window_pages_dma(k_pool, caches[0], bt, page0)
+        v_pool = paged_scatter_window_pages_dma(v_pool, caches[1], bt, page0)
+    pools[layer] = (k_pool, v_pool, idx_pool)  # trace-time: the step's carry-out
+    with jax.named_scope(DSA_SCORE_SCOPE):
+        scores = paged_index_scores(
+            q_idx, w_idx, idx_pool, bt, q_pos, kv_valid, page_size=ps)
+    with jax.named_scope(DSA_SELECT_SCOPE):
+        scores = jnp.where(scores == 0, 0.0, scores)  # -0.0 ties as +0.0
+        vals, cols = jax.lax.top_k(scores, min(topk, scores.shape[1]))
+        n_sel = jnp.sum(vals > -jnp.inf, axis=1).astype(jnp.int32)
+    with jax.named_scope(DSA_ATTEND_SCOPE):
+        return paged_sparse_decode_attention(
+            q, k_pool, v_pool, bt, cols, n_sel, page_size=ps)
 
 
 def cache_fingerprint(cache):
@@ -989,6 +1093,191 @@ def latent_decode_attention(q_c, q_r, c_cache, r_cache, q_pos, scale,
     out = jnp.einsum("bhsl,bld->bshd", p, c)
     denom = jnp.swapaxes(p.sum(-1), 1, 2)[..., None]   # (B, S, H, 1)
     return (out / jnp.maximum(denom, 1e-20)).astype(q_c.dtype)
+
+
+# --- learned sparse attention: an indexer beside GQA ------------------------------
+#
+# DeepSeek-Sparse-Attention's lightning indexer, as Keye-VL-2.0 configures it:
+# per token ``H_i`` index queries of ``d_i`` and ONE index key of ``d_i``
+# (cached: :class:`IndexedKVCache`), a weight a head; the score of query ``t``
+# for key ``s`` is ``I[t, s] = sum_j w[t, j] relu(q_idx[t, j] . k_idx[s])``; a
+# query attends the ``min(t + 1, topk)`` causal keys of largest score, ties to
+# the lower position (``jax.lax.top_k``'s rule), one set for all heads.
+
+# The TPU builds a prompt's learned mask in ONE kernel (``kernels/flash_attention
+# .sparse_keep_mask_kernel``) whose key tiles are multiples of this: a shorter
+# bucket is padded up to it (the engine's buckets past 512 are multiples
+# already). The einsum and :func:`topk_mask`, the same set, are the CPU's form
+# and the row cache's.
+PREFILL_MASK_KERNEL_MULTIPLE = 512
+
+# Query rows a tile of the prefill's index scores and of the row-cache decode
+# (Keye-VL-2.0's ``q_chunk_size``): no (rows x keys) array is ever wider.
+DSA_QUERY_CHUNK = 512
+
+
+def index_scores(q_idx, w_idx, k_idx):
+    """``I`` (B, T, S) float32 of index queries ``q_idx`` (B, T, H_i, d_i)
+    with weights ``w_idx`` (B, T, H_i) against index keys ``k_idx`` (B, S,
+    d_i): a head at a time, so that only one (T, S) product stands beside the
+    sum. Zeros are +0.0 (``relu`` times a negative weight gives -0.0, which a
+    bit-ordered selection would rank below)."""
+
+    def head(acc, xs):
+        qh, wh = xs                                            # (B, T, d_i), (B, T)
+        s = jnp.einsum("btd,bsd->bts", qh, k_idx,
+                       preferred_element_type=jnp.float32)
+        return acc + jax.nn.relu(s) * wh.astype(jnp.float32)[..., None], None
+
+    b, t = q_idx.shape[:2]
+    acc, _ = jax.lax.scan(
+        head, jnp.zeros((b, t, k_idx.shape[1]), jnp.float32),
+        (jnp.moveaxis(q_idx, 2, 0), jnp.moveaxis(w_idx, 2, 0)))
+    return jnp.where(acc == 0, 0.0, acc)
+
+
+def topk_mask(scores, ok, topk: int):
+    """Boolean mask (..., S) of each row's ``min(count(ok), topk)`` largest
+    ``scores`` among the columns where ``ok``, ties to the lower position:
+    exactly the set ``jax.lax.top_k`` returns, found WITHOUT a sort: the
+    ``k``-th largest value by bisection over the bits of the float32 scores
+    (32 counting passes), then the ties at that value in position order
+    (-0.0 ties with 0.0)."""
+    scores = scores.astype(jnp.float32)
+    bits = jax.lax.bitcast_convert_type(
+        jnp.where(ok, jnp.where(scores == 0, 0.0, scores), -jnp.inf), jnp.uint32)
+    # a monotone map of float32 onto uint32: negative floats reversed
+    key = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+    k = jnp.minimum(jnp.sum(ok, axis=-1), topk).astype(jnp.int32)[..., None]
+
+    def bit(i, thr):
+        cand = thr | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        enough = jnp.sum(key >= cand, axis=-1, keepdims=True) >= k
+        return jnp.where(enough, cand, thr)
+
+    thr = jax.lax.fori_loop(0, 32, bit, jnp.zeros(k.shape, jnp.uint32))
+    above, tie = key > thr, key == thr
+    need = k - jnp.sum(above, axis=-1, keepdims=True)
+    return ok & (above | (tie & (jnp.cumsum(tie, axis=-1) <= need)))
+
+
+def _masked_gqa_attention(q, k, v, keep):
+    """Softmax attention of q (B, T, H, D) over the keys of k/v (B, S, Hkv,
+    D) where ``keep`` (B, T, S): float32 einsum, the golden path. A row that
+    keeps nothing (a padded query) returns zeros."""
+    b, t, h, d = q.shape
+    hkv = k.shape[2]
+    qg = q.reshape(b, t, hkv, h // hkv, d).astype(jnp.float32)
+    s = jnp.einsum("bthgd,bshd->bhgts", qg, k.astype(jnp.float32)) / jnp.sqrt(
+        jnp.float32(d))
+    ok = keep[:, None, None]
+    s = jnp.where(ok, s, -1e30)
+    p = jnp.where(ok, jnp.exp(s - jnp.max(s, axis=-1, keepdims=True)), 0.0)
+    out = jnp.einsum("bhgts,bshd->bthgd", p, v.astype(jnp.float32))
+    den = jnp.moveaxis(p.sum(-1), (1, 2, 3), (2, 3, 1))[..., None]
+    return (out / jnp.maximum(den, 1e-30)).reshape(b, t, h, d).astype(q.dtype)
+
+
+def _query_chunks(fn, arrays, t: int):
+    """``fn`` over chunks of :data:`DSA_QUERY_CHUNK` query rows (axis 1 of
+    every array), one chunk's temporaries at a time (``lax.map``)."""
+    chunk = DSA_QUERY_CHUNK
+    if t <= chunk:
+        return fn(*arrays)
+    n = -(-t // chunk)
+    pad = lambda a: jnp.pad(a, ((0, 0), (0, n * chunk - t)) + ((0, 0),) * (a.ndim - 2))  # noqa: E731
+    split = lambda a: jnp.moveaxis(  # noqa: E731
+        pad(a).reshape((a.shape[0], n, chunk) + a.shape[2:]), 1, 0)
+    out = jax.lax.map(lambda xs: fn(*xs), tuple(split(a) for a in arrays))
+    return jnp.moveaxis(out, 0, 1).reshape((out.shape[1], n * chunk) + out.shape[3:])[:, :t]
+
+
+def sparse_keep_mask(q_idx, w_idx, k_idx, q_pos, k_valid, topk: int,
+                     dtype=jnp.bool_):
+    """The learned mask (B, T, S), one byte a pair (``dtype``): query row ``t`` (at key position
+    ``q_pos[b, t]``) keeps key ``s`` iff ``s`` is valid (``k_valid`` (B, S)),
+    not in its future, and among its ``topk`` best by index score. Built a
+    chunk of query rows at a time."""
+    cols = jnp.arange(k_idx.shape[1], dtype=jnp.int32)
+
+    def chunk(qi, wi, pos):
+        ok = (pos[:, :, None] >= cols[None, None]) & k_valid[:, None, :]
+        return topk_mask(index_scores(qi, wi, k_idx), ok, topk).astype(dtype)
+
+    return _query_chunks(chunk, (q_idx, w_idx, q_pos), q_idx.shape[1])
+
+
+def sparse_prefill_attention(q, k, v, q_idx, w_idx, k_idx, topk: int,
+                             impl: str = "auto", mask=None):
+    """Causal GQA self-attention of a prompt under the learned mask: q (B, S,
+    H, D), k/v (B, S, Hkv, D), index queries/weights/keys as
+    :func:`index_scores`; ``mask`` (B, S) True at valid (non-padding) tokens.
+    The mask is ONE byte a (query, key) pair and nothing wider is ever S x S:
+    scores and thresholds are taken a block of query rows at a time (on the
+    TPU inside one kernel, the scores never leaving VMEM). On the TPU the
+    flash kernel reads the byte mask tile by tile
+    (``kernels/flash_attention.masked_flash_attention``: dense work, sparse
+    result); elsewhere the float32 einsum."""
+    b, s = q.shape[0], q.shape[1]
+    valid = jnp.ones((b, s), jnp.bool_) if mask is None else mask.astype(jnp.bool_)
+    pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
+    flash = backend.resolve_attention_impl(impl) == "flash"
+    with jax.named_scope(DSA_SCORE_SCOPE):
+        if flash:
+            # scores, thresholds and mask in one kernel: no score reaches HBM
+            from neuronx_distributed_tpu.kernels.flash_attention import (
+                sparse_keep_mask_kernel,
+            )
+
+            args = (q_idx, w_idx, k_idx[:, :, 0], valid)
+            pad = -s % PREFILL_MASK_KERNEL_MULTIPLE
+            if pad:     # padded keys are invalid; padded rows are cut off again
+                args = tuple(
+                    jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2)) for a in args)
+            keep = sparse_keep_mask_kernel(*args, topk)[:, :s, :s]
+        else:
+            keep = sparse_keep_mask(q_idx, w_idx, k_idx[:, :, 0], pos, valid, topk)
+    with jax.named_scope(DSA_ATTEND_SCOPE):
+        if flash:
+            from neuronx_distributed_tpu.kernels.flash_attention import (
+                masked_flash_attention,
+            )
+
+            return masked_flash_attention(q, k, v, keep)
+        return _query_chunks(
+            lambda qc, kc: _masked_gqa_attention(qc, k, v, kc), (q, keep), s)
+
+
+def indexed_decode_attention(q, q_idx, w_idx, k_cache, v_cache, idx_cache,
+                             q_pos, topk: int, kv_valid=None):
+    """Sparse GQA attention of decode rows against an :class:`IndexedKVCache`:
+    q (B, S, H, D) rows at slot positions ``q_pos`` (S,), their index queries
+    ``q_idx`` (B, S, H_i, d_i) and weights ``w_idx`` (B, S, H_i); the cache
+    leaves ``k``/``v`` (B, L, Hkv, D) and ``k_idx`` (B, L, 1, d_i). Each row
+    scores every valid column at or before its position, keeps the ``topk``
+    best and attends those alone.
+
+    Inside a :class:`fused_paged_attention_scope` the three kernels of
+    ``kernels/flash_decode.py`` run off the page pool (index scores over the
+    blocks a slot maps, ``top_k``, K and V of the selected tokens only);
+    elsewhere (a row cache: a suffix prefill, ``generate()``) the same
+    mathematics as float32 einsums under :func:`sparse_keep_mask`."""
+    if _FUSED_PAGED_STACK:
+        return _fused_sparse_decode(
+            _FUSED_PAGED_STACK[-1], q, q_idx, w_idx,
+            (k_cache, v_cache, idx_cache), q_pos, kv_valid, topk,
+        )
+    b, s = q.shape[0], q.shape[1]
+    valid = (jnp.ones(k_cache.shape[:2], jnp.bool_) if kv_valid is None
+             else kv_valid.astype(jnp.bool_))
+    q_pos = q_pos[None] if q_pos.ndim == 0 else q_pos
+    pos = jnp.broadcast_to(q_pos.astype(jnp.int32)[None], (b, s))
+    with jax.named_scope(DSA_SCORE_SCOPE):
+        keep = sparse_keep_mask(q_idx, w_idx, idx_cache[:, :, 0], pos, valid, topk)
+    with jax.named_scope(DSA_ATTEND_SCOPE):
+        return _query_chunks(
+            lambda qc, kc: _masked_gqa_attention(qc, k_cache, v_cache, kc),
+            (q, keep), s)
 
 
 class ParallelSelfAttention(nn.Module):

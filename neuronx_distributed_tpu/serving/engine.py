@@ -594,16 +594,17 @@ class ServingEngine:
         if mesh is not None and tp is None:
             tp = int(mesh.mesh.shape["tp"]) if hasattr(mesh, "mesh") else None
         # a latent (MLA) cache holds ONE row a token for all heads
-        # (modules/attention.py LatentKVCache): nothing to shard over tp
-        latent = getattr(
+        # (modules/attention.py LatentKVCache): nothing to shard over tp; an
+        # indexed cache's one index key a token likewise (IndexedKVCache)
+        cache_kind = getattr(
             getattr(model, "config", None), "kv_cache_kind", "kv"
-        ) == "latent"
-        if latent and (tp or 1) > 1:
+        )
+        if cache_kind != "kv" and (tp or 1) > 1:
             raise ValueError(
-                "ServingEngine(tp>1) does not serve a latent-cache (MLA) "
-                "model: its cache has no head axis to shard and the paged "
-                "latent decode kernel has no sharded form — serve it on one "
-                "chip (tp=None)"
+                f"ServingEngine(tp>1) does not serve a {cache_kind}-cache "
+                "model: its one-row-a-token leaf has no head axis to shard "
+                "and its paged decode kernels have no sharded form — serve "
+                "it on one chip (tp=None)"
             )
         self.tp = tp
         self._partitioner = None
@@ -832,9 +833,10 @@ class ServingEngine:
                 getattr(model, "attention_impl", "auto")
             ),
             decode_attention=(
-                ("paged_latent_fused" if latent else "paged_fused")
+                {"latent": "paged_latent_fused",
+                 "indexed": "paged_sparse_fused"}.get(cache_kind, "paged_fused")
                 if self.paged_attention == "fused"
-                else "einsum" if latent  # latent_decode_attention's only other path
+                else "einsum" if cache_kind != "kv"  # the one-row-a-token kinds' only other path
                 else resolve_decode_impl(max_seq_len)
             ),
             paged_attention=(
@@ -2305,6 +2307,25 @@ class ServingEngine:
             )
         return int(round(self.metrics.kv_bytes_per_token_layer))
 
+    def _selection_stats(self) -> dict:
+        """``ctx_tokens`` (tokens the decoding slots hold) and
+        ``selected_tokens`` (``sum(min(held, topk))``: what a sparse-attention
+        model's decode step attends) for the dispatch span: host arithmetic
+        from the slots' requests, no readback. Empty for a model without an
+        indexer (``config.index_topk``)."""
+        topk = getattr(getattr(self.model, "config", None), "index_topk", None)
+        if topk is None:
+            return {}
+        held = [
+            len(r.prompt) + len(r.tokens)
+            for s, r in enumerate(self._slot_req)
+            if r is not None and self._active[s]
+        ]
+        return {
+            "ctx_tokens": int(sum(held)),
+            "selected_tokens": int(sum(min(n, int(topk)) for n in held)),
+        }
+
     def _kv_view_bytes(self) -> int:
         """Bytes of per-token leaves the decode program materialises
         outside the page pool per chunk, from the shapes it traces: the
@@ -3270,6 +3291,7 @@ class ServingEngine:
             tracing.STEP_DISPATCH, active=active_at_dispatch,
             kv_bytes_per_token_layer=self._kv_bytes_per_token_layer(),
             kv_view_bytes=self._kv_view_bytes(),
+            **self._selection_stats(),
         ):
             cache_in = self.cache.take()
             draft_in = self.draft_cache.take()
@@ -3458,6 +3480,7 @@ class ServingEngine:
             tracing.STEP_DISPATCH, active=active_at_dispatch,
             kv_bytes_per_token_layer=self._kv_bytes_per_token_layer(),
             kv_view_bytes=self._kv_view_bytes(),
+            **self._selection_stats(),
         ):
             cache_in = self.cache.take()
             attempt = self._dispatch_attempts

@@ -1,0 +1,222 @@
+"""The three kernels of decode under a learned sparse-attention indexer
+against plain ``jnp`` on a scattered page pool (interpret mode on CPU): the
+index scores over the 64-wide (here 8-wide) paged leaf, walking only the
+blocks a slot maps; the sparse GQA kernel that fetches the selected tokens'
+K and V and nothing else; and the byte-masked flash forward of the prefill."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from neuronx_distributed_tpu.kernels.flash_attention import masked_flash_attention
+from neuronx_distributed_tpu.kernels.flash_decode import (
+    LATENT_BLOCK_TOKENS,
+    SPARSE_CHUNK_TOKENS,
+    paged_gather_leaf,
+    paged_index_scores,
+    paged_sparse_decode_attention,
+)
+from neuronx_distributed_tpu.modules.attention import (
+    _masked_gqa_attention,
+    index_scores,
+    indexed_decode_attention,
+    topk_mask,
+)
+
+PS, D, HKV, H, H_I, D_I = 16, 16, 2, 8, 4, 8
+T = LATENT_BLOCK_TOKENS
+
+
+def _pool(rng, b, n_log, lens):
+    """A pool whose pages are dealt out of order; slot ``i`` maps the pages
+    covering ``lens[i]`` (a number: columns from 0; a list of ``(first,
+    end)`` ranges: those); the rest stay on the null page 0."""
+    pages = 1 + b * n_log
+    leaf = lambda h, d: jnp.asarray(rng.standard_normal((pages, PS, h, d)), jnp.float32)  # noqa: E731
+    ids = rng.permutation(np.arange(1, pages))
+    table = np.zeros((b, n_log), np.int32)
+    for i, spans in enumerate(lens):
+        for first, end in [(0, spans)] if isinstance(spans, int) else spans:
+            lo, hi = first // PS, -(-end // PS)
+            table[i, lo:hi] = ids[i * n_log + lo:i * n_log + hi]
+    return leaf(HKV, D), leaf(HKV, D), leaf(1, D_I), jnp.asarray(table)
+
+
+def _valid(b, n_log, lens):
+    valid = np.zeros((b, n_log * PS), bool)
+    for i, spans in enumerate(lens):
+        for first, end in [(0, spans)] if isinstance(spans, int) else spans:
+            valid[i, first:end] = True
+    return valid
+
+
+def _queries(rng, b):
+    return (jnp.asarray(rng.standard_normal((b, 1, H, D)), jnp.float32),
+            jnp.asarray(rng.standard_normal((b, 1, H_I, D_I)), jnp.float32),
+            jnp.asarray(rng.standard_normal((b, 1, H_I)), jnp.float32))
+
+
+@pytest.mark.parametrize("n_log,lens", [
+    (8, (100, 37)),                                    # ragged contexts in one block
+    (160, (2500, 16, [(1040, 2560)])),                 # three blocks; a one-page slot; an unmapped first block
+    (70, (1100, [(0, 40), (1030, 1100)])),             # a row whose last block is partial
+])
+def test_index_scores_match_jnp_over_the_blocks_a_slot_maps(n_log, lens):
+    rng = np.random.default_rng(n_log)
+    b = len(lens)
+    _, _, idx_pool, table = _pool(rng, b, n_log, lens)
+    _, q_idx, w_idx = _queries(rng, b)
+    valid = _valid(b, n_log, lens)
+    valid[0, 3:9] = False                              # an invalid stretch inside a mapped page
+    cur = int(valid.any(0).nonzero()[0].max())         # the shared cursor: the last valid column
+    got = paged_index_scores(q_idx, w_idx, idx_pool, table, jnp.asarray([cur], jnp.int32),
+                             jnp.asarray(valid), page_size=PS)
+    keys = paged_gather_leaf(idx_pool, table, PS)[:, :, 0]
+    want = index_scores(q_idx, w_idx, keys)[:, 0]
+    ok = valid & (np.arange(n_log * PS)[None] <= cur)
+    assert got.shape == (b, n_log * PS) and got.dtype == jnp.float32
+    np.testing.assert_array_equal(np.isneginf(np.asarray(got)), ~ok)
+    np.testing.assert_allclose(np.asarray(got)[ok], np.asarray(want)[ok], atol=2e-5)
+
+
+def test_index_scores_stop_at_the_row_position():
+    rng = np.random.default_rng(5)
+    _, _, idx_pool, table = _pool(rng, 2, 8, (100, 90))
+    _, q_idx, w_idx = _queries(rng, 2)
+    valid = jnp.asarray(_valid(2, 8, (100, 90)))
+    got = np.asarray(paged_index_scores(q_idx, w_idx, idx_pool, table, jnp.asarray(60), valid, page_size=PS))
+    assert np.isfinite(got[:, :61]).all() and np.isneginf(got[:, 61:]).all()
+
+
+def test_the_index_kernel_reads_only_mapped_blocks():
+    """Columns of an unmapped block read -inf whatever ``kv_valid`` says of
+    them: the block was never fetched."""
+    rng = np.random.default_rng(6)
+    _, _, idx_pool, table = _pool(rng, 1, 3 * T // PS, ([(T, 2 * T)],))
+    _, q_idx, w_idx = _queries(rng, 1)
+    valid = jnp.ones((1, 3 * T), bool)
+    got = np.asarray(paged_index_scores(q_idx, w_idx, idx_pool, table, jnp.asarray(3 * T - 1), valid, page_size=PS))
+    assert np.isneginf(got[:, :T]).all() and np.isneginf(got[:, 2 * T:]).all() and np.isfinite(got[:, T:2 * T]).all()
+
+
+@pytest.mark.parametrize("k_sel,lens", [
+    (16, (100, 37)),                   # fewer selected than a chunk
+    (SPARSE_CHUNK_TOKENS + 40, (700, 300, 16)),   # two chunks, the second partial; a slot that keeps all it has
+])
+def test_sparse_attention_reads_the_selected_columns_only(k_sel, lens):
+    rng = np.random.default_rng(k_sel)
+    b, n_log = len(lens), 48
+    k_pool, v_pool, _, table = _pool(rng, b, n_log, lens)
+    q, _, _ = _queries(rng, b)
+    cols = np.zeros((b, k_sel), np.int32)
+    n_sel = np.zeros((b,), np.int32)
+    keep = np.zeros((b, 1, n_log * PS), bool)
+    for i, n in enumerate(lens):
+        n_sel[i] = min(n, k_sel)
+        picked = rng.permutation(n)[:n_sel[i]]
+        cols[i, :n_sel[i]] = picked
+        cols[i, n_sel[i]:] = rng.integers(0, n_log * PS, k_sel - n_sel[i])   # garbage past the count
+        keep[i, 0, picked] = True
+    got = paged_sparse_decode_attention(q, k_pool, v_pool, table, jnp.asarray(cols), jnp.asarray(n_sel),
+                                        page_size=PS)
+    want = _masked_gqa_attention(q, paged_gather_leaf(k_pool, table, PS), paged_gather_leaf(v_pool, table, PS),
+                                 jnp.asarray(keep))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    # poison every unselected token of the pool: the result does not move
+    flat = np.asarray(table)[np.arange(b)[:, None], cols // PS] * PS + cols % PS
+    mask = np.ones((k_pool.shape[0] * PS,), bool)
+    mask[:PS] = False      # the null page: a chunk's padding reads its token 0 (finite garbage, weight 0)
+    for i in range(b):
+        mask[flat[i, :n_sel[i]]] = False
+    poison = lambda pool: jnp.where(jnp.asarray(mask).reshape(-1, PS)[..., None, None], jnp.nan, pool)  # noqa: E731
+    again = paged_sparse_decode_attention(q, poison(k_pool), poison(v_pool), table, jnp.asarray(cols),
+                                          jnp.asarray(n_sel), page_size=PS)
+    np.testing.assert_array_equal(np.asarray(again), np.asarray(got))
+
+
+def test_a_slot_that_selects_nothing_returns_zeros():
+    rng = np.random.default_rng(8)
+    k_pool, v_pool, _, table = _pool(rng, 2, 8, (50, 0))
+    q, _, _ = _queries(rng, 2)
+    cols = jnp.asarray(rng.integers(0, 50, (2, 16)), jnp.int32)
+    out = paged_sparse_decode_attention(q, k_pool, v_pool, table, cols, jnp.asarray([16, 0]), page_size=PS)
+    assert np.isfinite(np.asarray(out)).all() and not np.asarray(out[1]).any() and np.asarray(out[0]).any()
+
+
+def test_the_fused_decode_is_the_einsum_decode(monkeypatch):
+    """Score, select and attend off the pool inside a fused frame against
+    ``indexed_decode_attention``'s einsum on the gathered rows: one rule, two
+    implementations, the same sets (ties and all) and the same output."""
+    from neuronx_distributed_tpu.modules import attention as att
+
+    rng = np.random.default_rng(9)
+    lens, n_log, topk = (100, 37, 70), 8, 24
+    b = len(lens)
+    k_pool, v_pool, idx_pool, table = _pool(rng, b, n_log, lens)
+    q, q_idx, w_idx = _queries(rng, b)
+    valid = jnp.asarray(_valid(b, n_log, lens))
+    pos = jnp.asarray([99], jnp.int32)
+    rows = [paged_gather_leaf(p, table, PS) for p in (k_pool, v_pool, idx_pool)]
+    want = indexed_decode_attention(q, q_idx, w_idx, *rows, pos, topk, kv_valid=valid)
+    scores = paged_index_scores(q_idx, w_idx, idx_pool, table, pos, valid, page_size=PS)
+    vals, cols = jax.lax.top_k(scores, topk)
+    got = paged_sparse_decode_attention(q, k_pool, v_pool, table, cols, (vals > -jnp.inf).sum(1), page_size=PS)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    assert att.DSA_SCORE_SCOPE == "dsa.score" and att.DSA_ATTEND_SCOPE == "dsa.attend"
+
+
+@pytest.mark.parametrize("k", [1, 5, 16, 40])
+def test_topk_mask_is_top_k_with_ties_to_the_lower_position(k):
+    rng = np.random.default_rng(k)
+    x = rng.standard_normal((3, 7, 33)).astype(np.float32)
+    x[0, :, ::3] = 0.0                 # exact ties, and -0.0 among them
+    x[0, :, 3::6] = -0.0
+    x[1, 2] = 0.0                      # a row of nothing but ties
+    ok = rng.random((3, 7, 33)) < 0.8
+    ok[2, 3] = False                   # a row with nothing to keep
+    got = np.asarray(topk_mask(jnp.asarray(x), jnp.asarray(ok), k))
+    vals, idx = jax.lax.top_k(jnp.where(ok, jnp.where(x == 0, 0.0, x), -jnp.inf), min(k, 33))
+    want = np.zeros_like(ok)
+    for i in np.ndindex(3, 7):
+        want[i][np.asarray(idx[i])[np.asarray(vals[i]) > -np.inf]] = True
+    np.testing.assert_array_equal(got, want)
+    assert (got.sum(-1) == np.minimum(ok.sum(-1), k)).all()
+    # all-zero scores: the first k valid positions
+    first = np.asarray(topk_mask(jnp.zeros((1, 20)), jnp.ones((1, 20), bool), 6))
+    np.testing.assert_array_equal(first[0], np.arange(20) < 6)
+
+
+@pytest.mark.parametrize("s,bq", [(64, 16), (96, 32)])
+def test_masked_flash_forward_matches_the_einsum(s, bq):
+    rng = np.random.default_rng(s)
+    q = jnp.asarray(rng.standard_normal((2, s, H, D)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((2, s, HKV, D)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((2, s, HKV, D)), jnp.float32)
+    keep = np.tril(rng.random((2, s, s)) < 0.3)
+    keep[1, 5] = False                 # a row that keeps nothing: zeros
+    got = masked_flash_attention(q, k, v, jnp.asarray(keep, jnp.int8), block_q=bq, block_k=bq)
+    want = _masked_gqa_attention(q, k, v, jnp.asarray(keep))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    assert not np.asarray(got[1, 5]).any()
+
+
+@pytest.mark.parametrize("s,topk,pad", [(512, 40, 0), (1024, 100, 0), (2560, 300, 200)])
+def test_the_keep_mask_kernel_is_the_einsum_and_topk_mask(s, topk, pad):
+    """Scores, thresholds, ties and the byte mask in one kernel against the
+    float32 einsum and ``topk_mask``: the same sets, exact ties (zeroed index
+    keys) and a left-padded prompt included."""
+    from neuronx_distributed_tpu.kernels.flash_attention import sparse_keep_mask_kernel
+    from neuronx_distributed_tpu.modules.attention import sparse_keep_mask
+
+    rng = np.random.default_rng(s)
+    q = jnp.asarray(rng.standard_normal((1, s, H_I, D_I)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((1, s, H_I)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((1, s, D_I)), jnp.float32).at[:, 5:400:7].set(0.0)
+    valid = jnp.arange(s)[None] >= pad
+    pos = jnp.broadcast_to(jnp.arange(s)[None], (1, s))
+    want = np.asarray(sparse_keep_mask(q, w, k, pos, valid, topk))
+    got = np.asarray(sparse_keep_mask_kernel(q, w, k, valid, topk))
+    assert got.dtype == np.int8
+    np.testing.assert_array_equal(got != 0, want)
+    assert (want.sum(-1) == np.minimum(np.maximum(np.arange(s) + 1 - pad, 0), topk)).all()

@@ -187,6 +187,59 @@ def test_paged_latent_decode_compiles(topo):
     assert vmem < 16 * 1024**2 / 4
 
 
+def test_sparse_decode_kernels_compile_at_keye_geometry(topo):
+    """Keye-VL-2.0's decode under its indexer at the serve cell's shapes (8
+    slots of 32,768 columns, page 16, 32 Q / 4 KV heads of 128, 16 index
+    heads of 64, 2048 kept): the index-score kernel over the 64-wide leaf
+    (its page copies name the tile's 128 lanes) and the sparse kernel that
+    fetches a token's (4, 128) K and V rows; and the byte-masked flash
+    forward of a 24,576-token prefill with the kernel that builds its mask
+    (64 query rows' scores as int32 keys in 6 MiB of VMEM). None may copy a pool leaf whole but
+    the index kernel's 64-wide one (the layout conversion PR 26 found)."""
+    from neuronx_distributed_tpu.kernels.flash_attention import (
+        masked_flash_attention,
+        sparse_keep_mask_kernel,
+    )
+    from neuronx_distributed_tpu.kernels.flash_decode import (
+        paged_index_scores,
+        paged_sparse_decode_attention,
+    )
+
+    s = _one_chip(topo)
+    b, n_log, page, keep = 8, 2048, 16, 2048
+    pages = b * n_log + 1
+    table, valid = s((b, n_log), jnp.int32), s((b, n_log * page), jnp.bool_)
+    text = _compiled_text(
+        lambda q, w, pool, bt, pos, ok: paged_index_scores(q, w, pool, bt, pos, ok, page_size=page),
+        s((b, 1, 16, 64)), s((b, 1, 16)), s((pages, page, 1, 64)), table, s((1,), jnp.int32), valid)
+    assert KERNEL in text
+    text = _compiled_text(
+        lambda q, k, v, bt, cols, n: paged_sparse_decode_attention(q, k, v, bt, cols, n, page_size=page),
+        s((b, 1, H, D)), s((pages, page, 4, D)), s((pages, page, 4, D)), table,
+        s((b, keep), jnp.int32), s((b,), jnp.int32))
+    assert KERNEL in text
+    assert not re.search(r"bf16\[%d,%d,4,%d\]\S* copy\(" % (pages, page, D), text), "a K/V pool leaf is copied whole"
+    seq = 24576
+    text = _compiled_text(
+        lambda q, k, v, m: masked_flash_attention(q, k, v, m),
+        s((1, seq, H, D)), s((1, seq, 4, D)), s((1, seq, 4, D)), s((1, seq, seq), jnp.int8))
+    assert KERNEL in text
+    text = _compiled_text(
+        lambda q, w, k, ok: sparse_keep_mask_kernel(q, w, k, ok, keep),
+        s((1, seq, 16, 64)), s((1, seq, 16)), s((1, seq, 64)), s((1, seq), jnp.bool_))
+    assert KERNEL in text
+    # a bucket under the mask kernel's 512-wide key tile is padded up to it:
+    # the TPU has ONE prefill form (both kernels), whatever the prompt
+    from neuronx_distributed_tpu.modules.attention import sparse_prefill_attention
+
+    short = 128
+    text = _compiled_text(
+        lambda q, k, v, qi, wi, ki, ok: sparse_prefill_attention(q, k, v, qi, wi, ki, keep, "flash", ok),
+        s((1, short, H, D)), s((1, short, 4, D)), s((1, short, 4, D)), s((1, short, 16, 64)),
+        s((1, short, 16)), s((1, short, 1, 64)), s((1, short), jnp.bool_))
+    assert text.count(KERNEL) >= 2
+
+
 @pytest.mark.parametrize("seq", [2048, 20992, 32768])
 def test_flash_prefill_with_a_narrower_value_head_compiles(topo, seq):
     """MLA's materialised prefill: q and k of 192, v of 128, with the
@@ -568,3 +621,56 @@ def test_deepseek_v2_lite_engine_programs_compile_and_fit(topo):
     prefill = lower_prefill().compile()
     assert KERNEL in prefill.as_text()
     assert _fits(prefill, 15 * 1024**3)
+
+
+def _keye_vl2(layers, seq):
+    from neuronx_distributed_tpu.models.keye_vl2 import KeyeVL2ForCausalLM, keye_vl2_30b_a3b
+
+    return KeyeVL2ForCausalLM(
+        keye_vl2_30b_a3b(
+            num_layers=layers, max_seq_len=seq, param_dtype=jnp.bfloat16,
+            expert_strategy="blockwise",
+        ),
+        attention_impl="auto",
+    )
+
+
+# what the described-v5e compile of the configured depth showed for the decode
+# chunk's temporaries (GiB): the 64-wide index-key leaf's layout conversions
+# and the weights' hoisted layout copies
+KEYE_DECODE_TEMP_GIB = 0.47
+
+
+@pytest.mark.slow
+def test_keye_vl2_engine_programs_compile_and_fit(topo):
+    """The benchmark configuration's programs (``perfbench/configs/
+    keye-vl2-30b-a3b-serve.json``: its depth, 8 slots of 32,768, page 16):
+    the fused decode chunk with the three-leaf pool carried and the longest
+    prompt's prefill under the byte mask, both with Pallas kernels and inside
+    the chip's memory; the decode program holds no row-sized K/V array and
+    its measured temporaries + 10%."""
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    with open(os.path.join(root, "perfbench", "configs", "keye-vl2-30b-a3b-serve.json")) as f:
+        config = json.load(f)
+    seq = int(config["serving"]["max_seq_len"])
+    engine, lower_decode, lower_prefill, pool_shards = _engine_programs(
+        topo, 1, slots=int(config["serving"]["num_slots"]), seq=seq,
+        bucket=24576, model=_keye_vl2(int(config["model"]["num_hidden_layers"]), seq),
+    )
+    assert engine.programs.resolved == {
+        "attention": "flash", "decode_attention": "paged_sparse_fused",
+        "paged_attention": "fused",
+    }
+    assert sorted(set(pool_shards)) == [(16385, 16, 1, 64), (16385, 16, 4, 128)]
+    decode = lower_decode().compile()
+    assert KERNEL in decode.as_text()
+    assert _fits(decode, 15 * 1024**3)
+    _assert_pool_carried_and_no_view(decode, pool_shards, KEYE_DECODE_TEMP_GIB)
+    prefill = lower_prefill().compile()
+    assert KERNEL in prefill.as_text()
+    assert _fits(prefill, 15 * 1024**3)
+    # no S x S array wider than a byte
+    wide = [m.group(0) for m in re.finditer(r"(f32|bf16|s32|u32)\[[\d,]*24576,24576\]", prefill.as_text())]
+    assert not wide, wide[:3]

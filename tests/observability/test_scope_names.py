@@ -119,3 +119,58 @@ def test_the_latent_attention_programs_carry_their_names(deepseek):
     # the attention call itself sits outside the mla.* scopes, in the method
     assert any(path.endswith("attn._cached_attention/pallas_call") or "attn._cached_attention" in path
                for path in LOCATION.findall(prefill[0]))
+
+
+@pytest.fixture(scope="module")
+def keye():
+    return _programs("keye-vl2-30b-a3b-serve")
+
+
+def test_the_sparse_attention_programs_carry_their_names(keye):
+    """``dsa_block_dev_share_pct`` finds the scopes ``dsa.index`` (the index
+    projections, LayerNorm, rotary, the ``k_idx`` write), ``dsa.score``,
+    ``dsa.select`` and ``dsa.attend``; each decode kernel is called in ITS
+    scope and so carries its name (``dsa_index_roofline`` reads ``dsa.score``,
+    ``dsa_attend_roofline`` ``dsa.attend``; ``dsa.write`` is the window's page
+    copies into the K and V pools)."""
+    decode, prefill = keye["decode_chunk"], keye["prefill"]
+    assert re.search(r"HloModule (\S+?),", decode[1]).group(1) == "jit_chunk_fn"
+    assert re.search(r"HloModule (\S+?),", prefill[1]).group(1) == "jit_fn"
+    callers = {path.rsplit("/", 1)[-1] for path in KERNEL_CALL.findall(decode[0])}
+    assert callers == {"dsa.score", "dsa.attend", "dsa.write"}
+    scopes = {"dsa.index", "dsa.score", "dsa.select", "dsa.attend"}
+    assert scopes | {"attn", "kv_view", "moe", "sample", "lm_head"} <= _traced(decode)
+    assert scopes | {"moe", "moe.experts"} <= _optimized(decode)
+    # prefill: the scores and the mask under dsa.score, attention under dsa.attend
+    assert {"dsa.index", "dsa.score", "dsa.attend", "moe"} <= _traced(prefill)
+    assert {"dsa.index", "dsa.score"} <= _optimized(prefill)
+    assert "dsa.select" not in _traced(prefill)            # a threshold, not a top_k
+
+
+def test_the_dispatch_span_carries_the_selection_stats():
+    """``dsa_selected_share_pct`` reads ``selected_tokens`` / ``ctx_tokens`` off
+    ``nxd.step.decode.dispatch``: host arithmetic from the slots' lengths; a
+    model without an indexer has neither."""
+    from neuronx_distributed_tpu.models.keye_vl2 import KeyeVL2ForCausalLM, tiny_keye_vl2
+    from neuronx_distributed_tpu.models.mixtral import MixtralForCausalLM, tiny_mixtral
+
+    def stats(model, prompts):
+        params = jax.jit(model.init)(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+        engine = ServingEngine(model, params, num_slots=2, kv_page_size=16)
+        for p in prompts:
+            engine.submit(np.arange(1, 1 + p, dtype=np.int32), GenerationConfig(max_new_tokens=2, temperature=0.0))
+        seen = []
+        span = engine._span
+
+        def spy(name, **stats):
+            if name == "nxd.step.decode.dispatch":
+                seen.append({k: v for k, v in stats.items() if k in ("ctx_tokens", "selected_tokens")})
+            return span(name, **stats)
+
+        engine._span = spy
+        engine.run()
+        return seen[0]         # the first chunk: each slot holds its prompt and the prefill's token
+
+    got = stats(KeyeVL2ForCausalLM(tiny_keye_vl2(), attention_impl="xla"), (40, 9))
+    assert got == {"ctx_tokens": 41 + 10, "selected_tokens": 16 + 10}
+    assert stats(MixtralForCausalLM(tiny_mixtral(), attention_impl="xla"), (12,)) == {}

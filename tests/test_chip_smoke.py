@@ -78,18 +78,44 @@ MLA = chip_smoke.MlaSize(
 )
 
 
+def _tiny_dsa():
+    import json
+
+    path = os.path.join(os.path.dirname(_PATH), "tests", "benchmark", "data",
+                        "configs", "keye-vl2-30b-a3b-serve.json")
+    with open(path) as f:
+        return json.load(f)["model"]
+
+
+# Keye-VL-2.0's structure at the CPU stand-in's size (32 columns kept);
+# float32, so the tolerances are a float32 matmul's summation order; the
+# float8 control is held to the selection limit, the halved one to all
+DSA = chip_smoke.DsaSize(
+    model=_tiny_dsa(), max_seq_len=512, slots=2, prompt_lens=(300, 128, 40),
+    tail=32, new_tokens=12, kernel_contexts=(70, 200, 33), kernel_cursor=260,
+    kernel_calls=2, kernel_prefill=512, kernel_tol=1e-4, logit_tol=1e-3, typical_tol=1e-4,
+    selected_tol=1e-3, index_near_tie=1e-3, gap_tol=1e-3,
+)
+
+
 def test_one_chip_run_rehearsal():
     """Train, serve, then the MLA model in ONE process, exactly as ``main()``
     runs them (the train phase's global mesh must not leak into the
     mesh-free engines)."""
     _assert_only_kernel_checks_fail(
-        chip_smoke.one_chip(0, jax.devices()[:1], TRAIN, SERVE, MLA)
+        chip_smoke.one_chip(0, jax.devices()[:1], TRAIN, SERVE, MLA, dsa=DSA)
     )
 
 
 def test_mla_phase_alone_rehearsal():
     _assert_only_kernel_checks_fail(
         chip_smoke.one_chip(0, jax.devices()[:1], TRAIN, SERVE, MLA, only="mla")
+    )
+
+
+def test_dsa_phase_alone_rehearsal():
+    _assert_only_kernel_checks_fail(
+        chip_smoke.one_chip(0, jax.devices()[:1], TRAIN, SERVE, MLA, only="dsa", dsa=DSA)
     )
 
 
