@@ -920,6 +920,7 @@ def dsa_kernels(size: DsaSize, published: dict, seed: int, dtype) -> Dict[str, b
     import numpy as np
 
     from neuronx_distributed_tpu.kernels.flash_decode import (
+        SPARSE_CHUNK_TOKENS,
         paged_gather_leaf,
         paged_index_scores,
         paged_sparse_decode_attention,
@@ -929,6 +930,7 @@ def dsa_kernels(size: DsaSize, published: dict, seed: int, dtype) -> Dict[str, b
         _masked_gqa_attention,
         index_scores,
         sparse_keep_mask,
+        split_kv,
         topk_mask,
     )
 
@@ -948,8 +950,9 @@ def dsa_kernels(size: DsaSize, published: dict, seed: int, dtype) -> Dict[str, b
         valid[i, cur + 1 - n:cur + 1] = True
     key = jax.random.split(jax.random.PRNGKey(seed), 6)
     pages = b * n_log + 1
-    k_pool = jax.random.normal(key[0], (pages, page, hkv, d), dtype)
-    v_pool = jax.random.normal(key[1], (pages, page, hkv, d), dtype)
+    # the indexed cache's joined leaf: a token's K heads, then its V heads
+    kv_pool = jnp.concatenate([jax.random.normal(key[0], (pages, page, hkv, d), dtype),
+                               jax.random.normal(key[1], (pages, page, hkv, d), dtype)], axis=2)
     i_pool = jax.random.normal(key[2], (pages, page, 1, d_i), dtype)
     q = jax.random.normal(key[3], (b, 1, h, d), dtype)
     q_idx = jax.random.normal(key[4], (b, 1, h_i, d_i), dtype)
@@ -980,18 +983,19 @@ def dsa_kernels(size: DsaSize, published: dict, seed: int, dtype) -> Dict[str, b
     bisect_ms = _median_call_ms(bisect, (got,), size.kernel_calls)
 
     n_sel = jnp.sum(vals > -jnp.inf, axis=1).astype(jnp.int32)
-    attend = lambda qq, kp, vp: paged_sparse_decode_attention(qq, kp, vp, table, cols, n_sel, page_size=page)   # noqa: E731
-    out = jax.jit(attend)(q, k_pool, v_pool)
+    attend = lambda qq, kvp: paged_sparse_decode_attention(qq, kvp, table, cols, n_sel, page_size=page)   # noqa: E731
+    out = jax.jit(attend)(q, kv_pool)
     keep_mask = np.zeros((b, 1, length), bool)
     for i in range(b):
         keep_mask[i, 0, np.asarray(cols[i])[: int(n_sel[i])]] = True
     with jax.default_matmul_precision("highest"):
-        ref = jax.jit(lambda qq, kp, vp: _masked_gqa_attention(
-            f32(qq), f32(paged_gather_leaf(kp, table, page)), f32(paged_gather_leaf(vp, table, page)),
-            jnp.asarray(keep_mask)))(q, k_pool, v_pool)
+        ref = jax.jit(lambda qq, kvp: _masked_gqa_attention(
+            f32(qq), *split_kv(f32(paged_gather_leaf(kvp, table, page))), jnp.asarray(keep_mask)))(q, kv_pool)
     attend_err = float(np.abs(np.asarray(f32(out)) - np.asarray(ref)).max())
-    attend_ms = _median_call_ms(attend, (q, k_pool, v_pool), size.kernel_calls)
+    attend_ms = _median_call_ms(attend, (q, kv_pool), size.kernel_calls)
     attend_bytes = int(sum(min(n, keep) for n in ctx)) * 2 * hkv * d * 2
+    chunk = min(SPARSE_CHUNK_TOKENS, keep)
+    copies = sum(-(-int(n) // chunk) * chunk for n in np.asarray(n_sel))   # a slot's count, rounded up to the chunk
     # the prefill's learned mask: one kernel against the einsum + bisection
     sp = size.kernel_prefill
     pk = jax.random.split(jax.random.PRNGKey(seed + 1), 3)
@@ -1013,8 +1017,8 @@ def dsa_kernels(size: DsaSize, published: dict, seed: int, dtype) -> Dict[str, b
         f"({score_bytes / score_ms / 1e6:.1f} GB/s), max |kernel - float32 jnp| {score_err:.5f}; selection of "
         f"{keep} of {length}: top_k {top_ms:.3f} ms, bisection mask {bisect_ms:.3f} ms (the same sets: {same}); "
         f"sparse attention {attend_ms:.3f} ms a call for {attend_bytes / 1e6:.1f} MB "
-        f"({attend_bytes / attend_ms / 1e6:.1f} GB/s, {int(n_sel.sum())} tokens), max |kernel - float32 jnp| "
-        f"{attend_err:.5f}")
+        f"({attend_bytes / attend_ms / 1e6:.1f} GB/s, {int(n_sel.sum())} tokens; {copies} copies of "
+        f"{2 * hkv * d * 2} B, {1e6 * attend_ms / copies:.1f} ns each), max |kernel - float32 jnp| {attend_err:.5f}")
     return {
         "dsa_index_kernel_matches_jnp": score_ok,
         "dsa_bisection_selects_what_top_k_selects": same,

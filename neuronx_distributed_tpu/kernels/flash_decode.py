@@ -1048,15 +1048,26 @@ def paged_latent_decode_attention(
 #   bytes a token. Invalid, future and unmapped columns read ``-inf``.
 # * the selection, ``jax.lax.top_k`` over the row (the caller's).
 # * :func:`paged_sparse_decode_attention`: the selected columns' K and V, and
-#   nothing else, fetched a TOKEN at a time (one async copy of ``(Hkv, D)`` a
-#   leaf) through the block table into a two-chunk VMEM buffer; online softmax
-#   over chunks of ``SPARSE_CHUNK_TOKENS``, the GQA group's query rows of each
-#   kv head against that head's rows of the chunk.
+#   nothing else, fetched a TOKEN at a time through the block table into a
+#   two-chunk VMEM buffer: ONE async copy a token, of the cache's joined leaf
+#   ``(2 Hkv, D)`` (K's heads, then V's: ``(8, 128)`` in bf16 at Keye's
+#   widths, 2 KB), its ``(page, offset)`` read from two prefetched arrays (no
+#   divide in the issuing loop), and ONE wait a chunk (a DMA semaphore counts
+#   bytes; a chunk's copies fill the whole buffer). The kernel is bound by
+#   how many copies it names (~22 ns each on a v5e whoever waits, scalar work
+#   in the multiply's own instruction stream), not by their bytes. Online
+#   softmax over chunks of ``SPARSE_CHUNK_TOKENS``, the GQA group's query
+#   rows of each kv head against that head's K and V rows of the chunk.
 
-# Tokens a chunk of the sparse kernel: 2 chunks x 2 leaves x 256 x (Hkv, D).
-SPARSE_CHUNK_TOKENS = 256
-# Token copies a trip of the issuing (or waiting) loop, unrolled.
-_TOKENS_A_TRIP = 8
+# Tokens a chunk of the sparse kernel: 2 chunks x 512 x (2 Hkv, D) = 2 MiB of
+# VMEM at Keye's widths. Swept on the chip at the serve cell's shapes (PERF.md
+# section 6, PR 31), ms a call: 128 0.481, 256 0.480, 512 0.456 at 8 copies a
+# trip; fewer, longer chunks leave less of the multiply standing alone.
+SPARSE_CHUNK_TOKENS = 512
+# Token copies a trip of the issuing loop, unrolled: 16 copies, what the
+# two-leaf form unrolled (8 tokens x K and V). At chunks of 512: 8 0.456, 16
+# 0.439, 32 0.427 (not taken: a longer body to lower in every process).
+_TOKENS_A_TRIP = 16
 
 
 def _paged_index_kernel(bt_ref, live_ref, span_ref, bound_ref, valid_ref,
@@ -1189,12 +1200,12 @@ def paged_scatter_window_pages_dma(pool: jax.Array, window: jax.Array,
                                    interpret: Optional[bool] = None) -> jax.Array:
     """:func:`paged_scatter_window_pages` as a kernel: one copy a window
     page into the pool, which is aliased to the result and otherwise left
-    where it is. For a pool leaf whose ``(heads, width)`` XLA would lay out
-    otherwise than the sparse kernel reads it (4 kv heads of 128 in bf16: a
-    tile of ``(page, width)``): with every user of the carried pool inside
-    the decode scan a kernel of ONE layout, no step converts it (the XLA
-    scatter made each step copy every layer's K and V pool whole: 256 MiB a
-    leaf at the serve cell's size). Single layer ``(P, page, Hkv, D)``."""
+    where it is. For the pool leaf the sparse kernel reads a token at a
+    time: with every user of the carried pool inside the decode scan a
+    kernel of ONE layout, no step converts it (with K and V as two leaves of
+    4 heads an XLA scatter laid them out ``(page, width)`` minor and each
+    step copied every layer's K and V pool whole: 256 MiB a leaf at the
+    serve cell's size). Single layer ``(P, page, heads, D)``."""
     b, page_size = block_table.shape[0], pool.shape[1]
     n_win = window.shape[1] // page_size
     ids = jax.lax.dynamic_slice(block_table.astype(jnp.int32), (0, page0), (b, n_win)).reshape(-1)
@@ -1219,27 +1230,28 @@ def paged_scatter_window_pages_dma(pool: jax.Array, window: jax.Array,
     )(ids, vals, pool)
 
 
-def _sparse_decode_kernel(tok_ref, n_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
-                          v_buf, sems, m_scr, l_scr, acc_scr, *, chunk,
-                          num_kv_heads, page_size, scale):
+def _sparse_decode_kernel(page_ref, off_ref, n_ref, q_ref, kv_hbm, o_ref, buf,
+                          sems, m_scr, l_scr, acc_scr, *, chunk, num_kv_heads,
+                          scale):
     b = pl.program_id(0)
     n = n_ref[b]
     n_chunks = (n + chunk - 1) // chunk
 
-    def copies(c, wait=False):
+    def fetch(c):
         slot = c % 2
 
         def some(t, carry):
             for u in range(_TOKENS_A_TRIP):
                 j = t * _TOKENS_A_TRIP + u
+                i = c * chunk + j
                 # a token's pool row: (page, offset); the pool keeps its four
-                # dims (flattening the first two is a whole-pool copy on the chip)
-                tok = 0 if wait else tok_ref[b, c * chunk + j]
-                page, off = tok // page_size, tok % page_size
-                for hbm, buf in ((k_hbm, k_buf), (v_hbm, v_buf)):
-                    copy = pltpu.make_async_copy(
-                        hbm.at[page, off], buf.at[slot, j], sems.at[slot])
-                    copy.wait() if wait else copy.start()
+                # dims (PR 30: flattening the first two was a whole-pool copy
+                # on the chip), and one address read more costs nothing a
+                # call can show
+                pltpu.make_async_copy(
+                    kv_hbm.at[page_ref[b, i], off_ref[b, i]], buf.at[slot, j],
+                    sems.at[slot],
+                ).start()
             return carry
 
         jax.lax.fori_loop(0, chunk // _TOKENS_A_TRIP, some, 0)
@@ -1250,20 +1262,23 @@ def _sparse_decode_kernel(tok_ref, n_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
 
     @pl.when(n_chunks > 0)
     def _first():
-        copies(0)
+        fetch(0)
 
     def step(c, carry):
         @pl.when(c + 1 < n_chunks)
         def _prefetch():
-            copies(c + 1)
+            fetch(c + 1)
 
-        copies(c, wait=True)
         slot = c % 2
+        # ONE wait a chunk: a DMA semaphore counts bytes, and a chunk's copies
+        # (its padding entries copy token 0 of the null page) fill the whole
+        # buffer, so a descriptor over the buffer names exactly their bytes
+        pltpu.make_async_copy(buf.at[slot], buf.at[slot], sems.at[slot]).wait()
         rows = q_ref.shape[2]
         ok = jax.lax.broadcasted_iota(jnp.int32, (rows, chunk), 1) + c * chunk < n
         for h in range(num_kv_heads):
-            k = k_buf[slot, :, h, :]                           # (T, D)
-            v = v_buf[slot, :, h, :]
+            k = buf[slot, :, h, :]                             # (T, D)
+            v = buf[slot, :, num_kv_heads + h, :]
             s = jax.lax.dot_general(
                 q_ref[0, h], k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
@@ -1288,8 +1303,7 @@ def _sparse_decode_kernel(tok_ref, n_ref, q_ref, k_hbm, v_hbm, o_ref, k_buf,
 
 def paged_sparse_decode_attention(
     q: jax.Array,
-    k_pool: jax.Array,
-    v_pool: jax.Array,
+    kv_pool: jax.Array,
     block_table: jax.Array,
     sel_cols: jax.Array,
     n_sel: jax.Array,
@@ -1297,13 +1311,15 @@ def paged_sparse_decode_attention(
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """GQA decode attention over SELECTED columns only: ``q`` (B, 1, H, D);
-    ``k_pool``/``v_pool`` (P, page_size, Hkv, D); ``sel_cols`` (B, K) int32
-    logical columns of each slot, of which the first ``n_sel`` (B,) count (a
-    ``top_k``'s order: the valid ones first); ``block_table`` (B, n_log).
-    Softmax over those columns of ``q_h . k_g(h) / sqrt(D)``, times ``v``:
-    (B, 1, H, D). Only the selected tokens' K and V leave HBM: ``n_sel``
-    copies of ``(Hkv, D)`` a leaf a slot, rounded up to the chunk. The kernel
-    or nothing (interpreted only in tests); no mesh."""
+    ``kv_pool`` (P, page_size, 2 Hkv, D), a token's K heads then its V heads
+    (:class:`~neuronx_distributed_tpu.modules.attention.IndexedKVCache`'s
+    joined leaf); ``sel_cols`` (B, K) int32 logical columns of each slot, of
+    which the first ``n_sel`` (B,) count (a ``top_k``'s order: the valid ones
+    first); ``block_table`` (B, n_log). Softmax over those columns of ``q_h .
+    k_g(h) / sqrt(D)``, times ``v``: (B, 1, H, D). Only the selected tokens'
+    K and V leave HBM: ``n_sel`` copies of ``(2 Hkv, D)`` a slot, rounded up
+    to the chunk. The kernel or nothing (interpreted only in tests); no
+    mesh."""
     from neuronx_distributed_tpu.parallel import mesh as mesh_lib
 
     if mesh_lib.model_parallel_is_initialized():
@@ -1314,28 +1330,29 @@ def paged_sparse_decode_attention(
     b, s, h, d = q.shape
     if s != 1:
         raise ValueError(f"one query row a slot, got {s}")
-    hkv = k_pool.shape[2]
+    if kv_pool.ndim != 4 or kv_pool.shape[2] % 2 or kv_pool.shape[3] != d:
+        raise ValueError(
+            f"joined K/V pool leaf must be (P, page_size, 2 Hkv, {d}), got {kv_pool.shape}")
+    hkv = kv_pool.shape[2] // 2
     g = h // hkv
     k_sel = sel_cols.shape[1]
     chunk = min(SPARSE_CHUNK_TOKENS, -(-k_sel // _TOKENS_A_TRIP) * _TOKENS_A_TRIP)
     padded = -(-k_sel // chunk) * chunk
     n_sel = jnp.minimum(n_sel.astype(jnp.int32), k_sel)
     cols = sel_cols.astype(jnp.int32)
-    page = jnp.take_along_axis(block_table.astype(jnp.int32), cols // page_size, axis=1)
-    tok = page * page_size + cols % page_size
     # unselected entries (and the chunk's padding) read token 0 of the null page
-    tok = jnp.where(jnp.arange(k_sel)[None, :] < n_sel[:, None], tok, 0)
-    tok = jnp.pad(tok, ((0, 0), (0, padded - k_sel)))
+    live = jnp.arange(k_sel)[None, :] < n_sel[:, None]
+    pad = lambda a: jnp.pad(jnp.where(live, a, 0), ((0, 0), (0, padded - k_sel)))  # noqa: E731
+    page = pad(jnp.take_along_axis(block_table.astype(jnp.int32), cols // page_size, axis=1))
+    off = pad(cols % page_size)
     rows = pl.BlockSpec((1, hkv, g, d), lambda b_, *_: (b_, 0, 0, 0))
-    hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # the selected tokens' pool rows, their count
+        num_scalar_prefetch=3,  # the selected tokens' pages and offsets, their count
         grid=(b,),
-        in_specs=[rows, hbm, hbm],
+        in_specs=[rows, pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=rows,
         scratch_shapes=[
-            pltpu.VMEM((2, chunk, hkv, d), k_pool.dtype),
-            pltpu.VMEM((2, chunk, hkv, d), v_pool.dtype),
+            pltpu.VMEM((2, chunk, 2 * hkv, d), kv_pool.dtype),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.VMEM((hkv, g, 1), jnp.float32),
             pltpu.VMEM((hkv, g, 1), jnp.float32),
@@ -1344,10 +1361,10 @@ def paged_sparse_decode_attention(
     )
     out = pl.pallas_call(
         functools.partial(_sparse_decode_kernel, chunk=chunk, num_kv_heads=hkv,
-                          page_size=page_size, scale=1.0 / (d ** 0.5)),
+                          scale=1.0 / (d ** 0.5)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret_mode(interpret),
-    )(tok, n_sel, q.reshape(b, hkv, g, d), k_pool, v_pool)
+    )(page, off, n_sel, q.reshape(b, hkv, g, d), kv_pool)
     return out.reshape(b, 1, h, d)

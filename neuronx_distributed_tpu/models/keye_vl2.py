@@ -38,9 +38,9 @@ index keys is orthogonal and is left out with the fp8: the index key is
 cached in the model's dtype.
 
 The cache is an :class:`~neuronx_distributed_tpu.modules.attention.IndexedKVCache`:
-K, V and the index key per token. Prefill runs causal attention under the
-learned mask (one byte a pair, never wider); decode scores the cached index
-keys, selects and attends the selected columns
+K and V (one joined leaf) and the index key per token. Prefill runs causal
+attention under the learned mask (one byte a pair, never wider); decode
+scores the cached index keys, selects and attends the selected columns
 (``modules/attention.indexed_decode_attention``). Training runs the prefill
 mathematics through the float32 einsum; the masked flash kernel has no
 backward (serving is what this model is here for).
@@ -215,7 +215,7 @@ class KeyeSparseAttention(nn.Module):
             with jax.named_scope(DSA_INDEX_SCOPE):
                 cache.decode_write(k, v, k_idx, padding_mask)
             out = indexed_decode_attention(
-                q, q_idx, w_idx, cache.k.value, cache.v.value, cache.k_idx.value,
+                q, q_idx, w_idx, cache.kv.value, cache.k_idx.value,
                 pos, cfg.index_topk, kv_valid=cache.valid.value,
             )
         else:

@@ -326,30 +326,41 @@ class LatentKVCache(KVCache):
 
 class IndexedKVCache(KVCache):
     """The cache of GQA attention with a learned sparse-attention INDEXER
-    beside it: per token the K and V of every kv head, as :class:`KVCache`,
-    plus ONE index key ``k_idx`` of ``d_index`` values (after its LayerNorm
-    and rotary) shared by all index heads: ``2 * Hkv * D + d_index`` values
-    a token a layer (2048 + 128 = 2176 bytes at Keye-VL-2.0's widths in
-    bf16). A decode step scores every cached index key against the step's
-    index queries and attends only the ``topk`` best columns
+    beside it: per token the K and V of every kv head plus ONE index key
+    ``k_idx`` of ``d_index`` values (after its LayerNorm and rotary) shared
+    by all index heads: ``2 * Hkv * D + d_index`` values a token a layer
+    (2048 + 128 = 2176 bytes at Keye-VL-2.0's widths in bf16). A decode step
+    scores every cached index key against the step's index queries and
+    attends only the ``topk`` best columns
     (:func:`indexed_decode_attention`).
 
-    Leaves ``k``, ``v`` (B, L, Hkv, D) and ``k_idx`` (B, L, 1, d_index): a
-    third per-token leaf of the contract above (:data:`PAGED_LEAVES`), so
-    every walker handles it as it handles ``k_pe``. The writes take ``(k, v,
-    k_idx)``."""
+    Leaves ``kv`` (B, L, 2 Hkv, D), a token's K heads then its V heads, and
+    ``k_idx`` (B, L, 1, d_index): per-token leaves of the contract above
+    (:data:`PAGED_LEAVES`), so every walker handles them as it handles
+    ``k``/``v``. K and V are ONE leaf because the sparse decode kernel
+    fetches a selected token at a time and is bound by the copies it names:
+    one copy of ``(2 Hkv, D)`` a token (``(8, 128)`` in bf16 at Keye's
+    widths: a whole tile, the layout XLA itself picks) where two leaves cost
+    two. The writes take ``(k, v, k_idx)`` and join K and V on the head
+    axis; :func:`split_kv` takes the leaf apart."""
 
     def __init__(self, module, b, max_seq_len, hkv, d, d_index, dtype):
         super().__init__(
             module, b, max_seq_len, hkv, d, dtype,
-            leaves={"k": (hkv, d), "v": (hkv, d), "k_idx": (1, d_index)},
+            leaves={"kv": (2 * hkv, d), "k_idx": (1, d_index)},
         )
 
     def prefill_write(self, k, v, k_idx, padding_mask=None):
-        self._prefill_write((k, v, k_idx), padding_mask)
+        self._prefill_write((jnp.concatenate([k, v], axis=2), k_idx), padding_mask)
 
     def decode_write(self, k, v, k_idx, padding_mask=None):
-        self._decode_write((k, v, k_idx), padding_mask)
+        self._decode_write((jnp.concatenate([k, v], axis=2), k_idx), padding_mask)
+
+
+def split_kv(kv):
+    """``(k, v)`` of an :class:`IndexedKVCache`'s joined leaf (..., 2 Hkv, D)."""
+    hkv = kv.shape[-2] // 2
+    return kv[..., :hkv, :], kv[..., hkv:, :]
 
 
 # --- cache-collection slot helpers (serving) ----------------------------------
@@ -360,14 +371,14 @@ class IndexedKVCache(KVCache):
 # KVCache declares: k/v (..., B, L, Hkv, D), kv_valid (..., B, L), index
 # scalar cursor (nn.scan stacks a leading layer axis on each); a latent
 # cache's per-token leaves are k/k_pe (..., B, L, 1, d), an indexed cache's
-# k/v and k_idx (..., B, L, 1, d_index).
+# kv (..., B, L, 2 Hkv, D) and k_idx (..., B, L, 1, d_index).
 
 # THE names of the per-token storage leaves, (..., B, L, heads, width): what a
 # page pool pages, a prefix block copies and a fingerprint hashes. Every
 # walker of a cache tree classifies by this tuple, and a layer's leaves are
 # handed around in ITS order: (k, v), a latent cache's (k, k_pe), an indexed
-# cache's (k, v, k_idx).
-PAGED_LEAVES = ("k", "v", "k_pe", "k_idx")
+# cache's (kv, k_idx).
+PAGED_LEAVES = ("k", "v", "k_pe", "kv", "k_idx")
 
 
 def cache_leaf_name(path) -> str:
@@ -947,10 +958,10 @@ def _fused_paged_decode(frame, q, caches, q_pos, kv_valid, latent_scale=None,
 
 def _fused_sparse_decode(frame, q, q_idx, w_idx, caches, q_pos, kv_valid, topk):
     """An indexed cache's step in the active frame: ``caches`` the layer's
-    window leaves ``(k, v, k_idx)``. The windows go into the carried pools
-    (the K and V pools through a KERNEL, :func:`~neuronx_distributed_tpu.
-    kernels.flash_decode.paged_scatter_window_pages_dma`: every user of those
-    pools inside the decode scan is then a kernel of one layout; the 64-wide
+    window leaves ``(kv, k_idx)``. The windows go into the carried pools (the
+    joined K/V pool through a KERNEL, :func:`~neuronx_distributed_tpu.
+    kernels.flash_decode.paged_scatter_window_pages_dma`: every user of that
+    pool inside the decode scan is then a kernel of one layout; the 64-wide
     index keys as a latent cache's rotated key goes), then the three sparse
     kernels run off the pools, each called in ITS scope and named after it."""
     from neuronx_distributed_tpu.kernels.flash_decode import (
@@ -963,13 +974,12 @@ def _fused_sparse_decode(frame, q, q_idx, w_idx, caches, q_pos, kv_valid, topk):
     pools = frame["pools"]
     layer = _next_fused_layer(frame)
     ps, bt, page0 = frame["page_size"], frame["tables"], frame["page0"]
-    k_pool, v_pool, idx_pool = pools[layer]
+    kv_pool, idx_pool = pools[layer]
     with jax.named_scope(KV_VIEW_SCOPE):
-        idx_pool = paged_scatter_window_pages(idx_pool, caches[2], bt, page0)
+        idx_pool = paged_scatter_window_pages(idx_pool, caches[1], bt, page0)
     with jax.named_scope(DSA_WRITE_SCOPE):
-        k_pool = paged_scatter_window_pages_dma(k_pool, caches[0], bt, page0)
-        v_pool = paged_scatter_window_pages_dma(v_pool, caches[1], bt, page0)
-    pools[layer] = (k_pool, v_pool, idx_pool)  # trace-time: the step's carry-out
+        kv_pool = paged_scatter_window_pages_dma(kv_pool, caches[0], bt, page0)
+    pools[layer] = (kv_pool, idx_pool)  # trace-time: the step's carry-out
     with jax.named_scope(DSA_SCORE_SCOPE):
         scores = paged_index_scores(
             q_idx, w_idx, idx_pool, bt, q_pos, kv_valid, page_size=ps)
@@ -979,7 +989,7 @@ def _fused_sparse_decode(frame, q, q_idx, w_idx, caches, q_pos, kv_valid, topk):
         n_sel = jnp.sum(vals > -jnp.inf, axis=1).astype(jnp.int32)
     with jax.named_scope(DSA_ATTEND_SCOPE):
         return paged_sparse_decode_attention(
-            q, k_pool, v_pool, bt, cols, n_sel, page_size=ps)
+            q, kv_pool, bt, cols, n_sel, page_size=ps)
 
 
 def cache_fingerprint(cache):
@@ -1248,12 +1258,12 @@ def sparse_prefill_attention(q, k, v, q_idx, w_idx, k_idx, topk: int,
             lambda qc, kc: _masked_gqa_attention(qc, k, v, kc), (q, keep), s)
 
 
-def indexed_decode_attention(q, q_idx, w_idx, k_cache, v_cache, idx_cache,
-                             q_pos, topk: int, kv_valid=None):
+def indexed_decode_attention(q, q_idx, w_idx, kv_cache, idx_cache, q_pos,
+                             topk: int, kv_valid=None):
     """Sparse GQA attention of decode rows against an :class:`IndexedKVCache`:
     q (B, S, H, D) rows at slot positions ``q_pos`` (S,), their index queries
     ``q_idx`` (B, S, H_i, d_i) and weights ``w_idx`` (B, S, H_i); the cache
-    leaves ``k``/``v`` (B, L, Hkv, D) and ``k_idx`` (B, L, 1, d_i). Each row
+    leaves ``kv`` (B, L, 2 Hkv, D) and ``k_idx`` (B, L, 1, d_i). Each row
     scores every valid column at or before its position, keeps the ``topk``
     best and attends those alone.
 
@@ -1261,13 +1271,15 @@ def indexed_decode_attention(q, q_idx, w_idx, k_cache, v_cache, idx_cache,
     ``kernels/flash_decode.py`` run off the page pool (index scores over the
     blocks a slot maps, ``top_k``, K and V of the selected tokens only);
     elsewhere (a row cache: a suffix prefill, ``generate()``) the same
-    mathematics as float32 einsums under :func:`sparse_keep_mask`."""
+    mathematics as float32 einsums under :func:`sparse_keep_mask`, on the
+    leaf split into K and V."""
     if _FUSED_PAGED_STACK:
         return _fused_sparse_decode(
-            _FUSED_PAGED_STACK[-1], q, q_idx, w_idx,
-            (k_cache, v_cache, idx_cache), q_pos, kv_valid, topk,
+            _FUSED_PAGED_STACK[-1], q, q_idx, w_idx, (kv_cache, idx_cache),
+            q_pos, kv_valid, topk,
         )
     b, s = q.shape[0], q.shape[1]
+    k_cache, v_cache = split_kv(kv_cache)
     valid = (jnp.ones(k_cache.shape[:2], jnp.bool_) if kv_valid is None
              else kv_valid.astype(jnp.bool_))
     q_pos = q_pos[None] if q_pos.ndim == 0 else q_pos

@@ -192,7 +192,8 @@ def test_sparse_decode_kernels_compile_at_keye_geometry(topo):
     slots of 32,768 columns, page 16, 32 Q / 4 KV heads of 128, 16 index
     heads of 64, 2048 kept): the index-score kernel over the 64-wide leaf
     (its page copies name the tile's 128 lanes) and the sparse kernel that
-    fetches a token's (4, 128) K and V rows; and the byte-masked flash
+    fetches a token's joined (8, 128) K/V leaf with one copy and waits once a
+    chunk over the whole buffer; and the byte-masked flash
     forward of a 24,576-token prefill with the kernel that builds its mask
     (64 query rows' scores as int32 keys in 6 MiB of VMEM). None may copy a pool leaf whole but
     the index kernel's 64-wide one (the layout conversion PR 26 found)."""
@@ -214,11 +215,10 @@ def test_sparse_decode_kernels_compile_at_keye_geometry(topo):
         s((b, 1, 16, 64)), s((b, 1, 16)), s((pages, page, 1, 64)), table, s((1,), jnp.int32), valid)
     assert KERNEL in text
     text = _compiled_text(
-        lambda q, k, v, bt, cols, n: paged_sparse_decode_attention(q, k, v, bt, cols, n, page_size=page),
-        s((b, 1, H, D)), s((pages, page, 4, D)), s((pages, page, 4, D)), table,
-        s((b, keep), jnp.int32), s((b,), jnp.int32))
+        lambda q, kv, bt, cols, n: paged_sparse_decode_attention(q, kv, bt, cols, n, page_size=page),
+        s((b, 1, H, D)), s((pages, page, 2 * 4, D)), table, s((b, keep), jnp.int32), s((b,), jnp.int32))
     assert KERNEL in text
-    assert not re.search(r"bf16\[%d,%d,4,%d\]\S* copy\(" % (pages, page, D), text), "a K/V pool leaf is copied whole"
+    assert not re.search(r"bf16\[%d,%d,8,%d\]\S* copy\(" % (pages, page, D), text), "the K/V pool leaf is copied whole"
     seq = 24576
     text = _compiled_text(
         lambda q, k, v, m: masked_flash_attention(q, k, v, m),
@@ -637,7 +637,8 @@ def _keye_vl2(layers, seq):
 
 # what the described-v5e compile of the configured depth showed for the decode
 # chunk's temporaries (GiB): the 64-wide index-key leaf's layout conversions
-# and the weights' hoisted layout copies
+# and the weights' hoisted layout copies (PR 30: 0.47 with K and V as two
+# leaves; PR 31: 0.468 with the joined leaf, of which no copy at all)
 KEYE_DECODE_TEMP_GIB = 0.47
 
 
@@ -645,7 +646,8 @@ KEYE_DECODE_TEMP_GIB = 0.47
 def test_keye_vl2_engine_programs_compile_and_fit(topo):
     """The benchmark configuration's programs (``perfbench/configs/
     keye-vl2-30b-a3b-serve.json``: its depth, 8 slots of 32,768, page 16):
-    the fused decode chunk with the three-leaf pool carried and the longest
+    the fused decode chunk with the two-leaf pool (K and V joined, the index
+    keys) carried and the longest
     prompt's prefill under the byte mask, both with Pallas kernels and inside
     the chip's memory; the decode program holds no row-sized K/V array and
     its measured temporaries + 10%."""
@@ -663,10 +665,12 @@ def test_keye_vl2_engine_programs_compile_and_fit(topo):
         "attention": "flash", "decode_attention": "paged_sparse_fused",
         "paged_attention": "fused",
     }
-    assert sorted(set(pool_shards)) == [(16385, 16, 1, 64), (16385, 16, 4, 128)]
+    assert sorted(set(pool_shards)) == [(16385, 16, 1, 64), (16385, 16, 8, 128)]
     decode = lower_decode().compile()
     assert KERNEL in decode.as_text()
     assert _fits(decode, 15 * 1024**3)
+    # no pool-sized copy of the joined leaf inside the decode scan: its
+    # users there are two kernels of one layout
     _assert_pool_carried_and_no_view(decode, pool_shards, KEYE_DECODE_TEMP_GIB)
     prefill = lower_prefill().compile()
     assert KERNEL in prefill.as_text()

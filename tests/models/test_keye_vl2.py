@@ -23,6 +23,7 @@ from neuronx_distributed_tpu.modules.attention import (
     PAGED_LEAVES,
     index_scores,
     sparse_keep_mask,
+    split_kv,
 )
 
 from perfbench.references.keye_vl2 import Reference
@@ -216,15 +217,20 @@ def test_left_padded_prefill_equals_the_unpadded_one(tiny):
     np.testing.assert_allclose(np.asarray(got[:, 8:]), np.asarray(want), atol=ATOL)
 
 
-def test_cache_holds_k_v_and_one_index_key_a_token(tiny):
+def test_cache_holds_k_and_v_joined_and_one_index_key_a_token(tiny):
+    """``kv``: a token's K heads, then its V heads, in ONE leaf (what the
+    sparse decode kernel fetches with one copy); ``k_idx``: the index key."""
     cfg, model, params, ids, _ = tiny
     _, state = model.clone(mode="prefill").apply(params, ids[:, :16], mutable=["cache"])
     for i in range(cfg.num_layers):
         leaves = state["cache"]["model"][f"layers_{i}"]["attn"]
-        assert set(leaves) == {"k", "v", "k_idx", "index", "kv_valid"}
-        assert leaves["k"].shape == leaves["v"].shape == (2, cfg.max_seq_len, 2, 16)
+        assert set(leaves) == {"kv", "k_idx", "index", "kv_valid"}
+        assert leaves["kv"].shape == (2, cfg.max_seq_len, 2 * 2, 16)
         assert leaves["k_idx"].shape == (2, cfg.max_seq_len, 1, 8)
-    assert PAGED_LEAVES[:3] == ("k", "v", "k_pe") and "k_idx" in PAGED_LEAVES
+        k, v = split_kv(leaves["kv"])
+        assert k.shape == v.shape == (2, cfg.max_seq_len, 2, 16)
+        assert float(jnp.abs(k[:, :16]).min()) > 0 and float(jnp.abs(v[:, :16]).min()) > 0 and not k[:, 16:].any()
+    assert PAGED_LEAVES[:3] == ("k", "v", "k_pe") and {"kv", "k_idx"} <= set(PAGED_LEAVES)
 
 
 def test_masked_flash_prefill_is_the_einsum_prefill(tiny):

@@ -1,10 +1,10 @@
-"""The indexed cache kind (K, V and one index key a token) on the serving
+"""The indexed cache kind (K and V joined in one leaf and one index key a token) on the serving
 path, tiny, on the CPU: the engine's row cache, the ``gather`` transport and
 the fused paged path (the three sparse kernels interpreted) against the plain
 reference's FULL forward in logits, with contexts past ``topk`` so that
 selection is at work; what the cache leaves hold; prefix sharing, per-page
 fingerprints, fault injection's walkers, the host tier, preemption and resume
-on the three-leaf pool; tensor parallelism refused."""
+on the two-leaf pool; tensor parallelism refused."""
 
 import dataclasses
 
@@ -108,13 +108,16 @@ def test_the_three_transports_emit_one_stream(streams):
     assert streams["row"][1] == streams["gather"][1] == streams["fused"][1]
 
 
-def test_cache_leaves_hold_k_v_and_one_index_key_a_token(streams):
+def test_cache_leaves_hold_k_and_v_joined_and_one_index_key_a_token(streams):
+    """Two per-token leaves a layer: ``kv`` (a token's K heads, then its V
+    heads: what the sparse decode kernel fetches with ONE copy) and
+    ``k_idx``; no separate ``k`` or ``v``."""
     eng, _ = streams["fused"]
     names = {}
     for path, leaf in jax.tree_util.tree_flatten_with_path(eng.cache.cache["pool"])[0]:
         if path[-1].key in PAGED_LEAVES:
             names.setdefault(path[-1].key, leaf.shape[-2:])
-    assert names == {"k": (2, 16), "v": (2, 16), "k_idx": (1, 8)}
+    assert names == {"kv": (2 * 2, 16), "k_idx": (1, 8)}
     # tiny widths in float32: 2 x 2 heads of 16 + one key of 8, 4 bytes each
     for name in PATHS:
         assert cache_bytes_per_token_layer(streams[name][0].cache.cache) == (2 * 2 * 16 + 8) * 4
@@ -128,10 +131,13 @@ def test_cache_leaves_hold_k_v_and_one_index_key_a_token(streams):
     row = jax.eval_shape(
         lambda p, i: model.clone(mode="prefill").apply(p, i, mutable=["cache"])[1]["cache"], shapes, ids)
     assert cache_bytes_per_token_layer(row) == 2176
+    leaves = {path[-1].key: leaf.shape[-2:] for path, leaf in jax.tree_util.tree_flatten_with_path(row)[0]
+              if path[-1].key in PAGED_LEAVES}
+    assert leaves == {"kv": (8, 128), "k_idx": (1, 64)}      # the joined leaf: one whole bf16 tile a token
 
 
-def test_fused_chunk_carries_all_three_leaves(setup):
-    """PR 25's contract on the indexed pool: each layer's ``(k, v, k_idx)``
+def test_fused_chunk_carries_both_leaves(setup):
+    """PR 25's contract on the indexed pool: each layer's ``(kv, k_idx)``
     rides the scan's carry, paired with its layer in execution order, and the
     chunk's cache holds the write WINDOW of each, not a row."""
     cfg, model, params, _, _ = setup
@@ -147,15 +153,15 @@ def test_fused_chunk_carries_all_three_leaves(setup):
     paged = jax.eval_shape(pool_of, row)
     pairs = ordered_kv_pool_pairs(paged["pool"])
     assert [layer[-2] for layer in pairs] == ["layers_0", "layers_1"]
-    assert all([leaf.shape[-1] for leaf in trio] == [16, 16, 8] for trio in pairs.values())
+    assert all([leaf.shape[-2:] for leaf in pair] == [(4, 16), (1, 8)] for pair in pairs.values())
     state = jax.eval_shape(ServingEngine(model, params, num_slots=2, kv_page_size=PS)._fresh_slot_state)
     jaxpr = jax.make_jaxpr(chunked_decode_step(decode, 4, cfg.max_seq_len, page_size=PS,
                                                paged_attention="fused"))(params, paged, state)
     scans = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
     assert len(scans) == 1
     carried = [v.aval.shape for v in scans[0].invars[scans[0].params["num_consts"]:]]
-    for trio in pairs.values():
-        for leaf in trio:
+    for pair in pairs.values():
+        for leaf in pair:
             assert leaf.shape in carried
     # no per-token leaf as long as a row anywhere in the chunk
     rows = [v.aval.shape for e in jaxpr.jaxpr.eqns for v in e.outvars
@@ -164,7 +170,7 @@ def test_fused_chunk_carries_all_three_leaves(setup):
 
 
 def test_prefix_sharing_on_the_indexed_pool_is_zero_copy_and_stream_identical(setup):
-    """Prefix extract/seed and per-page fingerprints walk three leaves: the
+    """Prefix extract/seed and per-page fingerprints walk both leaves: the
     shared stream is the unshared one, no page is copied."""
     cfg, model, params, _, ref = setup
     rng = np.random.default_rng(3)
@@ -183,7 +189,7 @@ def test_prefix_sharing_on_the_indexed_pool_is_zero_copy_and_stream_identical(se
     assert _largest_gap(ref, prompts, plain) <= TOLERANCE
 
 
-def test_prefix_sharing_on_the_row_cache_extracts_and_seeds_three_leaves(setup):
+def test_prefix_sharing_on_the_row_cache_extracts_and_seeds_both_leaves(setup):
     cfg, model, params, _, _ = setup
     rng = np.random.default_rng(4)
     system = rng.integers(1, cfg.vocab_size, size=33).astype(np.int32)
@@ -212,24 +218,25 @@ def test_preemption_and_resume_on_the_indexed_pool_give_the_undisturbed_stream(s
 
 def test_page_fingerprints_and_the_fault_walkers_see_the_index_key(setup):
     """A flipped bit in a ``k_idx`` page is a corruption the per-page
-    fingerprint catches, as one in ``k`` is."""
+    fingerprint catches, as one in ``kv`` is."""
     from neuronx_distributed_tpu.utils.fingerprint import cache_fingerprint
 
     _, model, params, prompts, _ = setup
     eng, _ = _serve(model, params, prompts[:2], new_tokens=4, kv_page_size=PS)
     pool = eng.cache.cache["pool"]
     base = np.asarray(cache_fingerprint(pool))
-    for name in ("k", "k_idx"):
+    for name in ("kv", "k_idx"):
         def flip(path, leaf, name=name):
             return leaf.at[1, 0, 0, 0].add(1.0) if path[-1].key == name and "layers_0" in str(path) else leaf
         changed = np.asarray(cache_fingerprint(jax.tree_util.tree_map_with_path(flip, pool)))
         assert not np.array_equal(changed, base), name
 
 
-def test_the_host_tier_round_trips_three_leaf_pages(setup):
-    """The tier's two walkers on the three-leaf pool: pages spilled to the
-    host carry ``k``, ``v`` and ``k_idx`` blocks of every layer, and come
-    back, at fresh page ids, bit for bit."""
+def test_the_host_tier_round_trips_two_leaf_pages(setup):
+    """The tier's two walkers on the two-leaf pool: pages spilled to the
+    host carry ``kv`` and ``k_idx`` blocks of every layer (2 x 2 heads of 16
+    + one key of 8 in float32 a token), and come back, at fresh page ids, bit
+    for bit."""
     _, model, params, prompts, _ = setup
     eng, _ = _serve(model, params, prompts[:2], new_tokens=4, kv_page_size=PS)
     mgr = eng.cache
@@ -238,8 +245,9 @@ def test_the_host_tier_round_trips_three_leaf_pages(setup):
               for p, leaf in jax.tree_util.tree_flatten_with_path(mgr.cache["pool"])[0]
               if p[-1].key in PAGED_LEAVES}
     items, nbytes = mgr.spill_pages(ids)
-    assert sorted({keys[-1] for keys, _ in items}) == ["k", "k_idx", "v"]
-    assert len(items) == 3 * 2 and nbytes == sum(a.nbytes for a in before.values())
+    assert sorted({keys[-1] for keys, _ in items}) == ["k_idx", "kv"]
+    assert len(items) == 2 * 2 and nbytes == sum(a.nbytes for a in before.values())
+    assert nbytes == len(ids) * PS * 2 * (2 * 2 * 16 + 8) * 4      # pages x tokens x layers x bytes a token a layer
     fresh = mgr.prefetch_pages(items, len(ids))
     assert not set(fresh) & set(ids)
     for p, leaf in jax.tree_util.tree_flatten_with_path(mgr.cache["pool"])[0]:

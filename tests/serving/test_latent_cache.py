@@ -279,39 +279,50 @@ PARENT_PROGRAMS = {
 }
 
 
+def _model_program_texts(name, model):
+    """``{name.prefill, name.decode.gather, name.decode.fused}``: the jaxpr
+    text of a tiny model's prefill and paged decode chunk, both transports."""
+    out = {}
+    length = model.config.max_seq_len
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    pre, dec = serving_clones(model)
+    ids, mask = jnp.zeros((1, 32), jnp.int32), jnp.ones((1, 32), bool)
+
+    def prefill(p, i, m):
+        return pre.apply(p, i, padding_mask=m, mutable=["cache"])
+
+    out[f"{name}.prefill"] = str(jax.make_jaxpr(prefill)(params, ids, mask))
+    row = jax.eval_shape(lambda p, i, m: prefill(p, i, m)[1]["cache"], params, ids, mask)
+
+    def pool_of(row):
+        mgr = PagedCacheManager(4, length, 16)
+        mgr.allocate_from(row)
+        return mgr.cache
+
+    paged = jax.eval_shape(pool_of, row)
+    state = dict(
+        tok=jnp.zeros((4,), jnp.int32), keys=jnp.zeros((4, 2), jnp.uint32),
+        active=jnp.ones((4,), bool), remaining=jnp.full((4,), 5, jnp.int32),
+        temp=jnp.zeros((4,), jnp.float32), topk=jnp.zeros((4,), jnp.int32),
+        topp=jnp.ones((4,), jnp.float32), eos=jnp.full((4,), -1, jnp.int32))
+    for mode in ("gather", "fused"):
+        fn = chunked_decode_step(dec, 4, length, page_size=16, paged_attention=mode)
+        out[f"{name}.decode.{mode}"] = str(jax.make_jaxpr(fn)(params, paged, state))
+    return out
+
+
+def _digest(text):
+    return hashlib.sha256(re.sub(r"0x[0-9a-f]+", "0x", text).encode()).hexdigest()
+
+
 def _program_texts():
     from neuronx_distributed_tpu.models.codegen import CodeGenForCausalLM, tiny_codegen
     from neuronx_distributed_tpu.models.mixtral import MixtralForCausalLM, tiny_mixtral
 
-    out = {}
-    for name, model in (("mixtral", MixtralForCausalLM(tiny_mixtral(), attention_impl="xla")),
-                        ("codegen", CodeGenForCausalLM(tiny_codegen()))):
-        length = model.config.max_seq_len
-        params = jax.eval_shape(model.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
-        pre, dec = serving_clones(model)
-        ids, mask = jnp.zeros((1, 32), jnp.int32), jnp.ones((1, 32), bool)
-
-        def prefill(p, i, m, pre=pre):
-            return pre.apply(p, i, padding_mask=m, mutable=["cache"])
-
-        out[f"{name}.prefill"] = str(jax.make_jaxpr(prefill)(params, ids, mask))
-        row = jax.eval_shape(lambda p, i, m: prefill(p, i, m)[1]["cache"], params, ids, mask)
-
-        def pool_of(row, length=length):
-            mgr = PagedCacheManager(4, length, 16)
-            mgr.allocate_from(row)
-            return mgr.cache
-
-        paged = jax.eval_shape(pool_of, row)
-        state = dict(
-            tok=jnp.zeros((4,), jnp.int32), keys=jnp.zeros((4, 2), jnp.uint32),
-            active=jnp.ones((4,), bool), remaining=jnp.full((4,), 5, jnp.int32),
-            temp=jnp.zeros((4,), jnp.float32), topk=jnp.zeros((4,), jnp.int32),
-            topp=jnp.ones((4,), jnp.float32), eos=jnp.full((4,), -1, jnp.int32))
-        for mode in ("gather", "fused"):
-            fn = chunked_decode_step(dec, 4, length, page_size=16, paged_attention=mode)
-            out[f"{name}.decode.{mode}"] = str(jax.make_jaxpr(fn)(params, paged, state))
-    return out
+    return {
+        **_model_program_texts("mixtral", MixtralForCausalLM(tiny_mixtral(), attention_impl="xla")),
+        **_model_program_texts("codegen", CodeGenForCausalLM(tiny_codegen())),
+    }
 
 
 @pytest.mark.parametrize("program", list(PARENT_PROGRAMS))
@@ -321,3 +332,26 @@ def test_mixtral_and_codegen_programs_are_the_parents(program):
     text = test_mixtral_and_codegen_programs_are_the_parents.texts[program]
     digest = hashlib.sha256(re.sub(r"0x[0-9a-f]+", "0x", text).encode()).hexdigest()
     assert digest == PARENT_PROGRAMS[program], json.dumps({program: digest})
+
+
+# sha256 of the same three programs of tiny DeepSeek-V2 (this file's model),
+# taken on the PARENT commit (07a904c) of the PR that joined K and V into one
+# leaf of the INDEXED cache (PR 31): a new name in ``PAGED_LEAVES`` and a new
+# sparse decode kernel must leave the programs of a model with no such leaf
+# exactly as they were.
+DEEPSEEK_PARENT_PROGRAMS = {
+    "deepseek.prefill": "fead3bfa519cb976a59ab0e77801e17c93cdee964d75836884e38e694fa96c18",
+    "deepseek.decode.gather": "7bf70098d6a63175af42c4e54b11e002c312c3eb55fb2edc240eb4291da9e202",
+    "deepseek.decode.fused": "82f20c65afc58c2d59be974eb593f456a16208c7ddb7e440a006d90b2c2d535f",
+}
+
+
+@pytest.fixture(scope="module")
+def deepseek_program_texts():
+    return _model_program_texts("deepseek", DeepseekV2ForCausalLM(tiny_deepseek_v2(), attention_impl="xla"))
+
+
+@pytest.mark.parametrize("program", list(DEEPSEEK_PARENT_PROGRAMS))
+def test_deepseek_programs_are_the_parents(deepseek_program_texts, program):
+    digest = _digest(deepseek_program_texts[program])
+    assert digest == DEEPSEEK_PARENT_PROGRAMS[program], json.dumps({program: digest})
