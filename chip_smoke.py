@@ -50,6 +50,22 @@ and is printed. Weights and requests come from ``--seed``.
          and two controls that must fail: index keys in float8, and 1024 kept.
          ``--only dsa`` runs this phase alone.
 
+  glm    GLM-5's language model at its published widths on the benchmark
+         configuration's cut (``perfbench/configs/glm-5-serve.json``: its
+         depth, 8 of 256 experts held, a slice of the vocabulary): the sparse
+         LATENT decode kernel alone at the serve cell's shapes against float32
+         ``jnp``, then a ``ServingEngine`` of 32,768-column slots: prompts of
+         16,384, 8,192 and 2,048 tokens are prefilled (the learned mask over
+         materialised MLA) and 32 tokens decoded through the paged indexed
+         LATENT cache (index scores, ``top_k``, the absorbed form over the
+         selected rows). Against ``perfbench/references/glm_moe_dsa.py``,
+         which is given the same share: prefill logits and the reference's
+         logit of every decoded token, as ``mla``; the share of layer 0's
+         selected columns that differ; and four controls that must fail:
+         index keys in float8, 1024 kept, the latent in float8, and the
+         selection bias added to the weights. ``--only glm`` runs this phase
+         alone.
+
 ``--chips 4`` runs only the four-chip path and what it is compared with: a
 tp=4 + sequence-parallel train step against the same seeded step on one
 device of the same process, and ``ServingEngine(tp=4)`` against the
@@ -414,6 +430,49 @@ class DsaSize:
     # benchmark configuration's (its ``reference_check.why`` has the readings)
     gap_tol: float = 0.06
     near_tie: float = 0.035
+
+
+@dataclasses.dataclass(frozen=True)
+class GlmSize:
+    """What the GLM-5 phase runs (defaults: the chip run, the published widths
+    on the benchmark configuration's cut)."""
+
+    model: object = None          # the configuration's ``model`` group; None = the benchmark configuration's
+    max_seq_len: int = 32768
+    slots: int = 4
+    prompt_lens: Tuple[int, ...] = (16384, 8192, 2048)
+    tail: int = 256
+    new_tokens: int = 32
+    # the kernel alone: tokens each of 8 slots holds (the agentdocs_closed
+    # tape's quantiles + 320 decoded); each keeps min(held, topk)
+    kernel_contexts: Tuple[int, ...] = (4500, 5600, 6700, 7800, 8900, 10400, 12600, 16700)
+    kernel_calls: int = 20
+    kernel_tol: float = 2e-2      # |kernel - float32 jnp| on bf16 inputs: rounding of the output type
+    # Limits of the comparison with the reference, each between the system's
+    # reading and a control's (PERF.md section 6, PR 32, has the readings):
+    # ``typical_tol`` the median position's largest |difference| of prefill
+    # logits over the vocabulary, ``logit_tol`` the worst position's,
+    # ``selected_tol`` the share of layer 0's selected columns (rows past
+    # ``topk``) that are not the reference's
+    logit_tol: float = 2.0
+    typical_tol: float = 0.12
+    selected_tol: float = 0.005
+    # ONE block alone, which the layers after it cannot blur: the median
+    # position's |system - reference| / |reference| (L2 over the hidden
+    # vector) of what layer 0's attention adds to the stream, and of the held
+    # experts' routed sum in the first sparse layer on the SYSTEM's own input
+    # (positions where a held expert was chosen). A float8 latent and a bias
+    # in the weights move the final logits by LESS than bf16 itself does;
+    # here each stands alone
+    attn_tol: float = 0.05
+    routed_tol: float = 0.015
+    # and what layer 0's CACHE holds of the prompt (the latent and the
+    # rotated key of every token) against the reference's, the same ratio
+    latent_tol: float = 0.01
+    # the decoded tokens' gap and the router near-tie that excuses one: the
+    # benchmark configuration's (its ``reference_check.why`` has the readings)
+    gap_tol: float = 0.1
+    near_tie: float = 0.006
 
 
 def _prompts(lens: Sequence[int], vocab: int, seed: int):
@@ -1182,18 +1241,293 @@ def dsa_phase(size: DsaSize, seed: int) -> Dict[str, bool]:
     }
 
 
+def glm_kernel(size: GlmSize, published: dict, seed: int, dtype) -> Dict[str, bool]:
+    """The sparse latent decode kernel alone at the cell's shapes against
+    float32 ``jnp``, with its time against its bytes."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from neuronx_distributed_tpu.kernels.flash_decode import (
+        SPARSE_CHUNK_TOKENS,
+        paged_gather_leaf,
+        paged_sparse_latent_decode_attention,
+    )
+    from neuronx_distributed_tpu.modules.attention import (
+        _masked_latent_attention,
+        latent_leaf_shape,
+        split_latent,
+    )
+
+    h, d_c, d_r = (int(published[k]) for k in ("num_attention_heads", "kv_lora_rank", "qk_rope_head_dim"))
+    keep, page, length = int(published["index_topk"]), 16, size.max_seq_len
+    scale = (int(published["qk_nope_head_dim"]) + d_r) ** -0.5
+    ctx = list(size.kernel_contexts)
+    b, n_log = len(ctx), length // page
+    rows, lanes = latent_leaf_shape(d_c, d_r)
+    rng = np.random.default_rng(seed)
+    table = jnp.asarray(1 + rng.permutation(b * n_log).reshape(b, n_log), jnp.int32)
+    k_sel = min(keep, max(ctx))
+    cols = np.zeros((b, k_sel), np.int32)
+    for i, n in enumerate(ctx):
+        picked = rng.permutation(n)[:min(n, k_sel)]
+        cols[i, :len(picked)] = picked
+    n_sel = jnp.asarray([min(n, k_sel) for n in ctx], jnp.int32)
+    key = jax.random.split(jax.random.PRNGKey(seed), 3)
+    pool = jax.random.normal(key[0], (b * n_log + 1, page, rows, lanes), dtype)
+    q_c = jax.random.normal(key[1], (b, 1, h, d_c), dtype)
+    q_r = jax.random.normal(key[2], (b, 1, h, d_r), dtype)
+    cols = jnp.asarray(cols)
+    # every array is an ARGUMENT: closed over, the pool would be a constant of the program
+    attend = lambda qc, qr, kv: paged_sparse_latent_decode_attention(   # noqa: E731
+        qc, qr, kv, table, cols, n_sel, scale=scale, page_size=page)
+    out = jax.jit(attend)(q_c, q_r, pool)
+    keep_mask = np.zeros((b, 1, length), bool)
+    for i in range(b):
+        keep_mask[i, 0, np.asarray(cols[i])[: int(n_sel[i])]] = True
+    f32 = lambda a: a.astype(jnp.float32)    # noqa: E731
+    with jax.default_matmul_precision("highest"):
+        ref = jax.jit(lambda qc, qr, kv: _masked_latent_attention(
+            f32(qc), f32(qr), *split_latent(f32(paged_gather_leaf(kv, table, page)), d_c, d_r),
+            jnp.asarray(keep_mask), scale))(q_c, q_r, pool)
+    err = float(np.abs(np.asarray(f32(out)) - np.asarray(ref)).max())
+    ms = _median_call_ms(attend, (q_c, q_r, pool), size.kernel_calls)
+    used = int(n_sel.sum()) * (d_c + d_r) * 2
+    chunk = min(SPARSE_CHUNK_TOKENS, k_sel)
+    copies = sum(-(-int(n) // chunk) * chunk for n in np.asarray(n_sel))
+    log(f"glm kernel: {b} slots holding {ctx}, {int(n_sel.sum())} tokens selected, {h} heads, page {page}, "
+        f"{jnp.dtype(dtype).name}: sparse latent attention {ms:.3f} ms a call for {used / 1e6:.1f} MB held by the "
+        f"selected rows ({used / ms / 1e6:.1f} GB/s; {copies} copies of {rows * lanes * 2} B, "
+        f"{1e6 * ms / copies:.1f} ns each), max |kernel - float32 jnp| {err:.5f}")
+    return {"glm_sparse_latent_kernel_matches_jnp": err <= size.kernel_tol}
+
+
+def glm_phase(size: GlmSize, seed: int) -> Dict[str, bool]:
+    """GLM-5's language model through the default engine (module docstring)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from flax.core import meta
+
+    from neuronx_distributed_tpu.models.glm_moe_dsa import GlmMoeDsaModel
+    from neuronx_distributed_tpu.modules.attention import (
+        apply_rope,
+        latent_leaf_shape,
+        rope_frequencies,
+        sparse_keep_mask,
+        split_latent,
+    )
+    from neuronx_distributed_tpu.parallel import mesh as mesh_lib
+    from neuronx_distributed_tpu.serving import ServingEngine
+    from perfbench.families import glm_moe_dsa as family
+    from perfbench.references import common
+    from perfbench.references.glm_moe_dsa import Reference
+
+    mesh_lib.destroy_model_parallel()
+    published = size.model
+    if published is None:
+        path = os.path.join(os.path.dirname(os.path.abspath(__file__)), "perfbench", "configs", "glm-5-serve.json")
+        with open(path) as f:
+            published = json.load(f)["model"]
+    model = family.build(published, runner="serve", max_seq_len=size.max_seq_len)
+    if size.model is not None:    # the CPU rehearsal serves in float32
+        model = model.clone(config=dataclasses.replace(model.config, dtype=jnp.float32))
+    cfg = model.config
+    checks = glm_kernel(size, published, seed, cfg.dtype)
+    params = jax.jit(model.init)(jax.random.PRNGKey(seed), jnp.zeros((1, 8), jnp.int32))
+    jax.block_until_ready(params)
+    engine = ServingEngine(model, params, num_slots=size.slots, kv_page_size=16)
+    prompts = _prompts(size.prompt_lens, int(published["vocab_size"]), seed + 7)
+    reqs, wall = _serve(engine, prompts, size.new_tokens, seed)
+    resolved = dict(engine.programs.resolved)
+    kernels = _ledger_kernels(engine.programs, _hot_programs(engine))
+    per_token = engine.metrics.snapshot()["kv_bytes_per_token_layer"]
+    log(f"glm: {len(reqs)} requests, prompts {list(size.prompt_lens)} + {size.new_tokens} tokens in "
+        f"{wall:.1f}s; resolved {resolved}; {KERNEL} in compiled programs: {kernels}; cache "
+        f"{per_token:g} B a token a layer, pool {engine.cache.nbytes / 2**30:.2f} GiB; held experts "
+        f"{cfg.held_experts} of {cfg.num_experts}, vocabulary {cfg.vocab_size}")
+    engine.cache.check()
+    engine = None
+    gc.collect()
+
+    backbone = GlmMoeDsaModel(cfg, model.attention_impl, mode="prefill")
+
+    @jax.jit
+    def prefill_rows(params, ids, lo):
+        """The system's prefill logits at ``tail`` positions from ``lo``."""
+        hidden = backbone.apply({"params": params["params"]["model"]}, ids, mutable=["cache"])[0][0]
+        rows = jax.lax.dynamic_slice_in_dim(hidden[0], lo, min(size.tail, ids.shape[1]), axis=0)
+        return rows @ meta.unbox(params)["params"]["lm_head"]["kernel"]
+
+    @jax.jit
+    def layer0_keep(params, ids):
+        """The mask the SYSTEM's first layer keeps, from its own projections."""
+        _, state = backbone.apply(
+            {"params": params["params"]["model"]}, ids, mutable=["cache", "intermediates"],
+            capture_intermediates=lambda mdl, _: mdl.name in ("idx_q_proj", "idx_k_norm", "idx_w_proj")
+            and "layers_0" in "/".join(mdl.path))
+        got = state["intermediates"]["layers_0"]["attn"]
+        b, s = ids.shape
+        pos = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
+        freqs = rope_frequencies(cfg.qk_rope_head_dim, cfg.max_seq_len, cfg.rope_theta)
+        d_r = cfg.qk_rope_head_dim
+        first = lambda t: jnp.concatenate([apply_rope(t[..., :d_r], freqs, pos), t[..., d_r:]], -1)   # noqa: E731
+        q_idx = first(got["idx_q_proj"]["__call__"][0].reshape(b, s, cfg.index_n_heads, -1))
+        k_idx = first(got["idx_k_norm"]["__call__"][0][:, :, None, :])[:, :, 0]
+        return sparse_keep_mask(q_idx, got["idx_w_proj"]["__call__"][0], k_idx, pos,
+                                jnp.ones((b, s), bool), cfg.index_topk)
+
+    sparse = cfg.first_k_dense      # the first sparse layer
+
+    @jax.jit
+    def blocks_of(params, ids):
+        """What the SYSTEM's layer-0 attention adds to the stream, and its
+        first sparse layer's normed input and routed sum (held experts)."""
+        def wanted(mdl, _):
+            path = "/".join(mdl.path)
+            return (mdl.name == "attn" and "layers_0/" in path + "/") or (
+                mdl.name in ("post_attn_norm", "experts") and f"layers_{sparse}/" in path + "/")
+
+        _, state = backbone.apply(
+            {"params": params["params"]["model"]}, ids, mutable=["cache", "intermediates"],
+            capture_intermediates=wanted)
+        got = state["intermediates"]
+        layer = got[f"layers_{sparse}"]
+        held = split_latent(state["cache"]["layers_0"]["attn"]["kv"][:, :ids.shape[1]],
+                            cfg.kv_lora_rank, cfg.qk_rope_head_dim)
+        return (got["layers_0"]["attn"]["__call__"][0], layer["post_attn_norm"]["__call__"][0],
+                layer["moe"]["experts"]["__call__"][0].reshape(ids.shape + (-1,)), jnp.concatenate(held, axis=-1))
+
+    def rel(mine, theirs):
+        """Median over positions of |mine - theirs| / |theirs| (L2 over the
+        hidden vector), where ``theirs`` is not zero."""
+        mine, theirs = np.asarray(mine, np.float32)[0], np.asarray(theirs, np.float32)[0]
+        size_ = np.linalg.norm(theirs, axis=-1)
+        live = size_ > 0
+        return float(np.median(np.linalg.norm(mine - theirs, axis=-1)[live] / size_[live])), int(live.sum())
+
+    plain = meta.unbox(params)
+    ref = Reference(published, plain)
+    d_i = cfg.index_head_dim
+    rows_, lanes_ = latent_leaf_shape(cfg.kv_lora_rank, cfg.qk_rope_head_dim)
+    want_bytes = (rows_ * lanes_ + d_i) * jnp.dtype(cfg.dtype).itemsize
+    keep = cfg.index_topk
+    worst_gap, worst_diff, worst_median, ok, caught = 0.0, 0.0, 0.0, True, {}
+    differing, blocks_ok = None, False
+    for prompt, req in zip(prompts, reqs):
+        p, n = len(prompt), len(req.tokens)
+        ids = np.concatenate([prompt, np.asarray(req.tokens, np.int32)])[None]
+        hidden, router, _ = ref._hidden(ids)            # the whole context, once
+        head = lambda rows, r=ref, hid=hidden: np.asarray(r._head(      # noqa: E731
+            r.p["model"]["final_norm"], r.p["lm_head"], hid[:, rows])[0], np.float32)
+        rows = head(np.arange(p - 1, p - 1 + n))
+        toks = np.asarray(req.tokens)
+        gaps = rows.max(1) - rows[np.arange(n), toks]
+        wrong = rows.max(1) - rows[np.arange(n), (toks + 1) % rows.shape[1]]
+        margins = np.asarray(router[0, p - 1:p - 1 + n])
+        fine, over, excused = common.judge_gaps(gaps, margins, size.gap_tol, size.near_tie)
+        ok = ok and fine and wrong.min() > size.gap_tol
+        worst_gap = max(worst_gap, float(gaps[margins >= size.near_tie].max(initial=0.0)))
+        starts = range(0, p, size.tail) if p < max(size.prompt_lens) else [p - size.tail]
+        blocks = [max(min(lo, p - size.tail), 0) for lo in starts]
+        mine = [np.asarray(prefill_rows(params, prompt[None], lo), np.float32) for lo in blocks]
+        theirs = [head(np.arange(lo, lo + m.shape[0])) for lo, m in zip(blocks, mine)]
+        diffs = np.concatenate([np.abs(m - t).max(1) for m, t in zip(mine, theirs)])
+        median, worst = float(np.median(diffs)), float(diffs.max())
+        ok = ok and worst <= size.logit_tol and median <= size.typical_tol
+        worst_diff, worst_median = max(worst_diff, worst), max(worst_median, median)
+        log(f"glm: prompt {p}: decoded tokens' largest reference-logit gap {gaps.max():.4f} ({over} of {n} "
+            f"fail {size.gap_tol:g}, {excused} excused by a router margin under {size.near_tie:g} where a held "
+            f"expert is at the edge; wrong tokens' smallest gap {wrong.min():.3f}); prefill logits at "
+            f"{len(diffs)} positions, |system - reference|: median {median:.4f}, 99th percentile "
+            f"{np.percentile(diffs, 99):.4f}, largest {worst:.4f}")
+        if p == sorted(size.prompt_lens)[len(size.prompt_lens) // 2]:
+            # the middle prompt: selection is at work and an S x S mask fits.
+            # The system's layer-0 sets against the reference's, then the
+            # controls, each the reference changed in ONE way against the plain one
+            sel = ref.selected(prompt[None])[0]
+            sparse_rows = np.arange(p) >= keep
+
+            def share_differing(other):
+                return float((other[0][sparse_rows] != sel[0][sparse_rows]).sum() / (2.0 * keep * sparse_rows.sum()))
+
+            differing = share_differing(np.asarray(layer0_keep(params, prompt[None])))
+            sys_attn, sys_h, sys_routed, sys_latent = blocks_of(params, prompt[None])
+            x0 = ref.embed(prompt[None])
+            ref_attn, ref_routed = ref.attention_part(0, x0), ref.routed_part(sparse, sys_h)
+            ref_latent = ref.latent_part(0, x0)
+            (attn_rel, _), (routed_rel, hit) = rel(sys_attn, ref_attn), rel(sys_routed, ref_routed)
+            latent_rel = rel(sys_latent, ref_latent)[0]
+            blocks_ok = (attn_rel <= size.attn_tol and routed_rel <= size.routed_tol
+                         and latent_rel <= size.latent_tol)
+            log(f"glm: prompt {p}, layer 0: {100 * differing:.3f}% of the selected columns differ from the "
+                f"reference's (rows past {keep}; limit {100 * size.selected_tol:g}%); one block alone, median "
+                f"|system - reference| / |reference|: layer 0's attention {attn_rel:.4f} (limit {size.attn_tol:g}), "
+                f"layer {sparse}'s routed sum on the system's own input {routed_rel:.4f} at the {hit} of {p} "
+                f"positions where a held expert was chosen (limit {size.routed_tol:g}), what layer 0's cache holds "
+                f"{latent_rel:.5f} (limit {size.latent_tol:g})")
+            controls = (
+                ("float8 index keys", dict(index_dtype=jnp.float8_e4m3fn)),
+                ("1024 columns kept", dict(topk=keep // 2)),
+                ("a float8 latent", dict(latent_dtype=jnp.float8_e4m3fn)),
+                ("the bias in the weights", dict(bias_in_weights=True)),
+            )
+            for name, kw in controls:
+                other = Reference(published, plain, **kw)
+                h2 = other._hidden(ids)[0]
+                rows2 = [np.asarray(other._head(other.p["model"]["final_norm"], other.p["lm_head"],
+                                                h2[:, lo:lo + m.shape[0]])[0], np.float32)
+                         for lo, m in zip(blocks, mine)]
+                d2 = np.concatenate([np.abs(r - t).max(1) for r, t in zip(rows2, theirs)])
+                share = share_differing(other.selected(prompt[None])[0])
+                a_rel = rel(other.attention_part(0, x0), ref_attn)[0]
+                r_rel = rel(other.routed_part(sparse, sys_h), ref_routed)[0]
+                l_rel = rel(other.latent_part(0, x0), ref_latent)[0]
+                caught[name] = (float(d2.max()) > size.logit_tol or float(np.median(d2)) > size.typical_tol
+                                or share > size.selected_tol or a_rel > size.attn_tol or r_rel > size.routed_tol
+                                or l_rel > size.latent_tol)
+                log(f"glm: control, the reference with {name} against the plain reference: {100 * share:.3f}% of "
+                    f"layer 0's selected columns differ; logits at {len(d2)} positions: median {np.median(d2):.4f}, "
+                    f"99th percentile {np.percentile(d2, 99):.4f}, largest {d2.max():.4f}; layer 0's attention "
+                    f"{a_rel:.4f}, layer {sparse}'s routed sum {r_rel:.4f}, layer 0's cache {l_rel:.5f}: "
+                    f"{'outside' if caught[name] else 'INSIDE'} the limits")
+    log(f"glm: prefill logits against the reference: largest difference {worst_diff:.4f} (tolerance "
+        f"{size.logit_tol:g}), largest median {worst_median:.4f} ({size.typical_tol:g}); largest decoded-token gap "
+        f"outside router near-ties {worst_gap:.4f} ({size.gap_tol:g})")
+    return {
+        **checks,
+        "glm_matches_reference": ok,
+        "glm_selects_the_references_columns": differing is not None and differing <= size.selected_tol,
+        "glm_attention_routed_sum_and_cache_alone_match_reference": blocks_ok,
+        "glm_resolved_sparse_latent_fused": resolved == {
+            "attention": "flash", "decode_attention": "paged_sparse_latent_fused",
+            "paged_attention": "fused",
+        },
+        "glm_cache_is_a_tile_and_an_index_key": per_token == want_bytes,
+        "glm_float8_index_keys_are_caught": caught.get("float8 index keys", False),
+        "glm_1024_kept_is_caught": caught.get("1024 columns kept", False),
+        "glm_float8_latent_is_caught": caught.get("a float8 latent", False),
+        "glm_bias_in_the_weights_is_caught": caught.get("the bias in the weights", False),
+        "kernel_glm_programs": all(kernels.values()) and bool(kernels),
+    }
+
+
 def one_chip(seed: int, devices, train: TrainSize = TrainSize(),
              serve: ServeSize = ServeSize(), mla: MlaSize = MlaSize(),
-             only: str = "all", dsa: DsaSize = DsaSize()) -> Dict[str, bool]:
-    """The default run: train, then serve, then the MLA model, then the
-    sparse-attention model, in one process on one device; ``only="mla"`` or
-    ``"dsa"``: that phase alone."""
+             only: str = "all", dsa: DsaSize = DsaSize(),
+             glm: GlmSize = GlmSize()) -> Dict[str, bool]:
+    """The default run: train, then serve, then the MLA model, the
+    sparse-attention model and GLM-5's (sparse selection among latents), in
+    one process on one device; ``only="mla"``, ``"dsa"`` or ``"glm"``: that
+    phase alone."""
     if only == "mla":
         return mla_phase(mla, seed)
     if only == "dsa":
         return dsa_phase(dsa, seed)
+    if only == "glm":
+        return glm_phase(glm, seed)
     return {**train_phase(train, seed, devices), **serve_phase(serve, seed),
-            **mla_phase(mla, seed), **dsa_phase(dsa, seed)}
+            **mla_phase(mla, seed), **dsa_phase(dsa, seed), **glm_phase(glm, seed)}
 
 
 def four_chips(seed: int, devices, train: TrainSize = TrainSize(),
@@ -1212,9 +1546,9 @@ def parse_args(argv=None):
                         "tp=4 train step and tp=4 engine and their "
                         "one-device counterparts")
     p.add_argument("--seed", type=int, default=0)
-    p.add_argument("--only", default="all", choices=("all", "mla", "dsa"),
-                   help="one chip: every phase (default), the MLA phase or the "
-                        "sparse-attention (dsa) phase alone")
+    p.add_argument("--only", default="all", choices=("all", "mla", "dsa", "glm"),
+                   help="one chip: every phase (default), or the MLA, the "
+                        "sparse-attention (dsa) or the GLM-5 (glm) phase alone")
     return p.parse_args(argv)
 
 

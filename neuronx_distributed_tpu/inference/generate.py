@@ -154,7 +154,13 @@ def chunked_decode_step(decode_model, chunk_size: int, max_seq_len: int,
     nothing is scattered a second time.
     It is the compiled kernel or nothing: off the TPU it runs only where a
     test interprets it. Fused mode does not speak quantized pools (the
-    in-kernel page stream is float)."""
+    in-kernel page stream is float).
+
+    A model that names ``chunk_stats`` (int32 scalars its layers sow into the
+    ``stats`` collection each step: ``models/glm_moe_dsa.py``'s rows its held
+    experts computed) gets a seventh output, an int32 vector of their sums
+    over the chunk's executed steps and the layers, in that order; for every
+    other model the program is what it was."""
     from neuronx_distributed_tpu.inference.utils import unwrap_logits
     from neuronx_distributed_tpu.modules.attention import (
         adopt_kv_pool_pairs,
@@ -167,6 +173,7 @@ def chunked_decode_step(decode_model, chunk_size: int, max_seq_len: int,
     )
     from neuronx_distributed_tpu.utils.sampling import sample_per_row
 
+    stat_names = tuple(getattr(decode_model, "chunk_stats", ()))
     if chunk_size < 1:
         raise ValueError(f"chunk_size must be >= 1, got {chunk_size}")
     if paged_attention not in ("gather", "fused"):
@@ -227,10 +234,21 @@ def chunked_decode_step(decode_model, chunk_size: int, max_seq_len: int,
         def apply(cache, tok, done):
             return decode_model.apply(
                 {**params, "cache": cache}, tok[:, None],
-                padding_mask=decode_write_mask(done), mutable=["cache"],
+                padding_mask=decode_write_mask(done),
+                mutable=["cache", "stats"] if stat_names else ["cache"],
             )
 
+        def step_stats(variables):
+            """The step's ``stat_names`` summed over the layers that sowed them."""
+            sown = jax.tree_util.tree_flatten_with_path(variables["stats"])[0]
+            return jnp.stack([
+                sum(leaf for path, leaf in sown
+                    if any(getattr(k, "key", None) == name for k in path))
+                for name in stat_names]).astype(jnp.int32)
+
         def live(carry):
+            if stat_names:
+                *carry, stats = carry
             cache, tok, keys, remaining, done, pools = carry
             split = jax.vmap(jax.random.split)(keys)
             carry_keys, subs = split[:, 0], split[:, 1]
@@ -252,11 +270,11 @@ def chunked_decode_step(decode_model, chunk_size: int, max_seq_len: int,
             # at the values the single-step engine would have retired with
             tok = jnp.where(emit, nxt, tok)
             keys = jnp.where(emit[:, None], carry_keys, keys)
-            return (
-                (variables["cache"], tok, keys, remaining, done | finished,
-                 pools),
-                (nxt, emit),
-            )
+            carry = (variables["cache"], tok, keys, remaining,
+                     done | finished, pools)
+            if stat_names:
+                carry += (stats + step_stats(variables),)
+            return carry, (nxt, emit)
 
         def frozen(carry):
             tok, done = carry[1], carry[4]
@@ -272,7 +290,9 @@ def chunked_decode_step(decode_model, chunk_size: int, max_seq_len: int,
             cache, state["tok"], state["keys"], state["remaining"], done0,
             pools,
         )
-        (cache, tok, keys, remaining, done, pools), (toks, emits) = (
+        if stat_names:
+            carry0 += (jnp.zeros((len(stat_names),), jnp.int32),)
+        (cache, tok, keys, remaining, done, pools, *stats), (toks, emits) = (
             jax.lax.scan(
                 step, carry0, jnp.arange(chunk_size, dtype=jnp.int32)
             )
@@ -282,7 +302,8 @@ def chunked_decode_step(decode_model, chunk_size: int, max_seq_len: int,
             state, tok=tok, keys=keys, remaining=remaining,
             active=jnp.logical_not(done),
         )
-        out = cache, new_state, toks, counts, jnp.max(counts), keys.copy()
+        out = (cache, new_state, toks, counts, jnp.max(counts), keys.copy(),
+               *stats)
         return out, pools
 
     return chunk_fn

@@ -1230,6 +1230,53 @@ def paged_scatter_window_pages_dma(pool: jax.Array, window: jax.Array,
     )(ids, vals, pool)
 
 
+def _selected_addresses(block_table, sel_cols, n_sel, page_size):
+    """``(chunk, page, off, n_sel)`` for the sparse kernels' scalar prefetch:
+    each slot's selected columns as pool rows ``(page, offset)``, (B, K
+    rounded up to the chunk) int32, and their count held to ``K``."""
+    k_sel = sel_cols.shape[1]
+    chunk = min(SPARSE_CHUNK_TOKENS, -(-k_sel // _TOKENS_A_TRIP) * _TOKENS_A_TRIP)
+    padded = -(-k_sel // chunk) * chunk
+    n_sel = jnp.minimum(n_sel.astype(jnp.int32), k_sel)
+    cols = sel_cols.astype(jnp.int32)
+    # unselected entries (and the chunk's padding) read token 0 of the null page
+    live = jnp.arange(k_sel)[None, :] < n_sel[:, None]
+    pad = lambda a: jnp.pad(jnp.where(live, a, 0), ((0, 0), (0, padded - k_sel)))  # noqa: E731
+    page = pad(jnp.take_along_axis(block_table.astype(jnp.int32), cols // page_size, axis=1))
+    off = pad(cols % page_size)
+    return chunk, page, off, n_sel
+
+
+def _fetch_selected(page_ref, off_ref, kv_hbm, buf, sems, b, chunk, c):
+    """Start the copies of chunk ``c`` of slot ``b``'s selected tokens into
+    half ``c % 2`` of ``buf``: ONE async copy a token, whatever a token's
+    leaf holds."""
+    slot = c % 2
+
+    def some(t, carry):
+        for u in range(_TOKENS_A_TRIP):
+            j = t * _TOKENS_A_TRIP + u
+            i = c * chunk + j
+            # a token's pool row: (page, offset); the pool keeps its four
+            # dims (PR 30: flattening the first two was a whole-pool copy
+            # on the chip), and one address read more costs nothing a
+            # call can show
+            pltpu.make_async_copy(
+                kv_hbm.at[page_ref[b, i], off_ref[b, i]], buf.at[slot, j],
+                sems.at[slot],
+            ).start()
+        return carry
+
+    jax.lax.fori_loop(0, chunk // _TOKENS_A_TRIP, some, 0)
+
+
+def _wait_selected(buf, sems, slot):
+    """ONE wait a chunk: a DMA semaphore counts bytes, and a chunk's copies
+    (its padding entries copy token 0 of the null page) fill the whole
+    buffer, so a descriptor over the buffer names exactly their bytes."""
+    pltpu.make_async_copy(buf.at[slot], buf.at[slot], sems.at[slot]).wait()
+
+
 def _sparse_decode_kernel(page_ref, off_ref, n_ref, q_ref, kv_hbm, o_ref, buf,
                           sems, m_scr, l_scr, acc_scr, *, chunk, num_kv_heads,
                           scale):
@@ -1237,24 +1284,7 @@ def _sparse_decode_kernel(page_ref, off_ref, n_ref, q_ref, kv_hbm, o_ref, buf,
     n = n_ref[b]
     n_chunks = (n + chunk - 1) // chunk
 
-    def fetch(c):
-        slot = c % 2
-
-        def some(t, carry):
-            for u in range(_TOKENS_A_TRIP):
-                j = t * _TOKENS_A_TRIP + u
-                i = c * chunk + j
-                # a token's pool row: (page, offset); the pool keeps its four
-                # dims (PR 30: flattening the first two was a whole-pool copy
-                # on the chip), and one address read more costs nothing a
-                # call can show
-                pltpu.make_async_copy(
-                    kv_hbm.at[page_ref[b, i], off_ref[b, i]], buf.at[slot, j],
-                    sems.at[slot],
-                ).start()
-            return carry
-
-        jax.lax.fori_loop(0, chunk // _TOKENS_A_TRIP, some, 0)
+    fetch = functools.partial(_fetch_selected, page_ref, off_ref, kv_hbm, buf, sems, b, chunk)
 
     m_scr[:] = jnp.full_like(m_scr, NEG_INF)
     l_scr[:] = jnp.zeros_like(l_scr)
@@ -1270,10 +1300,7 @@ def _sparse_decode_kernel(page_ref, off_ref, n_ref, q_ref, kv_hbm, o_ref, buf,
             fetch(c + 1)
 
         slot = c % 2
-        # ONE wait a chunk: a DMA semaphore counts bytes, and a chunk's copies
-        # (its padding entries copy token 0 of the null page) fill the whole
-        # buffer, so a descriptor over the buffer names exactly their bytes
-        pltpu.make_async_copy(buf.at[slot], buf.at[slot], sems.at[slot]).wait()
+        _wait_selected(buf, sems, slot)
         rows = q_ref.shape[2]
         ok = jax.lax.broadcasted_iota(jnp.int32, (rows, chunk), 1) + c * chunk < n
         for h in range(num_kv_heads):
@@ -1335,16 +1362,7 @@ def paged_sparse_decode_attention(
             f"joined K/V pool leaf must be (P, page_size, 2 Hkv, {d}), got {kv_pool.shape}")
     hkv = kv_pool.shape[2] // 2
     g = h // hkv
-    k_sel = sel_cols.shape[1]
-    chunk = min(SPARSE_CHUNK_TOKENS, -(-k_sel // _TOKENS_A_TRIP) * _TOKENS_A_TRIP)
-    padded = -(-k_sel // chunk) * chunk
-    n_sel = jnp.minimum(n_sel.astype(jnp.int32), k_sel)
-    cols = sel_cols.astype(jnp.int32)
-    # unselected entries (and the chunk's padding) read token 0 of the null page
-    live = jnp.arange(k_sel)[None, :] < n_sel[:, None]
-    pad = lambda a: jnp.pad(jnp.where(live, a, 0), ((0, 0), (0, padded - k_sel)))  # noqa: E731
-    page = pad(jnp.take_along_axis(block_table.astype(jnp.int32), cols // page_size, axis=1))
-    off = pad(cols % page_size)
+    chunk, page, off, n_sel = _selected_addresses(block_table, sel_cols, n_sel, page_size)
     rows = pl.BlockSpec((1, hkv, g, d), lambda b_, *_: (b_, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # the selected tokens' pages and offsets, their count
@@ -1368,3 +1386,148 @@ def paged_sparse_decode_attention(
         interpret=interpret_mode(interpret),
     )(page, off, n_sel, q.reshape(b, hkv, g, d), kv_pool)
     return out.reshape(b, 1, h, d)
+
+
+# --- paged SPARSE LATENT decode (an indexer that selects among MLA's latents) ----
+#
+# DeepSeek-Sparse-Attention over multi-head latent attention (GLM-5): the
+# indexer's ``topk`` columns are rows of the LATENT cache, one ``(c, k_pe)`` a
+# token for every head, and the absorbed product runs against those rows
+# alone. The cache's joined leaf holds a token as ``(rows, lanes)``: the
+# latent in its first ``d_c / lanes`` rows, the rotated key at the start of
+# the next (``modules/attention.IndexedLatentKVCache``; ``(8, 128)`` in bf16
+# at 512 + 64: a whole HBM tile, which is the unit Mosaic copies). The fetch
+# is the GQA kernel's (:func:`_fetch_selected` / :func:`_wait_selected`): ONE
+# async copy a selected token, its ``(page, offset)`` from two prefetched
+# arrays, one wait a chunk over the whole buffer. What differs is the multiply: all ``H`` heads score the SAME
+# rows, so a chunk is ``d_c / lanes + 1`` matmuls of ``(H, lanes) x (lanes,
+# chunk)`` for the scores and ``d_c / lanes`` of ``(H, chunk) x (chunk,
+# lanes)`` for the values, a row of the token's tile each (no reshape of the
+# buffer: a relayout in VMEM).
+#
+# Measured alone at GLM-5's serve shapes (8 slots x 2048 selected, 64 heads;
+# v5e, PERF.md section 6, PR 32), ms a call: this form 0.526 (chunks of 256:
+# 0.543; 32 copies a trip: 0.517, not taken as in PR 31); the latent as a
+# leaf of (4, 128) and the rotated key as a second of (2, 128), two copies a
+# token: 1.403.
+
+
+def _sparse_latent_kernel(page_ref, off_ref, n_ref, qc_ref, qr_ref, kv_hbm,
+                          o_ref, buf, sems, m_scr, l_scr, acc_scr, *, chunk,
+                          scale):
+    b = pl.program_id(0)
+    n = n_ref[b]
+    n_chunks = (n + chunk - 1) // chunk
+    heads, d_c = qc_ref.shape[1], qc_ref.shape[2]
+    d_r, lanes = qr_ref.shape[2], buf.shape[3]
+    n_c = d_c // lanes
+
+    fetch = functools.partial(_fetch_selected, page_ref, off_ref, kv_hbm, buf, sems, b, chunk)
+
+    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(n_chunks > 0)
+    def _first():
+        fetch(0)
+
+    def step(c, carry):
+        @pl.when(c + 1 < n_chunks)
+        def _prefetch():
+            fetch(c + 1)
+
+        slot = c % 2
+        _wait_selected(buf, sems, slot)
+        dims = (((1,), (1,)), ((), ()))
+        s = jax.lax.dot_general(
+            qr_ref[0], buf[slot, :, n_c, :][:, :d_r], dims,
+            preferred_element_type=jnp.float32)                # (H, T)
+        for r in range(n_c):
+            s = s + jax.lax.dot_general(
+                qc_ref[0, :, r * lanes:(r + 1) * lanes], buf[slot, :, r, :], dims,
+                preferred_element_type=jnp.float32)
+        s = s * scale
+        ok = jax.lax.broadcasted_iota(jnp.int32, (heads, chunk), 1) + c * chunk < n
+        s = jnp.where(ok, s, NEG_INF)
+        m_prev = m_scr[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        ref = jnp.where(m_new > NEG_INF / 2, m_new, 0.0)
+        p = jnp.where(ok, jnp.exp(s - ref), 0.0)
+        alpha = jnp.exp(m_prev - ref)
+        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        p = p.astype(buf.dtype)
+        for r in range(n_c):     # the values are the latent rows already in VMEM
+            cols = slice(r * lanes, (r + 1) * lanes)
+            acc_scr[:, cols] = acc_scr[:, cols] * alpha + jax.lax.dot_general(
+                p, buf[slot, :, r, :], (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        m_scr[:] = m_new
+        return carry
+
+    jax.lax.fori_loop(0, n_chunks, step, 0)
+    o_ref[0] = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)).astype(o_ref.dtype)
+
+
+def paged_sparse_latent_decode_attention(
+    q_c: jax.Array,
+    q_r: jax.Array,
+    kv_pool: jax.Array,
+    block_table: jax.Array,
+    sel_cols: jax.Array,
+    n_sel: jax.Array,
+    *,
+    scale: float,
+    page_size: int = 16,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """Absorbed latent decode attention over SELECTED columns only: ``q_c``
+    (B, 1, H, d_c) the heads' content queries with ``W_uk`` folded in,
+    ``q_r`` (B, 1, H, d_r) their rotated part; ``kv_pool`` (P, page_size,
+    rows, lanes), a token's latent in its first ``d_c / lanes`` rows and its
+    rotated key at the start of the next
+    (:class:`~neuronx_distributed_tpu.modules.attention.IndexedLatentKVCache`'s
+    joined leaf); ``sel_cols`` (B, K) / ``n_sel`` (B,) / ``block_table`` as
+    :func:`paged_sparse_decode_attention`. Softmax in float32 over those
+    columns of ``(q_c . c + q_r . k_pe) * scale``, values the latent rows:
+    (B, 1, H, d_c) for the caller's ``W_uv``. Only the selected tokens' tiles
+    leave HBM. The kernel or nothing (interpreted only in tests); no mesh."""
+    from neuronx_distributed_tpu.parallel import mesh as mesh_lib
+
+    if mesh_lib.model_parallel_is_initialized():
+        raise NotImplementedError(
+            "the paged sparse latent decode kernel has no sharded form: serve "
+            "an indexed-latent-cache model without a model-parallel mesh"
+        )
+    b, s, h, d_c = q_c.shape
+    d_r = q_r.shape[3]
+    if s != 1:
+        raise ValueError(f"one query row a slot, got {s}")
+    rows, lanes = kv_pool.shape[2:] if kv_pool.ndim == 4 else (0, 1)
+    if kv_pool.ndim != 4 or d_c % lanes or d_r > lanes or rows < d_c // lanes + 1:
+        raise ValueError(
+            f"joined latent pool leaf must be (P, page_size, rows > {d_c} / lanes, lanes >= "
+            f"{d_r}), got {kv_pool.shape}")
+    chunk, page, off, n_sel = _selected_addresses(block_table, sel_cols, n_sel, page_size)
+    per_slot = lambda d: pl.BlockSpec((1, h, d), lambda b_, *_: (b_, 0, 0))  # noqa: E731
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,  # the selected tokens' pages and offsets, their count
+        grid=(b,),
+        in_specs=[per_slot(d_c), per_slot(d_r), pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=per_slot(d_c),
+        scratch_shapes=[
+            pltpu.VMEM((2, chunk, rows, lanes), kv_pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, d_c), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_sparse_latent_kernel, chunk=chunk, scale=scale),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, d_c), q_c.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        interpret=interpret_mode(interpret),
+    )(page, off, n_sel, q_c[:, 0], q_r[:, 0], kv_pool)
+    return out[:, None]

@@ -46,6 +46,13 @@ from neuronx_distributed_tpu.models.deepseek_v2 import (
     deepseek_v2_lite,
     tiny_deepseek_v2,
 )
+from neuronx_distributed_tpu.models.glm_moe_dsa import (
+    GlmMoeDsaConfig,
+    GlmMoeDsaForCausalLM,
+    GlmMoeDsaModel,
+    glm5,
+    tiny_glm_moe_dsa,
+)
 from neuronx_distributed_tpu.models.keye_vl2 import (
     KeyeVL2Config,
     KeyeVL2ForCausalLM,
@@ -72,6 +79,8 @@ __all__ = [
     "CodeGenConfig", "CodeGenForCausalLM", "codegen25_7b", "tiny_codegen",
     "DeepseekV2Config", "DeepseekV2ForCausalLM", "DeepseekV2Model",
     "deepseek_v2_lite", "tiny_deepseek_v2",
+    "GlmMoeDsaConfig", "GlmMoeDsaForCausalLM", "GlmMoeDsaModel",
+    "glm5", "tiny_glm_moe_dsa",
     "KeyeVL2Config", "KeyeVL2ForCausalLM", "KeyeVL2Model",
     "keye_vl2_30b_a3b", "tiny_keye_vl2",
 ]

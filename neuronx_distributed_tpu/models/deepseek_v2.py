@@ -7,7 +7,9 @@ equations are the published ``modeling_deepseek.py``'s, config
 ``deepseek-ai/DeepSeek-V2-Lite``.)
 
 Layer equations (``h`` a token's hidden vector, ``H`` heads; V2-Lite: no
-query compression, ``q_lora_rank`` null):
+query compression, ``q_lora_rank`` null. MLA WITH a query latent, ``q = W_q_b
+RMSNorm(W_q_a h)``, lives in ``models/glm_moe_dsa.py``, where the latent also
+feeds the sparse-attention indexer):
 
 * block: ``x += attn(norm1(x))``; ``x += ffn(norm2(x))``; RMSNorm, eps 1e-6;
   a final RMSNorm; an untied output head; no biases anywhere.
@@ -199,6 +201,61 @@ def yarn_frequencies(dim: int, max_seq_len: int, theta: float,
 # --- attention ------------------------------------------------------------------
 
 
+# --- what every latent attention shares (GLM-5's, under its indexer, too) ------------
+
+
+def compress_kv(module, cfg, x):
+    """Inside ``module``'s compact ``__call__``: ``(c (B, S, 1, d_c), k_pe (B,
+    S, 1, d_r) before its rotary, W_kv_b (d_c, H, d_nope + d_v))`` of block
+    inputs ``x``; parameters ``kv_a_proj``, ``kv_a_norm``, ``kv_b_proj``."""
+    h, d_c = cfg.num_heads, cfg.kv_lora_rank
+    d_n, d_r, d_v = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    with jax.named_scope("mla.compress"):
+        kv_a = ColumnParallelLinear(
+            cfg.hidden_size, d_c + d_r, gather_output=True, axis=None,
+            name="kv_a_proj", use_bias=False, dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype,
+        )(x)
+        c = RMSNorm(
+            d_c, eps=cfg.rms_eps, dtype=cfg.dtype,
+            param_dtype=cfg.param_dtype, name="kv_a_norm",
+        )(kv_a[..., :d_c])[:, :, None, :]                      # (B, S, 1, d_c)
+        k_pe = kv_a[..., d_c:][:, :, None, :]                  # (B, S, 1, d_r)
+    # W_kv_b as (d_c, H, d_nope + d_v): a matmul in the materialised form,
+    # its two per-head halves W_uk, W_uv in the absorbed one
+    w_kv_b = module.param(
+        "kv_b_proj",
+        nn.with_partitioning(
+            nn.initializers.lecun_normal(in_axis=0, out_axis=(1, 2)),
+            (None, mesh_lib.TP_AXIS, None),
+        ),
+        (d_c, h, d_n + d_v), cfg.param_dtype,
+    ).astype(cfg.dtype)
+    return c, k_pe, w_kv_b
+
+
+def expand_kv(c, k_pe, w_kv_b, d_n: int):
+    """The materialised form of latents ``c`` and ROTATED keys ``k_pe`` (the
+    caller's ``mla.expand`` scope): ``(k (B, S, H, d_nope + d_rope), kv (B, S,
+    H, d_nope + d_v))``, the values ``kv[..., d_nope:]``."""
+    kv = jnp.einsum("bsc,chd->bshd", c[:, :, 0], w_kv_b)
+    k = jnp.concatenate(
+        [kv[..., :d_n], jnp.broadcast_to(k_pe, kv.shape[:3] + k_pe.shape[3:])], -1)
+    return k, kv
+
+
+def absorb_query(q_nope, w_kv_b):
+    """``W_uk`` folded into the content queries: (B, S, H, d_c)."""
+    with jax.named_scope("mla.absorb"):
+        return jnp.einsum("bshd,chd->bshc", q_nope, w_kv_b[..., :q_nope.shape[-1]])
+
+
+def absorb_values(o_c, w_kv_b, d_n: int):
+    """``W_uv`` on the latent-space output: (B, S, H, d_v)."""
+    with jax.named_scope("mla.absorb"):
+        return jnp.einsum("bshc,chd->bshd", o_c, w_kv_b[..., d_n:])
+
+
 class MLAttention(nn.Module):
     """Multi-head latent attention (module docstring). ``mode``: ``train`` /
     ``prefill`` run the materialised form through :func:`attention_op`
@@ -226,26 +283,7 @@ class MLAttention(nn.Module):
         )(x).reshape(b, s, h, d_n + d_r)
         q = constrain(q, P(UNC, UNC, mesh_lib.TP_AXIS))
         q_nope, q_pe = q[..., :d_n], q[..., d_n:]
-        with jax.named_scope("mla.compress"):
-            kv_a = ColumnParallelLinear(
-                cfg.hidden_size, d_c + d_r, gather_output=True, axis=None,
-                name="kv_a_proj", **lin,
-            )(x)
-            c = RMSNorm(
-                d_c, eps=cfg.rms_eps, dtype=cfg.dtype,
-                param_dtype=cfg.param_dtype, name="kv_a_norm",
-            )(kv_a[..., :d_c])[:, :, None, :]                      # (B, S, 1, d_c)
-            k_pe = kv_a[..., d_c:][:, :, None, :]                  # (B, S, 1, d_r)
-        # W_kv_b as (d_c, H, d_nope + d_v): a matmul in the materialised form,
-        # its two per-head halves W_uk, W_uv in the absorbed one
-        w_kv_b = self.param(
-            "kv_b_proj",
-            nn.with_partitioning(
-                nn.initializers.lecun_normal(in_axis=0, out_axis=(1, 2)),
-                (None, mesh_lib.TP_AXIS, None),
-            ),
-            (d_c, h, d_n + d_v), cfg.param_dtype,
-        ).astype(cfg.dtype)
+        c, k_pe, w_kv_b = compress_kv(self, cfg, x)
 
         def rope(t, pos):
             out = apply_rope(t, freqs, pos)
@@ -270,9 +308,7 @@ class MLAttention(nn.Module):
                         self, b, cfg.max_seq_len, d_c, d_r, c.dtype
                     ).prefill_write(c, k_pe, padding_mask)
             with jax.named_scope("mla.expand"):
-                kv = jnp.einsum("bsc,chd->bshd", c[:, :, 0], w_kv_b)
-                k = jnp.concatenate(
-                    [kv[..., :d_n], jnp.broadcast_to(k_pe, (b, s, h, d_r))], -1)
+                k, kv = expand_kv(c, k_pe, w_kv_b, d_n)
                 # attention_op scales by (d_nope + d_rope)^-0.5; YaRN's m^2
                 # rides on the query
                 q = jnp.concatenate([q_nope, q_pe], -1) * jnp.asarray(
@@ -304,15 +340,13 @@ class MLAttention(nn.Module):
             pos, rope_pos = cache.decode_positions(s, positions)
             q_pe, k_pe = rope(q_pe, rope_pos), rope(k_pe, rope_pos)
             cache.decode_write(c, k_pe, padding_mask)
-        with jax.named_scope("mla.absorb"):
-            q_c = jnp.einsum("bshd,chd->bshc", q_nope, w_kv_b[..., :d_n])
+        q_c = absorb_query(q_nope, w_kv_b)
         o_c = self._cached_attention(
             lambda: latent_decode_attention(
                 q_c, q_pe, cache.k.value, cache.k_pe.value, pos,
                 cfg.softmax_scale, kv_valid=cache.valid.value,
             ))
-        with jax.named_scope("mla.absorb"):
-            return jnp.einsum("bshc,chd->bshd", o_c, w_kv_b[..., d_n:])
+        return absorb_values(o_c, w_kv_b, d_n)
 
 
 # --- the model ------------------------------------------------------------------

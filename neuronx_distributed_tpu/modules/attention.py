@@ -220,8 +220,8 @@ class KVCache:
 
     ``leaves`` names the per-token storage leaves and their ``(heads,
     width)``; the writes take one array per leaf, in that order.
-    :class:`LatentKVCache` and :class:`IndexedKVCache` are the other
-    kinds."""
+    :class:`LatentKVCache`, :class:`IndexedKVCache` and
+    :class:`IndexedLatentKVCache` are the other kinds."""
 
     def __init__(self, module, b, max_seq_len, hkv, d, dtype, leaves=None):
         self.max_seq_len = max_seq_len
@@ -363,6 +363,79 @@ def split_kv(kv):
     return kv[..., :hkv, :], kv[..., hkv:, :]
 
 
+def latent_leaf_shape(d_latent: int, d_rope: int):
+    """``(rows, lanes)`` of an :class:`IndexedLatentKVCache` token: the latent
+    in ``d_latent / lanes`` rows of ``lanes`` (128 wherever the width allows),
+    the rotated key at the start of the next row, and the rows up to what the
+    chip holds anyway: a bf16 ``(rows, 128)`` array is tiled ``(2, 128)``,
+    ``(4, 128)`` or ``(8, 128)``, so 5 rows are held as 8."""
+    import math
+
+    lanes = math.gcd(d_latent, 128)
+    if d_rope > lanes:
+        raise ValueError(
+            f"the rotated key ({d_rope}) must fit one row of {lanes} lanes "
+            f"(the latent is {d_latent})")
+    need = d_latent // lanes + 1
+    rows = next(r for r in (2, 4, 8) if r >= need) if need <= 8 else -(-need // 8) * 8
+    return rows, lanes
+
+
+def join_latent(c, k_pe, rows: int, lanes: int):
+    """The joined leaf (..., rows, lanes) of a latent row ``c`` (..., 1,
+    d_latent) and its rotated key ``k_pe`` (..., 1, d_rope)."""
+    lead = c.shape[:-2]
+    n_c = c.shape[-1] // lanes
+    kr = jnp.pad(k_pe, ((0, 0),) * (k_pe.ndim - 1) + ((0, lanes - k_pe.shape[-1]),))
+    spare = jnp.zeros(lead + (rows - n_c - 1, lanes), c.dtype)
+    return jnp.concatenate([c.reshape(lead + (n_c, lanes)), kr, spare], axis=-2)
+
+
+def split_latent(kv, d_latent: int, d_rope: int):
+    """``(c (..., d_latent), k_pe (..., d_rope))`` of a joined latent leaf
+    (..., rows, lanes)."""
+    n_c = d_latent // kv.shape[-1]
+    c = kv[..., :n_c, :].reshape(kv.shape[:-2] + (d_latent,))
+    return c, kv[..., n_c, :d_rope]
+
+
+class IndexedLatentKVCache(KVCache):
+    """The cache of multi-head LATENT attention whose keys a learned indexer
+    SELECTS (DeepSeek-Sparse-Attention over MLA): per token the latent row
+    ``c`` (``d_latent`` values), the rotated key ``k_pe`` (``d_rope``) and ONE
+    index key ``k_idx`` (``d_index``), all shared by every head: 512 + 64 +
+    128 values at GLM-5's widths, 1408 B in bf16.
+
+    Leaves ``kv`` (B, L, rows, lanes) and ``k_idx`` (B, L, 1, d_index), the
+    names an :class:`IndexedKVCache` has, so every walker
+    (:data:`PAGED_LEAVES`) and the window's page copies handle them alike.
+    ``kv`` is the token's latent in its first ``d_latent / lanes`` rows and
+    its rotated key at the start of the next (:func:`join_latent`): **(8,
+    128) at GLM-5's widths, 2048 B a token a layer in bf16 of which 1152 are
+    used, + 256 B of index key = 2304 B** (``cache_bytes_per_token_layer``
+    reads that). Why one leaf of a whole tile: the sparse decode kernel
+    fetches a SELECTED token at a time and is bound by the copies it names
+    (PERF.md section 6, PR 31), and Mosaic copies whole HBM tiles only: five
+    rows are held as eight whatever the shape says, a leaf of (4, 128) for
+    the latent is exact but leaves the rotated key a second leaf of (2, 128)
+    (1536 B a token) and a second copy a token. Measured at the serve cell's
+    shapes (PERF.md section 6, PR 32) before this form was kept. The writes
+    take ``(c, k_pe, k_idx)``."""
+
+    def __init__(self, module, b, max_seq_len, d_latent, d_rope, d_index, dtype):
+        self.leaf = latent_leaf_shape(d_latent, d_rope)
+        super().__init__(
+            module, b, max_seq_len, *self.leaf, dtype,
+            leaves={"kv": self.leaf, "k_idx": (1, d_index)},
+        )
+
+    def prefill_write(self, c, k_pe, k_idx, padding_mask=None):
+        self._prefill_write((join_latent(c, k_pe, *self.leaf), k_idx), padding_mask)
+
+    def decode_write(self, c, k_pe, k_idx, padding_mask=None):
+        self._decode_write((join_latent(c, k_pe, *self.leaf), k_idx), padding_mask)
+
+
 # --- cache-collection slot helpers (serving) ----------------------------------
 #
 # The continuous-batching engine (serving/) owns ONE cache collection whose
@@ -371,7 +444,8 @@ def split_kv(kv):
 # KVCache declares: k/v (..., B, L, Hkv, D), kv_valid (..., B, L), index
 # scalar cursor (nn.scan stacks a leading layer axis on each); a latent
 # cache's per-token leaves are k/k_pe (..., B, L, 1, d), an indexed cache's
-# kv (..., B, L, 2 Hkv, D) and k_idx (..., B, L, 1, d_index).
+# kv (..., B, L, 2 Hkv, D) and k_idx (..., B, L, 1, d_index), an indexed
+# latent cache's kv (..., B, L, rows, lanes) and the same k_idx.
 
 # THE names of the per-token storage leaves, (..., B, L, heads, width): what a
 # page pool pages, a prefix block copies and a fingerprint hashes. Every
@@ -956,9 +1030,12 @@ def _fused_paged_decode(frame, q, caches, q_pos, kv_valid, latent_scale=None,
     )
 
 
-def _fused_sparse_decode(frame, q, q_idx, w_idx, caches, q_pos, kv_valid, topk):
+def _fused_sparse_decode(frame, q, q_idx, w_idx, caches, q_pos, kv_valid, topk,
+                         latent_scale=None):
     """An indexed cache's step in the active frame: ``caches`` the layer's
-    window leaves ``(kv, k_idx)``. The windows go into the carried pools (the
+    window leaves ``(kv, k_idx)``. With ``latent_scale`` (an
+    :class:`IndexedLatentKVCache`) ``q`` is the absorbed pair ``(q_c, q_r)``
+    and the attend kernel the latent one. The windows go into the carried pools (the
     joined K/V pool through a KERNEL, :func:`~neuronx_distributed_tpu.
     kernels.flash_decode.paged_scatter_window_pages_dma`: every user of that
     pool inside the decode scan is then a kernel of one layout; the 64-wide
@@ -969,6 +1046,7 @@ def _fused_sparse_decode(frame, q, q_idx, w_idx, caches, q_pos, kv_valid, topk):
         paged_scatter_window_pages,
         paged_scatter_window_pages_dma,
         paged_sparse_decode_attention,
+        paged_sparse_latent_decode_attention,
     )
 
     pools = frame["pools"]
@@ -988,6 +1066,9 @@ def _fused_sparse_decode(frame, q, q_idx, w_idx, caches, q_pos, kv_valid, topk):
         vals, cols = jax.lax.top_k(scores, min(topk, scores.shape[1]))
         n_sel = jnp.sum(vals > -jnp.inf, axis=1).astype(jnp.int32)
     with jax.named_scope(DSA_ATTEND_SCOPE):
+        if latent_scale is not None:
+            return paged_sparse_latent_decode_attention(
+                *q, kv_pool, bt, cols, n_sel, scale=latent_scale, page_size=ps)
         return paged_sparse_decode_attention(
             q, kv_pool, bt, cols, n_sel, page_size=ps)
 
@@ -1290,6 +1371,57 @@ def indexed_decode_attention(q, q_idx, w_idx, kv_cache, idx_cache, q_pos,
         return _query_chunks(
             lambda qc, kc: _masked_gqa_attention(qc, k_cache, v_cache, kc),
             (q, keep), s)
+
+
+def _masked_latent_attention(q_c, q_r, c, k_pe, keep, scale):
+    """Absorbed latent attention of rows q_c (B, T, H, d_c) / q_r (B, T, H,
+    d_r) over the columns of ``c`` (B, S, d_c) / ``k_pe`` (B, S, d_r) where
+    ``keep`` (B, T, S): float32 einsums, the golden path. Returns (B, T, H,
+    d_c); a row that keeps nothing returns zeros."""
+    c = c.astype(jnp.float32)
+    s = (
+        jnp.einsum("bthd,bsd->bhts", q_c.astype(jnp.float32), c)
+        + jnp.einsum("bthd,bsd->bhts", q_r.astype(jnp.float32), k_pe.astype(jnp.float32))
+    ) * scale
+    ok = keep[:, None]
+    s = jnp.where(ok, s, -1e30)
+    p = jnp.where(ok, jnp.exp(s - jnp.max(s, axis=-1, keepdims=True)), 0.0)
+    out = jnp.einsum("bhts,bsd->bthd", p, c)
+    den = jnp.swapaxes(p.sum(-1), 1, 2)[..., None]
+    return (out / jnp.maximum(den, 1e-30)).astype(q_c.dtype)
+
+
+def indexed_latent_decode_attention(q_c, q_r, q_idx, w_idx, kv_cache, idx_cache,
+                                    q_pos, topk: int, scale: float, kv_valid=None):
+    """Sparse ABSORBED latent attention of decode rows against an
+    :class:`IndexedLatentKVCache`: ``q_c`` (B, S, H, d_c) the heads' content
+    queries with ``W_uk`` folded in, ``q_r`` (B, S, H, d_r) their rotated
+    part, index queries and weights as :func:`indexed_decode_attention`; the
+    cache leaves ``kv`` (B, L, rows, lanes) and ``k_idx`` (B, L, 1, d_i).
+    Each row scores every valid column at or before its position, keeps the
+    ``topk`` best and attends those latents alone, softmax in float32 over
+    them: (B, S, H, d_c) for the caller's ``W_uv``.
+
+    Inside a :class:`fused_paged_attention_scope` the index-score kernel,
+    ``top_k`` and the sparse latent kernel run off the page pool; elsewhere
+    (a row cache) float32 einsums under :func:`sparse_keep_mask`."""
+    if _FUSED_PAGED_STACK:
+        return _fused_sparse_decode(
+            _FUSED_PAGED_STACK[-1], (q_c, q_r), q_idx, w_idx,
+            (kv_cache, idx_cache), q_pos, kv_valid, topk, latent_scale=scale,
+        )
+    b, s = q_c.shape[0], q_c.shape[1]
+    c, k_pe = split_latent(kv_cache, q_c.shape[-1], q_r.shape[-1])
+    valid = (jnp.ones(c.shape[:2], jnp.bool_) if kv_valid is None
+             else kv_valid.astype(jnp.bool_))
+    q_pos = q_pos[None] if q_pos.ndim == 0 else q_pos
+    pos = jnp.broadcast_to(q_pos.astype(jnp.int32)[None], (b, s))
+    with jax.named_scope(DSA_SCORE_SCOPE):
+        keep = sparse_keep_mask(q_idx, w_idx, idx_cache[:, :, 0], pos, valid, topk)
+    with jax.named_scope(DSA_ATTEND_SCOPE):
+        return _query_chunks(
+            lambda qc, qr, kc: _masked_latent_attention(qc, qr, c, k_pe, kc, scale),
+            (q_c, q_r, keep), s)
 
 
 class ParallelSelfAttention(nn.Module):

@@ -27,13 +27,19 @@ Reference strategies → TPU-native formulations:
   handful of tokens, gather just the k expert weight slices each token
   routed to and run per-token matmuls; FLOPs = k/E of dense and no
   dispatch machinery. Auto-selected when T <= selective_threshold.
+* HELD experts (``held_experts=(first, count)``; no reference counterpart):
+  this device's share of an expert-parallel deployment run WITHOUT its
+  exchange. The router's ids range over all ``num_experts``; the weights are
+  those of experts ``[first, first + count)`` alone, and the result is the
+  part of the routed sum those experts give: rows routed elsewhere add
+  nothing (``_held``). Dropless and exact for any routing.
 """
 
 from __future__ import annotations
 
 import functools
 from math import ceil
-from typing import Any, Optional
+from typing import Any, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -44,6 +50,12 @@ from neuronx_distributed_tpu.parallel import mesh as mesh_lib
 from neuronx_distributed_tpu.parallel.sharding import UNC, constrain
 
 Dtype = Any
+
+
+# rows of the expert-sorted slots a trip of the held experts' loop takes
+# (``ExpertMLPs._held``): its gathered tokens are this many x hidden, whatever
+# the prompt
+HELD_BLOCK_ROWS = 2048
 
 
 def _act(name: str):
@@ -212,6 +224,10 @@ class ExpertMLPs(nn.Module):
     # quantization_layers.py:867,:979 — the quantized-MoE serving case where
     # 1-byte expert weights are the HBM win)
     quantization_config: Optional[Any] = None
+    # ``(first, count)``: the weights are experts ``[first, first + count)``
+    # of ``num_experts`` (what one device of an expert-parallel deployment
+    # holds); ``top_e`` still ranges over all of them (``_held``)
+    held_experts: Optional[Tuple[int, int]] = None
 
     def _one_param(self, name, shape, partition, init):
         from neuronx_distributed_tpu.parallel.layers import _declare_kernel
@@ -231,6 +247,8 @@ class ExpertMLPs(nn.Module):
         )
 
         E, H, I = self.num_experts, self.hidden_size, self.intermediate_size
+        if self.held_experts is not None:
+            E = self.held_experts[1]
         init = nn.initializers.lecun_normal(batch_axis=(0,))
         up = self._one_param("up_proj", (E, H, I), COLUMN_KERNEL_PARTITION, init)
         gate = None
@@ -259,6 +277,11 @@ class ExpertMLPs(nn.Module):
         """``x (T, H)`` tokens, ``top_e (T, k)`` expert ids, ``top_w (T, k)``
         affinities → ``(T, H)`` combined expert outputs."""
         gate, up, down = self._params()
+        if self.held_experts is not None:
+            x = x.astype(self.dtype)
+            return self._held(
+                x, top_e, top_w, None if gate is None else gate.astype(self.dtype),
+                up.astype(self.dtype), down.astype(self.dtype))
         strategy = self._resolve_strategy(n_tokens=x.shape[0])
         if self.strategy == "auto" and not self.is_initializing():
             from neuronx_distributed_tpu.utils.logger import get_logger
@@ -287,6 +310,77 @@ class ExpertMLPs(nn.Module):
         if strategy == "selective":
             return self._selective(x, top_e, top_w, gate, up, down)
         raise ValueError(f"unknown expert strategy {strategy!r}")
+
+    # --- held experts: one device's share, without the exchange ---------------
+
+    def held_slots(self, top_e):
+        """``(local, held)`` of router ids ``top_e``: the id inside this
+        device's ``[first, first + count)`` and whether it lies there."""
+        first, count = self.held_experts
+        local = top_e - first
+        return local, (local >= 0) & (local < count)
+
+    def _held(self, x, top_e, top_w, gate, up, down):
+        """The held experts' part of ``sum_i w_i expert_i(x)``: ``x`` (T, H),
+        ``top_e``/``top_w`` (T, k) over ALL ``num_experts``. Dropless and
+        exact for any routing (every slot routed here is computed, however
+        many), a decode step's few rows and a prefill's alike: the slots are
+        sorted by held expert, absent ones last, and a loop takes
+        ``HELD_BLOCK_ROWS`` sorted rows a trip through the grouped matmul
+        (``ragged_dot``, which reads the weights of the experts that have
+        rows) for as many trips as HELD rows need: none where nobody chose a
+        held expert. The gathered tokens are ``HELD_BLOCK_ROWS x H``, never
+        ``T x k x H`` (1.6 GB at 16,384 tokens of 6144 top-8); with ``count /
+        num_experts`` of the slots held a prompt takes ``T k count /
+        (num_experts HELD_BLOCK_ROWS)`` trips, rounded up.
+        """
+        count = self.held_experts[1]
+        if mesh_lib.model_parallel_is_initialized() and (
+            mesh_lib.get_tensor_model_parallel_size() > 1
+            or mesh_lib.get_expert_model_parallel_size() > 1
+        ):
+            raise NotImplementedError(
+                "held_experts is one device's share run without a mesh: "
+                "under an ep axis the layer holds every expert and "
+                "_sharded_blockwise_mlp slices them")
+        T, H = x.shape
+        k = self.top_k
+        local, held = self.held_slots(top_e)
+        N = T * k
+        block = min(HELD_BLOCK_ROWS, N)
+        with jax.named_scope("moe.dispatch"):
+            key = jnp.where(held, local, count).reshape(-1)
+            order = jnp.argsort(key, stable=True)      # held slots first, by expert
+            token_idx = order // k
+            sizes = jnp.bincount(key, length=count + 1)[:count].astype(jnp.int32)
+            ends = jnp.cumsum(sizes)
+            n_held = ends[-1]
+            ws = top_w.reshape(-1)[order].astype(x.dtype)
+            pad = -N % block
+            token_idx = jnp.pad(token_idx, (0, pad))
+            ws = jnp.pad(ws, (0, pad))
+
+        def trip(state):
+            i, out = state
+            lo = i * block
+            with jax.named_scope("moe.dispatch"):
+                idx = jax.lax.dynamic_slice_in_dim(token_idx, lo, block)
+                w = jax.lax.dynamic_slice_in_dim(ws, lo, block)
+                # the rows of each held expert that fall in [lo, lo + block)
+                part = jnp.clip(ends, lo, lo + block) - jnp.clip(ends - sizes, lo, lo + block)
+                rows = x[idx]
+            with jax.named_scope("moe.experts"):
+                ys = _grouped_mlp(rows, gate if gate is not None else up, up, down,
+                                  part.astype(jnp.int32), glu=self.glu_mlp, act=self.hidden_act)
+            with jax.named_scope("moe.combine"):
+                live = (lo + jnp.arange(block) < n_held)[:, None]
+                out = out.at[idx].add(jnp.where(live, ys * w[:, None], 0))
+            return i + 1, out
+
+        _, out = jax.lax.while_loop(
+            lambda state: state[0] * block < n_held, trip,
+            (jnp.zeros((), jnp.int32), jnp.zeros((T, H), x.dtype)))
+        return out
 
     # --- strategy: selective loading (reference expert_mlps.py:319) -----------
 

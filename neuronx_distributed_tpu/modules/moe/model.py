@@ -68,6 +68,16 @@ class MoE(nn.Module):
     # ``n_shared_experts * moe_intermediate_size``) that every token goes
     # through, added to the routed sum; None: no such branch
     shared_intermediate_size: Optional[int] = None
+    # DeepSeek-V3's ``noaux_tc``: a learned bias chooses the experts and is no
+    # part of their weights (``RouterTopK.selection_bias``)
+    router_selection_bias: bool = False
+    router_selection_bias_init_std: float = 0.0
+    # ``(first, count)``: this device holds experts ``[first, first + count)``
+    # of ``num_experts`` and computes their part of the routed sum alone, plus
+    # the shared expert (``ExpertMLPs.held_experts``). The stats ``held_rows``
+    # and ``routed_rows`` (slots routed to a held expert / all slots) are sown
+    # into the ``stats`` collection for whoever makes it mutable
+    held_experts: Optional[Tuple[int, int]] = None
 
     @nn.compact
     def __call__(
@@ -87,6 +97,12 @@ class MoE(nn.Module):
         router_options = {}
         if not self.normalize_top_k_affinities:
             router_options["normalize_top_k_affinities"] = False
+        if self.router_selection_bias:
+            router_options.update(
+                selection_bias=True,
+                selection_bias_init_std=self.router_selection_bias_init_std,
+                # a share's biases, and so its rows, are the same under every key
+                selection_bias_init_group=self.held_experts[1] if self.held_experts else 0)
         router = make_router(
             self.router_kind,
             hidden_size=self.hidden_size,
@@ -107,7 +123,7 @@ class MoE(nn.Module):
         top_w = route.top_w
         if self.routed_scaling_factor != 1.0:
             top_w = top_w * self.routed_scaling_factor
-        out = ExpertMLPs(
+        experts = ExpertMLPs(
             num_experts=self.num_experts,
             hidden_size=self.hidden_size,
             intermediate_size=self.intermediate_size,
@@ -119,8 +135,16 @@ class MoE(nn.Module):
             dtype=self.dtype,
             param_dtype=self.param_dtype,
             quantization_config=self.quantization_config,
+            held_experts=self.held_experts,
             name="experts",
-        )(tokens, route.top_e, top_w)
+        )
+        out = experts(tokens, route.top_e, top_w)
+        if self.held_experts is not None and not self.is_initializing():
+            held = experts.held_slots(route.top_e)[1]
+            latest = dict(init_fn=lambda: jnp.zeros((), jnp.int32),
+                          reduce_fn=lambda _, new: new)
+            self.sow("stats", "held_rows", jnp.sum(held, dtype=jnp.int32), **latest)
+            self.sow("stats", "routed_rows", jnp.asarray(held.size, jnp.int32), **latest)
 
         if self.shared_intermediate_size is not None:
             with jax.named_scope("moe.shared"):

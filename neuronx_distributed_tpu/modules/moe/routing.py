@@ -18,6 +18,34 @@ from flax import linen as nn
 Dtype = Any
 
 
+def stratified_normal(std: float, group: int = 0):
+    """An initializer for an ``(E,)`` vector whose values are the SAME numbers
+    under every key: the ``E`` quantiles of ``N(0, std^2)`` at ``(i + 0.5) / E``.
+    Every run of ``group`` consecutive entries (the experts one device of an
+    expert-parallel layer holds) takes one quantile from each of ``group``
+    strata of ``n = E / group`` neighbours: run ``r`` the ``(r + n // 2) % n``-th
+    of each, so run 0 gets the strata's middles. The key only orders the values
+    INSIDE a run. So the multiset a run holds, and with random router weights
+    the rows routed to it, are the same for every key: an independent draw
+    gives one device anything from half to one and a half times its share of
+    the rows, and a benchmark that holds one share would then do another
+    amount of work under every seed. ``group`` 0: one run of all ``E``."""
+
+    def init(key, shape, dtype=jnp.float32):
+        (num,) = shape
+        g = group or num
+        if num % g:
+            raise ValueError(f"{num} values do not divide into runs of {g}")
+        n = num // g
+        strata = std * jax.scipy.special.ndtri(
+            (jnp.arange(num, dtype=jnp.float32) + 0.5) / num).reshape(g, n)
+        runs = strata[:, (jnp.arange(n) + n // 2) % n].T          # (n runs, g values)
+        runs = jax.random.permutation(key, runs, axis=1, independent=True)
+        return runs.reshape(num).astype(dtype)
+
+    return init
+
+
 class RouterOutput(NamedTuple):
     logits: jax.Array  # (T, E) fp32 pre-activation
     probs: jax.Array  # (T, E) fp32 activation output (aux-loss input)
@@ -76,17 +104,40 @@ class RouterTopK(RouterBase):
       * ``top_e (T, k)`` int32 — chosen expert ids,
       * ``top_w (T, k)`` fp32 — affinity weights, renormalized over the k
         chosen experts when ``normalize_top_k_affinities`` (reference option;
-        Mixtral semantics).
-    """
+        Mixtral semantics), under either activation.
+
+    ``selection_bias`` (DeepSeek-V3's ``noaux_tc``): a parameter
+    ``e_score_correction_bias`` (E,) is added to the activations to CHOOSE
+    the k experts and is no part of their weights, which are the plain
+    activations of the chosen. ``selection_bias_init_std``: the normal it is
+    drawn from at init (published checkpoints start it at zero and move it
+    outside the gradient; a benchmark with random weights draws it wide
+    enough to change selections), as :func:`stratified_normal` draws it: the
+    normal's quantiles, every ``selection_bias_init_group`` consecutive experts
+    (one device's share; 0: all) the same multiset under every key."""
 
     normalize_top_k_affinities: bool = True
+    selection_bias: bool = False
+    selection_bias_init_std: float = 0.0
+    selection_bias_init_group: int = 0
 
     @nn.compact
     def __call__(self, x: jax.Array, deterministic: bool = True) -> RouterOutput:
         logits = self._logits(x, deterministic)
         probs = self._activate(logits)
-        top_w, top_e = jax.lax.top_k(probs, self.top_k)
-        if self.normalize_top_k_affinities and self.act_fn == "softmax":
+        if self.selection_bias:
+            bias = self.param(
+                "e_score_correction_bias",
+                nn.with_partitioning(
+                    stratified_normal(self.selection_bias_init_std,
+                                      self.selection_bias_init_group), (None,)),
+                (self.num_experts,), jnp.float32,
+            )
+            _, top_e = jax.lax.top_k(probs + jnp.asarray(bias, jnp.float32), self.top_k)
+            top_w = jnp.take_along_axis(probs, top_e, axis=-1)
+        else:
+            top_w, top_e = jax.lax.top_k(probs, self.top_k)
+        if self.normalize_top_k_affinities:
             top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
         return RouterOutput(logits, probs, top_e.astype(jnp.int32), top_w)
 

@@ -595,7 +595,8 @@ class ServingEngine:
             tp = int(mesh.mesh.shape["tp"]) if hasattr(mesh, "mesh") else None
         # a latent (MLA) cache holds ONE row a token for all heads
         # (modules/attention.py LatentKVCache): nothing to shard over tp; an
-        # indexed cache's one index key a token likewise (IndexedKVCache)
+        # indexed cache's one index key a token likewise (IndexedKVCache), and
+        # an indexed latent cache is both (IndexedLatentKVCache)
         cache_kind = getattr(
             getattr(model, "config", None), "kv_cache_kind", "kv"
         )
@@ -834,7 +835,9 @@ class ServingEngine:
             ),
             decode_attention=(
                 {"latent": "paged_latent_fused",
-                 "indexed": "paged_sparse_fused"}.get(cache_kind, "paged_fused")
+                 "indexed": "paged_sparse_fused",
+                 "indexed_latent": "paged_sparse_latent_fused",
+                 }.get(cache_kind, "paged_fused")
                 if self.paged_attention == "fused"
                 else "einsum" if cache_kind != "kv"  # the one-row-a-token kinds' only other path
                 else resolve_decode_impl(max_seq_len)
@@ -3488,8 +3491,10 @@ class ServingEngine:
             try:
                 if self._faults is not None:
                     self._faults.on_dispatch(attempt)
+                # past the six: the model's own per-chunk counters
+                # (chunked_decode_step's ``chunk_stats``; most models: none)
                 (new_cache, self._state, toks, counts, used,
-                 key_snap) = self._nonspec_chunk()(
+                 key_snap, *model_stats) = self._nonspec_chunk()(
                     self._params, cache_in, self._state
                 )
             except Exception as e:
@@ -3515,10 +3520,14 @@ class ServingEngine:
             # silently demote the next chunk's keys donation to a copy
             # graftlint: ok[GL02] THE one per-chunk sync of the fused decode
             # contract (tests/serving/test_decode_chunking.py pins it at 1)
-            toks, counts, used, chunk_keys = jax.device_get(
-                (toks, counts, used, key_snap)
+            toks, counts, used, chunk_keys, *model_stats = jax.device_get(
+                (toks, counts, used, key_snap, *model_stats)
             )
-            sp.set_metadata(steps=int(used))
+            sp.set_metadata(steps=int(used), **{
+                name: int(value) for name, value in zip(
+                    getattr(self._decode_model, "chunk_stats", ()),
+                    model_stats[0] if model_stats else ())
+            })
         t2 = self._clock()
         with self._span(tracing.STEP_EMIT) as sp:
             self._emit_chunk(

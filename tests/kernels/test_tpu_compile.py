@@ -678,3 +678,114 @@ def test_keye_vl2_engine_programs_compile_and_fit(topo):
     # no S x S array wider than a byte
     wide = [m.group(0) for m in re.finditer(r"(f32|bf16|s32|u32)\[[\d,]*24576,24576\]", prefill.as_text())]
     assert not wide, wide[:3]
+
+
+def _glm5(cfg, seq):
+    """The benchmark configuration's model, from its file's ``model`` group
+    (the family file maps the published keys and the share's own)."""
+    from perfbench.families import glm_moe_dsa
+
+    return glm_moe_dsa.build(cfg, runner="serve", max_seq_len=seq)
+
+
+# what the described-v5e compile of the configured depth showed for the two
+# programs' temporaries (GiB; PR 32, the configuration's ``reduced_why``)
+GLM5_DECODE_TEMP_GIB = 0.94
+GLM5_PREFILL_TEMP_GIB = 4.46
+
+
+def test_sparse_latent_decode_kernel_compiles_at_glm5_geometry(topo):
+    """GLM-5's decode under its indexer at the serve cell's shapes (8 slots of
+    32,768 columns, page 16, 64 heads against ONE latent row of 512 + 64 a
+    token, 32 index heads of 128, 2048 kept): the index-score kernel over a
+    leaf of 128 lanes (whole tiles: no padded copy) and the sparse latent
+    kernel that fetches a token's joined (8, 128) tile with one copy. Neither
+    may copy its pool leaf whole. Five rows instead of eight are refused:
+    Mosaic copies whole HBM tiles, which is why the leaf has eight."""
+    from neuronx_distributed_tpu.kernels.flash_decode import (
+        paged_index_scores,
+        paged_sparse_latent_decode_attention,
+    )
+
+    s = _one_chip(topo)
+    b, n_log, page, keep, heads = 8, 2048, 16, 2048, 64
+    pages = b * n_log + 1
+    table, valid = s((b, n_log), jnp.int32), s((b, n_log * page), jnp.bool_)
+    text = _compiled_text(
+        lambda q, w, pool, bt, pos, ok: paged_index_scores(q, w, pool, bt, pos, ok, page_size=page),
+        s((b, 1, 32, 128)), s((b, 1, 32)), s((pages, page, 1, 128)), table, s((1,), jnp.int32), valid)
+    assert KERNEL in text
+    assert not re.search(r"bf16\[%d,%d,1,128\]\S* copy\(" % (pages, page), text), "the index-key leaf is copied whole"
+
+    def attend(rows):
+        return _compiled_text(
+            lambda qc, qr, kv, bt, cols, n: paged_sparse_latent_decode_attention(
+                qc, qr, kv, bt, cols, n, scale=0.0625, page_size=page),
+            s((b, 1, heads, 512)), s((b, 1, heads, 64)), s((pages, page, rows, 128)), table,
+            s((b, keep), jnp.int32), s((b,), jnp.int32))
+
+    text = attend(8)
+    assert KERNEL in text
+    assert not re.search(r"bf16\[%d,%d,8,128\]\S* copy\(" % (pages, page), text), "the latent pool leaf is copied whole"
+    with pytest.raises(Exception, match="aligned to tiling"):
+        attend(5)
+
+
+@pytest.mark.slow
+def test_glm5_engine_programs_compile_and_fit(topo):
+    """The benchmark configuration's programs (``perfbench/configs/
+    glm-5-serve.json``: its depth, its 8 held experts and vocabulary slice, 8
+    slots of 32,768, page 16): the fused decode chunk with the two-leaf pool
+    (the joined latent tile, the index keys) carried, and the longest
+    prompt's prefill under the byte mask with the held experts' sorted loop,
+    both with Pallas kernels and inside the chip's memory. The decode program
+    holds no row-sized array and converts no pool leaf's layout inside its
+    scan (PR 30's 2.7 GiB lesson); both programs' temporaries are what the
+    configuration's ``reduced_why`` states + 10%; the prefill builds no
+    ``tokens x top_k x hidden`` dispatch buffer."""
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    with open(os.path.join(root, "perfbench", "configs", "glm-5-serve.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(root, "perfbench", "traffic", "agentdocs_closed.json")) as f:
+        longest = int(json.load(f)["prompt_len"]["max"])
+    seq = int(config["serving"]["max_seq_len"])
+    engine, lower_decode, lower_prefill, pool_shards = _engine_programs(
+        topo, 1, slots=int(config["serving"]["num_slots"]), seq=seq,
+        bucket=longest, model=_glm5(config["model"], seq),
+    )
+    assert engine.programs.resolved == {
+        "attention": "flash", "decode_attention": "paged_sparse_latent_fused",
+        "paged_attention": "fused",
+    }
+    assert sorted(set(pool_shards)) == [(16385, 16, 1, 128), (16385, 16, 8, 128)]
+    decode = lower_decode().compile()
+    assert KERNEL in decode.as_text()
+    assert _fits(decode, 15 * 1024**3)
+    # as _assert_pool_carried_and_no_view, but a view is looked for at the
+    # JOINED leaf's size alone: the index keys' logical view holds as many
+    # values (8 x 32,768 x 128) as W_q_b (2048 x 16,384), which IS there
+    text = decode.as_text()
+    shapes = {"bf16[%s]" % ",".join(map(str, s)) for s in pool_shards}
+    copies = _copies_inside_loops(text, shapes)
+    assert not copies, f"{len(copies)} whole-pool copies per decode step: " + "; ".join(copies[:3])
+    assert not [ln for ln in text.splitlines() if " copy(" in ln and "bf16[16385,16," in ln], "a pool leaf is copied"
+    views = _arrays_of_a_views_size(text, [(16385, 16, 8, 128)])
+    assert not views, f"the decode program builds a logical latent view: {views}"
+    temp = decode.memory_analysis().temp_size_in_bytes
+    assert temp < 1.1 * GLM5_DECODE_TEMP_GIB * 2**30, f"{temp / 2**30:.2f} GiB of decode temporaries"
+    prefill = lower_prefill().compile()
+    assert KERNEL in prefill.as_text()
+    assert _fits(prefill, 15 * 1024**3)
+    temp = prefill.memory_analysis().temp_size_in_bytes
+    assert temp < 1.1 * GLM5_PREFILL_TEMP_GIB * 2**30, f"{temp / 2**30:.2f} GiB of prefill temporaries"
+    text = prefill.as_text()
+    # no S x S array of scores or keys (4 bytes a pair; a bf16 array of that
+    # shape is q itself: 64 heads x 256 = 16,384 = the longest prompt), no
+    # tokens x top_k x hidden gather
+    wide = [m.group(0) for m in re.finditer(r"(f32|s32|u32)\[[\d,]*%d,%d\]" % (longest, longest), text)]
+    assert not wide, wide[:3]
+    hidden = int(config["model"]["hidden_size"])
+    slots_rows = longest * int(config["model"]["num_experts_per_tok"])
+    assert not re.search(r"bf16\[%d,%d\]" % (slots_rows, hidden), text), "a T x k x H dispatch buffer"

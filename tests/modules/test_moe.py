@@ -336,3 +336,147 @@ def test_zero1_spec_skips_param_sharded_axes():
     NamedSharding(mesh, spec)
     flat = [a for e in spec if e for a in (e if isinstance(e, tuple) else (e,))]
     assert flat.count("ep") == 1
+
+
+# --- a selection bias, sigmoid weights, and an expert layer told its share ------
+
+
+def _sigmoid_router(**kw):
+    return RouterTopK(hidden_size=H, num_experts=16, top_k=4, act_fn="sigmoid", **kw)
+
+
+def test_router_selection_bias_selects_and_does_not_weigh():
+    x = jax.random.normal(jax.random.PRNGKey(0), (T, H))
+    router = _sigmoid_router(selection_bias=True, selection_bias_init_std=0.3,
+                             normalize_top_k_affinities=False)
+    params = router.init(jax.random.PRNGKey(1), x)
+    bias = np.asarray(params["params"]["e_score_correction_bias"].value)
+    out = router.apply(params, x)
+    probs = np.asarray(out.probs)
+    want = np.argsort(-(probs + bias), axis=-1, kind="stable")[:, :4]
+    np.testing.assert_array_equal(np.sort(want, -1), np.sort(np.asarray(out.top_e), -1))
+    # the bias changed the choice somewhere, or the test would hold nothing
+    plain = np.argsort(-probs, axis=-1, kind="stable")[:, :4]
+    assert (np.sort(plain, -1) != np.sort(want, -1)).any()
+    # the weights are the plain sigmoids of the chosen: no bias in them
+    np.testing.assert_allclose(
+        np.asarray(out.top_w), np.take_along_axis(probs, np.asarray(out.top_e), -1), rtol=1e-6)
+
+
+@pytest.mark.parametrize("group", [0, 4])
+def test_selection_bias_draw_is_the_same_numbers_under_every_key(group):
+    """The normal's quantiles, whatever the key; with a share size, every run
+    of that many experts holds one value of each stratum, the same multiset
+    under every key (so the rows routed to a share do not depend on the seed),
+    in an order the key chooses."""
+    from statistics import NormalDist
+
+    from neuronx_distributed_tpu.modules.moe.routing import stratified_normal
+
+    draws = [np.asarray(stratified_normal(0.3, group)(jax.random.PRNGKey(k), (16,))) for k in (1, 2)]
+    want = [0.3 * NormalDist().inv_cdf((i + 0.5) / 16) for i in range(16)]
+    for d in draws:
+        np.testing.assert_allclose(np.sort(d), want, rtol=1e-4, atol=1e-6)
+    assert (draws[0] != draws[1]).any()
+    if group:
+        runs = [np.sort(d.reshape(-1, group), -1) for d in draws]
+        np.testing.assert_array_equal(runs[0], runs[1])
+        # run 0 holds the middle of each stratum of 16 / 4 = 4 quantiles
+        np.testing.assert_allclose(runs[0][0], [want[4 * j + 2] for j in range(4)], rtol=1e-4, atol=1e-6)
+    with pytest.raises(ValueError, match="do not divide"):
+        stratified_normal(0.3, 5)(jax.random.PRNGKey(0), (16,))
+
+
+@pytest.mark.parametrize("bias", [False, True])
+def test_router_sigmoid_weights_renormalise_when_asked(bias):
+    x = jax.random.normal(jax.random.PRNGKey(0), (T, H))
+    router = _sigmoid_router(selection_bias=bias, selection_bias_init_std=0.3)
+    params = router.init(jax.random.PRNGKey(1), x)
+    out = router.apply(params, x)
+    np.testing.assert_allclose(np.asarray(out.top_w.sum(-1)), 1.0, rtol=1e-5)
+    raw = np.take_along_axis(np.asarray(out.probs), np.asarray(out.top_e), -1)
+    np.testing.assert_allclose(np.asarray(out.top_w), raw / raw.sum(-1, keepdims=True), rtol=1e-5)
+    loose = _sigmoid_router(selection_bias=bias, selection_bias_init_std=0.3,
+                            normalize_top_k_affinities=False).apply(params, x)
+    np.testing.assert_allclose(np.asarray(loose.top_w), raw, rtol=1e-6)
+
+
+def _share_layer(held, experts=16, **kw):
+    return MoE(
+        num_experts=experts, hidden_size=H, intermediate_size=I, top_k=4,
+        router_act_fn="sigmoid", router_selection_bias=True,
+        router_selection_bias_init_std=0.3, routed_scaling_factor=2.5,
+        shared_intermediate_size=I, expert_strategy="all_experts",
+        held_experts=held, dtype=jnp.float32, **kw)
+
+
+def _slice_share(full, first, count):
+    """The parameters one share holds of the uncut layer's."""
+    import flax
+
+    share = flax.core.unfreeze(full)
+    exp = share["params"]["experts"]
+    for name in exp:
+        leaf = exp[name]
+        value = leaf.value if hasattr(leaf, "value") else leaf
+        cut = value[first:first + count]
+        exp[name] = leaf.replace_boxed(cut) if hasattr(leaf, "replace_boxed") else cut
+    return share
+
+
+@pytest.mark.parametrize("tokens", [(1, 6), (2, 40)], ids=["decode_rows", "prefill_rows"])
+def test_all_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(tokens):
+    """Guide section 4: 16 experts over 4 devices, 4 held each. The routed
+    parts that the four shares give, plus the shared expert counted once,
+    are the uncut layer's output, for a decode step's rows and a prefill's."""
+    x = jax.random.normal(jax.random.PRNGKey(0), tokens + (H,), jnp.float32)
+    uncut = _share_layer(None)
+    params = uncut.init(jax.random.PRNGKey(1), x)
+    want, _ = uncut.apply(params, x)
+    total, held_rows = 0.0, 0
+    for dev in range(4):
+        layer = _share_layer((4 * dev, 4))
+        p = _slice_share(params, 4 * dev, 4)
+        (out, _), stats = layer.apply(p, x, mutable=["stats"])
+        total = total + out
+        held_rows += int(stats["stats"]["held_rows"])
+        assert int(stats["stats"]["routed_rows"]) == tokens[0] * tokens[1] * 4
+    assert held_rows == tokens[0] * tokens[1] * 4     # every slot is held by exactly one share
+    # every share added the shared expert: count it once
+    only_shared = _shared_only(_share_layer((0, 4)), _slice_share(params, 0, 4), x)
+    np.testing.assert_allclose(
+        np.asarray(total - 3 * only_shared), np.asarray(want), rtol=2e-5, atol=2e-5)
+
+
+def _shared_only(layer, params, x):
+    """The shared expert's output alone: the layer with its held experts'
+    down projections zeroed."""
+    import flax
+
+    p = flax.core.unfreeze(params)
+    leaf = p["params"]["experts"]["down_proj"]
+    p["params"]["experts"]["down_proj"] = jax.tree.map(jnp.zeros_like, leaf)
+    return layer.apply(p, x)[0]
+
+
+@pytest.mark.parametrize("rows", [6, 40], ids=["decode_rows", "prefill_rows"])
+def test_every_token_routed_to_held_experts_is_computed_without_a_drop(rows, monkeypatch):
+    """Any routing is exact: ALL slots on the held experts (four times the
+    share a uniform router would send), in trips of 16 sorted rows."""
+    from neuronx_distributed_tpu.modules.moe import expert_mlps
+
+    monkeypatch.setattr(expert_mlps, "HELD_BLOCK_ROWS", 16)
+    mlps = ExpertMLPs(num_experts=16, hidden_size=H, intermediate_size=I, top_k=4,
+                      held_experts=(8, 4), dtype=jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(0), (rows, H), jnp.float32)
+    top_e = 8 + jnp.stack([jnp.roll(jnp.arange(4), i) for i in range(rows)]).astype(jnp.int32)
+    top_w = jax.nn.softmax(jax.random.normal(jax.random.PRNGKey(2), (rows, 4)))
+    params = mlps.init(jax.random.PRNGKey(3), x, top_e, top_w)
+    got = mlps.apply(params, x, top_e, top_w)
+    golden = ExpertMLPs(num_experts=4, hidden_size=H, intermediate_size=I, top_k=4,
+                        strategy="all_experts", dtype=jnp.float32)
+    want = golden.apply(params, x, top_e - 8, top_w)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
+    # and a routing that sends nothing here gives exactly nothing
+    none = mlps.apply(params, x, top_e - 8, top_w)
+    assert not np.asarray(none).any()

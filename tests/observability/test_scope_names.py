@@ -174,3 +174,64 @@ def test_the_dispatch_span_carries_the_selection_stats():
     got = stats(KeyeVL2ForCausalLM(tiny_keye_vl2(), attention_impl="xla"), (40, 9))
     assert got == {"ctx_tokens": 41 + 10, "selected_tokens": 16 + 10}
     assert stats(MixtralForCausalLM(tiny_mixtral(), attention_impl="xla"), (12,)) == {}
+
+
+@pytest.fixture(scope="module")
+def glm():
+    return _programs("glm-5-serve")
+
+
+def test_the_sparse_latent_attention_programs_carry_both_blocks_names(glm):
+    """GLM-5's programs answer to BOTH sets of readers: ``mla_block_dev_share_pct``
+    finds ``mla.compress``, ``mla.absorb`` (decode) and ``mla.expand``
+    (prefill); ``dsa_block_dev_share_pct`` finds ``dsa.index``, ``dsa.score``,
+    ``dsa.select``, ``dsa.attend`` and ``dsa.write``, each decode kernel
+    called in ITS scope (``dsa_index_roofline`` reads ``dsa.score``,
+    ``dsa_latent_attend_roofline`` ``dsa.attend``, no ``attn._cached_attention``
+    between); ``moe_block_dev_share_pct.tpot`` the held experts' path and the
+    shared expert under ``moe``."""
+    decode, prefill = glm["decode_chunk"], glm["prefill"]
+    assert re.search(r"HloModule (\S+?),", decode[1]).group(1) == "jit_chunk_fn"
+    assert re.search(r"HloModule (\S+?),", prefill[1]).group(1) == "jit_fn"
+    callers = {path.rsplit("/", 1)[-1] for path in KERNEL_CALL.findall(decode[0])}
+    assert callers == {"dsa.score", "dsa.attend", "dsa.write"}
+    dsa = {"dsa.index", "dsa.score", "dsa.select", "dsa.attend"}
+    assert dsa | {"mla.compress", "mla.absorb", "attn", "kv_view", "moe", "moe.router", "moe.dispatch",
+                  "moe.experts", "moe.shared", "sample", "lm_head"} <= _traced(decode)
+    assert dsa | {"mla.compress", "mla.absorb", "moe", "moe.shared", "moe.experts"} <= _optimized(decode)
+    assert "mla.expand" not in _traced(decode) and "attn._cached_attention" not in _traced(decode)
+    assert {"dsa.index", "dsa.score", "dsa.attend", "mla.compress", "mla.expand", "moe", "moe.router",
+            "moe.dispatch", "moe.experts", "moe.combine", "moe.shared"} <= _traced(prefill)
+    assert {"dsa.index", "dsa.score", "mla.compress", "mla.expand", "moe.shared"} <= _optimized(prefill)
+    assert "dsa.select" not in _traced(prefill) and "mla.absorb" not in _traced(prefill)
+
+
+def test_the_readback_span_carries_the_rows_the_held_experts_computed(tmp_path):
+    """``moe_held_rows_per_step`` reads ``held_rows`` / ``routed_rows`` off
+    ``nxd.step.decode.readback`` beside ``steps``: summed on the device over
+    the chunk's steps and sparse layers, read back with its tokens; a model
+    whose expert layers hold every expert has neither."""
+    import dataclasses
+
+    from neuronx_distributed_tpu.models.glm_moe_dsa import GlmMoeDsaForCausalLM, tiny_glm_moe_dsa
+    from neuronx_distributed_tpu.utils.timeline import Timeline
+
+    def stats(cfg, tmp):
+        model = GlmMoeDsaForCausalLM(cfg, attention_impl="xla")
+        params = jax.jit(model.init)(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+        timeline = Timeline(str(tmp))
+        engine = ServingEngine(model, params, num_slots=2, kv_page_size=16, decode_chunk_size=4, timeline=timeline)
+        for p in (20, 9):
+            engine.submit(np.arange(1, 1 + p, dtype=np.int32), GenerationConfig(max_new_tokens=6, temperature=0.0))
+        engine.run()
+        return [e["args"] for e in timeline._events if e.get("name") == "nxd.step.decode.readback" and e.get("args")]
+
+    cfg = tiny_glm_moe_dsa()
+    held = stats(dataclasses.replace(cfg, held_experts=(4, 8)), tmp_path / "held.json")
+    assert held and all(set(a) == {"steps", "held_rows", "routed_rows"} for a in held)
+    sparse_layers = cfg.num_layers - cfg.first_k_dense
+    for a in held:
+        assert a["routed_rows"] == a["steps"] * sparse_layers * 2 * cfg.top_k
+        assert 0 <= a["held_rows"] <= a["routed_rows"]
+    assert sum(a["held_rows"] for a in held) > 0
+    assert all(set(a) == {"steps"} for a in stats(cfg, tmp_path / "whole.json"))

@@ -98,12 +98,30 @@ DSA = chip_smoke.DsaSize(
 )
 
 
+def _tiny_glm():
+    import json
+
+    path = os.path.join(os.path.dirname(_PATH), "tests", "benchmark", "data", "configs", "glm-5-serve.json")
+    with open(path) as f:
+        return json.load(f)["model"]
+
+
+# GLM-5's structure at the CPU stand-in's size (32 columns kept, 8 of 16
+# experts held); float32, so the tolerances are a float32 matmul's summation order
+GLM = chip_smoke.GlmSize(
+    model=_tiny_glm(), max_seq_len=512, slots=2, prompt_lens=(300, 128, 40),
+    tail=32, new_tokens=12, kernel_contexts=(70, 200, 20), kernel_calls=2, kernel_tol=1e-4,
+    logit_tol=1e-3, typical_tol=1e-4, selected_tol=1e-3, attn_tol=1e-4, routed_tol=1e-4, latent_tol=1e-5, gap_tol=1e-3,
+    near_tie=0.0,
+)
+
+
 def test_one_chip_run_rehearsal():
     """Train, serve, then the MLA model in ONE process, exactly as ``main()``
     runs them (the train phase's global mesh must not leak into the
     mesh-free engines)."""
     _assert_only_kernel_checks_fail(
-        chip_smoke.one_chip(0, jax.devices()[:1], TRAIN, SERVE, MLA, dsa=DSA)
+        chip_smoke.one_chip(0, jax.devices()[:1], TRAIN, SERVE, MLA, dsa=DSA, glm=GLM)
     )
 
 
@@ -116,6 +134,12 @@ def test_mla_phase_alone_rehearsal():
 def test_dsa_phase_alone_rehearsal():
     _assert_only_kernel_checks_fail(
         chip_smoke.one_chip(0, jax.devices()[:1], TRAIN, SERVE, MLA, only="dsa", dsa=DSA)
+    )
+
+
+def test_glm_phase_alone_rehearsal():
+    _assert_only_kernel_checks_fail(
+        chip_smoke.one_chip(0, jax.devices()[:1], TRAIN, SERVE, MLA, only="glm", glm=GLM)
     )
 
 
