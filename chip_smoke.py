@@ -66,6 +66,14 @@ and is printed. Weights and requests come from ``--seed``.
          selection bias added to the weights. ``--only glm`` runs this phase
          alone.
 
+``--only moe`` (not part of the default run: it serves nothing) runs the
+streamed expert MLP (``kernels/moe_stream.py``) alone at the expert shapes of
+DeepSeek-V2-Lite, Keye-VL-2.0 and Mixtral-8x7B: against the float32 ``jnp``
+routed sum and against the grouped-matmul (``ragged_dot``) form, with two
+controls that must fail (the experts' weights in float8; one hit expert
+dropped), then ms a call of both forms over 1-256 rows: the sweep that sets
+``modules/moe/expert_mlps.MOE_STREAM_MAX_TOKENS``.
+
 ``--chips 4`` runs only the four-chip path and what it is compared with: a
 tp=4 + sequence-parallel train step against the same seeded step on one
 device of the same process, and ``ServingEngine(tp=4)`` against the
@@ -85,6 +93,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import gc
 import json
 import math
@@ -134,6 +143,26 @@ class ServeSize:
     # near 1 and a median top-1/top-2 margin of 0.03-0.3; the largest gap
     # seen on the chip is 0.009 (tp=4, 8 layers), a wrong token costs > 4
     logit_tol: float = 0.1
+
+
+@dataclasses.dataclass(frozen=True)
+class MoeSize:
+    """What ``--only moe`` runs (defaults: the chip run): ``(name, experts,
+    hidden, intermediate, top_k, rows of the cell's decode step)`` from the
+    three published configurations."""
+
+    shapes: Tuple[Tuple[str, int, int, int, int, int], ...] = (
+        ("deepseek-v2-lite", 64, 2048, 1408, 6, 8),
+        ("keye-vl2", 128, 2048, 768, 8, 8),
+        ("mixtral-8x7b", 8, 4096, 14336, 2, 16),
+    )
+    tokens: Tuple[int, ...] = (1, 8, 16, 32, 64, 128, 256)
+    calls: int = 20
+    dtype: str = "bfloat16"
+    # the worst row's |form - float32 jnp| / |float32 jnp| (L2 over the hidden
+    # vector) at the cell's rows, between the system's reading and the
+    # controls' (PERF.md section 6, PR 33, has the readings)
+    routed_tol: float = 0.015
 
 
 def log(msg: str) -> None:
@@ -933,7 +962,7 @@ def mla_phase(size: MlaSize, seed: int) -> Dict[str, bool]:
         "mla_matches_reference": ok,
         "mla_resolved_latent_fused": resolved == {
             "attention": "flash", "decode_attention": "paged_latent_fused",
-            "paged_attention": "fused",
+            "paged_attention": "fused", "moe_decode": "stream",
         },
         "mla_cache_is_latent_sized": per_token == want_bytes,
         "mla_float8_latent_is_caught": caught.get("float8 latent", False),
@@ -1232,7 +1261,7 @@ def dsa_phase(size: DsaSize, seed: int) -> Dict[str, bool]:
         and near <= size.index_near_tie,
         "dsa_resolved_sparse_fused": resolved == {
             "attention": "flash", "decode_attention": "paged_sparse_fused",
-            "paged_attention": "fused",
+            "paged_attention": "fused", "moe_decode": "stream",
         },
         "dsa_cache_is_indexed_sized": per_token == want_bytes,
         "dsa_float8_index_keys_are_caught": caught.get("float8 index keys", False),
@@ -1501,7 +1530,7 @@ def glm_phase(size: GlmSize, seed: int) -> Dict[str, bool]:
         "glm_attention_routed_sum_and_cache_alone_match_reference": blocks_ok,
         "glm_resolved_sparse_latent_fused": resolved == {
             "attention": "flash", "decode_attention": "paged_sparse_latent_fused",
-            "paged_attention": "fused",
+            "paged_attention": "fused", "moe_decode": "held",
         },
         "glm_cache_is_a_tile_and_an_index_key": per_token == want_bytes,
         "glm_float8_index_keys_are_caught": caught.get("float8 index keys", False),
@@ -1512,20 +1541,109 @@ def glm_phase(size: GlmSize, seed: int) -> Dict[str, bool]:
     }
 
 
+def moe_phase(size: MoeSize, seed: int) -> Dict[str, bool]:
+    """The streamed expert MLP alone, a call = one layer of a decode step:
+    against the float32 routed sum, the grouped-matmul form and two controls,
+    then both forms' ms a call over ``size.tokens`` rows."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from neuronx_distributed_tpu.kernels.moe_stream import hit_experts, moe_stream_mlp
+    from neuronx_distributed_tpu.modules.moe.expert_mlps import (
+        MOE_STREAM_MAX_TOKENS,
+        _ragged_routed_mlp,
+    )
+
+    dtype = jnp.dtype(size.dtype)
+    f32 = lambda a: a.astype(jnp.float32)    # noqa: E731
+    stream = moe_stream_mlp
+    ragged = functools.partial(_ragged_routed_mlp, act="silu")
+
+    @jax.jit
+    def exact(x, e, w, g, u, d):
+        """float32 ``jnp``, expert by expert (no (T, E, I) array at 14336)."""
+        def one(acc, args):
+            i, g_, u_, d_ = args
+            y = (jax.nn.silu(f32(x) @ f32(g_)) * (f32(x) @ f32(u_))) @ f32(d_)
+            return acc + y * jnp.sum(jnp.where(e == i, w, 0.0), axis=1)[:, None], None
+        with jax.default_matmul_precision("highest"):
+            return jax.lax.scan(one, jnp.zeros(x.shape, jnp.float32),
+                                (jnp.arange(u.shape[0]), g, u, d))[0]
+
+    def worst_row(got, want):
+        return float(jnp.max(jnp.linalg.norm(f32(got) - want, axis=1) / jnp.linalg.norm(want, axis=1)))
+
+    checks: Dict[str, bool] = {}
+    wins: Dict[str, Dict[int, bool]] = {}
+    for name, n_e, hid, inter, k, cell_rows in size.shapes:
+        keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+        gate = jax.random.normal(keys[0], (n_e, hid, inter), dtype) * hid ** -0.5
+        up = jax.random.normal(keys[1], (n_e, hid, inter), dtype) * hid ** -0.5
+        down = jax.random.normal(keys[2], (n_e, inter, hid), dtype) * inter ** -0.5
+
+        def routed(rows):
+            x = jax.random.normal(jax.random.fold_in(keys[3], rows), (rows, hid), dtype)
+            top_w, top_e = jax.lax.top_k(jax.nn.softmax(
+                jax.random.normal(jax.random.fold_in(keys[4], rows), (rows, n_e))), k)
+            return x, top_e, top_w
+
+        x, top_e, top_w = routed(cell_rows)
+        want = exact(x, top_e, top_w, gate, up, down)
+        ids, count = hit_experts(top_e, n_e, min(n_e, cell_rows * k))
+        dropped = jnp.where(top_e == ids[0], 0.0, top_w)   # the first hit expert's rows lose it
+        as_f8 = lambda a: a.astype(jnp.float8_e4m3fn).astype(dtype)   # noqa: E731
+        reads = {
+            "stream": worst_row(jax.jit(stream)(x, top_e, top_w, gate, up, down), want),
+            "ragged_dot": worst_row(jax.jit(ragged)(x, top_e, top_w, gate, up, down), want),
+            "float8 weights": worst_row(jax.jit(stream)(
+                x, top_e, top_w, as_f8(gate), as_f8(up), as_f8(down)), want),
+            "one hit expert dropped": worst_row(jax.jit(stream)(x, top_e, dropped, gate, up, down), want),
+        }
+        log(f"moe {name}: {n_e} experts of {hid} x {inter}, top-{k}, {cell_rows} rows hit {int(count)}: worst row's "
+            f"|form - float32 jnp| / |float32 jnp| " + ", ".join(f"{n} {v:.4f}" for n, v in reads.items())
+            + f" (limit {size.routed_tol})")
+        checks[f"moe_{name}_stream_matches_jnp"] = reads["stream"] <= size.routed_tol
+        checks[f"moe_{name}_ragged_dot_matches_jnp"] = reads["ragged_dot"] <= size.routed_tol
+        checks[f"moe_{name}_float8_weights_are_caught"] = reads["float8 weights"] > size.routed_tol
+        checks[f"moe_{name}_a_dropped_expert_is_caught"] = reads["one hit expert dropped"] > size.routed_tol
+
+        wins[name] = {}
+        expert_bytes = 3 * hid * inter * dtype.itemsize
+        for rows in size.tokens:
+            x, top_e, top_w = routed(rows)
+            hit = int(hit_experts(top_e, n_e, min(n_e, rows * k))[1])
+            ms = {form: _median_call_ms(fn, (x, top_e, top_w, gate, up, down), size.calls)
+                  for form, fn in (("stream", stream), ("ragged_dot", ragged))}
+            wins[name][rows] = ms["stream"] < ms["ragged_dot"]
+            log(f"moe {name}: {rows} rows, {hit} hit ({hit * expert_bytes / 1e6:.0f} MB): "
+                + ", ".join(f"{form} {v:.3f} ms a call ({hit * expert_bytes / v / 1e6:.0f} GB/s)"
+                            for form, v in ms.items()))
+        del gate, up, down
+    # the rule the layer applies: the streamed form up to MOE_STREAM_MAX_TOKENS rows
+    for name, by_rows in wins.items():
+        checks[f"moe_{name}_stream_wins_where_the_rule_takes_it"] = all(
+            won for rows, won in by_rows.items() if rows <= MOE_STREAM_MAX_TOKENS)
+    return checks
+
+
 def one_chip(seed: int, devices, train: TrainSize = TrainSize(),
              serve: ServeSize = ServeSize(), mla: MlaSize = MlaSize(),
              only: str = "all", dsa: DsaSize = DsaSize(),
-             glm: GlmSize = GlmSize()) -> Dict[str, bool]:
+             glm: GlmSize = GlmSize(), moe: MoeSize = MoeSize()) -> Dict[str, bool]:
     """The default run: train, then serve, then the MLA model, the
     sparse-attention model and GLM-5's (sparse selection among latents), in
     one process on one device; ``only="mla"``, ``"dsa"`` or ``"glm"``: that
-    phase alone."""
+    phase alone; ``only="moe"``: the streamed expert MLP alone, which no
+    other phase repeats."""
     if only == "mla":
         return mla_phase(mla, seed)
     if only == "dsa":
         return dsa_phase(dsa, seed)
     if only == "glm":
         return glm_phase(glm, seed)
+    if only == "moe":
+        return moe_phase(moe, seed)
     return {**train_phase(train, seed, devices), **serve_phase(serve, seed),
             **mla_phase(mla, seed), **dsa_phase(dsa, seed), **glm_phase(glm, seed)}
 
@@ -1546,9 +1664,10 @@ def parse_args(argv=None):
                         "tp=4 train step and tp=4 engine and their "
                         "one-device counterparts")
     p.add_argument("--seed", type=int, default=0)
-    p.add_argument("--only", default="all", choices=("all", "mla", "dsa", "glm"),
+    p.add_argument("--only", default="all", choices=("all", "mla", "dsa", "glm", "moe"),
                    help="one chip: every phase (default), or the MLA, the "
-                        "sparse-attention (dsa) or the GLM-5 (glm) phase alone")
+                        "sparse-attention (dsa) or the GLM-5 (glm) phase alone; "
+                        "moe: the streamed expert MLP's checks and sweep")
     return p.parse_args(argv)
 
 

@@ -158,7 +158,9 @@ def chunked_decode_step(decode_model, chunk_size: int, max_seq_len: int,
 
     A model that names ``chunk_stats`` (int32 scalars its layers sow into the
     ``stats`` collection each step: ``models/glm_moe_dsa.py``'s rows its held
-    experts computed) gets a seventh output, an int32 vector of their sums
+    experts computed; the distinct experts a step's rows hit in the models
+    whose layers hold every expert, ``modules/moe.MOE_CHUNK_STATS``) gets a
+    seventh output, an int32 vector of their sums
     over the chunk's executed steps and the layers, in that order; for every
     other model the program is what it was."""
     from neuronx_distributed_tpu.inference.utils import unwrap_logits

@@ -67,7 +67,7 @@ from neuronx_distributed_tpu.modules.attention import (
     latent_decode_attention,
     prefill_positions,
 )
-from neuronx_distributed_tpu.modules.moe import MoE
+from neuronx_distributed_tpu.modules.moe import MoE, moe_chunk_stats
 from neuronx_distributed_tpu.modules.rms_norm import RMSNorm
 from neuronx_distributed_tpu.parallel import mesh as mesh_lib
 from neuronx_distributed_tpu.parallel.layers import (
@@ -430,6 +430,9 @@ class DeepseekV2ForCausalLM(nn.Module):
     config: DeepseekV2Config
     attention_impl: str = "auto"
     mode: str = "train"
+
+    # the expert layers' per-step counters, which a decode chunk sums
+    chunk_stats = property(lambda self: moe_chunk_stats(self.config))
 
     @nn.compact
     def __call__(self, input_ids, positions=None, deterministic: bool = True,

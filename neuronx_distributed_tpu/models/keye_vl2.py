@@ -63,7 +63,7 @@ from neuronx_distributed_tpu.modules.attention import (
     prefill_positions,
     sparse_prefill_attention,
 )
-from neuronx_distributed_tpu.modules.moe import MoE
+from neuronx_distributed_tpu.modules.moe import MoE, moe_chunk_stats
 from neuronx_distributed_tpu.modules.qkv_linear import GQAQKVColumnParallelLinear
 from neuronx_distributed_tpu.modules.rms_norm import RMSNorm
 from neuronx_distributed_tpu.parallel import mesh as mesh_lib
@@ -323,6 +323,9 @@ class KeyeVL2ForCausalLM(nn.Module):
     config: KeyeVL2Config
     attention_impl: str = "auto"
     mode: str = "train"
+
+    # the expert layers' per-step counters, which a decode chunk sums
+    chunk_stats = property(lambda self: moe_chunk_stats(self.config))
 
     @nn.compact
     def __call__(self, input_ids, positions=None, deterministic: bool = True,

@@ -24,7 +24,7 @@ from neuronx_distributed_tpu.models.llama import (
     LlamaConfig,
     rope_frequencies,
 )
-from neuronx_distributed_tpu.modules.moe import MoE
+from neuronx_distributed_tpu.modules.moe import MoE, moe_chunk_stats
 from neuronx_distributed_tpu.modules.rms_norm import RMSNorm
 from neuronx_distributed_tpu.parallel.layers import (
     ColumnParallelLinear,
@@ -232,6 +232,9 @@ class MixtralForCausalLM(nn.Module):
     config: MixtralConfig
     attention_impl: str = "auto"
     mode: str = "train"
+
+    # the expert layers' per-step counters, which a decode chunk sums
+    chunk_stats = property(lambda self: moe_chunk_stats(self.config))
 
     @nn.compact
     def __call__(

@@ -22,7 +22,11 @@ Reference strategies → TPU-native formulations:
   With ep > 1 each ep rank rolls the expert-sorted rows to its own experts'
   segment, runs the grouped matmul on its E/ep local experts, and the
   combine is a psum over ep (the reference's blockwise NKI path composes
-  with EP the same way, blockwise.py:434).
+  with EP the same way, blockwise.py:434). A step's FEW rows on the TPU
+  (``blockwise_form``: mesh-free, float weights, at most
+  ``MOE_STREAM_MAX_TOKENS`` rows) go through ``kernels/moe_stream.py``
+  instead: every row through each HIT expert, whose weights are streamed
+  once, and no sort, gather or scatter of rows.
 * ``forward_selective_loading`` (expert_mlps.py:319): decode path — for a
   handful of tokens, gather just the k expert weight slices each token
   routed to and run per-token matmuls; FLOPs = k/E of dense and no
@@ -46,10 +50,19 @@ import jax.numpy as jnp
 from flax import linen as nn
 from jax.sharding import PartitionSpec as P
 
+from neuronx_distributed_tpu.kernels import backend
+from neuronx_distributed_tpu.kernels.moe_stream import moe_stream_mlp
 from neuronx_distributed_tpu.parallel import mesh as mesh_lib
 from neuronx_distributed_tpu.parallel.sharding import UNC, constrain
 
 Dtype = Any
+
+# rows of a step at or below which the blockwise strategy streams the hit
+# experts (``kernels/moe_stream.py``) where it would sort the slots for the
+# grouped matmul: from a sweep of both forms on a v5e at DeepSeek-V2-Lite's,
+# Keye's and Mixtral's expert shapes (``chip_smoke.py --only moe``; PERF.md §6,
+# PR 33)
+MOE_STREAM_MAX_TOKENS = 256
 
 
 # rows of the expert-sorted slots a trip of the held experts' loop takes
@@ -70,6 +83,70 @@ def _grouped_mlp(xs_, gate_, up_, down_, sizes, *, glu: bool, act: str):
     else:
         h = _act(act)(h)
     return jax.lax.ragged_dot(h, down_, sizes)
+
+
+def blockwise_form(n_tokens: int, *, sharded: bool, quantized: bool) -> str:
+    """``"stream"`` or ``"ragged_dot"``: how the blockwise strategy multiplies
+    a call's ``n_tokens`` rows, from what the layer can observe. The streamed
+    kernel is compiled for the TPU and takes a decode step's few rows of a
+    mesh-free layer with float weights; a prefill's thousands of rows, tp or
+    ep > 1 (``shard_map``) and quantized experts keep the grouped matmul."""
+    if sharded or quantized or n_tokens > MOE_STREAM_MAX_TOKENS:
+        return "ragged_dot"
+    return "stream" if backend.on_tpu() else "ragged_dot"
+
+
+def _sorted_slots(top_e, top_w, num_experts: int, dtype):
+    """The dropless dispatch: ``(token_idx, group_sizes, ws)`` of the
+    ``T k`` slots sorted by expert."""
+    flat_e = top_e.reshape(-1)
+    order = jnp.argsort(flat_e, stable=True)  # expert-sorted slot ids
+    token_idx = order // top_e.shape[1]
+    group_sizes = jnp.bincount(flat_e, length=num_experts).astype(jnp.int32)
+    ws = top_w.reshape(-1)[order].astype(dtype)
+    return token_idx, group_sizes, ws
+
+
+def _ragged_routed_mlp(x, top_e, top_w, gate, up, down, act: str):
+    """The routed sum through the grouped matmul, mesh-free: sort the slots,
+    gather their rows, three ``ragged_dot`` calls (two where ``gate`` is
+    None: no GLU), scatter-add."""
+    with jax.named_scope("moe.dispatch"):
+        token_idx, group_sizes, ws = _sorted_slots(
+            top_e, top_w, up.shape[0], x.dtype)
+        xs = x[token_idx]
+    with jax.named_scope("moe.experts"):
+        ys = _grouped_mlp(xs, gate, up, down, group_sizes,
+                          glu=gate is not None, act=act)
+    with jax.named_scope("moe.combine"):
+        return jnp.zeros(x.shape, ys.dtype).at[token_idx].add(ys * ws[:, None])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _streamed_routed_mlp(x, top_e, top_w, gate, up, down, act: str):
+    """The same sum through ``kernels/moe_stream.py``; differentiated as the
+    grouped-matmul form (the kernel has no backward of its own, and a step
+    small enough to stream costs a backward little either way)."""
+    with jax.named_scope("moe.experts"):
+        return moe_stream_mlp(x, top_e, top_w, gate, up, down, act=_act(act))
+
+
+def _streamed_fwd(x, top_e, top_w, gate, up, down, act):
+    out = _streamed_routed_mlp(x, top_e, top_w, gate, up, down, act)
+    return out, (x, top_e, top_w, gate, up, down)
+
+
+def _streamed_bwd(act, residuals, ct):
+    x, top_e, top_w, gate, up, down = residuals
+    _, vjp = jax.vjp(
+        lambda x_, w_, g_, u_, d_: _ragged_routed_mlp(
+            x_, top_e, w_, g_, u_, d_, act),
+        x, top_w, gate, up, down)
+    dx, dw, dg, du, dd = vjp(ct)
+    return dx, None, dw, dg, du, dd
+
+
+_streamed_routed_mlp.defvjp(_streamed_fwd, _streamed_bwd)
 
 
 @functools.lru_cache(maxsize=None)
@@ -503,14 +580,9 @@ class ExpertMLPs(nn.Module):
                     gate if gate is not None else up, up, down,
                 )
 
-        with jax.named_scope("moe.dispatch"):
-            flat_e = top_e.reshape(-1)
-            order = jnp.argsort(flat_e, stable=True)  # expert-sorted slot ids
-            token_idx = order // k
-            group_sizes = jnp.bincount(flat_e, length=E).astype(jnp.int32)
-            ws = top_w.reshape(-1)[order].astype(x.dtype)
-
         if tp > 1 or ep > 1:
+            with jax.named_scope("moe.dispatch"):
+                token_idx, group_sizes, ws = _sorted_slots(top_e, top_w, E, x.dtype)
             # Grouped (ragged) matmuls cannot be auto-partitioned by GSPMD, so
             # tp/ep sharding is an explicit shard_map. NOTE this is
             # deliberately PARTIAL manual ({tp, ep} only, unlike
@@ -546,12 +618,31 @@ class ExpertMLPs(nn.Module):
                 )
             with jax.named_scope("moe.combine"):
                 return contrib.sum(axis=(0, 1))
-        with jax.named_scope("moe.dispatch"):
-            xs = x[token_idx]
-        with jax.named_scope("moe.experts"):
-            ys = _grouped_mlp(xs, gate, up, down, group_sizes,
-                              glu=self.glu_mlp, act=self.hidden_act)
-        with jax.named_scope("moe.combine"):
-            return jnp.zeros((T, H), ys.dtype).at[token_idx].add(
-                ys * ws[:, None]
-            )
+        # a mesh of several devices with tp = ep = 1 still shards the rows
+        # (GSPMD), which a kernel call is not partitioned over
+        form = blockwise_form(
+            T, sharded=initialized and mesh_lib.get_mesh().size > 1,
+            quantized=self.quantization_config is not None)
+        routed = _streamed_routed_mlp if form == "stream" else _ragged_routed_mlp
+        return routed(x, top_e, top_w, gate, up, down, self.hidden_act)
+
+
+def decode_form(config, n_tokens: int, *, sharded: bool) -> str:
+    """What a decode step's ``n_tokens`` rows run their routed experts
+    through in a model built from ``config`` (its ``num_experts``,
+    ``expert_strategy`` and, where it has them, ``capacity_factor``,
+    ``quantization``, ``held_experts``): ``"held"``, the strategy ``auto``
+    resolves to, or for ``blockwise`` its form (``blockwise_form``). The
+    engine records it as ``programs.resolved["moe_decode"]``."""
+    if getattr(config, "held_experts", None) is not None:
+        return "held"
+    strategy = ExpertMLPs(
+        num_experts=config.num_experts, hidden_size=0, intermediate_size=0,
+        strategy=config.expert_strategy,
+        capacity_factor=getattr(config, "capacity_factor", None),
+    )._resolve_strategy(n_tokens)
+    if strategy != "blockwise":
+        return strategy
+    return blockwise_form(
+        n_tokens, sharded=sharded,
+        quantized=getattr(config, "quantization", None) is not None)

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 from jax.sharding import PartitionSpec as P
 
+from neuronx_distributed_tpu.kernels.moe_stream import hit_mask
 from neuronx_distributed_tpu.modules.attention import ParallelMLP
 from neuronx_distributed_tpu.modules.moe.expert_mlps import ExpertMLPs
 from neuronx_distributed_tpu.modules.moe.loss_function import (
@@ -37,6 +38,17 @@ from neuronx_distributed_tpu.parallel import mesh as mesh_lib
 from neuronx_distributed_tpu.parallel.sharding import UNC, constrain
 
 Dtype = Any
+
+
+# the counters a layer that holds every expert sows each step
+MOE_CHUNK_STATS = ("hit_experts", "routed_rows")
+
+
+def moe_chunk_stats(config) -> Tuple[str, ...]:
+    """What a model of such layers names as its ``chunk_stats``, which
+    ``inference/generate.chunked_decode_step`` sums over a chunk's steps and
+    layers; scanned layers carry no ``stats`` collection."""
+    return () if config.scan_layers else MOE_CHUNK_STATS
 
 
 class MoE(nn.Module):
@@ -76,7 +88,10 @@ class MoE(nn.Module):
     # of ``num_experts`` and computes their part of the routed sum alone, plus
     # the shared expert (``ExpertMLPs.held_experts``). The stats ``held_rows``
     # and ``routed_rows`` (slots routed to a held expert / all slots) are sown
-    # into the ``stats`` collection for whoever makes it mutable
+    # into the ``stats`` collection for whoever makes it mutable. A layer that
+    # holds every expert sows ``hit_experts`` (distinct experts with at least
+    # one row: ``hit_experts x 3 H I`` values are what a streamed decode step
+    # reads, ``kernels/moe_stream.py``) and ``routed_rows`` (``MOE_CHUNK_STATS``)
     held_experts: Optional[Tuple[int, int]] = None
 
     @nn.compact
@@ -139,12 +154,18 @@ class MoE(nn.Module):
             name="experts",
         )
         out = experts(tokens, route.top_e, top_w)
+        latest = dict(init_fn=lambda: jnp.zeros((), jnp.int32),
+                      reduce_fn=lambda _, new: new)
         if self.held_experts is not None and not self.is_initializing():
             held = experts.held_slots(route.top_e)[1]
-            latest = dict(init_fn=lambda: jnp.zeros((), jnp.int32),
-                          reduce_fn=lambda _, new: new)
             self.sow("stats", "held_rows", jnp.sum(held, dtype=jnp.int32), **latest)
             self.sow("stats", "routed_rows", jnp.asarray(held.size, jnp.int32), **latest)
+        elif self.is_mutable_collection("stats") and not self.is_initializing():
+            # only for whoever collects them: a program that does not (a
+            # prefill, a train step) is what it was
+            hit = hit_mask(route.top_e, self.num_experts)
+            self.sow("stats", "hit_experts", jnp.sum(hit, dtype=jnp.int32), **latest)
+            self.sow("stats", "routed_rows", jnp.asarray(route.top_e.size, jnp.int32), **latest)
 
         if self.shared_intermediate_size is not None:
             with jax.named_scope("moe.shared"):

@@ -846,6 +846,12 @@ class ServingEngine:
                 self.paged_attention if kv_page_size is not None else "none"
             ),
         )
+        model_cfg = getattr(model, "config", None)
+        if hasattr(model_cfg, "num_experts") and hasattr(model_cfg, "expert_strategy"):
+            from neuronx_distributed_tpu.modules.moe.expert_mlps import decode_form
+
+            self.programs.resolved["moe_decode"] = decode_form(
+                model_cfg, num_slots, sharded=(tp or 1) > 1)
         # host-side slot bookkeeping (scheduling only — the decode-visible
         # per-slot state lives on device in self._state)
         self._active = np.zeros((num_slots,), bool)

@@ -290,6 +290,44 @@ def test_mixtral_width_blockwise_moe_compiles(topo):
     assert KERNEL in _compiled_text(layer.apply, params, x, top_e, top_w)
 
 
+@pytest.mark.parametrize("name,experts,hidden,inter,k,rows", [
+    ("deepseek-v2-lite", 64, 2048, 1408, 6, 8),
+    ("keye-vl2", 128, 2048, 768, 8, 8),
+    ("mixtral-8x7b", 8, 4096, 14336, 2, 16),
+    ("mixtral-8x7b-at-the-limit", 8, 4096, 14336, 2, 256),
+])
+def test_streamed_expert_mlp_compiles_at_the_cells_shapes(topo, name, experts, hidden, inter, k, rows):
+    """``kernels/moe_stream.py`` through the layer, as a decode step of the
+    three expert cells calls it (and at ``MOE_STREAM_MAX_TOKENS`` rows): whole
+    experts a grid step at DeepSeek's and Keye's shapes, tiles of 1024 at
+    Mixtral's; what it asks of VMEM is stated (under 64 MiB at the cells' rows,
+    under 80 at the limit, of the chip's 128), and Mosaic refuses a kernel
+    that needs more than it asked for."""
+    from neuronx_distributed_tpu.kernels import moe_stream
+    from neuronx_distributed_tpu.modules.moe.expert_mlps import MOE_STREAM_MAX_TOKENS, ExpertMLPs
+
+    assert rows <= MOE_STREAM_MAX_TOKENS
+    s = _one_chip(topo)
+    layer = ExpertMLPs(
+        num_experts=experts, hidden_size=hidden, intermediate_size=inter,
+        top_k=k, strategy="blockwise", dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+    )
+    x, top_e, top_w = s((rows, hidden)), s((rows, k), jnp.int32), s((rows, k), jnp.float32)
+    params = jax.tree.map(
+        lambda a: s(a.shape, a.dtype),
+        jax.eval_shape(
+            layer.init, jax.random.PRNGKey(0),
+            *(jax.ShapeDtypeStruct(a.shape, a.dtype) for a in (x, top_e, top_w)),
+        ),
+    )
+    text = _compiled_text(layer.apply, params, x, top_e, top_w)
+    assert KERNEL in text and "ragged" not in text
+    tile = moe_stream.pick_block_i(hidden, inter, 2, True)
+    assert tile == (inter if inter < 2048 else 1024)
+    asked = moe_stream.vmem_limit_bytes(rows, hidden, tile, 2, True)
+    assert 2 * 3 * hidden * tile * 2 < asked < (64 if rows <= 16 else 80) * 1024**2
+
+
 # --- whole programs (slow: the rehearsal before a chip call) -------------------
 
 
@@ -611,7 +649,7 @@ def test_deepseek_v2_lite_engine_programs_compile_and_fit(topo):
     )
     assert engine.programs.resolved == {
         "attention": "flash", "decode_attention": "paged_latent_fused",
-        "paged_attention": "fused",
+        "paged_attention": "fused", "moe_decode": "stream",
     }
     assert sorted(set(pool_shards)) == [(16385, 16, 1, 64), (16385, 16, 1, 512)]
     decode = lower_decode().compile()
@@ -663,7 +701,7 @@ def test_keye_vl2_engine_programs_compile_and_fit(topo):
     )
     assert engine.programs.resolved == {
         "attention": "flash", "decode_attention": "paged_sparse_fused",
-        "paged_attention": "fused",
+        "paged_attention": "fused", "moe_decode": "stream",
     }
     assert sorted(set(pool_shards)) == [(16385, 16, 1, 64), (16385, 16, 8, 128)]
     decode = lower_decode().compile()
@@ -757,7 +795,7 @@ def test_glm5_engine_programs_compile_and_fit(topo):
     )
     assert engine.programs.resolved == {
         "attention": "flash", "decode_attention": "paged_sparse_latent_fused",
-        "paged_attention": "fused",
+        "paged_attention": "fused", "moe_decode": "held",
     }
     assert sorted(set(pool_shards)) == [(16385, 16, 1, 128), (16385, 16, 8, 128)]
     decode = lower_decode().compile()

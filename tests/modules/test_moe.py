@@ -2,6 +2,8 @@
 test/unit_test/modules/moe/test_impl_correctness.py — strategy equivalence
 against a dense golden, plus router/loss/shuffle units)."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -480,3 +482,146 @@ def test_every_token_routed_to_held_experts_is_computed_without_a_drop(rows, mon
     # and a routing that sends nothing here gives exactly nothing
     none = mlps.apply(params, x, top_e - 8, top_w)
     assert not np.asarray(none).any()
+
+
+# --- the blockwise strategy's two forms (PR 33) ----------------------------------
+
+
+def _has_kernel(layer, rows, top_e=None, top_w=None):
+    """Whether ``layer`` on ``rows`` rows calls a Pallas kernel."""
+    x = jnp.zeros((rows, H))
+    top_e = jnp.zeros((rows, K), jnp.int32) if top_e is None else top_e
+    top_w = jnp.ones((rows, K)) if top_w is None else top_w
+    params = jax.eval_shape(layer.init, jax.random.PRNGKey(0), x, top_e, top_w)
+    return "pallas_call" in str(jax.make_jaxpr(
+        lambda p, x_: layer.apply(p, x_, top_e, top_w))(params, x))
+
+
+def test_blockwise_form_boundary_is_the_module_constant(monkeypatch):
+    from neuronx_distributed_tpu.kernels import backend
+    from neuronx_distributed_tpu.modules.moe import expert_mlps
+
+    limit = expert_mlps.MOE_STREAM_MAX_TOKENS
+    form = expert_mlps.blockwise_form
+    # off the TPU nothing streams: the kernel is compiled for the chip
+    assert form(1, sharded=False, quantized=False) == "ragged_dot"
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    assert form(1, sharded=False, quantized=False) == "stream"
+    assert form(limit, sharded=False, quantized=False) == "stream"
+    assert form(limit + 1, sharded=False, quantized=False) == "ragged_dot"
+    assert form(8, sharded=True, quantized=False) == "ragged_dot"
+    assert form(8, sharded=False, quantized=True) == "ragged_dot"
+
+
+@pytest.mark.parametrize("case,want", [
+    ("decode_rows", True), ("at_the_limit", True), ("past_the_limit", False),
+    ("prefill_rows", False), ("quantized", False), ("held", False), ("tp_mesh", False),
+    ("dp_mesh", False), ("all_experts", False), ("selective", False),
+])
+def test_which_calls_take_the_streamed_form(case, want, monkeypatch):
+    """The rule, through the layer: mesh-free float blockwise calls of at most
+    ``MOE_STREAM_MAX_TOKENS`` rows stream; every other call is what it was."""
+    from neuronx_distributed_tpu.kernels import backend
+    from neuronx_distributed_tpu.modules.moe import expert_mlps
+    from neuronx_distributed_tpu.quantization.config import QuantizationType, QuantizedDtype
+    from neuronx_distributed_tpu.quantization import QuantizationConfig
+
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    limit = expert_mlps.MOE_STREAM_MAX_TOKENS
+    rows, kw = 8, {}
+    if case == "at_the_limit":
+        rows = limit
+    elif case == "past_the_limit":
+        rows = limit + 1
+    elif case == "prefill_rows":
+        rows = 4096
+    elif case == "quantized":
+        kw["quantization_config"] = QuantizationConfig(
+            quantization_type=QuantizationType.PER_CHANNEL_SYMMETRIC,
+            quantized_dtype=QuantizedDtype.INT8)
+    elif case == "held":
+        kw["held_experts"] = (0, 2)
+    elif case == "tp_mesh":
+        mesh_lib.initialize_model_parallel(tensor_model_parallel_size=2)
+    elif case == "dp_mesh":
+        mesh_lib.initialize_model_parallel(tensor_model_parallel_size=1)
+    strategy = case if case in ("all_experts", "selective") else "blockwise"
+    assert _has_kernel(_mlps(strategy, **kw), rows) is want
+
+
+@pytest.mark.parametrize("config,slots,want", [
+    ("mixtral_auto", 16, "stream"), ("mixtral_auto", 8, "selective"),
+    ("mixtral_auto", 257, "ragged_dot"), ("deepseek_blockwise", 8, "stream"),
+    ("mixtral_quantized", 16, "ragged_dot"), ("glm_held", 8, "held"),
+])
+def test_engine_records_the_decode_form(config, slots, want, monkeypatch):
+    """``decode_form``: what the engine writes into
+    ``programs.resolved["moe_decode"]``, from the model's config alone."""
+    from neuronx_distributed_tpu.kernels import backend
+    from neuronx_distributed_tpu.models.deepseek_v2 import tiny_deepseek_v2
+    from neuronx_distributed_tpu.models.glm_moe_dsa import tiny_glm_moe_dsa
+    from neuronx_distributed_tpu.models.mixtral import tiny_mixtral
+    from neuronx_distributed_tpu.modules.moe.expert_mlps import decode_form
+
+    from neuronx_distributed_tpu.modules.moe.expert_mlps import MOE_STREAM_MAX_TOKENS
+
+    assert MOE_STREAM_MAX_TOKENS == 256   # the cases' 257 is one past it
+    monkeypatch.setattr(backend, "on_tpu", lambda: True)
+    cfg = {
+        "mixtral_auto": lambda: dataclasses.replace(tiny_mixtral(), num_experts=8),
+        "mixtral_quantized": lambda: dataclasses.replace(tiny_mixtral(), num_experts=8, quantization=object()),
+        "deepseek_blockwise": lambda: dataclasses.replace(tiny_deepseek_v2(), expert_strategy="blockwise"),
+        "glm_held": lambda: tiny_glm_moe_dsa(held_experts=(0, 4)),
+    }[config]()
+    assert decode_form(cfg, slots, sharded=False) == want
+    assert decode_form(cfg, slots, sharded=True) == (want if want != "stream" else "ragged_dot")
+
+
+def test_layer_sows_the_distinct_experts_its_rows_hit():
+    """``hit_experts`` / ``routed_rows`` for whoever collects ``stats``, and
+    nothing in the program of whoever does not."""
+    layer = MoE(num_experts=8, hidden_size=H, intermediate_size=I, top_k=2, expert_strategy="blockwise")
+    x = jax.random.normal(jax.random.PRNGKey(0), (3, 5, H))
+    params = layer.init(jax.random.PRNGKey(1), x)
+    assert "stats" not in params
+    (out, _), stats = layer.apply(params, x, mutable=["stats"])
+    plain, _ = layer.apply(params, x)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(plain))
+    router = RouterTopK(hidden_size=H, num_experts=8, top_k=2)
+    top_e = router.apply({"params": params["params"]["router"]}, x.reshape(15, H)).top_e
+    assert int(stats["stats"]["hit_experts"]) == len(np.unique(np.asarray(top_e)))
+    assert int(stats["stats"]["routed_rows"]) == 15 * 2
+    text = lambda **kw: str(jax.make_jaxpr(lambda p: layer.apply(p, x, **kw))(params))  # noqa: E731
+    assert len(text(mutable=["stats"])) > len(text()) == len(text(mutable=["cache"]))
+
+
+def test_a_chunk_reads_back_the_experts_its_steps_hit():
+    """Through ``chunked_decode_step`` and the engine: one pair of int32 a
+    chunk, summed on the device over its steps and the expert layers."""
+    from neuronx_distributed_tpu.inference import GenerationConfig
+    from neuronx_distributed_tpu.models.mixtral import MixtralForCausalLM, tiny_mixtral
+    from neuronx_distributed_tpu.modules.moe import MOE_CHUNK_STATS
+    from neuronx_distributed_tpu.serving import ServingEngine
+
+    cfg = tiny_mixtral()
+    model = MixtralForCausalLM(cfg, attention_impl="xla")
+    params = jax.jit(model.init)(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    eng = ServingEngine(model, params, decode_chunk_size=4, num_slots=2, kv_page_size=16, prefix_cache=None)
+    assert eng._decode_model.chunk_stats == MOE_CHUNK_STATS == ("hit_experts", "routed_rows")
+    assert eng.programs.resolved["moe_decode"] in ("selective", "all_experts", "ragged_dot")
+    fn = eng._nonspec_chunk()
+    rng = np.random.default_rng(0)
+    for i, n in enumerate((9, 20)):
+        eng.submit(rng.integers(1, cfg.vocab_size, size=n).astype(np.int32),
+                   GenerationConfig(max_new_tokens=6, temperature=0.0), key=jax.random.PRNGKey(i))
+    while eng.has_work and not any(eng._active):
+        eng.step()
+    out = fn(eng._params, eng.cache.take(), eng._state)
+    assert len(out) == 7
+    hit, routed = (int(v) for v in out[6])
+    steps = int(out[4])
+    assert routed == steps * cfg.num_layers * 2 * cfg.top_k
+    # a step's 2 rows hit between top_k and min(E, 2 top_k) experts a layer
+    assert steps * cfg.num_layers * cfg.top_k <= hit <= steps * cfg.num_layers * min(cfg.num_experts, 2 * cfg.top_k)
+    scanned = MixtralForCausalLM(dataclasses.replace(cfg, scan_layers=True), attention_impl="xla")
+    assert scanned.chunk_stats == ()

@@ -247,10 +247,14 @@ def test_tensor_parallel_serving_refuses_an_indexed_latent_cache_model(setup):
 # form, `chunked_decode_step` a model's own counters and the router a
 # selection bias; the model with the OTHER indexed cache must not notice.
 # (Mixtral's, CodeGen's and DeepSeek-V2's nine are in test_latent_cache.py.)
+# The two ``decode`` digests were taken anew by PR 33, whose expert layers sow
+# ``hit_experts`` / ``routed_rows`` into a decode chunk; ``keye.prefill`` is
+# the parent's, and so are GLM-5's three at the end of this file (a layer told which experts it
+# holds sows what it sowed).
 KEYE_PARENT_PROGRAMS = {
     "keye.prefill": "b6cab9cad2a08e38110d3c0b904d73989f7f803088075fe75bfdb6c9d3465882",
-    "keye.decode.gather": "81eff3e80f80b7002983e98c18c2d17ac7141aa488d43def40ec958031f4307d",
-    "keye.decode.fused": "2832ed6322633f8e7b839566182205ffcd174cbf219e288c8435a19f9a8dea64",
+    "keye.decode.gather": "6f6da1847487b7f11b14a9cfc1d8fb65ef40c4705c01b7cc4a0fea5ad2a4f673",
+    "keye.decode.fused": "c20e4f96af4860cdf30a31356bd4c4b70bf9fac825ac6af2c0a9f7c61c3a039e",
 }
 
 
@@ -265,3 +269,26 @@ def keye_program_texts():
 def test_keye_programs_are_the_parents(keye_program_texts, program):
     digest = _digest(keye_program_texts[program])
     assert digest == KEYE_PARENT_PROGRAMS[program], json.dumps({program: digest})
+
+
+# The same three programs of tiny GLM-5 with a share of its experts held,
+# taken on the PARENT commit (97eb835) of the PR that gave the other expert
+# layers their counters and a streamed decode form (PR 33): a layer told which
+# experts it holds sows and multiplies what it did.
+GLM_PARENT_PROGRAMS = {
+    "glm.prefill": "212888961eaff3339697e55a3b1de353d6252f85a2c75980d5c7e71537802798",
+    "glm.decode.gather": "afebe38b74c255baf4d9d3ee112ea89d355c4ed7833c5ccf3f1220c731a0a6c4",
+    "glm.decode.fused": "876af4ed1e1f5290a74cf59b9de82999a720b7a85dc6a7588f584812782242c0",
+}
+
+
+@pytest.fixture(scope="module")
+def glm_program_texts():
+    return _model_program_texts("glm", GlmMoeDsaForCausalLM(
+        tiny_glm_moe_dsa(max_seq_len=128, held_experts=(2, 4)), attention_impl="xla"))
+
+
+@pytest.mark.parametrize("program", list(GLM_PARENT_PROGRAMS))
+def test_glm_programs_are_the_parents(glm_program_texts, program):
+    digest = _digest(glm_program_texts[program])
+    assert digest == GLM_PARENT_PROGRAMS[program], json.dumps({program: digest})

@@ -268,11 +268,15 @@ def test_tensor_parallel_serving_refuses_a_latent_cache_model(setup):
 # value head size) must leave these programs exactly as they were. The two
 # ``decode.fused`` digests were taken anew by the PR that made the fused chunk
 # gather its write window instead of the logical view (PR 27); that PR left
-# the other four, prefill and the ``gather`` chunk, as they are.
+# the other four, prefill and the ``gather`` chunk, as they are. Mixtral's two
+# ``decode`` digests were taken anew by the PR that made the expert layers sow
+# ``hit_experts`` / ``routed_rows`` into a decode chunk (PR 33: the model names
+# ``chunk_stats``, so the chunk sums them and has a seventh output); that PR
+# left ``mixtral.prefill`` and CodeGen's three as they are.
 PARENT_PROGRAMS = {
     "mixtral.prefill": "e0d71476f6d38b847454df722dd626fe5ecbef1e0635893022a29220a2e62e69",
-    "mixtral.decode.gather": "f83929d86aba6a010d0dd0b09b39dc77a2821abaed89b52de60775bd11382d30",
-    "mixtral.decode.fused": "dd56949aa1be557b65126e73c20de4195b99c9a186241977a99428cb3102c0df",
+    "mixtral.decode.gather": "3b8d20065c272feacd44ff99038fcf07669d56ba176083950bdcab31738857bc",
+    "mixtral.decode.fused": "b57121a5626154e889de5c99a0f7ffe51b95ab685ef0314efb4d6b76659eac82",
     "codegen.prefill": "2ea437e83f3d502ceb35b1052f326594d3425b7c623598af85f683630941d54a",
     "codegen.decode.gather": "62e88bb2a8cef0f633d4819199e6bf72c6007083536647d5145b86413f3e75bc",
     "codegen.decode.fused": "b1c3be63e8f332d846e594f8575d781edab479adc3cb67be2985b64a5b4ad818",
@@ -338,11 +342,12 @@ def test_mixtral_and_codegen_programs_are_the_parents(program):
 # taken on the PARENT commit (07a904c) of the PR that joined K and V into one
 # leaf of the INDEXED cache (PR 31): a new name in ``PAGED_LEAVES`` and a new
 # sparse decode kernel must leave the programs of a model with no such leaf
-# exactly as they were.
+# exactly as they were. The two ``decode`` digests: anew with PR 33's counters,
+# as Mixtral's above; ``deepseek.prefill`` is the parent's.
 DEEPSEEK_PARENT_PROGRAMS = {
     "deepseek.prefill": "fead3bfa519cb976a59ab0e77801e17c93cdee964d75836884e38e694fa96c18",
-    "deepseek.decode.gather": "7bf70098d6a63175af42c4e54b11e002c312c3eb55fb2edc240eb4291da9e202",
-    "deepseek.decode.fused": "82f20c65afc58c2d59be974eb593f456a16208c7ddb7e440a006d90b2c2d535f",
+    "deepseek.decode.gather": "e57e4c5fed38209f62b5169e61b086fcd04581a2e3af97eb2bfed52c9a13be96",
+    "deepseek.decode.fused": "1e7b528f71655f7c94bbfb3f52e8eb418b981204a2ae7c2a221dee2868a833e7",
 }
 
 

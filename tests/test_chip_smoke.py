@@ -143,6 +143,18 @@ def test_glm_phase_alone_rehearsal():
     )
 
 
+def test_moe_phase_alone_rehearsal():
+    """``--only moe`` at a tiny size: every comparison must hold; which form
+    is FASTER is the chip's to say (an interpreted kernel's time says nothing)."""
+    moe = chip_smoke.MoeSize(
+        shapes=(("tiny", 8, 128, 256, 2, 8), ("tiny-odd", 16, 128, 384, 4, 4)),
+        tokens=(1, 8), calls=1, dtype="float32", routed_tol=1e-4)
+    checks = chip_smoke.one_chip(0, jax.devices()[:1], TRAIN, SERVE, MLA, only="moe", moe=moe)
+    compared = {name: ok for name, ok in checks.items() if not name.endswith("_wins_where_the_rule_takes_it")}
+    assert len(compared) == 8 and len(checks) == 10
+    assert all(compared.values()), sorted(name for name, ok in compared.items() if not ok)
+
+
 def test_four_chip_run_rehearsal():
     _assert_only_kernel_checks_fail(
         chip_smoke.four_chips(0, jax.devices()[:4], TRAIN, SERVE)
