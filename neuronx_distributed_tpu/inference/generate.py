@@ -214,13 +214,6 @@ def chunked_decode_step(decode_model, chunk_size: int, max_seq_len: int,
         # the carried pool is current through the last executed step
         return (adopt_kv_pool_pairs(paged, out[0], pools),) + out[1:]
 
-    # what the program materialises outside the pool (the row layout decodes
-    # on its own storage), for whoever accounts for it from the traced
-    # shapes: the engine's ``kv_view_bytes``
-    chunk_fn.staged = lambda cache: (
-        {} if page_size is None else stage(cache, cache_cursor(cache))[0]
-    )
-
     def _row_chunk(params, cache, state, pools=(), window=None):
         """The row-per-slot chunk on a logical cache: ``(outputs, pools)``.
         Fused mode hands in the page pool (``pools``, every layer's

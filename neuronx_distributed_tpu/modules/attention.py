@@ -517,19 +517,6 @@ def cache_bytes_per_token_layer(cache) -> float:
     return total / n_layers if n_layers else 0.0
 
 
-def per_token_leaf_bytes(tree) -> int:
-    """Bytes of a cache tree's per-token storage leaves
-    (:data:`PAGED_LEAVES`), from their shapes: arrays or what
-    ``jax.eval_shape`` hands back."""
-    import math
-
-    return sum(
-        math.prod(leaf.shape) * leaf.dtype.itemsize
-        for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]
-        if cache_leaf_name(path) in PAGED_LEAVES
-    )
-
-
 def cache_cursor(cache):
     """Shared write cursor of a raw cache collection as a traced int32
     scalar — the min over its ``index`` leaves (every attention module

@@ -15,7 +15,9 @@ training.
   Timeline``, so one trace explains a single request's whole life.
 * :mod:`flight_recorder` — :class:`FlightRecorder`: bounded ring of recent
   structured events, auto-dumped as a redacted JSON post-mortem on serving
-  ``HALTED``, ``TrainerHalted``, and emergency checkpoints.
+  ``HALTED``, ``TrainerHalted``, and emergency checkpoints; its second ring
+  (``steps``, a ``StepLedger``) holds one record per serving step, judges
+  each against its own history and samples the stack of a step that overran.
 * :mod:`profiler` — :func:`profile_window` (``jax.profiler`` start/stop
   around a block), :func:`install_compile_listener` (compile-event
   counter/duration histogram), :func:`record_device_memory` (per-device

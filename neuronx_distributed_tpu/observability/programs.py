@@ -560,6 +560,9 @@ class ProgramLedger:
         self.memory_analysis = memory_analysis
         self._clock = clock
         self._prewarm_depth = 0
+        # compiles counted over every program: two reads bracket a span of
+        # host time (the serving step ledger's "did this step compile?")
+        self.compiles = 0
         self._records: "OrderedDict[str, _ProgramRecord]" = OrderedDict()
         # implementation choices the owner resolved by platform ("auto" →
         # the kernel or the reference it actually traces), written once by
@@ -717,6 +720,7 @@ class ProgramLedger:
         self._emit_compile_event(rec, wall_s)
 
     def _emit_compile_event(self, rec: _ProgramRecord, wall_s: float) -> None:
+        self.compiles += 1
         if self._timeline is not None:
             self._timeline.instant(
                 f"compile {rec.name}", self._subsystem,

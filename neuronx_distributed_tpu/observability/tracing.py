@@ -4,7 +4,7 @@ Two things live here. :func:`span` is the ONE way the serving engine and the
 trainer record a duration; :class:`RequestTracer` links the events of one
 request into a Perfetto flow.
 
-``span(name, timeline, **stats)`` is a context manager with two sinks:
+``span(name, timeline, **stats)`` is a context manager with three sinks:
 
 * a ``jax.profiler.TraceAnnotation(name, **stats)``. It is recorded only
   while a profiler session is open (``observability.profile_window`` or any
@@ -15,6 +15,11 @@ request into a Perfetto flow.
 * the caller's :class:`~neuronx_distributed_tpu.utils.timeline.Timeline`,
   if it has one that is enabled: a Chrome ``X`` event of the same name with
   the stats as its ``args``.
+* the caller's step ledger (``ledger=``, a
+  :class:`~neuronx_distributed_tpu.observability.flight_recorder.StepLedger`),
+  if it has one: the span's wall on ``time.perf_counter()`` goes to the
+  account of the step in flight, session or no session, so every step of a
+  run leaves a record and not only those a trace happened to cover.
 
 Stats are host scalars the caller already owns. One known only at the
 span's end (``ttft_us``, ``delivered``) is added with ``set_metadata``
@@ -84,45 +89,57 @@ TRAIN_SPANS = (
 )
 
 
-class _TimelineSpan:
-    """A span with both sinks: the profiler's annotation and an ``X`` event
-    on the timeline (which carries the stats as its ``args``)."""
+class _SinkSpan:
+    """A span with more sinks than the profiler's annotation: an ``X``
+    event on the timeline (which carries the stats as its ``args``) and the
+    step ledger's account of the phase."""
 
-    __slots__ = ("_annotation", "_timeline", "_name", "_stats")
+    __slots__ = ("_annotation", "_timeline", "_ledger", "_name", "_stats")
 
-    def __init__(self, annotation, timeline: Timeline, name: str, stats: dict):
+    def __init__(self, annotation, timeline, ledger, name: str, stats: dict):
         self._annotation = annotation
         self._timeline = timeline
+        self._ledger = ledger
         self._name = name
         self._stats = stats
 
     def __enter__(self):
         self._annotation.__enter__()
-        self._timeline.mark_event_start(self._name, SPAN_CATEGORY)
+        if self._timeline is not None:
+            self._timeline.mark_event_start(self._name, SPAN_CATEGORY)
+        if self._ledger is not None:
+            self._ledger.enter(self._name, self._stats)
         return self
 
     def set_metadata(self, **stats) -> None:
         self._annotation.set_metadata(**stats)
         self._stats.update(stats)
+        if self._ledger is not None:
+            self._ledger.note(self._name, stats)
 
     def __exit__(self, *exc):
-        self._timeline.mark_event_end(
-            self._name, SPAN_CATEGORY, args=self._stats
-        )
+        if self._ledger is not None:
+            self._ledger.exit(self._name)
+        if self._timeline is not None:
+            self._timeline.mark_event_end(
+                self._name, SPAN_CATEGORY, args=self._stats
+            )
         return self._annotation.__exit__(*exc)
 
 
-def span(name: str, timeline: Optional[Timeline] = None, *,
+def span(name: str, timeline: Optional[Timeline] = None, *, ledger=None,
          annotation=TraceAnnotation, **stats):
     """The one span primitive (module docstring). Returns a context manager
-    with ``set_metadata(**stats)``; with no enabled ``timeline`` that is the
-    bare profiler annotation, so an uninstrumented run pays for nothing
-    else. ``annotation`` is the profiler class to enter
+    with ``set_metadata(**stats)``; with no enabled ``timeline`` and no
+    ``ledger`` that is the bare profiler annotation, so an uninstrumented
+    run pays for nothing else. ``annotation`` is the profiler class to enter
     (``jax.profiler.StepTraceAnnotation`` for a training step)."""
     ann = annotation(name, **stats)
-    if timeline is None or not timeline.enabled:
+    if timeline is not None and not timeline.enabled:
+        timeline = None
+    if timeline is None and ledger is None:
         return ann
-    return _TimelineSpan(ann, timeline, name, stats)
+    return _SinkSpan(ann, timeline, ledger, name, stats)
 
 # flow category: one namespace for request-lifecycle flows so trace
 # processors can select them structurally

@@ -158,8 +158,15 @@ with everything enabled):
 * Every phase of ``step()`` is a ``nxd.step*`` span
   (``observability.tracing.span``): recorded on the profiler's clock while a
   ``jax.profiler`` session is open (``observability.profile_window`` around
-  the run), and on the ``timeline`` if the engine has one. Off both, a span
-  costs under a microsecond and no sync.
+  the run), on the ``timeline`` if the engine has one, and, whenever the
+  flight recorder is on, in its step ledger (``self.flight.steps``): one
+  record a ``step()`` all run long (each phase's wall, the prefills'
+  buckets, CPU time), a verdict per step against the running medians of
+  what it is made of, and for a step that OVERRAN (seconds where a chunk
+  takes a tenth) one ``slow_step`` flight event and one warning line that
+  say which phase it sat in and where a watchdog thread found the stepping
+  thread's stack. With none of the three a span costs under a microsecond
+  and no sync.
 * Device efficiency (ISSUE 12): every jitted program the engine (and its
   cache/paging managers) dispatches registers in ``self.programs`` — a
   :class:`~neuronx_distributed_tpu.observability.programs.ProgramLedger`
@@ -214,6 +221,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+import json
 import os
 import re
 import time
@@ -241,8 +249,6 @@ from neuronx_distributed_tpu.modules.attention import (
     cache_bytes_per_token_layer,
     cache_fingerprint,
     extract_cache_prefix,
-    gather_cache_pages,
-    per_token_leaf_bytes,
     resolve_decode_impl,
     seed_cache_prefix,
 )
@@ -270,8 +276,11 @@ from neuronx_distributed_tpu.serving.scheduler import (
     RequestState,
     Scheduler,
 )
+from neuronx_distributed_tpu.utils.logger import get_logger
 from neuronx_distributed_tpu.utils.retry import RetryPolicy
 from neuronx_distributed_tpu.utils.sampling import sample_row
+
+logger = get_logger(__name__)
 
 
 class EngineHealth(enum.Enum):
@@ -814,6 +823,12 @@ class ServingEngine:
                 dump_dir=flight_dir, subsystem="serving"
             )
         self.flight = flight_recorder  # None disables
+        # the step ledger: every phase of step() accounts to it through
+        # _span (observability/tracing.py's third sink); gone with the
+        # recorder
+        self._ledger = (
+            flight_recorder.steps if flight_recorder is not None else None
+        )
         # device-efficiency observability (ISSUE 12): every jitted program
         # below registers in a ProgramLedger — dispatch counts, compile
         # wall, compiler-reported FLOPs/bytes (lazy cost analysis at
@@ -887,11 +902,6 @@ class ServingEngine:
         # a failed speculative dispatch ever needs the non-speculative
         # fallback
         if draft_model is not None:
-            # the speculative chunk decodes on both models' whole views
-            self._stage = lambda cache: (
-                {} if kv_page_size is None
-                else gather_cache_pages(cache, kv_page_size)
-            )
             self._spec_chunk = jax.jit(
                 speculative_decode_chunk(
                     self._decode_model, self._draft_decode_model,
@@ -919,7 +929,6 @@ class ServingEngine:
                     if kv_page_size is not None else "gather"
                 ),
             )
-            self._stage = chunk.staged
             self._decode_chunk = jax.jit(chunk, donate_argnums=(1, 2))
             self._decode_chunk = self.programs.wrap(
                 "decode_chunk", self._comms_scoped(self._decode_chunk)
@@ -2302,9 +2311,46 @@ class ServingEngine:
                 self._steps_seen, self._params
             )
         self._steps_seen += 1
-        with self._span(tracing.STEP):
+        compiles = self.programs.compiles
+        with self._span(tracing.STEP) as sp:
             self._step()
+            if self._ledger is not None:
+                self._close_step(sp, compiles)
         return self.has_work
+
+    def _close_step(self, sp, compiles_before: int) -> None:
+        """The step's account, as its span closes: one record into the
+        flight recorder's step ring, the stepping thread's CPU time and the
+        run's overrun seconds onto the span, and, for a step the ledger
+        judged to have overrun, the ``slow_step`` record."""
+        record = self._ledger.finish(self.programs.compiles - compiles_before)
+        if record is None:
+            return
+        if record["overran"]:
+            self._report_slow_step(record)
+        sp.set_metadata(
+            cpu_us=int(1e6 * record["thread_cpu_s"]),
+            overrun_us=int(1e6 * self._ledger.overrun_seconds),
+        )
+
+    def _report_slow_step(self, record: dict) -> None:
+        """ONE flight event and ONE warning line for a step that overran:
+        which phase, whether the thread ran or sat, what the host did to
+        it, and where the watchdog found it (README, "when a step
+        stalls")."""
+        fields = {
+            k: record[k] for k in (
+                "step", "since_start_s", "wall_s", "expected_s", "phases",
+                "thread_cpu_s", "process_cpu_s", "voluntary_switches",
+                "involuntary_switches", "major_faults", "run_delay_s",
+                "prefills", "active", "samples",
+            ) if k in record
+        }
+        event = self.flight.record("slow_step", **fields)
+        self.metrics.record_step_overrun(
+            record["wall_s"] - record["expected_s"]
+        )
+        logger.warning("slow_step %s", json.dumps(event))
 
     def _kv_bytes_per_token_layer(self) -> int:
         """Bytes a token holds per attention layer, from the allocated
@@ -2335,23 +2381,12 @@ class ServingEngine:
             "selected_tokens": int(sum(min(n, int(topk)) for n in held)),
         }
 
-    def _kv_view_bytes(self) -> int:
-        """Bytes of per-token leaves the decode program materialises
-        outside the page pool per chunk, from the shapes it traces: the
-        ``gather`` transport's logical view (the speculative chunk's two),
-        the fused transport's write window, nothing for the row layout;
-        the ``serving_kv_view_bytes`` gauge carries the same."""
-        if self.metrics.kv_view_bytes is None:
-            self.metrics.record_kv_view_bytes(sum(
-                per_token_leaf_bytes(jax.eval_shape(self._stage, mgr.cache))
-                for mgr in (self.cache, self.draft_cache) if mgr is not None
-            ))
-        return self.metrics.kv_view_bytes
-
     def _span(self, name: str, **stats):
         """A phase of ``step()``: the one span primitive, bound to this
         engine's timeline (``observability/tracing.py``)."""
-        return tracing.span(name, self.timeline, **stats)
+        return tracing.span(
+            name, self.timeline, ledger=self._ledger, **stats
+        )
 
     def _step(self) -> None:
         now = self._now()
@@ -3299,7 +3334,7 @@ class ServingEngine:
         with self._span(
             tracing.STEP_DISPATCH, active=active_at_dispatch,
             kv_bytes_per_token_layer=self._kv_bytes_per_token_layer(),
-            kv_view_bytes=self._kv_view_bytes(),
+            cursor=int(self.cache.cursor), row_columns=self.max_seq_len,
             **self._selection_stats(),
         ):
             cache_in = self.cache.take()
@@ -3488,7 +3523,7 @@ class ServingEngine:
         with self._span(
             tracing.STEP_DISPATCH, active=active_at_dispatch,
             kv_bytes_per_token_layer=self._kv_bytes_per_token_layer(),
-            kv_view_bytes=self._kv_view_bytes(),
+            cursor=int(self.cache.cursor), row_columns=self.max_seq_len,
             **self._selection_stats(),
         ):
             cache_in = self.cache.take()

@@ -111,6 +111,11 @@ _COUNTERS = (
     ("prefill_suffix_wall_s", "serving_prefill_suffix_wall_s", False),
     ("decode_dispatch_s", "serving_decode_dispatch_s", False),
     ("decode_readback_s", "serving_decode_readback_s", False),
+    # the step ledger's verdicts (observability/flight_recorder.py): steps
+    # that overran their expected wall, and the excess seconds: what an
+    # operator alerts on; the ``slow_step`` flight event says where
+    ("step_overruns", "serving_step_overruns", True),
+    ("step_overrun_s", "serving_step_overrun_seconds", False),
 )
 
 _HEALTH_CODES = {"ok": 0, "degraded": 1, "draining": 2, "halted": 3}
@@ -225,11 +230,6 @@ class ServingMetrics:
             "serving_kv_bytes_per_token_layer",
             help="bytes one token holds in one attention layer's cache",
         )
-        self._g_kv_view_bytes = self.view.gauge(
-            "serving_kv_view_bytes",
-            help="bytes of per-token cache leaves the decode program "
-                 "materialises outside the page pool per chunk",
-        )
         self._g_health = self.view.gauge(
             "serving_health", help="0=ok 1=degraded 2=draining 3=halted"
         )
@@ -282,10 +282,6 @@ class ServingMetrics:
         # bytes a token holds per attention layer, from the allocated cache
         # leaves (0 until the first admission allocates them)
         self.kv_bytes_per_token_layer = 0.0
-        # bytes of per-token leaves the decode program materialises outside
-        # the page pool per chunk (None until the first dispatch reads the
-        # program's shapes; 0 for the row layout, which has no pool)
-        self.kv_view_bytes = None
         # device-efficiency ledgers (ISSUE 12): attached weakly by the
         # engine so snapshot() can carry "programs"/"hbm" without a kept
         # metrics object pinning a retired engine's ledgers
@@ -439,6 +435,10 @@ class ServingMetrics:
 
     def record_preemption(self, req) -> None:
         self._inc("preemptions")
+
+    def record_step_overrun(self, excess_s: float) -> None:
+        self._inc("step_overruns")
+        self._inc("step_overrun_s", excess_s)
 
     # --- fault tolerance ----------------------------------------------------
 
@@ -598,10 +598,6 @@ class ServingMetrics:
     def record_kv_bytes(self, per_token_layer: float) -> None:
         self.kv_bytes_per_token_layer = float(per_token_layer)
         self._g_kv_bytes.set(per_token_layer)
-
-    def record_kv_view_bytes(self, nbytes: int) -> None:
-        self.kv_view_bytes = int(nbytes)
-        self._g_kv_view_bytes.set(nbytes)
 
     def record_decode_chunk(
         self,
@@ -800,7 +796,6 @@ class ServingMetrics:
             "health": self.health,
             "cursor_high_water": self.cursor_high_water,
             "kv_bytes_per_token_layer": self.kv_bytes_per_token_layer,
-            "kv_view_bytes": self.kv_view_bytes,
             "mean_occupancy": self.mean_occupancy,
             "mean_ttft": _mean(ttfts),
             "max_ttft": max(ttfts) if ttfts else 0.0,

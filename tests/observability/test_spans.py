@@ -118,8 +118,19 @@ def test_stats_are_host_ints_and_rid_links_a_request(traced):
     assert all(e[2]["active"] > 0 for e in events[tracing.STEP_DISPATCH])
     assert sum(e[2]["delivered"] for e in events[tracing.STEP_EMIT]) == \
         sum(len(t) for t in facts["on"]["tokens"]) - len(first)
+    # the shared write cursor against its row, in columns (48 here): it runs into the wall
+    cursors = [e[2]["cursor"] for e in events[tracing.STEP_DISPATCH]]
+    assert all(e[2]["row_columns"] == 48 for e in events[tracing.STEP_DISPATCH])
+    assert all(0 < c < 48 for c in cursors) and max(cursors) >= 40
+    assert any(b < a for a, b in zip(cursors, cursors[1:]))      # the preemption rewound it
+    # the step ledger's two, at the step's close: the stepping thread's CPU time
+    # inside the step, and the engine's overrun seconds so far (no step stalled here)
+    for start, end, stats, _ in events[tracing.STEP]:
+        assert set(stats) == {"cpu_us", "overrun_us"}
+        assert 0 <= stats["cpu_us"] <= (end - start) / 1e3 + 1000 and stats["overrun_us"] == 0
+    assert sum(e[2]["cpu_us"] for e in events[tracing.STEP]) > 0
     # what nothing reads is not emitted
-    for name in (tracing.STEP, tracing.STEP_REAP, tracing.STEP_PREEMPT, tracing.STEP_ADMIT, tracing.STEP_HEALTH):
+    for name in (tracing.STEP_REAP, tracing.STEP_PREEMPT, tracing.STEP_ADMIT, tracing.STEP_HEALTH):
         assert all(e[2] == {} for e in events[name]), name
 
 def test_no_session_no_event_and_no_extra_sync(traced):

@@ -14,15 +14,13 @@ for a ``KVCache`` model and a ``LatentKVCache`` one:
   mid-chunk, one is empty, and two slots map the same physical prefix pages,
   which stay byte for byte what they were;
 * structure: no array of a logical leaf's shape anywhere in the fused
-  chunk's jaxpr (the ``gather`` chunk's has them: the control);
-* the account: ``kv_view_bytes`` on the engine reads the window's bytes."""
+  chunk's jaxpr (the ``gather`` chunk's has them: the control)."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from neuronx_distributed_tpu.inference import GenerationConfig
 from neuronx_distributed_tpu.inference.generate import (
     chunked_decode_step,
     serving_clones,
@@ -40,7 +38,6 @@ from neuronx_distributed_tpu.modules.attention import (
     fused_paged_attention_scope,
     gather_cache_pages,
 )
-from neuronx_distributed_tpu.serving import ServingEngine
 
 SLOTS, PAGE, ROW = 4, 16, 128
 N_LOG = ROW // PAGE
@@ -256,32 +253,3 @@ def test_a_tree_mask_inside_a_fused_frame_is_refused():
                 mask=jnp.ones((2, 4 * PAGE), bool),
                 kv_valid=jnp.ones((1, 4 * PAGE), bool),
             )
-
-
-@pytest.mark.parametrize("path, kw", [
-    ("row", {}),
-    ("gather", {"kv_page_size": PAGE, "paged_attention": "gather"}),
-    ("fused", {"kv_page_size": PAGE, "paged_attention": "fused"}),
-])
-def test_kv_view_bytes_reads_what_the_program_stages(setup, path, kw):
-    """``kv_view_bytes``: per-token leaves the decode program materialises
-    outside the pool a chunk, from its traced shapes: the whole logical view
-    under ``gather``, ``slots x n_win x page_size`` tokens under ``fused``,
-    nothing for the row layout."""
-    cfg, model, params = setup
-    chunk = 4
-    eng = ServingEngine(model, params, num_slots=2, decode_chunk_size=chunk,
-                        prefix_cache=None, **kw)
-    assert eng.metrics.snapshot()["kv_view_bytes"] is None
-    req = eng.submit(np.arange(1, 12, dtype=np.int32),
-                     GenerationConfig(max_new_tokens=6, temperature=0.0),
-                     key=jax.random.PRNGKey(0))
-    eng.run()
-    assert len(req.tokens) == 6
-    per_token = eng.metrics.snapshot()["kv_bytes_per_token_layer"]
-    layers = cfg.num_layers
-    tokens = {"row": 0, "gather": 2 * ROW, "fused": 2 * 2 * PAGE}[path]
-    assert eng.metrics.snapshot()["kv_view_bytes"] == tokens * per_token * layers
-    assert eng.metrics.registry.get("serving_kv_view_bytes").value == (
-        tokens * per_token * layers
-    )
