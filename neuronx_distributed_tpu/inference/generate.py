@@ -253,10 +253,13 @@ def chunked_decode_step(decode_model, chunk_size: int, max_seq_len: int,
                 with fused_paged_attention_scope(pools, *window) as frame:
                     out, variables = apply(cache, tok, done)
                 pools = frame["pools"]
-            nxt = sample_per_row(
-                unwrap_logits(out)[:, -1], subs, temp, topk, topp
-            )
             emit = jnp.logical_not(done)
+            # a done slot's token is discarded below, and a freed slot keeps
+            # its last request's temperature: only the emitting rows decide
+            # whether the step samples
+            nxt = sample_per_row(
+                unwrap_logits(out)[:, -1], subs, temp, topk, topp, kept=emit
+            )
             remaining = remaining - emit.astype(jnp.int32)
             finished = emit & (
                 ((eos >= 0) & (nxt == eos)) | (remaining <= 0)

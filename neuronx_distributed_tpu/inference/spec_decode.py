@@ -181,7 +181,11 @@ def speculative_decode_chunk(
             # zero-acceptance correction
             split0 = jax.vmap(jax.random.split)(keys)
             k1, subs = split0[:, 0], split0[:, 1]
-            tok0 = sample_per_row(t_logits[:, 0], subs, temp, topk, topp)
+            # (a dead row emits nothing, so it does not decide whether the
+            # round samples)
+            tok0 = sample_per_row(
+                t_logits[:, 0], subs, temp, topk, topp, kept=live_m
+            )
 
             fix_pos = jnp.minimum(n_acc, gamma - 1)
             fix_val = jnp.where(

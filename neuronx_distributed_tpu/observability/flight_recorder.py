@@ -145,8 +145,8 @@ class _OpenStep:
     ``samples`` appended to) by the watchdog."""
 
     __slots__ = ("ordinal", "ident", "t0", "cpu0", "process_cpu0", "stack",
-                 "phases", "prefills", "bucket", "chunk", "active", "expected",
-                 "cold", "samples")
+                 "phases", "prefills", "bucket", "chunk", "active",
+                 "sampled_slots", "expected", "cold", "samples")
 
     def __init__(self, ordinal: int, t0: float):
         self.ordinal = ordinal
@@ -160,6 +160,7 @@ class _OpenStep:
         self.bucket = None          # of the prefill in flight
         self.chunk = False          # a chunk was read back
         self.active = 0             # slots the chunk was dispatched for
+        self.sampled_slots = 0      # those of them whose request samples
         self.expected = 0.0         # grows as the step's parts are entered
         self.cold = False           # a part with no history: no verdict
         self.samples = StackSamples()
@@ -224,6 +225,7 @@ class StepLedger:
                     self._start_watchdog()
         elif name == tracing.STEP_DISPATCH:
             step.active = int(stats.get("active", 0))
+            step.sampled_slots = int(stats.get("sampled_slots", 0))
             self._expect(step, self._chunk)
         step.stack.append((name, now))
 
@@ -291,6 +293,7 @@ class StepLedger:
             "phases": phases,
             "prefills": [b[0] for b, _ in step.prefills if b is not None],
             "chunk": step.chunk, "active": step.active,
+            "sampled_slots": step.sampled_slots,
             "compiles": int(compiles),
             "thread_cpu_s": thread_cpu, "process_cpu_s": process_cpu,
             "cold": cold, "expected_s": None if cold else expected,

@@ -2381,6 +2381,18 @@ class ServingEngine:
             "selected_tokens": int(sum(min(n, int(topk)) for n in held)),
         }
 
+    def _sampled_slots(self) -> int:
+        """The decoding slots whose request samples (``temperature != 0``),
+        for the dispatch span and the ``greedy_chunks_dispatched`` counter:
+        the count the chunk's sampler branches on (``sample_per_row``'s
+        ``kept`` rows), from the slots' requests. At 0 every step of the
+        chunk takes its tokens by ``argmax`` alone."""
+        return sum(
+            1 for s, r in enumerate(self._slot_req)
+            if r is not None and self._active[s]
+            and np.float32(r.config.temperature) != 0.0
+        )
+
     def _span(self, name: str, **stats):
         """A phase of ``step()``: the one span primitive, bound to this
         engine's timeline (``observability/tracing.py``)."""
@@ -3329,13 +3341,14 @@ class ServingEngine:
         the spec-off engine runs), then preempts to resync the draft cache;
         consumed buffers route through full dispatch recovery."""
         active_at_dispatch = int(self._active.sum())
+        sampled_slots = self._sampled_slots()
         t0 = self._clock()
         fault = None
         with self._span(
             tracing.STEP_DISPATCH, active=active_at_dispatch,
             kv_bytes_per_token_layer=self._kv_bytes_per_token_layer(),
             cursor=int(self.cache.cursor), row_columns=self.max_seq_len,
-            **self._selection_stats(),
+            sampled_slots=sampled_slots, **self._selection_stats(),
         ):
             cache_in = self.cache.take()
             draft_in = self.draft_cache.take()
@@ -3365,6 +3378,7 @@ class ServingEngine:
         t1 = self._clock()
         self._consecutive_dispatch_failures = 0
         self._chunks_since_failure += 1
+        self.metrics.record_chunk_dispatch(sampled_slots)
         with self._span(tracing.STEP_READBACK) as sp:
             # THE one host sync per speculative chunk: the ragged (rounds,
             # slots, gamma) token block, per-round per-slot counts +
@@ -3518,13 +3532,14 @@ class ServingEngine:
         """The non-speculative fused chunk (the pre-ISSUE-9 `_decode` body;
         also the speculative engine's fallback program)."""
         active_at_dispatch = int(self._active.sum())
+        sampled_slots = self._sampled_slots()
         t0 = self._clock()
         fault = None
         with self._span(
             tracing.STEP_DISPATCH, active=active_at_dispatch,
             kv_bytes_per_token_layer=self._kv_bytes_per_token_layer(),
             cursor=int(self.cache.cursor), row_columns=self.max_seq_len,
-            **self._selection_stats(),
+            sampled_slots=sampled_slots, **self._selection_stats(),
         ):
             cache_in = self.cache.take()
             attempt = self._dispatch_attempts
@@ -3552,6 +3567,7 @@ class ServingEngine:
         t1 = self._clock()
         self._consecutive_dispatch_failures = 0
         self._chunks_since_failure += 1
+        self.metrics.record_chunk_dispatch(sampled_slots)
         with self._span(tracing.STEP_READBACK) as sp:
             # THE one host sync per chunk: the (chunk, slots) token block,
             # the per-slot valid-prefix lengths, the executed step count —

@@ -116,6 +116,12 @@ _COUNTERS = (
     # operator alerts on; the ``slow_step`` flight event says where
     ("step_overruns", "serving_step_overruns", True),
     ("step_overrun_s", "serving_step_overrun_seconds", False),
+    # decode chunks dispatched, and those of them with no sampled slot
+    # (``sampled_slots`` 0 on the dispatch span): every step of such a chunk
+    # takes its tokens by argmax alone (utils/sampling.sample_per_row), so
+    # the two read equal on all-greedy traffic
+    ("chunks_dispatched", "serving_decode_chunks_dispatched", True),
+    ("greedy_chunks_dispatched", "serving_greedy_chunks_dispatched", True),
 )
 
 _HEALTH_CODES = {"ok": 0, "degraded": 1, "draining": 2, "halted": 3}
@@ -440,6 +446,13 @@ class ServingMetrics:
         self._inc("step_overruns")
         self._inc("step_overrun_s", excess_s)
 
+    def record_chunk_dispatch(self, sampled_slots: int) -> None:
+        """A decode chunk went to the device with ``sampled_slots`` of its
+        active slots asking ``temperature != 0``."""
+        self._inc("chunks_dispatched")
+        if not sampled_slots:
+            self._inc("greedy_chunks_dispatched")
+
     # --- fault tolerance ----------------------------------------------------
 
     def record_shed(self, req, now: float, where: str) -> None:
@@ -736,6 +749,8 @@ class ServingMetrics:
             "num_slots": self.num_slots,
             "steps": self.steps,
             "chunks": self.chunks,
+            "chunks_dispatched": self.chunks_dispatched,
+            "greedy_chunks_dispatched": self.greedy_chunks_dispatched,
             "decode_dispatch_s": self.decode_dispatch_s,
             "decode_readback_s": self.decode_readback_s,
             "chunk_tokens_per_sec": (

@@ -63,20 +63,16 @@ def sample(
 # disable the respective filter, temperature == 0.0 is greedy — exactly the
 # conditions `sample` checks in python.
 
-def sample_row(
+def _filtered_row(
     logits: jax.Array,
     key: jax.Array,
     temperature: jax.Array,
     top_k: jax.Array,
     top_p: jax.Array,
 ) -> jax.Array:
-    """One row's token from ``logits`` (V,) with traced scalar config.
-
-    Numerically identical to :func:`sample` on the same (logits, key,
-    config): the filters apply the same thresholds (k-th largest value /
-    smallest top-p prefix) and the Gumbel draw over (V,) consumes the same
-    bits as `sample`'s over (1, V), so a request served through the engine's
-    per-slot path reproduces its solo `generate()` tokens bit-for-bit."""
+    """The sampling side of :func:`sample_row`: the whole body of every row
+    of a batch in which some kept row samples, its greedy rows included
+    (their token is their ``argmax``, taken by the last line)."""
     v = logits.shape[-1]
     greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     temp = jnp.asarray(temperature, jnp.float32)
@@ -102,16 +98,63 @@ def sample_row(
     return jnp.where(temp == 0.0, greedy_tok, tok)
 
 
+def sample_row(
+    logits: jax.Array,
+    key: jax.Array,
+    temperature: jax.Array,
+    top_k: jax.Array,
+    top_p: jax.Array,
+) -> jax.Array:
+    """One row's token from ``logits`` (V,) with traced scalar config.
+
+    Numerically identical to :func:`sample` on the same (logits, key,
+    config): the filters apply the same thresholds (k-th largest value /
+    smallest top-p prefix) and the Gumbel draw over (V,) consumes the same
+    bits as `sample`'s over (1, V), so a request served through the engine's
+    per-slot path reproduces its solo `generate()` tokens bit-for-bit.
+
+    A greedy row (``temperature == 0``) is its ``argmax`` and nothing else:
+    the branch is a conditional on the traced scalar, so the sort, the
+    softmax and the Gumbel draw over the vocabulary run for a sampled row
+    only. Batched, call :func:`sample_per_row`: under ``vmap`` the
+    conditional becomes a select that computes both sides."""
+    return jax.lax.cond(
+        jnp.asarray(temperature, jnp.float32) != 0.0,
+        lambda: _filtered_row(logits, key, temperature, top_k, top_p),
+        lambda: greedy(logits),
+    )
+
+
 def sample_per_row(
     logits: jax.Array,
     keys: jax.Array,
     temperature: jax.Array,
     top_k: jax.Array,
     top_p: jax.Array,
+    kept: Optional[jax.Array] = None,
 ) -> jax.Array:
-    """Vectorized :func:`sample_row`: logits (B, V), keys (B, 2), per-row
+    """Batched :func:`sample_row`: logits (B, V), keys (B, 2), per-row
     (B,) config arrays → (B,) int32 tokens. The serving engine's shared
     decode step samples every slot with its own request's config here.
-    Traced under the named scope ``sample`` (a trace reader's handle)."""
+    Traced under the named scope ``sample`` (a trace reader's handle).
+
+    It branches ONCE for the batch, before any work on the vocabulary: where
+    no kept row samples, every token is its row's ``argmax`` and nothing
+    else runs; one kept row that samples sends every row through the whole
+    body. ``kept`` (B,) bool marks the rows whose token the caller keeps
+    (all of them when left out): a row it discards (a slot that is done but
+    still carries its last request's temperature) decides nothing, and in
+    an otherwise greedy batch its discarded token is its ``argmax``. The
+    conditional stands OUTSIDE the ``vmap``, on a scalar: on a per-row
+    predicate it would lower to a select over both sides."""
     with jax.named_scope("sample"):
-        return jax.vmap(sample_row)(logits, keys, temperature, top_k, top_p)
+        samples = jnp.asarray(temperature, jnp.float32) != 0.0
+        if kept is not None:
+            samples &= kept
+        return jax.lax.cond(
+            jnp.any(samples),
+            lambda: jax.vmap(_filtered_row)(
+                logits, keys, temperature, top_k, top_p
+            ),
+            lambda: greedy(logits),
+        )

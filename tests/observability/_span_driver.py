@@ -46,16 +46,22 @@ def serve(model, params):
     engine = ServingEngine(model, params, num_slots=2, admission="eager", prefix_cache=None)
     prompts = ([3, 5, 7, 11], [13, 17, 19, 23], [29, 31, 37, 41])
     new = (30, 20, 25)
+    # the second request samples: the dispatch span's ``sampled_slots`` reads 1
+    # while it decodes and 0 in the chunks after it
+    sampling = ({}, {"temperature": 0.8, "top_k": 17}, {})
     with SyncCounter() as c:
         reqs = [
-            engine.submit(np.asarray(p, np.int32), GenerationConfig(max_new_tokens=n, temperature=0.0),
+            engine.submit(np.asarray(p, np.int32),
+                          GenerationConfig(max_new_tokens=n, **{"temperature": 0.0, **how}),
                           key=jax.random.PRNGKey(60 + i))
-            for i, (p, n) in enumerate(zip(prompts, new))
+            for i, (p, n, how) in enumerate(zip(prompts, new, sampling))
         ]
         engine.run()
     m = engine.metrics
     return {"syncs": c.calls, "rids": [r.rid for r in reqs], "prefills": int(m.prefills),
             "chunks": int(m.chunks), "preemptions": int(m.preemptions),
+            "chunks_dispatched": int(m.chunks_dispatched),
+            "greedy_chunks_dispatched": int(m.greedy_chunks_dispatched),
             "tokens": [list(map(int, r.tokens)) for r in reqs]}
 
 

@@ -123,6 +123,12 @@ def test_stats_are_host_ints_and_rid_links_a_request(traced):
     assert all(e[2]["row_columns"] == 48 for e in events[tracing.STEP_DISPATCH])
     assert all(0 < c < 48 for c in cursors) and max(cursors) >= 40
     assert any(b < a for a, b in zip(cursors, cursors[1:]))      # the preemption rewound it
+    # the active slots whose request samples (the driver's second does): what the chunk's
+    # sampler branches on, and at 0 what ``serving_greedy_chunks_dispatched`` counts
+    sampled = [e[2]["sampled_slots"] for e in events[tracing.STEP_DISPATCH]]
+    assert set(sampled) == {0, 1} and all(s <= e[2]["active"] for s, e in zip(sampled, events[tracing.STEP_DISPATCH]))
+    assert len(sampled) == facts["on"]["chunks_dispatched"] == facts["on"]["chunks"]
+    assert sampled.count(0) == facts["on"]["greedy_chunks_dispatched"]
     # the step ledger's two, at the step's close: the stepping thread's CPU time
     # inside the step, and the engine's overrun seconds so far (no step stalled here)
     for start, end, stats, _ in events[tracing.STEP]:

@@ -250,11 +250,14 @@ def test_tensor_parallel_serving_refuses_an_indexed_latent_cache_model(setup):
 # The two ``decode`` digests were taken anew by PR 33, whose expert layers sow
 # ``hit_experts`` / ``routed_rows`` into a decode chunk; ``keye.prefill`` is
 # the parent's, and so are GLM-5's three at the end of this file (a layer told which experts it
-# holds sows what it sowed).
+# holds sows what it sowed). PR 36 (the chunk's sampler behind one conditional
+# on "some emitting row samples", ``utils/sampling.sample_per_row``) took the
+# two ``decode`` digests anew, here and in GLM-5's below; both ``prefill``
+# digests stand.
 KEYE_PARENT_PROGRAMS = {
     "keye.prefill": "b6cab9cad2a08e38110d3c0b904d73989f7f803088075fe75bfdb6c9d3465882",
-    "keye.decode.gather": "6f6da1847487b7f11b14a9cfc1d8fb65ef40c4705c01b7cc4a0fea5ad2a4f673",
-    "keye.decode.fused": "c20e4f96af4860cdf30a31356bd4c4b70bf9fac825ac6af2c0a9f7c61c3a039e",
+    "keye.decode.gather": "745243e191fa1af397416893de4bfb8335a4040b9dd4c285bd488fb3be53102b",
+    "keye.decode.fused": "3250f7de95cc048c6da0b48e83f9e8a6c03e923b231f561c69cf6f8d305fceee",
 }
 
 
@@ -274,11 +277,12 @@ def test_keye_programs_are_the_parents(keye_program_texts, program):
 # The same three programs of tiny GLM-5 with a share of its experts held,
 # taken on the PARENT commit (97eb835) of the PR that gave the other expert
 # layers their counters and a streamed decode form (PR 33): a layer told which
-# experts it holds sows and multiplies what it did.
+# experts it holds sows and multiplies what it did. (The two ``decode`` digests:
+# anew with PR 36's sampler branch, see Keye's above.)
 GLM_PARENT_PROGRAMS = {
     "glm.prefill": "212888961eaff3339697e55a3b1de353d6252f85a2c75980d5c7e71537802798",
-    "glm.decode.gather": "afebe38b74c255baf4d9d3ee112ea89d355c4ed7833c5ccf3f1220c731a0a6c4",
-    "glm.decode.fused": "876af4ed1e1f5290a74cf59b9de82999a720b7a85dc6a7588f584812782242c0",
+    "glm.decode.gather": "cff08709ba7f506ae7f3a9aab3660044fd2a0b55a54ae56da5ca2a99c10ea39b",
+    "glm.decode.fused": "709276835566c6a217ceaed065c302fc9acfe5fd61e02ddc3a910f9d383dd08d",
 }
 
 

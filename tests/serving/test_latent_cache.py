@@ -272,14 +272,18 @@ def test_tensor_parallel_serving_refuses_a_latent_cache_model(setup):
 # ``decode`` digests were taken anew by the PR that made the expert layers sow
 # ``hit_experts`` / ``routed_rows`` into a decode chunk (PR 33: the model names
 # ``chunk_stats``, so the chunk sums them and has a seventh output); that PR
-# left ``mixtral.prefill`` and CodeGen's three as they are.
+# left ``mixtral.prefill`` and CodeGen's three as they are. All four ``decode``
+# digests were taken anew by the PR that put the chunk's sampler behind one
+# conditional on "some emitting row samples" (PR 36: ``sample_per_row`` takes
+# ``kept=~done`` and branches round the sort); that PR left both ``prefill``
+# digests as they are (a prefill samples nothing).
 PARENT_PROGRAMS = {
     "mixtral.prefill": "e0d71476f6d38b847454df722dd626fe5ecbef1e0635893022a29220a2e62e69",
-    "mixtral.decode.gather": "3b8d20065c272feacd44ff99038fcf07669d56ba176083950bdcab31738857bc",
-    "mixtral.decode.fused": "b57121a5626154e889de5c99a0f7ffe51b95ab685ef0314efb4d6b76659eac82",
+    "mixtral.decode.gather": "3dd48b7bb2d393f00deb7568a1c77dc997d3743775d913a32f7f90d29d1f5f83",
+    "mixtral.decode.fused": "3d166644826da274dc5f22bb35072967bd480d62c1aeb3903b898c56b6e9feb0",
     "codegen.prefill": "2ea437e83f3d502ceb35b1052f326594d3425b7c623598af85f683630941d54a",
-    "codegen.decode.gather": "62e88bb2a8cef0f633d4819199e6bf72c6007083536647d5145b86413f3e75bc",
-    "codegen.decode.fused": "b1c3be63e8f332d846e594f8575d781edab479adc3cb67be2985b64a5b4ad818",
+    "codegen.decode.gather": "26031da023b7a3167fd7da7206124f5efad09ea86c03cde92890e6da20ec99dd",
+    "codegen.decode.fused": "414b5c2ac9b6016838e6863ceecf99ba70debfc0aefabf9d6c1270a2152ce969",
 }
 
 
@@ -343,11 +347,12 @@ def test_mixtral_and_codegen_programs_are_the_parents(program):
 # leaf of the INDEXED cache (PR 31): a new name in ``PAGED_LEAVES`` and a new
 # sparse decode kernel must leave the programs of a model with no such leaf
 # exactly as they were. The two ``decode`` digests: anew with PR 33's counters,
-# as Mixtral's above; ``deepseek.prefill`` is the parent's.
+# as Mixtral's above, and anew again with PR 36's sampler branch;
+# ``deepseek.prefill`` is the parent's.
 DEEPSEEK_PARENT_PROGRAMS = {
     "deepseek.prefill": "fead3bfa519cb976a59ab0e77801e17c93cdee964d75836884e38e694fa96c18",
-    "deepseek.decode.gather": "e57e4c5fed38209f62b5169e61b086fcd04581a2e3af97eb2bfed52c9a13be96",
-    "deepseek.decode.fused": "1e7b528f71655f7c94bbfb3f52e8eb418b981204a2ae7c2a221dee2868a833e7",
+    "deepseek.decode.gather": "277cce6135272958e7f21a7520375984ab6fcf96160e46688f9604f6fdadcc09",
+    "deepseek.decode.fused": "821885426fa3755ddf462db3230e800b98f18b5000050c355d391f46039854fe",
 }
 
 
