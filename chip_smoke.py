@@ -74,6 +74,26 @@ controls that must fail (the experts' weights in float8; one hit expert
 dropped), then ms a call of both forms over 1-256 rows: the sweep that sets
 ``modules/moe/expert_mlps.MOE_STREAM_MAX_TOKENS``.
 
+``--only trinity`` (not part of the default run) serves Trinity-Large-Preview's
+language model at its published widths on the benchmark configuration's cut
+(``perfbench/configs/trinity-large-serve.json``: a dense window layer and one
+period of three window layers and a full one, 32 of 256 experts held, a slice
+of the vocabulary) through a ``ServingEngine`` of 32,768-column slots whose
+paged cache has a block table and a pool a layer KIND: prompts of 9,146, 4,402
+and 2,048 tokens are prefilled (the banded flash forward on the window
+layers), admitted shortest first and an engine step apart so that each longer
+prompt's admission jumps the shared cursor over the slots already decoding,
+and 32 tokens decoded over those gap columns through the kernel that walks
+the blocks a slot maps. Against
+``perfbench/references/afmoe.py``, which is given the same share: prefill
+logits and the reference's logit of every decoded token; then each mechanism
+held on ONE block, every limit between the system's reading and a control's:
+a window layer's attention (| no window | a window of 2048 | the gate left
+out), the full layer's on the system's own input (| rotary applied), what a
+window layer's POOL holds of a served context after pages were freed behind
+the window (| a float8 cache), and the held experts' routed sum (| the bias
+added to the weights).
+
 ``--chips 4`` runs only the four-chip path and what it is compared with: a
 tp=4 + sequence-parallel train step against the same seeded step on one
 device of the same process, and ``ServingEngine(tp=4)`` against the
@@ -1541,6 +1561,277 @@ def glm_phase(size: GlmSize, seed: int) -> Dict[str, bool]:
     }
 
 
+@dataclasses.dataclass(frozen=True)
+class TrinitySize:
+    """What ``--only trinity`` runs (defaults: the chip run, the published
+    widths on the benchmark configuration's cut)."""
+
+    model: object = None          # the configuration's ``model`` group; None = the benchmark configuration's
+    max_seq_len: int = 32768
+    slots: int = 4
+    page: int = 16
+    prompt_lens: Tuple[int, ...] = (9146, 4402, 2048)
+    tail: int = 256
+    new_tokens: int = 32
+    pool_tokens: int = 24         # decoded before the window layer's pool is read
+    # Limits of the comparison with the reference, each between the system's
+    # reading and a control's (PERF.md section 6, PR 39, has the readings):
+    # the median position's largest |difference| of prefill logits over the
+    # vocabulary and the worst position's
+    logit_tol: float = 1.0
+    typical_tol: float = 0.1
+    # ONE block alone, which the layers after it cannot blur: the median
+    # position's |system - reference| / |reference| (L2 over the hidden
+    # vector) of what a window layer's and the full layer's attention add to
+    # the stream (positions past the window), of the held experts' routed sum
+    # in the first sparse layer on the SYSTEM's own input, and of what a window
+    # layer's pool holds of a served context
+    attn_tol: float = 0.03
+    routed_tol: float = 0.015
+    cache_tol: float = 0.01
+    # the decoded tokens' gap and the router near-tie that excuses one: the
+    # benchmark configuration's (its ``reference_check.why`` has the readings)
+    gap_tol: float = 0.065
+    near_tie: float = 0.006
+
+
+def trinity_phase(size: TrinitySize, seed: int) -> Dict[str, bool]:
+    """Trinity's language model through the default engine (module docstring)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from flax.core import meta
+
+    from neuronx_distributed_tpu.inference import GenerationConfig
+    from neuronx_distributed_tpu.models.afmoe import SLIDING, AfmoeModel
+    from neuronx_distributed_tpu.parallel import mesh as mesh_lib
+    from neuronx_distributed_tpu.serving import ServingEngine
+    from perfbench.families import afmoe as family
+    from perfbench.references import common
+    from perfbench.references.afmoe import Reference
+
+    mesh_lib.destroy_model_parallel()
+    published = size.model
+    if published is None:
+        path = os.path.join(os.path.dirname(os.path.abspath(__file__)), "perfbench", "configs",
+                            "trinity-large-serve.json")
+        with open(path) as f:
+            published = json.load(f)["model"]
+    model = family.build(published, runner="serve", max_seq_len=size.max_seq_len)
+    if size.model is not None:    # the CPU rehearsal serves in float32
+        model = model.clone(config=dataclasses.replace(model.config, dtype=jnp.float32))
+    cfg = model.config
+    window, ps = cfg.sliding_window, size.page
+    params = jax.jit(model.init)(jax.random.PRNGKey(seed), jnp.zeros((1, 8), jnp.int32))
+    jax.block_until_ready(params)
+    plain = meta.unbox(params)
+    ref = Reference(published, plain)
+    engine = ServingEngine(model, params, num_slots=size.slots, kv_page_size=ps)
+    prompts = _prompts(size.prompt_lens, int(published["vocab_size"]), seed + 7)
+    middle = prompts[0]           # the longest: past two windows at the chip's size
+    window_layer = cfg.layer_types.index(SLIDING)
+    if window_layer != 0:
+        raise NotImplementedError("the window layer compared alone is the stack's first: its input is the embedding")
+    full_layer = next(i for i, k in enumerate(cfg.layer_types) if k != SLIDING)
+    sparse = cfg.num_dense_layers   # the first sparse layer
+
+    def rel(mine, theirs, rows=slice(None)):
+        """Median over positions of |mine - theirs| / |theirs| (L2 over the
+        last axes), where ``theirs`` is not zero."""
+        mine = np.asarray(mine, np.float32)[0][rows].reshape(-1, np.prod(np.shape(mine)[2:]))
+        theirs = np.asarray(theirs, np.float32)[0][rows].reshape(mine.shape)
+        size_ = np.linalg.norm(theirs, axis=-1)
+        live = size_ > 0
+        return float(np.median(np.linalg.norm(mine - theirs, axis=-1)[live] / size_[live])), int(live.sum())
+
+    # --- what a window layer's POOL holds of a served context, pages freed on the way
+    req = engine.submit(middle, GenerationConfig(max_new_tokens=size.pool_tokens + 8, temperature=0.0),
+                        key=jax.random.PRNGKey(seed))
+    while len(req.tokens) < size.pool_tokens:
+        engine.step()
+    mgr = engine.cache
+    slot, cursor = req.slot, mgr.cursor
+    start, floor = mgr._slot_start[slot], mgr._window_floor(slot)
+    table = mgr._tables_w[slot].copy()
+    mapped = np.flatnonzero(table)
+    freed_ok = (mapped.min() == floor // ps and len(mapped) <= mgr.window_pages_per_slot
+                and mgr.window_pages_freed_total > 0 and (mgr._tables[slot] != 0).sum() > len(mapped))
+    pool = np.asarray(jax.device_get(
+        mgr.cache["pool"]["model"][f"layers_{window_layer}"]["attn"]["kv"]).astype(jnp.float32))
+    context = np.concatenate([middle, np.asarray(req.tokens, np.int32)])
+    cols = np.arange(floor, cursor)                       # the columns the next query attends
+    held = pool[table[cols // ps], cols % ps][None]       # (1, n, 2 Hkv, D)
+    tokens = cols - start
+    x_ctx = ref.embed(context[None])
+    sys_cache = rel(held, np.asarray(ref.cache_part(window_layer, x_ctx))[:, tokens])[0]
+    f8_cache = rel(np.asarray(Reference(published, plain, kv_dtype=jnp.float8_e4m3fn).cache_part(
+        window_layer, x_ctx))[:, tokens], np.asarray(ref.cache_part(window_layer, x_ctx))[:, tokens])[0]
+    log(f"trinity: a context of {len(context)} tokens, cursor {cursor}: the window kind's table maps "
+        f"{len(mapped)} pages of slot {slot} (the full kind's {int((mgr._tables[slot] != 0).sum())}; "
+        f"{mgr.window_pages_freed_total} freed so far; at most {mgr.window_pages_per_slot} a slot), the first at "
+        f"the window's floor {floor}: {'as the window says' if freed_ok else 'NOT as the window says'}; what "
+        f"layer {window_layer}'s pool holds of the {len(cols)} columns the next query attends, median |pool - "
+        f"reference| / |reference| {sys_cache:.5f} (limit {size.cache_tol:g}); control, a float8 cache: {f8_cache:.5f}")
+    engine.run()
+    engine.step()     # drained: the cursor rewinds, so the prompts below are admitted from column 0
+    del pool, held
+
+    # shortest first and an engine step apart: each longer prompt's admission
+    # jumps the shared cursor and leaves gap columns inside the contexts
+    # already decoding
+    gcfg = GenerationConfig(max_new_tokens=size.new_tokens, temperature=0.0)
+    t0, reqs = time.perf_counter(), []
+    for i, prompt in enumerate(prompts[::-1]):
+        reqs.append(engine.submit(prompt, gcfg, key=jax.random.PRNGKey(seed + i)))
+        engine.step()
+    engine.run()
+    wall = time.perf_counter() - t0
+    if any(len(r.tokens) != size.new_tokens for r in reqs):
+        raise RuntimeError(f"trinity: tokens {[len(r.tokens) for r in reqs]} of {size.new_tokens} (halt {engine.halt_reason!r})")
+    reqs, jumps = reqs[::-1], engine.cache.cursor_jumps_total
+    resolved = dict(engine.programs.resolved)
+    kernels = _ledger_kernels(engine.programs, _hot_programs(engine))
+    per_token = engine.metrics.snapshot()["kv_bytes_per_token_layer"]
+    log(f"trinity: {len(reqs)} requests, prompts {list(size.prompt_lens)} + {size.new_tokens} tokens in "
+        f"{wall:.1f}s; resolved {resolved}; {KERNEL} in compiled programs: {kernels}; cache {per_token:g} B a "
+        f"token a layer, pools {engine.cache.nbytes / 2**30:.2f} GiB; held experts {cfg.held_experts} of "
+        f"{cfg.num_experts}, vocabulary {cfg.vocab_size}; window pages freed {engine.cache.window_pages_freed_total}; "
+        f"{jumps} cursor jumps left gap columns in a held context")
+    engine.cache.check()
+    leak_free = (engine.cache.alloc_w.free_pages == engine.cache.alloc_w.num_pages - 1
+                 and engine.cache.alloc.free_pages == engine.cache.alloc.num_pages - 1)
+    engine = mgr = None
+    gc.collect()
+
+    backbone = AfmoeModel(cfg, model.attention_impl, mode="prefill")
+
+    @jax.jit
+    def prefill_rows(params, ids, lo):
+        """The system's prefill logits at ``tail`` positions from ``lo``."""
+        hidden = backbone.apply({"params": params["params"]["model"]}, ids, mutable=["cache"])[0][0]
+        rows = jax.lax.dynamic_slice_in_dim(hidden[0], lo, min(size.tail, ids.shape[1]), axis=0)
+        return rows @ meta.unbox(params)["params"]["lm_head"]["kernel"]
+
+    @jax.jit
+    def blocks_of(params, ids):
+        """From the SYSTEM's prefill: what the window layer's and the full
+        layer's attention add to the stream, the full layer's input, and the
+        first sparse layer's normed input and routed sum (held experts)."""
+        def wanted(mdl, _):
+            path = "/".join(mdl.path) + "/"
+            return ((mdl.name == "post_attn_norm" and (f"layers_{window_layer}/" in path or f"layers_{full_layer}/" in path))
+                    or mdl.name == f"layers_{full_layer - 1}"
+                    or (mdl.name in ("pre_mlp_norm", "experts") and f"layers_{sparse}/" in path))
+
+        _, state = backbone.apply(
+            {"params": params["params"]["model"]}, ids, mutable=["cache", "intermediates"],
+            capture_intermediates=wanted)
+        got = state["intermediates"]
+        layer = got[f"layers_{sparse}"]
+        return (got[f"layers_{window_layer}"]["post_attn_norm"]["__call__"][0],
+                got[f"layers_{full_layer}"]["post_attn_norm"]["__call__"][0],
+                got[f"layers_{full_layer - 1}"]["__call__"][0][0],
+                layer["pre_mlp_norm"]["__call__"][0],
+                layer["moe"]["experts"]["__call__"][0].reshape(ids.shape + (-1,)))
+
+    def tiled(prompt):
+        """``prompt`` (S,) as a batch of one, padded on the right to whole
+        tiles of 512 (the engine's buckets are; the full layers' flash forward
+        takes no other length): no row reads a key after it."""
+        return np.pad(prompt, (0, -len(prompt) % 512))[None]
+
+    worst_gap, worst_diff, worst_median, ok, caught = 0.0, 0.0, 0.0, True, {}
+    blocks_ok = False
+    for prompt, req in zip(prompts, reqs):
+        p, n = len(prompt), len(req.tokens)
+        ids = np.concatenate([prompt, np.asarray(req.tokens, np.int32)])[None]
+        hidden, router = ref._hidden(ids)               # the whole context, once
+        head = lambda rows, r=ref, hid=hidden: np.asarray(r._head(      # noqa: E731
+            r.p["model"]["final_norm"], r.p["lm_head"], hid[:, rows])[0], np.float32)
+        rows = head(np.arange(p - 1, p - 1 + n))
+        toks = np.asarray(req.tokens)
+        gaps = rows.max(1) - rows[np.arange(n), toks]
+        wrong = rows.max(1) - rows[np.arange(n), (toks + 1) % rows.shape[1]]
+        margins = np.asarray(router[0, p - 1:p - 1 + n])
+        fine, over, excused = common.judge_gaps(gaps, margins, size.gap_tol, size.near_tie)
+        ok = ok and fine and wrong.min() > size.gap_tol
+        worst_gap = max(worst_gap, float(gaps[margins >= size.near_tie].max(initial=0.0)))
+        starts = range(0, p, size.tail) if p < max(size.prompt_lens) else [p - size.tail]
+        blocks = [max(min(lo, p - size.tail), 0) for lo in starts]
+        mine = [np.asarray(prefill_rows(params, tiled(prompt), lo), np.float32) for lo in blocks]
+        theirs = [head(np.arange(lo, lo + m.shape[0])) for lo, m in zip(blocks, mine)]
+        diffs = np.concatenate([np.abs(m - t).max(1) for m, t in zip(mine, theirs)])
+        median, worst = float(np.median(diffs)), float(diffs.max())
+        ok = ok and worst <= size.logit_tol and median <= size.typical_tol
+        worst_diff, worst_median = max(worst_diff, worst), max(worst_median, median)
+        log(f"trinity: prompt {p}: decoded tokens' largest reference-logit gap {gaps.max():.4f} ({over} of {n} "
+            f"fail {size.gap_tol:g}, {excused} excused by a router margin under {size.near_tie:g} where a held "
+            f"expert is at the edge; wrong tokens' smallest gap {wrong.min():.3f}); prefill logits at "
+            f"{len(diffs)} positions, |system - reference|: median {median:.4f}, 99th percentile "
+            f"{np.percentile(diffs, 99):.4f}, largest {worst:.4f}")
+        if prompt is middle:
+            # each mechanism on ONE block: the system against the reference,
+            # then the controls, each the reference changed in ONE way
+            past = slice(min(window, p - 1), None)      # the rows whose window is cut
+            sys_win, sys_full, x_full, sys_h, sys_routed = (a[:, :p] for a in blocks_of(params, tiled(prompt)))
+            x0 = ref.embed(prompt[None])
+            ref_win = ref.attention_part(window_layer, x0)
+            ref_full = ref.attention_part(full_layer, x_full)
+            ref_routed = ref.routed_part(sparse, sys_h)
+            (win_rel, _), (full_rel, _) = rel(sys_win, ref_win, past), rel(sys_full, ref_full, past)
+            routed_rel, hit = rel(sys_routed, ref_routed)
+            blocks_ok = (win_rel <= size.attn_tol and full_rel <= size.attn_tol
+                         and routed_rel <= size.routed_tol and sys_cache <= size.cache_tol)
+            log(f"trinity: prompt {p}, one block alone, median |system - reference| / |reference| over the "
+                f"positions past the window: layer {window_layer}'s (window) attention {win_rel:.4f}, layer "
+                f"{full_layer}'s (full) on the system's own input {full_rel:.4f} (limit {size.attn_tol:g}); layer "
+                f"{sparse}'s routed sum on the system's own input {routed_rel:.4f} at the {hit} of {p} positions "
+                f"where a held expert was chosen (limit {size.routed_tol:g})")
+            controls = (
+                ("no window", dict(window="none"), "win"),
+                ("a window of half the width", dict(window=window // 2), "win"),
+                ("the gate left out", dict(gate=False), "win"),
+                ("rotary on the full layer", dict(rope_full=True), "full"),
+                ("the bias in the weights", dict(bias_in_weights=True), "routed"),
+            )
+            for name, kw, block in controls:
+                other = Reference(published, plain, **kw)
+                if block == "win":
+                    reading, limit = rel(other.attention_part(window_layer, x0), ref_win, past)[0], size.attn_tol
+                elif block == "full":
+                    reading, limit = rel(other.attention_part(full_layer, x_full), ref_full, past)[0], size.attn_tol
+                else:
+                    reading, limit = rel(other.routed_part(sparse, sys_h), ref_routed)[0], size.routed_tol
+                caught[name] = reading > limit
+                log(f"trinity: control, the reference with {name} against the plain reference, that block: "
+                    f"{reading:.4f} (limit {limit:g}): {'outside' if caught[name] else 'INSIDE'} the limit")
+            caught["a float8 cache"] = f8_cache > size.cache_tol
+    log(f"trinity: prefill logits against the reference: largest difference {worst_diff:.4f} (tolerance "
+        f"{size.logit_tol:g}), largest median {worst_median:.4f} ({size.typical_tol:g}); largest decoded-token gap "
+        f"outside router near-ties {worst_gap:.4f} ({size.gap_tol:g})")
+    return {
+        "trinity_matches_reference": ok,
+        "trinity_window_full_routed_and_pool_alone_match_reference": blocks_ok,
+        "trinity_frees_pages_behind_the_window_and_leaks_none": bool(freed_ok) and leak_free,
+        # every longer prompt's admission jumped the cursor over a decoding slot:
+        # the tokens compared above were decoded over gap columns
+        "trinity_cursor_jumps_leave_gap_columns": jumps >= len(prompts) - 1,
+        "trinity_resolved_paged_walk_fused": resolved == {
+            "attention": "flash", "decode_attention": "paged_walk_fused",
+            "paged_attention": "fused", "moe_decode": "held",
+        },
+        "trinity_cache_is_k_and_v_of_every_kv_head": per_token == 2 * cfg.num_kv_heads * cfg.head_dim
+        * jnp.dtype(cfg.dtype).itemsize,
+        "trinity_no_window_is_caught": caught.get("no window", False),
+        "trinity_half_window_is_caught": caught.get("a window of half the width", False),
+        "trinity_missing_gate_is_caught": caught.get("the gate left out", False),
+        "trinity_rotary_on_the_full_layer_is_caught": caught.get("rotary on the full layer", False),
+        "trinity_float8_cache_is_caught": caught.get("a float8 cache", False),
+        "trinity_bias_in_the_weights_is_caught": caught.get("the bias in the weights", False),
+        "kernel_trinity_programs": all(kernels.values()) and bool(kernels),
+    }
+
+
 def moe_phase(size: MoeSize, seed: int) -> Dict[str, bool]:
     """The streamed expert MLP alone, a call = one layer of a decode step:
     against the float32 routed sum, the grouped-matmul form and two controls,
@@ -1630,12 +1921,14 @@ def moe_phase(size: MoeSize, seed: int) -> Dict[str, bool]:
 def one_chip(seed: int, devices, train: TrainSize = TrainSize(),
              serve: ServeSize = ServeSize(), mla: MlaSize = MlaSize(),
              only: str = "all", dsa: DsaSize = DsaSize(),
-             glm: GlmSize = GlmSize(), moe: MoeSize = MoeSize()) -> Dict[str, bool]:
+             glm: GlmSize = GlmSize(), moe: MoeSize = MoeSize(),
+             trinity: TrinitySize = TrinitySize()) -> Dict[str, bool]:
     """The default run: train, then serve, then the MLA model, the
     sparse-attention model and GLM-5's (sparse selection among latents), in
     one process on one device; ``only="mla"``, ``"dsa"`` or ``"glm"``: that
     phase alone; ``only="moe"``: the streamed expert MLP alone, which no
-    other phase repeats."""
+    other phase repeats; ``only="trinity"``: Trinity's window and full
+    attention layers in one paged cache, likewise."""
     if only == "mla":
         return mla_phase(mla, seed)
     if only == "dsa":
@@ -1644,6 +1937,8 @@ def one_chip(seed: int, devices, train: TrainSize = TrainSize(),
         return glm_phase(glm, seed)
     if only == "moe":
         return moe_phase(moe, seed)
+    if only == "trinity":
+        return trinity_phase(trinity, seed)
     return {**train_phase(train, seed, devices), **serve_phase(serve, seed),
             **mla_phase(mla, seed), **dsa_phase(dsa, seed), **glm_phase(glm, seed)}
 
@@ -1664,10 +1959,11 @@ def parse_args(argv=None):
                         "tp=4 train step and tp=4 engine and their "
                         "one-device counterparts")
     p.add_argument("--seed", type=int, default=0)
-    p.add_argument("--only", default="all", choices=("all", "mla", "dsa", "glm", "moe"),
+    p.add_argument("--only", default="all", choices=("all", "mla", "dsa", "glm", "moe", "trinity"),
                    help="one chip: every phase (default), or the MLA, the "
                         "sparse-attention (dsa) or the GLM-5 (glm) phase alone; "
-                        "moe: the streamed expert MLP's checks and sweep")
+                        "moe: the streamed expert MLP's checks and sweep; "
+                        "trinity: window and full attention layers in one paged cache")
     return p.parse_args(argv)
 
 

@@ -162,9 +162,15 @@ def chunked_decode_step(decode_model, chunk_size: int, max_seq_len: int,
     whose layers hold every expert, ``modules/moe.MOE_CHUNK_STATS``) gets a
     seventh output, an int32 vector of their sums
     over the chunk's executed steps and the layers, in that order; for every
-    other model the program is what it was."""
+    other model the program is what it was.
+
+    A model whose stack mixes WINDOW and full attention layers
+    (``modules/attention.JoinedKVCache``) is paged as ``{"pages",
+    "window_pages", "pool"}``: every walker picks a leaf's block table by its
+    layer's kind, and the fused frame carries both tables."""
     from neuronx_distributed_tpu.inference.utils import unwrap_logits
     from neuronx_distributed_tpu.modules.attention import (
+        WINDOW_PAGES,
         adopt_kv_pool_pairs,
         cache_cursor,
         fused_chunk_window,
@@ -209,7 +215,8 @@ def chunked_decode_step(decode_model, chunk_size: int, max_seq_len: int,
         out, pools = _row_chunk(
             params, staged, state,
             pools=ordered_kv_pool_pairs(paged["pool"]),
-            window=(paged["pages"], page_size, page0),
+            # a model with window layers: that kind's block table beside it
+            window=(paged["pages"], page_size, page0, paged.get(WINDOW_PAGES)),
         )
         # the carried pool is current through the last executed step
         return (adopt_kv_pool_pairs(paged, out[0], pools),) + out[1:]

@@ -1034,6 +1034,191 @@ def paged_latent_decode_attention(
     return jnp.swapaxes(out.reshape(b, h, s, d_c), 1, 2).astype(q_c.dtype)
 
 
+# --- paged GQA decode that walks the blocks a slot maps ----------------------------
+#
+# The latent kernel's form (one grid step a slot, the pool left in HBM, a walk
+# over the blocks of the row that THIS slot maps, one async copy a page into
+# a two-block VMEM buffer: :func:`_latent_block_walk`,
+# :func:`_block_page_copies`) for grouped-query attention over a cache whose K
+# and V are ONE joined leaf ``(2 Hkv, D)`` a token
+# (``modules/attention.JoinedKVCache``): a page is one copy of ``page_size x
+# 2 Hkv x D`` values (64 KB at 16 tokens of 8 kv heads of 128 in bf16), and all
+# the query heads of the slot meet a block while it is in VMEM, the group of
+# each kv head against that head's K and V rows (the sparse kernel's
+# multiply). It serves both kinds of layer of a stack that mixes WINDOW and
+# full attention: the walk runs from the first block in which the block table
+# maps a page to the cursor, and a window layer's table maps nothing behind the
+# window (the cache manager freed those pages), so there the walk is the
+# window; ``floor`` (each slot's lowest attendable column) trims the first
+# block. :func:`paged_flash_decode_attention`, one 16-token page a grid step
+# up to the shared cursor, is some ten thousand grid steps a layer at eight
+# rows of 32,768 columns.
+
+# Tokens a block: two blocks of 512 x (16, 128) bf16 are 4 MiB of VMEM.
+WALK_BLOCK_TOKENS = 512
+
+
+def _paged_walk_kernel(bt_ref, live_ref, span_ref, edge_ref, valid_ref, q_ref,
+                       kv_hbm, o_ref, buf, sems, m_scr, l_scr, acc_scr, *,
+                       page_size, group, num_kv_heads, scale, use_valid):
+    b = pl.program_id(0)
+    lo, hi = span_ref[b, 0], span_ref[b, 1]   # this slot's own blocks
+    floor, pos = edge_ref[b, 0], edge_ref[b, 1]
+    n_log, n_blocks = bt_ref.shape[1], live_ref.shape[1]
+    block = group * page_size
+
+    def copies(i, wait=False):
+        def page_id(g):
+            page = i * group + g
+            if n_log % group:  # the row's last block: spare rows repeat its last page
+                page = jnp.minimum(page, n_log - 1)
+            return bt_ref[b, page]
+
+        slot = i % 2
+        _block_page_copies(((kv_hbm, buf.at[slot], None),), sems.at[slot],
+                           page_id, page_size, group, wait)
+
+    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    def step(i, carry):
+        # block i + 1 is issued before block i is waited on; the trip before
+        # the span's first block only issues it
+        nxt = jnp.minimum(i + 1, n_blocks - 1)
+
+        @pl.when((i + 1 < hi) & (live_ref[b, nxt] != 0))
+        def _prefetch():
+            copies(nxt)
+
+        @pl.when((i >= lo) & (live_ref[b, jnp.maximum(i, 0)] != 0))
+        def _body():
+            copies(i, wait=True)
+            slot = i % 2
+            rows = q_ref.shape[2]
+            cols = jax.lax.broadcasted_iota(jnp.int32, (rows, block), 1) + i * block
+            ok = (cols <= pos) & (cols >= floor)
+            if use_valid:
+                ok = ok & (valid_ref[0, pl.ds(i, 1), :] != 0)
+            for h in range(num_kv_heads):
+                # operands stay in their storage type (bf16 on the chip: the
+                # MXU's own), accumulation is float32
+                k = buf[slot, :, h, :]                             # (T, D)
+                v = buf[slot, :, num_kv_heads + h, :]
+                s = jax.lax.dot_general(
+                    q_ref[0, h], k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ) * scale                                          # (G, T)
+                s = jnp.where(ok, s, NEG_INF)
+                m_prev = m_scr[h]
+                m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+                ref = jnp.where(m_new > NEG_INF / 2, m_new, 0.0)
+                p = jnp.where(ok, jnp.exp(s - ref), 0.0)
+                alpha = jnp.exp(m_prev - ref)
+                l_scr[h] = l_scr[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
+                acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
+                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )
+                m_scr[h] = m_new
+
+        return carry
+
+    jax.lax.fori_loop(lo - 1, hi, step, 0)
+    o_ref[0] = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)).astype(o_ref.dtype)
+
+
+def paged_walk_decode_attention(
+    q: jax.Array,
+    kv_pool: jax.Array,
+    block_table: jax.Array,
+    q_pos: jax.Array,
+    kv_valid: Optional[jax.Array] = None,
+    floor: Optional[jax.Array] = None,
+    *,
+    page_size: int = 16,
+    interpret: Optional[bool] = None,
+) -> jax.Array:
+    """GQA decode attention straight off the page pool, one query row a slot:
+    ``q`` (B, 1, H, D) at cache column ``q_pos`` (1,); ``kv_pool`` (P,
+    page_size, 2 Hkv, D), a token's K heads then its V heads; ``block_table``
+    (B, n_log); ``kv_valid`` (B, L) bool or None; ``floor`` (B,) int32 each
+    slot's lowest attendable column (a window layer's lower edge) or None.
+    Softmax over the valid columns in ``[floor, q_pos]`` of ``q_h . k_g(h) /
+    sqrt(D)``, times ``v``: (B, 1, H, D).
+
+    The pool is not copied, gathered or blocked: the kernel reads each slot's
+    pages out of HBM itself, :data:`WALK_BLOCK_TOKENS` tokens at a time, and
+    only between the first and the last block in which the block table maps
+    a page at or before ``q_pos``. A block there with no mapped page is
+    skipped; a slot that maps nothing returns zeros. Inside a fetched block
+    an unmapped page reads the null page 0, which ``kv_valid`` or ``floor``
+    keeps out of the result (a page the window's manager freed lies below
+    ``floor``). The kernel or nothing (interpreted only in tests); no mesh."""
+    from neuronx_distributed_tpu.parallel import mesh as mesh_lib
+
+    if mesh_lib.model_parallel_is_initialized():
+        raise NotImplementedError(
+            "the paged walking decode kernel has no sharded form: serve a "
+            "joined-cache model without a model-parallel mesh")
+    b, s, h, d = q.shape
+    if s != 1:
+        raise ValueError(f"one query row a slot, got {s}")
+    if kv_pool.ndim != 4 or kv_pool.shape[2] % 2 or kv_pool.shape[3] != d:
+        raise ValueError(
+            f"joined K/V pool leaf must be (P, page_size, 2 Hkv, {d}), got {kv_pool.shape}")
+    if kv_pool.shape[1] != page_size:
+        raise ValueError(f"pool pages hold {kv_pool.shape[1]} tokens, page_size is {page_size}")
+    interpret = interpret_mode(interpret)
+    hkv = kv_pool.shape[2] // 2
+    g = h // hkv
+    n_log = block_table.shape[1]
+    group = min(max(WALK_BLOCK_TOKENS // page_size, 1), n_log)
+    block = group * page_size
+    block_table = block_table.astype(jnp.int32)
+    pos = jnp.reshape(q_pos, (-1,))[0].astype(jnp.int32)
+    live, span = _latent_block_walk(block_table, pos + 1, group, page_size)
+    n_blocks = live.shape[1]
+    lower = jnp.zeros((b,), jnp.int32) if floor is None else floor.astype(jnp.int32)
+    edge = jnp.stack([lower, jnp.broadcast_to(pos, (b,))], axis=1)
+    use_valid = kv_valid is not None
+    if kv_valid is None:
+        kv_valid = jnp.zeros((1, 1), jnp.int32)
+        vspec = _SMEM_SPEC
+    else:
+        # a slot's whole row, one block a sublane: the loop picks row ``i``
+        kv_valid = jnp.pad(
+            kv_valid.astype(jnp.int32),
+            ((0, 0), (0, n_blocks * block - kv_valid.shape[1])),
+        ).reshape(b, n_blocks, block)
+        vspec = pl.BlockSpec((1, n_blocks, block), lambda b_, *_: (b_, 0, 0))
+    rows = pl.BlockSpec((1, hkv, g, d), lambda b_, *_: (b_, 0, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,  # block table, live blocks, each slot's span, its (floor, position)
+        grid=(b,),
+        in_specs=[vspec, rows, pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=rows,
+        scratch_shapes=[
+            pltpu.VMEM((2, block, 2 * hkv, d), kv_pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((hkv, g, 1), jnp.float32),
+            pltpu.VMEM((hkv, g, 1), jnp.float32),
+            pltpu.VMEM((hkv, g, d), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(
+            _paged_walk_kernel, page_size=page_size, group=group, num_kv_heads=hkv,
+            scale=1.0 / (d ** 0.5), use_valid=use_valid,
+        ),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        interpret=interpret,
+    )(block_table, live, span, edge, kv_valid, q.reshape(b, hkv, g, d), kv_pool)
+    return out.reshape(b, 1, h, d)
+
+
 # --- paged SPARSE decode (a learned indexer beside GQA) --------------------------
 #
 # A sparse-attention indexer caches, beside each token's K and V, ONE index key

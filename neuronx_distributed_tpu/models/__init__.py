@@ -1,3 +1,10 @@
+from neuronx_distributed_tpu.models.afmoe import (
+    AfmoeConfig,
+    AfmoeForCausalLM,
+    AfmoeModel,
+    tiny_afmoe,
+    trinity_large,
+)
 from neuronx_distributed_tpu.models.bert import (
     BertConfig,
     BertForMaskedLM,
@@ -83,4 +90,5 @@ __all__ = [
     "glm5", "tiny_glm_moe_dsa",
     "KeyeVL2Config", "KeyeVL2ForCausalLM", "KeyeVL2Model",
     "keye_vl2_30b_a3b", "tiny_keye_vl2",
+    "AfmoeConfig", "AfmoeForCausalLM", "AfmoeModel", "trinity_large", "tiny_afmoe",
 ]

@@ -186,6 +186,15 @@ DSA_ATTEND_SCOPE = "dsa.attend"
 # (a kernel, named after this scope)
 DSA_WRITE_SCOPE = "dsa.write"
 
+# Named scopes of a model whose stack mixes WINDOW and FULL attention layers
+# (``models/afmoe.py``), a trace reader's contract (PERF.md section 3): each
+# kind's attention from the head norms to the kernel (rotary, the cache write
+# and the kernels included: a Pallas kernel called inside one is named after
+# it), and the sigmoid gate on the attention output.
+ATTN_WINDOW_SCOPE = "attn.window"
+ATTN_FULL_SCOPE = "attn.full"
+ATTN_GATE_SCOPE = "attn.gate"
+
 
 def prefill_positions(padding_mask: jax.Array) -> jax.Array:
     """RoPE positions for a (possibly left-)padded prompt (B, S): restart at
@@ -434,6 +443,94 @@ class IndexedLatentKVCache(KVCache):
 
     def decode_write(self, c, k_pe, k_idx, padding_mask=None):
         self._decode_write((join_latent(c, k_pe, *self.leaf), k_idx), padding_mask)
+
+
+# The leaf a WINDOW layer's cache node carries beside its storage: a bool
+# array of shape ``(0, window)``. It holds no byte; its SHAPE is the layer's
+# window, a static attribute every walker of a cache tree can read inside or
+# outside a jitted program (:func:`cache_node_window`). A node without it is
+# a full-attention layer's. Walkers that do not know it treat it as they
+# treat ``index`` (a leaf with no slot axis) and write nothing: it is empty.
+WINDOW_LEAF = "window"
+
+
+class JoinedKVCache(KVCache):
+    """The cache of GQA attention whose K and V are ONE joined leaf ``kv``
+    (B, L, 2 Hkv, D), a token's K heads then its V heads (``(16, 128)`` a
+    token at 8 kv heads of 128: whole tiles, one copy a page for the paged
+    kernel that walks a slot's blocks), for a stack that mixes two KINDS of
+    layer: ``window=None`` a full-attention layer's, ``window=W`` a layer
+    that attends the last ``W`` tokens only and whose node says so
+    (:data:`WINDOW_LEAF`). The paged cache manager gives the window kind a
+    block table and a pool of its own and frees a window layer's pages as
+    the cursor passes them (``serving/paging.py``). The writes take ``(k,
+    v)``; :func:`split_kv` takes the leaf apart."""
+
+    def __init__(self, module, b, max_seq_len, hkv, d, dtype, window=None):
+        super().__init__(
+            module, b, max_seq_len, hkv, d, dtype, leaves={"kv": (2 * hkv, d)},
+        )
+        self.window = window
+        if window is not None:
+            module.variable("cache", WINDOW_LEAF, jnp.zeros, (0, int(window)), jnp.bool_)
+
+    def prefill_write(self, k, v, padding_mask=None):
+        self._prefill_write((jnp.concatenate([k, v], axis=2),), padding_mask)
+
+    def decode_write(self, k, v, padding_mask=None):
+        self._decode_write((jnp.concatenate([k, v], axis=2),), padding_mask)
+
+
+def cache_node_window(node) -> Optional[int]:
+    """The window of the layer whose cache node (the dict holding its leaves)
+    this is; ``None`` for a full-attention layer."""
+    leaf = node.get(WINDOW_LEAF) if hasattr(node, "get") else None
+    return None if leaf is None else int(leaf.shape[-1])
+
+
+def cache_windows(tree) -> dict:
+    """``{layer path (key tuple): window}`` of the WINDOW layers of a cache
+    tree (a row collection or a pool tree)."""
+    from neuronx_distributed_tpu.utils.tree import path_keys
+
+    out = {}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
+        keys = tuple(path_keys(path))
+        if keys[-1] == WINDOW_LEAF:
+            out[keys[:-1]] = int(leaf.shape[-1])
+    return out
+
+
+def window_floor(kv_valid, cur, window: int):
+    """(B,) int32: the lowest cache column the query written at column
+    ``cur`` may attend under a window of ``window`` TOKENS. A slot's tokens
+    are its VALID columns (gap columns, left by another slot's admission
+    moving the shared cursor, hold none), so the window is counted over
+    ``kv_valid`` and not over columns: the lowest valid column ``j <= cur``
+    with at most ``window`` valid columns in ``[j, cur]``. ``cur`` where the
+    row holds nothing."""
+    length = kv_valid.shape[1]
+    cols = jnp.arange(length, dtype=jnp.int32)[None]
+    v = kv_valid & (cols <= cur)
+    after = jnp.cumsum(v[:, ::-1].astype(jnp.int32), axis=1)[:, ::-1]   # valid in [j, cur]
+    keep = v & (after <= window)
+    return jnp.where(keep.any(axis=1), jnp.argmax(keep, axis=1), cur).astype(jnp.int32)
+
+
+def window_keep(kv_valid, q_pos, window: Optional[int]):
+    """(B, S, L) bool: the cache columns the query rows at columns ``q_pos``
+    (S,) attend: valid, at or before the row's column and, under a
+    ``window``, among the row's last ``window`` tokens (counted over valid
+    columns, as :func:`window_floor` counts them)."""
+    length = kv_valid.shape[1]
+    cols = jnp.arange(length, dtype=jnp.int32)
+    keep = kv_valid[:, None, :] & (cols[None, None, :] <= q_pos[None, :, None])
+    if window is not None:
+        token = jnp.cumsum(kv_valid.astype(jnp.int32), axis=1) - 1        # (B, L)
+        q_token = jnp.take_along_axis(
+            token, jnp.broadcast_to(q_pos[None], (kv_valid.shape[0], q_pos.shape[0])), axis=1)
+        keep = keep & (token[:, None, :] > q_token[:, :, None] - window)
+    return keep
 
 
 # --- cache-collection slot helpers (serving) ----------------------------------
@@ -706,6 +803,29 @@ def _rebuild_tree(items):
     return out
 
 
+# The key of the WINDOW kind's block table in a paged cache pytree: a model
+# whose cache tree has window layers (:data:`WINDOW_LEAF`) is paged as
+# ``{"pages": bt, "window_pages": bt_w, "pool": tree}``, the window layers'
+# pool leaves sized for the window and mapped by ``bt_w``, whose entries
+# behind the window read the null page; every other model as ``{"pages",
+# "pool"}``.
+WINDOW_PAGES = "window_pages"
+
+
+def block_table_of(paged, path):
+    """The block table that maps the pool leaf at ``path``: the window
+    kind's for a leaf of a window layer's node, ``pages`` for every other."""
+    if WINDOW_PAGES in paged and cache_node_window(
+            cache_node_at(paged["pool"], path[:-1])) is not None:
+        return paged[WINDOW_PAGES]
+    return paged["pages"]
+
+
+def with_pool(paged, pool):
+    """``paged`` with its pool tree replaced (the block tables kept)."""
+    return {**paged, "pool": pool}
+
+
 def gather_cache_pages(paged, page_size: int):
     """Materialize the LOGICAL cache collection from a paged cache pytree
     ``{"pages": (B, n_log) int32 block table, "pool": tree}``: k/v pool
@@ -729,7 +849,6 @@ def gather_cache_pages(paged, page_size: int):
     )
     from neuronx_distributed_tpu.utils.tree import path_keys
 
-    bt = paged["pages"]
     pool = paged["pool"]
     items = []
     for path, leaf in jax.tree_util.tree_flatten_with_path(pool)[0]:
@@ -738,6 +857,7 @@ def gather_cache_pages(paged, page_size: int):
         if pool_scale_base(name) is not None:
             continue  # transport metadata — dropped from the logical view
         if name in PAGED_LEAVES:
+            bt = block_table_of(paged, path)
             scale = pool_scale_sibling(pool, path, name)
             with jax.named_scope(KV_VIEW_SCOPE):
                 leaf = (
@@ -772,9 +892,8 @@ def scatter_cache_window(paged, logical, page_size: int, start_col,
         quantize_page_block,
     )
 
-    bt = paged["pages"]
     pool = paged["pool"]
-    n_log = bt.shape[1]
+    n_log = paged["pages"].shape[1]
     # pages a width-column window can overlap, wherever it starts
     n_win = min((width - 1) // page_size + 2, n_log)
     page0 = jnp.asarray(start_col, jnp.int32) // page_size
@@ -785,6 +904,7 @@ def scatter_cache_window(paged, logical, page_size: int, start_col,
         if base not in PAGED_LEAVES:
             # index / kv_valid: logical IS the storage
             return cache_node_at(logical, path[:-1])[name]
+        bt = block_table_of(paged, path)
         lg = cache_node_at(logical, path[:-1])[base]
         if pool_scale_sibling(pool, path, base) is None:
             return paged_scatter_window_leaf(
@@ -798,7 +918,7 @@ def scatter_cache_window(paged, logical, page_size: int, start_col,
 
     with jax.named_scope(KV_VIEW_SCOPE):
         new_pool = jax.tree_util.tree_map_with_path(fn, pool)
-    return {"pages": bt, "pool": new_pool}
+    return with_pool(paged, new_pool)
 
 
 # --- fused paged decode attention (ISSUE 14) ----------------------------------
@@ -838,13 +958,18 @@ class fused_paged_attention_scope:
     pool does not hold yet), which is all the cache's per-token leaves hold
     (:func:`fused_chunk_window`). ``__enter__`` returns the frame: after the
     model apply its ``"pools"`` hold each layer's pair as that layer's
-    window scatter left it."""
+    window scatter left it. ``window_tables``: the block table of the window
+    kind, for a model that has window layers."""
 
-    def __init__(self, pools, tables, page_size: int, page0):
+    def __init__(self, pools, tables, page_size: int, page0, window_tables=None):
         self.frame = {
             "pools": dict(pools), "order": _execution_order(pools),
             "tables": tables, "page_size": page_size, "page0": page0,
             "idx": 0,
+            # the window kind's block table (a model with window layers);
+            # ``floor``: each slot's lowest attendable column this step,
+            # worked out by the step's first window layer for all of them
+            "window_tables": window_tables, "floor": None,
         }
 
     def __enter__(self):
@@ -876,15 +1001,14 @@ def fused_chunk_window(paged, page_size: int, start_col, chunk_size: int):
         paged_gather_window_leaf,
     )
 
-    bt = paged["pages"]
-    n_log = bt.shape[1]
+    n_log = paged["pages"].shape[1]
     n_win = min((chunk_size - 1) // page_size + 2, n_log)
     page0 = jnp.clip(start_col // page_size, 0, n_log - n_win)
 
     def fn(path, leaf):
         if cache_leaf_name(path) not in PAGED_LEAVES:
             return leaf
-        return paged_gather_window_leaf(leaf, bt, page0, n_win)
+        return paged_gather_window_leaf(leaf, block_table_of(paged, path), page0, n_win)
 
     with jax.named_scope(KV_VIEW_SCOPE):
         cache = jax.tree_util.tree_map_with_path(fn, paged["pool"])
@@ -955,10 +1079,7 @@ def adopt_kv_pool_pairs(paged, logical, pairs):
             return pairs[tuple(layer)][held.index(name)]
         return cache_node_at(logical, path)
 
-    return {
-        "pages": paged["pages"],
-        "pool": jax.tree_util.tree_map_with_path(fn, paged["pool"]),
-    }
+    return with_pool(paged, jax.tree_util.tree_map_with_path(fn, paged["pool"]))
 
 
 def _next_fused_layer(frame):
@@ -1058,6 +1179,93 @@ def _fused_sparse_decode(frame, q, q_idx, w_idx, caches, q_pos, kv_valid, topk,
                 *q, kv_pool, bt, cols, n_sel, scale=latent_scale, page_size=ps)
         return paged_sparse_decode_attention(
             q, kv_pool, bt, cols, n_sel, page_size=ps)
+
+
+def _fused_walk_decode(frame, q, kv_window, q_pos, kv_valid, window):
+    """A joined K/V cache's step in the active frame (:class:`JoinedKVCache`:
+    both kinds of layer of a stack that mixes window and full attention):
+    ``kv_window`` the layer's window leaf. The window's pages go into the
+    carried pool through a kernel (as an indexed cache's joined leaf goes:
+    every user of that pool inside the decode scan is then a kernel of one
+    layout), through the block table of the layer's KIND; then the kernel
+    that walks the blocks a slot maps attends straight off the pool. For a
+    window layer the table maps nothing behind the window (the manager freed
+    those pages), so the walk IS the window, and ``floor`` (each slot's
+    lowest attendable column, counted in tokens over ``kv_valid``) trims the
+    first block. The caller's scope names both kernels."""
+    from neuronx_distributed_tpu.kernels.flash_decode import (
+        paged_scatter_window_pages_dma,
+        paged_walk_decode_attention,
+    )
+
+    if q.shape[1] != 1:
+        raise ValueError(f"one query row a slot in a fused paged frame, got {q.shape[1]}")
+    pools = frame["pools"]
+    layer = _next_fused_layer(frame)
+    bt = frame["tables"]
+    floor = None
+    if window is not None:
+        bt = frame["window_tables"]
+        if bt is None:
+            raise ValueError(
+                "a window layer in a fused paged frame without the window "
+                "kind's block table: the cache manager was not told the window")
+        if frame["floor"] is None:
+            frame["floor"] = window_floor(kv_valid, q_pos[0], window)
+        floor = frame["floor"]
+    (kv_pool,) = pools[layer]
+    kv_pool = paged_scatter_window_pages_dma(kv_pool, kv_window, bt, frame["page0"])
+    pools[layer] = (kv_pool,)  # trace-time: the step's carry-out
+    return paged_walk_decode_attention(
+        q, kv_pool, bt, q_pos, kv_valid=kv_valid, floor=floor,
+        page_size=frame["page_size"])
+
+
+def joined_decode_attention(q, kv_cache, q_pos, kv_valid, window: Optional[int] = None):
+    """GQA attention of q (B, S, H, D) rows at cache columns ``q_pos`` (S,)
+    against a :class:`JoinedKVCache` leaf (B, L, 2 Hkv, D), each row masked at
+    its own column, by ``kv_valid`` (B, L) and, for a WINDOW layer, to its
+    last ``window`` tokens (:func:`window_keep`). Inside a
+    :class:`fused_paged_attention_scope` it attends the page pool through
+    ``kernels/flash_decode.paged_walk_decode_attention`` (the kernel, or
+    nothing); elsewhere this float32 einsum, on every platform (there is no
+    row-cache kernel with a window's lower edge: the engine records
+    ``decode_attention: "einsum"``)."""
+    if _FUSED_PAGED_STACK:
+        return _fused_walk_decode(
+            _FUSED_PAGED_STACK[-1], q, kv_cache, q_pos, kv_valid, window)
+    q_pos = q_pos[None] if q_pos.ndim == 0 else q_pos
+    k, v = split_kv(kv_cache)
+    return _masked_gqa_attention(q, k, v, window_keep(kv_valid, q_pos, window))
+
+
+def window_prefill_attention(q, k, v, window: Optional[int] = None, impl: str = "auto",
+                             mask: Optional[jax.Array] = None):
+    """Causal GQA attention of a prompt, q (B, S, H, D) over k/v (B, S, Hkv,
+    D), each query reading its last ``window`` keys only; ``mask`` (B, S) True
+    at valid (not padded) keys. ``window=None`` (a full layer) is
+    :func:`attention_op`: the flash forward every other model's prefill runs.
+    A window layer runs, on the TPU, the banded flash forward (``kernels/
+    flash_attention.banded_flash_attention``: tiles wholly outside the band
+    are skipped, not masked; forward only), elsewhere and for ``impl="xla"``
+    (training differentiates) the float32 einsum under the mask built from
+    indices. A prompt's padding is on ONE side, so its valid keys are adjacent
+    and a window of columns is a window of tokens."""
+    if window is None:
+        return attention_op(q, k, v, causal=True, impl=impl, mask=mask)
+    b, s = q.shape[0], q.shape[1]
+    if backend.resolve_attention_impl(impl) == "flash":
+        from neuronx_distributed_tpu.kernels.flash_attention import (
+            banded_flash_attention,
+        )
+
+        return banded_flash_attention(q, k, v, window=window, kv_valid=mask)
+    rows = jnp.arange(s, dtype=jnp.int32)
+    keep = (rows[:, None] >= rows[None, :]) & (rows[None, :] > rows[:, None] - window)
+    keep = jnp.broadcast_to(keep[None], (b, s, s))
+    if mask is not None:
+        keep = keep & mask.astype(jnp.bool_)[:, None, :]
+    return _masked_gqa_attention(q, k, v, keep)
 
 
 def cache_fingerprint(cache):

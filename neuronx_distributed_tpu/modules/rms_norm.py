@@ -28,12 +28,17 @@ class RMSNorm(nn.Module):
     param_dtype: Any = jnp.float32
     sequence_parallel_enabled: bool = False
     axis: str = mesh_lib.TP_AXIS
+    # the gain every channel starts at (a trained checkpoint carries its own)
+    weight_init: float = 1.0
 
     @nn.compact
     def __call__(self, x):
+        # 1.0 keeps ``ones_init``: every other model's init program stays as it was
+        init = (nn.initializers.ones_init() if self.weight_init == 1.0
+                else nn.initializers.constant(self.weight_init))
         weight = self.param(
             "weight",
-            nn.with_partitioning(nn.initializers.ones_init(), (None,)),
+            nn.with_partitioning(init, (None,)),
             (self.hidden_size,),
             self.param_dtype,
         )

@@ -155,6 +155,16 @@ class DisaggregatedServer:
                 "disaggregation does not speak speculative engines yet "
                 "(the draft cache would need its own handoff)"
             )
+        if getattr(engine.cache, "window", None) is not None:
+            from neuronx_distributed_tpu.serving.paging import (
+                WindowedCacheUnsupported,
+            )
+
+            raise WindowedCacheUnsupported(
+                "disaggregation hands a context over by its pages, and a "
+                "model with window layers frees a window layer's pages "
+                "behind the window: serve it coupled"
+            )
         if n_workers < 1:
             raise ValueError(f"n_workers must be >= 1, got {n_workers}")
         if prefills_per_step < 1:

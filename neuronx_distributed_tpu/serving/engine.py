@@ -616,6 +616,35 @@ class ServingEngine:
                 "and its paged decode kernels have no sharded form — serve "
                 "it on one chip (tp=None)"
             )
+        # window layers (modules/attention.py JoinedKVCache(window=)): the
+        # paged manager frees their pages behind the window, so whatever
+        # holds a context by its PAGES (the prefix cache and zero-copy
+        # sharing, tiering, a disaggregated handoff) lacks the freed ones,
+        # and a draft model or a quantized pool has no window kind
+        kv_window = getattr(
+            getattr(model, "config", None), "kv_cache_window", None
+        )
+        if kv_window is not None and kv_page_size is not None:
+            from neuronx_distributed_tpu.serving.paging import (
+                WindowedCacheUnsupported,
+            )
+
+            asked = {
+                "prefix_cache": prefix_cache not in ("auto", None, 0)
+                and getattr(prefix_cache, "enabled", True),
+                "kv_host_pages": kv_host_pages is not None,
+                "draft_model": draft_model is not None,
+                "quantize.kv": quantize is not None and quantize.kv is not None,
+            }
+            for what, on in asked.items():
+                if on:
+                    raise WindowedCacheUnsupported(
+                        f"{what} is not available for a model with window "
+                        f"layers (window {kv_window}): their pages behind "
+                        "the window are freed, so no context can be held, "
+                        "shared, spilled or drafted by its pages yet"
+                    )
+            prefix_cache = None   # "auto": on wherever the cache can have one
         self.tp = tp
         self._partitioner = None
         if tp is not None:
@@ -731,6 +760,7 @@ class ServingEngine:
             # must release those refs or the pool leaks
             prefix_cache.on_evict = self._on_prefix_evict
         self._prefix_reuses = 0  # reuse-attempt index (poison-hook schedule)
+        self._window_pages_freed_seen = 0
         self._steps_seen = 0  # step() index (flip_bits("params") schedule)
         self._prefill_model, self._decode_model = serving_clones(model)
         # scheduling policy (ISSUE 16): "fifo" (default — bit-identical to
@@ -752,6 +782,9 @@ class ServingEngine:
             self.cache = PagedCacheManager(
                 num_slots, max_seq_len, kv_page_size, kv_num_pages,
                 kv_quant=quantize.kv if quantize is not None else None,
+                # a block table and a pool a layer KIND for a model with
+                # window layers; None builds exactly the one-kind tree
+                window=kv_window, window_write_cols=decode_chunk_size,
             )
             self.cache.reclaim = self._reclaim_prefix_entry
         else:
@@ -852,6 +885,7 @@ class ServingEngine:
                 {"latent": "paged_latent_fused",
                  "indexed": "paged_sparse_fused",
                  "indexed_latent": "paged_sparse_latent_fused",
+                 "joined": "paged_walk_fused",
                  }.get(cache_kind, "paged_fused")
                 if self.paged_attention == "fused"
                 else "einsum" if cache_kind != "kv"  # the one-row-a-token kinds' only other path
@@ -2362,6 +2396,14 @@ class ServingEngine:
             )
         return int(round(self.metrics.kv_bytes_per_token_layer))
 
+    def _held_tokens(self) -> List[int]:
+        """Tokens each decoding slot holds (its prompt and what it emitted)."""
+        return [
+            len(r.prompt) + len(r.tokens)
+            for s, r in enumerate(self._slot_req)
+            if r is not None and self._active[s]
+        ]
+
     def _selection_stats(self) -> dict:
         """``ctx_tokens`` (tokens the decoding slots hold) and
         ``selected_tokens`` (``sum(min(held, topk))``: what a sparse-attention
@@ -2371,14 +2413,27 @@ class ServingEngine:
         topk = getattr(getattr(self.model, "config", None), "index_topk", None)
         if topk is None:
             return {}
-        held = [
-            len(r.prompt) + len(r.tokens)
-            for s, r in enumerate(self._slot_req)
-            if r is not None and self._active[s]
-        ]
+        held = self._held_tokens()
         return {
             "ctx_tokens": int(sum(held)),
             "selected_tokens": int(sum(min(n, int(topk)) for n in held)),
+        }
+
+    def _window_stats(self) -> dict:
+        """For a model with window layers, on the dispatch span: the pages
+        each kind's block table maps (host arithmetic from the two tables),
+        ``ctx_tokens`` (tokens the decoding slots hold) and ``window_tokens``
+        (``sum(min(held, window))``: what a window layer's decode step
+        attends). Empty for every other model."""
+        window = getattr(self.cache, "window", None)
+        if window is None:
+            return {}
+        held = self._held_tokens()
+        return {
+            "window_pages_mapped": self.cache.window_pages_mapped,
+            "full_pages_mapped": self.cache.pages_mapped,
+            "ctx_tokens": int(sum(held)),
+            "window_tokens": int(sum(min(n, int(window)) for n in held)),
         }
 
     def _sampled_slots(self) -> int:
@@ -3539,7 +3594,9 @@ class ServingEngine:
             tracing.STEP_DISPATCH, active=active_at_dispatch,
             kv_bytes_per_token_layer=self._kv_bytes_per_token_layer(),
             cursor=int(self.cache.cursor), row_columns=self.max_seq_len,
-            sampled_slots=sampled_slots, **self._selection_stats(),
+            sampled_slots=sampled_slots,
+            # one dict: both name ``ctx_tokens``
+            **{**self._selection_stats(), **self._window_stats()},
         ):
             cache_in = self.cache.take()
             attempt = self._dispatch_attempts
@@ -3607,6 +3664,7 @@ class ServingEngine:
         # chunk bound so corrupted output can never run the cursor away
         used = max(0, min(int(used), self.decode_chunk_size))
         self.cache.update_after_decode(new_cache, used)
+        self._count_window_pages_freed()
         # validate the block BEFORE any token reaches a stream: a poisoned
         # slot is quarantined and its chunk discarded; neighbors proceed
         bad = _validate_readback(
@@ -3656,6 +3714,16 @@ class ServingEngine:
         # chunks excluded
         if not self._decode_chunk.last_call_compiled:
             self.programs.observe_wall("decode_chunk", t2 - t0)
+
+    def _count_window_pages_freed(self) -> None:
+        """Counter ``serving_window_pages_freed``: the window kind's pages
+        the manager gave back since the last look (a model with window
+        layers; the cursor moved by a chunk or an admission)."""
+        total = getattr(self.cache, "window_pages_freed_total", 0)
+        if total > self._window_pages_freed_seen:
+            self.metrics.record_window_pages_freed(
+                total - self._window_pages_freed_seen)
+            self._window_pages_freed_seen = total
 
     def _recover_dispatch(self, cache_in, exc: Exception,
                           draft_in=None) -> None:

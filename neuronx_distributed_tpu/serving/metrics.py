@@ -122,6 +122,9 @@ _COUNTERS = (
     # the two read equal on all-greedy traffic
     ("chunks_dispatched", "serving_decode_chunks_dispatched", True),
     ("greedy_chunks_dispatched", "serving_greedy_chunks_dispatched", True),
+    # pages of the window kind's pool given back behind a slot's window (a
+    # model with window layers: serving/paging.py)
+    ("window_pages_freed", "serving_window_pages_freed", True),
 )
 
 _HEALTH_CODES = {"ok": 0, "degraded": 1, "draining": 2, "halted": 3}
@@ -453,6 +456,10 @@ class ServingMetrics:
         if not sampled_slots:
             self._inc("greedy_chunks_dispatched")
 
+    def record_window_pages_freed(self, n: int) -> None:
+        """``n`` pages of the window kind went back to its allocator."""
+        self._inc("window_pages_freed", n)
+
     # --- fault tolerance ----------------------------------------------------
 
     def record_shed(self, req, now: float, where: str) -> None:
@@ -751,6 +758,7 @@ class ServingMetrics:
             "chunks": self.chunks,
             "chunks_dispatched": self.chunks_dispatched,
             "greedy_chunks_dispatched": self.greedy_chunks_dispatched,
+            "window_pages_freed": self.window_pages_freed,
             "decode_dispatch_s": self.decode_dispatch_s,
             "decode_readback_s": self.decode_readback_s,
             "chunk_tokens_per_sec": (

@@ -36,6 +36,16 @@ byte of the decode math untouched:
   it are requeued, the slot indices stay in rotation, and capacity degrades
   by one page instead of one permanent slot row.
 
+* Window layers (``modules/attention.JoinedKVCache(window=)``): a model whose
+  stack mixes window and full attention layers gets a block table and a pool
+  a layer KIND. The full kind is everything above; the window kind's pool is
+  sized for the window (``num_slots x (window / page_size + the chunk's write
+  window + slack)`` + the null page) and its table's entries behind a slot's
+  window go back to its allocator and read the null page, as the cursor moves
+  (:meth:`PagedCacheManager._free_behind_window`). The paged pytree is then
+  ``{"pages": bt, "window_pages": bt_w, "pool": tree}``; a model with one
+  kind of layer builds exactly ``{"pages", "pool"}``.
+
 Every manager instance registers in a weak set; ``check_all_live()`` runs
 the leak/ref-count invariant (:meth:`PagedCacheManager.check`) over all
 live managers — the serving test suite calls it after every test teardown.
@@ -54,14 +64,18 @@ import numpy as np
 from neuronx_distributed_tpu.modules.attention import (
     _SCALE_SUFFIX,
     PAGED_LEAVES,
+    WINDOW_PAGES,
     cache_batch_axis,
     cache_leaf_name,
     cache_node_at,
+    cache_node_window,
+    cache_windows,
     pool_scale_base,
     pool_scale_sibling,
     reset_cache,
     reset_cache_slot,
     seed_cache_prefix,
+    with_pool,
 )
 from neuronx_distributed_tpu.observability.programs import per_instance
 
@@ -116,6 +130,22 @@ class PageExhausted(RuntimeError):
     reclaim callback ran dry). Admission accounting exists to make this
     unreachable on the conservative path; the eager path treats it as the
     page-pressure wall (preempt-and-rewind)."""
+
+
+class WindowedCacheUnsupported(ValueError):
+    """Asked of a cache that has WINDOW layers (pages freed behind the
+    window) something it cannot give yet: a pinned or shared prefix lacks the
+    freed pages, and so does a staged, exported, spilled or seeded context; a
+    draft model, a quantized pool and ``tp > 1`` have no window kind."""
+
+
+# Pages a slot may hold on the window kind's table beyond ``window /
+# page_size`` + the chunk's write window + one for the window's misalignment:
+# room for the gap columns another slot's admission leaves inside a window
+# (a jump of the shared cursor to a longer prompt's bucket strands at most a
+# page at each of its ends). A slot that needs more meets the page-pressure
+# wall, which preempts and rewinds.
+WINDOW_SLACK_PAGES = 8
 
 
 class PageAllocator:
@@ -230,7 +260,8 @@ class PagedCacheManager:
 
     def __init__(self, num_slots: int, max_seq_len: int, page_size: int,
                  num_pages: Optional[int] = None,
-                 kv_quant: Optional[str] = None):
+                 kv_quant: Optional[str] = None,
+                 window: Optional[int] = None, window_write_cols: int = 8):
         if page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {page_size}")
         if max_seq_len % page_size != 0:
@@ -259,6 +290,34 @@ class PagedCacheManager:
             # change; smaller pools buy the packing win
             num_pages = num_slots * self.pages_per_row + 1
         self.alloc = PageAllocator(num_pages)
+        # the WINDOW kind (a model with window layers; None: no such kind):
+        # its own allocator, pool size and block table. ``window_write_cols``:
+        # the columns a decode chunk can write (the engine's chunk size)
+        self.window = window
+        self.alloc_w: Optional[PageAllocator] = None
+        self._tables_w = None
+        if window is not None:
+            if kv_quant is not None:
+                raise WindowedCacheUnsupported(
+                    "a cache with window layers has no quantized pool")
+            if window < 1:
+                raise ValueError(f"window must be >= 1, got {window}")
+            per_slot = (
+                -(-window // page_size) + 1
+                + (max(window_write_cols, 1) - 1) // page_size + 2
+                + WINDOW_SLACK_PAGES
+            )
+            self.window_pages_per_slot = min(-(-per_slot // 8) * 8, self.pages_per_row)
+            self.alloc_w = PageAllocator(num_slots * self.window_pages_per_slot + 1)
+            self._tables_w = np.zeros((num_slots, self.pages_per_row), np.int32)
+        # a slot's admission cursor; the shared cursor's jumps over columns
+        # nobody wrote (gap columns of every slot then decoding), ascending;
+        # the lowest page each slot's window row may still map
+        self._slot_target: List[Optional[int]] = [None] * num_slots
+        self._gaps: List[Tuple[int, int]] = []
+        self._w_lo = [0] * num_slots
+        self.window_pages_freed_total = 0
+        self.cursor_jumps_total = 0   # jumps that left gap columns in a slot's context
         self.cache = None  # {"pages": bt, "pool": tree}; lazy like the row mgr
         self.cursor = 0
         self._free = list(range(num_slots))
@@ -275,7 +334,8 @@ class PagedCacheManager:
         self.prefix_pages_shared_total = 0
         ps, n_log = page_size, self.pages_per_row
 
-        def _paged_admit(paged, row, slot, shift, cursor, ids, lo_page):
+        def _paged_admit(paged, row, slot, shift, cursor, ids, lo_page,
+                         ids_w=None):
             from neuronx_distributed_tpu.kernels.flash_decode import (
                 paged_write_pages_leaf,
                 quantize_page_block,
@@ -309,7 +369,12 @@ class PagedCacheManager:
                     if pool_scale_sibling(pool_in, path, base) is not None:
                         q, s = quantize_page_block(pages)
                         pages = q if base == name else s
-                    return paged_write_pages_leaf(pool_leaf, pages, ids)
+                    # a window layer's leaf takes the pages of its own kind:
+                    # those of the window's columns, the others the null page
+                    windowed = ids_w is not None and cache_node_window(
+                        cache_node_at(pool_in, path[:-1])) is not None
+                    return paged_write_pages_leaf(
+                        pool_leaf, pages, ids_w if windowed else ids)
                 ax = cache_batch_axis(name, pool_leaf.ndim)
                 if name == "kv_valid":
                     row_leaf = cache_node_at(row, path[:-1])[name]
@@ -320,7 +385,7 @@ class PagedCacheManager:
                 return jnp.full_like(pool_leaf, cursor)
 
             pool = jax.tree_util.tree_map_with_path(fn, pool_in)
-            return {"pages": paged["pages"], "pool": pool}
+            return with_pool(paged, pool)
 
         def _seed_from_pages(pool, ids, m, start):
             """Batch-1 row whose columns [start, start+m) hold the first
@@ -529,6 +594,10 @@ class PagedCacheManager:
             # layer stacking); scales are real per-page HBM, so plan()
             # capacity math must charge them
             if name in PAGED_LEAVES or pool_scale_base(name) is not None:
+                if cache_node_window(
+                    cache_node_at(self.cache["pool"], path[:-1])
+                ) is not None:
+                    continue  # the window kind's pool is not the planner's unit
                 pages_ax = max(int(leaf.shape[leaf.ndim - 4]), 1)
                 total += int(leaf.nbytes) // pages_ax
         return total
@@ -563,6 +632,20 @@ class PagedCacheManager:
     @property
     def pages_mapped(self) -> int:
         return int((self._tables != 0).sum())
+
+    @property
+    def window_pages_mapped(self) -> int:
+        """Pages the window kind's table maps (0 without window layers)."""
+        return 0 if self._tables_w is None else int((self._tables_w != 0).sum())
+
+    def _refuse_windowed(self, what: str) -> None:
+        if self.window is not None:
+            raise WindowedCacheUnsupported(
+                f"{what} is not available for a cache with window layers: a "
+                "window layer's pages behind the window are freed, so a "
+                "context cannot be pinned, shared, staged, exported, spilled "
+                "or seeded from its pages"
+            )
 
     @property
     def seed_compilations(self) -> int:
@@ -601,7 +684,12 @@ class PagedCacheManager:
         """Smallest cursor >= ``base`` placing a p-token context's first
         token on a page boundary (``(target - p) % page_size == 0``) — the
         alignment every paged admission enforces so whole context pages are
-        shareable. Costs < page_size gap columns, invisible to the math."""
+        shareable. Costs < page_size gap columns, invisible to the math. A
+        cache with window layers shares no page, so its admissions are not
+        aligned: every gap column of a slot then decoding lies in its window
+        for ``window`` steps."""
+        if self.window is not None:
+            return base
         return base + (-(base - p)) % self.page_size
 
     def page_span(self, lo_col: int, hi_col: int) -> int:
@@ -650,6 +738,7 @@ class PagedCacheManager:
     def pin_pages(self, ids: Sequence[int]) -> None:
         """A prefix entry takes a reference on each page (insert-on-miss:
         the slot's own context pages become shared storage, zero copies)."""
+        self._refuse_windowed("pinning a prefix's pages")
         for pid in ids:
             self.alloc.ref(int(pid))
             self._pins[int(pid)] = self._pins.get(int(pid), 0) + 1
@@ -720,6 +809,64 @@ class PagedCacheManager:
                 # recompile the decode program (decode_compilations pin)
                 pages = self.placement({"pages": pages})["pages"]
             self.cache = dict(self.cache, pages=pages)
+            self._upload_window_table()
+
+    def _upload_window_table(self) -> None:
+        if self.cache is not None and self._tables_w is not None:
+            self.cache = {**self.cache, WINDOW_PAGES: jnp.asarray(self._tables_w)}
+
+    # --- the window kind ------------------------------------------------------
+
+    def _window_floor(self, slot: int) -> int:
+        """The lowest column the query slot ``slot`` writes at the cursor may
+        attend: its window counted in TOKENS. A slot's context is adjacent
+        columns up to its admission cursor, then every column but the shared
+        cursor's jumps (``_gaps``: columns nobody wrote)."""
+        start, target = self._slot_start[slot], self._slot_target[slot]
+        left, pos = self.window - 1, self.cursor   # tokens wanted below the cursor
+        for a, b in reversed(self._gaps):
+            if a < target or b > pos:
+                continue
+            if pos - b >= left:
+                break
+            left -= pos - b
+            pos = a
+        return max(pos - left, start)
+
+    def _free_behind_window(self) -> int:
+        """Give back every window-kind page that lies wholly below its slot's
+        window (its last column under :meth:`_window_floor`): the entry reads
+        the null page from now on. Returns the pages freed."""
+        if self.window is None:
+            return 0
+        freed = 0
+        for slot, start in enumerate(self._slot_start):
+            if start is None:
+                continue
+            hi = self._window_floor(slot) // self.page_size
+            lo = self._w_lo[slot]
+            if hi <= lo:
+                continue
+            row = self._tables_w[slot, lo:hi]
+            for pid in row[row != 0]:
+                self.alloc_w.deref(int(pid))
+                freed += 1
+            row[:] = 0
+            self._w_lo[slot] = hi
+        if freed:
+            self.window_pages_freed_total += freed
+            self._upload_window_table()
+        return freed
+
+    def _note_cursor_jump(self, old: int, new: int, but: int) -> None:
+        """The shared cursor moved from ``old`` to ``new`` with nothing
+        written between: gap columns of every slot holding a context (``but``
+        the one being admitted)."""
+        if self.window is not None and new > old and any(
+            st is not None and s != but for s, st in enumerate(self._slot_start)
+        ):
+            self._gaps.append((old, new))
+            self.cursor_jumps_total += 1
 
     def allocate_from(self, row_cache) -> None:
         """Build the page pool + block table from a batch-1 prefill row's
@@ -732,12 +879,21 @@ class PagedCacheManager:
         from neuronx_distributed_tpu.modules.attention import _rebuild_tree
         from neuronx_distributed_tpu.utils.tree import path_keys
 
-        num_pages, ps = self.alloc.num_pages, self.page_size
+        ps = self.page_size
+        windows = cache_windows(row_cache)
+        if set(windows.values()) != ({self.window} if self.window is not None else set()):
+            raise ValueError(
+                f"the model's cache has window layers {sorted(set(windows.values()))} "
+                f"and the manager was built for window={self.window}: the "
+                "engine reads it from model.config.kv_cache_window"
+            )
         items = []
         for path, leaf in jax.tree_util.tree_flatten_with_path(row_cache)[0]:
             keys = tuple(path_keys(path))
             name = keys[-1]
             ax = cache_batch_axis(name, leaf.ndim)
+            # a window layer's leaves live in the window kind's pool
+            num_pages = (self.alloc_w if keys[:-1] in windows else self.alloc).num_pages
             if name in PAGED_LEAVES:
                 lead = leaf.shape[:ax]
                 tail = leaf.shape[ax + 2:]  # (Hkv, D)
@@ -773,12 +929,14 @@ class PagedCacheManager:
         }
         if self.placement is not None:
             self.cache = self.placement(self.cache)
+        self._upload_window_table()
 
     def allocate_like(self, other: "PagedCacheManager") -> None:
         """Build this pool from ANOTHER manager's allocated pool structure
         (own ``num_pages``/``num_slots`` geometry) — the distinct-pool
         disaggregation path's decode-side bootstrap, where the decode
         engine may never have run a prefill of its own."""
+        self._refuse_windowed("a pool built from another manager's")
         if other.cache is None:
             raise RuntimeError("source manager has no allocated pool")
         if self.cache is not None:
@@ -838,7 +996,7 @@ class PagedCacheManager:
                 "prompt's last token cannot land left of its own start"
             )
         start = target - p
-        if start % ps != 0:
+        if start % ps != 0 and self.window is None:
             raise ValueError(
                 f"context start {start} not page-aligned (page_size {ps}) — "
                 "use aligned_target for the cursor"
@@ -854,9 +1012,22 @@ class PagedCacheManager:
             )
         if (self._tables[slot] != 0).any():
             raise ValueError(f"slot {slot} still maps pages (not freed?)")
+        if m_shared:
+            self._refuse_windowed("mapping a shared prefix's pages")
         own_lo = (start + m_shared) // ps
-        n_own = -(-(p - m_shared) // ps)
-        own = self._alloc_pages(n_own)
+        n_own = self.page_span(start + m_shared, target)
+        # the window kind: the pages of the columns the next query can attend
+        w_lo = max(start, target - self.window + 1) // ps if self.window is not None else 0
+        own_w = (
+            self.alloc_w.alloc(self.page_span(w_lo * ps, target))
+            if self.window is not None else []
+        )
+        try:
+            own = self._alloc_pages(n_own)
+        except PageExhausted:
+            for pid in own_w:
+                self.alloc_w.deref(pid)
+            raise
         s0 = start // ps
         for j in range(n_sh):
             pid = int(shared_ids[j])
@@ -866,12 +1037,21 @@ class PagedCacheManager:
         for j, pid in enumerate(own):
             self._tables[slot, own_lo + j] = pid
         self._slot_start[slot] = start
+        self._slot_target[slot] = target
         # device roll-in: one compiled program per (row bucket, n_adm)
         n_adm = min(padded_len // ps + 1, n_log)
         lo_c = min(own_lo, n_log - n_adm)
         ids_arr = np.zeros((n_adm,), np.int32)
         for j, pid in enumerate(own):
             ids_arr[own_lo - lo_c + j] = pid
+        extra = ()
+        if self.window is not None:
+            ids_w = np.zeros((n_adm,), np.int32)
+            for j, pid in enumerate(own_w):
+                self._tables_w[slot, w_lo + j] = pid
+                ids_w[w_lo - lo_c + j] = pid
+            self._w_lo[slot] = w_lo
+            extra = (jnp.asarray(ids_w),)
         self.cache = self._admit_fn(
             self.cache, row_cache,
             jnp.asarray(slot, jnp.int32),
@@ -879,15 +1059,20 @@ class PagedCacheManager:
             jnp.asarray(target, jnp.int32),
             jnp.asarray(ids_arr),
             jnp.asarray(lo_c, jnp.int32),
+            *extra,
         )
+        self._note_cursor_jump(self.cursor, target, but=slot)
         self.cursor = target
         self._upload_tables()
+        # the jump may have carried the other slots' windows past pages
+        self._free_behind_window()
 
     def seed_row(self, page_ids: Sequence[int], m: int, start: int):
         """Batch-1 row whose columns [start, start+m) read the shared pages
         — the zero-copy prefix hit's suffix-prefill substrate. Pool pages
         are gathered for COMPUTE only (nothing allocated, nothing written;
         ``PageAllocator.copy_bytes`` untouched)."""
+        self._refuse_windowed("a row seeded from shared pages")
         if self.cache is None:
             raise RuntimeError("no cache allocated yet (nothing to seed from)")
         return self._seed_fn(
@@ -907,6 +1092,7 @@ class PagedCacheManager:
         releases them. This is the prefill worker's half of the
         disaggregated handoff — the decode side then binds the pages by
         block-table mapping alone."""
+        self._refuse_windowed("staging a context without a slot")
         if self.cache is None:
             if self.cursor > 0:
                 raise RuntimeError(
@@ -1032,6 +1218,7 @@ class PagedCacheManager:
         context — a REAL device transfer, charged to
         ``PageAllocator.copy_bytes`` (the accounting that proves the
         shared-pool handoff moved nothing)."""
+        self._refuse_windowed("importing an exported context")
         if self.cache is None:
             raise RuntimeError(
                 "import_pages needs an allocated pool — serve one "
@@ -1063,6 +1250,7 @@ class PagedCacheManager:
         with host-numpy blocks. Runs only on the reclaim valve (a page-
         pressure event, never a steady chunk), so the pinned per-chunk
         budgets are untouched."""
+        self._refuse_windowed("spilling pages to the host tier")
         if self.cache is None:
             raise RuntimeError("spill needs an allocated pool")
         from neuronx_distributed_tpu.utils.tree import path_keys
@@ -1129,20 +1317,31 @@ class PagedCacheManager:
         ps, n_log = self.page_size, self.pages_per_row
         lo = self.cursor // ps
         hi = min(n_log, -(-(self.cursor + width) // ps))
-        need = [
-            (int(s), j)
-            for s in active_slots
-            for j in range(lo, hi)
-            if self._tables[int(s), j] == 0
-        ]
-        if not need:
+        def unmapped(tables):
+            return [
+                (int(s), j)
+                for s in active_slots
+                for j in range(lo, hi)
+                if tables[int(s), j] == 0
+            ]
+
+        need = unmapped(self._tables)
+        need_w = unmapped(self._tables_w) if self.window is not None else []
+        if not need and not need_w:
             return True
+        ids_w: List[int] = []
         try:
+            if need_w:
+                ids_w = self.alloc_w.alloc(len(need_w))
             ids = self._alloc_pages(len(need))
         except PageExhausted:
+            for pid in ids_w:   # either kind short is the wall: take nothing
+                self.alloc_w.deref(pid)
             return False
         for (s, j), pid in zip(need, ids):
             self._tables[s, j] = pid
+        for (s, j), pid in zip(need_w, ids_w):
+            self._tables_w[s, j] = pid
         self._upload_tables()
         return True
 
@@ -1153,11 +1352,7 @@ class PagedCacheManager:
         rotation."""
         if self.cache is not None:
             self.cache = self._free_fn(self.cache, jnp.asarray(slot, jnp.int32))
-        row = self._tables[slot]
-        for pid in row[row != 0]:
-            self.alloc.deref(int(pid))
-        self._tables[slot] = 0
-        self._slot_start[slot] = None
+        self._drop_slot_mappings(slot)
         self._upload_tables()
         if slot not in self._quarantined and slot not in self._free:
             self._free.append(slot)
@@ -1170,13 +1365,29 @@ class PagedCacheManager:
     def restore(self, cache) -> None:
         self.cache = cache
 
+    def _drop_slot_mappings(self, slot: int) -> None:
+        """Deref every page the slot maps, on both kinds' tables."""
+        for tables, alloc in self._kinds():
+            row = tables[slot]
+            for pid in row[row != 0]:
+                alloc.deref(int(pid))
+            tables[slot] = 0
+        self._slot_start[slot] = None
+        self._slot_target[slot] = None
+        self._w_lo[slot] = 0
+        if not any(st is not None for st in self._slot_start):
+            self._gaps.clear()   # nobody is left whose window they lie in
+
+    def _kinds(self):
+        """``(block table, allocator)`` of each kind this manager has."""
+        kinds = [(self._tables, self.alloc)]
+        if self.window is not None:
+            kinds.append((self._tables_w, self.alloc_w))
+        return kinds
+
     def _release_all_mappings(self) -> None:
         for slot in range(self.num_slots):
-            row = self._tables[slot]
-            for pid in row[row != 0]:
-                self.alloc.deref(int(pid))
-            self._tables[slot] = 0
-            self._slot_start[slot] = None
+            self._drop_slot_mappings(slot)
 
     def recover(self, cache) -> bool:
         """Post-failed-dispatch salvage (the SlotCacheManager contract):
@@ -1217,6 +1428,7 @@ class PagedCacheManager:
     def update_after_decode(self, new_cache, steps: int = 1) -> None:
         self.cache = new_cache
         self.cursor += steps
+        self._free_behind_window()
 
     def reset(self) -> None:
         """Rewind the cursor, invalidate every slot's context, and release
@@ -1237,15 +1449,37 @@ class PagedCacheManager:
         counts reconcile with the mappers + pins, no slot double-maps a
         page, and the free list is duplicate-free. AssertionError with the
         offending page on any violation."""
-        a = self.alloc
+        self._check_kind(self.alloc, self._tables, self._pins, self._staged)
+        if self.window is not None:
+            # the window kind: mapped by its own table alone (nothing pins or
+            # stages a window page), a slot never past its share + the slack
+            self._check_kind(self.alloc_w, self._tables_w, {}, {})
+            for s in range(self.num_slots):
+                held = int((self._tables_w[s] != 0).sum())
+                assert held <= self.window_pages_per_slot, (
+                    f"slot {s} maps {held} window pages, over "
+                    f"{self.window_pages_per_slot}"
+                )
+                assert not self._tables_w[s, :self._w_lo[s]].any(), (
+                    f"slot {s} maps a window page below its freed edge"
+                )
+        # tiered KV (ISSUE 19): a prefetch hold is an overlay on a PINNED
+        # page, never a reference of its own — a hold on an unpinned page
+        # means the release path lost track of a claimed prefetch
+        for pid in self._prefetch_hold:
+            assert self._pins.get(pid, 0) > 0, (
+                f"page {pid} carries a prefetch hold but no prefix pin"
+            )
+
+    def _check_kind(self, a, tables, pins, staged) -> None:
         free = set(a._free)
         assert len(free) == len(a._free), "free list has duplicates"
-        assert 0 not in free and 0 not in a._refs and 0 not in self._pins, (
+        assert 0 not in free and 0 not in a._refs and 0 not in pins, (
             "reserved null page 0 entered circulation"
         )
         mapped: Dict[int, int] = {}
         for s in range(self.num_slots):
-            row = [int(p) for p in self._tables[s] if p != 0]
+            row = [int(p) for p in tables[s] if p != 0]
             assert len(row) == len(set(row)), (
                 f"slot {s} double-maps a page: {row}"
             )
@@ -1253,14 +1487,14 @@ class PagedCacheManager:
                 mapped[pid] = mapped.get(pid, 0) + 1
         for pid in range(1, a.num_pages):
             expect = (
-                mapped.get(pid, 0) + self._pins.get(pid, 0)
-                + self._staged.get(pid, 0)
+                mapped.get(pid, 0) + pins.get(pid, 0)
+                + staged.get(pid, 0)
             )
             have = a.refcount(pid)
             assert have == expect, (
                 f"page {pid}: refcount {have} != mapped({mapped.get(pid, 0)})"
-                f" + pinned({self._pins.get(pid, 0)})"
-                f" + staged({self._staged.get(pid, 0)})"
+                f" + pinned({pins.get(pid, 0)})"
+                f" + staged({staged.get(pid, 0)})"
             )
             states = [
                 pid in free,
@@ -1271,11 +1505,4 @@ class PagedCacheManager:
                 f"page {pid} is not exactly one of free/referenced/"
                 f"quarantined: free={pid in free} refs={have} "
                 f"quarantined={pid in a._quarantined}"
-            )
-        # tiered KV (ISSUE 19): a prefetch hold is an overlay on a PINNED
-        # page, never a reference of its own — a hold on an unpinned page
-        # means the release path lost track of a claimed prefetch
-        for pid in self._prefetch_hold:
-            assert self._pins.get(pid, 0) > 0, (
-                f"page {pid} carries a prefetch hold but no prefix pin"
             )

@@ -449,7 +449,9 @@ def _engine_programs(topo, n_devices, *, layers=8, slots=8, seq=4096,
     row = jax.eval_shape(lambda p, i, m: prefill(p, i, m)[1], params, ids, mask)
 
     def pool_of(row):
-        mgr = PagedCacheManager(slots, seq, page)
+        # a model with window layers: a block table and a pool a layer kind
+        mgr = PagedCacheManager(slots, seq, page, window=engine.cache.window,
+                                window_write_cols=engine.decode_chunk_size)
         mgr.allocate_from(row)
         return mgr.cache
 
@@ -827,3 +829,118 @@ def test_glm5_engine_programs_compile_and_fit(topo):
     hidden = int(config["model"]["hidden_size"])
     slots_rows = longest * int(config["model"]["num_experts_per_tok"])
     assert not re.search(r"bf16\[%d,%d\]" % (slots_rows, hidden), text), "a T x k x H dispatch buffer"
+
+
+# --- Trinity-Large-Preview: window and full attention layers in one paged cache ------
+
+
+def _trinity(cfg, seq):
+    from perfbench.families import afmoe
+
+    return afmoe.build(cfg, runner="serve", max_seq_len=seq)
+
+
+# what the described-v5e compile of the configured depth showed for the two
+# programs' temporaries (GiB; PR 39, the configuration's ``reduced_why``)
+TRINITY_DECODE_TEMP_GIB = 0.40
+TRINITY_PREFILL_TEMP_GIB = 1.41
+
+
+@pytest.mark.parametrize("kind", ["window", "full"])
+def test_walking_decode_kernel_compiles_at_trinity_geometry(topo, kind):
+    """Trinity's decode attention at the serve cell's shapes (8 slots of
+    32,768 columns, page 16, 48 query heads against 8 kv heads of 128, K and V
+    one joined leaf of (16, 128) a token): the kernel that walks the blocks a
+    slot maps, over the full kind's pool and over the window kind's (272
+    pages a slot), and the window pages' copies into it. Neither may copy
+    its pool leaf whole."""
+    from neuronx_distributed_tpu.kernels.flash_decode import (
+        paged_scatter_window_pages_dma,
+        paged_walk_decode_attention,
+    )
+
+    s = _one_chip(topo)
+    b, n_log, page = 8, 2048, 16
+    pages = b * (n_log if kind == "full" else 272) + 1
+    table, valid = s((b, n_log), jnp.int32), s((b, n_log * page), jnp.bool_)
+    floor = s((b,), jnp.int32) if kind == "window" else None
+
+    def step(q, pool, win, bt, pos, ok, lo):
+        pool = paged_scatter_window_pages_dma(pool, win, bt, pos[0] // page)
+        return paged_walk_decode_attention(q, pool, bt, pos, kv_valid=ok, floor=lo, page_size=page), pool
+
+    # the pool is donated, as the decode scan's carry is: the copies' kernel writes it in place
+    text = jax.jit(step, donate_argnums=(1,)).lower(
+        s((b, 1, 48, 128)), s((pages, page, 16, 128)), s((b, 2 * page, 16, 128)), table,
+        s((1,), jnp.int32), valid, floor).compile().as_text()
+    assert text.count(KERNEL) >= 2
+    assert not re.search(r"bf16\[%d,%d,16,128\]\S* copy\(" % (pages, page), text), "the joined pool leaf is copied whole"
+
+
+@pytest.mark.parametrize("seq", [4096, 16384])
+@pytest.mark.parametrize("window", [4096, None], ids=["window", "full"])
+def test_banded_flash_prefill_compiles_at_trinity_geometry(topo, seq, window):
+    """A window layer's banded forward and a full layer's flash forward (the
+    padding mask as segment -1), as ``AfmoeAttention`` calls them."""
+    from neuronx_distributed_tpu.modules.attention import window_prefill_attention
+
+    s = _one_chip(topo)
+    text = _compiled_text(
+        lambda q, k, v, ok: window_prefill_attention(q, k, v, window, impl="flash", mask=ok),
+        s((1, seq, 48, 128)), s((1, seq, 8, 128)), s((1, seq, 8, 128)), s((1, seq), jnp.bool_))
+    assert KERNEL in text
+
+
+@pytest.mark.slow
+def test_trinity_engine_programs_compile_and_fit(topo):
+    """The benchmark configuration's programs (``perfbench/configs/
+    trinity-large-serve.json``: its depth, its 32 held experts and vocabulary
+    slice, 8 slots of 32,768, page 16): the fused decode chunk with BOTH
+    kinds' pools carried (the full kind's rows, the window kind's 272 pages a
+    slot) and the longest prompt's prefill through the banded flash forward
+    (window layers) and the flash forward (the full layer) with the held
+    experts' sorted loop, both with Pallas kernels and inside
+    the chip's memory. The decode program holds no row-sized array and copies
+    no pool leaf; both programs' temporaries are what the configuration's
+    ``reduced_why`` states + 10%."""
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    with open(os.path.join(root, "perfbench", "configs", "trinity-large-serve.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(root, "perfbench", "traffic", "mixedctx_closed.json")) as f:
+        longest = int(json.load(f)["prompt_len"]["max"])
+    seq = int(config["serving"]["max_seq_len"])
+    engine, lower_decode, lower_prefill, pool_shards = _engine_programs(
+        topo, 1, slots=int(config["serving"]["num_slots"]), seq=seq,
+        bucket=longest, model=_trinity(config["model"], seq),
+    )
+    assert engine.programs.resolved == {
+        "attention": "flash", "decode_attention": "paged_walk_fused",
+        "paged_attention": "fused", "moe_decode": "held",
+    }
+    # one full layer's rows, four window layers' 8 x 272 pages (+ the null page each)
+    assert sorted(pool_shards) == [(2177, 16, 16, 128)] * 4 + [(16385, 16, 16, 128)]
+    decode = lower_decode().compile()
+    assert KERNEL in decode.as_text()
+    live = _fits(decode, 15 * 1024**3)
+    text = decode.as_text()
+    shapes = {"bf16[%s]" % ",".join(map(str, s)) for s in pool_shards}
+    copies = _copies_inside_loops(text, shapes)
+    assert not copies, f"{len(copies)} whole-pool copies per decode step: " + "; ".join(copies[:3])
+    assert not [ln for ln in text.splitlines() if " copy(" in ln and re.search(r"bf16\[(16385|2177),16,16,128\]", ln)], \
+        "a pool leaf is copied"
+    views = _arrays_of_a_views_size(text, [(16385, 16, 16, 128)])
+    assert not views, f"the decode program builds a logical K/V view: {views}"
+    temp = decode.memory_analysis().temp_size_in_bytes
+    print(f"trinity decode: live {live / 2**30:.2f} GiB, temporaries {temp / 2**30:.2f} GiB")
+    assert temp < 1.1 * TRINITY_DECODE_TEMP_GIB * 2**30, f"{temp / 2**30:.2f} GiB of decode temporaries"
+    prefill = lower_prefill().compile()
+    assert KERNEL in prefill.as_text()
+    live = _fits(prefill, 15 * 1024**3)
+    temp = prefill.memory_analysis().temp_size_in_bytes
+    print(f"trinity prefill[{longest}]: live {live / 2**30:.2f} GiB, temporaries {temp / 2**30:.2f} GiB")
+    assert temp < 1.1 * TRINITY_PREFILL_TEMP_GIB * 2**30, f"{temp / 2**30:.2f} GiB of prefill temporaries"
+    text = prefill.as_text()
+    wide = [m.group(0) for m in re.finditer(r"(f32|s32|u32|pred|s8)\[[\d,]*%d,%d\]" % (longest, longest), text)]
+    assert not wide, wide[:3]

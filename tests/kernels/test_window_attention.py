@@ -1,0 +1,144 @@
+"""The two kernels a stack of window and full attention layers runs on, in
+interpret mode: the paged GQA decode kernel that walks the blocks a slot maps
+(``kernels/flash_decode.paged_walk_decode_attention``) against the gather
+transport and the float32 einsum under a mask built from indices, and a
+prefill of either kind (a window layer's banded flash forward, ``kernels/
+flash_attention.banded_flash_attention``; a full layer's ``flash_attention``)
+against ``xla_attention``'s mathematics under an index mask."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from neuronx_distributed_tpu.kernels import flash_decode
+from neuronx_distributed_tpu.kernels.flash_attention import banded_flash_attention
+from neuronx_distributed_tpu.kernels.flash_decode import (
+    paged_gather_leaf,
+    paged_walk_decode_attention,
+)
+from neuronx_distributed_tpu.modules.attention import (
+    _masked_gqa_attention,
+    split_kv,
+    window_floor,
+    window_keep,
+    window_prefill_attention,
+    xla_attention,
+)
+
+B, H, HKV, D, PS, N_LOG = 3, 4, 2, 16, 8, 32
+
+
+@pytest.fixture()
+def small_blocks(monkeypatch):
+    monkeypatch.setattr(flash_decode, "WALK_BLOCK_TOKENS", 32)   # four pages a block
+
+
+def paged_case(seed=0):
+    """Three slots over a pool: one context from column 26, one from 80 with
+    invalid (gap) columns inside it, one slot that maps nothing."""
+    rng = np.random.default_rng(seed)
+    pool = jnp.asarray(rng.standard_normal((1 + B * N_LOG, PS, 2 * HKV, D)), jnp.float32)
+    ids = rng.permutation(np.arange(1, 1 + B * N_LOG))
+    cur = 157
+    bt = np.zeros((B, N_LOG), np.int32)
+    bt[0, 3:20], bt[1, 10:20] = ids[:17], ids[17:27]
+    valid = np.zeros((B, N_LOG * PS), bool)
+    valid[0, 26:cur + 1] = valid[1, 80:cur + 1] = True
+    valid[1, 100:109] = False
+    q = jnp.asarray(rng.standard_normal((B, 1, H, D)), jnp.float32)
+    return pool, bt, valid, q, cur
+
+
+@pytest.mark.parametrize("window", [None, 40, 8], ids=["full", "window_40", "window_8"])
+def test_the_walking_kernel_is_the_gather_transport_under_an_index_mask(small_blocks, window):
+    pool, bt, valid, q, cur = paged_case()
+    q_pos = jnp.asarray([cur], jnp.int32)
+    k, v = split_kv(paged_gather_leaf(pool, jnp.asarray(bt), PS))
+    want = _masked_gqa_attention(q, k, v, window_keep(jnp.asarray(valid), q_pos, window))
+    floor, table = None, bt
+    if window is not None:
+        floor = window_floor(jnp.asarray(valid), cur, window)
+        table = bt.copy()
+        for row, lo in enumerate(np.asarray(floor)):
+            table[row, :lo // PS] = 0          # what the manager freed behind the window
+    got = paged_walk_decode_attention(
+        q, pool, jnp.asarray(table), q_pos, kv_valid=jnp.asarray(valid), floor=floor, page_size=PS)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-6)
+    assert not np.asarray(got[2]).any()        # the slot that maps nothing
+
+
+def test_the_window_is_counted_over_valid_columns_not_columns():
+    """Slot 1's nine gap columns lie inside a window of 64 tokens: its floor
+    is nine columns lower than the gap-free slot's."""
+    _, _, valid, _, cur = paged_case()
+    floor = np.asarray(window_floor(jnp.asarray(valid), cur, 64))
+    assert floor[0] == cur - 63 and floor[1] == cur - 63 - 9 and floor[2] == cur
+    keep = np.asarray(window_keep(jnp.asarray(valid), jnp.asarray([cur]), 64))
+    assert keep[0, 0].sum() == keep[1, 0].sum() == 64
+    assert keep[1, 0].argmax() == floor[1]
+
+
+def test_the_walking_kernel_refuses_what_it_does_not_do(small_blocks):
+    pool, bt, valid, q, cur = paged_case()
+    with pytest.raises(ValueError, match="one query row"):
+        paged_walk_decode_attention(jnp.concatenate([q, q], 1), pool, jnp.asarray(bt), jnp.asarray([cur, cur + 1]))
+    with pytest.raises(ValueError, match="joined K/V pool"):
+        paged_walk_decode_attention(q, pool[:, :, :3], jnp.asarray(bt), jnp.asarray([cur]))
+
+
+@pytest.mark.parametrize("window", [None, 32, 100], ids=["causal", "window_32", "window_100"])
+@pytest.mark.parametrize("padded", [False, True], ids=["whole", "left_padded"])
+def test_a_prefill_of_either_kind_is_attention_under_an_index_mask(window, padded):
+    """A window layer's banded forward, and a full layer's route (``window=None``:
+    the flash forward every other model's prefill runs, padding as segment -1)."""
+    b, s = 2, 256
+    keys = jax.random.split(jax.random.PRNGKey(3), 3)
+    q = jax.random.normal(keys[0], (b, s, H, D))
+    k = jax.random.normal(keys[1], (b, s, HKV, D))
+    v = jax.random.normal(keys[2], (b, s, HKV, D))
+    valid = jnp.arange(s)[None] >= jnp.asarray([[0], [37]]) if padded else None
+    rows = jnp.arange(s)
+    band = jnp.ones((s, s), bool) if window is None else rows[None, :] > rows[:, None] - window
+    # xla_attention's segment path takes a (B, Sq, Sk) pair mask in no form: the
+    # index mask goes through the same float32 einsum
+    keep = jnp.broadcast_to((rows[:, None] >= rows[None, :]) & band, (b, s, s))
+    if valid is not None:
+        keep = keep & valid[:, None, :]
+    want = _masked_gqa_attention(q, k, v, keep)
+    if window is None:
+        got = window_prefill_attention(q, k, v, None, impl="flash", mask=valid)
+    else:
+        got = banded_flash_attention(q, k, v, window=window, kv_valid=valid, block_q=64, block_k=32)
+    rows_ok = np.ones((b, s), bool) if valid is None else np.asarray(valid)
+    np.testing.assert_allclose(np.asarray(got)[rows_ok], np.asarray(want)[rows_ok], atol=3e-6)
+    if window is None and not padded:
+        np.testing.assert_allclose(np.asarray(got), np.asarray(xla_attention(q, k, v)), atol=3e-6)
+    if padded and window is not None:
+        assert not np.asarray(got)[1, :37].any()       # a padded query keeps nothing: zeros
+
+
+@pytest.mark.parametrize("length", [100, 203])
+def test_a_length_that_is_no_multiple_of_a_tile_is_padded_and_cut(length):
+    """The engine's exact-length bucket at the row's end: 203 tokens run as
+    256, 100 as 104, and the rows that exist are what they were."""
+    keys = jax.random.split(jax.random.PRNGKey(5), 3)
+    q = jax.random.normal(keys[0], (1, length, H, D))
+    k = jax.random.normal(keys[1], (1, length, HKV, D))
+    v = jax.random.normal(keys[2], (1, length, HKV, D))
+    rows = jnp.arange(length)
+    keep = (rows[:, None] >= rows[None, :]) & (rows[None, :] > rows[:, None] - 32)
+    want = _masked_gqa_attention(q, k, v, keep[None])
+    got = banded_flash_attention(q, k, v, window=32)
+    assert got.shape == q.shape
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-6)
+
+
+def test_the_band_cuts_the_grid_to_the_key_blocks_it_can_overlap():
+    """16,384 tokens, a window of 4096, tiles of 512: ten key steps a query
+    block where a causal forward takes thirty-two."""
+    q = jax.ShapeDtypeStruct((1, 16384, 6, 128), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, 16384, 1, 128), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(lambda a, b, c: banded_flash_attention(a, b, c, window=4096))(q, kv, kv)
+    (eqn,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert eqn.params["grid_mapping"].grid == (1, 1, 32, 10)

@@ -426,28 +426,32 @@ def _slice_share(full, first, count):
     return share
 
 
+@pytest.mark.parametrize("devices", [4, 8], ids=["4_shares_of_4", "8_shares_of_2"])
 @pytest.mark.parametrize("tokens", [(1, 6), (2, 40)], ids=["decode_rows", "prefill_rows"])
-def test_all_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(tokens):
-    """Guide section 4: 16 experts over 4 devices, 4 held each. The routed
-    parts that the four shares give, plus the shared expert counted once,
-    are the uncut layer's output, for a decode step's rows and a prefill's."""
+def test_all_shares_and_the_shared_expert_once_add_up_to_the_uncut_layer(tokens, devices):
+    """Guide section 4: 16 experts over 4 devices, 4 held each (GLM-5's
+    share), or over 8, 2 held each (Trinity's: eight chips share a layer).
+    The routed parts that the shares give, plus the shared expert counted
+    once, are the uncut layer's output, for a decode step's rows and a
+    prefill's."""
     x = jax.random.normal(jax.random.PRNGKey(0), tokens + (H,), jnp.float32)
     uncut = _share_layer(None)
     params = uncut.init(jax.random.PRNGKey(1), x)
     want, _ = uncut.apply(params, x)
+    held = 16 // devices
     total, held_rows = 0.0, 0
-    for dev in range(4):
-        layer = _share_layer((4 * dev, 4))
-        p = _slice_share(params, 4 * dev, 4)
+    for dev in range(devices):
+        layer = _share_layer((held * dev, held))
+        p = _slice_share(params, held * dev, held)
         (out, _), stats = layer.apply(p, x, mutable=["stats"])
         total = total + out
         held_rows += int(stats["stats"]["held_rows"])
         assert int(stats["stats"]["routed_rows"]) == tokens[0] * tokens[1] * 4
     assert held_rows == tokens[0] * tokens[1] * 4     # every slot is held by exactly one share
     # every share added the shared expert: count it once
-    only_shared = _shared_only(_share_layer((0, 4)), _slice_share(params, 0, 4), x)
+    only_shared = _shared_only(_share_layer((0, held)), _slice_share(params, 0, held), x)
     np.testing.assert_allclose(
-        np.asarray(total - 3 * only_shared), np.asarray(want), rtol=2e-5, atol=2e-5)
+        np.asarray(total - (devices - 1) * only_shared), np.asarray(want), rtol=2e-5, atol=2e-5)
 
 
 def _shared_only(layer, params, x):

@@ -235,3 +235,29 @@ def test_the_readback_span_carries_the_rows_the_held_experts_computed(tmp_path):
         assert 0 <= a["held_rows"] <= a["routed_rows"]
     assert sum(a["held_rows"] for a in held) > 0
     assert all(set(a) == {"steps"} for a in stats(cfg, tmp_path / "whole.json"))
+
+
+@pytest.fixture(scope="module")
+def trinity():
+    return _programs("trinity-large-serve")
+
+
+def test_the_window_and_full_attention_programs_carry_their_kinds_names(trinity):
+    """``window_attn_dev_share_pct`` finds the scope ``attn.window``,
+    ``full_attn_dev_share_pct`` ``attn.full``; each kind's decode kernels (the
+    walk over the blocks a slot maps, the write window's page copies) are
+    called in ITS scope and so carry its name (``swa_decode_roofline`` reads
+    both), no ``attn._cached_attention`` and no ``kv_view`` between; the gate's
+    projection and product lie under ``attn.gate``; the held experts' path and
+    the shared expert under ``moe``."""
+    decode, prefill = trinity["decode_chunk"], trinity["prefill"]
+    assert re.search(r"HloModule (\S+?),", decode[1]).group(1) == "jit_chunk_fn"
+    assert re.search(r"HloModule (\S+?),", prefill[1]).group(1) == "jit_fn"
+    callers = {path.rsplit("/", 1)[-1] for path in KERNEL_CALL.findall(decode[0])}
+    assert callers == {"attn.window", "attn.full"}
+    kinds = {"attn.window", "attn.full", "attn.gate"}
+    assert kinds | {"attn", "kv_view", "moe", "moe.router", "moe.experts", "moe.shared",
+                    "sample", "lm_head"} <= _traced(decode)
+    assert kinds | {"moe", "moe.shared"} <= _optimized(decode)
+    assert kinds | {"moe", "moe.shared"} <= _traced(prefill)
+    assert {"attn.window", "attn.full"} <= _optimized(prefill)

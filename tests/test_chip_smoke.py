@@ -143,6 +143,29 @@ def test_glm_phase_alone_rehearsal():
     )
 
 
+def _tiny_trinity():
+    import json
+
+    path = os.path.join(os.path.dirname(_PATH), "tests", "benchmark", "data", "configs", "trinity-large-serve.json")
+    with open(path) as f:
+        return json.load(f)["model"]
+
+
+def test_trinity_phase_alone_rehearsal():
+    """``--only trinity`` at the CPU stand-in's size (a window of 32 under
+    contexts of 200, page 8, 4 of 16 experts held); float32, so the
+    tolerances are a float32 matmul's summation order and every control is
+    caught."""
+    trinity = chip_smoke.TrinitySize(
+        model=_tiny_trinity(), max_seq_len=512, slots=3, page=8, prompt_lens=(200, 90, 40), tail=32,
+        new_tokens=12, pool_tokens=10, logit_tol=1e-3, typical_tol=1e-4, attn_tol=1e-4, routed_tol=1e-4,
+        cache_tol=1e-5, gap_tol=1e-3, near_tie=0.0,
+    )
+    _assert_only_kernel_checks_fail(
+        chip_smoke.one_chip(0, jax.devices()[:1], TRAIN, SERVE, MLA, only="trinity", trinity=trinity)
+    )
+
+
 def test_moe_phase_alone_rehearsal():
     """``--only moe`` at a tiny size: every comparison must hold; which form
     is FASTER is the chip's to say (an interpreted kernel's time says nothing)."""
