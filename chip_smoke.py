@@ -96,8 +96,12 @@ added to the weights).
 
 ``--chips 4`` runs only the four-chip path and what it is compared with: a
 tp=4 + sequence-parallel train step against the same seeded step on one
-device of the same process, and ``ServingEngine(tp=4)`` against the
-mesh-free engine on the same requests.
+device of the same process, ``ServingEngine(tp=4)`` against the
+mesh-free engine on the same requests, and the training cell's step
+(CodeGen2-7B's widths at ten layers, 8 x 2048 tokens) rematerialised under
+"save nothing" and under the two named saves of ``modules/remat.py``: the
+same losses to bf16's rounding, each chip's peak bytes and the step's time
+printed, so the trade can be measured again in one call.
 
 Every phase also asserts which implementation ``"auto"`` resolved to (the
 program ledger's ``resolved`` record) and that the Pallas kernels are in the
@@ -235,24 +239,16 @@ def _bytes_on(tree, device) -> int:
 # --- train ---------------------------------------------------------------------
 
 
-def _train_steps(size: TrainSize, seed: int, devices, *, tp: int, sp: bool,
-                 steps: int):
-    """``steps`` Trainer steps on a (tp, dp=1) mesh over ``devices``.
+def _fit_steps(model, vocab_size: int, batch: int, seq: int, seed: int,
+               steps: int):
+    """``steps`` Trainer steps of ``model`` on the mesh that is set up.
     Returns the trainer and per-step (loss, grad_norm, wall_s)."""
     import jax
 
-    from neuronx_distributed_tpu.models.llama import LlamaForCausalLM
-    from neuronx_distributed_tpu.parallel import mesh as mesh_lib
     from neuronx_distributed_tpu.trainer import OptimizerConfig
     from neuronx_distributed_tpu.trainer.data import SyntheticTokens
     from neuronx_distributed_tpu.trainer.loop import Callback, Trainer
 
-    mesh_lib.destroy_model_parallel()
-    mesh_lib.initialize_model_parallel(
-        tensor_model_parallel_size=tp, devices=list(devices)
-    )
-    cfg = _llama(size, max_seq_len=size.seq, sequence_parallel=sp)
-    model = LlamaForCausalLM(cfg, attention_impl="auto")
     rows: List[Tuple[float, float, float]] = []
 
     class Capture(Callback):
@@ -269,8 +265,27 @@ def _train_steps(size: TrainSize, seed: int, devices, *, tp: int, sp: bool,
     trainer = Trainer(
         model=model, optimizer_config=OptimizerConfig(), callbacks=[Capture()]
     )
-    data = SyntheticTokens(cfg.vocab_size, size.batch, size.seq, seed=seed)
+    data = SyntheticTokens(vocab_size, batch, seq, seed=seed)
     trainer.fit(data, jax.random.PRNGKey(seed), steps)
+    return trainer, rows
+
+
+def _train_steps(size: TrainSize, seed: int, devices, *, tp: int, sp: bool,
+                 steps: int):
+    """``steps`` Trainer steps on a (tp, dp=1) mesh over ``devices``.
+    Returns the trainer, the config and per-step (loss, grad_norm, wall_s)."""
+    from neuronx_distributed_tpu.models.llama import LlamaForCausalLM
+    from neuronx_distributed_tpu.parallel import mesh as mesh_lib
+
+    mesh_lib.destroy_model_parallel()
+    mesh_lib.initialize_model_parallel(
+        tensor_model_parallel_size=tp, devices=list(devices)
+    )
+    cfg = _llama(size, max_seq_len=size.seq, sequence_parallel=sp)
+    model = LlamaForCausalLM(cfg, attention_impl="auto")
+    trainer, rows = _fit_steps(
+        model, cfg.vocab_size, size.batch, size.seq, seed, steps
+    )
     return trainer, cfg, rows
 
 
@@ -405,6 +420,90 @@ def tp_train_phase(size: TrainSize, seed: int, devices) -> Dict[str, bool]:
         "tp_train_params_split": max(per_device) < 0.3 * solo_bytes
         and min(per_device) > 0.2 * solo_bytes,
         "kernel_tp_train_step": all(kernels.values()) and bool(kernels),
+    }
+
+
+@dataclasses.dataclass(frozen=True)
+class RematSize:
+    """What the remat phase runs (defaults: the training cell's model and
+    step, ``perfbench/configs/codegen2-7b-train-tp4.json`` at 8 x 2048)."""
+
+    model: object = None          # published config.json keys; None = the benchmark configuration's
+    batch: int = 8
+    seq: int = 2048
+    steps: int = 4                # the first holds the compile; the time is the median of the rest
+    # in the order of the memory each keeps: a chip's peak never falls
+    policies: Tuple = (None, "mlp_up", "mlp_up+attn")
+    # How far a step's loss may lie from "save nothing"'s. In float32 (the
+    # CPU rehearsal) saving changes no arithmetic and the limit is 0. In bf16
+    # a tensor that is SAVED is rounded to bf16 where the compiler, computing
+    # it again inside one fusion, keeps the float32 it had: over 7 steps at
+    # the cell's shape five policies' losses lay within 2.3e-4 of "save
+    # nothing"'s, 6e-6 at step 1 (PERF.md section 6, PR 40). ONE reading: what
+    # a wrong gradient would read was not measured, so the limit is ten times
+    # it and no more.
+    loss_tol: float = 2e-3
+
+
+def remat_phase(size: RematSize, seed: int, devices) -> Dict[str, bool]:
+    """The trade a remat policy makes, on the training cell's step: the same
+    seeded steps of CodeGen under tp + SP over all ``devices`` with the
+    blocks rematerialised under each of ``size.policies`` ("save nothing"
+    first). Saving a tensor in place of computing it twice changes no
+    arithmetic, so every step's loss must be the same number to
+    ``size.loss_tol`` (bf16's rounding; the same bits in float32); what differs
+    is printed: the step's time, each chip's peak bytes
+    (``memory_stats()["peak_bytes_in_use"]``: a process's high water, so a
+    chip reads a LATER policy's peak only where it is the larger; device 0
+    also held the whole float32 model at every init) and what the trainer
+    recorded of the policy (``programs.resolved["remat"]``, gauge
+    ``train_remat_saved_bytes``)."""
+    import statistics
+
+    import jax.numpy as jnp
+
+    from neuronx_distributed_tpu.parallel import mesh as mesh_lib
+    from perfbench.families import codegen as family
+
+    published = size.model
+    if published is None:
+        path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                            "perfbench", "configs", "codegen2-7b-train-tp4.json")
+        with open(path) as f:
+            published = json.load(f)["model"]
+    tp = len(devices)
+    mesh_lib.destroy_model_parallel()
+    mesh_lib.initialize_model_parallel(tensor_model_parallel_size=tp, devices=list(devices))
+    built = family.build(published, runner="train", max_seq_len=size.seq,
+                         sequence_parallel=tp > 1, remat=True)
+    if size.model is not None:    # the CPU rehearsal trains in float32
+        built = built.clone(config=dataclasses.replace(built.config, dtype=jnp.float32))
+    losses, names, saved = {}, {}, {}
+    for policy in size.policies:
+        model = built.clone(config=dataclasses.replace(built.config, remat_policy=policy))
+        trainer, rows = _fit_steps(model, model.config.vocab_size, size.batch, size.seq, seed, size.steps)
+        losses[policy] = [loss for loss, _, _ in rows]
+        names[policy] = trainer.programs.resolved["remat"]
+        saved[policy] = int(trainer.programs.registry.get("train_remat_saved_bytes").value)
+        step_ms = 1e3 * statistics.median(wall for _, _, wall in rows[1:])
+        trainer.state = trainer = None
+        gc.collect()
+        peaks = [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0)) for d in devices]
+        log(f"remat {policy!r}: tp{tp} + SP, {model.config.num_layers} layers, {size.batch} x {size.seq} tokens; "
+            f"saved by name {names[policy]}, {saved[policy]} B a chip; losses {[f'{x:.6f}' for x in losses[policy]]}; "
+            f"step {step_ms:.1f} ms (median of {len(rows) - 1}); peak bytes a chip so far {peaks} "
+            f"({max(peaks) / 2**30:.2f} GiB the fullest)")
+    nothing, *kept = size.policies
+    apart = max(abs(a - b) for p in kept for a, b in zip(losses[p], losses[nothing]))
+    log(f"remat: largest |loss - save nothing's| over {size.steps} steps and {len(kept)} policies "
+        f"{apart:.2e} (tolerance {size.loss_tol:g})")
+    per_layer = (size.batch * size.seq * model.config.intermediate_size // tp
+                 * jnp.dtype(model.config.dtype).itemsize)
+    return {
+        "remat_policies_give_the_same_losses": apart <= size.loss_tol,
+        "remat_save_nothing_is_recorded_empty": names[nothing] == [] and saved[nothing] == 0,
+        "remat_named_saves_are_recorded": all(
+            "mlp_up" in names[p] and saved[p] >= model.config.num_layers * per_layer for p in kept),
     }
 
 
@@ -1944,11 +2043,12 @@ def one_chip(seed: int, devices, train: TrainSize = TrainSize(),
 
 
 def four_chips(seed: int, devices, train: TrainSize = TrainSize(),
-               serve: ServeSize = TP_SERVE) -> Dict[str, bool]:
+               serve: ServeSize = TP_SERVE, remat: RematSize = RematSize()) -> Dict[str, bool]:
     """``--chips 4``: the tp paths and what they are compared with."""
     return {
         **tp_train_phase(train, seed, devices),
         **tp_serve_phase(serve, seed, devices),
+        **remat_phase(remat, seed, devices),
     }
 
 

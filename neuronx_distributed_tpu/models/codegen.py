@@ -10,13 +10,14 @@ projections."""
 from __future__ import annotations
 
 import dataclasses
-from typing import Any
+from typing import Any, Optional
 
 import jax.numpy as jnp
 from flax import linen as nn
 
 from neuronx_distributed_tpu.modules.attention import ParallelMLP, ParallelSelfAttention
 from neuronx_distributed_tpu.modules.layer_norm import LayerNorm
+from neuronx_distributed_tpu.modules.remat import remat_layer_cls
 from neuronx_distributed_tpu.parallel.layers import (
     ColumnParallelLinear,
     ParallelEmbedding,
@@ -38,7 +39,20 @@ class CodeGenConfig:
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     sequence_parallel: bool = False
+    # activation checkpointing per block: the backward pass re-runs a block's
+    # forward from its input, but for what ``remat_policy`` keeps
     remat: bool = False
+    # what a remat'd block keeps (modules/remat.py has the table and the
+    # measurements). The default keeps, a layer a chip, the MLP
+    # up-projection's pre-activation (``B S intermediate_size / tp`` values:
+    # 134 MB in bf16 at 8 x 2048 tokens, 16384 and tp=4), q, k and v after
+    # rotary (``3 B S hidden_size / tp``: 101 MB) and the flash forward's
+    # outputs (34 MB + the row sums): no matmul and no kernel of the block is
+    # then run twice, where "save nothing" runs 7 of the block's 12 H^2 of
+    # matmuls and the flash forward again. "mlp_up" keeps the first alone;
+    # None = save nothing, for whoever sits at the memory limit; "dots" /
+    # "dots_saveable" as LlamaConfig's.
+    remat_policy: Optional[str] = "mlp_up+attn"
 
     @property
     def head_dim_(self) -> int:
@@ -93,7 +107,7 @@ class CodeGenForCausalLM(nn.Module):
             cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, name="embed",
         )(input_ids)
-        block_cls = nn.remat(CodeGenBlock) if cfg.remat else CodeGenBlock
+        block_cls = remat_layer_cls(CodeGenBlock, cfg.remat, cfg.remat_policy)
         for i in range(cfg.num_layers):
             x = block_cls(cfg, self.mode, name=f"blocks_{i}")(
                 x, positions, segment_ids, padding_mask

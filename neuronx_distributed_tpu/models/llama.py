@@ -20,6 +20,7 @@ from flax import linen as nn
 from jax.sharding import PartitionSpec as P
 
 from neuronx_distributed_tpu.modules.qkv_linear import GQAQKVColumnParallelLinear
+from neuronx_distributed_tpu.modules.remat import remat_layer_cls
 from neuronx_distributed_tpu.modules.rms_norm import RMSNorm
 from neuronx_distributed_tpu.parallel import mesh as mesh_lib
 from neuronx_distributed_tpu.parallel.layers import (
@@ -47,10 +48,11 @@ class LlamaConfig:
     param_dtype: Any = jnp.float32
     sequence_parallel: bool = False
     remat: bool = True  # activation checkpointing per decoder layer
-    # rematerialization policy when remat is on: None = save nothing
-    # (recompute everything), "dots" = jax.checkpoint_policies.
-    # dots_with_no_batch_dims_saveable (keep matmul outputs, recompute the
-    # cheap elementwise ops — the usual MFU/memory sweet spot at width)
+    # rematerialization policy when remat is on (modules/remat.py has the
+    # table): None = save nothing (recompute everything), "dots" =
+    # jax.checkpoint_policies.dots_with_no_batch_dims_saveable (keep matmul
+    # outputs, recompute the cheap elementwise ops — the usual MFU/memory
+    # sweet spot at width), "dots_saveable"
     remat_policy: Optional[str] = None
     scan_layers: bool = True  # lax.scan over layers (fast compile at depth)
     # weight-only serving quantization (a QuantizationConfig): every linear
@@ -85,20 +87,6 @@ def llama3_8b(**over) -> LlamaConfig:
         num_layers=32, num_heads=32, num_kv_heads=8, max_seq_len=8192,
         rope_theta=500000.0,
     ), **over})
-
-
-def _remat_layer_cls(cfg: "LlamaConfig"):
-    """LlamaDecoderLayer, optionally wrapped in nn.remat with the config's
-    checkpoint policy (None = recompute everything)."""
-    if not cfg.remat:
-        return LlamaDecoderLayer
-    if cfg.remat_policy is None:
-        return nn.remat(LlamaDecoderLayer)
-    policy = {
-        "dots": jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
-        "dots_saveable": jax.checkpoint_policies.dots_saveable,
-    }[cfg.remat_policy]
-    return nn.remat(LlamaDecoderLayer, policy=policy)
 
 
 def early_exit_draft_params(params, num_layers: int, draft_layers: int,
@@ -342,7 +330,9 @@ class _ScanLayerAdapter(nn.Module):
 
     @nn.compact
     def __call__(self, x, freqs, positions, attn_mask, segment_ids, padding_mask):
-        layer_cls = _remat_layer_cls(self.config)
+        layer_cls = remat_layer_cls(
+            LlamaDecoderLayer, self.config.remat, self.config.remat_policy
+        )
         x = layer_cls(self.config, self.attention_impl, self.mode, name="layer")(
             x, freqs, positions, attn_mask, segment_ids, padding_mask
         )
@@ -382,7 +372,9 @@ class LlamaModel(nn.Module):
             )(cfg, self.attention_impl, self.mode, name="layers")
             x, _ = scanned(x, freqs, positions, attn_mask, segment_ids, padding_mask)
         else:
-            layer_cls = _remat_layer_cls(cfg)
+            layer_cls = remat_layer_cls(
+                LlamaDecoderLayer, cfg.remat, cfg.remat_policy
+            )
             for i in range(cfg.num_layers):
                 x = layer_cls(cfg, self.attention_impl, self.mode, name=f"layers_{i}")(
                     x, freqs, positions, attn_mask, segment_ids, padding_mask

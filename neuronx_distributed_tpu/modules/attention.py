@@ -14,10 +14,12 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from neuronx_distributed_tpu.kernels import backend
 from neuronx_distributed_tpu.modules.qkv_linear import GQAQKVColumnParallelLinear
+from neuronx_distributed_tpu.modules.remat import ATTN_QKV, MLP_UP
 from neuronx_distributed_tpu.parallel import mesh as mesh_lib
 from neuronx_distributed_tpu.parallel.layers import RowParallelLinear
 from neuronx_distributed_tpu.parallel.sharding import UNC, constrain
@@ -1687,6 +1689,10 @@ class ParallelSelfAttention(nn.Module):
         q = constrain(q, P(UNC, UNC, mesh_lib.TP_AXIS))
         if self.mode == "train":
             q, k = self._rope(q, k, positions)
+            # what the attention receives: a layer rematerialised under
+            # "mlp_up+attn" keeps these (modules/remat.py); elsewhere the
+            # name is the identity
+            q, k, v = (checkpoint_name(t, ATTN_QKV) for t in (q, k, v))
             out = attention_op(
                 q, k, v, causal=self.causal, impl=self.attention_impl,
                 mask=attention_mask, segment_ids=segment_ids,
@@ -1733,7 +1739,15 @@ class ParallelSelfAttention(nn.Module):
 class ParallelMLP(nn.Module):
     """Plain 2-layer MLP: CPL → activation → RPL (BERT/NeoX/ViT FFN).
     ``glu`` gates it: ``down(act(gate(x)) * up(x))`` (SwiGLU with
-    ``activation="silu"``: DeepSeek-V2's dense layer and shared experts)."""
+    ``activation="silu"``: DeepSeek-V2's dense layer and shared experts).
+
+    The up-projection's output, before the activation, carries the name
+    ``modules.remat.MLP_UP`` (under ``glu`` the gate's does too): a layer
+    rematerialised under the policies ``"mlp_up"`` and ``"mlp_up+attn"`` keeps
+    it through the backward pass, ``B S I / tp`` values a layer a chip (twice
+    that under ``glu``), and does not run the up-projection a second time; the
+    activation is still recomputed from it. Anywhere else the name is the
+    identity."""
 
     hidden_size: int
     intermediate_size: int
@@ -1760,13 +1774,13 @@ class ParallelMLP(nn.Module):
             dtype=self.dtype,
             param_dtype=self.param_dtype,
         )
-        y = ColumnParallelLinear(
+        y = checkpoint_name(ColumnParallelLinear(
             self.hidden_size, self.intermediate_size, name="up", **common
-        )(x)
+        )(x), MLP_UP)
         if self.glu:
-            y = act(ColumnParallelLinear(
+            y = act(checkpoint_name(ColumnParallelLinear(
                 self.hidden_size, self.intermediate_size, name="gate", **common
-            )(x)) * y
+            )(x), MLP_UP)) * y
         else:
             y = act(y)
         return RowParallelLinear(

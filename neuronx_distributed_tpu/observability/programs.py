@@ -567,7 +567,8 @@ class ProgramLedger:
         # implementation choices the owner resolved by platform ("auto" →
         # the kernel or the reference it actually traces), written once by
         # the engine/trainer and exported verbatim in snapshot()["resolved"]
-        self.resolved: Dict[str, str] = {}
+        # (the trainer's "remat" is a list: the names its policy saves)
+        self.resolved: Dict[str, Any] = {}
         self.peaks = device_peaks()
         name = self._name
         self._fam_dispatch = view.family(

@@ -1055,6 +1055,7 @@ class Trainer:
                 grad_accum_steps=accum,
                 anomaly_guard=guard_cfg,
             )
+            self._record_remat_saves(first, accum)
             if guard_cfg is not None:
                 self.state = self.state.replace(guard=init_anomaly_guard_state())
             if accum > 1:
@@ -1428,6 +1429,44 @@ class Trainer:
         if halted is not None:
             raise halted
         return metrics
+
+    def _record_remat_saves(self, first: dict, accum: int) -> None:
+        """What the model's remat policy keeps through the backward pass of
+        the train program just built (``modules/remat.py``):
+        ``programs.resolved["remat"]`` the saved names, gauge
+        ``train_remat_saved_bytes`` the bytes a chip keeps for them (named
+        tensors x layers, of one microbatch; an activation a column-parallel
+        layer hands on is sharded over every axis of the mesh: batch over dp,
+        sequence over cp, features over tp). ``[]`` and 0 under "save
+        nothing" and for a model without remat. One abstract trace of the
+        forward, no compute."""
+        from functools import partial
+
+        from neuronx_distributed_tpu.modules.remat import saved_by_name
+        from neuronx_distributed_tpu.parallel import mesh as mesh_lib
+        from neuronx_distributed_tpu.trainer.trainer import default_loss_fn
+
+        saved: dict = {}
+        if getattr(getattr(self.model, "config", None), "remat", False):
+            saved = saved_by_name(
+                self.loss_fn or partial(default_loss_fn, self.model),
+                jax.tree.map(
+                    lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                    self.state.params,
+                ),
+                {
+                    k: jax.ShapeDtypeStruct(
+                        (v.shape[0] // accum, *v.shape[1:]), v.dtype
+                    )
+                    for k, v in first.items()
+                },
+            )
+        self.programs.resolved["remat"] = sorted(saved)
+        self.programs.view.gauge(
+            "train_remat_saved_bytes",
+            help="bytes a chip keeps through the backward pass for the "
+                 "tensors the remat policy saves by name",
+        ).set(sum(saved.values()) // mesh_lib.get_mesh().size)
 
     def evaluate(self, data_iter: Iterable[dict], max_steps: int) -> dict:
         """Mean loss over ``max_steps`` eval batches with the CURRENT params,

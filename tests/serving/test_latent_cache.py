@@ -289,7 +289,23 @@ PARENT_PROGRAMS = {
 
 def _model_program_texts(name, model):
     """``{name.prefill, name.decode.gather, name.decode.fused}``: the jaxpr
-    text of a tiny model's prefill and paged decode chunk, both transports."""
+    text of a tiny model's prefill and paged decode chunk, both transports.
+
+    Taken with ``checkpoint_name`` as the identity it lowers to: PR 40 named
+    ``ParallelMLP``'s up-projection output for the trainer's remat policy, a
+    ``name`` equation in every jaxpr that holds a dense MLP and nothing in the
+    lowered program (``tests/models/test_remat_policy.py`` holds the lowered
+    texts equal), so the digests below still say "everything else is the
+    parent's"."""
+    from unittest import mock
+
+    from neuronx_distributed_tpu.modules import attention
+
+    with mock.patch.object(attention, "checkpoint_name", lambda x, name: x):
+        return _program_texts_of(name, model)
+
+
+def _program_texts_of(name, model):
     out = {}
     length = model.config.max_seq_len
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))

@@ -179,8 +179,15 @@ def test_moe_phase_alone_rehearsal():
 
 
 def test_four_chip_run_rehearsal():
+    import json
+
+    path = os.path.join(os.path.dirname(_PATH), "tests", "benchmark", "data",
+                        "configs", "codegen2-7b-train-tp4.json")
+    with open(path) as f:
+        # float32 here: saving in place of computing twice changes no bit
+        remat = chip_smoke.RematSize(model=json.load(f)["model"], batch=4, seq=64, steps=2, loss_tol=0.0)
     _assert_only_kernel_checks_fail(
-        chip_smoke.four_chips(0, jax.devices()[:4], TRAIN, SERVE)
+        chip_smoke.four_chips(0, jax.devices()[:4], TRAIN, SERVE, remat)
     )
 
 

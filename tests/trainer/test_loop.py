@@ -62,9 +62,10 @@ def test_trainer_fit_runs_and_loss_decreases(tmp_path):
     assert (tmp_path / "trace.json").exists()
     # the attention implementation the step traced is on the ledger (what
     # "auto" resolves to is pinned in tests/kernels/test_backend.py and the
-    # chip_smoke rehearsal)
+    # chip_smoke rehearsal), and so is what the model's remat policy keeps by
+    # name (nothing: this model does not remat; tests/trainer/test_remat_record.py)
     assert trainer.programs.snapshot(analyze=False)["resolved"] == {
-        "attention": "xla"
+        "attention": "xla", "remat": [],
     }
 
 
