@@ -74,6 +74,19 @@ controls that must fail (the experts' weights in float8; one hit expert
 dropped), then ms a call of both forms over 1-256 rows: the sweep that sets
 ``modules/moe/expert_mlps.MOE_STREAM_MAX_TOKENS``.
 
+``--only walk`` (not part of the default run: it serves nothing) runs the
+walking GQA decode kernel (``kernels/flash_decode.paged_walk_decode_attention``)
+alone at the Trinity cell's shapes: 8 slots of 32,768 columns, page 16, 48
+query heads against 8 kv heads of 128, the tape's eight prompt lengths + 256
+as contexts that end at a shared cursor; once as a window layer (272 pages a
+slot, ``floor`` set) and once as a full layer. Each against the float32
+einsum under an index mask, then ms a call with the GB/s of the bytes the call
+needs (``perfbench/swa_costs.py``) and of the bytes its blocks fetch. With
+``--bundles DIR`` it first compiles the kernel for a described v5e in a
+process of its own with the compiler's listing dumped to ``DIR``, and prints
+how many instruction bundles one block's body is (no chip needed for that
+part: it is printed before the device is asked for).
+
 ``--only trinity`` (not part of the default run) serves Trinity-Large-Preview's
 language model at its published widths on the benchmark configuration's cut
 (``perfbench/configs/trinity-large-serve.json``: a dense window layer and one
@@ -187,6 +200,29 @@ class MoeSize:
     # vector) at the cell's rows, between the system's reading and the
     # controls' (PERF.md section 6, PR 33, has the readings)
     routed_tol: float = 0.015
+
+
+@dataclasses.dataclass(frozen=True)
+class WalkSize:
+    """What ``--only walk`` runs (defaults: the chip run, the Trinity cell's
+    decode attention: ``perfbench/configs/trinity-large-serve.json``'s heads,
+    ``perfbench/traffic/mixedctx_closed.json``'s eight prompt lengths)."""
+
+    q_heads: int = 48
+    kv_heads: int = 8
+    head_dim: int = 128
+    window: int = 4096
+    max_seq_len: int = 32768
+    page: int = 16
+    window_pages: int = 272       # a window layer's pool, pages a slot
+    contexts: Tuple[int, ...] = tuple(n + 256 for n in (2799, 4402, 5818, 7338, 9146, 11534, 15244, 16384))
+    cursor: int = 21000
+    calls: int = 20
+    dtype: str = "bfloat16"
+    # largest |kernel - float32 einsum| over the output: bf16 probabilities
+    # and output against float32 (the chip reads 0.0003-0.0004 on unit-variance
+    # values, PERF.md section 6, PR 44; a head taken from another's rows ~1)
+    tol: float = 0.02
 
 
 def log(msg: str) -> None:
@@ -1931,6 +1967,126 @@ def trinity_phase(size: TrinitySize, seed: int) -> Dict[str, bool]:
     }
 
 
+def _walk_listing_compile(size: WalkSize = WalkSize()) -> None:
+    """Compile the walking kernel at ``size`` for a described v5e. Run in a
+    process of its own with ``LIBTPU_INIT_ARGS`` naming the dump directory
+    (:func:`walk_block_bundles`): the compiler aborts the process once the
+    listing is written (it looks for a report template this install lacks)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from neuronx_distributed_tpu.kernels import backend
+    from neuronx_distributed_tpu.kernels.flash_decode import paged_walk_decode_attention
+
+    backend.INTERPRET, backend.on_tpu = False, lambda: True
+    jax.config.update("jax_enable_compilation_cache", False)
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    one = SingleDeviceSharding(topo.devices[0])
+    s = lambda shape, dtype=jnp.dtype(size.dtype): jax.ShapeDtypeStruct(shape, dtype, sharding=one)   # noqa: E731
+    b, page, n_log = len(size.contexts), size.page, size.max_seq_len // size.page
+
+    def walk_step(q, pool, bt, pos, ok, lo):
+        return paged_walk_decode_attention(q, pool, bt, pos, kv_valid=ok, floor=lo, page_size=page)
+
+    jax.jit(walk_step).lower(
+        s((b, 1, size.q_heads, size.head_dim)), s((b * size.window_pages + 1, page, 2 * size.kv_heads, size.head_dim)),
+        s((b, n_log), jnp.int32), s((1,), jnp.int32), s((b, n_log * page), jnp.bool_), s((b,), jnp.int32)).compile()
+
+
+def walk_block_bundles(directory: str, size: WalkSize = WalkSize()) -> int:
+    """Instruction bundles of ONE block's body in the walking kernel's final
+    schedule, compiled for a described v5e (no chip): in the listing of the
+    custom call named after ``walk_step``, the region from the second
+    innermost loop body (the block's wait, then its heads) to the predicated
+    region's end after it."""
+    import glob
+    import re
+    import subprocess
+
+    os.makedirs(directory, exist_ok=True)
+    env = dict(os.environ, JAX_PLATFORMS="cpu", TPU_LOG_DIR="disabled",
+               LIBTPU_INIT_ARGS=f"--xla_jf_dump_to={directory} --xla_jf_dump_llo_text=true")
+    code = f"import chip_smoke; chip_smoke._walk_listing_compile(chip_smoke.WalkSize(**{dataclasses.asdict(size)!r}))"
+    subprocess.run([sys.executable, "-c", code], env=env, cwd=os.path.dirname(os.path.abspath(__file__)),
+                   stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL, timeout=600)
+    listings = [f for f in glob.glob(os.path.join(directory, "*walk_step*final_bundles.txt"))
+                if "schedule-analysis" not in f]
+    if not listings:
+        raise RuntimeError(f"the compile left no listing of walk_step under {directory}")
+    address = re.compile(r"^\s*(0x[0-9a-f]+)\s+(LB: >>> |PF: >> )")
+    marks = [(int(m.group(1), 16), m.group(2)) for m in map(address.match, open(max(listings, key=os.path.getmtime)))
+             if m]
+    bodies = [i for i, (_, kind) in enumerate(marks) if kind.startswith("LB")]
+    start = bodies[1]
+    end = next(i for i in range(start + 1, len(marks)) if marks[i][1].startswith("PF"))
+    return marks[end][0] - marks[start][0]
+
+
+def walk_phase(size: WalkSize, seed: int) -> Dict[str, bool]:
+    """The walking GQA decode kernel alone, a call = one layer of a decode
+    step, as a window layer and as a full layer: against the float32 einsum
+    under an index mask, then ms a call and GB/s of needed and fetched bytes."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from neuronx_distributed_tpu.kernels import flash_decode
+    from neuronx_distributed_tpu.kernels.flash_decode import paged_gather_leaf, paged_walk_decode_attention
+    from neuronx_distributed_tpu.modules.attention import _masked_gqa_attention, split_kv
+    from perfbench.swa_costs import swa_decode_cost
+
+    dtype = jnp.dtype(size.dtype)
+    h, hkv, d, page, cur = size.q_heads, size.kv_heads, size.head_dim, size.page, size.cursor
+    ctx, b, n_log = list(size.contexts), len(size.contexts), size.max_seq_len // size.page
+    group = min(max(flash_decode.WALK_BLOCK_TOKENS // page, 1), n_log)
+    pos = jnp.asarray([cur], jnp.int32)
+    valid = np.zeros((b, size.max_seq_len), bool)
+    for i, n in enumerate(ctx):            # contexts END at the shared cursor
+        valid[i, cur + 1 - n:cur + 1] = True
+    f32 = lambda a: a.astype(jnp.float32)    # noqa: E731
+    checks: Dict[str, bool] = {}
+    for kind, window in (("window", size.window), ("full", None)):
+        rng = np.random.default_rng(seed)
+        lowest = [cur + 1 - (n if window is None else min(n, window)) for n in ctx]
+        held = [range(lo // page, cur // page + 1) for lo in lowest]
+        per_slot = size.window_pages if window is not None else max(len(r) for r in held)
+        assert all(len(r) <= per_slot for r in held), "a window of pages a slot does not hold the window"
+        ids = rng.permutation(np.arange(1, b * per_slot + 1))
+        table = np.zeros((b, n_log), np.int32)
+        for i, r in enumerate(held):
+            table[i, r.start:r.stop] = ids[i * per_slot:i * per_slot + len(r)]
+        keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+        # the joined leaf: a token's K heads, then its V heads
+        pool = jax.random.normal(keys[0], (b * per_slot + 1, page, 2 * hkv, d), dtype)
+        q = jax.random.normal(keys[1], (b, 1, h, d), dtype)
+        floor = None if window is None else jnp.asarray(lowest, jnp.int32)
+        bt, ok = jnp.asarray(table), jnp.asarray(valid)
+
+        # every array is an ARGUMENT: closed over, the pool would be a constant of the program
+        walk = lambda qq, kvp: paged_walk_decode_attention(   # noqa: E731
+            qq, kvp, bt, pos, kv_valid=ok, floor=floor, page_size=page)
+        got = jax.jit(walk)(q, pool)
+        keep = valid & (np.arange(size.max_seq_len)[None] >= np.asarray(lowest)[:, None])
+        with jax.default_matmul_precision("highest"):
+            want = jax.jit(lambda qq, kvp: _masked_gqa_attention(
+                f32(qq), *split_kv(f32(paged_gather_leaf(kvp, bt, page))), jnp.asarray(keep[:, None])))(q, pool)
+        err = float(np.abs(np.asarray(f32(got)) - np.asarray(want)).max())
+        ms = _median_call_ms(walk, (q, pool), size.calls)
+        needed = swa_decode_cost(ctx, num_q_heads=h, num_kv_heads=hkv, head_dim=d, window=window,
+                                 act_bytes=dtype.itemsize)[1]
+        blocks = int(flash_decode._latent_block_walk(bt, pos[0] + 1, group, page)[0].sum())
+        fetched = blocks * group * page * 2 * hkv * d * dtype.itemsize
+        log(f"walk {kind}: {b} slots, contexts {min(ctx)}-{max(ctx)} ending at {cur}, {blocks} blocks of "
+            f"{group * page} tokens: {ms:.4f} ms a call, {needed / ms / 1e6:.1f} GB/s of {needed / 1e6:.1f} MB needed, "
+            f"{fetched / ms / 1e6:.1f} GB/s of {fetched / 1e6:.1f} MB fetched; largest |kernel - float32 einsum| "
+            f"{err:.5f} (limit {size.tol})")
+        checks[f"walk_{kind}_matches_the_float32_einsum"] = err <= size.tol
+        del pool, got, want
+    return checks
+
+
 def moe_phase(size: MoeSize, seed: int) -> Dict[str, bool]:
     """The streamed expert MLP alone, a call = one layer of a decode step:
     against the float32 routed sum, the grouped-matmul form and two controls,
@@ -2021,13 +2177,14 @@ def one_chip(seed: int, devices, train: TrainSize = TrainSize(),
              serve: ServeSize = ServeSize(), mla: MlaSize = MlaSize(),
              only: str = "all", dsa: DsaSize = DsaSize(),
              glm: GlmSize = GlmSize(), moe: MoeSize = MoeSize(),
-             trinity: TrinitySize = TrinitySize()) -> Dict[str, bool]:
+             trinity: TrinitySize = TrinitySize(), walk: WalkSize = WalkSize()) -> Dict[str, bool]:
     """The default run: train, then serve, then the MLA model, the
     sparse-attention model and GLM-5's (sparse selection among latents), in
     one process on one device; ``only="mla"``, ``"dsa"`` or ``"glm"``: that
     phase alone; ``only="moe"``: the streamed expert MLP alone, which no
     other phase repeats; ``only="trinity"``: Trinity's window and full
-    attention layers in one paged cache, likewise."""
+    attention layers in one paged cache, likewise; ``only="walk"``: their
+    decode kernel alone."""
     if only == "mla":
         return mla_phase(mla, seed)
     if only == "dsa":
@@ -2038,6 +2195,8 @@ def one_chip(seed: int, devices, train: TrainSize = TrainSize(),
         return moe_phase(moe, seed)
     if only == "trinity":
         return trinity_phase(trinity, seed)
+    if only == "walk":
+        return walk_phase(walk, seed)
     return {**train_phase(train, seed, devices), **serve_phase(serve, seed),
             **mla_phase(mla, seed), **dsa_phase(dsa, seed), **glm_phase(glm, seed)}
 
@@ -2059,16 +2218,27 @@ def parse_args(argv=None):
                         "tp=4 train step and tp=4 engine and their "
                         "one-device counterparts")
     p.add_argument("--seed", type=int, default=0)
-    p.add_argument("--only", default="all", choices=("all", "mla", "dsa", "glm", "moe", "trinity"),
+    p.add_argument("--only", default="all", choices=("all", "mla", "dsa", "glm", "moe", "trinity", "walk"),
                    help="one chip: every phase (default), or the MLA, the "
                         "sparse-attention (dsa) or the GLM-5 (glm) phase alone; "
                         "moe: the streamed expert MLP's checks and sweep; "
-                        "trinity: window and full attention layers in one paged cache")
+                        "trinity: window and full attention layers in one paged cache; "
+                        "walk: their decode kernel alone, ms a call and GB/s")
+    p.add_argument("--bundles", metavar="DIR", default=None,
+                   help="with --only walk: first compile the kernel for a described v5e with the "
+                        "compiler's listing dumped to DIR, and print one block's instruction bundles")
     return p.parse_args(argv)
 
 
 def main(argv=None) -> int:
     args = parse_args(argv)
+    if args.bundles is not None:
+        if args.only != "walk":
+            print("chip_smoke: --bundles goes with --only walk", file=sys.stderr)
+            return 2
+        # before this process touches JAX: the compile's process loads the TPU's library itself
+        log(f"walk: one block's body is {walk_block_bundles(args.bundles)} instruction bundles in the "
+            f"described-v5e listing under {args.bundles}")
     import jax
 
     devices = jax.devices()

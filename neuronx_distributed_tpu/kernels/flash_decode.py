@@ -1054,13 +1054,63 @@ def paged_latent_decode_attention(
 # up to the shared cursor, is some ten thousand grid steps a layer at eight
 # rows of 32,768 columns.
 
-# Tokens a block: two blocks of 512 x (16, 128) bf16 are 4 MiB of VMEM.
+# Tokens a block: two blocks of 512 x (16, 128) bf16 are 4 MiB of VMEM, and a
+# block's 32 page copies (2 MiB) last ~3,840 cycles. What the kernel issues
+# for a block has to stay under that, and how a kv head's (T, D) rows are taken
+# out of the fetched block decides it. Instruction bundles of one block's body
+# in the final schedule of a described-v5e compile at Trinity's geometry
+# (``chip_smoke.py --only walk --bundles DIR`` makes it again; PERF.md section
+# 6, PR 44): ``buf[slot, :, h, :]`` a head, 8,857 (a load a (token, head) row:
+# 8,192 loads and ~37,000 shuffles for 512 matrix pushes); the block read once
+# and turned with ``jnp.swapaxes``, 2,579; each 32-bit word of a token read
+# once with a sublane stride (:func:`_block_head_rows`), 1,696, beside ~1,200
+# cycles that name the next block's copies; pages held head-major in the POOL
+# would need no shuffle at all, 963, at the price of a layout every
+# ``PAGED_LEAVES`` walker would have to learn.
 WALK_BLOCK_TOKENS = 512
+
+
+def _block_head_rows(block_ref):
+    """``rows_of(x)``: the ``(T, D)`` rows of head row ``x`` of a fetched block
+    ``(T, R, D)`` (a token's ``R`` head rows: K's heads, then V's), read with
+    a sublane STRIDE out of the block's 32-bit view. A token's rows are packed
+    tiles in VMEM (16 rows of bf16 a tile: two rows a 32-bit sublane), so
+    ``block[:, x, :]`` is one sublane-half out of each of ``T`` tiles, and
+    Mosaic lowers it to a load a (token, head) row plus the unpacks, rotates
+    and selects that assemble registers from them: 45,000 vector operations a
+    block for 512 matrix pushes. Viewed as ``(T * R / 2, D)`` 32-bit words,
+    word ``w`` of every token is ONE strided load (``T / 8`` registers), and
+    the two heads that share it come apart with a shift and a truncating pack.
+    Each word is read once, whoever asks for its heads."""
+    t, r, d = block_ref.shape
+    dtype = block_ref.dtype
+    packed = 4 // dtype.itemsize               # head rows a 32-bit word: 2 in bf16, 1 in float32
+    words = block_ref.reshape(t * r, d).bitcast(jnp.uint32)       # (T * R / packed, D)
+    read = {}
+
+    def rows_of(x):
+        word, part = divmod(x, packed)
+        if word not in read:
+            read[word] = words[pl.ds(word, t, stride=r // packed), :]
+        w = read[word]
+        if packed > 1:                          # row 2w in the low half, 2w + 1 in the high
+            bits = 32 // packed
+            w = (w >> (bits * part)).astype(jnp.dtype(f"uint{bits}"))
+        return pltpu.bitcast(w, dtype)
+
+    return rows_of
 
 
 def _paged_walk_kernel(bt_ref, live_ref, span_ref, edge_ref, valid_ref, q_ref,
                        kv_hbm, o_ref, buf, sems, m_scr, l_scr, acc_scr, *,
                        page_size, group, num_kv_heads, scale, use_valid):
+    """One slot: walk its live blocks, block ``i + 1``'s page copies in flight
+    while block ``i`` is multiplied. A waited block ``(T, 2 Hkv, D)`` gives up
+    each kv head's K and V rows through :func:`_block_head_rows` (each 32-bit
+    word of a token read ONCE a block, with a sublane stride: the comment at
+    :data:`WALK_BLOCK_TOKENS` has the three counts), and the head's group of
+    query rows meets them: scores and probabilities in float32, the operands
+    in their storage type, an online softmax over the blocks."""
     b = pl.program_id(0)
     lo, hi = span_ref[b, 0], span_ref[b, 1]   # this slot's own blocks
     floor, pos = edge_ref[b, 0], edge_ref[b, 1]
@@ -1100,11 +1150,12 @@ def _paged_walk_kernel(bt_ref, live_ref, span_ref, edge_ref, valid_ref, q_ref,
             ok = (cols <= pos) & (cols >= floor)
             if use_valid:
                 ok = ok & (valid_ref[0, pl.ds(i, 1), :] != 0)
+            rows_of = _block_head_rows(buf.at[slot])
             for h in range(num_kv_heads):
                 # operands stay in their storage type (bf16 on the chip: the
                 # MXU's own), accumulation is float32
-                k = buf[slot, :, h, :]                             # (T, D)
-                v = buf[slot, :, num_kv_heads + h, :]
+                k = rows_of(h)                                     # (T, D)
+                v = rows_of(num_kv_heads + h)
                 s = jax.lax.dot_general(
                     q_ref[0, h], k, (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32,
@@ -1169,6 +1220,10 @@ def paged_walk_decode_attention(
             f"joined K/V pool leaf must be (P, page_size, 2 Hkv, {d}), got {kv_pool.shape}")
     if kv_pool.shape[1] != page_size:
         raise ValueError(f"pool pages hold {kv_pool.shape[1]} tokens, page_size is {page_size}")
+    if kv_pool.shape[2] * kv_pool.dtype.itemsize % 4:
+        raise ValueError(
+            f"a token's {kv_pool.shape[2]} head rows of {kv_pool.dtype} do not fill whole 32-bit words: "
+            "the kernel reads a fetched block a word a token")
     interpret = interpret_mode(interpret)
     hkv = kv_pool.shape[2] // 2
     g = h // hkv

@@ -68,6 +68,93 @@ def test_the_walking_kernel_is_the_gather_transport_under_an_index_mask(small_bl
     assert not np.asarray(got[2]).any()        # the slot that maps nothing
 
 
+def walk_geometry_case(hkv, g, d, dtype, n_log, cur, seed=0):
+    """Four slots over a pool of ``hkv`` kv heads of ``d``, ``g`` query heads
+    each: a context from column 26 that ends mid-block at ``cur``; one from 80
+    whose gap columns 96-111 fill two pages, the second UNMAPPED inside a
+    fetched block; a slot that maps nothing; a context of the cursor's own
+    block alone. ``n_log`` pages of 8 a row, blocks of four."""
+    rng = np.random.default_rng(seed)
+    b = 4
+    pool = jnp.asarray(rng.standard_normal((1 + b * n_log, PS, 2 * hkv, d)), dtype)
+    ids = rng.permutation(np.arange(1, 1 + b * n_log)).reshape(b, n_log)
+    bt = np.zeros((b, n_log), np.int32)
+    valid = np.zeros((b, n_log * PS), bool)
+    for row, start in ((0, 26), (1, 80), (3, cur - cur % 32 + 3)):
+        valid[row, start:cur + 1] = True
+        bt[row, start // PS:cur // PS + 1] = ids[row, start // PS:cur // PS + 1]
+    valid[1, 96:112] = False
+    bt[1, 13] = 0
+    q = jnp.asarray(rng.standard_normal((b, 1, hkv * g, d)), dtype)
+    return pool, bt, valid, q
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("hkv,g,d", [(8, 6, 128), (8, 4, 128), (16, 1, 256)], ids=["trinity", "mixtral", "codegen2"])
+@pytest.mark.parametrize("window,n_log,cur", [(None, 32, 157), (40, 32, 157), (28, 30, 237)],
+                         ids=["full", "floor_mid_block", "floor_in_a_first_page_and_a_short_last_block"])
+def test_the_walking_kernel_at_the_cells_head_geometries(small_blocks, hkv, g, d, dtype, window, n_log, cur):
+    """The three head geometries that meet this kernel (Trinity's, Mixtral's,
+    CodeGen2's), in float32 and in the cells' bf16 (two heads share a 32-bit
+    word of the block there), against the float32 einsum under an index mask:
+    a context that ends mid-block, a ``floor`` mid-block (157 - 39 = 118, the
+    third page of its block) and in a block's first page (237 - 27 = 210 of
+    208-215), unmapped pages inside a fetched block, a slot that maps nothing,
+    gap columns, and 30 pages a row where a block holds four."""
+    pool, bt, valid, q = walk_geometry_case(hkv, g, d, dtype, n_log, cur)
+    q_pos = jnp.asarray([cur], jnp.int32)
+    f32 = lambda a: a.astype(jnp.float32)    # noqa: E731
+    k, v = split_kv(f32(paged_gather_leaf(pool, jnp.asarray(bt), PS)))
+    want = _masked_gqa_attention(f32(q), k, v, window_keep(jnp.asarray(valid), q_pos, window))
+    floor, table = None, bt
+    if window is not None:
+        floor = window_floor(jnp.asarray(valid), cur, window)
+        assert int(floor[0]) == cur + 1 - window
+        table = bt.copy()
+        for row, lo in enumerate(np.asarray(floor)):
+            table[row, :lo // PS] = 0          # what the manager freed behind the window
+    got = paged_walk_decode_attention(
+        q, pool, jnp.asarray(table), q_pos, kv_valid=jnp.asarray(valid), floor=floor, page_size=PS)
+    assert got.dtype == dtype and got.shape == q.shape
+    # bf16: the probabilities and the output are rounded to 8 bits; a head taken from another's rows reads ~1
+    np.testing.assert_allclose(np.asarray(f32(got)), np.asarray(want), atol=3e-6 if dtype == jnp.float32 else 2e-2)
+    assert not np.asarray(f32(got[2])).any()   # the slot that maps nothing
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
+def test_the_walking_kernel_reads_each_word_of_a_block_once_and_never_a_head_a_row_at_a_time(dtype):
+    """The traced kernel's reads of its block buffer: ``2 Hkv x itemsize / 4``
+    of them a block (8 at Trinity's 16 rows of bf16 a token), each a (T, D)
+    array of 32-bit words taken with a sublane stride from the buffer's 32-bit
+    view. The parent's form, ``buf[slot, :, h, :]`` (2 Hkv reads in the storage
+    type, which Mosaic lowers to a load a (token, head) row: 8,857 instruction
+    bundles a block where this form is 1,696), cannot come back unseen."""
+    hkv, g, d, page, n_log = 8, 6, 128, 16, 64
+    shape = jax.ShapeDtypeStruct
+    jaxpr = jax.make_jaxpr(lambda q, pool, bt, pos: paged_walk_decode_attention(q, pool, bt, pos, page_size=page))(
+        shape((2, 1, hkv * g, d), dtype), shape((65, page, 2 * hkv, d), dtype), shape((2, n_log), jnp.int32),
+        shape((1,), jnp.int32))
+    (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    buffer = (2, flash_decode.WALK_BLOCK_TOKENS, 2 * hkv, d)
+    reads = []
+
+    def walk(j):
+        for e in j.eqns:
+            if e.primitive.name == "get" and e.invars[0].aval.shape == buffer:
+                reads.append((e.outvars[0].aval, jax.tree.unflatten(e.params["tree"], e.invars[1:])))
+            for sub in jax.core.jaxprs_in_params(e.params):
+                walk(sub)
+
+    walk(call.params["jaxpr"])
+    words_a_token = 2 * hkv * jnp.dtype(dtype).itemsize // 4
+    assert len(reads) == words_a_token
+    for aval, transforms in reads:
+        assert aval.shape == (flash_decode.WALK_BLOCK_TOKENS, d) and aval.dtype == jnp.uint32
+        rows = transforms[-1].indices[0]
+        assert (rows.size, rows.stride) == (flash_decode.WALK_BLOCK_TOKENS, words_a_token)
+    assert sorted(t[-1].indices[0].start for _, t in reads) == list(range(words_a_token))
+
+
 def test_the_window_is_counted_over_valid_columns_not_columns():
     """Slot 1's nine gap columns lie inside a window of 64 tokens: its floor
     is nine columns lower than the gap-free slot's."""
@@ -85,6 +172,10 @@ def test_the_walking_kernel_refuses_what_it_does_not_do(small_blocks):
         paged_walk_decode_attention(jnp.concatenate([q, q], 1), pool, jnp.asarray(bt), jnp.asarray([cur, cur + 1]))
     with pytest.raises(ValueError, match="joined K/V pool"):
         paged_walk_decode_attention(q, pool[:, :, :3], jnp.asarray(bt), jnp.asarray([cur]))
+    with pytest.raises(ValueError, match="whole 32-bit words"):      # 2 x 3 rows of 8 bits
+        paged_walk_decode_attention(
+            jnp.zeros((B, 1, 3, D), jnp.int8), jnp.zeros((5, PS, 6, D), jnp.int8), jnp.asarray(bt), jnp.asarray([cur]),
+            page_size=PS)
 
 
 @pytest.mark.parametrize("window", [None, 32, 100], ids=["causal", "window_32", "window_100"])

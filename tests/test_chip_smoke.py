@@ -178,6 +178,18 @@ def test_moe_phase_alone_rehearsal():
     assert all(compared.values()), sorted(name for name, ok in compared.items() if not ok)
 
 
+def test_walk_phase_alone_rehearsal():
+    """``--only walk`` at a tiny size (rows of 32 pages of 8 tokens, one block
+    a row; a window of 40 under contexts that start mid-page): both kinds of
+    layer against the float32 einsum. The times are the chip's to say."""
+    walk = chip_smoke.WalkSize(
+        q_heads=4, kv_heads=2, head_dim=16, window=40, max_seq_len=256, page=8, window_pages=8,
+        contexts=(131, 77, 30), cursor=157, calls=1, dtype="float32", tol=1e-5)
+    checks = chip_smoke.one_chip(0, jax.devices()[:1], TRAIN, SERVE, MLA, only="walk", walk=walk)
+    assert sorted(checks) == ["walk_full_matches_the_float32_einsum", "walk_window_matches_the_float32_einsum"]
+    assert all(checks.values()), checks
+
+
 def test_four_chip_run_rehearsal():
     import json
 
