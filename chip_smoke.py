@@ -87,6 +87,22 @@ process of its own with the compiler's listing dumped to ``DIR``, and prints
 how many instruction bundles one block's body is (no chip needed for that
 part: it is printed before the device is asked for).
 
+``--only flash`` (not part of the default run: it serves nothing) runs the
+flash forward (``kernels/flash_attention._flash_fwd``, the prefill attention
+of every model but the sparse ones and a window layer, and the training
+forward) alone at the cells' shapes: DeepSeek-V2-Lite's 16 heads of 192 / 128
+at 9,003 tokens of a 16,384 bucket and 20,566 of 20,992, Trinity's full layer
+(48 / 8 heads of 128) at 11,534 of 16,384, CodeGen2's 16 heads of 256 at
+1,000 of 1,024 and 1,100 of 2,048, and the training cell's 4 heads a chip at
+8 x 2,048 with the residuals kept. Sampled content rows against a float32
+reference, padded rows zero, then ms a call and the share of the bf16 peak of
+the NEEDED work (the prompt's own causal triangle), and the same with the
+kernel's tile classes held back a stage at a time (the causal triangle's
+pairs only; + blocks that are all padding dropped; + the unmasked interior
+body = the kernel), each stage's content rows equal to the kernel's bit for
+bit. With ``--bundles DIR`` it first prints the instruction bundles of the
+interior and of the edge body from a described-v5e compile (no chip needed).
+
 ``--only trinity`` (not part of the default run) serves Trinity-Large-Preview's
 language model at its published widths on the benchmark configuration's cut
 (``perfbench/configs/trinity-large-serve.json``: a dense window layer and one
@@ -223,6 +239,31 @@ class WalkSize:
     # and output against float32 (the chip reads 0.0003-0.0004 on unit-variance
     # values, PERF.md section 6, PR 44; a head taken from another's rows ~1)
     tol: float = 0.02
+
+
+@dataclasses.dataclass(frozen=True)
+class FlashSize:
+    """What ``--only flash`` runs (defaults: the chip run): ``(name, q heads,
+    kv heads, d_qk, d_v, batch, bucket, prompt, residuals)``, the flash
+    forward's callers in the benchmark's cells: a prompt left-padded to its
+    bucket; ``residuals``: the call keeps ``lse`` for a backward (training)."""
+
+    shapes: Tuple[Tuple[str, int, int, int, int, int, int, int, bool], ...] = (
+        ("dsv2lite 9,003 of 16,384", 16, 16, 192, 128, 1, 16384, 9003, False),
+        ("dsv2lite 20,566 of 20,992", 16, 16, 192, 128, 1, 20992, 20566, False),
+        ("trinity full layer 11,534 of 16,384", 48, 8, 128, 128, 1, 16384, 11534, False),
+        ("codegen2 1,000 of 1,024", 16, 16, 256, 256, 1, 1024, 1000, False),
+        ("codegen2 1,100 of 2,048", 16, 16, 256, 256, 1, 2048, 1100, False),
+        ("codegen2 train 8 x 2,048", 4, 4, 256, 256, 8, 2048, 2048, True),
+    )
+    rows: int = 256               # content rows compared with the float32 reference, a shape
+    calls: int = 10
+    dtype: str = "bfloat16"
+    # largest |kernel - float32 reference| over the compared rows: a bf16
+    # output of unit-variance values against float32
+    tol: float = 0.02
+    # the shape whose bodies --bundles counts
+    bundles_shape: int = 0
 
 
 def log(msg: str) -> None:
@@ -2087,6 +2128,152 @@ def walk_phase(size: WalkSize, seed: int) -> Dict[str, bool]:
     return checks
 
 
+def _flash_forward(q, k, v, seg, residuals):
+    """The forward under test on (B, H, S, D) arrays: ``kernels/flash_attention
+    ._flash_fwd`` with the blocks ``flash_attention`` picks."""
+    import importlib
+
+    fa = importlib.import_module("neuronx_distributed_tpu.kernels.flash_attention")   # the package exports the function under this name
+    block = fa._pick_block(q.shape[2])
+    return fa._flash_fwd(q, k, v, True, block, block, fa.interpret_mode(None), q_seg=seg, k_seg=seg,
+                         residuals=residuals)[0]
+
+
+# what --only flash measures besides the kernel as it is: the same call with
+# the tile classes held back a stage at a time (ISSUE 45's stages)
+_FLASH_STAGES = ("triangle only", "+ padded rows", "+ interior body")
+
+
+def _flash_stage_classes(stage: str, rule):
+    """``_tile_classes`` as stage ``stage`` would have it: 'triangle only'
+    keeps padding meeting padding and every live pair in the masked body,
+    '+ padded rows' drops the all-padding blocks, '+ interior body' is the rule."""
+    def classes(xp, *args):
+        *head, residuals = args
+        found = rule(xp, *head, True if stage == _FLASH_STAGES[0] else residuals)
+        return found if stage == _FLASH_STAGES[2] else xp.minimum(found * 2, 2)   # interior -> edge
+    return classes
+
+
+def _flash_listing_compile(shape) -> None:
+    """Compile the flash forward at ``shape`` for a described v5e (a process of
+    its own, as :func:`_walk_listing_compile`)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from neuronx_distributed_tpu.kernels import backend
+
+    backend.INTERPRET, backend.on_tpu = False, lambda: True
+    jax.config.update("jax_enable_compilation_cache", False)
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    one = SingleDeviceSharding(topo.devices[0])
+    s = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one)   # noqa: E731
+    _, h, hkv, d, dv, b, seq, _, residuals = shape
+
+    def flash_step(q, k, v, seg):
+        return _flash_forward(q, k, v, seg, residuals)
+
+    jax.jit(flash_step).lower(s((b, h, seq, d)), s((b, hkv, seq, d)), s((b, hkv, seq, dv)),
+                              s((b, seq), jnp.int32)).compile()
+
+
+def flash_body_bundles(directory: str, size: FlashSize = FlashSize()) -> Tuple[int, int]:
+    """Instruction bundles of the interior and of the edge body in the flash
+    forward's final schedule, compiled for a described v5e (no chip): in the
+    listing of the custom call named after ``flash_step``, the two largest of
+    the kernel's four predicated regions (init, interior, edge and finish, in
+    that order)."""
+    import glob
+    import re
+    import subprocess
+
+    os.makedirs(directory, exist_ok=True)
+    env = dict(os.environ, JAX_PLATFORMS="cpu", TPU_LOG_DIR="disabled",
+               LIBTPU_INIT_ARGS=f"--xla_jf_dump_to={directory} --xla_jf_dump_llo_text=true")
+    code = f"import chip_smoke; chip_smoke._flash_listing_compile({size.shapes[size.bundles_shape]!r})"
+    subprocess.run([sys.executable, "-c", code], env=env, cwd=os.path.dirname(os.path.abspath(__file__)),
+                   stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL, timeout=600)
+    listings = [f for f in glob.glob(os.path.join(directory, "*flash_step*final_bundles.txt"))
+                if "schedule-analysis" not in f]
+    if not listings:
+        raise RuntimeError(f"the compile left no listing of flash_step under {directory}")
+    # each predicated region ends at its fallthrough (``PF:``); the address counts bundles
+    address = re.compile(r"^\s*(0x[0-9a-f]+) PF: ")
+    ends = [int(m.group(1), 16) for m in map(address.match, open(max(listings, key=os.path.getmtime))) if m]
+    spans = [b - a for a, b in zip(ends, ends[1:])]
+    interior, edge = [n for n in spans if n in sorted(spans)[-2:]]     # in program order
+    return interior, edge
+
+
+def flash_phase(size: FlashSize, seed: int, forward=None) -> Dict[str, bool]:
+    """The flash forward alone at the cells' shapes, a call = one layer of one
+    prefill (or of one training microbatch): content rows against a float32
+    reference, padded rows zero, the interior body against the edge body bit
+    for bit, then ms a call and the share of the bf16 peak of NEEDED work
+    (the prompt's own causal triangle), the stages one at a time. ``forward``:
+    another ``(q, k, v, seg, residuals) -> out`` to measure in the kernel's
+    place (an earlier tree's), without the stages."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from perfbench.peaks import peaks_for
+
+    fa = importlib.import_module("neuronx_distributed_tpu.kernels.flash_attention")
+
+    peak = peaks_for(jax.devices()[0].device_kind)["flops_bf16"] if jax.devices()[0].platform == "tpu" else float("nan")
+    dtype, f32 = jnp.dtype(size.dtype), lambda a: a.astype(jnp.float32)   # noqa: E731
+    rule, checks = fa._tile_classes, {}
+    for n, (name, h, hkv, d, dv, b, seq, prompt, residuals) in enumerate(size.shapes):
+        keys = jax.random.split(jax.random.PRNGKey(seed + n), 3)
+        q = jax.random.normal(keys[0], (b, h, seq, d), dtype)
+        k = jax.random.normal(keys[1], (b, hkv, seq, d), dtype)
+        v = jax.random.normal(keys[2], (b, hkv, seq, dv), dtype)
+        pad = seq - prompt
+        seg = jnp.broadcast_to(jnp.where(jnp.arange(seq) < pad, -1, 0).astype(jnp.int32), (b, seq)) if pad else None
+        rows = np.sort(np.random.default_rng(seed).choice(np.arange(pad, seq), min(size.rows, prompt), replace=False))
+
+        def reference(q, k, v):   # float32, the sampled content rows against every key
+            s = jnp.einsum("bhqd,bhkd->bhqk", f32(q[:, :, rows]), jnp.repeat(f32(k), h // hkv, axis=1)) / math.sqrt(d)
+            cols = jnp.arange(seq)[None]
+            s = jnp.where((cols <= rows[:, None]) & (cols >= pad), s, -jnp.inf)
+            return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), jnp.repeat(f32(v), h // hkv, axis=1))
+
+        with jax.default_matmul_precision("highest"):
+            want = np.asarray(jax.jit(reference)(q, k, v))
+        def call():   # a new function a trace: jit caches by identity, and a stage patches what a trace reads
+            return lambda qq, kk, vv: (forward or _flash_forward)(qq, kk, vv, seg, residuals)
+
+        got = np.asarray(f32(jax.jit(call())(q, k, v)))
+        err = float(np.abs(got[:, :, rows] - want).max())
+        checks[f"flash_{n}_content_rows_match_float32"] = err <= size.tol
+        checks[f"flash_{n}_padded_rows_are_zero"] = residuals or forward is not None or not got[:, :, :pad].any()
+        needed = b * 2.0 * prompt * prompt * h * (d + dv) / 2.0
+        ms = {}
+        if forward is None:
+            try:
+                for stage in _FLASH_STAGES[:2]:
+                    fa._tile_classes = _flash_stage_classes(stage, rule)
+                    held = np.asarray(f32(jax.jit(call())(q, k, v)))
+                    checks[f"flash_{n}_equals_the_stage_{stage.strip('+ ').replace(' ', '_')}_bit_for_bit"] = bool(
+                        (held[:, :, pad:] == got[:, :, pad:]).all())
+                    ms[stage] = _median_call_ms(call(), (q, k, v), size.calls)
+            finally:
+                fa._tile_classes = rule
+            log(f"flash {name}: grid steps, bodies, edge bodies, needed a head = {fa.flash_tile_plan(seq, prompt)}")
+        ms["the kernel" if forward is None else "the given forward"] = _median_call_ms(call(), (q, k, v), size.calls)
+        log(f"flash {name}: {h}/{hkv} heads of {d}/{dv}, batch {b}: " + "; ".join(
+            f"{stage} {t:.3f} ms a call, {100 * needed / (t * 1e-3) / peak:.1f}% of the bf16 peak"
+            for stage, t in ms.items())
+            + f"; largest |kernel - float32| over {len(rows)} content rows {err:.5f} (limit {size.tol})")
+        del q, k, v, got, want
+    return checks
+
+
 def moe_phase(size: MoeSize, seed: int) -> Dict[str, bool]:
     """The streamed expert MLP alone, a call = one layer of a decode step:
     against the float32 routed sum, the grouped-matmul form and two controls,
@@ -2177,14 +2364,15 @@ def one_chip(seed: int, devices, train: TrainSize = TrainSize(),
              serve: ServeSize = ServeSize(), mla: MlaSize = MlaSize(),
              only: str = "all", dsa: DsaSize = DsaSize(),
              glm: GlmSize = GlmSize(), moe: MoeSize = MoeSize(),
-             trinity: TrinitySize = TrinitySize(), walk: WalkSize = WalkSize()) -> Dict[str, bool]:
+             trinity: TrinitySize = TrinitySize(), walk: WalkSize = WalkSize(),
+             flash: FlashSize = FlashSize()) -> Dict[str, bool]:
     """The default run: train, then serve, then the MLA model, the
     sparse-attention model and GLM-5's (sparse selection among latents), in
     one process on one device; ``only="mla"``, ``"dsa"`` or ``"glm"``: that
     phase alone; ``only="moe"``: the streamed expert MLP alone, which no
     other phase repeats; ``only="trinity"``: Trinity's window and full
     attention layers in one paged cache, likewise; ``only="walk"``: their
-    decode kernel alone."""
+    decode kernel alone; ``only="flash"``: the flash forward alone."""
     if only == "mla":
         return mla_phase(mla, seed)
     if only == "dsa":
@@ -2197,6 +2385,8 @@ def one_chip(seed: int, devices, train: TrainSize = TrainSize(),
         return trinity_phase(trinity, seed)
     if only == "walk":
         return walk_phase(walk, seed)
+    if only == "flash":
+        return flash_phase(flash, seed)
     return {**train_phase(train, seed, devices), **serve_phase(serve, seed),
             **mla_phase(mla, seed), **dsa_phase(dsa, seed), **glm_phase(glm, seed)}
 
@@ -2218,27 +2408,35 @@ def parse_args(argv=None):
                         "tp=4 train step and tp=4 engine and their "
                         "one-device counterparts")
     p.add_argument("--seed", type=int, default=0)
-    p.add_argument("--only", default="all", choices=("all", "mla", "dsa", "glm", "moe", "trinity", "walk"),
+    p.add_argument("--only", default="all", choices=("all", "mla", "dsa", "glm", "moe", "trinity", "walk", "flash"),
                    help="one chip: every phase (default), or the MLA, the "
                         "sparse-attention (dsa) or the GLM-5 (glm) phase alone; "
                         "moe: the streamed expert MLP's checks and sweep; "
                         "trinity: window and full attention layers in one paged cache; "
-                        "walk: their decode kernel alone, ms a call and GB/s")
+                        "walk: their decode kernel alone, ms a call and GB/s; "
+                        "flash: the flash forward alone at the cells' prefill and training shapes, "
+                        "ms a call and share of the bf16 peak, a stage of its tile classes at a time")
     p.add_argument("--bundles", metavar="DIR", default=None,
-                   help="with --only walk: first compile the kernel for a described v5e with the "
-                        "compiler's listing dumped to DIR, and print one block's instruction bundles")
+                   help="with --only walk or flash: first compile the kernel for a described v5e with the "
+                        "compiler's listing dumped to DIR, and print the instruction bundles of one block's "
+                        "body (walk) or of the interior and the edge body (flash)")
     return p.parse_args(argv)
 
 
 def main(argv=None) -> int:
     args = parse_args(argv)
     if args.bundles is not None:
-        if args.only != "walk":
-            print("chip_smoke: --bundles goes with --only walk", file=sys.stderr)
+        if args.only not in ("walk", "flash"):
+            print("chip_smoke: --bundles goes with --only walk or --only flash", file=sys.stderr)
             return 2
         # before this process touches JAX: the compile's process loads the TPU's library itself
-        log(f"walk: one block's body is {walk_block_bundles(args.bundles)} instruction bundles in the "
-            f"described-v5e listing under {args.bundles}")
+        if args.only == "walk":
+            log(f"walk: one block's body is {walk_block_bundles(args.bundles)} instruction bundles in the "
+                f"described-v5e listing under {args.bundles}")
+        else:
+            interior, edge = flash_body_bundles(args.bundles)
+            log(f"flash: the interior body is {interior} instruction bundles and the edge body {edge} in the "
+                f"described-v5e listing under {args.bundles}")
     import jax
 
     devices = jax.devices()

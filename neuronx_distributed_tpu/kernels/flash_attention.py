@@ -3,10 +3,14 @@
 ``flash_fwd``/``flash_attn_bwd`` kernels; here the kernels themselves).
 
 Structure (canonical TPU flash attention):
-  * layout (B, H, S, D); grid (B, H, nQ, nK) with the K dimension innermost and
-    sequential, carrying the online-softmax state (running max m, sum l, and
-    the output accumulator) in VMEM scratch across K blocks;
-  * causal skipping: K blocks strictly above the diagonal are skipped;
+  * layout (B, H, S, D); the forward's grid is (B, H, pairs): a static list of
+    (q block, k block) pairs, a q block's k blocks in order and sequential,
+    carrying the online-softmax state (running max m, sum l, and the output
+    accumulator) in VMEM scratch across them; each pair is classed empty,
+    interior or edge and the kernel acts on the class (see "forward" below);
+    the backward kernels keep the rectangular grid (B, H, nQ, nK);
+  * causal skipping: K blocks strictly above the diagonal are skipped (the
+    forward of a self-attention call does not visit them);
   * forward also emits LSE (= m + log l) per row, the residual the backward
     uses to recompute attention probabilities blockwise — so no S×S matrix is
     ever materialized in HBM (the reference kernel keeps the same residual);
@@ -46,10 +50,11 @@ recipe needs regularization. See PARITY.md.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -65,24 +70,147 @@ def _pick_block(s: int, preferred: int = 512) -> int:
     return max(b, 1)
 
 
-def _seg_block_ranges(seg: jax.Array, block: int):
+def _seg_block_ranges(seg, block: int, xp=jnp):
     """Per-block (min, max) of segment ids: (B, S) → two (B, S//block) int32
     arrays. Rides in SMEM so kernels can skip block pairs whose segment ranges
     cannot intersect (exact for sorted/packed layouts, conservative-correct
     for arbitrary ones)."""
     b, s = seg.shape
     tiles = seg.reshape(b, s // block, block)
-    return tiles.min(-1).astype(jnp.int32), tiles.max(-1).astype(jnp.int32)
+    return tiles.min(-1).astype(xp.int32), tiles.max(-1).astype(xp.int32)
 
 
 # --- forward ------------------------------------------------------------------
+#
+# Every (q block, k block) pair has a CLASS, from the causal geometry and the
+# blocks' segment ranges (:func:`_tile_classes`, the one rule; the kernel, the
+# fetch table and :func:`flash_tile_plan` all read it):
+#
+#   empty     nothing of the pair is kept: above the diagonal, segment ranges
+#             that cannot meet and, where no residual is kept, a block that is
+#             all padding. No body runs and nothing is fetched: the step names
+#             the K / V block already resident.
+#   interior  everything is kept: strictly below the diagonal, both blocks one
+#             and the same segment. A body with no iota, no ``where``, no
+#             compare; q and k go to the first product in their storage type.
+#   edge      the diagonal cuts it, or segment ranges that overlap without
+#             being equal: the masked body.
+#
+# The grid's last axis walks a static list of pairs, a q block's k blocks in
+# order: the causal TRIANGLE for a static-offset self-attention call (pairs
+# above the diagonal are not in the list), the rectangle otherwise (ring
+# attention's offsets and cross-length calls, whose geometry is only known at
+# run time: there an empty pair is a step that fetches nothing). The class and
+# the block to fetch ride in ONE prefetched int32 a (batch row, pair).
 
-def _fwd_kernel(q_off_ref, k_off_ref, qseg_ref, kseg_ref, qmin_ref, qmax_ref,
-                kmin_ref, kmax_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr, *, causal, scale, block_q, block_k,
-                num_k_blocks, dyn_offsets, segments):
-    i = pl.program_id(2)  # q block
-    j = pl.program_id(3)  # k block
+_EMPTY, _INTERIOR, _EDGE = 0, 1, 2
+
+
+def _diag_block(i, block_q: int, block_k: int):
+    """The last k block q block ``i`` reads under a causal mask: its last
+    row's own column's. The three causal forwards of this file end a q block's
+    keys here: a step past it names this block again, and fetches nothing."""
+    return (i * block_q + block_q - 1) // block_k
+
+
+# The running max ``m`` and sum ``l`` of a row live LANE-REPLICATED, (block_q,
+# 128): as a (block_q, 1) column each vreg held eight useful numbers, and every
+# use against the (block_q, block_k) scores was a lane broadcast on the
+# cross-lane unit, the unit the row max and the row sum already queue on (the
+# described-v5e listing: the body's 2,421 bundles held 128 ``vperm`` and fell
+# to 1,721 without them, the masks being under 20 of either; PERF.md section
+# 6, PR 45). The same numbers, in the same order.
+_LANES = 128
+
+
+def _lanes(x, n: int):
+    """``x`` (rows, 128), a row's lanes all equal, as (rows, n)."""
+    if n % _LANES == 0:
+        return x if n == _LANES else pltpu.repeat(x, n // _LANES, axis=1)
+    if n < _LANES:
+        return x[:, :n]
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
+def _tile_pairs(nq: int, nk: int, block_q: int, block_k: int, triangle: bool):
+    """The pairs the grid walks, row-major: ``(qi, kj)`` int32 numpy arrays.
+    ``triangle``: a q block's k blocks end at its diagonal."""
+    last = _diag_block(np.arange(nq), block_q, block_k) if triangle else np.full(nq, nk - 1)
+    last = np.minimum(last, nk - 1)
+    if int((last + 1).sum()) > 2 ** 20:
+        raise ValueError(
+            f"flash attention: {nq} x {nk} blocks of {block_q} x {block_k} are more pairs than the "
+            "kernel's prefetched tables hold; pad the sequence to a multiple of 128")
+    qi = np.repeat(np.arange(nq), last + 1)
+    kj = np.concatenate([np.arange(n + 1) for n in last])
+    return qi.astype(np.int32), kj.astype(np.int32)
+
+
+def _tile_classes(xp, qi, kj, block_q: int, block_k: int, causal: bool, q_off, k_off,
+                  ranges, residuals: bool):
+    """The class of each pair ``(qi[t], kj[t])``: ``(T,)``, or ``(B, T)`` under
+    segment ``ranges`` = per-block ``(qmin, qmax, kmin, kmax)``, each ``(B,
+    blocks)``. ``xp`` is ``numpy`` or ``jax.numpy``: one rule for the kernel's
+    table and for :func:`flash_tile_plan`'s count. ``residuals``: padded rows
+    are kept as rows (the backward reads their ``lse``), so padding meets
+    padding like any other segment."""
+    live, cut = True, False
+    if causal:
+        row0, col0 = q_off + qi * block_q, k_off + kj * block_k
+        live = col0 <= row0 + (block_q - 1)          # some key at or before some row
+        cut = col0 + (block_k - 1) > row0            # some key after some row
+    if ranges is not None:
+        qmn, qmx, kmn, kmx = ranges
+        qmn, qmx, kmn, kmx = qmn[:, qi], qmx[:, qi], kmn[:, kj], kmx[:, kj]
+        live = live & (qmx >= kmn) & (qmn <= kmx)
+        if not residuals:                            # nobody reads a padded row
+            live = live & (qmx >= 0) & (kmx >= 0)
+        cut = cut | ~((qmn == qmx) & (kmn == kmx) & (qmn == kmn))
+    return xp.where(live, xp.where(cut, _EDGE, _INTERIOR), _EMPTY).astype(xp.int32)
+
+
+def _tile_plan(classes: jax.Array, kj) -> jax.Array:
+    """``block_to_fetch * 4 + class`` for each (batch row, pair): a live pair
+    fetches its own k block, an empty one names the last live pair's (or, in
+    front of the first, the next one's): resident, so nothing moves."""
+    n = classes.shape[-1]
+    t = jnp.arange(n, dtype=jnp.int32)
+    live = classes != _EMPTY
+    before = jax.lax.cummax(jnp.where(live, t, -1), axis=classes.ndim - 1)
+    after = jax.lax.cummin(jnp.where(live, t, n - 1), axis=classes.ndim - 1, reverse=True)
+    return jnp.asarray(kj)[jnp.where(before >= 0, before, after)] * 4 + classes
+
+
+def flash_tile_plan(seq: int, n_valid: int, block_q: Optional[int] = None,
+                    block_k: Optional[int] = None) -> Tuple[int, int, int, int]:
+    """What the forward does for ONE left-padded prompt of ``n_valid`` tokens in
+    a bucket of ``seq`` (a forward-only causal self-attention call, blocks as
+    :func:`flash_attention` picks them): ``(grid_steps, bodies, edge_bodies,
+    needed)`` a head. ``needed`` counts the pairs that hold a content row and a
+    content key at or before it; ``1 - bodies / grid_steps`` is how often a
+    step runs nothing, ``bodies / needed`` what is still multiplied in vain."""
+    bq, bk = block_q or _pick_block(seq), block_k or _pick_block(seq)
+    pad = seq - n_valid
+    qi, kj = _tile_pairs(seq // bq, seq // bk, bq, bk, triangle=True)
+    seg = np.where(np.arange(seq) < pad, -1, 0)[None]
+    ranges = _seg_block_ranges(seg, bq, np) + _seg_block_ranges(seg, bk, np)
+    classes = _tile_classes(np, qi, kj, bq, bk, True, 0, 0, ranges, residuals=False)[0]
+    needed = ((qi + 1) * bq > pad) & ((kj + 1) * bk > pad)
+    return (int(qi.size), int((classes != _EMPTY).sum()), int((classes == _EDGE).sum()),
+            int(needed.sum()))
+
+
+def _fwd_kernel(qi_ref, kj_ref, plan_ref, q_off_ref, k_off_ref, qseg_ref, kseg_ref,
+                q_ref, k_ref, v_ref, o_ref, *rest, causal, scale, block_q, block_k,
+                num_k_blocks, triangle, dyn_offsets, segments, residuals):
+    lse_ref = rest[0] if residuals else None
+    m_scr, l_scr, acc_scr = rest[-3:]
+    t = pl.program_id(2)
+    i, j = qi_ref[t], kj_ref[t]                      # q block, k block
+    kind = plan_ref[pl.program_id(0), t] & 3
+    last = num_k_blocks - 1
+    if triangle:
+        last = jnp.minimum(_diag_block(i, block_q, block_k), last)
 
     @pl.when(j == 0)
     def _init():
@@ -90,35 +218,45 @@ def _fwd_kernel(q_off_ref, k_off_ref, qseg_ref, kseg_ref, qmin_ref, qmax_ref,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # causal: skip K blocks entirely above the diagonal. With dynamic global
-    # offsets (ring attention: this shard's rows start at q_off, the visiting
-    # K/V shard's at k_off) the skip test moves to runtime — a fully-future
-    # K shard skips every block, leaving l = 0 → lse ≈ -inf, which the ring
-    # merge treats as a zero contribution.
-    q_off = q_off_ref[0] if dyn_offsets else 0
-    k_off = k_off_ref[0] if dyn_offsets else 0
-    run = (
-        (k_off + j * block_k <= q_off + i * block_q + block_q - 1)
-        if causal
-        else True
-    )
-    if segments:
-        # skip block pairs whose segment-id ranges cannot intersect
-        bidx = pl.program_id(0)
-        overlap = (qmax_ref[bidx, i] >= kmin_ref[bidx, j]) & (
-            qmin_ref[bidx, i] <= kmax_ref[bidx, j]
+    def accumulate(s):
+        m_prev = m_scr[:]                              # (BQ, 128), lane-replicated
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        # exp-safe reference point: rows with every key masked so far keep
+        # m = -inf; subtracting a finite 0 makes exp(s - ref) underflow to 0
+        # instead of exp(-inf - -inf) = 1 polluting l
+        ref = jnp.where(m_new > NEG_INF / 2, m_new, 0.0)
+        p = jnp.exp(s - _lanes(ref, block_k))          # (BQ, BK)
+        alpha = jnp.exp(m_prev - ref)                  # (BQ, 128)
+        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[:] = acc_scr[:] * _lanes(alpha, acc_scr.shape[1]) + jax.lax.dot_general(
+            p, v_ref[0, 0].astype(jnp.float32), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )
-        run = overlap if run is True else (run & overlap)
+        m_scr[:] = m_new
 
-    @pl.when(run)
-    def _body():
+    @pl.when(kind == _INTERIOR)
+    def _interior():
+        # no mask can cut this tile. bf16 x bf16 products are exact in the
+        # float32 accumulator: the edge body's arithmetic without its casts
+        accumulate(jax.lax.dot_general(
+            q_ref[0, 0], k_ref[0, 0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale)
+
+    # with dynamic global offsets (ring attention: this shard's rows start at
+    # q_off, the visiting K/V shard's at k_off) a fully-future K shard is all
+    # empty pairs, leaving l = 0 → lse ≈ -inf, which the ring merge treats as a
+    # zero contribution
+    @pl.when(kind == _EDGE)
+    def _edge():
         q = q_ref[0, 0].astype(jnp.float32)           # (BQ, D)
         k = k_ref[0, 0].astype(jnp.float32)           # (BK, D)
-        v = v_ref[0, 0].astype(jnp.float32)           # (BK, D)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale                                      # (BQ, BK)
         if causal:
+            q_off = q_off_ref[0] if dyn_offsets else 0
+            k_off = k_off_ref[0] if dyn_offsets else 0
             rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0) + i * block_q + q_off
             cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1) + j * block_k + k_off
             s = jnp.where(rows >= cols, s, NEG_INF)
@@ -126,25 +264,17 @@ def _fwd_kernel(q_off_ref, k_off_ref, qseg_ref, kseg_ref, qmin_ref, qmax_ref,
             qs = qseg_ref[0]                           # (BQ, 1)
             ks = kseg_ref[0]                           # (1, BK)
             s = jnp.where(qs == ks, s, NEG_INF)
-        m_prev = m_scr[:]                              # (BQ, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        # exp-safe reference point: rows with every key masked so far keep
-        # m = -inf; subtracting a finite 0 makes exp(s - ref) underflow to 0
-        # instead of exp(-inf - -inf) = 1 polluting l
-        ref = jnp.where(m_new > NEG_INF / 2, m_new, 0.0)
-        p = jnp.exp(s - ref)                           # (BQ, BK)
-        alpha = jnp.exp(m_prev - ref)                  # (BQ, 1)
-        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        m_scr[:] = m_new
+        accumulate(s)
 
-    @pl.when(j == num_k_blocks - 1)
+    @pl.when(j == last)
     def _finish():
-        l = l_scr[:]
-        o_ref[0, 0] = (acc_scr[:] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_scr[:] + jnp.log(jnp.maximum(l, 1e-30))
+        l = l_scr[:, :1]
+        out = acc_scr[:] / jnp.maximum(l, 1e-30)
+        if residuals:
+            lse_ref[0, 0] = m_scr[:, :1] + jnp.log(jnp.maximum(l, 1e-30))
+        elif segments:
+            out = jnp.where(qseg_ref[0] >= 0, out, 0.0)   # a padded row reads as zeros
+        o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
 def _off_arr(off) -> jax.Array:
@@ -195,13 +325,14 @@ def _seg_specs(segments, block_q, block_k, qmap, kmap):
 
 
 def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int, interpret: bool,
-               q_off=None, k_off=None, q_seg=None, k_seg=None):
+               q_off=None, k_off=None, q_seg=None, k_seg=None, residuals: bool = True):
     """Forward kernel call. ``q`` (B, H, S, D); ``k``/``v`` (B, Hkv, Sk, D)
     with Hkv | H — the BlockSpec head map serves GQA natively, no repeat.
     ``q_off``/``k_off`` are dynamic global position offsets for the causal
     mask (ring attention); None compiles the static zero-offset fast path.
     ``q_seg``/``k_seg`` (B, S)/(B, Sk) int32 segment ids enable the
-    equal-segment mask (packed documents / padding)."""
+    equal-segment mask (packed documents / padding). ``residuals=False`` (no
+    backward will read this call): ``(out, None)``, padded rows zero."""
     b, h, s, d = q.shape
     hkv, sk = k.shape[1], k.shape[2]
     dv = v.shape[3]  # the value head size may differ from q/k's (MLA: 192/128)
@@ -210,50 +341,62 @@ def _flash_fwd(q, k, v, causal: bool, block_q: int, block_k: int, interpret: boo
     scale = 1.0 / (d ** 0.5)
     dyn = q_off is not None or k_off is not None
     segments = q_seg is not None
+    triangle = causal and not dyn and s == sk
+    qi, kj = _tile_pairs(nq, nk, block_q, block_k, triangle)
+    q_off, k_off = _off_arr(0 if q_off is None else q_off), _off_arr(0 if k_off is None else k_off)
+    q_col, k_row, *ranges = _seg_operands(q_seg, k_seg, block_q, block_k)
+    classes = _tile_classes(jnp, qi, kj, block_q, block_k, causal, q_off[0] if dyn else 0,
+                            k_off[0] if dyn else 0, ranges if segments else None, residuals)
+    plan = _tile_plan(jnp.broadcast_to(classes, (b, qi.size)), kj)
     kernel = functools.partial(
         _fwd_kernel, causal=causal, scale=scale,
-        block_q=block_q, block_k=block_k, num_k_blocks=nk, dyn_offsets=dyn,
-        segments=segments,
+        block_q=block_q, block_k=block_k, num_k_blocks=nk, triangle=triangle,
+        dyn_offsets=dyn, segments=segments, residuals=residuals,
     )
-    out, lse = pl.pallas_call(
+
+    def q_row(b_, h_, t, qi_ref, *_):
+        return (b_, h_, qi_ref[t], 0)
+
+    def kv_block(b_, h_, t, qi_ref, kj_ref, plan_ref, *_):
+        return (b_, h_ // group, plan_ref[b_, t] >> 2, 0)
+
+    seg_specs = [_SMEM_SPEC] * 2       # the dummies of a call without segments
+    if segments:
+        seg_specs = [
+            pl.BlockSpec((1, block_q, 1), lambda b_, h_, t, qi_ref, *_: (b_, qi_ref[t], 0)),
+            pl.BlockSpec((1, 1, block_k),
+                         lambda b_, h_, t, qi_ref, kj_ref, plan_ref, *_: (b_, 0, plan_ref[b_, t] >> 2)),
+        ]
+    out_specs = [pl.BlockSpec((1, 1, block_q, dv), q_row)]
+    out_shape = [jax.ShapeDtypeStruct((b, h, s, dv), q.dtype)]
+    if residuals:
+        out_specs.append(pl.BlockSpec((1, 1, block_q, 1), q_row))
+        out_shape.append(jax.ShapeDtypeStruct((b, h, s, 1), jnp.float32))
+    out = pl.pallas_call(
         kernel,
-        grid=(b, h, nq, nk),
-        in_specs=[
-            _SMEM_SPEC,
-            _SMEM_SPEC,
-            *_seg_specs(
-                segments, block_q, block_k,
-                lambda b_, h_, i, j: (b_, i),
-                lambda b_, h_, i, j: (b_, j),
-            ),
-            pl.BlockSpec((1, 1, block_q, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, i, j: (b_, h_ // group, j, 0)),
-            pl.BlockSpec((1, 1, block_k, dv), lambda b_, h_, i, j: (b_, h_ // group, j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, block_q, dv), lambda b_, h_, i, j: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, block_q, 1), lambda b_, h_, i, j: (b_, h_, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, h, s, dv), q.dtype),
-            jax.ShapeDtypeStruct((b, h, s, 1), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, dv), jnp.float32),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(b, h, qi.size),
+            in_specs=[
+                *seg_specs,
+                pl.BlockSpec((1, 1, block_q, d), q_row),
+                pl.BlockSpec((1, 1, block_k, d), kv_block),
+                pl.BlockSpec((1, 1, block_k, dv), kv_block),
+            ],
+            out_specs=out_specs,
+            scratch_shapes=[
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+                pltpu.VMEM((block_q, _LANES), jnp.float32),
+                pltpu.VMEM((block_q, dv), jnp.float32),
+            ],
+        ),
+        out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(
-        _off_arr(q_off if q_off is not None else 0),
-        _off_arr(k_off if k_off is not None else 0),
-        *_seg_operands(q_seg, k_seg, block_q, block_k),
-        q, k, v,
-    )
-    return out, lse
+    )(jnp.asarray(qi), jnp.asarray(kj), plan, q_off, k_off, q_col, k_row, q, k, v)
+    return (out[0], out[1]) if residuals else (out[0], None)
 
 
 # --- backward -----------------------------------------------------------------
@@ -516,7 +659,7 @@ def _flash_bwd(res, g, causal: bool, block_q: int, block_k: int, interpret: bool
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
 def _flash_attention_bhsd(q, k, v, q_seg, k_seg, causal, block_q, block_k, interpret):
     out, _ = _flash_fwd(q, k, v, causal, block_q, block_k, interpret,
-                        q_seg=q_seg, k_seg=k_seg)
+                        q_seg=q_seg, k_seg=k_seg, residuals=False)
     return out
 
 
@@ -628,10 +771,17 @@ def flash_attention(
 
     ``segment_ids`` (B, S) int32: positions attend only within EQUAL segment
     ids — block-diagonal packed-document isolation and padding masking in one
-    mechanism (use ``-1`` for padding). ``kv_segment_ids`` defaults to
-    ``segment_ids`` (self-attention); pass it separately for cross-length
-    cases. Block pairs with disjoint segment ranges are skipped in all three
-    kernels."""
+    mechanism. ``kv_segment_ids`` defaults to ``segment_ids``
+    (self-attention); pass it separately for cross-length cases.
+
+    The contract the kernels rely on: ids ``>= 0`` are documents, a NEGATIVE
+    id is padding. A padded query row's output is ZERO in a forward-only call
+    (no block pair that is all padding on either side is multiplied or
+    fetched), and unspecified but finite under differentiation (there a padded
+    row is kept as a row: the backward kernels form ``exp(s - lse)`` of it).
+    Block pairs above the causal diagonal or with disjoint segment ranges run
+    no body in any of the three kernels; the forward does not visit or fetch
+    them either, and runs its unmasked body where no mask can cut a pair."""
     b, s, h, d = q.shape
     hkv = k.shape[2]
     if h % hkv != 0:
@@ -717,7 +867,7 @@ def masked_flash_attention(q, k, v, keep, block_q: int = 512, block_k: int = 512
     kt, vt = jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)
 
     def last(i):  # the last k block a q block reads: later steps name it again, and fetch nothing
-        return (i * bq + bq - 1) // bk
+        return _diag_block(i, bq, bk)
 
     out = pl.pallas_call(
         functools.partial(
@@ -839,7 +989,7 @@ def banded_flash_attention(q, k, v, window: int, kv_valid=None,
 
     def key_block(i, j):
         # the band's blocks, then the last one again: a repeated block is not fetched
-        return jnp.minimum(_band_first(i, bq, bk, window) + j, (i * bq + bq - 1) // bk)
+        return jnp.minimum(_band_first(i, bq, bk, window) + j, _diag_block(i, bq, bk))
 
     valid_map = (
         (lambda b_, h_, i, j: (b_, 0, key_block(i, j))) if use_valid

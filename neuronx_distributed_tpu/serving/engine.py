@@ -245,6 +245,7 @@ from neuronx_distributed_tpu.inference.spec_decode import (
 )
 from neuronx_distributed_tpu.inference.utils import unwrap_logits
 from neuronx_distributed_tpu.kernels import backend
+from neuronx_distributed_tpu.kernels.flash_attention import flash_tile_plan
 from neuronx_distributed_tpu.modules.attention import (
     cache_bytes_per_token_layer,
     cache_fingerprint,
@@ -347,6 +348,23 @@ def _bucket(p: int, max_seq_len: int, remaining: int, floor: int = 8) -> int:
         if b + remaining > max_seq_len:
             b = p
     return b
+
+
+def _flash_tiles(padded: int, p: int) -> Dict[str, int]:
+    """What the flash forward does with a fresh ``p``-token prompt in its
+    bucket, a head a layer that runs it, for the prefill span
+    (``kernels/flash_attention.flash_tile_plan``): ``flash_steps`` the grid
+    walks, ``flash_tiles`` whose body runs (``1 - flash_tiles / flash_steps``:
+    how often a step runs nothing), ``flash_edge_tiles`` of them in the masked
+    body, ``flash_needed_tiles`` that hold a content row and a content key.
+    Nothing for a suffix prefill (it runs the decode path's attention) or for
+    an exact-length fallback over 512 tokens, which the kernel tiles in blocks
+    of a few rows."""
+    if p <= 0 or (padded > 512 and padded % 128):
+        return {}
+    steps, bodies, edge, needed = flash_tile_plan(padded, p)
+    return {"flash_steps": steps, "flash_tiles": bodies, "flash_edge_tiles": edge,
+            "flash_needed_tiles": needed}
 
 
 def _suffix_bucket(s: int, padded: int, max_seq_len: int) -> int:
@@ -2795,7 +2813,7 @@ class ServingEngine:
         plan = self._plan_prefix_reuse(ctx, p, padded)
         reused = plan[1] if plan is not None else 0
         self.tracer.step(req.rid, "prefix_lookup", args={"matched": reused})
-        sp.set_metadata(padded=padded, reused=reused)
+        sp.set_metadata(padded=padded, reused=reused, **_flash_tiles(padded, p if plan is None else 0))
         call = self._prefill_calls
         self._prefill_calls += 1
         t0 = self._clock()

@@ -8,7 +8,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from neuronx_distributed_tpu.kernels.flash_attention import flash_attention
+from _flash_fwd_parent import _flash_fwd as _parent_flash_fwd
+
+from neuronx_distributed_tpu.kernels.flash_attention import (
+    _flash_fwd,
+    flash_attention,
+    flash_tile_plan,
+)
 from neuronx_distributed_tpu.models.llama import _xla_attention
 
 
@@ -193,7 +199,7 @@ def test_segments_forward(causal):
 
 def test_segments_padding_forward():
     # padding = segment -1 at the tail; valid rows must exactly match the
-    # padding-masked golden
+    # padding-masked golden, and a padded row of a forward-only call is zeros
     q, k, v = _rand_qkv(jax.random.PRNGKey(11), 2, 128, 2, 32)
     valid = np.ones((2, 128), bool)
     valid[0, 96:] = False
@@ -203,7 +209,8 @@ def test_segments_padding_forward():
         q, k, v, causal=True, segment_ids=seg, block_q=64, block_k=64
     )
     ref = _xla_attention(q, k, v, causal=True, segment_ids=seg)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(out)[valid], np.asarray(ref)[valid], atol=2e-5)
+    assert not np.asarray(out)[~valid].any()
 
 
 def test_segments_gqa_forward():
@@ -261,12 +268,15 @@ def test_segments_equal_unpacked_documents():
         start += n
 
 
-def test_segments_backward_padding():
-    """Grads flow only within valid segments; padded tail contributes the
-    same as the masked golden (incl. the lse≈-inf guard in the backward)."""
+@pytest.mark.parametrize("side", ["right", "left", "left_whole_blocks"])
+def test_segments_backward_padding(side):
+    """Grads flow only within valid segments; the padding (a tail; a prompt's
+    left padding, its edge inside a block and on one) contributes the same as
+    the masked golden (incl. the lse≈-inf guard in the backward): under
+    differentiation a padded row is KEPT as a row, so every grad is finite."""
     q, k, v = _rand_qkv(jax.random.PRNGKey(15), 1, 128, 2, 32)
     valid = np.ones((1, 128), bool)
-    valid[0, 80:] = False
+    valid[0, {"right": slice(80, None), "left": slice(0, 48), "left_whole_blocks": slice(0, 64)}[side]] = False
     seg = jnp.asarray(np.where(valid, 0, -1).astype(np.int32))
     vmask = jnp.asarray(valid)[..., None, None]
 
@@ -286,3 +296,153 @@ def test_segments_backward_padding():
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), atol=5e-4, err_msg=f"d{name}"
         )
+        assert np.isfinite(np.asarray(a)).all(), f"d{name}"
+
+
+# --- classed tiles: empty / interior / edge (the forward since PR 45) -----------
+#
+# The reference is the forward as it stood before (``_flash_fwd_parent.py``):
+# a content row meets the same key blocks in the same order with the same
+# arithmetic. On the chip that is EQUAL, bit for bit (``chip_smoke.py --only
+# flash`` holds the interior body to the edge body there); the CPU's compiler
+# contracts a multiply-add in one body and not in the other, so here it is
+# equal to float32's last bit (``_LAST_BIT``, on values of magnitude < 8).
+
+_LAST_BIT = 1e-6
+
+
+def _same(got, want, err_msg=""):
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0, atol=_LAST_BIT, err_msg=err_msg)
+
+
+_HEADS = {"mha": (2, 2, 32, 32), "gqa": (4, 2, 32, 32), "dv_narrower": (2, 2, 48, 32)}
+
+
+def _bhsd(key, b, s, h, hkv, d, dv, dtype=jnp.float32):
+    ks = jax.random.split(key, 3)
+    return (jax.random.normal(ks[0], (b, h, s, d), dtype), jax.random.normal(ks[1], (b, hkv, s, d), dtype),
+            jax.random.normal(ks[2], (b, hkv, s, dv), dtype))
+
+
+@pytest.mark.parametrize("heads", sorted(_HEADS))
+@pytest.mark.parametrize("bucket,prompt", [(256, 100), (256, 128), (256, 256), (384, 70)],
+                         ids=["edge_inside_a_block", "edge_on_a_boundary", "no_padding", "mostly_padding"])
+def test_left_padded_prompt_equals_the_parents_forward(bucket, prompt, heads):
+    h, hkv, d, dv = _HEADS[heads]
+    q, k, v = _bhsd(jax.random.PRNGKey(20), 2, bucket, h, hkv, d, dv)
+    seg = jnp.asarray(np.tile(np.where(np.arange(bucket) < bucket - prompt, -1, 0)[None], (2, 1)).astype(np.int32))
+    want, want_lse = _parent_flash_fwd(q, k, v, True, 64, 64, True, q_seg=seg, k_seg=seg)
+    got, none = _flash_fwd(q, k, v, True, 64, 64, True, q_seg=seg, k_seg=seg, residuals=False)
+    assert none is None
+    content = np.asarray(seg[0]) >= 0
+    _same(np.asarray(got)[:, :, content], np.asarray(want)[:, :, content])
+    assert not np.asarray(got)[:, :, ~content].any()        # nobody reads a padded row: zeros
+    # where the backward will read the rows, every row is the parent's, lse too
+    kept, lse = _flash_fwd(q, k, v, True, 64, 64, True, q_seg=seg, k_seg=seg)
+    _same(kept, want)
+    _same(lse, want_lse)
+
+
+def test_left_padded_bf16_prompt_equals_the_parents_forward():
+    """Storage-type operands in the interior body: bf16 x bf16 products are
+    exact in the float32 accumulator, so the casts it leaves out change nothing."""
+    q, k, v = _bhsd(jax.random.PRNGKey(21), 1, 256, 4, 2, 32, 32, jnp.bfloat16)
+    seg = jnp.asarray(np.where(np.arange(256) < 90, -1, 0)[None].astype(np.int32))
+    want, _ = _parent_flash_fwd(q, k, v, True, 64, 64, True, q_seg=seg, k_seg=seg)
+    got, _ = _flash_fwd(q, k, v, True, 64, 64, True, q_seg=seg, k_seg=seg, residuals=False)
+    np.testing.assert_allclose(np.asarray(got, np.float32)[:, :, 90:], np.asarray(want, np.float32)[:, :, 90:],
+                               rtol=2 ** -7, atol=1e-3)     # bf16's last bit
+
+
+@pytest.mark.parametrize("case", ["packed_documents", "packed_and_padded", "no_segments", "not_causal",
+                                  "uneven_blocks", "cross_length"])
+def test_forward_unchanged_where_no_row_is_padding(case):
+    """Packed documents (ids >= 0), plain causal, non-causal, blocks of two
+    sizes and a cross-length call: output AND lse equal to the parent's."""
+    b, s, sk, bq, bk, causal = 2, 256, 256, 64, 64, case != "not_causal"
+    if case == "uneven_blocks":
+        bq, bk = 64, 32
+    if case == "cross_length":
+        sk, causal = 128, False
+    ks = jax.random.split(jax.random.PRNGKey(22), 3)
+    q = jax.random.normal(ks[0], (b, 4, s, 32))
+    k, v = jax.random.normal(ks[1], (b, 2, sk, 32)), jax.random.normal(ks[2], (b, 2, sk, 32))
+    q_seg = k_seg = None
+    if case.startswith("packed"):
+        q_seg = k_seg = _doc_segments([128, 96, 32], b=b)          # two documents and a third in one batch row
+        if case == "packed_and_padded":
+            q_seg = k_seg = jnp.where(jnp.arange(s)[None] >= 240, -1, q_seg)
+    if case == "cross_length":
+        q_seg, k_seg = _doc_segments([128, 128], b=b), _doc_segments([64, 64], b=b)
+    want = _parent_flash_fwd(q, k, v, causal, bq, bk, True, q_seg=q_seg, k_seg=k_seg)
+    got = _flash_fwd(q, k, v, causal, bq, bk, True, q_seg=q_seg, k_seg=k_seg)
+    for a, b_, name in zip(got, want, ("out", "lse")):
+        _same(a, b_, name)
+    primal, _ = _flash_fwd(q, k, v, causal, bq, bk, True, q_seg=q_seg, k_seg=k_seg, residuals=False)
+    rows = np.ones(s, bool) if q_seg is None else np.asarray(q_seg[0]) >= 0
+    _same(np.asarray(primal)[:, :, rows], np.asarray(want[0])[:, :, rows])
+
+
+@pytest.mark.parametrize("q_off,k_off", [(256, 0), (256, 256), (0, 256), (128, 64)],
+                         ids=["past_shard", "own_shard", "future_shard", "straddling"])
+def test_dynamic_offsets_unchanged(q_off, k_off):
+    """Ring attention's form (the causal geometry known at run time only): the
+    rectangle is walked, a fully-future shard is all empty pairs (lse ~ -inf)."""
+    q, k, v = _bhsd(jax.random.PRNGKey(23), 1, 256, 2, 2, 32, 32)
+    seg = _doc_segments([160, 96])
+    fwd = lambda f: jax.jit(lambda qo, ko: f(q, k, v, True, 64, 64, True, q_off=qo, k_off=ko,   # noqa: E731
+                                             q_seg=seg, k_seg=seg))(jnp.int32(q_off), jnp.int32(k_off))
+    for a, b_, name in zip(fwd(_flash_fwd), fwd(_parent_flash_fwd), ("out", "lse")):
+        _same(a, b_, name)
+
+
+def _brute_force_tiles(seq, n_valid, block, parent):
+    """Count a left-padded prompt's tiles element by element: the pairs a grid
+    walks, those whose body runs, and those that hold a content row and a
+    content key at or before it."""
+    n, pad = seq // block, seq - n_valid
+    seg = np.where(np.arange(seq) < pad, -1, 0).reshape(n, block)
+    steps = bodies = needed = 0
+    for i in range(n):
+        for j in range(n):
+            causal = j * block <= i * block + block - 1
+            steps += parent or causal
+            meet = seg[i].max() >= seg[j].min() and seg[i].min() <= seg[j].max()
+            content = seg[i].max() >= 0 and seg[j].max() >= 0
+            bodies += causal and meet and (parent or content)
+            rows, cols = np.arange(block)[:, None] + i * block, np.arange(block)[None] + j * block
+            needed += bool(((rows >= cols) & (rows >= pad) & (cols >= pad)).any())
+    return steps, bodies, needed
+
+
+# (bucket, prompt) of the serving cells' eight-prompt blocks: the tape's
+# quantiles (perfbench/tape.py) in the buckets ``serving/engine._bucket`` gives
+_CELL_BLOCKS = {
+    "dsv2lite_docs_closed": [(4096, 3263), (8192, 4811), (8192, 6110), (8192, 7454), (16384, 9003),
+                             (16384, 10984), (16384, 13950), (20992, 20566)],
+    "trinity_mixedctx_closed": [(4096, 2799), (8192, 4402), (8192, 5818), (8192, 7338), (16384, 9146),
+                                (16384, 11534), (16384, 15244), (16384, 16384)],
+    "codegen2_lines_steady": [(1024, 1000), (2048, 1100), (512, 300), (2048, 2048)],
+    "small_blocks": [(256, 100), (256, 128), (384, 70), (64, 1)],
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_CELL_BLOCKS))
+def test_flash_tile_plan_against_a_brute_force_count(cell):
+    block = 64 if cell == "small_blocks" else 512
+    plans = [flash_tile_plan(seq, n, block, block) for seq, n in _CELL_BLOCKS[cell]]
+    brute = [_brute_force_tiles(seq, n, block, parent=False) for seq, n in _CELL_BLOCKS[cell]]
+    was = [_brute_force_tiles(seq, n, block, parent=True) for seq, n in _CELL_BLOCKS[cell]]
+    for (steps, bodies, edge, needed), (b_steps, b_bodies, b_needed) in zip(plans, brute):
+        assert (steps, bodies, needed) == (b_steps, b_bodies, b_needed)
+        assert bodies == needed and 0 < edge <= bodies
+    assert all(new[0] <= old[0] and new[1] <= old[1] for new, old in zip(plans, was))
+    if cell == "dsv2lite_docs_closed":       # ISSUE 45's count of the cell's block, a head a layer
+        assert (sum(w[0] for w in was), sum(w[1] for w in was)) == (5585, 2215)
+        assert (sum(p[0] for p in plans), sum(p[1] for p in plans)) == (2889, 1972)
+
+
+def test_flash_tile_plan_defaults_to_the_blocks_the_kernel_picks():
+    assert flash_tile_plan(16384, 9003) == flash_tile_plan(16384, 9003, 512, 512)
+    steps, bodies, edge, needed = flash_tile_plan(16384, 9003)
+    assert (steps, bodies, needed) == (528, 171, 171) and edge == 18 + 17   # the diagonal + the padding's edge column

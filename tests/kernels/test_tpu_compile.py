@@ -255,6 +255,28 @@ def test_flash_prefill_with_a_narrower_value_head_compiles(topo, seq):
     assert KERNEL in _compiled_text(fn, qk, qk, v, seg)
 
 
+@pytest.mark.parametrize("residuals", [False, True], ids=["forward_only", "residuals_kept"])
+@pytest.mark.parametrize("bucket", [16384, 20992])
+def test_classed_flash_forward_compiles_at_the_docs_cells_geometry(topo, bucket, residuals):
+    """The forward since PR 45 at cell 4's prefill (16 heads, q/k 192, v 128,
+    the 16,384 and 20,992 buckets, batch 1): the grid walks the causal
+    triangle's pairs from prefetched tables (528 and 861 steps, not 1,024 and
+    1,681), the class and the block to fetch in one int32 a pair; forward-only
+    (no ``lse`` output) and as the backward's residuals keep it."""
+    from neuronx_distributed_tpu.kernels.flash_attention import _flash_fwd, _tile_pairs
+
+    s = _one_chip(topo)
+    qk, v = s((1, MLA["h"], bucket, MLA["d_qk"])), s((1, MLA["h"], bucket, MLA["d_v"]))
+    n = bucket // 512
+    assert _tile_pairs(n, n, 512, 512, triangle=True)[0].size == n * (n + 1) // 2
+
+    def fn(q, k, v, seg):
+        return _flash_fwd(q, k, v, True, 512, 512, False, q_seg=seg, k_seg=seg, residuals=residuals)
+
+    text = _compiled_text(fn, qk, qk, v, s((1, bucket), jnp.int32))
+    assert KERNEL in text and f"s32[1,{n * (n + 1) // 2}]" in text        # the plan: a row a batch row, an entry a pair
+
+
 def test_flash_backward_with_a_narrower_value_head_compiles(topo):
     s = _one_chip(topo)
     qk, v = s((2, 2048, MLA["h"], MLA["d_qk"])), s((2, 2048, MLA["h"], MLA["d_v"]))

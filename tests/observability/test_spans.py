@@ -111,6 +111,9 @@ def test_stats_are_host_ints_and_rid_links_a_request(traced):
         assert {"rid", "prompt_tokens", "padded", "reused", "decoding_slots"} <= set(stats)
     fresh = [s for _, _, s, _ in prefills if "ttft_us" in s]
     assert len(fresh) == len(first)                # a re-admission has no TTFT and waited once
+    # what the flash forward does with the prompt in its bucket: a bucket of one block here
+    assert all((s["flash_steps"], s["flash_tiles"], s["flash_edge_tiles"], s["flash_needed_tiles"]) == (1, 1, 1, 1)
+               for _, _, s, _ in prefills if s["reused"] == 0)
     assert all(s["ttft_us"] >= s["queue_wait_us"] >= 0 for s in fresh)
     assert any(s["decoding_slots"] > 0 for _, _, s, _ in prefills)
     # a chunk's executed steps and the tokens that reached a stream

@@ -208,3 +208,19 @@ def test_main_refuses_without_a_tpu(capsys):
     assert chip_smoke.main([]) != 0
     out = capsys.readouterr()
     assert out.out == "" and "needs a TPU" in out.err
+
+
+def test_flash_phase_alone_rehearsal():
+    """``--only flash`` at a tiny size (blocks of 128; the padding's edge
+    inside a block, on a boundary and absent; GQA; a narrower value head; a
+    call that keeps residuals): every comparison. The times are the chip's."""
+    flash = chip_smoke.FlashSize(
+        shapes=(("tiny edge inside", 2, 2, 48, 32, 1, 512, 300, False),
+                ("tiny gqa on a boundary", 4, 2, 32, 32, 1, 512, 256, False),
+                ("tiny train", 2, 2, 32, 32, 2, 256, 256, True)),
+        rows=64, calls=1, dtype="float32", tol=1e-4)
+    checks = chip_smoke.one_chip(0, jax.devices()[:1], TRAIN, SERVE, MLA, only="flash", flash=flash)
+    assert len(checks) == 3 * 4
+    # on the CPU the interior body may differ from the edge body in the last
+    # bit (the compiler contracts a multiply-add in one and not in the other)
+    assert all(ok for name, ok in checks.items() if "_bit_for_bit" not in name), checks
