@@ -6,131 +6,15 @@ Llama-2-7B's published widths (hidden 4096, ffn 11008, 32 heads x 128, vocab
 32000; bf16 compute). No width is cut; depth is cut as far as 16 GB forces
 and is printed. Weights and requests come from ``--seed``.
 
-  train  ``Trainer`` (the ``examples/train_llama.py --model 7b --layers 2
-         --seq-len 2048 --batch-size 4`` path, attention ``auto``) takes 3
-         steps. Step 1's loss and grad-norm are compared with the same
-         seeded model under ``attention_impl="xla"``.
-  serve  a ``ServingEngine`` with its defaults (paged KV, page 16,
-         ``paged_attention="auto"``, decode chunk 8, prefix cache on)
-         answers 8 requests whose prompts span 128-2048 tokens, 32 new
-         tokens each; then a short pass on the row-cache layout. Every
-         emitted token is checked against a plain reference: a cache-free
-         full forward of the same weights with ``attention_impl="xla"`` in
-         fp32 under ``jax.default_matmul_precision("highest")``,
-         teacher-forced on the emitted tokens. Logits are compared, not
-         tokens: the reference logit of every emitted token must be within
-         the printed tolerance of the reference maximum at its position.
-
-  mla    DeepSeek-V2-Lite at its published widths and the benchmark
-         configuration's depth (``perfbench/configs/deepseek-v2-lite-serve
-         .json``), a ``ServingEngine`` of 32,768-column slots: prompts of
-         24,576, 8,192 and 2,048 seeded tokens are prefilled (materialised
-         attention through the flash kernel) and 32 tokens decoded through
-         the paged LATENT cache (absorbed attention through the paged latent
-         kernel). Against ``perfbench/references/deepseek_v2.py`` (float32,
-         ``highest``, materialised form, no cache, the whole context in query
-         blocks): the system's prefill logits at every position of the two
-         shorter prompts and at the last 256 of the longest, and the
-         reference's logit of every decoded token. ``--only mla`` runs this
-         phase alone.
-
-  dsa    Keye-VL-2.0's language model at its published widths and the
-         benchmark configuration's depth (``perfbench/configs/keye-vl2-30b-a3b
-         -serve.json``): first the three decode kernels alone at the serve
-         cell's shapes (8 slots whose contexts end at a shared cursor) against
-         float32 ``jnp``, each with its time against its bytes, and the
-         selection, ``top_k`` against a bisection. Then a ``ServingEngine`` of
-         32,768-column slots: prompts of 24,576, 8,192 and 2,048 tokens are
-         prefilled (the learned mask, the byte-masked flash kernel) and 32
-         tokens decoded through the paged INDEXED cache (index scores,
-         ``top_k``, the sparse kernel). Against ``perfbench/references/
-         keye_vl2.py``: prefill logits and the reference's logit of every
-         decoded token, as ``mla``; the share of layer 0's selected columns
-         that differ from the reference's and how near the threshold they lie;
-         and two controls that must fail: index keys in float8, and 1024 kept.
-         ``--only dsa`` runs this phase alone.
-
-  glm    GLM-5's language model at its published widths on the benchmark
-         configuration's cut (``perfbench/configs/glm-5-serve.json``: its
-         depth, 8 of 256 experts held, a slice of the vocabulary): the sparse
-         LATENT decode kernel alone at the serve cell's shapes against float32
-         ``jnp``, then a ``ServingEngine`` of 32,768-column slots: prompts of
-         16,384, 8,192 and 2,048 tokens are prefilled (the learned mask over
-         materialised MLA) and 32 tokens decoded through the paged indexed
-         LATENT cache (index scores, ``top_k``, the absorbed form over the
-         selected rows). Against ``perfbench/references/glm_moe_dsa.py``,
-         which is given the same share: prefill logits and the reference's
-         logit of every decoded token, as ``mla``; the share of layer 0's
-         selected columns that differ; and four controls that must fail:
-         index keys in float8, 1024 kept, the latent in float8, and the
-         selection bias added to the weights. ``--only glm`` runs this phase
-         alone.
-
-``--only moe`` (not part of the default run: it serves nothing) runs the
-streamed expert MLP (``kernels/moe_stream.py``) alone at the expert shapes of
-DeepSeek-V2-Lite, Keye-VL-2.0 and Mixtral-8x7B: against the float32 ``jnp``
-routed sum and against the grouped-matmul (``ragged_dot``) form, with two
-controls that must fail (the experts' weights in float8; one hit expert
-dropped), then ms a call of both forms over 1-256 rows: the sweep that sets
-``modules/moe/expert_mlps.MOE_STREAM_MAX_TOKENS``.
-
-``--only walk`` (not part of the default run: it serves nothing) runs the
-walking GQA decode kernel (``kernels/flash_decode.paged_walk_decode_attention``)
-alone at the Trinity cell's shapes: 8 slots of 32,768 columns, page 16, 48
-query heads against 8 kv heads of 128, the tape's eight prompt lengths + 256
-as contexts that end at a shared cursor; once as a window layer (272 pages a
-slot, ``floor`` set) and once as a full layer. Each against the float32
-einsum under an index mask, then ms a call with the GB/s of the bytes the call
-needs (``perfbench/swa_costs.py``) and of the bytes its blocks fetch. With
-``--bundles DIR`` it first compiles the kernel for a described v5e in a
-process of its own with the compiler's listing dumped to ``DIR``, and prints
-how many instruction bundles one block's body is (no chip needed for that
-part: it is printed before the device is asked for).
-
-``--only flash`` (not part of the default run: it serves nothing) runs the
-flash forward (``kernels/flash_attention._flash_fwd``, the prefill attention
-of every model but the sparse ones and a window layer, and the training
-forward) alone at the cells' shapes: DeepSeek-V2-Lite's 16 heads of 192 / 128
-at 9,003 tokens of a 16,384 bucket and 20,566 of 20,992, Trinity's full layer
-(48 / 8 heads of 128) at 11,534 of 16,384, CodeGen2's 16 heads of 256 at
-1,000 of 1,024 and 1,100 of 2,048, and the training cell's 4 heads a chip at
-8 x 2,048 with the residuals kept. Sampled content rows against a float32
-reference, padded rows zero, then ms a call and the share of the bf16 peak of
-the NEEDED work (the prompt's own causal triangle), and the same with the
-kernel's tile classes held back a stage at a time (the causal triangle's
-pairs only; + blocks that are all padding dropped; + the unmasked interior
-body = the kernel), each stage's content rows equal to the kernel's bit for
-bit. With ``--bundles DIR`` it first prints the instruction bundles of the
-interior and of the edge body from a described-v5e compile (no chip needed).
-
-``--only trinity`` (not part of the default run) serves Trinity-Large-Preview's
-language model at its published widths on the benchmark configuration's cut
-(``perfbench/configs/trinity-large-serve.json``: a dense window layer and one
-period of three window layers and a full one, 32 of 256 experts held, a slice
-of the vocabulary) through a ``ServingEngine`` of 32,768-column slots whose
-paged cache has a block table and a pool a layer KIND: prompts of 9,146, 4,402
-and 2,048 tokens are prefilled (the banded flash forward on the window
-layers), admitted shortest first and an engine step apart so that each longer
-prompt's admission jumps the shared cursor over the slots already decoding,
-and 32 tokens decoded over those gap columns through the kernel that walks
-the blocks a slot maps. Against
-``perfbench/references/afmoe.py``, which is given the same share: prefill
-logits and the reference's logit of every decoded token; then each mechanism
-held on ONE block, every limit between the system's reading and a control's:
-a window layer's attention (| no window | a window of 2048 | the gate left
-out), the full layer's on the system's own input (| rotary applied), what a
-window layer's POOL holds of a served context after pages were freed behind
-the window (| a float8 cache), and the held experts' routed sum (| the bias
-added to the weights).
-
-``--chips 4`` runs only the four-chip path and what it is compared with: a
-tp=4 + sequence-parallel train step against the same seeded step on one
-device of the same process, ``ServingEngine(tp=4)`` against the
-mesh-free engine on the same requests, and the training cell's step
-(CodeGen2-7B's widths at ten layers, 8 x 2048 tokens) rematerialised under
-"save nothing" and under the two named saves of ``modules/remat.py``: the
-same losses to bf16's rounding, each chip's peak bytes and the step's time
-printed, so the trade can be measured again in one call.
+What runs is one table, ``PHASES`` at the end of this file: a phase a row,
+each phase's function saying in its docstring what it runs and what it is
+compared with. The default run is the table's ``default`` rows of one chip in
+order (``train``, ``serve``, ``mla``, ``dsa``, ``glm``); ``--only NAME`` runs
+one row alone, among them the rows no default run includes (they serve
+nothing, or repeat what a benchmark cell holds): ``moe``, ``trinity``,
+``walk``, ``flash``; ``--chips 4`` runs only the four-chip path and what it is
+compared with, the table's ``default`` rows of four chips (``tp_train``,
+``tp_serve``, ``remat``).
 
 Every phase also asserts which implementation ``"auto"`` resolved to (the
 program ledger's ``resolved`` record) and that the Pallas kernels are in the
@@ -153,7 +37,7 @@ import math
 import os
 import sys
 import time
-from typing import Dict, List, Sequence, Tuple
+from typing import Callable, Dict, List, Sequence, Tuple
 
 KERNEL = "tpu_custom_call"
 
@@ -276,6 +160,16 @@ def _llama(size, **over):
     if size.config is not None:
         return dataclasses.replace(size.config, **over)
     return llama2_7b(num_layers=size.layers, **over)
+
+
+def _published(size, config: str) -> dict:
+    """``size.model`` (the CPU rehearsal's tiny configuration), or the
+    ``model`` group of the benchmark configuration ``perfbench/configs/<config>``."""
+    if size.model is not None:
+        return size.model
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)), "perfbench", "configs", config)
+    with open(path) as f:
+        return json.load(f)["model"]
 
 
 def _ledger_kernels(ledger, names: Sequence[str],
@@ -411,8 +305,11 @@ def _close(a: float, b: float, *, atol: float = 0.0, rtol: float = 0.0) -> bool:
 
 
 def train_phase(size: TrainSize, seed: int, devices) -> Dict[str, bool]:
-    """3 Trainer steps on one device, attention ``auto``; step 1 against the
-    ``xla`` reference. Returns the named checks."""
+    """``Trainer`` takes 3 steps on one device, attention ``auto``, step 1
+    against the ``xla`` reference. The ``examples/train_llama.py --model 7b
+    --layers 2 --seq-len 2048 --batch-size 4`` path: step 1's loss and
+    grad-norm are compared with the same seeded model under
+    ``attention_impl="xla"``. Returns the named checks."""
     trainer, cfg, rows = _train_steps(
         size, seed, devices[:1], tp=1, sp=False, steps=size.steps
     )
@@ -458,8 +355,9 @@ def train_phase(size: TrainSize, seed: int, devices) -> Dict[str, bool]:
 
 
 def tp_train_phase(size: TrainSize, seed: int, devices) -> Dict[str, bool]:
-    """One tp=4 + sequence-parallel step over all ``devices`` against the
-    same seeded step on one of them (init is tp-degree invariant)."""
+    """One tp=4 + sequence-parallel train step over all ``devices`` against
+    the same seeded step on one of them, in the same process (init is
+    tp-degree invariant)."""
     from neuronx_distributed_tpu.observability.hbm import tree_nbytes
 
     solo, cfg, solo_rows = _train_steps(
@@ -523,13 +421,16 @@ class RematSize:
 
 
 def remat_phase(size: RematSize, seed: int, devices) -> Dict[str, bool]:
-    """The trade a remat policy makes, on the training cell's step: the same
-    seeded steps of CodeGen under tp + SP over all ``devices`` with the
-    blocks rematerialised under each of ``size.policies`` ("save nothing"
-    first). Saving a tensor in place of computing it twice changes no
-    arithmetic, so every step's loss must be the same number to
-    ``size.loss_tol`` (bf16's rounding; the same bits in float32); what differs
-    is printed: the step's time, each chip's peak bytes
+    """The training cell's step under each remat policy: the same losses,
+    each chip's peak bytes and the step's time printed. The trade a remat
+    policy makes, so that it can be measured again in one call: the same
+    seeded steps of CodeGen (CodeGen2-7B's widths at ten layers, 8 x 2048
+    tokens) under tp + SP over all ``devices`` with the blocks rematerialised
+    under each of ``size.policies`` ("save nothing" first, then the two named
+    saves of ``modules/remat.py``). Saving a tensor in place of computing it
+    twice changes no arithmetic, so every step's loss must be the same number
+    to ``size.loss_tol`` (bf16's rounding; the same bits in float32); what
+    differs is printed: the step's time, each chip's peak bytes
     (``memory_stats()["peak_bytes_in_use"]``: a process's high water, so a
     chip reads a LATER policy's peak only where it is the larger; device 0
     also held the whole float32 model at every init) and what the trainer
@@ -542,12 +443,7 @@ def remat_phase(size: RematSize, seed: int, devices) -> Dict[str, bool]:
     from neuronx_distributed_tpu.parallel import mesh as mesh_lib
     from perfbench.families import codegen as family
 
-    published = size.model
-    if published is None:
-        path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                            "perfbench", "configs", "codegen2-7b-train-tp4.json")
-        with open(path) as f:
-            published = json.load(f)["model"]
+    published = _published(size, "codegen2-7b-train-tp4.json")
     tp = len(devices)
     mesh_lib.destroy_model_parallel()
     mesh_lib.initialize_model_parallel(tensor_model_parallel_size=tp, devices=list(devices))
@@ -885,8 +781,17 @@ def _engine_report(tag: str, engine, cfg, reqs, wall: float,
 
 
 def serve_phase(size: ServeSize, seed: int) -> Dict[str, bool]:
-    """The default (paged, fused on a TPU) engine, then the row-cache pass,
-    each against the plain reference."""
+    """A ``ServingEngine`` with its defaults answers 8 requests whose prompts
+    span 128-2048 tokens, then a short pass on the row-cache layout, each
+    against the plain reference. The defaults: paged KV, page 16,
+    ``paged_attention="auto"`` (fused on a TPU), decode chunk 8, prefix cache
+    on; 32 new tokens each. Every emitted token is checked against a plain
+    reference: a cache-free full forward of the same weights with
+    ``attention_impl="xla"`` in fp32 under
+    ``jax.default_matmul_precision("highest")``, teacher-forced on the
+    emitted tokens. Logits are compared, not tokens: the reference logit of
+    every emitted token must be within the printed tolerance of the reference
+    maximum at its position."""
     from flax.core import meta
 
     from neuronx_distributed_tpu.observability.hbm import tree_nbytes
@@ -957,8 +862,8 @@ def serve_phase(size: ServeSize, seed: int) -> Dict[str, bool]:
 
 
 def tp_serve_phase(size: ServeSize, seed: int, devices) -> Dict[str, bool]:
-    """``ServingEngine(tp=len(devices))`` against the mesh-free engine on
-    the same requests; both against the plain reference."""
+    """``ServingEngine(tp=len(devices))`` (tp=4) against the mesh-free engine
+    on the same requests; both against the plain reference."""
     import jax
     from flax.core import meta
 
@@ -1057,7 +962,18 @@ TP_SERVE = ServeSize(
 
 
 def mla_phase(size: MlaSize, seed: int) -> Dict[str, bool]:
-    """DeepSeek-V2-Lite through the default engine (module docstring)."""
+    """DeepSeek-V2-Lite alone: 24,576 / 8,192 / 2,048-token prompts through
+    the paged latent cache against the plain reference. The published widths
+    at the benchmark configuration's depth
+    (``perfbench/configs/deepseek-v2-lite-serve.json``), a ``ServingEngine``
+    of 32,768-column slots: the seeded prompts are prefilled (materialised
+    attention through the flash kernel) and 32 tokens decoded through the
+    paged LATENT cache (absorbed attention through the paged latent kernel).
+    Against ``perfbench/references/deepseek_v2.py`` (float32, ``highest``,
+    materialised form, no cache, the whole context in query blocks): the
+    system's prefill logits at every position of the two shorter prompts and
+    at the last 256 of the longest, and the reference's logit of every
+    decoded token."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -1071,12 +987,7 @@ def mla_phase(size: MlaSize, seed: int) -> Dict[str, bool]:
     from perfbench.references.deepseek_v2 import Reference
 
     mesh_lib.destroy_model_parallel()
-    published = size.model
-    if published is None:
-        path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                            "perfbench", "configs", "deepseek-v2-lite-serve.json")
-        with open(path) as f:
-            published = json.load(f)["model"]
+    published = _published(size, "deepseek-v2-lite-serve.json")
     model = family.build(published, runner="serve", max_seq_len=size.max_seq_len)
     if size.model is not None:    # the CPU rehearsal serves in float32
         model = model.clone(config=dataclasses.replace(model.config, dtype=jnp.float32))
@@ -1312,8 +1223,22 @@ def dsa_kernels(size: DsaSize, published: dict, seed: int, dtype) -> Dict[str, b
 
 
 def dsa_phase(size: DsaSize, seed: int) -> Dict[str, bool]:
-    """Keye-VL-2.0's language model through the default engine (module
-    docstring)."""
+    """Keye-VL-2.0's language model alone: the sparse decode kernels by
+    themselves, then the same prompts through the paged indexed cache against
+    the plain reference. The published widths at the benchmark
+    configuration's depth (``perfbench/configs/keye-vl2-30b-a3b-serve.json``):
+    first the three decode kernels alone at the serve cell's shapes (8 slots
+    whose contexts end at a shared cursor) against float32 ``jnp``, each with
+    its time against its bytes, and the selection, ``top_k`` against a
+    bisection. Then a ``ServingEngine`` of 32,768-column slots: prompts of
+    24,576, 8,192 and 2,048 tokens are prefilled (the learned mask, the
+    byte-masked flash kernel) and 32 tokens decoded through the paged INDEXED
+    cache (index scores, ``top_k``, the sparse kernel). Against
+    ``perfbench/references/keye_vl2.py``: prefill logits and the reference's
+    logit of every decoded token, as ``mla``; the share of layer 0's selected
+    columns that differ from the reference's and how near the threshold they
+    lie; and two controls that must fail: index keys in float8, and 1024
+    kept."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -1328,12 +1253,7 @@ def dsa_phase(size: DsaSize, seed: int) -> Dict[str, bool]:
     from perfbench.references.keye_vl2 import Reference
 
     mesh_lib.destroy_model_parallel()
-    published = size.model
-    if published is None:
-        path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                            "perfbench", "configs", "keye-vl2-30b-a3b-serve.json")
-        with open(path) as f:
-            published = json.load(f)["model"]
+    published = _published(size, "keye-vl2-30b-a3b-serve.json")
     model = family.build(published, runner="serve", max_seq_len=size.max_seq_len)
     if size.model is not None:    # the CPU rehearsal serves in float32
         model = model.clone(config=dataclasses.replace(model.config, dtype=jnp.float32))
@@ -1528,7 +1448,22 @@ def glm_kernel(size: GlmSize, published: dict, seed: int, dtype) -> Dict[str, bo
 
 
 def glm_phase(size: GlmSize, seed: int) -> Dict[str, bool]:
-    """GLM-5's language model through the default engine (module docstring)."""
+    """GLM-5's language model alone (8 of 256 experts held, a vocabulary
+    slice): the sparse latent kernel by itself, then 16,384 / 8,192 /
+    2,048-token prompts through the paged indexed latent cache against the
+    plain reference and its four controls. The published widths on the
+    benchmark configuration's cut (``perfbench/configs/glm-5-serve.json``: its
+    depth, 8 of 256 experts held, a slice of the vocabulary): the sparse
+    LATENT decode kernel alone at the serve cell's shapes against float32
+    ``jnp``, then a ``ServingEngine`` of 32,768-column slots: the prompts are
+    prefilled (the learned mask over materialised MLA) and 32 tokens decoded
+    through the paged indexed LATENT cache (index scores, ``top_k``, the
+    absorbed form over the selected rows). Against
+    ``perfbench/references/glm_moe_dsa.py``, which is given the same share:
+    prefill logits and the reference's logit of every decoded token, as
+    ``mla``; the share of layer 0's selected columns that differ; and four
+    controls that must fail: index keys in float8, 1024 kept, the latent in
+    float8, and the selection bias added to the weights."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -1549,11 +1484,7 @@ def glm_phase(size: GlmSize, seed: int) -> Dict[str, bool]:
     from perfbench.references.glm_moe_dsa import Reference
 
     mesh_lib.destroy_model_parallel()
-    published = size.model
-    if published is None:
-        path = os.path.join(os.path.dirname(os.path.abspath(__file__)), "perfbench", "configs", "glm-5-serve.json")
-        with open(path) as f:
-            published = json.load(f)["model"]
+    published = _published(size, "glm-5-serve.json")
     model = family.build(published, runner="serve", max_seq_len=size.max_seq_len)
     if size.model is not None:    # the CPU rehearsal serves in float32
         model = model.clone(config=dataclasses.replace(model.config, dtype=jnp.float32))
@@ -1772,7 +1703,27 @@ class TrinitySize:
 
 
 def trinity_phase(size: TrinitySize, seed: int) -> Dict[str, bool]:
-    """Trinity's language model through the default engine (module docstring)."""
+    """Trinity-Large-Preview alone: window and full attention layers in one
+    paged cache, against the plain reference, each mechanism held on one block
+    against its control. Not part of the default run. The language model at
+    its published widths on the benchmark configuration's cut
+    (``perfbench/configs/trinity-large-serve.json``: a dense window layer and
+    one period of three window layers and a full one, 32 of 256 experts held,
+    a slice of the vocabulary) through a ``ServingEngine`` of 32,768-column
+    slots whose paged cache has a block table and a pool a layer KIND:
+    prompts of 9,146, 4,402 and 2,048 tokens are prefilled (the banded flash
+    forward on the window layers), admitted shortest first and an engine step
+    apart so that each longer prompt's admission jumps the shared cursor over
+    the slots already decoding, and 32 tokens decoded over those gap columns
+    through the kernel that walks the blocks a slot maps. Against
+    ``perfbench/references/afmoe.py``, which is given the same share: prefill
+    logits and the reference's logit of every decoded token; then each
+    mechanism held on ONE block, every limit between the system's reading and
+    a control's: a window layer's attention (| no window | a window of 2048 |
+    the gate left out), the full layer's on the system's own input (| rotary
+    applied), what a window layer's POOL holds of a served context after
+    pages were freed behind the window (| a float8 cache), and the held
+    experts' routed sum (| the bias added to the weights)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -1787,12 +1738,7 @@ def trinity_phase(size: TrinitySize, seed: int) -> Dict[str, bool]:
     from perfbench.references.afmoe import Reference
 
     mesh_lib.destroy_model_parallel()
-    published = size.model
-    if published is None:
-        path = os.path.join(os.path.dirname(os.path.abspath(__file__)), "perfbench", "configs",
-                            "trinity-large-serve.json")
-        with open(path) as f:
-            published = json.load(f)["model"]
+    published = _published(size, "trinity-large-serve.json")
     model = family.build(published, runner="serve", max_seq_len=size.max_seq_len)
     if size.model is not None:    # the CPU rehearsal serves in float32
         model = model.clone(config=dataclasses.replace(model.config, dtype=jnp.float32))
@@ -2066,9 +2012,20 @@ def walk_block_bundles(directory: str, size: WalkSize = WalkSize()) -> int:
 
 
 def walk_phase(size: WalkSize, seed: int) -> Dict[str, bool]:
-    """The walking GQA decode kernel alone, a call = one layer of a decode
-    step, as a window layer and as a full layer: against the float32 einsum
-    under an index mask, then ms a call and GB/s of needed and fetched bytes."""
+    """The walking GQA decode kernel alone at the Trinity cell's shapes, a
+    window layer and a full layer: against the float32 einsum, ms a call and
+    GB/s of needed and fetched bytes. Not part of the default run: it serves
+    nothing. ``kernels/flash_decode.paged_walk_decode_attention``, a call =
+    one layer of a decode step: 8 slots of 32,768 columns, page 16, 48 query
+    heads against 8 kv heads of 128, the tape's eight prompt lengths + 256 as
+    contexts that end at a shared cursor; once as a window layer (272 pages a
+    slot, ``floor`` set) and once as a full layer. Each against the float32
+    einsum under an index mask, then ms a call with the GB/s of the bytes the
+    call needs (``perfbench/swa_costs.py``) and of the bytes its blocks fetch.
+    With ``--bundles DIR`` ``main`` first compiles the kernel for a described
+    v5e in a process of its own with the compiler's listing dumped to ``DIR``,
+    and prints how many instruction bundles one block's body is (no chip
+    needed for that part: it is printed before the device is asked for)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -2208,13 +2165,27 @@ def flash_body_bundles(directory: str, size: FlashSize = FlashSize()) -> Tuple[i
 
 
 def flash_phase(size: FlashSize, seed: int, forward=None) -> Dict[str, bool]:
-    """The flash forward alone at the cells' shapes, a call = one layer of one
-    prefill (or of one training microbatch): content rows against a float32
-    reference, padded rows zero, the interior body against the edge body bit
-    for bit, then ms a call and the share of the bf16 peak of NEEDED work
-    (the prompt's own causal triangle), the stages one at a time. ``forward``:
-    another ``(q, k, v, seg, residuals) -> out`` to measure in the kernel's
-    place (an earlier tree's), without the stages."""
+    """The flash forward alone at the cells' prefill and training shapes: ms
+    a call and share of the bf16 peak, a stage of its tile classes at a time.
+    Not part of the default run: it serves nothing.
+    ``kernels/flash_attention._flash_fwd`` (the prefill attention of every
+    model but the sparse ones and a window layer, and the training forward),
+    a call = one layer of one prefill (or of one training microbatch), a
+    prompt left-padded to its bucket: DeepSeek-V2-Lite's 16 heads of 192 / 128
+    at 9,003 tokens of a 16,384 bucket and 20,566 of 20,992, Trinity's full
+    layer (48 / 8 heads of 128) at 11,534 of 16,384, CodeGen2's 16 heads of
+    256 at 1,000 of 1,024 and 1,100 of 2,048, and the training cell's 4 heads
+    a chip at 8 x 2,048 with the residuals kept. Sampled content rows against
+    a float32 reference, padded rows zero, then ms a call and the share of
+    the bf16 peak of the NEEDED work (the prompt's own causal triangle), and
+    the same with the kernel's tile classes held back a stage at a time (the
+    causal triangle's pairs only; + blocks that are all padding dropped; +
+    the unmasked interior body = the kernel), each stage's content rows equal
+    to the kernel's bit for bit. ``forward``: another ``(q, k, v, seg,
+    residuals) -> out`` to measure in the kernel's place (an earlier
+    tree's), without the stages. With ``--bundles DIR`` ``main`` first prints
+    the instruction bundles of the interior and of the edge body from a
+    described-v5e compile (no chip needed)."""
     import importlib
 
     import jax
@@ -2275,9 +2246,15 @@ def flash_phase(size: FlashSize, seed: int, forward=None) -> Dict[str, bool]:
 
 
 def moe_phase(size: MoeSize, seed: int) -> Dict[str, bool]:
-    """The streamed expert MLP alone, a call = one layer of a decode step:
-    against the float32 routed sum, the grouped-matmul form and two controls,
-    then both forms' ms a call over ``size.tokens`` rows."""
+    """The streamed expert MLP alone at DeepSeek-V2-Lite's, Keye's and
+    Mixtral's expert shapes: checks against float32 ``jnp`` and two controls,
+    then both forms' ms a call over 1-256 rows. Not part of the default run:
+    it serves nothing. ``kernels/moe_stream.py``, a call = one layer of a
+    decode step: against the float32 ``jnp`` routed sum and against the
+    grouped-matmul (``ragged_dot``) form, with two controls that must fail
+    (the experts' weights in float8; one hit expert dropped), then ms a call
+    of both forms over ``size.tokens`` rows: the sweep that sets
+    ``modules/moe/expert_mlps.MOE_STREAM_MAX_TOKENS``."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -2360,62 +2337,66 @@ def moe_phase(size: MoeSize, seed: int) -> Dict[str, bool]:
     return checks
 
 
-def one_chip(seed: int, devices, train: TrainSize = TrainSize(),
-             serve: ServeSize = ServeSize(), mla: MlaSize = MlaSize(),
-             only: str = "all", dsa: DsaSize = DsaSize(),
-             glm: GlmSize = GlmSize(), moe: MoeSize = MoeSize(),
-             trinity: TrinitySize = TrinitySize(), walk: WalkSize = WalkSize(),
-             flash: FlashSize = FlashSize()) -> Dict[str, bool]:
-    """The default run: train, then serve, then the MLA model, the
-    sparse-attention model and GLM-5's (sparse selection among latents), in
-    one process on one device; ``only="mla"``, ``"dsa"`` or ``"glm"``: that
-    phase alone; ``only="moe"``: the streamed expert MLP alone, which no
-    other phase repeats; ``only="trinity"``: Trinity's window and full
-    attention layers in one paged cache, likewise; ``only="walk"``: their
-    decode kernel alone; ``only="flash"``: the flash forward alone."""
-    if only == "mla":
-        return mla_phase(mla, seed)
-    if only == "dsa":
-        return dsa_phase(dsa, seed)
-    if only == "glm":
-        return glm_phase(glm, seed)
-    if only == "moe":
-        return moe_phase(moe, seed)
-    if only == "trinity":
-        return trinity_phase(trinity, seed)
-    if only == "walk":
-        return walk_phase(walk, seed)
-    if only == "flash":
-        return flash_phase(flash, seed)
-    return {**train_phase(train, seed, devices), **serve_phase(serve, seed),
-            **mla_phase(mla, seed), **dsa_phase(dsa, seed), **glm_phase(glm, seed)}
+@dataclasses.dataclass(frozen=True)
+class Phase:
+    """One row of ``PHASES``. Its help line is the first sentence of ``fn``'s
+    docstring."""
+
+    fn: Callable[..., Dict[str, bool]]   # (size, seed) or, with ``devices``, (size, seed, devices) -> the named checks
+    size: object                         # the chip run's size; the CPU rehearsal passes a tiny one
+    devices: bool = False                # ``fn`` takes the devices
+    chips: int = 1                       # the ``--chips`` it runs under
+    default: bool = False                # the run of its ``--chips`` includes it
+    only: bool = False                   # ``--only`` may name it
+
+    @property
+    def help(self) -> str:
+        return " ".join(self.fn.__doc__.split()).split(". ")[0].rstrip(".")
 
 
-def four_chips(seed: int, devices, train: TrainSize = TrainSize(),
-               serve: ServeSize = TP_SERVE, remat: RematSize = RematSize()) -> Dict[str, bool]:
-    """``--chips 4``: the tp paths and what they are compared with."""
-    return {
-        **tp_train_phase(train, seed, devices),
-        **tp_serve_phase(serve, seed, devices),
-        **remat_phase(remat, seed, devices),
-    }
+# in the order they run
+PHASES: Dict[str, Phase] = {
+    "train": Phase(train_phase, TrainSize(), devices=True, default=True),
+    "serve": Phase(serve_phase, ServeSize(), default=True),
+    "mla": Phase(mla_phase, MlaSize(), default=True, only=True),
+    "dsa": Phase(dsa_phase, DsaSize(), default=True, only=True),
+    "glm": Phase(glm_phase, GlmSize(), default=True, only=True),
+    "moe": Phase(moe_phase, MoeSize(), only=True),
+    "trinity": Phase(trinity_phase, TrinitySize(), only=True),
+    "walk": Phase(walk_phase, WalkSize(), only=True),
+    "flash": Phase(flash_phase, FlashSize(), only=True),
+    "tp_train": Phase(tp_train_phase, TrainSize(), devices=True, chips=4, default=True),
+    "tp_serve": Phase(tp_serve_phase, TP_SERVE, devices=True, chips=4, default=True),
+    "remat": Phase(remat_phase, RematSize(), devices=True, chips=4, default=True),
+}
+
+
+def default_run(chips: int) -> List[str]:
+    """The phases ``--chips chips`` runs when ``--only`` names none."""
+    return [name for name, phase in PHASES.items() if phase.default and phase.chips == chips]
+
+
+def run(names: Sequence[str], seed: int, devices, sizes=None) -> Dict[str, bool]:
+    """The phases ``names`` in that order, in one process; ``sizes``: phase
+    name -> a size in place of the table's."""
+    checks: Dict[str, bool] = {}
+    for name in names:
+        phase = PHASES[name]
+        size = (sizes or {}).get(name, phase.size)
+        checks.update(phase.fn(size, seed, devices) if phase.devices else phase.fn(size, seed))
+    return checks
 
 
 def parse_args(argv=None):
+    only = [name for name, phase in PHASES.items() if phase.only]
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     p.add_argument("--chips", type=int, default=1, choices=(1, 4),
-                   help="1 (default): train + serve on one chip. 4: only the "
-                        "tp=4 train step and tp=4 engine and their "
-                        "one-device counterparts")
+                   help=f"1 (default): {' + '.join(default_run(1))} on one chip. 4: only "
+                        f"{' + '.join(default_run(4))}, the four-chip paths and their one-device counterparts")
     p.add_argument("--seed", type=int, default=0)
-    p.add_argument("--only", default="all", choices=("all", "mla", "dsa", "glm", "moe", "trinity", "walk", "flash"),
-                   help="one chip: every phase (default), or the MLA, the "
-                        "sparse-attention (dsa) or the GLM-5 (glm) phase alone; "
-                        "moe: the streamed expert MLP's checks and sweep; "
-                        "trinity: window and full attention layers in one paged cache; "
-                        "walk: their decode kernel alone, ms a call and GB/s; "
-                        "flash: the flash forward alone at the cells' prefill and training shapes, "
-                        "ms a call and share of the bf16 peak, a stage of its tile classes at a time")
+    p.add_argument("--only", default="all", choices=("all", *only),
+                   help="one chip: every default phase (default), or one phase alone. "
+                        + " ".join(f"{name}: {PHASES[name].help}." for name in only))
     p.add_argument("--bundles", metavar="DIR", default=None,
                    help="with --only walk or flash: first compile the kernel for a described v5e with the "
                         "compiler's listing dumped to DIR, and print the instruction bundles of one block's "
@@ -2465,10 +2446,8 @@ def main(argv=None) -> int:
     }
     log(f"device {device}, jax {jax.__version__}, compile cache {cache}")
     t0 = time.perf_counter()
-    if args.chips == 1:
-        checks = one_chip(args.seed, devices, only=args.only)
-    else:
-        checks = four_chips(args.seed, devices)
+    names = [args.only] if args.chips == 1 and args.only != "all" else default_run(args.chips)
+    checks = run(names, args.seed, devices)
     failed = sorted(name for name, ok in checks.items() if not ok)
     log(
         f"{len(checks) - len(failed)}/{len(checks)} checks passed in "

@@ -42,15 +42,13 @@ def _llama_cfg(**over):
     return tiny_llama(max_seq_len=S, **over)
 
 
-@pytest.fixture(scope="module")
-def llama_data():
+def _llama_data():
     ids = jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, 256)
     return {"input_ids": ids, "labels": jnp.roll(ids, -1, 1)}
 
 
-@pytest.fixture(scope="module")
-def llama_baseline(llama_data):
-    """Unsharded golden: params + first-step loss (computed once per module)."""
+def _llama_baseline(llama_data):
+    """Unsharded golden: params + first-step loss."""
     mesh_lib.destroy_model_parallel()
     cfg = _llama_cfg(scan_layers=True)
     model = LlamaForCausalLM(cfg, attention_impl="xla")
@@ -67,6 +65,17 @@ def llama_baseline(llama_data):
     return jax.device_get(params), loss
 
 
+@pytest.fixture(scope="module")
+def llama_data():
+    return _llama_data()
+
+
+@pytest.fixture(scope="module")
+def llama_baseline(llama_data):
+    """Computed once per module."""
+    return _llama_baseline(llama_data)
+
+
 # (tp, sp, pp, zero1, cp, schedule)
 LLAMA_MATRIX = [
     (2, False, 1, False, 1, None),
@@ -81,8 +90,16 @@ LLAMA_MATRIX = [
 ]
 
 
+def _llama_matrix_in_a_child(*case):
+    data = _llama_data()
+    test_llama_matrix(data, _llama_baseline(data), *case, in_child_process=None)
+
+
 @pytest.mark.parametrize("tp,sp,pp,zero1,cp,schedule", LLAMA_MATRIX)
-def test_llama_matrix(llama_data, llama_baseline, tp, sp, pp, zero1, cp, schedule):
+def test_llama_matrix(llama_data, llama_baseline, tp, sp, pp, zero1, cp, schedule, in_child_process):
+    if (tp, pp, cp, schedule) == (2, 2, 1, "1f1b") and in_child_process:
+        # two independent collectives in flight on the 8-device mesh: see conftest.in_child_process
+        return in_child_process(__file__, "_llama_matrix_in_a_child", tp, sp, pp, zero1, cp, schedule)
     base_params, base_loss = llama_baseline
     mesh_lib.destroy_model_parallel()
     mesh_lib.initialize_model_parallel(
@@ -213,7 +230,7 @@ LLAMA_MATRIX_R5 = [
 @pytest.mark.parametrize("tp,sp,pp,zero1,cp,schedule", LLAMA_MATRIX_R5)
 def test_llama_matrix_r5(llama_data, llama_baseline, tp, sp, pp, zero1, cp,
                          schedule):
-    test_llama_matrix(llama_data, llama_baseline, tp, sp, pp, zero1, cp, schedule)
+    test_llama_matrix(llama_data, llama_baseline, tp, sp, pp, zero1, cp, schedule, in_child_process=None)
 
 
 def test_llama_interleaved_c4(llama_data):

@@ -26,6 +26,7 @@ from neuronx_distributed_tpu.models.afmoe import (
 from neuronx_distributed_tpu.modules.attention import WINDOW_LEAF, cache_windows
 
 from perfbench.references.afmoe import Reference
+from tests.models.jitted import forward, through_the_cache
 
 ATOL = 3e-5
 
@@ -73,7 +74,7 @@ def system(request):
 
 def test_the_full_forward_is_the_references(system):
     cfg, model, params, ids, want = system
-    got, _ = model.apply(params, jnp.asarray(ids))
+    got, _ = forward(model, params, jnp.asarray(ids))
     np.testing.assert_allclose(np.asarray(got), want, atol=ATOL)
 
 
@@ -82,17 +83,14 @@ def test_prefill_then_decode_past_three_windows_is_the_references(system):
     window (32) passes three times, the full layer reads everything."""
     cfg, model, params, ids, want = system
     prefill, decode = serving_clones(model)
-    (logits, _), variables = prefill.apply(params, jnp.asarray(ids[:, :20]), mutable=["cache"])
+    (logits, _), cache = through_the_cache(prefill, params, jnp.asarray(ids[:, :20]))
     assert logits.shape[1] == 1        # the head on the LAST position alone
     np.testing.assert_allclose(np.asarray(logits)[:, 0], want[:, 19], atol=ATOL)
-    cache = variables["cache"]
     assert cache_windows(cache) == {
         ("model", f"layers_{i}", "attn"): 32 for i in range(3)}     # the full layer's node has none
     assert cache["model"]["layers_0"]["attn"][WINDOW_LEAF].size == 0
-    step = jax.jit(lambda c, t: decode.apply({**params, "cache": c}, t, mutable=["cache"]))
     for t in range(20, 100):
-        (logits, _), variables = step(cache, jnp.asarray(ids[:, t:t + 1]))
-        cache = variables["cache"]
+        (logits, _), cache = through_the_cache(decode, {**params, "cache": cache}, jnp.asarray(ids[:, t:t + 1]))
         np.testing.assert_allclose(np.asarray(logits)[:, 0], want[:, t], atol=ATOL)
 
 
@@ -107,15 +105,12 @@ def test_unequal_prompts_in_one_batch_count_the_window_in_tokens(system):
     mask = np.zeros((2, 64), bool)
     for r, n in enumerate(lens):
         padded[r, 64 - n:], mask[r, 64 - n:] = ids[r, :n], True
-    (logits, _), variables = prefill.apply(
-        params, jnp.asarray(padded), padding_mask=jnp.asarray(mask), mutable=["cache"])
+    (logits, _), cache = through_the_cache(prefill, params, jnp.asarray(padded), padding_mask=jnp.asarray(mask))
     for r, n in enumerate(lens):
         np.testing.assert_allclose(np.asarray(logits)[r, 0], want[r, n - 1], atol=ATOL)
-    cache = variables["cache"]
     for t in range(8):
         tok = np.stack([ids[r, n + t] for r, n in enumerate(lens)])[:, None]
-        (logits, _), variables = decode.apply({**params, "cache": cache}, jnp.asarray(tok), mutable=["cache"])
-        cache = variables["cache"]
+        (logits, _), cache = through_the_cache(decode, {**params, "cache": cache}, jnp.asarray(tok))
         for r, n in enumerate(lens):
             np.testing.assert_allclose(np.asarray(logits)[r, 0], want[r, n + t], atol=ATOL)
 

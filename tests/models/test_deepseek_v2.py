@@ -18,6 +18,7 @@ from neuronx_distributed_tpu.models.deepseek_v2 import (
     tiny_deepseek_v2,
     yarn_frequencies,
 )
+from tests.models.jitted import forward, through_the_cache
 
 
 @pytest.fixture(scope="module")
@@ -87,23 +88,20 @@ def test_absorbed_decode_matches_the_materialised_forward(tiny):
     form against it and must give the full (materialised, cache-free)
     forward's logits, past the original rope positions too."""
     cfg, model, params, ids = tiny
-    full, _ = model.apply(params, ids)
+    full, _ = forward(model, params, ids)
     prefill, decode = model.clone(mode="prefill"), model.clone(mode="decode")
-    (logits, _), state = prefill.apply(params, ids[:, :40], mutable=["cache"])
+    (logits, _), cache = through_the_cache(prefill, params, ids[:, :40])
     np.testing.assert_allclose(np.asarray(logits), np.asarray(full[:, :40]), atol=2e-5)
-    cache = state["cache"]
     for t in range(40, 72):   # crosses original_max_position_embeddings = 32 .. 72
-        (logits, _), state = decode.apply(
-            {**params, "cache": cache}, ids[:, t:t + 1], mutable=["cache"])
-        cache = state["cache"]
+        (logits, _), cache = through_the_cache(decode, {**params, "cache": cache}, ids[:, t:t + 1])
         np.testing.assert_allclose(np.asarray(logits[:, 0]), np.asarray(full[:, t]), atol=2e-5)
 
 
 def test_cache_holds_one_latent_row_and_one_rope_key_a_token(tiny):
     cfg, model, params, ids = tiny
-    _, state = model.clone(mode="prefill").apply(params, ids[:, :16], mutable=["cache"])
+    _, cache = through_the_cache(model.clone(mode="prefill"), params, ids[:, :16])
     for i in range(cfg.num_layers):
-        leaves = state["cache"]["model"][f"layers_{i}"]["attn"]
+        leaves = cache["model"][f"layers_{i}"]["attn"]
         assert set(leaves) == {"k", "k_pe", "index", "kv_valid"}      # no per-head K or V
         assert leaves["k"].shape == (2, cfg.max_seq_len, 1, cfg.kv_lora_rank)
         assert leaves["k_pe"].shape == (2, cfg.max_seq_len, 1, cfg.qk_rope_head_dim)
@@ -112,10 +110,10 @@ def test_cache_holds_one_latent_row_and_one_rope_key_a_token(tiny):
 def test_left_padded_prefill_equals_the_unpadded_one(tiny):
     cfg, model, params, ids = tiny
     prefill = model.clone(mode="prefill")
-    (want, _), _ = prefill.apply(params, ids[:1, :24], mutable=["cache"])
+    (want, _), _ = through_the_cache(prefill, params, ids[:1, :24])
     padded = jnp.concatenate([jnp.zeros((1, 8), ids.dtype), ids[:1, :24]], axis=1)
     mask = jnp.arange(32)[None] >= 8
-    (got, _), _ = prefill.apply(params, padded, padding_mask=mask, mutable=["cache"])
+    (got, _), _ = through_the_cache(prefill, params, padded, padding_mask=mask)
     np.testing.assert_allclose(np.asarray(got[:, 8:]), np.asarray(want), atol=2e-5)
 
 
@@ -124,14 +122,14 @@ def test_routing_weights_are_not_renormalised_and_shared_experts_add(tiny):
     (the branch is live); renormalising the top-k weights must change them
     too (V2's ``norm_topk_prob`` is false)."""
     cfg, model, params, ids = tiny
-    base, _ = model.apply(params, ids[:, :16])
+    base, _ = forward(model, params, ids[:, :16])
     p = meta.unbox(params)
     no_shared = jax.tree_util.tree_map_with_path(
         lambda path, a: jnp.zeros_like(a) if "shared" in str(path) and "down" in str(path) else a, p)
-    out, _ = model.apply(no_shared, ids[:, :16])
+    out, _ = forward(model, no_shared, ids[:, :16])
     assert float(jnp.abs(out - base).max()) > 1e-3
     renorm = DeepseekV2ForCausalLM(dataclasses.replace(cfg, norm_topk_prob=True), attention_impl="xla")
-    out, _ = renorm.apply(params, ids[:, :16])
+    out, _ = forward(renorm, params, ids[:, :16])
     assert float(jnp.abs(out - base).max()) > 1e-3
 
 
@@ -139,15 +137,15 @@ def test_flash_prefill_runs_with_a_value_head_smaller_than_the_keys(tiny):
     """The materialised form through the flash kernel (interpreted): q/k of
     24 channels, v of 16."""
     cfg, model, params, ids = tiny
-    want, _ = model.apply(params, ids[:, :64])
-    got, _ = model.clone(attention_impl="flash").apply(params, ids[:, :64])
+    want, _ = forward(model, params, ids[:, :64])
+    got, _ = forward(model.clone(attention_impl="flash"), params, ids[:, :64])
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=5e-5)
 
 
 def test_loss_and_gradients_are_finite(tiny):
     cfg, model, params, ids = tiny
-    loss, grads = jax.value_and_grad(
-        lambda p: model.loss(p, ids[:, :-1], ids[:, 1:]))(meta.unbox(params))
+    loss, grads = jax.jit(jax.value_and_grad(
+        lambda p: model.loss(p, ids[:, :-1], ids[:, 1:])))(meta.unbox(params))
     assert np.isfinite(float(loss))
     assert all(np.isfinite(np.asarray(g)).all() for g in jax.tree.leaves(grads))
     assert float(jnp.abs(grads["params"]["model"]["layers_1"]["attn"]["kv_b_proj"]).max()) > 0

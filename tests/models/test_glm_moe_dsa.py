@@ -26,6 +26,7 @@ from neuronx_distributed_tpu.modules.attention import (
 )
 
 from perfbench.references.glm_moe_dsa import Reference
+from tests.models.jitted import forward, through_the_cache
 
 ATOL = 3e-5
 
@@ -62,17 +63,16 @@ def prefill_logits(model, params, ids, **kw):
     """``(logits at EVERY position, cache)`` of a prefill: the served model
     applies its head to the last position alone."""
     backbone = GlmMoeDsaModel(model.config, model.attention_impl, mode="prefill")
-    (hidden, _), state = backbone.apply({"params": params["params"]["model"]}, ids, mutable=["cache"], **kw)
-    return hidden @ params["params"]["lm_head"]["kernel"], {"model": state["cache"]}
+    (hidden, _), cache = through_the_cache(backbone, {"params": params["params"]["model"]}, ids, **kw)
+    return hidden @ params["params"]["lm_head"]["kernel"], {"model": cache}
 
 
 def _decode_step(decode):
-    """One jitted decode step: ``(params, cache, token) -> (logits, cache)``."""
+    """One compiled decode step: ``(params, cache, tokens) -> (logits, cache)``."""
 
-    @jax.jit
     def step(params, cache, tok):
-        (logits, _), state = decode.apply({**params, "cache": cache}, tok, mutable=["cache"])
-        return logits, state["cache"]
+        (logits, _), cache = through_the_cache(decode, {**params, "cache": cache}, tok)
+        return logits, cache
 
     return step
 
@@ -94,6 +94,13 @@ def tiny(request):
     model = GlmMoeDsaForCausalLM(cfg, attention_impl="xla")
     ids = jax.random.randint(jax.random.PRNGKey(1), (2, 96), 1, cfg.vocab_size)
     return cfg, model, params, ids, Reference(published_keys(cfg), params)
+
+
+@pytest.fixture(scope="module")
+def want(tiny):
+    """The reference's logits of ``ids``, its full forward, once a share."""
+    *_, ids, ref = tiny
+    return ref.logits(np.asarray(ids))
 
 
 def test_the_tiny_preset_holds_every_mechanism():
@@ -140,7 +147,7 @@ def test_published_widths_count_the_issues_parameters():
 
 def test_full_forward_matches_the_reference_with_selection_at_work(tiny):
     cfg, model, params, ids, ref = tiny
-    logits, _ = model.apply(params, ids)
+    logits, _ = forward(model, params, ids)
     want, margin = ref.logits_and_router_margin(np.asarray(ids))
     np.testing.assert_allclose(np.asarray(logits), want, atol=ATOL)
     assert margin.shape == (2, 96) and (margin >= 0).all()
@@ -149,29 +156,26 @@ def test_full_forward_matches_the_reference_with_selection_at_work(tiny):
     assert np.isfinite(margin).all() == (cfg.held_experts is None) and np.isfinite(margin).any()
 
 
-def test_prefill_then_decode_through_the_cache_matches_the_references_full_forward(tiny):
+def test_prefill_then_decode_through_the_cache_matches_the_references_full_forward(tiny, want):
     """Logits, not tokens: the prompt's at every position, then 56 decode
     steps against the cache, each row keeping 16 of up to 96 latents."""
     cfg, model, params, ids, ref = tiny
-    want = ref.logits(np.asarray(ids))
     prefill, decode = model.clone(mode="prefill"), model.clone(mode="decode")
     logits, _ = prefill_logits(model, params, ids[:, :40])
     np.testing.assert_allclose(np.asarray(logits), want[:, :40], atol=ATOL)
-    (last, _), state = prefill.apply(params, ids[:, :40], mutable=["cache"])
+    (last, _), cache = through_the_cache(prefill, params, ids[:, :40])
     assert last.shape == (2, 1, cfg.vocab_size)          # all a caller of a prefill reads
     np.testing.assert_allclose(np.asarray(last[:, 0]), want[:, 39], atol=ATOL)
-    cache, step = state["cache"], _decode_step(decode)
+    step = _decode_step(decode)
     for t in range(40, 96):
         logits, cache = step(params, cache, ids[:, t:t + 1])
         np.testing.assert_allclose(np.asarray(logits[:, 0]), want[:, t], atol=ATOL)
 
 
-def test_a_many_row_decode_step_is_the_suffix_prefill(tiny):
+def test_a_many_row_decode_step_is_the_suffix_prefill(tiny, want):
     cfg, model, params, ids, ref = tiny
-    want = ref.logits(np.asarray(ids))
-    _, state = model.clone(mode="prefill").apply(params, ids[:, :24], mutable=["cache"])
-    (logits, _), _ = model.clone(mode="decode").apply(
-        {**params, "cache": state["cache"]}, ids[:, 24:96], mutable=["cache"])
+    _, cache = through_the_cache(model.clone(mode="prefill"), params, ids[:, :24])
+    (logits, _), _ = through_the_cache(model.clone(mode="decode"), {**params, "cache": cache}, ids[:, 24:96])
     np.testing.assert_allclose(np.asarray(logits), want[:, 24:], atol=ATOL)
 
 
@@ -184,9 +188,9 @@ def test_unequal_prompts_in_one_batch_decode_as_each_alone(tiny):
     rows = [np.asarray(ids[i, :n]) for i, n in enumerate(lens)]
     padded = np.stack([np.r_[np.zeros(width - n, rows[i].dtype), rows[i]] for i, n in enumerate(lens)])
     mask = np.stack([np.arange(width) >= width - n for n in lens])
-    _, state = model.clone(mode="prefill").apply(
-        params, jnp.asarray(padded), padding_mask=jnp.asarray(mask), mutable=["cache"])
-    cache, decode = state["cache"], _decode_step(model.clone(mode="decode"))
+    _, cache = through_the_cache(
+        model.clone(mode="prefill"), params, jnp.asarray(padded), padding_mask=jnp.asarray(mask))
+    decode = _decode_step(model.clone(mode="decode"))
     wants = [ref.logits(np.asarray(ids[i:i + 1]))[0] for i in range(2)]
     for step in range(30):
         tok = jnp.stack([ids[i, n + step] for i, n in enumerate(lens)])[:, None]
@@ -208,10 +212,10 @@ def test_the_selected_sets_past_topk_are_sparse_and_causal(tiny):
 def test_topk_at_or_past_the_context_is_dense_mla(tiny):
     cfg, model, params, ids, _ = tiny
     dense = GlmMoeDsaForCausalLM(dataclasses.replace(cfg, index_topk=96), attention_impl="xla")
-    a, _ = dense.apply(params, ids)
+    a, _ = forward(dense, params, ids)
     ref = Reference(published_keys(dense.config), params)
     np.testing.assert_allclose(np.asarray(a), ref.logits(np.asarray(ids)), atol=ATOL)
-    sparse, _ = model.apply(params, ids)
+    sparse, _ = forward(model, params, ids)
     assert float(jnp.abs(sparse - a)[:, cfg.index_topk:].max()) > 1e-3      # selection changes the result
     np.testing.assert_allclose(np.asarray(sparse[:, :cfg.index_topk]), np.asarray(a[:, :cfg.index_topk]), atol=ATOL)
 
@@ -225,7 +229,7 @@ def test_each_control_of_the_reference_moves_its_logits(tiny, control):
     kw = {"topk": {"topk": cfg.index_topk // 2}, "bias_in_weights": {"bias_in_weights": True},
           "latent_dtype": {"latent_dtype": jnp.float8_e4m3fn}}[control]
     wrong = Reference(published_keys(cfg), params, **kw).logits(np.asarray(ids))
-    logits, _ = model.apply(params, ids)
+    logits, _ = forward(model, params, ids)
     assert float(np.abs(np.asarray(logits) - wrong).max()) > 100 * ATOL
 
 
@@ -243,11 +247,11 @@ def test_cache_holds_the_latent_and_rotated_key_joined_and_one_index_key_a_token
     the next, ONE leaf (what the sparse latent kernel fetches with one
     copy); ``k_idx``: the index key."""
     cfg, model, params, ids, _ = tiny
-    _, state = model.clone(mode="prefill").apply(params, ids[:, :16], mutable=["cache"])
+    _, cache = through_the_cache(model.clone(mode="prefill"), params, ids[:, :16])
     rows, lanes = latent_leaf_shape(cfg.kv_lora_rank, cfg.qk_rope_head_dim)
     assert (rows, lanes) == (2, 32) and latent_leaf_shape(512, 64) == (8, 128)
     for i in range(cfg.num_layers):
-        leaves = state["cache"]["model"][f"layers_{i}"]["attn"]
+        leaves = cache["model"][f"layers_{i}"]["attn"]
         assert set(leaves) == {"kv", "k_idx", "index", "kv_valid"} <= set(PAGED_LEAVES) | {"index", "kv_valid"}
         assert leaves["kv"].shape == (2, cfg.max_seq_len, rows, lanes)
         assert leaves["k_idx"].shape == (2, cfg.max_seq_len, 1, cfg.index_head_dim)

@@ -27,6 +27,7 @@ from neuronx_distributed_tpu.modules.attention import (
 )
 
 from perfbench.references.keye_vl2 import Reference
+from tests.models.jitted import forward, through_the_cache
 
 ATOL = 3e-5
 
@@ -60,8 +61,8 @@ def prefill_logits(model, params, ids, **kw):
     applies its head to the last position alone, so the backbone and the
     head's kernel."""
     backbone = KeyeVL2Model(model.config, model.attention_impl, mode="prefill")
-    (hidden, _), state = backbone.apply({"params": params["params"]["model"]}, ids, mutable=["cache"], **kw)
-    return hidden @ params["params"]["lm_head"]["kernel"], {"model": state["cache"]}
+    (hidden, _), cache = through_the_cache(backbone, {"params": params["params"]["model"]}, ids, **kw)
+    return hidden @ params["params"]["lm_head"]["kernel"], {"model": cache}
 
 
 @pytest.fixture(scope="module")
@@ -71,6 +72,21 @@ def tiny():
     params = _weights(model)
     ids = jax.random.randint(jax.random.PRNGKey(1), (2, 96), 1, cfg.vocab_size)
     return cfg, model, params, ids, Reference(published_keys(cfg), params)
+
+
+@pytest.fixture(scope="module")
+def want(tiny):
+    """The reference's logits of ``ids``, its full forward, once."""
+    *_, ids, ref = tiny
+    return ref.logits(np.asarray(ids))
+
+
+@pytest.fixture(scope="module")
+def prefilled(tiny):
+    """``(the prefill's last logits, its cache)`` of the first 40 tokens."""
+    _, model, params, ids, _ = tiny
+    (last, _), cache = through_the_cache(model.clone(mode="prefill"), params, ids[:, :40])
+    return last, cache
 
 
 def test_the_tiny_preset_holds_every_mechanism():
@@ -105,39 +121,40 @@ def test_published_widths_count_30b_parameters():
 
 def test_full_forward_matches_the_reference_with_selection_at_work(tiny):
     cfg, model, params, ids, ref = tiny
-    logits, _ = model.apply(params, ids)
+    logits, _ = forward(model, params, ids)
     want, router, index = ref.logits_and_margins(np.asarray(ids))
     np.testing.assert_allclose(np.asarray(logits), want, atol=ATOL)
     assert router.shape == index.shape == (2, 96) and np.isinf(index[:, :cfg.index_topk]).all()
     assert np.isfinite(index[:, cfg.index_topk:]).all() and (index >= 0).all()
 
 
-def test_prefill_then_decode_through_the_cache_matches_the_references_full_forward(tiny):
-    """Logits, not tokens: the prompt's at every position, then 56 decode
-    steps against the cache, each row keeping 16 of up to 96 columns."""
-    cfg, model, params, ids, ref = tiny
-    want = ref.logits(np.asarray(ids))
-    prefill, decode = model.clone(mode="prefill"), model.clone(mode="decode")
+def test_prefill_matches_the_references_full_forward(tiny, want, prefilled):
+    """Logits, not tokens: the prompt's at every position, and what a caller
+    of a prefill reads of them."""
+    cfg, model, params, ids, _ = tiny
     logits, _ = prefill_logits(model, params, ids[:, :40])
     np.testing.assert_allclose(np.asarray(logits), want[:, :40], atol=ATOL)
-    (last, _), state = prefill.apply(params, ids[:, :40], mutable=["cache"])
+    last, _ = prefilled
     assert last.shape == (2, 1, cfg.vocab_size)          # all a caller of a prefill reads
     np.testing.assert_allclose(np.asarray(last[:, 0]), want[:, 39], atol=ATOL)
-    cache = state["cache"]
+
+
+def test_decode_through_the_cache_matches_the_references_full_forward(tiny, want, prefilled):
+    """After the prefill of 40 tokens, 56 decode steps against the cache,
+    each row keeping 16 of up to 96 columns: every step's logits."""
+    _, model, params, ids, _ = tiny
+    decode, (_, cache) = model.clone(mode="decode"), prefilled
     for t in range(40, 96):
-        (logits, _), state = decode.apply({**params, "cache": cache}, ids[:, t:t + 1], mutable=["cache"])
-        cache = state["cache"]
+        (logits, _), cache = through_the_cache(decode, {**params, "cache": cache}, ids[:, t:t + 1])
         np.testing.assert_allclose(np.asarray(logits[:, 0]), want[:, t], atol=ATOL)
 
 
-def test_a_many_row_decode_step_is_the_suffix_prefill(tiny):
+def test_a_many_row_decode_step_is_the_suffix_prefill(tiny, want):
     """The decode path with many query rows at once (how the engine resumes a
     context): each row selects for itself."""
     cfg, model, params, ids, ref = tiny
-    want = ref.logits(np.asarray(ids))
-    _, state = model.clone(mode="prefill").apply(params, ids[:, :24], mutable=["cache"])
-    (logits, _), _ = model.clone(mode="decode").apply(
-        {**params, "cache": state["cache"]}, ids[:, 24:96], mutable=["cache"])
+    _, cache = through_the_cache(model.clone(mode="prefill"), params, ids[:, :24])
+    (logits, _), _ = through_the_cache(model.clone(mode="decode"), {**params, "cache": cache}, ids[:, 24:96])
     np.testing.assert_allclose(np.asarray(logits), want[:, 24:], atol=ATOL)
 
 
@@ -173,7 +190,7 @@ def test_exact_ties_resolve_to_the_lower_position(tiny):
     want = np.tril(np.ones((96, 96), bool)) & (np.arange(96)[None] < cfg.index_topk)
     for layer in sets:
         np.testing.assert_array_equal(layer[0], want)
-    logits, _ = model.apply(zeroed, ids)
+    logits, _ = forward(model, zeroed, ids)
     np.testing.assert_allclose(np.asarray(logits), ref.logits(np.asarray(ids)), atol=ATOL)
     assert float(index_scores(jnp.ones((1, 2, 4, 8)), -jnp.ones((1, 2, 4)), -jnp.ones((1, 3, 8)))[0, 0, 0]) == 0.0
 
@@ -182,13 +199,13 @@ def test_topk_at_or_past_the_context_is_dense_gqa(tiny):
     cfg, model, params, ids, _ = tiny
     dense = KeyeVL2ForCausalLM(dataclasses.replace(cfg, index_topk=96), attention_impl="xla")
     wide = KeyeVL2ForCausalLM(dataclasses.replace(cfg, index_topk=4096), attention_impl="xla")
-    a, _ = dense.apply(params, ids)
-    b, _ = wide.apply(params, ids)
+    a, _ = forward(dense, params, ids)
+    b, _ = forward(wide, params, ids)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
     # and it is the reference with its selection turned off
     ref = Reference(published_keys(dense.config), params)
     np.testing.assert_allclose(np.asarray(a), ref.logits(np.asarray(ids)), atol=ATOL)
-    sparse, _ = model.apply(params, ids)
+    sparse, _ = forward(model, params, ids)
     assert float(jnp.abs(sparse - a)[:, cfg.index_topk:].max()) > 1e-3      # selection changes the result
     np.testing.assert_allclose(np.asarray(sparse[:, :cfg.index_topk]), np.asarray(a[:, :cfg.index_topk]), atol=ATOL)
 
@@ -197,10 +214,10 @@ def test_unequal_mrope_streams_match_the_reference(tiny):
     cfg, model, params, ids, ref = tiny
     t = jnp.arange(96)
     pos = jnp.stack([jnp.broadcast_to(p[None], (2, 96)) for p in (t, t // 4, t % 7)])
-    logits, _ = model.apply(params, ids, positions=pos)
+    logits, _ = forward(model, params, ids, positions=pos)
     want = ref.logits(np.asarray(ids), np.asarray(pos))
     np.testing.assert_allclose(np.asarray(logits), want, atol=ATOL)
-    plain, _ = model.apply(params, ids)
+    plain, _ = forward(model, params, ids)
     assert float(jnp.abs(plain - logits).max()) > 1e-2
     # sections [2, 3, 3]: pair 0-1 temporal, 2-4 height, 5-7 width
     ang = np.asarray(mrope_angles(pos, 16, 1e7, (2, 3, 3)))
@@ -221,9 +238,9 @@ def test_cache_holds_k_and_v_joined_and_one_index_key_a_token(tiny):
     """``kv``: a token's K heads, then its V heads, in ONE leaf (what the
     sparse decode kernel fetches with one copy); ``k_idx``: the index key."""
     cfg, model, params, ids, _ = tiny
-    _, state = model.clone(mode="prefill").apply(params, ids[:, :16], mutable=["cache"])
+    _, cache = through_the_cache(model.clone(mode="prefill"), params, ids[:, :16])
     for i in range(cfg.num_layers):
-        leaves = state["cache"]["model"][f"layers_{i}"]["attn"]
+        leaves = cache["model"][f"layers_{i}"]["attn"]
         assert set(leaves) == {"kv", "k_idx", "index", "kv_valid"}
         assert leaves["kv"].shape == (2, cfg.max_seq_len, 2 * 2, 16)
         assert leaves["k_idx"].shape == (2, cfg.max_seq_len, 1, 8)
@@ -243,7 +260,7 @@ def test_masked_flash_prefill_is_the_einsum_prefill(tiny):
 
 def test_loss_and_gradients_are_finite(tiny):
     cfg, model, params, ids, _ = tiny
-    loss, grads = jax.value_and_grad(lambda p: model.loss(p, ids[:, :-1], ids[:, 1:]))(params)
+    loss, grads = jax.jit(jax.value_and_grad(lambda p: model.loss(p, ids[:, :-1], ids[:, 1:])))(params)
     assert np.isfinite(float(loss))
     assert all(np.isfinite(np.asarray(g)).all() for g in jax.tree.leaves(grads))
     assert float(jnp.abs(grads["params"]["model"]["layers_1"]["attn"]["qkv"]["q_proj"]["kernel"]).max()) > 0

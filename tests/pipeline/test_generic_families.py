@@ -20,6 +20,13 @@ B, S, M = 8, 16, 4
 SCHEDULES = ["gpipe", "1f1b", "interleaved"]
 
 
+def _aborts_its_process(schedule):
+    """A 1f1b step at pp=2 x tp=2 on the 8-device mesh: the case runs in a
+    child process (``conftest.in_child_process`` says why), which calls the
+    test again with no ``in_child_process``."""
+    return schedule == "1f1b"
+
+
 def _chunks(schedule):
     return 2 if schedule == "interleaved" else 1
 
@@ -46,7 +53,9 @@ def _run_engine(family, schedule, params, batch_mb):
 
 
 @pytest.mark.parametrize("schedule", SCHEDULES)
-def test_codegen_pipeline_matches_monolith(schedule):
+def test_codegen_pipeline_matches_monolith(schedule, in_child_process):
+    if _aborts_its_process(schedule) and in_child_process:
+        return in_child_process(__file__, "test_codegen_pipeline_matches_monolith", schedule, None)
     from neuronx_distributed_tpu.models.codegen import (
         CodeGenForCausalLM,
         tiny_codegen,
@@ -74,14 +83,25 @@ def test_codegen_pipeline_matches_monolith(schedule):
 
 
 @pytest.mark.parametrize("schedule", SCHEDULES)
-def test_dbrx_pipeline_matches_monolith_no_aux(schedule):
+def test_dbrx_pipeline_matches_monolith_no_aux(schedule, in_child_process):
     """Exact parity with aux coefficients 0 (aux is per-microbatch under PP —
     same contract as pipeline/mixtral.py)."""
+    if _aborts_its_process(schedule) and in_child_process:
+        return in_child_process(__file__, "test_dbrx_pipeline_matches_monolith_no_aux", schedule, None)
     from neuronx_distributed_tpu.models.dbrx import DbrxForCausalLM, tiny_dbrx
     from neuronx_distributed_tpu.pipeline.dbrx import dbrx_family
 
+    # The 1f1b step of THIS family holds three independent collectives in
+    # flight on 8 devices (the experts' all-gather over dp beside tp's
+    # all-reduce and the stages' permute), and XLA:CPU's rendezvous gives up
+    # on it under a loaded host far more often than on the others': 22 of 36
+    # runs aborted with twelve copies side by side, none of 36 on the first
+    # four devices (dp=1), none of 108 of the six other families' cases
+    # (CHANGES.md, PR 46). So 1f1b runs at dp=1 here; gpipe and interleaved
+    # keep dp=2, and test_dbrx_pipeline_aux_losses runs 1f1b over dp=4.
     mesh_lib.initialize_model_parallel(
-        tensor_model_parallel_size=2, pipeline_model_parallel_size=2
+        tensor_model_parallel_size=2, pipeline_model_parallel_size=2,
+        devices=jax.devices()[:4] if _aborts_its_process(schedule) else None,
     )
     cfg = tiny_dbrx(
         num_layers=4, max_seq_len=S,
@@ -147,7 +167,9 @@ def test_dbrx_pipeline_aux_losses():
 
 
 @pytest.mark.parametrize("schedule", SCHEDULES)
-def test_bert_pipeline_matches_monolith(schedule):
+def test_bert_pipeline_matches_monolith(schedule, in_child_process):
+    if _aborts_its_process(schedule) and in_child_process:
+        return in_child_process(__file__, "test_bert_pipeline_matches_monolith", schedule, None)
     from neuronx_distributed_tpu.models.bert import BertForMaskedLM, tiny_bert
     from neuronx_distributed_tpu.pipeline.bert import bert_family
 
@@ -178,7 +200,9 @@ def test_bert_pipeline_matches_monolith(schedule):
 
 
 @pytest.mark.parametrize("schedule", SCHEDULES)
-def test_vit_pipeline_matches_monolith(schedule):
+def test_vit_pipeline_matches_monolith(schedule, in_child_process):
+    if _aborts_its_process(schedule) and in_child_process:
+        return in_child_process(__file__, "test_vit_pipeline_matches_monolith", schedule, None)
     from neuronx_distributed_tpu.models.vit import (
         ViTForImageClassification,
         tiny_vit,

@@ -45,7 +45,9 @@ def _assert_tree_close(got, want, atol):
 
 
 @pytest.mark.parametrize("schedule", ["gpipe", "1f1b"])
-def test_gpt_neox_pipeline_matches_monolith(schedule):
+def test_gpt_neox_pipeline_matches_monolith(schedule, in_child_process):
+    if schedule == "1f1b" and in_child_process:    # pp=2 x tp=2: see conftest.in_child_process
+        return in_child_process(__file__, "test_gpt_neox_pipeline_matches_monolith", schedule, None)
     mesh_lib.initialize_model_parallel(
         tensor_model_parallel_size=2, pipeline_model_parallel_size=2
     )
@@ -72,9 +74,11 @@ def test_gpt_neox_pipeline_matches_monolith(schedule):
 
 
 @pytest.mark.parametrize("schedule", ["gpipe", "1f1b"])
-def test_mixtral_pipeline_matches_monolith_no_aux(schedule):
+def test_mixtral_pipeline_matches_monolith_no_aux(schedule, in_child_process):
     """Exact parity with aux coefficients 0 (aux is per-microbatch under PP,
     see pipeline/mixtral.py docstring)."""
+    if schedule == "1f1b" and in_child_process:    # pp=2 x tp=2: see conftest.in_child_process
+        return in_child_process(__file__, "test_mixtral_pipeline_matches_monolith_no_aux", schedule, None)
     mesh_lib.initialize_model_parallel(
         tensor_model_parallel_size=2, pipeline_model_parallel_size=2
     )

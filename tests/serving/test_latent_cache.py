@@ -1,10 +1,9 @@
-"""The latent (MLA) cache kind on the serving path, tiny, on the CPU: the
-engine's row cache, the ``gather`` transport and the fused paged path (kernel
-interpreted) against the plain reference's FULL forward in logits; what the
-cache leaves hold; prefix sharing, preemption and resume on the latent pool;
-tensor parallelism refused; the other models' programs unchanged."""
+"""What is particular to the latent (MLA) cache kind on the serving path (the
+contract it shares with the other kinds: ``test_cache_kinds.py``): the logit
+check against the plain reference's full forward fails on a lower-precision
+latent; an int8 latent pool; a K/V cache reads its own bytes; the other
+models' programs unchanged."""
 
-import dataclasses
 import hashlib
 import json
 import re
@@ -13,101 +12,21 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from flax.core import meta
 
-from neuronx_distributed_tpu.inference import GenerationConfig
 from neuronx_distributed_tpu.inference.generate import chunked_decode_step, serving_clones
-from neuronx_distributed_tpu.models.deepseek_v2 import (
-    DeepseekV2ForCausalLM,
-    deepseek_v2_lite,
-    tiny_deepseek_v2,
-)
-from neuronx_distributed_tpu.modules.attention import (
-    PAGED_LEAVES,
-    cache_bytes_per_token_layer,
-    ordered_kv_pool_pairs,
-)
+from neuronx_distributed_tpu.models.deepseek_v2 import DeepseekV2ForCausalLM, tiny_deepseek_v2
+from neuronx_distributed_tpu.modules.attention import PAGED_LEAVES, cache_bytes_per_token_layer
 from neuronx_distributed_tpu.quantization import QuantConfig
-from neuronx_distributed_tpu.serving import PagedCacheManager, PrefixCache, ServingEngine
-from perfbench.references import common
-from perfbench.references.deepseek_v2 import Reference
+from neuronx_distributed_tpu.serving import PagedCacheManager
+from tests.models.jitted import through_the_cache
+from tests.serving.test_cache_kinds import TOLERANCE, built, serve
 
 PS = 16
-# float32 model against the float32 reference: the largest gap seen is 3e-6
-# (summation order); 1e-4 is thirty of those and a hundredth of what an int8
-# latent costs (test_the_check_fails_on_a_lower_precision_latent)
-TOLERANCE = 1e-4
-PATHS = {
-    "row": {},
-    "gather": {"kv_page_size": PS, "paged_attention": "gather"},
-    "fused": {"kv_page_size": PS, "paged_attention": "fused"},
-}
-
-
-def _published_keys(cfg):
-    rs = cfg.rope_scaling
-    return {
-        "num_hidden_layers": cfg.num_layers, "first_k_dense_replace": cfg.first_k_dense,
-        "num_attention_heads": cfg.num_heads, "kv_lora_rank": cfg.kv_lora_rank,
-        "qk_nope_head_dim": cfg.qk_nope_head_dim, "qk_rope_head_dim": cfg.qk_rope_head_dim,
-        "v_head_dim": cfg.v_head_dim, "num_experts_per_tok": cfg.top_k,
-        "n_routed_experts": cfg.num_experts, "rms_norm_eps": cfg.rms_eps,
-        "rope_theta": cfg.rope_theta, "routed_scaling_factor": cfg.routed_scaling_factor,
-        "norm_topk_prob": cfg.norm_topk_prob,
-        "rope_scaling": {**dataclasses.asdict(rs), "type": "yarn"},
-    }
 
 
 @pytest.fixture(scope="module")
 def setup():
-    cfg = tiny_deepseek_v2(max_seq_len=256)
-    model = DeepseekV2ForCausalLM(cfg, attention_impl="xla")
-    params = jax.jit(model.init)(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
-    rng = np.random.default_rng(0)
-    prompts = [rng.integers(1, cfg.vocab_size, size=n).astype(np.int32) for n in (20, 37, 9, 50)]
-    return cfg, model, params, prompts, Reference(_published_keys(cfg), meta.unbox(params))
-
-
-def _serve(model, params, prompts, new_tokens=12, **kw):
-    kw.setdefault("num_slots", 2)
-    kw.setdefault("prefix_cache", None)
-    eng = ServingEngine(model, params, decode_chunk_size=4, **kw)
-    gcfg = GenerationConfig(max_new_tokens=new_tokens, temperature=0.0)
-    reqs = [eng.submit(p, gcfg, key=jax.random.PRNGKey(i)) for i, p in enumerate(prompts)]
-    eng.run()
-    return eng, [list(r.tokens) for r in reqs]
-
-
-def _largest_gap(ref, prompts, streams):
-    worst = 0.0
-    for prompt, toks in zip(prompts, streams):
-        gaps, controls, _, _ = common.emitted_token_gaps(ref, prompt, toks, 128)
-        assert controls.min() > 100 * TOLERANCE      # the check is able to fail
-        worst = max(worst, float(gaps.max()))
-    return worst
-
-
-@pytest.fixture(scope="module")
-def streams(setup):
-    _, model, params, prompts, _ = setup
-    return {name: _serve(model, params, prompts, **kw) for name, kw in PATHS.items()}
-
-
-@pytest.mark.parametrize("path", list(PATHS))
-def test_prefill_then_decode_matches_the_references_full_forward(setup, streams, path):
-    """Prefill (materialised) then decode (absorbed) through the cache: every
-    emitted token is the reference's largest logit at its position, within
-    ``TOLERANCE``, the reference never having seen a cache."""
-    *_, prompts, ref = setup
-    eng, toks = streams[path]
-    assert all(len(t) == 12 for t in toks)
-    assert _largest_gap(ref, prompts, toks) <= TOLERANCE
-    want = "paged_latent_fused" if path == "fused" else "einsum"
-    assert eng.programs.resolved["decode_attention"] == want
-
-
-def test_the_three_transports_emit_one_stream(streams):
-    assert streams["row"][1] == streams["gather"][1] == streams["fused"][1]
+    return tuple(built("latent"))
 
 
 def _decode_logits(model, params, ids, split, latent_dtype=None):
@@ -115,16 +34,14 @@ def _decode_logits(model, params, ids, split, latent_dtype=None):
     through the row cache; ``latent_dtype`` rounds the cached latent and
     rotated key to that type first (a lower-precision cache)."""
     prefill, decode = serving_clones(model)
-    _, state = prefill.apply(params, ids[:, :split], mutable=["cache"])
-    cache = state["cache"]
+    _, cache = through_the_cache(prefill, params, ids[:, :split])
     if latent_dtype is not None:
         cache = jax.tree_util.tree_map_with_path(
             lambda path, a: a.astype(latent_dtype).astype(a.dtype) if path[-1].key in PAGED_LEAVES else a,
             cache)
     rows = []
     for t in range(split, ids.shape[1]):
-        (logits, _), state = decode.apply({**params, "cache": cache}, ids[:, t:t + 1], mutable=["cache"])
-        cache = state["cache"]
+        (logits, _), cache = through_the_cache(decode, {**params, "cache": cache}, ids[:, t:t + 1])
         rows.append(logits[:, 0])
     return jnp.stack(rows, axis=1)
 
@@ -148,37 +65,12 @@ def test_an_int8_latent_pool_serves_through_the_gather_transport(setup):
     k/v (a scale sibling each); its stream stays the reference's at these
     margins, which is why the precision check above is made in logits."""
     _, model, params, prompts, ref = setup
-    eng, toks = _serve(model, params, prompts, kv_page_size=PS,
-                       quantize=QuantConfig(weights=None, kv="int8"))
+    eng, toks = serve(model, params, prompts, kv_page_size=PS,
+                      quantize=QuantConfig(weights=None, kv="int8"))
     names = {p[-1].key for p, _ in jax.tree_util.tree_flatten_with_path(eng.cache.cache["pool"])[0]}
     assert {"k", "k_scale", "k_pe", "k_pe_scale"} <= names and "v" not in names
     assert all(len(t) == 12 for t in toks)
     assert cache_bytes_per_token_layer(eng.cache.cache) == (32 + 8) * 1 + 2 * 4 / PS
-
-
-def test_cache_leaves_hold_576_values_a_token_and_nothing_per_head(streams):
-    eng, _ = streams["fused"]
-    pool = eng.cache.cache["pool"]
-    for path, leaf in jax.tree_util.tree_flatten_with_path(pool)[0]:
-        name = path[-1].key
-        if name in PAGED_LEAVES:
-            assert name in ("k", "k_pe") and leaf.shape[-2] == 1, (name, leaf.shape)
-    # tiny widths in float32: (32 + 8) values of 4 bytes, in all three layouts
-    for name in PATHS:
-        assert cache_bytes_per_token_layer(streams[name][0].cache.cache) == (32 + 8) * 4
-        assert streams[name][0].metrics.snapshot()["kv_bytes_per_token_layer"] == (32 + 8) * 4
-    # the published widths in bf16: 576 values, 1152 bytes; K and V per head
-    # would be 16 * (192 + 128) * 2 = 10240
-    model = DeepseekV2ForCausalLM(
-        deepseek_v2_lite(num_layers=2, param_dtype=jnp.bfloat16), attention_impl="xla")
-    ids = jax.ShapeDtypeStruct((1, 32), jnp.int32)
-    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0), ids)
-    row = jax.eval_shape(
-        lambda p, i: model.clone(mode="prefill").apply(p, i, mutable=["cache"])[1]["cache"], shapes, ids)
-    assert cache_bytes_per_token_layer(row) == 1152
-    values = sum(np.prod(a.shape[-2:]) for p, a in jax.tree_util.tree_flatten_with_path(row)[0]
-                 if p[-1].key in PAGED_LEAVES) / 2
-    assert values == 576
 
 
 def test_a_kv_cache_reads_its_own_bytes():
@@ -190,73 +82,6 @@ def test_a_kv_cache_reads_its_own_bytes():
     params = model.init(jax.random.PRNGKey(0), ids)
     _, state = model.clone(mode="prefill").apply(params, ids, mutable=["cache"])
     assert cache_bytes_per_token_layer(state["cache"]) == 2 * 2 * 8 * 4   # k and v, 2 heads of 8, f32
-
-
-def test_fused_chunk_carries_both_latent_leaves(setup):
-    """PR 25's contract on the latent pool: each layer's ``(k, k_pe)`` pair
-    rides the scan's carry, paired with its layer in execution order."""
-    cfg, model, params, _, _ = setup
-    prefill, decode = serving_clones(model)
-    ids = jnp.zeros((1, 16), jnp.int32)
-    row = jax.eval_shape(lambda p, i: prefill.apply(p, i, mutable=["cache"])[1]["cache"], params, ids)
-
-    def pool_of(row):
-        mgr = PagedCacheManager(2, cfg.max_seq_len, PS)
-        mgr.allocate_from(row)
-        return mgr.cache
-
-    paged = jax.eval_shape(pool_of, row)
-    pairs = ordered_kv_pool_pairs(paged["pool"])
-    assert [layer[-2] for layer in pairs] == ["layers_0", "layers_1", "layers_2"]
-    assert all([leaf.shape[-1] for leaf in pair] == [32, 8] for pair in pairs.values())
-    state = jax.eval_shape(ServingEngine(model, params, num_slots=2, kv_page_size=PS)._fresh_slot_state)
-    jaxpr = jax.make_jaxpr(chunked_decode_step(decode, 4, cfg.max_seq_len, page_size=PS,
-                                               paged_attention="fused"))(params, paged, state)
-    scans = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
-    assert len(scans) == 1
-    carried = [v.aval.shape for v in scans[0].invars[scans[0].params["num_consts"]:]]
-    for pair in pairs.values():
-        for leaf in pair:
-            assert leaf.shape in carried
-
-
-def test_prefix_sharing_on_the_latent_pool_is_zero_copy_and_stream_identical(setup):
-    cfg, model, params, _, ref = setup
-    rng = np.random.default_rng(3)
-    system = rng.integers(1, cfg.vocab_size, size=35).astype(np.int32)    # two whole pages
-    prompts = [np.concatenate([system, rng.integers(1, cfg.vocab_size, size=5 + i).astype(np.int32)])
-               for i in range(4)]
-    _, plain = _serve(model, params, prompts, new_tokens=8, kv_page_size=PS)
-    for attention in ("gather", "fused"):
-        eng, shared = _serve(model, params, prompts, new_tokens=8, kv_page_size=PS,
-                             paged_attention=attention, prefix_cache=PrefixCache(min_match=8))
-        assert shared == plain
-        snap = eng.metrics.snapshot()
-        assert snap["prefix_hits"] >= 3 and snap["prefix_pages_shared"] >= 2 * snap["prefix_hits"]
-        assert eng.cache.alloc.copy_bytes == 0
-        eng.cache.check()
-    assert _largest_gap(ref, prompts, plain) <= TOLERANCE
-
-
-def test_preemption_and_resume_on_the_latent_pool_give_the_undisturbed_stream(setup):
-    """A short row: the shared cursor reaches its end, every request is
-    preempted and resumed from its context; the streams are those of an
-    engine that never hit the wall."""
-    cfg, model, params, prompts, _ = setup
-    short = DeepseekV2ForCausalLM(dataclasses.replace(cfg, max_seq_len=64), attention_impl="xla")
-    picks = [prompts[0][:12], prompts[1][:17], prompts[2]]
-    _, want = _serve(model, params, picks, new_tokens=24, num_slots=3)
-    eng, got = _serve(short, params, picks, new_tokens=24, num_slots=2, kv_page_size=PS,
-                      admission="eager")
-    assert eng.metrics.snapshot()["preemptions"] > 0
-    assert got == want
-    eng.cache.check()
-
-
-def test_tensor_parallel_serving_refuses_a_latent_cache_model(setup):
-    _, model, params, _, _ = setup
-    with pytest.raises(ValueError, match="latent-cache"):
-        ServingEngine(model, params, num_slots=2, tp=2)
 
 
 # --- the other models' programs ------------------------------------------------------

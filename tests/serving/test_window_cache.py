@@ -3,7 +3,9 @@ a block table and a pool a layer kind): Trinity's tiny model through
 ``ServingEngine.submit`` / ``step`` against the plain reference's full
 forward, in LOGITS, for more than three windows with pages freed on the way;
 the allocator's invariants over both tables; preempt-and-rewind and a drain;
-and each thing a windowed model cannot have yet, refused by name."""
+a disaggregated handoff refused. (The contract every cache kind holds, this
+``joined`` kind among them, and what a windowed model cannot have yet, refused
+by name at construction: ``test_cache_kinds.py``.)"""
 
 import jax
 import jax.numpy as jnp
@@ -186,24 +188,6 @@ def test_a_model_with_one_kind_of_layer_builds_todays_tree():
     assert engine.cache.window is None and engine.cache.alloc_w is None
     assert engine._window_stats() == {} and engine.prefix is not None
     engine.run()
-
-
-@pytest.mark.parametrize("what, kwargs", [
-    ("prefix_cache", {"prefix_cache": 4}),
-    ("kv_host_pages", {"kv_host_pages": 8}),
-    ("draft_model", {"draft": True}),
-    ("quantize.kv", {"quantize_kv": True}),
-])
-def test_what_a_windowed_model_cannot_have_yet_is_refused_at_construction(system, what, kwargs):
-    _, model, params, _ = system
-    if kwargs.pop("draft", False):
-        kwargs.update(draft_model=model, draft_params=params)
-    if kwargs.pop("quantize_kv", False):
-        from neuronx_distributed_tpu.quantization import QuantConfig
-
-        kwargs["quantize"] = QuantConfig(weights=None, kv="int8")
-    with pytest.raises(WindowedCacheUnsupported, match=what.replace(".", r"\.")):
-        ServingEngine(model, params, num_slots=2, kv_page_size=PAGE, **kwargs)
 
 
 def test_tensor_parallel_and_a_disaggregated_handoff_are_refused(system):
