@@ -1954,6 +1954,183 @@ def trinity_phase(size: TrinitySize, seed: int) -> Dict[str, bool]:
     }
 
 
+@dataclasses.dataclass(frozen=True)
+class ZayaSize:
+    """What ``--only zaya`` runs (defaults: the chip run, the published widths
+    on the benchmark configuration's cut)."""
+
+    model: object = None          # the configuration's ``model`` group; None = the benchmark configuration's
+    max_seq_len: int = 16384
+    slots: int = 4
+    page: int = 16
+    prompt_lens: Tuple[int, ...] = (3000, 1100, 300)
+    new_tokens: int = 32
+    # ONE block alone, which the layers after it cannot blur: the median
+    # position's |system - reference| / |reference| (L2 over the vector) of the
+    # stream after layer 0 and after layer 1 and of layer 1's router state,
+    # layer 1 on the SYSTEM's own input; each limit between the system's
+    # reading and a control's (PERF.md section 6, PR 47, has the readings)
+    block_tol: float = 0.02
+    state_tol: float = 0.02
+    # the decoded tokens' gap and the router near-tie that excuses one: the
+    # benchmark configuration's (its ``reference_check.why`` has the readings)
+    gap_tol: float = 0.1
+    near_tie: float = 0.002
+
+
+def zaya_phase(size: ZayaSize, seed: int) -> Dict[str, bool]:
+    """ZAYA1-8B alone: attention in a compressed latent whose convolutions and
+    value shift read a per-slot state beside the paged cache, and a router with
+    a state of its own through the layers, against the plain reference, each
+    mechanism held on one block against its control. Not part of the default
+    run. The language model at its published widths on the benchmark
+    configuration's cut (``perfbench/configs/zaya1-8b-serve.json``) through a
+    ``ServingEngine`` whose paged cache holds 1,024 B a token a layer and whose
+    slots each keep 5,376 B of state a layer: prompts of 3,000, 1,100 and 300
+    tokens are prefilled, admitted shortest first and an engine step apart so
+    that each longer prompt's admission jumps the shared cursor over the slots
+    already decoding (their next token then lies columns away from its
+    predecessor), and 32 tokens decoded through the state and the kernel that
+    walks the blocks a slot maps. Against ``perfbench/references/zaya.py``
+    (no cache, no state): the reference's logit of every decoded token; then
+    layers 0 and 1 each alone, every limit between the system's reading and a
+    control's: the stream after layer 0 (| no convolution | the second value
+    head unshifted | the whole reference in float8), layer 1's router state on
+    the system's own input (| no state mixed in)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from flax.core import meta
+
+    from neuronx_distributed_tpu.inference import GenerationConfig
+    from neuronx_distributed_tpu.models.zaya import ZayaModel
+    from neuronx_distributed_tpu.modules.attention import slot_state_bytes_per_layer
+    from neuronx_distributed_tpu.parallel import mesh as mesh_lib
+    from neuronx_distributed_tpu.serving import ServingEngine
+    from perfbench import cca_costs
+    from perfbench.families import zaya as family
+    from perfbench.references import common
+    from perfbench.references.zaya import Reference
+
+    mesh_lib.destroy_model_parallel()
+    published = _published(size, "zaya1-8b-serve.json")
+    model = family.build(published, runner="serve", max_seq_len=size.max_seq_len)
+    if size.model is not None:    # the CPU rehearsal serves in float32
+        model = model.clone(config=dataclasses.replace(model.config, dtype=jnp.float32))
+    cfg = model.config
+    params = jax.jit(model.init)(jax.random.PRNGKey(seed), jnp.zeros((1, 8), jnp.int32))
+    jax.block_until_ready(params)
+    plain = meta.unbox(params)
+    ref = Reference(published, plain)
+    engine = ServingEngine(model, params, num_slots=size.slots, kv_page_size=size.page)
+    prompts = _prompts(size.prompt_lens, int(published["vocab_size"]), seed + 7)
+
+    # shortest first and an engine step apart: each longer prompt's admission
+    # jumps the shared cursor and leaves gap columns between a decoding slot's
+    # next token and its predecessor
+    gcfg = GenerationConfig(max_new_tokens=size.new_tokens, temperature=0.0)
+    t0, reqs, jumps = time.perf_counter(), [], 0
+    for i, prompt in enumerate(prompts[::-1]):
+        before = engine.cache.cursor
+        reqs.append(engine.submit(prompt, gcfg, key=jax.random.PRNGKey(seed + i)))
+        engine.step()
+        # past what the step's own chunk writes, under a slot already decoding
+        jumps += bool(i and engine.cache.cursor - before > engine.decode_chunk_size)
+    engine.run()
+    wall = time.perf_counter() - t0
+    if any(len(r.tokens) != size.new_tokens for r in reqs):
+        raise RuntimeError(f"zaya: tokens {[len(r.tokens) for r in reqs]} of {size.new_tokens} (halt {engine.halt_reason!r})")
+    reqs = reqs[::-1]
+    resolved = dict(engine.programs.resolved)
+    kernels = _ledger_kernels(engine.programs, _hot_programs(engine))
+    per_token = engine.metrics.snapshot()["kv_bytes_per_token_layer"]
+    state_bytes = slot_state_bytes_per_layer(engine.cache.cache)
+    log(f"zaya: {len(reqs)} requests, prompts {list(size.prompt_lens)} + {size.new_tokens} tokens in {wall:.1f}s; "
+        f"resolved {resolved}; {KERNEL} in compiled programs: {kernels}; cache {per_token:g} B a token a layer, "
+        f"state {state_bytes:g} B a slot a layer, pool {engine.cache.nbytes / 2**30:.2f} GiB; {jumps} cursor jumps "
+        f"left gap columns in a held context")
+    engine.cache.check()
+    leak_free = engine.cache.alloc.free_pages == engine.cache.alloc.num_pages - 1
+    engine = None
+    gc.collect()
+
+    ok, worst_gap = True, 0.0
+    for prompt, req in zip(prompts, reqs):
+        pad_to = -(-(len(prompt) + len(req.tokens)) // 128) * 128
+        gaps, wrong, _, margins = common.emitted_token_gaps(ref, prompt, req.tokens, pad_to)
+        fine, over, excused = common.judge_gaps(gaps, margins, size.gap_tol, size.near_tie)
+        ok = ok and fine and wrong.min() > size.gap_tol
+        worst_gap = max(worst_gap, float(gaps[margins >= size.near_tie].max(initial=0.0)))
+        log(f"zaya: prompt {len(prompt)}: decoded tokens' largest reference-logit gap {gaps.max():.4f} ({over} of "
+            f"{len(gaps)} fail {size.gap_tol:g}, {excused} excused by a router margin under {size.near_tie:g}; wrong "
+            f"tokens' smallest gap {wrong.min():.3f})")
+
+    backbone = ZayaModel(cfg, model.attention_impl, mode="prefill")
+
+    @jax.jit
+    def two_layers(params, ids):
+        """From the SYSTEM's prefill: ``(stream, router state)`` after layer 0 and after layer 1."""
+        _, state = backbone.apply(
+            {"params": params["params"]["model"]}, ids, mutable=["cache", "intermediates"],
+            capture_intermediates=lambda mdl, _: mdl.name in ("layers_0", "layers_1"))
+        got = state["intermediates"]
+        return tuple(got[f"layers_{i}"]["__call__"][0][:2] for i in (0, 1))
+
+    def rel(mine, theirs):
+        """Median over positions of |mine - theirs| / |theirs| (L2 over the last axis)."""
+        mine, theirs = (np.asarray(a, np.float32)[0] for a in (mine, theirs))
+        return float(np.median(np.linalg.norm(mine - theirs, axis=-1) / np.linalg.norm(theirs, axis=-1)))
+
+    prompt = prompts[0]
+    p = len(prompt)
+    tiled = np.pad(prompt, (0, -p % 512))[None]          # whole tiles, as the engine's buckets are
+    (x0_sys, r0_sys), (x1_sys, r1_sys) = (tuple(a[:, :p] for a in pair) for pair in two_layers(params, tiled))
+    emb = ref.embed(prompt[None])
+    x0_ref = ref.block(0, emb)[0]
+    x1_ref, r1_ref = ref.block(1, x0_sys, r0_sys)[:2]
+    readings = {"layer 0's stream": (rel(x0_sys, x0_ref), size.block_tol),
+                "layer 1's stream on the system's own input": (rel(x1_sys, x1_ref), size.block_tol),
+                "layer 1's router state on the system's own input": (rel(r1_sys, r1_ref), size.state_tol)}
+    blocks_ok = all(reading <= limit for reading, limit in readings.values())
+    log(f"zaya: prompt {p}, one block alone, median |system - reference| / |reference|: "
+        + "; ".join(f"{name} {reading:.5f} (limit {limit:g})" for name, (reading, limit) in readings.items()))
+    controls = (
+        ("no convolution", dict(conv="none"), 0), ("the second value head unshifted", dict(value_shift=False), 0),
+        ("the whole reference in float8", dict(dtype=jnp.float8_e4m3fn), 0), ("no state mixed in", dict(eda=False), 1),
+    )
+    caught = {}
+    for name, kw, layer in controls:
+        other = Reference(published, plain, **kw)
+        if layer == 0:
+            reading, limit, what = rel(other.block(0, emb)[0], x0_ref), size.block_tol, "layer 0's stream"
+        else:
+            reading, limit, what = rel(other.block(1, x0_sys, r0_sys)[1], r1_ref), size.state_tol, "layer 1's router state"
+        caught[name] = reading > limit
+        log(f"zaya: control, the reference with {name} against the plain reference, {what}: {reading:.5f} "
+            f"(limit {limit:g}): {'outside' if caught[name] else 'INSIDE'} the limit")
+    log(f"zaya: largest decoded-token gap outside router near-ties {worst_gap:.4f} ({size.gap_tol:g})")
+    widths = dict(num_q_heads=cfg.num_heads, num_kv_heads=cfg.num_kv_heads, head_dim=cfg.head_dim)
+    itemsize = jnp.dtype(cfg.dtype).itemsize
+    return {
+        "zaya_matches_reference": ok,
+        "zaya_two_blocks_alone_match_reference": blocks_ok,
+        # every longer prompt's admission jumped the cursor over a decoding slot:
+        # the tokens compared above were decoded columns away from their predecessors
+        "zaya_cursor_jumps_leave_gap_columns": jumps >= len(prompts) - 1,
+        "zaya_leaks_no_page": leak_free,
+        "zaya_resolved_paged_walk_fused": {k: resolved[k] for k in ("attention", "decode_attention", "paged_attention")} == {
+            "attention": "flash", "decode_attention": "paged_walk_fused", "paged_attention": "fused"},
+        "zaya_cache_is_a_kib_a_token_and_the_state_five_and_a_quarter_a_slot": (
+            per_token == 2 * cfg.num_kv_heads * cfg.head_dim * itemsize
+            and state_bytes == cca_costs.slot_state_bytes(**widths, act_bytes=itemsize)),
+        "zaya_no_convolution_is_caught": caught["no convolution"],
+        "zaya_unshifted_value_head_is_caught": caught["the second value head unshifted"],
+        "zaya_float8_is_caught": caught["the whole reference in float8"],
+        "zaya_no_router_state_is_caught": caught["no state mixed in"],
+        "kernel_zaya_programs": all(kernels.values()) and bool(kernels),
+    }
+
+
 def _walk_listing_compile(size: WalkSize = WalkSize()) -> None:
     """Compile the walking kernel at ``size`` for a described v5e. Run in a
     process of its own with ``LIBTPU_INIT_ARGS`` naming the dump directory
@@ -2363,6 +2540,7 @@ PHASES: Dict[str, Phase] = {
     "glm": Phase(glm_phase, GlmSize(), default=True, only=True),
     "moe": Phase(moe_phase, MoeSize(), only=True),
     "trinity": Phase(trinity_phase, TrinitySize(), only=True),
+    "zaya": Phase(zaya_phase, ZayaSize(), only=True),
     "walk": Phase(walk_phase, WalkSize(), only=True),
     "flash": Phase(flash_phase, FlashSize(), only=True),
     "tp_train": Phase(tp_train_phase, TrainSize(), devices=True, chips=4, default=True),

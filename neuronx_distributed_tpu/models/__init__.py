@@ -67,6 +67,13 @@ from neuronx_distributed_tpu.models.keye_vl2 import (
     keye_vl2_30b_a3b,
     tiny_keye_vl2,
 )
+from neuronx_distributed_tpu.models.zaya import (
+    ZayaConfig,
+    ZayaForCausalLM,
+    ZayaModel,
+    tiny_zaya,
+    zaya1_8b,
+)
 from neuronx_distributed_tpu.models.vit import (
     ViTConfig,
     ViTForImageClassification,
@@ -91,4 +98,5 @@ __all__ = [
     "KeyeVL2Config", "KeyeVL2ForCausalLM", "KeyeVL2Model",
     "keye_vl2_30b_a3b", "tiny_keye_vl2",
     "AfmoeConfig", "AfmoeForCausalLM", "AfmoeModel", "trinity_large", "tiny_afmoe",
+    "ZayaConfig", "ZayaForCausalLM", "ZayaModel", "zaya1_8b", "tiny_zaya",
 ]

@@ -196,6 +196,14 @@ DSA_WRITE_SCOPE = "dsa.write"
 ATTN_WINDOW_SCOPE = "attn.window"
 ATTN_FULL_SCOPE = "attn.full"
 ATTN_GATE_SCOPE = "attn.gate"
+# Attention in a compressed latent whose q/k pass two short causal
+# convolutions (``models/zaya.py``): the whole block; inside it the
+# projections, the convolutions with the per-slot state's read and write
+# (everything the architecture adds around the kernel), and the attend.
+ATTN_CCA_SCOPE = "attn.cca"
+ATTN_CCA_PROJECT_SCOPE = "attn.cca.project"
+ATTN_CCA_CONV_SCOPE = "attn.cca.conv"
+ATTN_CCA_ATTEND_SCOPE = "attn.cca.attend"
 
 
 def prefill_positions(padding_mask: jax.Array) -> jax.Array:
@@ -466,15 +474,26 @@ class JoinedKVCache(KVCache):
     (:data:`WINDOW_LEAF`). The paged cache manager gives the window kind a
     block table and a pool of its own and frees a window layer's pages as
     the cursor passes them (``serving/paging.py``). The writes take ``(k,
-    v)``; :func:`split_kv` takes the leaf apart."""
+    v)``; :func:`split_kv` takes the leaf apart.
 
-    def __init__(self, module, b, max_seq_len, hkv, d, dtype, window=None):
+    ``state_width``: the layer also keeps ``state`` (B, state_width), what its
+    NEXT token needs of the request's last one and the cache does not hold
+    (:data:`SLOT_STATE_LEAVES`: a slot axis and no length axis). A prefill
+    leaves the state of each row's last valid token, a decode step reads and
+    replaces it."""
+
+    def __init__(self, module, b, max_seq_len, hkv, d, dtype, window=None,
+                 state_width: Optional[int] = None):
         super().__init__(
             module, b, max_seq_len, hkv, d, dtype, leaves={"kv": (2 * hkv, d)},
         )
         self.window = window
         if window is not None:
             module.variable("cache", WINDOW_LEAF, jnp.zeros, (0, int(window)), jnp.bool_)
+        self.state = None
+        if state_width is not None:
+            self.state = module.variable(
+                "cache", SLOT_STATE_LEAVES[0], jnp.zeros, (b, int(state_width)), dtype)
 
     def prefill_write(self, k, v, padding_mask=None):
         self._prefill_write((jnp.concatenate([k, v], axis=2),), padding_mask)
@@ -552,6 +571,15 @@ def window_keep(kv_valid, q_pos, window: Optional[int]):
 # handed around in ITS order: (k, v), a latent cache's (k, k_pe), an indexed
 # cache's (kv, k_idx).
 PAGED_LEAVES = ("k", "v", "k_pe", "kv", "k_idx")
+# THE names of the per-slot STATE leaves, (..., B, width): a slot axis and NO
+# length axis: what a layer's next token needs of the request's last one and
+# no cache column holds (``JoinedKVCache(state_width=)``). Slot-shaped like
+# ``kv_valid``, so the paged transports hand them through as they are; an
+# admission copies the prefill row's into the slot (no roll: there is no
+# column), a freed slot's is left for the next admission to overwrite, and
+# nothing that holds a context by its pages or columns alone (a prefix block,
+# a staged or spilled context) has one: those are refused for the kind.
+SLOT_STATE_LEAVES = ("state",)
 
 
 def cache_leaf_name(path) -> str:
@@ -566,9 +594,43 @@ def cache_batch_axis(name: str, ndim: int):
     batch axis right, so classify from the TRAILING dims."""
     if name in PAGED_LEAVES:
         return ndim - 4
-    if name == "kv_valid":
+    if name == "kv_valid" or name in SLOT_STATE_LEAVES:
         return ndim - 2
     return None
+
+
+def cache_length_axis(name: str, ndim: int):
+    """Column (cache-length) axis of a cache leaf, right after its batch
+    axis, or None for a leaf without one: the ``index`` cursor and the
+    per-slot state leaves (:data:`SLOT_STATE_LEAVES`)."""
+    if name in PAGED_LEAVES or name == "kv_valid":
+        return cache_batch_axis(name, ndim) + 1
+    return None
+
+
+def refuse_slot_state(name: str, what: str) -> None:
+    """A walker that moves a context by its COLUMNS alone met a per-slot
+    state leaf: the columns do not hold what the next token needs."""
+    if name in SLOT_STATE_LEAVES:
+        raise ValueError(
+            f"{what} is not available for a cache with per-slot state "
+            f"(leaf {name!r}): the state at a context's end is in no column")
+
+
+def slot_state_bytes_per_layer(cache) -> float:
+    """Bytes ONE slot's state leaves (:data:`SLOT_STATE_LEAVES`) hold in ONE
+    layer of a cache tree (a row collection or a paged pytree), averaged over
+    the layers that have them; 0 for a cache without."""
+    import math
+
+    tree = cache["pool"] if isinstance(cache, dict) and "pool" in cache else cache
+    total, layers = 0.0, 0
+    for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
+        if cache_leaf_name(path) in SLOT_STATE_LEAVES:
+            lead = math.prod(leaf.shape[:-2])
+            total += lead * leaf.shape[-1] * leaf.dtype.itemsize
+            layers += lead
+    return total / layers if layers else 0.0
 
 
 def reset_cache_slot(cache, slot):
@@ -669,6 +731,7 @@ def extract_cache_prefix(cache, start, m, bucket: int):
 
     def fn(path, leaf):
         name = cache_leaf_name(path)
+        refuse_slot_state(name, "a prefix block copied out of a row")
         ax = cache_batch_axis(name, leaf.ndim)
         if ax is None:  # index cursor → the prefix token count
             return jnp.full_like(leaf, m)
@@ -697,6 +760,7 @@ def seed_cache_prefix(prefix, m, start, length: int):
 
     def fn(path, leaf):
         name = cache_leaf_name(path)
+        refuse_slot_state(name, "a row seeded from a prefix block")
         ax = cache_batch_axis(name, leaf.ndim)
         if ax is None:
             return jnp.full_like(leaf, start + m)

@@ -58,7 +58,12 @@ class MoE(nn.Module):
     hidden_size: int
     intermediate_size: int
     top_k: int = 2
-    router_kind: str = "top_k"  # top_k | sinkhorn
+    router_kind: str = "top_k"  # top_k | sinkhorn | mlp
+    # ``mlp`` (``routing.RouterMLP``): the width of the router's own state,
+    # which the caller passes from layer to layer (``router_state``), and the
+    # eps of its RMSNorm
+    router_state_size: int = 256
+    router_eps: float = 1e-5
     router_act_fn: str = "softmax"
     router_jitter_eps: float = 0.0
     hidden_act: str = "silu"
@@ -96,8 +101,13 @@ class MoE(nn.Module):
 
     @nn.compact
     def __call__(
-        self, x: jax.Array, deterministic: bool = True
+        self, x: jax.Array, deterministic: bool = True,
+        router_state: Optional[jax.Array] = None,
     ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+        """``router_state`` (B, S, ``router_state_size``): the previous
+        layer's router state for a router that keeps one (``router_kind=
+        "mlp"``; ``None``: the first such layer); this layer's comes back as
+        ``aux["router_state"]``."""
         B, S, H = x.shape
         if self.sequence_parallel_enabled:
             # exit SP: routing needs the full sequence per data shard
@@ -112,6 +122,8 @@ class MoE(nn.Module):
         router_options = {}
         if not self.normalize_top_k_affinities:
             router_options["normalize_top_k_affinities"] = False
+        if self.router_kind == "mlp":
+            router_options.update(state_size=self.router_state_size, eps=self.router_eps)
         if self.router_selection_bias:
             router_options.update(
                 selection_bias=True,
@@ -132,8 +144,19 @@ class MoE(nn.Module):
         )
         # named scopes (with the modules' own: this block is ``moe``) so a
         # device trace can be cut into router / dispatch / experts / combine
+        stateful = self.router_kind == "mlp"
+        if router_state is not None and (not stateful or perm is not None):
+            raise ValueError(
+                "router_state is the mlp router's, on tokens in their order: "
+                f"router_kind {self.router_kind!r}, token_shuffle {perm is not None}")
         with jax.named_scope("moe.router"):
-            route = router(tokens, deterministic=deterministic)
+            if stateful:
+                route, new_state = router(
+                    tokens,
+                    None if router_state is None else router_state.reshape(B * S, -1),
+                    deterministic=deterministic)
+            else:
+                route = router(tokens, deterministic=deterministic)
 
         top_w = route.top_w
         if self.routed_scaling_factor != 1.0:
@@ -189,4 +212,6 @@ class MoE(nn.Module):
             ),
             "router_z_loss": router_z_loss_func(route.logits),
         }
+        if stateful:
+            aux["router_state"] = new_state.reshape(B, S, -1)
         return out, aux

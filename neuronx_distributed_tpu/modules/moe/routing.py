@@ -123,7 +123,9 @@ class RouterTopK(RouterBase):
 
     @nn.compact
     def __call__(self, x: jax.Array, deterministic: bool = True) -> RouterOutput:
-        logits = self._logits(x, deterministic)
+        return self._select(self._logits(x, deterministic))
+
+    def _select(self, logits: jax.Array) -> RouterOutput:
         probs = self._activate(logits)
         if self.selection_bias:
             bias = self.param(
@@ -182,6 +184,68 @@ class RouterSinkhorn(RouterBase):
         return RouterOutput(logits, probs, top_e.astype(jnp.int32), top_w)
 
 
+def zero_sum_lecun_normal(key, shape, dtype=jnp.float32):
+    """``lecun_normal`` with every output's weights summing to zero over its
+    inputs: what an input's common (token-independent) mean adds to an output
+    is then nothing. A router logit fed by gelus otherwise starts with an
+    offset of its own as large as half its spread over tokens, and a top-1
+    choice among 16 experts hits 10 of them with 32 rows where an even one
+    hits 14; which 10 changes with the key."""
+    w = nn.initializers.lecun_normal()(key, shape, jnp.float32)
+    return (w - w.mean(axis=0, keepdims=True)).astype(dtype)
+
+
+class RouterMLP(RouterTopK):
+    """A router that is an MLP over a narrow STATE of its own, which passes
+    from layer to layer beside the residual stream (Zyphra's ZAYA1,
+    ``models/zaya.py``): ``s = W_d x + b_d`` (hidden -> ``state_size``); given
+    the previous layer's state ``r``: ``s <- s + gamma * r`` (``gamma`` a
+    learned vector, absent where no state comes in: the first layer); the new
+    state is ``s``. Logits ``W3 gelu(W2 gelu(W1 RMSNorm(s) + c1) + c2)``
+    (``state_size`` -> ``state_size`` -> ``state_size`` -> E, the gelu exact;
+    ``W2`` and ``W3`` start with zero column sums, :func:`zero_sum_lecun_normal`).
+    Activation, selection bias and top-k as :class:`RouterTopK`. All of it in
+    float32 at matmul precision ``highest`` (the matrices are small; the
+    choice is one of 16 through four matmuls). Called with ``(x, state)``;
+    returns ``(RouterOutput, new state)``."""
+
+    state_size: int = 256
+    eps: float = 1e-5
+
+    @nn.compact
+    def __call__(self, x: jax.Array, state: Optional[jax.Array] = None,
+                 deterministic: bool = True):
+        r = self.state_size
+
+        def dense(name, y, n_in, n_out, bias=True, init=nn.initializers.lecun_normal()):
+            w = self.param(f"{name}_weight", nn.with_partitioning(
+                init, (None, None)), (n_in, n_out), self.param_dtype)
+            # float32 in fact: on a TPU a float32 matmul at the default precision
+            # rounds its operands to bf16, and four chained ones move a top-1
+            # choice that a linear router's single matmul leaves alone
+            y = jnp.matmul(y, jnp.asarray(w, jnp.float32), precision=jax.lax.Precision.HIGHEST)
+            if bias:
+                y = y + jnp.asarray(self.param(f"{name}_bias", nn.with_partitioning(
+                    nn.initializers.zeros_init(), (None,)), (n_out,), self.param_dtype), jnp.float32)
+            return y
+
+        s = dense("down", jnp.asarray(x, jnp.float32), self.hidden_size, r)
+        if state is not None:
+            gamma = self.param("state_mix", nn.with_partitioning(
+                nn.initializers.ones_init(), (None,)), (r,), self.param_dtype)
+            s = s + jnp.asarray(gamma, jnp.float32) * jnp.asarray(state, jnp.float32)
+        norm = self.param("norm_weight", nn.with_partitioning(
+            nn.initializers.ones_init(), (None,)), (r,), self.param_dtype)
+        y = s * jax.lax.rsqrt(jnp.mean(jnp.square(s), axis=-1, keepdims=True) + self.eps)
+        y = y * jnp.asarray(norm, jnp.float32)
+        y = jax.nn.gelu(dense("fc1", y, r, r), approximate=False)
+        # a gelu's output has a mean of its own; weights that sum to zero over
+        # it give no unit, and no expert, a constant head start at the start
+        y = jax.nn.gelu(dense("fc2", y, r, r, init=zero_sum_lecun_normal), approximate=False)
+        logits = dense("fc3", y, r, self.num_experts, bias=False, init=zero_sum_lecun_normal)
+        return self._select(logits), s
+
+
 def make_router(
     kind: str,
     hidden_size: int,
@@ -190,7 +254,7 @@ def make_router(
     name: Optional[str] = None,
     **kw,
 ):
-    cls = {"top_k": RouterTopK, "sinkhorn": RouterSinkhorn}[kind]
+    cls = {"top_k": RouterTopK, "sinkhorn": RouterSinkhorn, "mlp": RouterMLP}[kind]
     return cls(
         hidden_size=hidden_size, num_experts=num_experts, top_k=top_k, name=name, **kw
     )

@@ -46,6 +46,7 @@ import jax.numpy as jnp
 
 from neuronx_distributed_tpu.modules.attention import (
     cache_batch_axis,
+    cache_length_axis,
     cache_leaf_name,
     reset_cache,
     reset_cache_slot,
@@ -67,8 +68,10 @@ def _admit_row(big, row, slot, padded_len, cursor):
         if ax is None:  # shared write cursor
             return jnp.full_like(b_leaf, cursor)
         # k/v (..., B, L, Hkv, D) and kv_valid (..., B, L): the cache-length
-        # axis sits right after the batch axis in both layouts
-        r = jnp.roll(r_leaf, shift, axis=ax + 1)
+        # axis sits right after the batch axis in both layouts; a per-slot
+        # state leaf (..., B, W) has none and is copied as it is
+        col = cache_length_axis(name, b_leaf.ndim)
+        r = r_leaf if col is None else jnp.roll(r_leaf, shift, axis=col)
         return jax.lax.dynamic_update_slice_in_dim(b_leaf, r, slot, axis=ax)
 
     return jax.tree_util.tree_map_with_path(fn, big, row)

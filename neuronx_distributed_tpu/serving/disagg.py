@@ -155,15 +155,17 @@ class DisaggregatedServer:
                 "disaggregation does not speak speculative engines yet "
                 "(the draft cache would need its own handoff)"
             )
-        if getattr(engine.cache, "window", None) is not None:
+        if getattr(engine.cache, "window", None) is not None or getattr(
+                getattr(engine.model, "config", None), "kv_cache_slot_state", False):
             from neuronx_distributed_tpu.serving.paging import (
-                WindowedCacheUnsupported,
+                CacheKindUnsupported,
             )
 
-            raise WindowedCacheUnsupported(
+            raise CacheKindUnsupported(
                 "disaggregation hands a context over by its pages, and a "
                 "model with window layers frees a window layer's pages "
-                "behind the window: serve it coupled"
+                "behind the window, a model with per-slot state keeps what "
+                "the next token needs in no page: serve it coupled"
             )
         if n_workers < 1:
             raise ValueError(f"n_workers must be >= 1, got {n_workers}")

@@ -252,6 +252,7 @@ from neuronx_distributed_tpu.modules.attention import (
     extract_cache_prefix,
     resolve_decode_impl,
     seed_cache_prefix,
+    slot_state_bytes_per_layer,
 )
 from neuronx_distributed_tpu.observability.flight_recorder import FlightRecorder
 from neuronx_distributed_tpu.observability.hbm import HBMLedger, tree_nbytes
@@ -642,9 +643,15 @@ class ServingEngine:
         kv_window = getattr(
             getattr(model, "config", None), "kv_cache_window", None
         )
-        if kv_window is not None and kv_page_size is not None:
+        # per-slot state beside the pages (modules/attention.py
+        # SLOT_STATE_LEAVES): what the next token needs of the last one is in
+        # no page, so the same holders lack it at a context's end
+        kv_slot_state = bool(getattr(
+            getattr(model, "config", None), "kv_cache_slot_state", False
+        ))
+        if (kv_window is not None and kv_page_size is not None) or kv_slot_state:
             from neuronx_distributed_tpu.serving.paging import (
-                WindowedCacheUnsupported,
+                CacheKindUnsupported,
             )
 
             asked = {
@@ -654,13 +661,20 @@ class ServingEngine:
                 "draft_model": draft_model is not None,
                 "quantize.kv": quantize is not None and quantize.kv is not None,
             }
+            why = (
+                f"a model with window layers (window {kv_window}): their "
+                "pages behind the window are freed, so no context can be "
+                "held, shared, spilled or drafted by its pages yet"
+                if kv_window is not None else
+                "a model whose layers keep per-slot state beside their "
+                "pages: the state at a context's end is in no page, so no "
+                "context can be held, shared, spilled or drafted by its "
+                "pages yet"
+            )
             for what, on in asked.items():
                 if on:
-                    raise WindowedCacheUnsupported(
-                        f"{what} is not available for a model with window "
-                        f"layers (window {kv_window}): their pages behind "
-                        "the window are freed, so no context can be held, "
-                        "shared, spilled or drafted by its pages yet"
+                    raise CacheKindUnsupported(
+                        f"{what} is not available for {why}"
                     )
             prefix_cache = None   # "auto": on wherever the cache can have one
         self.tp = tp
@@ -779,6 +793,7 @@ class ServingEngine:
             prefix_cache.on_evict = self._on_prefix_evict
         self._prefix_reuses = 0  # reuse-attempt index (poison-hook schedule)
         self._window_pages_freed_seen = 0
+        self._slot_state_bytes = None   # the dispatch span's stat, read once
         self._steps_seen = 0  # step() index (flip_bits("params") schedule)
         self._prefill_model, self._decode_model = serving_clones(model)
         # scheduling policy (ISSUE 16): "fifo" (default — bit-identical to
@@ -2454,6 +2469,18 @@ class ServingEngine:
             "window_tokens": int(sum(min(n, int(window)) for n in held)),
         }
 
+    def _slot_state_stats(self) -> dict:
+        """For a model whose layers keep per-slot state beside their pages,
+        on the dispatch span: ``slot_state_bytes_per_layer``, the bytes a
+        slot's state leaves hold a layer, from the allocated leaves. Empty
+        for every other model."""
+        if not getattr(self.cache, "slot_state", False):
+            return {}
+        if self._slot_state_bytes is None:
+            self._slot_state_bytes = int(round(
+                slot_state_bytes_per_layer(self.cache.cache)))
+        return {"slot_state_bytes_per_layer": self._slot_state_bytes}
+
     def _sampled_slots(self) -> int:
         """The decoding slots whose request samples (``temperature != 0``),
         for the dispatch span and the ``greedy_chunks_dispatched`` counter:
@@ -3615,6 +3642,7 @@ class ServingEngine:
             sampled_slots=sampled_slots,
             # one dict: both name ``ctx_tokens``
             **{**self._selection_stats(), **self._window_stats()},
+            **self._slot_state_stats(),
         ):
             cache_in = self.cache.take()
             attempt = self._dispatch_attempts

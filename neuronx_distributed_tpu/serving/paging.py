@@ -64,6 +64,7 @@ import numpy as np
 from neuronx_distributed_tpu.modules.attention import (
     _SCALE_SUFFIX,
     PAGED_LEAVES,
+    SLOT_STATE_LEAVES,
     WINDOW_PAGES,
     cache_batch_axis,
     cache_leaf_name,
@@ -132,11 +133,16 @@ class PageExhausted(RuntimeError):
     page-pressure wall (preempt-and-rewind)."""
 
 
-class WindowedCacheUnsupported(ValueError):
-    """Asked of a cache that has WINDOW layers (pages freed behind the
-    window) something it cannot give yet: a pinned or shared prefix lacks the
-    freed pages, and so does a staged, exported, spilled or seeded context; a
-    draft model, a quantized pool and ``tp > 1`` have no window kind."""
+class CacheKindUnsupported(ValueError):
+    """Asked of a cache KIND something it cannot give yet. A cache that has
+    WINDOW layers (pages freed behind the window): a pinned or shared prefix
+    lacks the freed pages, and so does a staged, exported, spilled or seeded
+    context; a draft model, a quantized pool and ``tp > 1`` have no window
+    kind. A cache with per-slot STATE beside its pages
+    (``modules/attention.SLOT_STATE_LEAVES``): whatever holds a context by its
+    pages alone has no state at the context's end, and a draft model, a
+    quantized pool and ``tp > 1`` have no such kind either."""
+
 
 
 # Pages a slot may hold on the window kind's table beyond ``window /
@@ -294,11 +300,16 @@ class PagedCacheManager:
         # its own allocator, pool size and block table. ``window_write_cols``:
         # the columns a decode chunk can write (the engine's chunk size)
         self.window = window
+        # whether the model's layers keep per-slot STATE beside their pages
+        # (SLOT_STATE_LEAVES; ``allocate_from`` sees the leaves): copied into
+        # the slot by an admission, refused wherever a context is held or
+        # moved by its pages alone
+        self.slot_state = False
         self.alloc_w: Optional[PageAllocator] = None
         self._tables_w = None
         if window is not None:
             if kv_quant is not None:
-                raise WindowedCacheUnsupported(
+                raise CacheKindUnsupported(
                     "a cache with window layers has no quantized pool")
             if window < 1:
                 raise ValueError(f"window must be >= 1, got {window}")
@@ -381,6 +392,10 @@ class PagedCacheManager:
                     rolled = jnp.roll(row_leaf, shift, axis=ax + 1)
                     return jax.lax.dynamic_update_slice_in_dim(
                         pool_leaf, rolled, slot, axis=ax
+                    )
+                if name in SLOT_STATE_LEAVES:  # the row's, as it is: no column
+                    return jax.lax.dynamic_update_slice_in_dim(
+                        pool_leaf, cache_node_at(row, path[:-1])[name], slot, axis=ax
                     )
                 return jnp.full_like(pool_leaf, cursor)
 
@@ -638,13 +653,22 @@ class PagedCacheManager:
         """Pages the window kind's table maps (0 without window layers)."""
         return 0 if self._tables_w is None else int((self._tables_w != 0).sum())
 
-    def _refuse_windowed(self, what: str) -> None:
+    def _refuse_held_by_pages(self, what: str) -> None:
+        """Refuse ``what``, which holds or moves a context by its pages alone,
+        for a kind whose contexts are not their pages."""
         if self.window is not None:
-            raise WindowedCacheUnsupported(
+            raise CacheKindUnsupported(
                 f"{what} is not available for a cache with window layers: a "
                 "window layer's pages behind the window are freed, so a "
                 "context cannot be pinned, shared, staged, exported, spilled "
                 "or seeded from its pages"
+            )
+        if self.slot_state:
+            raise CacheKindUnsupported(
+                f"{what} is not available for a cache with per-slot state: "
+                "the state at a context's end is in no page, so a context "
+                "cannot be pinned, shared, staged, exported, spilled or "
+                "seeded from its pages"
             )
 
     @property
@@ -738,7 +762,7 @@ class PagedCacheManager:
     def pin_pages(self, ids: Sequence[int]) -> None:
         """A prefix entry takes a reference on each page (insert-on-miss:
         the slot's own context pages become shared storage, zero copies)."""
-        self._refuse_windowed("pinning a prefix's pages")
+        self._refuse_held_by_pages("pinning a prefix's pages")
         for pid in ids:
             self.alloc.ref(int(pid))
             self._pins[int(pid)] = self._pins.get(int(pid), 0) + 1
@@ -921,6 +945,17 @@ class PagedCacheManager:
                         lead + (self.num_slots, self.max_seq_len), jnp.bool_
                     ))
                 )
+            elif name in SLOT_STATE_LEAVES:
+                if self.kv_quant is not None:
+                    raise CacheKindUnsupported(
+                        "a cache with per-slot state has no quantized pool")
+                self.slot_state = True
+                items.append(
+                    (keys, jnp.zeros(
+                        leaf.shape[:ax] + (self.num_slots,) + leaf.shape[ax + 1:],
+                        leaf.dtype,
+                    ))
+                )
             else:
                 items.append((keys, jnp.zeros_like(leaf)))
         self.cache = {
@@ -936,7 +971,7 @@ class PagedCacheManager:
         (own ``num_pages``/``num_slots`` geometry) — the distinct-pool
         disaggregation path's decode-side bootstrap, where the decode
         engine may never have run a prefill of its own."""
-        self._refuse_windowed("a pool built from another manager's")
+        self._refuse_held_by_pages("a pool built from another manager's")
         if other.cache is None:
             raise RuntimeError("source manager has no allocated pool")
         if self.cache is not None:
@@ -1013,7 +1048,7 @@ class PagedCacheManager:
         if (self._tables[slot] != 0).any():
             raise ValueError(f"slot {slot} still maps pages (not freed?)")
         if m_shared:
-            self._refuse_windowed("mapping a shared prefix's pages")
+            self._refuse_held_by_pages("mapping a shared prefix's pages")
         own_lo = (start + m_shared) // ps
         n_own = self.page_span(start + m_shared, target)
         # the window kind: the pages of the columns the next query can attend
@@ -1072,7 +1107,7 @@ class PagedCacheManager:
         — the zero-copy prefix hit's suffix-prefill substrate. Pool pages
         are gathered for COMPUTE only (nothing allocated, nothing written;
         ``PageAllocator.copy_bytes`` untouched)."""
-        self._refuse_windowed("a row seeded from shared pages")
+        self._refuse_held_by_pages("a row seeded from shared pages")
         if self.cache is None:
             raise RuntimeError("no cache allocated yet (nothing to seed from)")
         return self._seed_fn(
@@ -1092,7 +1127,7 @@ class PagedCacheManager:
         releases them. This is the prefill worker's half of the
         disaggregated handoff — the decode side then binds the pages by
         block-table mapping alone."""
-        self._refuse_windowed("staging a context without a slot")
+        self._refuse_held_by_pages("staging a context without a slot")
         if self.cache is None:
             if self.cursor > 0:
                 raise RuntimeError(
@@ -1101,6 +1136,8 @@ class PagedCacheManager:
                     "update_after_decode/restore"
                 )
             self.allocate_from(row_cache)
+            # the first row is what shows a per-slot state
+            self._refuse_held_by_pages("staging a context without a slot")
         if p < 1 or p > padded:
             raise ValueError(f"bad staged context length p={p} (padded "
                              f"{padded})")
@@ -1218,7 +1255,7 @@ class PagedCacheManager:
         context — a REAL device transfer, charged to
         ``PageAllocator.copy_bytes`` (the accounting that proves the
         shared-pool handoff moved nothing)."""
-        self._refuse_windowed("importing an exported context")
+        self._refuse_held_by_pages("importing an exported context")
         if self.cache is None:
             raise RuntimeError(
                 "import_pages needs an allocated pool — serve one "
@@ -1250,7 +1287,7 @@ class PagedCacheManager:
         with host-numpy blocks. Runs only on the reclaim valve (a page-
         pressure event, never a steady chunk), so the pinned per-chunk
         budgets are untouched."""
-        self._refuse_windowed("spilling pages to the host tier")
+        self._refuse_held_by_pages("spilling pages to the host tier")
         if self.cache is None:
             raise RuntimeError("spill needs an allocated pool")
         from neuronx_distributed_tpu.utils.tree import path_keys

@@ -133,20 +133,19 @@ def cache_fingerprint(cache):
     equality — this is corruption detection (bit flips, injected poison),
     not cryptographic integrity."""
     from neuronx_distributed_tpu.modules.attention import (
-        cache_batch_axis,
         cache_leaf_name,
+        cache_length_axis,
     )
 
     total = jnp.zeros((), jnp.float32)
     flat, _ = jax.tree_util.tree_flatten_with_path(cache)
     for path, leaf in flat:
         name = cache_leaf_name(path)
-        ax = cache_batch_axis(name, leaf.ndim)
+        col = cache_length_axis(name, leaf.ndim)
         x = jnp.abs(leaf.astype(jnp.float32)) if jnp.issubdtype(
             leaf.dtype, jnp.floating
         ) else leaf.astype(jnp.float32)
-        if ax is not None:
-            col = ax + 1
+        if col is not None:  # a cursor or a per-slot state leaf has no column
             shape = [1] * leaf.ndim
             shape[col] = leaf.shape[col]
             w = (1.0 + jnp.arange(leaf.shape[col], dtype=jnp.float32)).reshape(shape)

@@ -996,3 +996,94 @@ def test_trinity_engine_programs_compile_and_fit(topo):
     text = prefill.as_text()
     wide = [m.group(0) for m in re.finditer(r"(f32|s32|u32|pred|s8)\[[\d,]*%d,%d\]" % (longest, longest), text)]
     assert not wide, wide[:3]
+
+
+# --- ZAYA1-8B: a joined leaf of (4, 128) a token and a per-slot state ----------------
+
+# what the described-v5e compile of the configured depth showed for the two
+# programs' temporaries (GiB; PR 47, the configuration's ``reduced_why``)
+ZAYA_DECODE_TEMP_GIB = 0.10
+ZAYA_PREFILL_TEMP_GIB = 0.39
+
+
+def test_walking_decode_kernel_compiles_on_a_quarter_tile_leaf_and_the_pool_stays_dense(topo):
+    """ZAYA1-8B's decode attention at the serve cell's shapes (32 slots of
+    16,384 columns, page 16, 8 query heads against 2 kv heads of 128, K and V
+    one joined leaf of (4, 128) a token: a QUARTER of a bf16 tile): the kernel
+    that walks the blocks a slot maps reads the fetched block through its
+    32-bit view (two words a token) as it is, the window pages' copies go into
+    the pool in place, and the compiler holds the pool leaf DENSE, tiled (4,
+    128): 1,024 B a token, not a (16, 128) tile's 4,096."""
+    from neuronx_distributed_tpu.kernels.flash_decode import (
+        paged_scatter_window_pages_dma,
+        paged_walk_decode_attention,
+    )
+
+    s = _one_chip(topo)
+    b, n_log, page = 32, 1024, 16
+    pages = b * n_log + 1
+
+    def step(q, pool, win, bt, pos, ok):
+        pool = paged_scatter_window_pages_dma(pool, win, bt, pos[0] // page)
+        return paged_walk_decode_attention(q, pool, bt, pos, kv_valid=ok, page_size=page), pool
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        s((b, 1, 8, 128)), s((pages, page, 4, 128)), s((b, 2 * page, 4, 128)), s((b, n_log), jnp.int32),
+        s((1,), jnp.int32), s((b, n_log * page), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert _kernels(text) >= 2
+    assert not re.search(r"bf16\[%d,%d,4,128\]\S* copy\(" % (pages, page), text), "the joined pool leaf is copied whole"
+    assert re.search(r"bf16\[%d,%d,4,128\]\{3,2,1,0:T\(4,128\)\(2,1\)\}" % (pages, page), text), "the pool leaf's tiling"
+    dense = pages * page * 4 * 128 * 2
+    held = compiled.memory_analysis().argument_size_in_bytes
+    assert dense <= held < dense + 2**24, f"{held} bytes of arguments for a dense pool of {dense}"
+
+
+def _zaya(cfg, seq):
+    from perfbench.families import zaya
+
+    return zaya.build(cfg, runner="serve", max_seq_len=seq)
+
+
+@pytest.mark.slow
+def test_zaya_engine_programs_compile_and_fit(topo):
+    """The benchmark configuration's programs (``perfbench/configs/
+    zaya1-8b-serve.json``: its depth, all 16 experts, the whole vocabulary, 32
+    slots of 16,384, page 16): the fused decode chunk with every layer's pool
+    AND per-slot state carried, and the longest prompt's prefill, both with
+    Pallas kernels and inside the chip's memory. The decode program holds no
+    row-sized array and copies no pool leaf; both programs' temporaries are
+    what the configuration's ``reduced_why`` states + 10%."""
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    with open(os.path.join(root, "perfbench", "configs", "zaya1-8b-serve.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(root, "perfbench", "traffic", "reasoning_closed.json")) as f:
+        longest = int(json.load(f)["prompt_len"]["max"])
+    seq, slots = int(config["serving"]["max_seq_len"]), int(config["serving"]["num_slots"])
+    model = _zaya(config["model"], seq)
+    engine, lower_decode, lower_prefill, pool_shards = _engine_programs(
+        topo, 1, slots=slots, seq=seq, bucket=longest, model=model)
+    assert engine.programs.resolved == {
+        "attention": "flash", "decode_attention": "paged_walk_fused",
+        "paged_attention": "fused", "moe_decode": "stream",
+    }
+    layers = int(config["model"]["num_hidden_layers"])
+    assert pool_shards == [(slots * seq // 16 + 1, 16, 4, 128)] * layers
+    decode = lower_decode().compile()
+    assert _kernels(decode.as_text())
+    live = _fits(decode, 15 * 1024**3)
+    text = decode.as_text()
+    copies = _copies_inside_loops(text, {"bf16[%s]" % ",".join(map(str, pool_shards[0]))})
+    assert not copies, f"{len(copies)} whole-pool copies per decode step: " + "; ".join(copies[:3])
+    assert not _arrays_of_a_views_size(text, pool_shards[:1])
+    temp = decode.memory_analysis().temp_size_in_bytes
+    print(f"zaya decode: live {live / 2**30:.2f} GiB, temporaries {temp / 2**30:.2f} GiB")
+    assert temp < 1.1 * ZAYA_DECODE_TEMP_GIB * 2**30 + 2**26, f"{temp / 2**30:.2f} GiB of decode temporaries"
+    prefill = lower_prefill().compile()
+    assert _kernels(prefill.as_text())
+    live = _fits(prefill, 15 * 1024**3)
+    temp = prefill.memory_analysis().temp_size_in_bytes
+    print(f"zaya prefill[{longest}]: live {live / 2**30:.2f} GiB, temporaries {temp / 2**30:.2f} GiB")
+    assert temp < 1.1 * ZAYA_PREFILL_TEMP_GIB * 2**30, f"{temp / 2**30:.2f} GiB of prefill temporaries"

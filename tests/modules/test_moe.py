@@ -629,3 +629,104 @@ def test_a_chunk_reads_back_the_experts_its_steps_hit():
     assert steps * cfg.num_layers * cfg.top_k <= hit <= steps * cfg.num_layers * min(cfg.num_experts, 2 * cfg.top_k)
     scanned = MixtralForCausalLM(dataclasses.replace(cfg, scan_layers=True), attention_impl="xla")
     assert scanned.chunk_stats == ()
+
+
+# --- a router that is an MLP over a state of its own (routing.RouterMLP) -------------
+
+
+def _mlp_router_reference(p, x, state, eps=1e-5):
+    """Step 7 of ``models/zaya.py`` in numpy float64: ``(p + bias, p, new state)``."""
+    from scipy.special import erf
+
+    p = {k: np.asarray(getattr(v, "value", v), np.float64) for k, v in p.items()}
+    gelu = lambda a: 0.5 * a * (1.0 + erf(a / np.sqrt(2.0)))    # noqa: E731
+    s = np.asarray(x, np.float64) @ p["down_weight"] + p["down_bias"]
+    if state is not None:
+        s = s + p["state_mix"] * np.asarray(state, np.float64)
+    y = s / np.sqrt((s * s).mean(-1, keepdims=True) + eps) * p["norm_weight"]
+    y = gelu(y @ p["fc1_weight"] + p["fc1_bias"])
+    y = gelu(y @ p["fc2_weight"] + p["fc2_bias"])
+    z = y @ p["fc3_weight"]
+    e = np.exp(z - z.max(-1, keepdims=True))
+    probs = e / e.sum(-1, keepdims=True)
+    return probs + p["e_score_correction_bias"], probs, s
+
+
+@pytest.mark.parametrize("with_state", [False, True], ids=["first_layer_no_gamma", "later_layer_with_gamma"])
+def test_mlp_router_takes_and_returns_its_state(with_state):
+    """The state-passing router is the written one, with the previous layer's
+    state mixed in by ``gamma`` and without (the first layer has no ``gamma``
+    at all); its new state is the mixed down-projection, BEFORE the norm; top-1
+    by ``p + bias``, weighed by ``p`` alone."""
+    from neuronx_distributed_tpu.modules.moe.routing import RouterMLP
+
+    x = jax.random.normal(jax.random.PRNGKey(0), (T, H))
+    state = jax.random.normal(jax.random.PRNGKey(2), (T, 24)) if with_state else None
+    router = RouterMLP(hidden_size=H, num_experts=8, top_k=1, state_size=24, selection_bias=True,
+                       selection_bias_init_std=0.1, normalize_top_k_affinities=False)
+    params = router.init(jax.random.PRNGKey(1), x, state)
+    leaves = jax.tree_util.tree_leaves(params)
+    keys = jax.random.split(jax.random.PRNGKey(3), len(leaves))
+    params = jax.tree_util.tree_unflatten(jax.tree_util.tree_structure(params), [
+        leaf + 0.2 * jax.random.normal(k, leaf.shape) if leaf.ndim == 1 else leaf for leaf, k in zip(leaves, keys)])
+    assert ("state_mix" in params["params"]) == with_state
+    out, new = router.apply(params, x, state)
+    biased, probs, want_state = _mlp_router_reference(params["params"], x, state)
+    np.testing.assert_allclose(np.asarray(new), want_state, atol=2e-5)
+    np.testing.assert_allclose(np.asarray(out.probs), probs, atol=2e-6)
+    np.testing.assert_array_equal(np.asarray(out.top_e)[:, 0], biased.argmax(-1))
+    np.testing.assert_allclose(np.asarray(out.top_w)[:, 0], probs[np.arange(T), biased.argmax(-1)], atol=2e-6)
+    assert (biased.argmax(-1) != probs.argmax(-1)).any() or np.ptp(probs.max(-1)) > 0.1
+
+
+def test_mlp_router_starts_without_an_experts_head_start():
+    """The last two matrices start with zero column sums
+    (``routing.zero_sum_lecun_normal``): the gelus' common mean then gives no
+    expert an offset of its own, and the share of tokens an expert starts with
+    is near even under every key; with plain ``lecun_normal`` an expert's mean
+    probability is off by half of what a token moves it."""
+    from flax import linen as nn
+    from flax.core import meta
+
+    from neuronx_distributed_tpu.modules.moe.routing import RouterMLP
+
+    x = jax.random.normal(jax.random.PRNGKey(0), (4096, 64))
+    router = RouterMLP(hidden_size=64, num_experts=16, top_k=1, state_size=256, normalize_top_k_affinities=False)
+    for seed in range(3):
+        params = meta.unbox(router.init(jax.random.PRNGKey(seed), x))
+        for name in ("fc2_weight", "fc3_weight"):
+            assert float(jnp.abs(params["params"][name].sum(axis=0)).max()) < 1e-5
+        probs = np.asarray(router.apply(params, x)[0].probs)
+        assert probs.mean(axis=0).std() < 0.25 * (probs - probs.mean(axis=0)).std()
+        plain = dict(params["params"])
+        for i, name in enumerate(("fc2_weight", "fc3_weight")):
+            plain[name] = nn.initializers.lecun_normal()(jax.random.PRNGKey(100 + 2 * seed + i), plain[name].shape)
+        probs = np.asarray(router.apply({"params": plain}, x)[0].probs)
+        assert probs.mean(axis=0).std() > 0.35 * (probs - probs.mean(axis=0)).std()
+
+
+def test_moe_passes_the_router_state_through_and_every_other_caller_is_what_it_was():
+    """``MoE(router_kind="mlp")`` hands ``router_state`` to its router and
+    returns the new one in ``aux``; a layer of any other router lowers to ONE
+    program whether the argument is named or not, returns no state, and
+    refuses one."""
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 6, H))
+    kw = dict(num_experts=8, hidden_size=H, intermediate_size=I, top_k=1, expert_strategy="all_experts",
+              normalize_top_k_affinities=False, dtype=jnp.float32)
+    layer = MoE(router_kind="mlp", router_state_size=24, router_selection_bias=True, **kw)
+    state = jax.random.normal(jax.random.PRNGKey(1), (2, 6, 24))
+    first = layer.init(jax.random.PRNGKey(2), x)
+    later = layer.init(jax.random.PRNGKey(2), x, router_state=state)
+    assert "state_mix" not in first["params"]["router"] and "state_mix" in later["params"]["router"]
+    _, aux0 = layer.apply(first, x)
+    out, aux = layer.apply(later, x, router_state=state)
+    assert aux0["router_state"].shape == aux["router_state"].shape == (2, 6, 24) and out.shape == x.shape
+    moved, _ = layer.apply(later, x, router_state=2.0 * state)
+    assert not np.allclose(np.asarray(moved), np.asarray(out))            # the state reaches the choice and the weight
+    plain = MoE(router_selection_bias=True, **kw)
+    params = plain.init(jax.random.PRNGKey(2), x)
+    named = jax.jit(lambda p, a: plain.apply(p, a, router_state=None)).lower(params, x).as_text()
+    unnamed = jax.jit(lambda p, a: plain.apply(p, a)).lower(params, x).as_text()
+    assert named == unnamed and "router_state" not in plain.apply(params, x)[1]
+    with pytest.raises(ValueError, match="router_state is the mlp router's"):
+        plain.apply(params, x, router_state=state)

@@ -261,3 +261,27 @@ def test_the_window_and_full_attention_programs_carry_their_kinds_names(trinity)
     assert kinds | {"moe", "moe.shared"} <= _optimized(decode)
     assert kinds | {"moe", "moe.shared"} <= _traced(prefill)
     assert {"attn.window", "attn.full"} <= _optimized(prefill)
+
+
+@pytest.fixture(scope="module")
+def zaya():
+    return _programs("zaya1-8b-serve")
+
+
+def test_the_compressed_latent_attention_programs_carry_their_names(zaya):
+    """``cca_block_dev_share_pct`` finds the scope ``attn.cca``,
+    ``cca_conv_dev_share_pct`` ``attn.cca.conv`` (the convolutions and the
+    per-slot state's read and write: no kernel is called there),
+    ``cca_decode_roofline`` the decode kernels called in ``attn.cca.attend``
+    (the walk over the blocks a slot maps and the write window's page copies),
+    no ``attn._cached_attention`` between; ``router_mlp_dev_share_pct`` the
+    router's MLP under ``moe.router``; the tied head under ``lm_head``."""
+    decode, prefill = zaya["decode_chunk"], zaya["prefill"]
+    assert re.search(r"HloModule (\S+?),", decode[1]).group(1) == "jit_chunk_fn"
+    assert re.search(r"HloModule (\S+?),", prefill[1]).group(1) == "jit_fn"
+    assert {path.rsplit("/", 1)[-1] for path in KERNEL_CALL.findall(decode[0])} == {"attn.cca.attend"}
+    cca = {"attn.cca", "attn.cca.project", "attn.cca.conv", "attn.cca.attend"}
+    assert cca | {"attn", "kv_view", "moe", "moe.router", "moe.experts", "sample", "lm_head"} <= _traced(decode)
+    assert cca | {"moe", "moe.router", "lm_head"} <= _optimized(decode)
+    assert cca | {"moe", "moe.router"} <= _traced(prefill)
+    assert {"attn.cca", "attn.cca.conv"} <= _optimized(prefill)

@@ -11,11 +11,14 @@ Keye-VL-2.0's (K and V joined in one leaf and one index key; contexts past
 ``topk`` so that selection is at work). ``indexed_latent``: GLM-5's (the latent
 and the rotated key joined, and one index key; a share of the experts held).
 ``joined``: Trinity's (K and V joined; window and full attention layers, a
-block table and a pool a layer kind).
+block table and a pool a layer kind). ``joined_state``: ZAYA1's (K and V
+joined, and per-slot STATE beside the pages: what a layer's next token needs of
+the slot's last one, a slot axis and no length axis).
 
 What is particular to ONE kind stays in that kind's file
 (``test_latent_cache.py``, ``test_indexed_cache.py``,
-``test_indexed_latent_cache.py``, ``test_window_cache.py``), on ``built(kind)``
+``test_indexed_latent_cache.py``, ``test_window_cache.py``,
+``test_slot_state.py``), on ``built(kind)``
 from here: a kind's model, parameters, reference and served streams are built
 once a process. A new cache kind is a row of ``KINDS``."""
 
@@ -33,12 +36,14 @@ from neuronx_distributed_tpu.inference import GenerationConfig
 from neuronx_distributed_tpu.inference.generate import chunked_decode_step, serving_clones
 from neuronx_distributed_tpu.modules.attention import (
     PAGED_LEAVES,
+    SLOT_STATE_LEAVES,
     cache_bytes_per_token_layer,
     ordered_kv_pool_pairs,
+    slot_state_bytes_per_layer,
 )
 from neuronx_distributed_tpu.quantization import QuantConfig
 from neuronx_distributed_tpu.serving import PagedCacheManager, PrefixCache, ServingEngine
-from neuronx_distributed_tpu.serving.paging import WindowedCacheUnsupported
+from neuronx_distributed_tpu.serving.paging import CacheKindUnsupported
 from perfbench.references import common
 
 # float32 model against the float32 reference: the largest gap seen is 3e-6
@@ -94,6 +99,15 @@ def _joined():
     return AfmoeForCausalLM, tiny_afmoe(held_experts=(4, 4), max_seq_len=256), published_keys, Reference, None
 
 
+def _joined_state():
+    from neuronx_distributed_tpu.models.zaya import ZayaForCausalLM, tiny_zaya, zaya1_8b
+    from perfbench.references.zaya import Reference
+    from tests.models.test_zaya import published_keys
+
+    return (ZayaForCausalLM, tiny_zaya(max_seq_len=256), published_keys, Reference,
+            zaya1_8b(num_layers=2, param_dtype=jnp.bfloat16))
+
+
 @dataclasses.dataclass(frozen=True)
 class Kind:
     """A cache kind's row: what builds its tiny model and reference, and what
@@ -112,6 +126,9 @@ class Kind:
     # hold, and its preempt-and-rewind at the wall, are test_window_cache.py's
     whole_pool: bool = True
     refuses: Tuple[str, ...] = ("tp",)   # see ``REFUSALS``
+    # per-slot state leaf -> its width a slot, tiny widths (a slot axis, no length axis) ...
+    state: Dict[str, int] = dataclasses.field(default_factory=dict)
+    published_state_bytes: int = 0       # ... and their bytes a slot a layer at the published widths in bf16
 
     @property
     def bytes(self) -> int:              # a token a layer, tiny widths in float32
@@ -138,17 +155,26 @@ KINDS = {
     # have yet (ROADMAP queue 2, C1) is refused at construction, each by name
     "joined": Kind(_joined, "paged_walk_fused", {"kv": (2 * 2, 16)}, page=8, prefix_transports=(), whole_pool=False,
                    refuses=("tp", "prefix_cache", "kv_host_pages", "draft_model", "quantize.kv")),
+    # a token: 2 x 2 heads of 16 (published in bf16: (4, 128), 1024 bytes, a QUARTER of a tile). A slot: the
+    # packed q/k latent of its last token, the first convolution's output for it and the shifted value
+    # half, 2 x 96 + 16 (published: 2 x 1280 + 128 values, 5376 bytes). A context is not its pages alone,
+    # so whatever holds one by them is refused at construction, each by name
+    "joined_state": Kind(_joined_state, "paged_walk_fused", {"kv": (2 * 2, 16)}, ("layers_0", "layers_1", "layers_2"),
+                         {"kv": (4, 128)}, 1024, prefix_transports=(), state={"state": 2 * 96 + 16},
+                         published_state_bytes=5376,
+                         refuses=("tp", "prefix_cache", "kv_host_pages", "draft_model", "quantize.kv", "disagg")),
 }
 WHOLE_POOL = [name for name, row in KINDS.items() if row.whole_pool]
 
 # what a kind may refuse at construction: name -> (engine arguments, the
 # exception, what its message names), given the kind's ``Built``
 REFUSALS = {
-    "tp": lambda b: ({"tp": 2}, ValueError, f"{b.name}-cache"),
-    "prefix_cache": lambda b: ({"prefix_cache": 4}, WindowedCacheUnsupported, "prefix_cache"),
-    "kv_host_pages": lambda b: ({"kv_host_pages": 8}, WindowedCacheUnsupported, "kv_host_pages"),
-    "draft_model": lambda b: ({"draft_model": b.model, "draft_params": b.params}, WindowedCacheUnsupported, "draft_model"),
-    "quantize.kv": lambda b: ({"quantize": QuantConfig(weights=None, kv="int8")}, WindowedCacheUnsupported, r"quantize\.kv"),
+    "tp": lambda b: ({"tp": 2}, ValueError, f"{b.cfg.kv_cache_kind}-cache"),
+    "prefix_cache": lambda b: ({"prefix_cache": 4}, CacheKindUnsupported, "prefix_cache"),
+    "kv_host_pages": lambda b: ({"kv_host_pages": 8}, CacheKindUnsupported, "kv_host_pages"),
+    "draft_model": lambda b: ({"draft_model": b.model, "draft_params": b.params}, CacheKindUnsupported, "draft_model"),
+    "quantize.kv": lambda b: ({"quantize": QuantConfig(weights=None, kv="int8")}, CacheKindUnsupported, r"quantize\.kv"),
+    "disagg": None,   # refused where the handoff is built, from a built engine: the test's own branch
 }
 
 
@@ -263,6 +289,13 @@ def test_cache_leaves_hold_the_kinds_values_a_token_and_nothing_else(kind):
         lambda p, i: model.clone(mode="prefill").apply(p, i, mutable=["cache"])[1]["cache"], shapes, ids)
     assert cache_bytes_per_token_layer(cache) == row.published_bytes
     assert paged_leaves(cache) == row.published_leaves
+    # per-slot state: the kind's leaves and no other, (slots, width), the same bytes in every layout
+    pool = kind.stream("fused")[0].cache.cache["pool"]
+    assert {path[-1].key: leaf.shape for path, leaf in jax.tree_util.tree_flatten_with_path(pool)[0]
+            if path[-1].key in SLOT_STATE_LEAVES} == {name: (2, width) for name, width in row.state.items()}
+    for path in PATHS:
+        assert slot_state_bytes_per_layer(kind.stream(path)[0].cache.cache) == 4 * sum(row.state.values())
+    assert slot_state_bytes_per_layer(cache) == row.published_state_bytes
 
 
 @kinds(WHOLE_POOL)
@@ -294,6 +327,8 @@ def test_fused_chunk_carries_every_leaf(kind):
     for pair in pairs.values():
         for leaf in pair:
             assert leaf.shape in carried
+    for width in kind.kind.state.values():     # a layer's state leaf rides the carry too
+        assert carried.count((2, width)) == len(kind.kind.layers)
     # no per-token leaf as long as a row anywhere in the chunk
     rows = [v.aval.shape for e in jaxpr.jaxpr.eqns for v in e.outvars
             if len(v.aval.shape) == 4 and v.aval.shape[:2] == (2, cfg.max_seq_len)]
@@ -344,6 +379,12 @@ def test_preemption_and_resume_give_the_undisturbed_stream(kind):
                          indirect=["kind"])
 def test_a_kind_refuses_by_name_what_it_cannot_have(kind, what):
     _, model, params, _, _ = kind
+    if what == "disagg":
+        from neuronx_distributed_tpu.serving.disagg import DisaggregatedServer
+
+        with pytest.raises(CacheKindUnsupported, match="disaggregation"):
+            DisaggregatedServer(ServingEngine(model, params, num_slots=2, kv_page_size=kind.kind.page))
+        return
     kwargs, error, names = REFUSALS[what](kind)
     with pytest.raises(error, match=names):
         ServingEngine(model, params, num_slots=2, kv_page_size=kind.kind.page, **kwargs)

@@ -21,7 +21,7 @@ from neuronx_distributed_tpu.serving import ServingEngine
 from neuronx_distributed_tpu.serving.paging import (
     WINDOW_SLACK_PAGES,
     PagedCacheManager,
-    WindowedCacheUnsupported,
+    CacheKindUnsupported,
 )
 
 from perfbench.references.afmoe import Reference
@@ -197,12 +197,12 @@ def test_tensor_parallel_and_a_disaggregated_handoff_are_refused(system):
     with pytest.raises(ValueError, match="joined-cache"):
         ServingEngine(model, params, num_slots=2, kv_page_size=PAGE, tp=2)
     engine = engine_of(system, "gather", slots=2)
-    with pytest.raises(WindowedCacheUnsupported, match="disaggregation"):
+    with pytest.raises(CacheKindUnsupported, match="disaggregation"):
         DisaggregatedServer(engine)
     mgr = engine.cache
     for call in (lambda: mgr.pin_pages([1]), lambda: mgr.seed_row([1], 8, 0),
                  lambda: mgr.stage_context(None, 8, 8), lambda: mgr.spill_pages([1])):
-        with pytest.raises(WindowedCacheUnsupported):
+        with pytest.raises(CacheKindUnsupported):
             call()
 
 

@@ -126,6 +126,16 @@ ROWS = {
         "trinity_no_window_is_caught", "trinity_half_window_is_caught", "trinity_missing_gate_is_caught",
         "trinity_rotary_on_the_full_layer_is_caught", "trinity_float8_cache_is_caught",
         "trinity_bias_in_the_weights_is_caught", "kernel_trinity_programs")),
+    # ``--only zaya`` at the CPU stand-in's size (3 layers, contexts of 150 in a row of 512, page 8);
+    # float32, so the tolerances are a float32 matmul's summation order and every control is caught
+    "zaya": Row(chip_smoke.ZayaSize(
+        model=_tiny("zaya1-8b-serve"), max_seq_len=512, slots=3, page=8, prompt_lens=(150, 70, 20),
+        new_tokens=12, block_tol=1e-4, state_tol=1e-4, gap_tol=1e-3, near_tie=0.0), (
+        "zaya_matches_reference", "zaya_two_blocks_alone_match_reference", "zaya_cursor_jumps_leave_gap_columns",
+        "zaya_leaks_no_page", "zaya_resolved_paged_walk_fused",
+        "zaya_cache_is_a_kib_a_token_and_the_state_five_and_a_quarter_a_slot", "zaya_no_convolution_is_caught",
+        "zaya_unshifted_value_head_is_caught", "zaya_float8_is_caught", "zaya_no_router_state_is_caught",
+        "kernel_zaya_programs")),
     # ``--only moe`` at a tiny size: every comparison must hold; which form is
     # FASTER is the chip's to say (an interpreted kernel's time says nothing)
     "moe": Row(chip_smoke.MoeSize(
@@ -217,7 +227,7 @@ def test_default_runs_are_the_tables_rows():
     assert chip_smoke.default_run(1) == ["train", "serve", "mla", "dsa", "glm"]
     assert chip_smoke.default_run(4) == ["tp_train", "tp_serve", "remat"]
     only = [name for name, phase in chip_smoke.PHASES.items() if phase.only]
-    assert only == ["mla", "dsa", "glm", "moe", "trinity", "walk", "flash"]
+    assert only == ["mla", "dsa", "glm", "moe", "trinity", "zaya", "walk", "flash"]
     for name in only:
         assert chip_smoke.parse_args(["--only", name]).only == name
         assert chip_smoke.PHASES[name].help and chip_smoke.PHASES[name].chips == 1
