@@ -961,6 +961,23 @@ TP_SERVE = ServeSize(
 # --- multi-head latent attention ------------------------------------------------------
 
 
+def _prefill_rows(backbone, tail: int):
+    """``fn(params, ids, lo)``: the system's prefill logits at ``tail`` positions
+    from ``lo``. A ``*ForCausalLM`` in ``prefill`` mode applies its head to the
+    LAST position alone (``models/__init__.py``), so every position is read
+    from the headless ``backbone``'s hidden states through the head's kernel."""
+    import jax
+    from flax.core import meta
+
+    @jax.jit
+    def fn(params, ids, lo):
+        hidden = backbone.apply({"params": params["params"]["model"]}, ids, mutable=["cache"])[0][0]
+        rows = jax.lax.dynamic_slice_in_dim(hidden[0], lo, min(tail, ids.shape[1]), axis=0)
+        return rows @ meta.unbox(params)["params"]["lm_head"]["kernel"]
+
+    return fn
+
+
 def mla_phase(size: MlaSize, seed: int) -> Dict[str, bool]:
     """DeepSeek-V2-Lite alone: 24,576 / 8,192 / 2,048-token prompts through
     the paged latent cache against the plain reference. The published widths
@@ -979,7 +996,7 @@ def mla_phase(size: MlaSize, seed: int) -> Dict[str, bool]:
     import numpy as np
     from flax.core import meta
 
-    from neuronx_distributed_tpu.inference.generate import serving_clones
+    from neuronx_distributed_tpu.models.deepseek_v2 import DeepseekV2Model
     from neuronx_distributed_tpu.parallel import mesh as mesh_lib
     from neuronx_distributed_tpu.serving import ServingEngine
     from perfbench.families import deepseek_v2 as family
@@ -1006,12 +1023,9 @@ def mla_phase(size: MlaSize, seed: int) -> Dict[str, bool]:
     engine = None
     gc.collect()
 
-    prefill, _ = serving_clones(model)
+    backbone = DeepseekV2Model(model.config, model.attention_impl, mode="prefill")
 
-    @jax.jit
-    def prefill_rows(params, ids, lo):
-        logits = prefill.apply(params, ids, mutable=["cache"])[0][0]
-        return jax.lax.dynamic_slice_in_dim(logits[0], lo, min(size.tail, ids.shape[1]), axis=0)
+    prefill_rows = _prefill_rows(backbone, size.tail)
 
     ref = Reference(published, meta.unbox(params))
     dtype = jnp.dtype(model.config.dtype).itemsize
@@ -1276,12 +1290,7 @@ def dsa_phase(size: DsaSize, seed: int) -> Dict[str, bool]:
 
     backbone = KeyeVL2Model(cfg, model.attention_impl, mode="prefill")
 
-    @jax.jit
-    def prefill_rows(params, ids, lo):
-        """The system's prefill logits at ``tail`` positions from ``lo``."""
-        hidden = backbone.apply({"params": params["params"]["model"]}, ids, mutable=["cache"])[0][0]
-        rows = jax.lax.dynamic_slice_in_dim(hidden[0], lo, min(size.tail, ids.shape[1]), axis=0)
-        return rows @ meta.unbox(params)["params"]["lm_head"]["kernel"]
+    prefill_rows = _prefill_rows(backbone, size.tail)
 
     @jax.jit
     def layer0_keep(params, ids):
@@ -1508,12 +1517,7 @@ def glm_phase(size: GlmSize, seed: int) -> Dict[str, bool]:
 
     backbone = GlmMoeDsaModel(cfg, model.attention_impl, mode="prefill")
 
-    @jax.jit
-    def prefill_rows(params, ids, lo):
-        """The system's prefill logits at ``tail`` positions from ``lo``."""
-        hidden = backbone.apply({"params": params["params"]["model"]}, ids, mutable=["cache"])[0][0]
-        rows = jax.lax.dynamic_slice_in_dim(hidden[0], lo, min(size.tail, ids.shape[1]), axis=0)
-        return rows @ meta.unbox(params)["params"]["lm_head"]["kernel"]
+    prefill_rows = _prefill_rows(backbone, size.tail)
 
     @jax.jit
     def layer0_keep(params, ids):
@@ -1827,12 +1831,7 @@ def trinity_phase(size: TrinitySize, seed: int) -> Dict[str, bool]:
 
     backbone = AfmoeModel(cfg, model.attention_impl, mode="prefill")
 
-    @jax.jit
-    def prefill_rows(params, ids, lo):
-        """The system's prefill logits at ``tail`` positions from ``lo``."""
-        hidden = backbone.apply({"params": params["params"]["model"]}, ids, mutable=["cache"])[0][0]
-        rows = jax.lax.dynamic_slice_in_dim(hidden[0], lo, min(size.tail, ids.shape[1]), axis=0)
-        return rows @ meta.unbox(params)["params"]["lm_head"]["kernel"]
+    prefill_rows = _prefill_rows(backbone, size.tail)
 
     @jax.jit
     def blocks_of(params, ids):

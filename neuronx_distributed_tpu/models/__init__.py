@@ -1,3 +1,26 @@
+"""The model families.
+
+What a causal LM returns in each ``mode`` (every ``*ForCausalLM`` here and
+``MedusaForCausalLM``, one contract):
+
+- ``mode="train"``: logits at EVERY position, ``(B, S, V)``.
+- ``mode="prefill"``: the whole prompt goes through the layers and into the
+  cache, and the head is applied to the LAST position alone: logits
+  ``(B, 1, V)`` (``if self.mode == "prefill": x = x[:, -1:]`` between the final
+  norm and the head). Every caller of a prefill reads ``[:, -1]`` of a
+  LEFT-padded prompt and no other row (``inference/generate.py``, the
+  speculative pair, Medusa, ``ServingEngine``, the disaggregated prefill
+  worker), and XLA does not push that slice through the head's matmul: at
+  every position the logits of a 20,992-token DeepSeek-V2-Lite prompt are
+  4.3 GiB in bf16 and a ninth of the prefill's time.
+- ``mode="decode"``: logits at every position of the step's window,
+  ``(B, W, V)`` (W = 1, or a speculative round's window).
+
+Logits at every position of a context: ``mode="train"``, or, where the family
+has one, the headless ``*Model`` in ``prefill`` mode and the head's kernel
+(``params["params"]["lm_head"]["kernel"]``), as ``chip_smoke.py``'s reference
+comparisons do."""
+
 from neuronx_distributed_tpu.models.afmoe import (
     AfmoeConfig,
     AfmoeForCausalLM,

@@ -340,9 +340,10 @@ class AfmoeModel(nn.Module):
 
 class AfmoeForCausalLM(nn.Module):
     """In ``prefill`` mode the head is applied to the LAST position alone
-    (logits (B, 1, V)), as ``KeyeVL2ForCausalLM`` and ``GlmMoeDsaForCausalLM``
-    do and for their reason: every caller of a prefill reads ``[:, -1]`` and
-    no other row. Logits at every position of a context: ``mode="train"``.
+    (logits (B, 1, V)): the contract every causal LM here keeps, stated in
+    ``models/__init__.py``. Logits at every position of a context:
+    ``mode="train"``, or ``AfmoeModel`` in ``prefill`` mode and the head's
+    kernel.
 
     ``chunk_stats``: the counters a model with held experts sows into the
     ``stats`` collection each decode step (``modules/moe.MoE``)."""

@@ -96,6 +96,10 @@ class CodeGenBlock(nn.Module):
 
 
 class CodeGenForCausalLM(nn.Module):
+    """In ``prefill`` mode the head is applied to the LAST position alone
+    (logits (B, 1, V)): the contract every causal LM here keeps, stated in
+    ``models/__init__.py``."""
+
     config: CodeGenConfig
     mode: str = "train"
 
@@ -114,6 +118,8 @@ class CodeGenForCausalLM(nn.Module):
             )
         x = LayerNorm(cfg.hidden_size, eps=cfg.layer_norm_eps, dtype=cfg.dtype,
                       param_dtype=cfg.param_dtype, name="final_norm")(x)
+        if self.mode == "prefill":
+            x = x[:, -1:]
         return ColumnParallelLinear(
             cfg.hidden_size, cfg.vocab_size, use_bias=True, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, name="lm_head",

@@ -111,6 +111,10 @@ class DbrxBlock(nn.Module):
 
 
 class DbrxForCausalLM(nn.Module):
+    """In ``prefill`` mode the head is applied to the LAST position alone
+    (logits (B, 1, V)): the contract every causal LM here keeps, stated in
+    ``models/__init__.py``."""
+
     config: DbrxConfig
     attention_impl: str = "auto"
     mode: str = "train"
@@ -137,6 +141,8 @@ class DbrxForCausalLM(nn.Module):
         x = LayerNorm(cfg.hidden_size, eps=cfg.layer_norm_eps, use_bias=False,
                       dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                       name="final_norm")(x)
+        if self.mode == "prefill":
+            x = x[:, -1:]
         logits = ColumnParallelLinear(
             cfg.hidden_size, cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype,

@@ -427,6 +427,10 @@ class DeepseekV2Model(nn.Module):
 
 
 class DeepseekV2ForCausalLM(nn.Module):
+    """In ``prefill`` mode the head is applied to the LAST position alone
+    (logits (B, 1, V)): the contract every causal LM here keeps, stated in
+    ``models/__init__.py``."""
+
     config: DeepseekV2Config
     attention_impl: str = "auto"
     mode: str = "train"
@@ -440,6 +444,8 @@ class DeepseekV2ForCausalLM(nn.Module):
         cfg = self.config
         x, aux = DeepseekV2Model(cfg, self.attention_impl, self.mode, name="model")(
             input_ids, positions, deterministic, segment_ids, padding_mask)
+        if self.mode == "prefill":
+            x = x[:, -1:]
         logits = ColumnParallelLinear(
             cfg.hidden_size, cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, name="lm_head",

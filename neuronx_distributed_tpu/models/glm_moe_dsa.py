@@ -372,10 +372,10 @@ class GlmMoeDsaModel(nn.Module):
 
 class GlmMoeDsaForCausalLM(nn.Module):
     """In ``prefill`` mode the head is applied to the LAST position alone
-    (logits (B, 1, V)), as ``KeyeVL2ForCausalLM`` does and for its reason:
-    every caller of a prefill reads ``[:, -1]`` and no other row. Logits at
-    every position of a context: ``mode="train"``, or ``GlmMoeDsaModel`` in
-    ``prefill`` mode and the head's kernel.
+    (logits (B, 1, V)): the contract every causal LM here keeps, stated in
+    ``models/__init__.py``. Logits at every position of a context:
+    ``mode="train"``, or ``GlmMoeDsaModel`` in ``prefill`` mode and the
+    head's kernel.
 
     ``chunk_stats``: the counters a model with held experts sows into the
     ``stats`` collection each decode step (``modules/moe.MoE``), which

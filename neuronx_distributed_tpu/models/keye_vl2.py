@@ -313,11 +313,10 @@ class KeyeVL2Model(nn.Module):
 
 class KeyeVL2ForCausalLM(nn.Module):
     """In ``prefill`` mode the head is applied to the LAST position alone
-    (logits (B, 1, V)): every caller of a prefill reads ``[:, -1]`` and no
-    other row (``inference/generate.py``, the engine). XLA does not push that
-    slice through the head's matmul, and the logits of every position of a
-    24,576-token prompt at this vocabulary are 7.1 GiB in bf16. Logits at
-    every position of a context: ``mode="train"``, or ``KeyeVL2Model`` in
+    (logits (B, 1, V)): the contract every causal LM here keeps, stated in
+    ``models/__init__.py`` (the logits of every position of a 24,576-token
+    prompt at this vocabulary would be 7.1 GiB in bf16). Logits at every
+    position of a context: ``mode="train"``, or ``KeyeVL2Model`` in
     ``prefill`` mode and the head's kernel."""
 
     config: KeyeVL2Config

@@ -388,6 +388,10 @@ class LlamaModel(nn.Module):
 
 
 class LlamaForCausalLM(nn.Module):
+    """In ``prefill`` mode the head is applied to the LAST position alone
+    (logits (B, 1, V)): the contract every causal LM here keeps, stated in
+    ``models/__init__.py``."""
+
     config: LlamaConfig
     attention_impl: str = "auto"
     mode: str = "train"
@@ -402,6 +406,8 @@ class LlamaForCausalLM(nn.Module):
         if cfg.sequence_parallel and x.ndim >= 3:
             # leave SP for the logits: gather the sequence back
             x = constrain(x, P(UNC))
+        if self.mode == "prefill":
+            x = x[:, -1:]
         logits = ColumnParallelLinear(
             cfg.hidden_size, cfg.vocab_size, use_bias=False,
             dtype=cfg.dtype, param_dtype=cfg.param_dtype,

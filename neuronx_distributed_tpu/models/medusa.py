@@ -58,7 +58,9 @@ def medusa_head_loss(model, params, input_ids, labels):
 
 class MedusaForCausalLM(nn.Module):
     """Base Llama + ``num_medusa_heads`` decoding heads. Returns
-    ``(logits (B,S,V), medusa_logits (B,S,heads,V))``."""
+    ``(logits (B,S,V), medusa_logits (B,S,heads,V))``. In ``prefill`` mode the
+    head AND the Medusa heads are applied to the LAST position alone (S = 1):
+    the contract every causal LM here keeps, stated in ``models/__init__.py``."""
 
     config: LlamaConfig
     num_medusa_heads: int = 4
@@ -71,6 +73,8 @@ class MedusaForCausalLM(nn.Module):
         x = LlamaModel(cfg, self.attention_impl, self.mode, name="model")(
             input_ids, positions, attn_mask
         )
+        if self.mode == "prefill":
+            x = x[:, -1:]
         head = ColumnParallelLinear(
             cfg.hidden_size, cfg.vocab_size, use_bias=False,
             dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="lm_head",

@@ -229,6 +229,10 @@ class MixtralModel(nn.Module):
 
 
 class MixtralForCausalLM(nn.Module):
+    """In ``prefill`` mode the head is applied to the LAST position alone
+    (logits (B, 1, V)): the contract every causal LM here keeps, stated in
+    ``models/__init__.py``."""
+
     config: MixtralConfig
     attention_impl: str = "auto"
     mode: str = "train"
@@ -247,6 +251,8 @@ class MixtralForCausalLM(nn.Module):
         )
         if cfg.sequence_parallel and x.ndim >= 3:
             x = constrain(x, P(UNC))
+        if self.mode == "prefill":
+            x = x[:, -1:]
         logits = ColumnParallelLinear(
             cfg.hidden_size, cfg.vocab_size, use_bias=False,
             dtype=cfg.dtype, param_dtype=cfg.param_dtype,

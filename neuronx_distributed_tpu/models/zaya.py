@@ -399,8 +399,8 @@ class ZayaModel(nn.Module):
 
 class ZayaForCausalLM(nn.Module):
     """The head is the embedding table (tied). In ``prefill`` mode it is
-    applied to the LAST position alone (logits (B, 1, V)), as
-    ``AfmoeForCausalLM`` does and for its reason. ``chunk_stats``: the
+    applied to the LAST position alone (logits (B, 1, V)): the contract every
+    causal LM here keeps, stated in ``models/__init__.py``. ``chunk_stats``: the
     counters a layer that holds every expert sows each decode step
     (``modules/moe.MOE_CHUNK_STATS``)."""
 

@@ -944,6 +944,9 @@ class ServingEngine:
         # engines must never mint the same rid)
         self._next_rid = int(rid_base)
         self._prefill_fns: Dict[int, Callable] = {}
+        # bucket -> the rows of logits its prefill program computes, written
+        # while the program is traced (_prefill_fn): stat head_rows
+        self._prefill_head_rows: Dict[int, int] = {}
         self._state = self._fresh_slot_state()
         # disaggregated serving (ISSUE 14): True routes ALL prefill work to
         # external workers — step() keeps decoding but never self-admits;
@@ -2774,13 +2777,18 @@ class ServingEngine:
         fn = self._prefill_fns.get(padded_len)
         if fn is None:
             prefill = self._prefill_model
+            head_rows = self._prefill_head_rows
 
             @jax.jit
             def fn(params, ids, mask):
                 out, variables = prefill.apply(
                     params, ids, padding_mask=mask, mutable=["cache"]
                 )
-                return unwrap_logits(out)[0, -1], variables["cache"]
+                logits = unwrap_logits(out)
+                # runs when the bucket's program is traced, not when it
+                # runs: the shape is the program's own, and costs nothing
+                head_rows[ids.shape[1]] = logits.shape[1]
+                return logits[0, -1], variables["cache"]
 
             fn = self.programs.wrap(
                 f"prefill[{padded_len}]", self._comms_scoped(fn)
@@ -2882,6 +2890,11 @@ class ServingEngine:
                     logits, row_cache = self._prefill_fn(padded)(
                         self._params, jnp.asarray(ids), jnp.asarray(mask)
                     )
+                    # a program restored without a trace reports none
+                    if padded in self._prefill_head_rows:
+                        sp.set_metadata(
+                            head_rows=self._prefill_head_rows[padded]
+                        )
                 if self.draft_model is not None:
                     # the draft context ALWAYS full-prefills (a target
                     # prefix hit composes with it untouched: prefix

@@ -709,6 +709,25 @@ def test_deepseek_v2_lite_engine_programs_compile_and_fit(topo):
     prefill = lower_prefill().compile()
     assert _kernels(prefill.as_text())
     assert _fits(prefill, 15 * 1024**3)
+    _assert_head_on_one_row(prefill, 20992, int(config["model"]["vocab_size"]), 2.34)
+
+
+def _assert_head_on_one_row(compiled, padded, vocab, temp_gib):
+    """PR 49: a prefill applies its head to the last position alone, so its
+    program holds no array of ``padded x vocab`` elements (the parent held
+    ``bf16[1,20992,102400]``, 4.0 GiB, and its bitcast), and its temporaries
+    are what the described-v5e compile showed (``temp_gib``; the parent: 4.10)
+    and a tenth."""
+    logits = sorted({
+        made.group(0) for made in re.finditer(r"(?:bf16|f32)\[([\d,]+)\]", compiled.as_text())
+        if math.prod(map(int, made.group(1).split(","))) >= padded * vocab
+    })
+    assert not logits, f"the prefill program computes logits at every position: {logits}"
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(f"prefill[{padded}]: temporaries {temp / 2**30:.2f} GiB")
+    assert temp < 1.1 * temp_gib * 2**30, (
+        f"{temp / 2**30:.2f} GiB of temporaries, {temp_gib} when this bound was taken"
+    )
 
 
 def _keye_vl2(layers, seq):

@@ -7,6 +7,7 @@ the static argument and a program is compiled once a model and a shape."""
 import functools
 
 import jax
+from flax.core import meta
 
 
 @functools.partial(jax.jit, static_argnums=0)
@@ -21,3 +22,14 @@ def through_the_cache(model, variables, *args, **kw):
     ``model.apply(..., mutable=["cache"])``."""
     out, state = model.apply(variables, *args, mutable=["cache"], **kw)
     return out, state["cache"]
+
+
+def every_position(backbone, variables, *args, **kw):
+    """``(logits at EVERY position, cache)`` of a prefill. A ``*ForCausalLM``
+    in ``prefill`` mode applies its head to the last position alone
+    (``models/__init__.py``), so every position is read from the headless
+    ``backbone`` (the family's ``*Model`` in ``prefill`` mode) through the
+    head's kernel; ``variables`` are the causal LM's."""
+    variables = meta.unbox(variables)
+    (hidden, _), cache = through_the_cache(backbone, {"params": variables["params"]["model"]}, *args, **kw)
+    return hidden @ variables["params"]["lm_head"]["kernel"], {"model": cache}

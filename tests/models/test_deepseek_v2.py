@@ -13,12 +13,13 @@ from flax.core import meta
 
 from neuronx_distributed_tpu.models.deepseek_v2 import (
     DeepseekV2ForCausalLM,
+    DeepseekV2Model,
     YarnScaling,
     deepseek_v2_lite,
     tiny_deepseek_v2,
     yarn_frequencies,
 )
-from tests.models.jitted import forward, through_the_cache
+from tests.models.jitted import every_position, forward, through_the_cache
 
 
 @pytest.fixture(scope="module")
@@ -28,6 +29,13 @@ def tiny():
     ids = jax.random.randint(jax.random.PRNGKey(1), (2, 72), 1, cfg.vocab_size)
     params = jax.jit(model.init)(jax.random.PRNGKey(0), ids)
     return cfg, model, params, ids
+
+
+def prefill_logits(model, params, ids, **kw):
+    """Logits at EVERY position of a prefill: ``DeepseekV2ForCausalLM`` applies
+    its head to the last position alone."""
+    backbone = DeepseekV2Model(model.config, model.attention_impl, mode="prefill")
+    return every_position(backbone, params, ids, **kw)[0]
 
 
 def test_the_tiny_preset_holds_every_mechanism():
@@ -91,7 +99,9 @@ def test_absorbed_decode_matches_the_materialised_forward(tiny):
     full, _ = forward(model, params, ids)
     prefill, decode = model.clone(mode="prefill"), model.clone(mode="decode")
     (logits, _), cache = through_the_cache(prefill, params, ids[:, :40])
-    np.testing.assert_allclose(np.asarray(logits), np.asarray(full[:, :40]), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(logits[:, 0]), np.asarray(full[:, 39]), atol=2e-5)
+    # every position of the prefill: the headless model's hidden states through the head
+    np.testing.assert_allclose(np.asarray(prefill_logits(model, params, ids[:, :40])), np.asarray(full[:, :40]), atol=2e-5)
     for t in range(40, 72):   # crosses original_max_position_embeddings = 32 .. 72
         (logits, _), cache = through_the_cache(decode, {**params, "cache": cache}, ids[:, t:t + 1])
         np.testing.assert_allclose(np.asarray(logits[:, 0]), np.asarray(full[:, t]), atol=2e-5)
@@ -109,11 +119,10 @@ def test_cache_holds_one_latent_row_and_one_rope_key_a_token(tiny):
 
 def test_left_padded_prefill_equals_the_unpadded_one(tiny):
     cfg, model, params, ids = tiny
-    prefill = model.clone(mode="prefill")
-    (want, _), _ = through_the_cache(prefill, params, ids[:1, :24])
+    want = prefill_logits(model, params, ids[:1, :24])
     padded = jnp.concatenate([jnp.zeros((1, 8), ids.dtype), ids[:1, :24]], axis=1)
     mask = jnp.arange(32)[None] >= 8
-    (got, _), _ = through_the_cache(prefill, params, padded, padding_mask=mask)
+    got = prefill_logits(model, params, padded, padding_mask=mask)
     np.testing.assert_allclose(np.asarray(got[:, 8:]), np.asarray(want), atol=2e-5)
 
 

@@ -26,7 +26,7 @@ from neuronx_distributed_tpu.modules.attention import (
 )
 
 from perfbench.references.glm_moe_dsa import Reference
-from tests.models.jitted import forward, through_the_cache
+from tests.models.jitted import every_position, forward, through_the_cache
 
 ATOL = 3e-5
 
@@ -63,8 +63,7 @@ def prefill_logits(model, params, ids, **kw):
     """``(logits at EVERY position, cache)`` of a prefill: the served model
     applies its head to the last position alone."""
     backbone = GlmMoeDsaModel(model.config, model.attention_impl, mode="prefill")
-    (hidden, _), cache = through_the_cache(backbone, {"params": params["params"]["model"]}, ids, **kw)
-    return hidden @ params["params"]["lm_head"]["kernel"], {"model": cache}
+    return every_position(backbone, params, ids, **kw)
 
 
 def _decode_step(decode):

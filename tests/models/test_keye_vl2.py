@@ -27,7 +27,7 @@ from neuronx_distributed_tpu.modules.attention import (
 )
 
 from perfbench.references.keye_vl2 import Reference
-from tests.models.jitted import forward, through_the_cache
+from tests.models.jitted import every_position, forward, through_the_cache
 
 ATOL = 3e-5
 
@@ -61,8 +61,7 @@ def prefill_logits(model, params, ids, **kw):
     applies its head to the last position alone, so the backbone and the
     head's kernel."""
     backbone = KeyeVL2Model(model.config, model.attention_impl, mode="prefill")
-    (hidden, _), cache = through_the_cache(backbone, {"params": params["params"]["model"]}, ids, **kw)
-    return hidden @ params["params"]["lm_head"]["kernel"], {"model": cache}
+    return every_position(backbone, params, ids, **kw)
 
 
 @pytest.fixture(scope="module")

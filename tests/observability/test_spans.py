@@ -114,6 +114,8 @@ def test_stats_are_host_ints_and_rid_links_a_request(traced):
     # what the flash forward does with the prompt in its bucket: a bucket of one block here
     assert all((s["flash_steps"], s["flash_tiles"], s["flash_edge_tiles"], s["flash_needed_tiles"]) == (1, 1, 1, 1)
                for _, _, s, _ in prefills if s["reused"] == 0)
+    # the rows of logits a fresh prompt's prefill computes for the one its caller reads
+    assert all(s["head_rows"] == 1 for _, _, s, _ in prefills if s["reused"] == 0)
     assert all(s["ttft_us"] >= s["queue_wait_us"] >= 0 for s in fresh)
     assert any(s["decoding_slots"] > 0 for _, _, s, _ in prefills)
     # a chunk's executed steps and the tokens that reached a stream

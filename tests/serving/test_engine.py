@@ -440,3 +440,90 @@ def test_timeline_wiring(setup, tmp_path):
     emits = [e for e in events if e["name"] == "nxd.step.decode.emit"]
     assert sum(e["args"]["delivered"] for e in emits) == 3  # 4 - first
     assert "chunk_tokens" in names  # per-chunk counter track
+
+
+def _prefill_spans(tl, path):
+    import json
+
+    tl.save()
+    return [e["args"] for e in json.loads(path.read_text())["traceEvents"] if e["name"] == "nxd.step.prefill"]
+
+
+def test_building_an_engine_traces_the_model_zero_times(setup, tmp_path):
+    """Set-up pays for no trace of the model (PR 48 was refused for ONE
+    abstract trace in ``__init__``: 1.5-4.9 s of every serving cell's
+    ``setup_s``): from construction to the first ``submit`` the model's
+    ``__call__`` is never entered, and the engine has no resolved key that
+    would need it. The first prefill of a bucket traces its program once, and
+    stat ``head_rows`` of ``nxd.step.prefill`` is that trace's own output
+    shape: 1, on the bucket's first prefill and on every later one."""
+    from unittest import mock
+
+    from neuronx_distributed_tpu.utils.timeline import Timeline
+
+    cfg, model, params = setup
+    calls = []
+    real = LlamaForCausalLM.__call__
+
+    def counted(self, *args, **kw):
+        calls.append(self.mode)
+        return real(self, *args, **kw)
+
+    path = tmp_path / "trace.json"
+    tl = Timeline(str(path))
+    gcfg = GenerationConfig(max_new_tokens=3, temperature=0.0)
+    with mock.patch.object(LlamaForCausalLM, "__call__", counted):
+        engine = ServingEngine(model, params, num_slots=1, prefix_cache=None, timeline=tl)
+        assert set(engine.programs.resolved) == {"attention", "decode_attention", "paged_attention"}
+        engine.submit(np.asarray([5, 6, 7, 8, 9], np.int32), gcfg)
+        assert calls == []
+        engine.run()
+        assert calls.count("prefill") == 1     # the bucket's one trace, where head_rows is read
+        engine.submit(np.asarray([9, 8, 7, 6], np.int32), gcfg)
+        engine.run()
+        assert calls.count("prefill") == 1     # the same bucket: the compiled program, no trace
+    first, second = _prefill_spans(tl, path)
+    assert first["padded"] == second["padded"] and first["head_rows"] == second["head_rows"] == 1
+
+
+def test_head_rows_is_the_prefill_programs_own_shape_and_no_suffix_prefill_has_one(setup, tmp_path):
+    """``head_rows`` is read from what the bucket's program returns, not from
+    what ``models/__init__.py`` promises: a model that applies its head to
+    every position reads ``padded``, and its stream is the same (the engine
+    reads the last row either way). A suffix prefill runs the decode
+    program's chunk, every row of it, and reports no ``head_rows``."""
+    from flax import linen as nn
+
+    from neuronx_distributed_tpu.models.llama import LlamaModel
+    from neuronx_distributed_tpu.parallel.layers import ColumnParallelLinear
+    from neuronx_distributed_tpu.serving import PrefixCache
+    from neuronx_distributed_tpu.utils.timeline import Timeline
+
+    class EveryPosition(LlamaForCausalLM):
+        @nn.compact
+        def __call__(self, input_ids, positions=None, attn_mask=None, segment_ids=None, padding_mask=None):
+            cfg = self.config
+            x = LlamaModel(cfg, self.attention_impl, self.mode, name="model")(
+                input_ids, positions, attn_mask, segment_ids, padding_mask)
+            return ColumnParallelLinear(cfg.hidden_size, cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
+                                        param_dtype=cfg.param_dtype, name="lm_head")(x)
+
+    cfg, model, params = setup
+    gcfg = GenerationConfig(max_new_tokens=4, temperature=0.0)
+    shared = np.arange(1, 13, dtype=np.int32)
+    prompts = [np.concatenate([shared, tail]).astype(np.int32) for tail in ([20, 21], [30, 31, 32])]
+    streams = {}
+    for name, lm in (("one", model), ("every", EveryPosition(cfg, attention_impl="xla"))):
+        path = tmp_path / f"{name}.json"
+        tl = Timeline(str(path))
+        engine = ServingEngine(lm, params, num_slots=1, timeline=tl,
+                               prefix_cache=PrefixCache(max_entries=4, min_match=4))
+        streams[name] = []
+        for prompt in prompts:
+            streams[name].append(engine.submit(prompt, gcfg, key=jax.random.PRNGKey(2)))
+            engine.run()
+        full, suffix = _prefill_spans(tl, path)
+        assert (full["reused"], suffix["reused"] > 0) == (0, True)
+        assert full["head_rows"] == (1 if name == "one" else full["padded"])
+        assert "head_rows" not in suffix
+    assert [r.tokens for r in streams["one"]] == [r.tokens for r in streams["every"]]

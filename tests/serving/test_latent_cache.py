@@ -101,12 +101,15 @@ def test_a_kv_cache_reads_its_own_bytes():
 # digests were taken anew by the PR that put the chunk's sampler behind one
 # conditional on "some emitting row samples" (PR 36: ``sample_per_row`` takes
 # ``kept=~done`` and branches round the sort); that PR left both ``prefill``
-# digests as they are (a prefill samples nothing).
+# digests as they are (a prefill samples nothing). Both ``prefill`` digests were
+# taken anew by the PR that applies a prefill's head to the last position alone
+# in every model (PR 49: one slice in front of the head, logits of one row);
+# that PR left all four ``decode`` digests as they are.
 PARENT_PROGRAMS = {
-    "mixtral.prefill": "e0d71476f6d38b847454df722dd626fe5ecbef1e0635893022a29220a2e62e69",
+    "mixtral.prefill": "23b127972724c2b21a1bd6c517d8ab95382f6442d3dd03bcaeea89180ab57e5e",
     "mixtral.decode.gather": "3dd48b7bb2d393f00deb7568a1c77dc997d3743775d913a32f7f90d29d1f5f83",
     "mixtral.decode.fused": "3d166644826da274dc5f22bb35072967bd480d62c1aeb3903b898c56b6e9feb0",
-    "codegen.prefill": "2ea437e83f3d502ceb35b1052f326594d3425b7c623598af85f683630941d54a",
+    "codegen.prefill": "0b3585bbf36fd58d4a6ad7a7b57a066ce3bcbbacb5b96577efd67747f619d91b",
     "codegen.decode.gather": "26031da023b7a3167fd7da7206124f5efad09ea86c03cde92890e6da20ec99dd",
     "codegen.decode.fused": "414b5c2ac9b6016838e6863ceecf99ba70debfc0aefabf9d6c1270a2152ce969",
 }
@@ -189,9 +192,10 @@ def test_mixtral_and_codegen_programs_are_the_parents(program):
 # sparse decode kernel must leave the programs of a model with no such leaf
 # exactly as they were. The two ``decode`` digests: anew with PR 33's counters,
 # as Mixtral's above, and anew again with PR 36's sampler branch;
-# ``deepseek.prefill`` is the parent's.
+# ``deepseek.prefill``: anew with PR 49's head on the last position alone, as
+# Mixtral's and CodeGen's above; that PR left the two ``decode`` digests.
 DEEPSEEK_PARENT_PROGRAMS = {
-    "deepseek.prefill": "fead3bfa519cb976a59ab0e77801e17c93cdee964d75836884e38e694fa96c18",
+    "deepseek.prefill": "a08844df325f29bf805134bc6c1f4c7dce7b7738457908712deb9b7d3cd39fe6",
     "deepseek.decode.gather": "277cce6135272958e7f21a7520375984ab6fcf96160e46688f9604f6fdadcc09",
     "deepseek.decode.fused": "821885426fa3755ddf462db3230e800b98f18b5000050c355d391f46039854fe",
 }
