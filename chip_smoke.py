@@ -37,7 +37,7 @@ import math
 import os
 import sys
 import time
-from typing import Callable, Dict, List, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 KERNEL = "tpu_custom_call"
 
@@ -529,6 +529,10 @@ class DsaSize:
     kernel_cursor: int = 27000
     kernel_calls: int = 20
     kernel_prefill: int = 12288   # the prompt whose learned mask is built both ways (the tape's median)
+    # the prefill's byte-masked forward alone (frozen parent | new): the bucket, and the prompts
+    # left-padded to it (a full bucket; one 44% empty)
+    forward_bucket: int = 16384
+    forward_prompts: Tuple[int, ...] = (16384, 9175)
     kernel_tol: float = 2e-2      # |kernel - float32 jnp| on bf16 inputs: rounding of the output type
     # Limits of the comparison with the reference (readings: PERF.md section
     # 6). As MlaSize: ``logit_tol`` the worst position's largest |difference|
@@ -568,6 +572,9 @@ class GlmSize:
     # tape's quantiles + 320 decoded); each keeps min(held, topk)
     kernel_contexts: Tuple[int, ...] = (4500, 5600, 6700, 7800, 8900, 10400, 12600, 16700)
     kernel_calls: int = 20
+    # the prefill's byte-masked forward alone, as DsaSize's
+    forward_bucket: int = 16384
+    forward_prompts: Tuple[int, ...] = (16384, 9175)
     kernel_tol: float = 2e-2      # |kernel - float32 jnp| on bf16 inputs: rounding of the output type
     # Limits of the comparison with the reference, each between the system's
     # reading and a control's (PERF.md section 6, PR 32, has the readings):
@@ -1244,7 +1251,10 @@ def dsa_phase(size: DsaSize, seed: int) -> Dict[str, bool]:
     first the three decode kernels alone at the serve cell's shapes (8 slots
     whose contexts end at a shared cursor) against float32 ``jnp``, each with
     its time against its bytes, and the selection, ``top_k`` against a
-    bisection. Then a ``ServingEngine`` of 32,768-column slots: prompts of
+    bisection; and the prefill's byte-masked forward alone against PR 49's
+    kernel (:func:`grouped_forward_pair`; with ``--bundles DIR`` ``main`` first
+    prints a step's instruction bundles beside PR 49's from a described-v5e
+    compile). Then a ``ServingEngine`` of 32,768-column slots: prompts of
     24,576, 8,192 and 2,048 tokens are prefilled (the learned mask, the
     byte-masked flash kernel) and 32 tokens decoded through the paged INDEXED
     cache (index scores, ``top_k``, the sparse kernel). Against
@@ -1273,6 +1283,9 @@ def dsa_phase(size: DsaSize, seed: int) -> Dict[str, bool]:
         model = model.clone(config=dataclasses.replace(model.config, dtype=jnp.float32))
     cfg = model.config
     checks = dsa_kernels(size, published, seed, cfg.dtype)
+    checks.update(grouped_forward_pair(
+        "dsa", (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, cfg.head_dim), None, size.forward_bucket,
+        size.forward_prompts, size.kernel_calls, cfg.dtype, seed))
     params = jax.jit(model.init)(jax.random.PRNGKey(seed), jnp.zeros((1, 8), jnp.int32))
     jax.block_until_ready(params)
     engine = ServingEngine(model, params, num_slots=size.slots, kv_page_size=16)
@@ -1464,7 +1477,9 @@ def glm_phase(size: GlmSize, seed: int) -> Dict[str, bool]:
     benchmark configuration's cut (``perfbench/configs/glm-5-serve.json``: its
     depth, 8 of 256 experts held, a slice of the vocabulary): the sparse
     LATENT decode kernel alone at the serve cell's shapes against float32
-    ``jnp``, then a ``ServingEngine`` of 32,768-column slots: the prompts are
+    ``jnp`` and the prefill's byte-masked forward alone against PR 49's kernel
+    (:func:`grouped_forward_pair`; ``--bundles`` as ``dsa``), then a
+    ``ServingEngine`` of 32,768-column slots: the prompts are
     prefilled (the learned mask over materialised MLA) and 32 tokens decoded
     through the paged indexed LATENT cache (index scores, ``top_k``, the
     absorbed form over the selected rows). Against
@@ -1499,6 +1514,9 @@ def glm_phase(size: GlmSize, seed: int) -> Dict[str, bool]:
         model = model.clone(config=dataclasses.replace(model.config, dtype=jnp.float32))
     cfg = model.config
     checks = glm_kernel(size, published, seed, cfg.dtype)
+    checks.update(grouped_forward_pair(
+        "glm", (cfg.num_heads, cfg.num_heads, cfg.qk_head_dim, cfg.v_head_dim), None, size.forward_bucket,
+        size.forward_prompts, size.kernel_calls, cfg.dtype, seed))
     params = jax.jit(model.init)(jax.random.PRNGKey(seed), jnp.zeros((1, 8), jnp.int32))
     jax.block_until_ready(params)
     engine = ServingEngine(model, params, num_slots=size.slots, kv_page_size=16)
@@ -1685,6 +1703,10 @@ class TrinitySize:
     tail: int = 256
     new_tokens: int = 32
     pool_tokens: int = 24         # decoded before the window layer's pool is read
+    # a window layer's banded forward alone, as DsaSize's byte-masked one
+    forward_bucket: int = 16384
+    forward_prompts: Tuple[int, ...] = (16384, 9175)
+    kernel_calls: int = 20
     # Limits of the comparison with the reference, each between the system's
     # reading and a control's (PERF.md section 6, PR 39, has the readings):
     # the median position's largest |difference| of prefill logits over the
@@ -1709,7 +1731,9 @@ class TrinitySize:
 def trinity_phase(size: TrinitySize, seed: int) -> Dict[str, bool]:
     """Trinity-Large-Preview alone: window and full attention layers in one
     paged cache, against the plain reference, each mechanism held on one block
-    against its control. Not part of the default run. The language model at
+    against its control. Not part of the default run. First a window
+    layer's banded forward alone against PR 49's kernel
+    (:func:`grouped_forward_pair`; ``--bundles`` as ``dsa``); then the language model at
     its published widths on the benchmark configuration's cut
     (``perfbench/configs/trinity-large-serve.json``: a dense window layer and
     one period of three window layers and a full one, 32 of 256 experts held,
@@ -1748,6 +1772,9 @@ def trinity_phase(size: TrinitySize, seed: int) -> Dict[str, bool]:
         model = model.clone(config=dataclasses.replace(model.config, dtype=jnp.float32))
     cfg = model.config
     window, ps = cfg.sliding_window, size.page
+    forward = grouped_forward_pair(
+        "trinity", (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, cfg.head_dim), window, size.forward_bucket,
+        size.forward_prompts, size.kernel_calls, cfg.dtype, seed)
     params = jax.jit(model.init)(jax.random.PRNGKey(seed), jnp.zeros((1, 8), jnp.int32))
     jax.block_until_ready(params)
     plain = meta.unbox(params)
@@ -1931,6 +1958,7 @@ def trinity_phase(size: TrinitySize, seed: int) -> Dict[str, bool]:
         f"{size.logit_tol:g}), largest median {worst_median:.4f} ({size.typical_tol:g}); largest decoded-token gap "
         f"outside router near-ties {worst_gap:.4f} ({size.gap_tol:g})")
     return {
+        **forward,
         "trinity_matches_reference": ok,
         "trinity_window_full_routed_and_pool_alone_match_reference": blocks_ok,
         "trinity_frees_pages_behind_the_window_and_leaks_none": bool(freed_ok) and leak_free,
@@ -2261,6 +2289,144 @@ def walk_phase(size: WalkSize, seed: int) -> Dict[str, bool]:
     return checks
 
 
+# --- the grouped prefill forwards alone: the frozen parent | the kernel --------------
+
+
+def _grouped_forward(parent: bool, window: Optional[int]):
+    """A prefill's grouped forward as ``(q, k, v, valid[, keep]) -> out``: the
+    banded one under ``window``, else the byte-masked one; the tree's, or with
+    ``parent`` PR 49's (``tests/kernels/_group_fwd_parent.py``: the two kernels
+    before they took the flash forward's form, which knew no prompt's extent)."""
+    import importlib
+
+    if parent:
+        tests = os.path.join(os.path.dirname(os.path.abspath(__file__)), "tests", "kernels")
+        if tests not in sys.path:
+            sys.path.insert(0, tests)
+    kernels = importlib.import_module(    # the package exports the function under the module's name
+        "_group_fwd_parent" if parent else "neuronx_distributed_tpu.kernels.flash_attention")
+    if window is not None:
+        return lambda q, k, v, valid: kernels.banded_flash_attention(q, k, v, window, valid)
+    if parent:
+        return lambda q, k, v, valid, keep: kernels.masked_flash_attention(q, k, v, keep)
+    return lambda q, k, v, valid, keep: kernels.masked_flash_attention(q, k, v, keep, valid)
+
+
+def grouped_forward_pair(tag: str, heads: Tuple[int, int, int, int], window: Optional[int], bucket: int,
+                         prompts: Sequence[int], calls: int, dtype, seed: int) -> Dict[str, bool]:
+    """A prefill's grouped forward alone at ``heads`` = (q heads, kv heads,
+    d_qk, d_v), one layer of one prefill a call: the byte-masked forward
+    (``window`` None; a random causal mask of the prompt's keys) or a window
+    layer's banded one, each prompt of ``prompts`` left-padded to ``bucket``.
+    The kernel against PR 49's on the same inputs: content rows equal bit for
+    bit, query blocks wholly past the prompt's end zero, and both forms' ms a
+    call beside the tiles the plan counts."""
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    fa = importlib.import_module("neuronx_distributed_tpu.kernels.flash_attention")
+    h, hkv, d, dv = heads
+    keys = jax.random.split(jax.random.PRNGKey(seed), 4)
+    q = jax.random.normal(keys[0], (1, bucket, h, d), dtype)
+    k = jax.random.normal(keys[1], (1, bucket, hkv, d), dtype)
+    v = jax.random.normal(keys[2], (1, bucket, hkv, dv), dtype)
+    equal, zeros = True, True
+    for prompt in prompts:
+        pad = bucket - prompt
+        valid = jnp.arange(bucket)[None] >= pad
+        args = (q, k, v, valid)
+        if window is None:
+            rows = jnp.arange(bucket)
+            args += ((jax.random.bernoulli(keys[3], 0.125, (1, bucket, bucket)) & (rows[:, None] >= rows[None])
+                      & valid[:, None, :]).astype(jnp.int8),)
+        forms = {"PR 49's": _grouped_forward(True, window), "the kernel": _grouped_forward(False, window)}
+        was, new = (np.asarray(jax.jit(fn)(*args).astype(jnp.float32))[0] for fn in forms.values())
+        bq = fa._group_blocks(bucket, h // hkv)[0]
+        past = pad // bq * bq                          # the rows of query blocks wholly of padding
+        equal &= bool((new[pad:] == was[pad:]).all()) and bool(np.abs(was[pad:]).max() > 0)
+        zeros &= not new[:past].any()
+        del was, new
+        ms = [_median_call_ms(fn, args, calls) for fn in forms.values()]
+        log(f"{tag}: the prefill forward alone, {h}/{hkv} heads of {d}/{dv}"
+            + (f", window {window}" if window else ", byte mask") + f", {prompt} of {bucket}: "
+            + ", ".join(f"{name} {t:.3f} ms a call" for name, t in zip(forms, ms)) + f" (x{ms[1] / ms[0]:.3f}); "
+            f"grid steps, bodies, edge bodies, needed a KV head = {fa.group_tile_plan(bucket, prompt, h // hkv, window)}; "
+            f"content rows {'equal' if equal else 'DIFFER'} bit for bit, {past} rows of blocks past the prompt's end "
+            f"{'zero' if zeros else 'NOT zero'}")
+    return {f"{tag}_prefill_forward_equals_pr49s_bit_for_bit": equal,
+            f"{tag}_prefill_forward_blocks_past_the_prompt_are_zero": zeros}
+
+
+def _group_listing_compile(heads, window, bucket: int, parent: bool) -> None:
+    """Compile a grouped forward at ``heads`` for a described v5e (a process of
+    its own, as :func:`_walk_listing_compile`)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from neuronx_distributed_tpu.kernels import backend
+
+    backend.INTERPRET, backend.on_tpu = False, lambda: True
+    jax.config.update("jax_enable_compilation_cache", False)
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    one = SingleDeviceSharding(topo.devices[0])
+    s = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(shape, dtype, sharding=one)   # noqa: E731
+    h, hkv, d, dv = heads
+    forward = _grouped_forward(parent, window)
+    args = (s((1, bucket, h, d)), s((1, bucket, hkv, d)), s((1, bucket, hkv, dv)), s((1, bucket), jnp.bool_))
+    if window is None:
+        args += (s((1, bucket, bucket), jnp.int8),)
+
+    def group_step(*a):
+        return forward(*a)
+
+    jax.jit(group_step).lower(*args).compile()
+
+
+def _listed_regions(directory: str, code: str, name: str) -> List[int]:
+    """Instruction bundles of each predicated region of the custom call named
+    after ``name`` in the final schedule ``code`` leaves (a process of its own
+    that compiles for a described v5e with the listing dumped to
+    ``directory``), in program order: each region ends at its fallthrough
+    (``PF:``), and the address counts bundles."""
+    import glob
+    import re
+    import subprocess
+
+    os.makedirs(directory, exist_ok=True)
+    env = dict(os.environ, JAX_PLATFORMS="cpu", TPU_LOG_DIR="disabled",
+               LIBTPU_INIT_ARGS=f"--xla_jf_dump_to={directory} --xla_jf_dump_llo_text=true")
+    subprocess.run([sys.executable, "-c", code], env=env, cwd=os.path.dirname(os.path.abspath(__file__)),
+                   stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL, timeout=600)
+    listings = [f for f in glob.glob(os.path.join(directory, f"*{name}*final_bundles.txt"))
+                if "schedule-analysis" not in f]
+    if not listings:
+        raise RuntimeError(f"the compile left no listing of {name} under {directory}")
+    address = re.compile(r"^\s*(0x[0-9a-f]+) PF: ")
+    ends = [int(m.group(1), 16) for m in map(address.match, open(max(listings, key=os.path.getmtime))) if m]
+    return [b - a for a, b in zip(ends, ends[1:])]
+
+
+def group_body_bundles(directory: str, heads, window, bucket: int, parent: bool = False) -> List[int]:
+    """Instruction bundles of a grouped forward's bodies (one step: a KV
+    head's whole group on one tile) in its final schedule, compiled for a
+    described v5e (no chip): the regions between the kernel's init and its
+    finish, in program order (the byte-masked body; the interior and the edge
+    body under a window; PR 49's one body with ``parent``). A kernel over the
+    scoped VMEM limit leaves no listing: that raises."""
+    code = f"import chip_smoke; chip_smoke._group_listing_compile({tuple(heads)!r}, {window!r}, {bucket}, {parent})"
+    # the listing's regions end: ..., the bodies, the finish, the step's epilogue
+    return _listed_regions(os.path.join(directory, "parent" if parent else "kernel"), code, "group_step")[:-2]
+
+
+# what ``--bundles`` compiles with ``--only dsa | glm | trinity``: the cell's head geometry and window
+GROUP_BUNDLES = {"dsa": ((32, 4, 128, 128), None), "glm": ((64, 64, 256, 256), None), "trinity": ((48, 8, 128, 128), 4096)}
+
+
 def _flash_forward(q, k, v, seg, residuals):
     """The forward under test on (B, H, S, D) arrays: ``kernels/flash_attention
     ._flash_fwd`` with the blocks ``flash_attention`` picks."""
@@ -2318,24 +2484,8 @@ def flash_body_bundles(directory: str, size: FlashSize = FlashSize()) -> Tuple[i
     listing of the custom call named after ``flash_step``, the two largest of
     the kernel's four predicated regions (init, interior, edge and finish, in
     that order)."""
-    import glob
-    import re
-    import subprocess
-
-    os.makedirs(directory, exist_ok=True)
-    env = dict(os.environ, JAX_PLATFORMS="cpu", TPU_LOG_DIR="disabled",
-               LIBTPU_INIT_ARGS=f"--xla_jf_dump_to={directory} --xla_jf_dump_llo_text=true")
     code = f"import chip_smoke; chip_smoke._flash_listing_compile({size.shapes[size.bundles_shape]!r})"
-    subprocess.run([sys.executable, "-c", code], env=env, cwd=os.path.dirname(os.path.abspath(__file__)),
-                   stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL, timeout=600)
-    listings = [f for f in glob.glob(os.path.join(directory, "*flash_step*final_bundles.txt"))
-                if "schedule-analysis" not in f]
-    if not listings:
-        raise RuntimeError(f"the compile left no listing of flash_step under {directory}")
-    # each predicated region ends at its fallthrough (``PF:``); the address counts bundles
-    address = re.compile(r"^\s*(0x[0-9a-f]+) PF: ")
-    ends = [int(m.group(1), 16) for m in map(address.match, open(max(listings, key=os.path.getmtime))) if m]
-    spans = [b - a for a, b in zip(ends, ends[1:])]
+    spans = _listed_regions(directory, code, "flash_step")
     interior, edge = [n for n in spans if n in sorted(spans)[-2:]]     # in program order
     return interior, edge
 
@@ -2575,22 +2725,30 @@ def parse_args(argv=None):
                    help="one chip: every default phase (default), or one phase alone. "
                         + " ".join(f"{name}: {PHASES[name].help}." for name in only))
     p.add_argument("--bundles", metavar="DIR", default=None,
-                   help="with --only walk or flash: first compile the kernel for a described v5e with the "
-                        "compiler's listing dumped to DIR, and print the instruction bundles of one block's "
-                        "body (walk) or of the interior and the edge body (flash)")
+                   help="with --only walk, flash, dsa, glm or trinity: first compile the kernel for a described v5e "
+                        "with the compiler's listing dumped to DIR, and print the instruction bundles of one block's "
+                        "body (walk), of the interior and the edge body (flash) or of the prefill's grouped forward's "
+                        "bodies beside PR 49's (dsa, glm, trinity)")
     return p.parse_args(argv)
 
 
 def main(argv=None) -> int:
     args = parse_args(argv)
     if args.bundles is not None:
-        if args.only not in ("walk", "flash"):
-            print("chip_smoke: --bundles goes with --only walk or --only flash", file=sys.stderr)
+        if args.only not in ("walk", "flash", *GROUP_BUNDLES):
+            print("chip_smoke: --bundles goes with --only walk, flash, dsa, glm or trinity", file=sys.stderr)
             return 2
         # before this process touches JAX: the compile's process loads the TPU's library itself
         if args.only == "walk":
             log(f"walk: one block's body is {walk_block_bundles(args.bundles)} instruction bundles in the "
                 f"described-v5e listing under {args.bundles}")
+        elif args.only in GROUP_BUNDLES:
+            heads, window = GROUP_BUNDLES[args.only]
+            bucket = PHASES[args.only].size.forward_bucket
+            log(f"{args.only}: a step of the prefill's grouped forward ({heads[0]}/{heads[1]} heads of {heads[2]}/"
+                f"{heads[3]}, a {bucket} bucket) is {group_body_bundles(args.bundles, heads, window, bucket)} "
+                f"instruction bundles a body, PR 49's {group_body_bundles(args.bundles, heads, window, bucket, True)}, "
+                f"in the described-v5e listings under {args.bundles}")
         else:
             interior, edge = flash_body_bundles(args.bundles)
             log(f"flash: the interior body is {interior} instruction bundles and the edge body {edge} in the "

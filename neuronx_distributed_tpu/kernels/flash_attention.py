@@ -8,7 +8,9 @@ Structure (canonical TPU flash attention):
     carrying the online-softmax state (running max m, sum l, and the output
     accumulator) in VMEM scratch across them; each pair is classed empty,
     interior or edge and the kernel acts on the class (see "forward" below);
-    the backward kernels keep the rectangular grid (B, H, nQ, nK);
+    the backward kernels keep the rectangular grid (B, H, nQ, nK); the two
+    serving-only forwards (a learned byte mask; a window) are one grouped
+    kernel of the same form, grid (B, Hkv, pairs) ("grouped forwards" below);
   * causal skipping: K blocks strictly above the diagonal are skipped (the
     forward of a self-attention call does not visit them);
   * forward also emits LSE (= m + log l) per row, the residual the backward
@@ -132,33 +134,47 @@ def _lanes(x, n: int):
     return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
 
 
-def _tile_pairs(nq: int, nk: int, block_q: int, block_k: int, triangle: bool):
+def _band_first(i, block_q: int, block_k: int, window: int, xp=jnp):
+    """The first key block query block ``i`` reads under a window of the last
+    ``window`` keys: that of its first row's lowest visible column."""
+    return xp.maximum(i * block_q - window + 1, 0) // block_k
+
+
+def _tile_pairs(nq: int, nk: int, block_q: int, block_k: int, triangle: bool,
+                window: Optional[int] = None):
     """The pairs the grid walks, row-major: ``(qi, kj)`` int32 numpy arrays.
-    ``triangle``: a q block's k blocks end at its diagonal."""
+    ``triangle``: a q block's k blocks end at its diagonal; ``window``: they
+    start at its band's first block (:func:`_band_first`), not at 0."""
     last = _diag_block(np.arange(nq), block_q, block_k) if triangle else np.full(nq, nk - 1)
     last = np.minimum(last, nk - 1)
-    if int((last + 1).sum()) > 2 ** 20:
+    first = np.zeros(nq, np.int64) if window is None else _band_first(np.arange(nq), block_q, block_k, window, np)
+    if int((last - first + 1).sum()) > 2 ** 20:
         raise ValueError(
             f"flash attention: {nq} x {nk} blocks of {block_q} x {block_k} are more pairs than the "
             "kernel's prefetched tables hold; pad the sequence to a multiple of 128")
-    qi = np.repeat(np.arange(nq), last + 1)
-    kj = np.concatenate([np.arange(n + 1) for n in last])
+    qi = np.repeat(np.arange(nq), last - first + 1)
+    kj = np.concatenate([np.arange(a, z + 1) for a, z in zip(first, last)])
     return qi.astype(np.int32), kj.astype(np.int32)
 
 
 def _tile_classes(xp, qi, kj, block_q: int, block_k: int, causal: bool, q_off, k_off,
-                  ranges, residuals: bool):
+                  ranges, residuals: bool, window: Optional[int] = None):
     """The class of each pair ``(qi[t], kj[t])``: ``(T,)``, or ``(B, T)`` under
     segment ``ranges`` = per-block ``(qmin, qmax, kmin, kmax)``, each ``(B,
-    blocks)``. ``xp`` is ``numpy`` or ``jax.numpy``: one rule for the kernel's
-    table and for :func:`flash_tile_plan`'s count. ``residuals``: padded rows
-    are kept as rows (the backward reads their ``lse``), so padding meets
-    padding like any other segment."""
+    blocks)``. ``xp`` is ``numpy`` or ``jax.numpy``: one rule for the kernels'
+    tables and for the host's counts (:func:`flash_tile_plan`,
+    :func:`group_tile_plan`). ``residuals``: padded rows are kept as rows (the
+    backward reads their ``lse``), so padding meets padding like any other
+    segment. ``window``: a row reads its last ``window`` keys only (the band
+    ``row - window < key <= row``)."""
     live, cut = True, False
     if causal:
         row0, col0 = q_off + qi * block_q, k_off + kj * block_k
         live = col0 <= row0 + (block_q - 1)          # some key at or before some row
         cut = col0 + (block_k - 1) > row0            # some key after some row
+        if window is not None:
+            live = live & (col0 + (block_k - 1) > row0 - window)       # some key inside some row's band
+            cut = cut | (col0 <= row0 + (block_q - 1) - window)        # some key behind some row's band
     if ranges is not None:
         qmn, qmx, kmn, kmx = ranges
         qmn, qmx, kmn, kmx = qmn[:, qi], qmx[:, qi], kmn[:, kj], kmx[:, kj]
@@ -169,16 +185,51 @@ def _tile_classes(xp, qi, kj, block_q: int, block_k: int, causal: bool, q_off, k
     return xp.where(live, xp.where(cut, _EDGE, _INTERIOR), _EMPTY).astype(xp.int32)
 
 
-def _tile_plan(classes: jax.Array, kj) -> jax.Array:
+_Q_SHIFT = 16      # where a plan entry holds the q block to fetch (the grouped forward's)
+
+
+def _tile_plan(classes: jax.Array, kj, qi=None) -> jax.Array:
     """``block_to_fetch * 4 + class`` for each (batch row, pair): a live pair
     fetches its own k block, an empty one names the last live pair's (or, in
-    front of the first, the next one's): resident, so nothing moves."""
+    front of the first, the next one's): resident, so nothing moves. With
+    ``qi`` the q block to fetch rides above bit ``_Q_SHIFT`` by the same rule
+    (the grouped forward: a q block wholly past the prompt's end is not read)."""
     n = classes.shape[-1]
     t = jnp.arange(n, dtype=jnp.int32)
     live = classes != _EMPTY
     before = jax.lax.cummax(jnp.where(live, t, -1), axis=classes.ndim - 1)
     after = jax.lax.cummin(jnp.where(live, t, n - 1), axis=classes.ndim - 1, reverse=True)
-    return jnp.asarray(kj)[jnp.where(before >= 0, before, after)] * 4 + classes
+    source = jnp.where(before >= 0, before, after)
+    plan = jnp.asarray(kj)[source] * 4 + classes
+    return plan if qi is None else plan + (jnp.asarray(qi)[source] << _Q_SHIFT)
+
+
+def _prompt_classes(xp, valid, qi, kj, block_q: int, block_k: int, window: Optional[int] = None,
+                    learned: bool = False):
+    """``(B, T)`` classes of a forward-only causal call's pairs from where the
+    prompts' content lies (``valid`` (B, S), nonzero at content: segment 0
+    against padding's -1). ``learned``: a byte mask decides inside every live
+    pair, so none is interior."""
+    seg = xp.where(valid != 0, 0, -1)
+    ranges = _seg_block_ranges(seg, block_q, xp) + _seg_block_ranges(seg, block_k, xp)
+    classes = _tile_classes(xp, qi, kj, block_q, block_k, True, 0, 0, ranges, residuals=False, window=window)
+    return xp.minimum(classes * 2, _EDGE) if learned else classes
+
+
+def _prompt_tile_plan(seq: int, n_valid: int, block_q: int, block_k: int,
+                      window: Optional[int] = None, learned: bool = False) -> Tuple[int, int, int, int]:
+    """``(grid_steps, bodies, edge_bodies, needed)`` a head for ONE left-padded
+    prompt of ``n_valid`` tokens in a bucket of ``seq``, by the kernels' own
+    rule. ``needed`` counts the pairs that hold a content row and a content key
+    it may read (at or before it and, under ``window``, inside its band)."""
+    pad = seq - n_valid
+    qi, kj = _tile_pairs(seq // block_q, seq // block_k, block_q, block_k, triangle=True, window=window)
+    classes = _prompt_classes(np, (np.arange(seq) >= pad)[None], qi, kj, block_q, block_k, window, learned)[0]
+    row0, col0 = np.maximum(qi * block_q, pad), np.maximum(kj * block_k, pad)
+    row1, col1 = (qi + 1) * block_q - 1, (kj + 1) * block_k - 1
+    needed = (row0 <= row1) & (col0 <= col1) & (col0 <= row1) & (row0 - col1 < (window or seq))
+    return (int(qi.size), int((classes != _EMPTY).sum()), int((classes == _EDGE).sum()),
+            int(needed.sum()))
 
 
 def flash_tile_plan(seq: int, n_valid: int, block_q: Optional[int] = None,
@@ -189,15 +240,18 @@ def flash_tile_plan(seq: int, n_valid: int, block_q: Optional[int] = None,
     needed)`` a head. ``needed`` counts the pairs that hold a content row and a
     content key at or before it; ``1 - bodies / grid_steps`` is how often a
     step runs nothing, ``bodies / needed`` what is still multiplied in vain."""
-    bq, bk = block_q or _pick_block(seq), block_k or _pick_block(seq)
-    pad = seq - n_valid
-    qi, kj = _tile_pairs(seq // bq, seq // bk, bq, bk, triangle=True)
-    seg = np.where(np.arange(seq) < pad, -1, 0)[None]
-    ranges = _seg_block_ranges(seg, bq, np) + _seg_block_ranges(seg, bk, np)
-    classes = _tile_classes(np, qi, kj, bq, bk, True, 0, 0, ranges, residuals=False)[0]
-    needed = ((qi + 1) * bq > pad) & ((kj + 1) * bk > pad)
-    return (int(qi.size), int((classes != _EMPTY).sum()), int((classes == _EDGE).sum()),
-            int(needed.sum()))
+    return _prompt_tile_plan(seq, n_valid, block_q or _pick_block(seq), block_k or _pick_block(seq))
+
+
+def group_tile_plan(seq: int, n_valid: int, group: int, window: Optional[int] = None,
+                    block_q: Optional[int] = None, block_k: Optional[int] = None) -> Tuple[int, int, int, int]:
+    """:func:`flash_tile_plan` for the two grouped prefill forwards, a KV head
+    (one step serves its ``group`` query heads), blocks as they pick them:
+    :func:`banded_flash_attention` under ``window``, else
+    :func:`masked_flash_attention` (whose every body reads its byte tile: all
+    are edge bodies)."""
+    bq, bk = _group_blocks(seq, group, block_q, block_k)
+    return _prompt_tile_plan(seq, n_valid, bq, bk, window, learned=window is None)
 
 
 def _fwd_kernel(qi_ref, kj_ref, plan_ref, q_off_ref, k_off_ref, qseg_ref, kseg_ref,
@@ -801,176 +855,200 @@ def flash_attention(
     return jnp.swapaxes(out, 1, 2)
 
 
-# --- masked forward: causal attention under a learned one-byte mask -------------
+# --- grouped forwards: a prefill under a learned byte mask, or inside a window ---
 #
-# Prefill of a sparse-attention model (an indexer picks, per query row, the
-# ``topk`` keys it may read: ``modules/attention.sparse_prefill_attention``).
-# The mask arrives as ONE byte a (query, key) pair, causality and padding
-# already folded in, and is read tile by tile beside K and V: every causal
-# tile is multiplied and masked (dense work, sparse result). One grid step
-# serves the whole GQA group of a kv head, so a K, V and mask tile leave HBM
-# once for its ``G`` query heads. Forward only: serving.
+# The two prefill forwards of serving models whose attention is not plain
+# causal: one grid step serves the whole GQA group of a KV head, so a K and V
+# tile leave HBM once for its ``G`` query heads; operands stay in their storage
+# type (the MXU's own), products and the accumulator in float32; forward only.
+# They are ONE kernel in the form of ``_flash_fwd``: the grid's last axis walks
+# the static list of (q block, k block) pairs the geometry allows (the causal
+# triangle; under a window the band ``row - window < key <= row``, a q block's
+# ``(block_q + W - 2) // block_k + 2`` key blocks at most), each pair classed
+# per batch row by :func:`_tile_classes` from where the prompt's content lies
+# (``valid``), the class and the blocks to fetch in one prefetched int32:
+#
+#   empty     outside the triangle or band, or no content row, or no content
+#             key: no body runs and no q, k, v or mask tile is fetched.
+#   interior  (window) wholly inside the band and the content: the unmasked body.
+#   edge      (window) the diagonal, the band's lower edge or the prompt's end
+#             cuts it: the mask is built from indices and ``valid``.
+#             (learned mask) every live pair: the byte tile IS the mask.
+#
+# Nobody reads a row past the prompt's end: a q block with no content row is
+# not visited and returns zeros. In a q block the prompt ends inside, a padded
+# row returns what its own mask row says over the visited tiles (the learned
+# mask: the bytes the caller wrote for it; the window: the content keys inside
+# its band, zeros if none); the content rows beside it pay nothing for it.
+# The running max and sum are lane-replicated, as the forward's above.
+
+_GROUP_ROWS = 3072      # group x block_q rows a step may hold
 
 
-def _masked_fwd_kernel(keep_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
-                       acc_scr, *, scale, block_q, block_k, num_k_blocks, group):
-    i = pl.program_id(2)  # q block
-    j = pl.program_id(3)  # k block
+def _group_blocks(s: int, group: int, block_q: Optional[int] = None, block_k: Optional[int] = None):
+    """The blocks a grouped forward tiles ``s`` rows by: 512 keys, and 512
+    query rows or the power of two the group leaves of ``_GROUP_ROWS``. A
+    step holds ``group x block_q`` rows of two lane-replicated statistics, of
+    the accumulator and of the double-buffered q and o blocks: at heads of 128
+    2.5 KiB a row, 7.5 MiB at Trinity's group of 6 and 512 rows beside ~6 MiB
+    of the body's own tiles; at Keye's group of 8 512 rows are 18.6 MiB, over
+    the 16 MiB of scoped VMEM the compiler grants (described-v5e compile:
+    PERF.md section 6, PR 50), so it tiles by 256."""
+    if block_q is None:
+        block_q = max(128, min(512, 1 << ((_GROUP_ROWS // group).bit_length() - 1)))
+    return _pick_block(s, block_q), _pick_block(s, block_k or 512)
 
-    @pl.when(j == 0)
+
+def _group_fwd_kernel(qi_ref, kj_ref, plan_ref, mask_ref, q_ref, k_ref, v_ref, o_ref,
+                      m_scr, l_scr, acc_scr, *, scale, block_q, block_k, group, window, learned):
+    t = pl.program_id(2)
+    i, j = qi_ref[t], kj_ref[t]                      # q block, k block
+    kind = plan_ref[pl.program_id(0), t] & 3
+
+    @pl.when(j == (0 if window is None else _band_first(i, block_q, block_k, window)))
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    @pl.when(j * block_k <= i * block_q + block_q - 1)   # tiles above the diagonal keep nothing
-    def _body():
-        keep = keep_ref[0] != 0                        # (BQ, BK)
-        k = k_ref[0, 0]                                # (BK, D), storage type: the MXU's own
+    def accumulate(keep):
+        k = k_ref[0, 0]                                # (BK, D), storage type
         v = v_ref[0, 0]
         for g in range(group):
             s = jax.lax.dot_general(
                 q_ref[0, 0, g], k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
             ) * scale                                  # (BQ, BK)
-            s = jnp.where(keep, s, NEG_INF)
-            m_prev = m_scr[g]
+            if keep is not None:
+                s = jnp.where(keep, s, NEG_INF)
+            m_prev = m_scr[g]                          # (BQ, 128), lane-replicated
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            # a row with every key masked so far keeps m = -inf: a finite 0
+            # makes exp(s - ref) underflow to 0; a masked key's does anyway
             ref = jnp.where(m_new > NEG_INF / 2, m_new, 0.0)
-            p = jnp.where(keep, jnp.exp(s - ref), 0.0)
+            p = jnp.exp(s - _lanes(ref, block_k))
             alpha = jnp.exp(m_prev - ref)
             l_scr[g] = l_scr[g] * alpha + jnp.sum(p, axis=1, keepdims=True)
-            acc_scr[g] = acc_scr[g] * alpha + jax.lax.dot_general(
+            acc_scr[g] = acc_scr[g] * _lanes(alpha, acc_scr.shape[2]) + jax.lax.dot_general(
                 p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
             m_scr[g] = m_new
 
-    @pl.when(j == num_k_blocks - 1)
+    if learned:
+        @pl.when(kind == _EDGE)
+        def _masked():
+            accumulate(mask_ref[0] != 0)               # (BQ, BK)
+    else:
+        @pl.when(kind == _INTERIOR)
+        def _interior():
+            accumulate(None)
+
+        @pl.when(kind == _EDGE)
+        def _edge():
+            rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0) + i * block_q
+            cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1) + j * block_k
+            accumulate((rows >= cols) & (cols > rows - window) & (mask_ref[0] != 0))   # (1, BK): content keys
+
+    @pl.when(j == _diag_block(i, block_q, block_k))
     def _finish():
-        o_ref[0, 0] = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[:] / jnp.maximum(l_scr[:, :, :1], 1e-30)).astype(o_ref.dtype)
 
 
-def masked_flash_attention(q, k, v, keep, block_q: int = 512, block_k: int = 512,
-                           interpret: Optional[bool] = None):
+def _group_fwd(q, k, v, keep, valid, window, block_q, block_k, interpret):
+    """The grouped forward on q (B, S, H, D), k/v (B, S, Hkv, D): under the
+    learned byte mask ``keep`` (B, S, S), or (``keep`` None) inside ``window``.
+    ``valid`` (B, S) nonzero at content tokens, or None: all are."""
+    b, s, h, d = q.shape
+    hkv, dv = k.shape[2], v.shape[3]
+    group = h // hkv
+    learned = keep is not None
+    bq, bk = _group_blocks(s, group, block_q, block_k)
+    qi, kj = _tile_pairs(s // bq, s // bk, bq, bk, triangle=True, window=window)
+    if s // bk >= 1 << (_Q_SHIFT - 2):
+        raise ValueError(f"{s} keys in blocks of {bk} are more blocks than a plan entry names")
+    valid = jnp.ones((b, s), jnp.int32) if valid is None else valid.astype(jnp.int32)
+    plan = _tile_plan(_prompt_classes(jnp, valid, qi, kj, bq, bk, window, learned), kj, qi)
+    qt = jnp.swapaxes(q, 1, 2).reshape(b, hkv, group, s, d)
+    kt, vt = jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)
+
+    def fetch(plan_ref, b_, t):                        # the (q block, k block) step t names
+        entry = plan_ref[b_, t]
+        return entry >> _Q_SHIFT, (entry >> 2) & ((1 << (_Q_SHIFT - 2)) - 1)
+
+    def q_block(b_, h_, t, qi_ref, kj_ref, plan_ref):
+        return (b_, h_, 0, fetch(plan_ref, b_, t)[0], 0)
+
+    def kv_block(b_, h_, t, qi_ref, kj_ref, plan_ref):
+        return (b_, h_, fetch(plan_ref, b_, t)[1], 0)
+
+    if learned:
+        mask, mask_spec = keep, pl.BlockSpec(
+            (1, bq, bk), lambda b_, h_, t, qi_ref, kj_ref, plan_ref: (b_, *fetch(plan_ref, b_, t)))
+    else:                                              # the keys' validity, a row a batch row
+        mask, mask_spec = valid[:, None, :], pl.BlockSpec(
+            (1, 1, bk), lambda b_, h_, t, qi_ref, kj_ref, plan_ref: (b_, 0, fetch(plan_ref, b_, t)[1]))
+
+    out = pl.pallas_call(
+        functools.partial(
+            _group_fwd_kernel, scale=1.0 / (d ** 0.5), block_q=bq, block_k=bk,
+            group=group, window=window, learned=learned,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b, hkv, qi.size),
+            in_specs=[
+                mask_spec,
+                pl.BlockSpec((1, 1, group, bq, d), q_block),
+                pl.BlockSpec((1, 1, bk, d), kv_block),
+                pl.BlockSpec((1, 1, bk, dv), kv_block),
+            ],
+            out_specs=pl.BlockSpec((1, 1, group, bq, dv), lambda b_, h_, t, qi_ref, *_: (b_, h_, 0, qi_ref[t], 0)),
+            scratch_shapes=[
+                pltpu.VMEM((group, bq, _LANES), jnp.float32),
+                pltpu.VMEM((group, bq, _LANES), jnp.float32),
+                pltpu.VMEM((group, bq, dv), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, group, s, dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        ),
+        interpret=interpret_mode(interpret),
+    )(jnp.asarray(qi), jnp.asarray(kj), plan, mask, qt, kt, vt)
+    return jnp.swapaxes(out.reshape(b, h, s, dv), 1, 2)
+
+
+def masked_flash_attention(q, k, v, keep, valid=None, block_q: Optional[int] = None,
+                           block_k: Optional[int] = None, interpret: Optional[bool] = None):
     """Attention of q (B, S, H, D) over k/v (B, S, Hkv, D) where ``keep`` (B,
     S, S) int8 is nonzero (rows queries, columns keys; CAUSAL: nothing above
     the diagonal may be kept, those tiles are not read). Softmax in float32
     over the kept keys of ``q . k / sqrt(D)``; a row that keeps nothing
-    returns zeros. (B, S, H, Dv)."""
-    b, s, h, d = q.shape
-    hkv, dv = k.shape[2], v.shape[3]
-    group = h // hkv
-    bq, bk = _pick_block(s, block_q), _pick_block(s, block_k)
-    nq, nk = s // bq, s // bk
-    qt = jnp.swapaxes(q, 1, 2).reshape(b, hkv, group, s, d)
-    kt, vt = jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)
+    returns zeros. (B, S, H, Dv).
 
-    def last(i):  # the last k block a q block reads: later steps name it again, and fetch nothing
-        return _diag_block(i, bq, bk)
-
-    out = pl.pallas_call(
-        functools.partial(
-            _masked_fwd_kernel, scale=1.0 / (d ** 0.5), block_q=bq, block_k=bk,
-            num_k_blocks=nk, group=group,
-        ),
-        grid=(b, hkv, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, bq, bk), lambda b_, h_, i, j: (b_, i, jnp.minimum(j, last(i)))),
-            pl.BlockSpec((1, 1, group, bq, d), lambda b_, h_, i, j: (b_, h_, 0, i, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b_, h_, i, j: (b_, h_, jnp.minimum(j, last(i)), 0)),
-            pl.BlockSpec((1, 1, bk, dv), lambda b_, h_, i, j: (b_, h_, jnp.minimum(j, last(i)), 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, group, bq, dv), lambda b_, h_, i, j: (b_, h_, 0, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, group, s, dv), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((group, bq, 1), jnp.float32),
-            pltpu.VMEM((group, bq, 1), jnp.float32),
-            pltpu.VMEM((group, bq, dv), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret_mode(interpret),
-    )(keep, qt, kt, vt)
-    return jnp.swapaxes(out.reshape(b, h, s, dv), 1, 2)
-
-
-# --- banded forward: causal attention inside a window of the last W keys ---------
-#
-# Prefill of a WINDOW layer (a query reads its last ``W`` keys: ``i - W < j <=
-# i``) of a model whose stack mixes window and full layers; the full layers
-# run :func:`flash_attention`. The masked forward's form (one grid step serves
-# the whole GQA group of a kv head; operands in their storage type, float32
-# accumulation; forward only: serving) with the mask built from indices inside
-# the kernel, and the key axis of the grid cut to the band: a query block
-# meets the ``(block_q + W - 2) // block_k + 2`` key blocks its band can
-# overlap and no other, so a tile wholly outside the band is neither fetched
-# nor multiplied. Padded keys (``kv_valid``) are masked; a row that keeps
-# nothing returns zeros.
-
-
-def _band_first(i, block_q: int, block_k: int, window: int):
-    """The first key block query block ``i`` reads: that of its first row's
-    lowest visible column."""
-    return jnp.maximum(i * block_q - window + 1, 0) // block_k
-
-
-def _banded_fwd_kernel(valid_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                       scale, block_q, block_k, steps, group, window, use_valid):
-    i = pl.program_id(2)  # q block
-    j = pl.program_id(3)  # step along this q block's band
-
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    jj = _band_first(i, block_q, block_k, window) + j       # the key block
-
-    @pl.when(jj * block_k <= i * block_q + block_q - 1)     # past the diagonal: nothing
-    def _body():
-        rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0) + i * block_q
-        cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1) + jj * block_k
-        keep = (rows >= cols) & (cols > rows - window)
-        if use_valid:
-            keep = keep & (valid_ref[0] != 0)              # (1, BK)
-        k = k_ref[0, 0]                                    # (BK, D), storage type
-        v = v_ref[0, 0]
-        for g in range(group):
-            s = jax.lax.dot_general(
-                q_ref[0, 0, g], k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale                                      # (BQ, BK)
-            s = jnp.where(keep, s, NEG_INF)
-            m_prev = m_scr[g]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            ref = jnp.where(m_new > NEG_INF / 2, m_new, 0.0)
-            p = jnp.where(keep, jnp.exp(s - ref), 0.0)
-            alpha = jnp.exp(m_prev - ref)
-            l_scr[g] = l_scr[g] * alpha + jnp.sum(p, axis=1, keepdims=True)
-            acc_scr[g] = acc_scr[g] * alpha + jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            m_scr[g] = m_new
-
-    @pl.when(j == steps - 1)
-    def _finish():
-        o_ref[0, 0] = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)).astype(o_ref.dtype)
+    ``valid`` (B, S), nonzero at the prompt's content tokens (``keep`` holds
+    nothing at a padded key: the caller folded that in): a tile with no
+    content row or no content key is neither read nor multiplied, so a query
+    block wholly past the prompt's end returns zeros; a padded row in the
+    block the prompt ends inside returns what its ``keep`` row says."""
+    return _group_fwd(q, k, v, keep, valid, None, block_q, block_k, interpret)
 
 
 def banded_flash_attention(q, k, v, window: int, kv_valid=None,
-                           block_q: int = 512, block_k: int = 512,
+                           block_q: Optional[int] = None, block_k: Optional[int] = None,
                            interpret: Optional[bool] = None):
     """Causal attention of q (B, S, H, D) over k/v (B, S, Hkv, D) in which
     query ``i`` reads keys ``i - window < j <= i``, ``kv_valid`` (B, S)
-    nonzero at keys that are not padding. Softmax in float32 over the kept
-    keys of ``q . k / sqrt(D)``; (B, S, H, Dv)."""
-    b, length, h, d = q.shape
-    hkv, dv = k.shape[2], v.shape[3]
-    group = h // hkv
+    nonzero at keys that are not padding: a prompt's content, whose rows are
+    the rows anybody reads. Softmax in float32 over the kept keys of ``q . k /
+    sqrt(D)``; (B, S, H, Dv). A tile outside the band, or with no content row
+    or key, is neither fetched nor multiplied (a query block wholly past the
+    prompt's end returns zeros); only a tile the diagonal, the band's lower
+    edge or the prompt's end can cut is masked. A padded row in the block the
+    prompt ends inside returns the softmax over the content keys in its band,
+    zeros if it has none."""
+    length = q.shape[1]
     # a length that is no multiple of a tile (the engine's buckets are; its
     # exact-length fallback at the row's end is not) is padded on the right:
     # no query reads a key after it, and the padded rows are cut off again
@@ -979,46 +1057,7 @@ def banded_flash_attention(q, k, v, window: int, kv_valid=None,
         pad = lambda a: jnp.pad(a, ((0, 0), (0, s - length)) + ((0, 0),) * (a.ndim - 2))  # noqa: E731
         q, k, v = pad(q), pad(k), pad(v)
         kv_valid = None if kv_valid is None else pad(kv_valid)
-    bq, bk = _pick_block(s, block_q), _pick_block(s, block_k)
-    nq, nk = s // bq, s // bk
-    steps = min(nk, (bq + window - 2) // bk + 2)
-    qt = jnp.swapaxes(q, 1, 2).reshape(b, hkv, group, s, d)
-    kt, vt = jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)
-    use_valid = kv_valid is not None
-    valid = (kv_valid.astype(jnp.int32) if use_valid else jnp.ones((1, bk), jnp.int32))[:, None, :]
-
-    def key_block(i, j):
-        # the band's blocks, then the last one again: a repeated block is not fetched
-        return jnp.minimum(_band_first(i, bq, bk, window) + j, _diag_block(i, bq, bk))
-
-    valid_map = (
-        (lambda b_, h_, i, j: (b_, 0, key_block(i, j))) if use_valid
-        else (lambda b_, h_, i, j: (0, 0, 0)))
-    out = pl.pallas_call(
-        functools.partial(
-            _banded_fwd_kernel, scale=1.0 / (d ** 0.5), block_q=bq, block_k=bk,
-            steps=steps, group=group, window=window, use_valid=use_valid,
-        ),
-        grid=(b, hkv, nq, steps),
-        in_specs=[
-            pl.BlockSpec((1, 1, bk), valid_map),
-            pl.BlockSpec((1, 1, group, bq, d), lambda b_, h_, i, j: (b_, h_, 0, i, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b_, h_, i, j: (b_, h_, key_block(i, j), 0)),
-            pl.BlockSpec((1, 1, bk, dv), lambda b_, h_, i, j: (b_, h_, key_block(i, j), 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, group, bq, dv), lambda b_, h_, i, j: (b_, h_, 0, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, hkv, group, s, dv), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((group, bq, 1), jnp.float32),
-            pltpu.VMEM((group, bq, 1), jnp.float32),
-            pltpu.VMEM((group, bq, dv), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
-        ),
-        interpret=interpret_mode(interpret),
-    )(valid, qt, kt, vt)
-    return jnp.swapaxes(out.reshape(b, h, s, dv), 1, 2)[:, :length]
+    return _group_fwd(q, k, v, None, kv_valid, window, block_q, block_k, interpret)[:, :length]
 
 
 # --- the learned mask of a prefill: index scores, thresholds, one byte a pair -----

@@ -1313,7 +1313,8 @@ def window_prefill_attention(q, k, v, window: Optional[int] = None, impl: str = 
     :func:`attention_op`: the flash forward every other model's prefill runs.
     A window layer runs, on the TPU, the banded flash forward (``kernels/
     flash_attention.banded_flash_attention``: tiles wholly outside the band
-    are skipped, not masked; forward only), elsewhere and for ``impl="xla"``
+    or the prompt are skipped, only the tiles their edges cut are masked;
+    forward only), elsewhere and for ``impl="xla"``
     (training differentiates) the float32 einsum under the mask built from
     indices. A prompt's padding is on ONE side, so its valid keys are adjacent
     and a window of columns is a window of tokens."""
@@ -1568,8 +1569,9 @@ def sparse_prefill_attention(q, k, v, q_idx, w_idx, k_idx, topk: int,
     scores and thresholds are taken a block of query rows at a time (on the
     TPU inside one kernel, the scores never leaving VMEM). On the TPU the
     flash kernel reads the byte mask tile by tile
-    (``kernels/flash_attention.masked_flash_attention``: dense work, sparse
-    result); elsewhere the float32 einsum."""
+    (``kernels/flash_attention.masked_flash_attention``: dense work over the
+    prompt's own causal tiles, sparse result; a padded row reads as zeros
+    there); elsewhere the float32 einsum."""
     b, s = q.shape[0], q.shape[1]
     valid = jnp.ones((b, s), jnp.bool_) if mask is None else mask.astype(jnp.bool_)
     pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
@@ -1595,7 +1597,8 @@ def sparse_prefill_attention(q, k, v, q_idx, w_idx, k_idx, topk: int,
                 masked_flash_attention,
             )
 
-            return masked_flash_attention(q, k, v, keep)
+            # the prompt's extent too: the kernel does the prompt's work, not the bucket's
+            return masked_flash_attention(q, k, v, keep, valid)
         return _query_chunks(
             lambda qc, kc: _masked_gqa_attention(qc, k, v, kc), (q, keep), s)
 

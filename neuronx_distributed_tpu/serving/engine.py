@@ -245,7 +245,7 @@ from neuronx_distributed_tpu.inference.spec_decode import (
 )
 from neuronx_distributed_tpu.inference.utils import unwrap_logits
 from neuronx_distributed_tpu.kernels import backend
-from neuronx_distributed_tpu.kernels.flash_attention import flash_tile_plan
+from neuronx_distributed_tpu.kernels.flash_attention import flash_tile_plan, group_tile_plan
 from neuronx_distributed_tpu.modules.attention import (
     cache_bytes_per_token_layer,
     cache_fingerprint,
@@ -351,21 +351,33 @@ def _bucket(p: int, max_seq_len: int, remaining: int, floor: int = 8) -> int:
     return b
 
 
-def _flash_tiles(padded: int, p: int) -> Dict[str, int]:
-    """What the flash forward does with a fresh ``p``-token prompt in its
-    bucket, a head a layer that runs it, for the prefill span
-    (``kernels/flash_attention.flash_tile_plan``): ``flash_steps`` the grid
-    walks, ``flash_tiles`` whose body runs (``1 - flash_tiles / flash_steps``:
-    how often a step runs nothing), ``flash_edge_tiles`` of them in the masked
-    body, ``flash_needed_tiles`` that hold a content row and a content key.
-    Nothing for a suffix prefill (it runs the decode path's attention) or for
-    an exact-length fallback over 512 tokens, which the kernel tiles in blocks
-    of a few rows."""
+def _flash_tiles(padded: int, p: int, config=None) -> Dict[str, int]:
+    """What the prefill's attention kernels do with a fresh ``p``-token prompt
+    in its bucket, for the prefill span, by the kernels' own rule
+    (``kernels/flash_attention.flash_tile_plan``, a head a layer that runs the
+    flash forward): ``flash_steps`` the grid walks, ``flash_tiles`` whose body
+    runs (``1 - flash_tiles / flash_steps``: how often a step runs nothing),
+    ``flash_edge_tiles`` of them in the masked body, ``flash_needed_tiles``
+    that hold a content row and a content key. The same four as ``masked_*``
+    for a model with an indexer (``config.index_topk``: its prefills run the
+    byte-masked forward) and as ``band_*`` for one with window layers
+    (``config.kv_cache_window``), a KV head a layer
+    (``group_tile_plan``). Nothing for a suffix prefill (it runs the decode
+    path's attention) or for an exact-length fallback over 512 tokens, which
+    the kernels tile in blocks of a few rows."""
     if p <= 0 or (padded > 512 and padded % 128):
         return {}
-    steps, bodies, edge, needed = flash_tile_plan(padded, p)
-    return {"flash_steps": steps, "flash_tiles": bodies, "flash_edge_tiles": edge,
-            "flash_needed_tiles": needed}
+    plans = {"flash": flash_tile_plan(padded, p)}
+    heads = getattr(config, "num_heads", None)
+    if heads:
+        group = heads // getattr(config, "num_kv_heads", heads)
+        if getattr(config, "index_topk", None) is not None:
+            plans["masked"] = group_tile_plan(padded, p, group)
+        window = getattr(config, "kv_cache_window", None)
+        if window is not None:
+            plans["band"] = group_tile_plan(padded, p, group, window)
+    return {f"{kernel}_{stat}": n for kernel, plan in plans.items()
+            for stat, n in zip(("steps", "tiles", "edge_tiles", "needed_tiles"), plan)}
 
 
 def _suffix_bucket(s: int, padded: int, max_seq_len: int) -> int:
@@ -2848,7 +2860,8 @@ class ServingEngine:
         plan = self._plan_prefix_reuse(ctx, p, padded)
         reused = plan[1] if plan is not None else 0
         self.tracer.step(req.rid, "prefix_lookup", args={"matched": reused})
-        sp.set_metadata(padded=padded, reused=reused, **_flash_tiles(padded, p if plan is None else 0))
+        sp.set_metadata(padded=padded, reused=reused, **_flash_tiles(
+            padded, p if plan is None else 0, getattr(self.model, "config", None)))
         call = self._prefill_calls
         self._prefill_calls += 1
         t0 = self._clock()

@@ -8,12 +8,19 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _group_fwd_parent
 from _flash_fwd_parent import _flash_fwd as _parent_flash_fwd
 
 from neuronx_distributed_tpu.kernels.flash_attention import (
     _flash_fwd,
+    _tile_classes,
+    _tile_pairs,
+    _tile_plan,
+    banded_flash_attention,
     flash_attention,
     flash_tile_plan,
+    group_tile_plan,
+    masked_flash_attention,
 )
 from neuronx_distributed_tpu.models.llama import _xla_attention
 
@@ -446,3 +453,175 @@ def test_flash_tile_plan_defaults_to_the_blocks_the_kernel_picks():
     assert flash_tile_plan(16384, 9003) == flash_tile_plan(16384, 9003, 512, 512)
     steps, bodies, edge, needed = flash_tile_plan(16384, 9003)
     assert (steps, bodies, needed) == (528, 171, 171) and edge == 18 + 17   # the diagonal + the padding's edge column
+
+
+# --- the grouped forwards: a learned byte mask, a window (the form since PR 50) --
+#
+# The reference is the two kernels as they stood before (``_group_fwd_parent.py``:
+# a rectangular grid, column statistics, every tile of the bucket multiplied and
+# masked twice). A content row meets the same key tiles in the same order with the
+# same arithmetic, so it is the parent's BIT FOR BIT, here as on the chip; a query
+# block wholly past the prompt's end is not visited and reads zeros.
+
+# the serving cells' HEAD geometry (q heads, kv heads, d_qk, d_v, window), a KV head or two
+_GROUPED = {
+    "glm5": (2, 2, 256, 256, None),          # group 1, 256 / 256
+    "keye": (8, 1, 128, 128, None),          # group 8
+    "trinity": (6, 1, 128, 128, 384),        # group 6, a window of a tile and a half
+}
+# (bucket, tile, the batch rows' (first content token, one past the last))
+_PROMPTS = {
+    "ends_inside_a_tile": (1024, 256, [(324, 1024)]),
+    "ends_on_a_tiles_edge": (1024, 256, [(256, 1024)]),
+    "fills_the_bucket": (1024, 256, [(0, 1024)]),
+    "two_rows_of_different_lengths": (1024, 128, [(724, 1024), (1, 1024)]),
+    "a_row_that_keeps_nothing": (512, 128, [(200, 512), (512, 512)]),
+    "padded_on_the_right": (1024, 256, [(0, 700)]),
+}
+
+
+def _grouped_case(geometry, prompt, dtype=jnp.bfloat16):
+    h, hkv, d, dv, window = _GROUPED[geometry]
+    s, tile, extents = _PROMPTS[prompt]
+    b = len(extents)
+    ks = jax.random.split(jax.random.PRNGKey(len(geometry) * 31 + len(prompt)), 3)
+    q = jax.random.normal(ks[0], (b, s, h, d), dtype)
+    k, v = jax.random.normal(ks[1], (b, s, hkv, d), dtype), jax.random.normal(ks[2], (b, s, hkv, dv), dtype)
+    rows = np.arange(s)
+    valid = np.stack([(rows >= lo) & (rows < hi) for lo, hi in extents])
+    return (q, k, v), valid, window, tile
+
+
+@pytest.mark.parametrize("prompt", sorted(_PROMPTS))
+@pytest.mark.parametrize("geometry", sorted(_GROUPED))
+def test_grouped_forward_equals_the_parents_on_content_rows(geometry, prompt):
+    (q, k, v), valid, window, tile = _grouped_case(geometry, prompt)
+    b, s = valid.shape
+    blocks = dict(block_q=tile, block_k=tile)
+    if window is None:
+        rng = np.random.default_rng(s + tile)
+        keep = (rng.random((b, s, s)) < 0.2) & np.tril(np.ones((s, s), bool)) & valid[:, None, :]
+        content = np.flatnonzero(valid[0])
+        keep[0, content[len(content) // 2]] = False           # a content row that keeps nothing: zeros
+        keep = jnp.asarray(keep, jnp.int8)
+        want = _group_fwd_parent.masked_flash_attention(q, k, v, keep, **blocks)
+        got = masked_flash_attention(q, k, v, keep, jnp.asarray(valid), **blocks)
+        np.testing.assert_array_equal(np.asarray(masked_flash_attention(q, k, v, keep, **blocks), np.float32),
+                                      np.asarray(want, np.float32))     # no extent given: every row the parent's
+    else:
+        want = _group_fwd_parent.banded_flash_attention(q, k, v, window, jnp.asarray(valid), **blocks)
+        got = banded_flash_attention(q, k, v, window, jnp.asarray(valid), **blocks)
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert np.abs(want[valid]).max() > 0.1
+    np.testing.assert_array_equal(got[valid], want[valid])
+    # nobody reads a row past the prompt's end: a query block wholly of padding is zeros ...
+    blocks_past = ~valid.reshape(b, s // tile, tile).any(-1)
+    assert not got.reshape(b, s // tile, tile, -1)[blocks_past].any()
+    # ... and a padded row beside content rows is what its own mask row says: the parent's
+    np.testing.assert_array_equal(got.reshape(b, s // tile, tile, -1)[~blocks_past],
+                                  want.reshape(b, s // tile, tile, -1)[~blocks_past])
+    if prompt == "a_row_that_keeps_nothing":
+        assert not got[1].any()
+        if window is None:
+            assert not got[0, np.flatnonzero(valid[0])[valid[0].sum() // 2]].any()
+
+
+def test_grouped_forward_in_float32_equals_the_parents_to_the_last_bit():
+    """float32 operands (the tests' models): as the flash forward above, the
+    CPU's compiler may contract a multiply-add in one form and not the other."""
+    (q, k, v), valid, window, tile = _grouped_case("trinity", "ends_inside_a_tile", jnp.float32)
+    want = _group_fwd_parent.banded_flash_attention(q, k, v, window, jnp.asarray(valid), block_q=tile, block_k=tile)
+    got = banded_flash_attention(q, k, v, window, jnp.asarray(valid), block_q=tile, block_k=tile)
+    _same(np.asarray(got)[valid], np.asarray(want)[valid])
+
+
+def _brute_force_group_tiles(seq, n_valid, bq, bk, window):
+    """A left-padded prompt's tiles element by element: the pairs of the
+    triangle or band, and those that hold a content row and a content key the
+    row may read."""
+    pad = seq - n_valid
+    steps = needed = 0
+    for i in range(seq // bq):
+        for j in range(seq // bk):
+            rows, cols = np.arange(bq)[:, None] + i * bq, np.arange(bk)[None] + j * bk
+            may = (rows >= cols) & (cols > rows - (window or seq))
+            steps += bool(may.any())
+            needed += bool((may & (rows >= pad) & (cols >= pad)).any())
+    return steps, needed
+
+
+# (bucket, prompt) of cells 5, 6 and 7's eight-prompt blocks (PERF.md section 4), and the group and window
+_GROUP_CELLS = {
+    "keye_longdocs_closed": (8, None, [(8192, 5700), (16384, 8862), (16384, 12765), (32768, 24600)]),
+    "glm5_agentdocs_closed": (1, None, [(4096, 4096), (8192, 5793), (8192, 8192), (16384, 8862), (16384, 10460),
+                                        (16384, 12765), (16384, 16384)]),
+    "trinity_mixedctx_closed": (6, 4096, [(4096, 2799), (8192, 4402), (8192, 7338), (16384, 9146),
+                                          (16384, 11534), (16384, 16384)]),
+    "small_blocks": (2, 100, [(256, 100), (256, 128), (384, 70), (64, 1)]),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_GROUP_CELLS))
+def test_group_tile_plan_against_a_brute_force_count(cell):
+    group, window, prompts = _GROUP_CELLS[cell]
+    blocks = (64, 32) if cell == "small_blocks" else (256 if group == 8 else 512, 512)
+    for seq, n in prompts:
+        steps, bodies, edge, needed = (group_tile_plan(seq, n, group, window, *blocks) if cell == "small_blocks"
+                                       else group_tile_plan(seq, n, group, window))
+        assert (steps, needed) == _brute_force_group_tiles(seq, n, *blocks, window), (seq, n)
+        assert bodies == needed                              # visited = needed: nothing multiplied in vain
+        assert (edge == bodies) if window is None else (0 < edge <= bodies)
+    if cell == "glm5_agentdocs_closed":                      # ISSUE 50's count: 171, 231 and 325 of 528
+        assert [group_tile_plan(16384, n, 1)[1] for n in (8862, 10460, 12765)] == [171, 231, 325]
+        assert group_tile_plan(16384, 16384, 1)[:2] == (528, 528)
+    if cell == "trinity_mixedctx_closed":                    # nine key blocks a query block at most, not ten
+        assert group_tile_plan(16384, 16384, 6, 4096)[0] == 36 + 24 * 9
+
+
+def test_the_kernels_table_is_the_plans_count():
+    """The (batch row, pair) table the kernel prefetches, built by ``jnp`` from
+    the mask, against the host's count for the same prompts."""
+    seq, bq, bk, window, pads = 1024, 128, 64, 200, (0, 300, 1023)
+    valid = np.stack([np.arange(seq) >= p for p in pads])
+    seg = jnp.where(jnp.asarray(valid), 0, -1)
+    qi, kj = _tile_pairs(seq // bq, seq // bk, bq, bk, True, window)
+    ranges = tuple(r for blk in (bq, bk) for r in (seg.reshape(3, -1, blk).min(-1), seg.reshape(3, -1, blk).max(-1)))
+    classes = np.asarray(_tile_classes(jnp, qi, kj, bq, bk, True, 0, 0, ranges, False, window))
+    for row, pad in zip(classes, pads):
+        assert (row.size, (row != 0).sum(), (row == 2).sum()) == group_tile_plan(seq, seq - pad, 1, window, bq, bk)[:3]
+    plan = np.asarray(_tile_plan(jnp.asarray(classes), kj, qi))
+    live = classes != 0
+    assert (plan[live] >> 16 == np.broadcast_to(qi, plan.shape)[live]).all()        # a live pair fetches its own blocks
+    assert ((plan[live] >> 2) & 0x3FFF == np.broadcast_to(kj, plan.shape)[live]).all()
+    moves = (np.diff(plan >> 2, axis=1) != 0).sum(1)                                # an empty pair moves nothing
+    assert (moves <= live.sum(1)).all()
+
+
+@pytest.mark.parametrize("nq,nk,bq,bk,triangle", [(32, 32, 512, 512, True), (41, 41, 512, 512, True),
+                                                  (8, 16, 128, 64, True), (4, 6, 64, 64, False)])
+def test_the_flash_forwards_tables_are_the_parents(nq, nk, bq, bk, triangle):
+    """``_tile_pairs`` / ``_tile_classes`` / ``_tile_plan`` learned two
+    geometries; what ``_flash_fwd`` asks of them is what PR 45 wrote down."""
+    qi, kj = _tile_pairs(nq, nk, bq, bk, triangle)
+    last = np.minimum((np.arange(nq) * bq + bq - 1) // bk if triangle else nk - 1, nk - 1) + np.zeros(nq, int)
+    assert (qi == np.repeat(np.arange(nq), last + 1)).all()
+    assert (kj == np.concatenate([np.arange(n + 1) for n in last])).all()
+    seg = np.where(np.arange(nq * bq) < 700, -1, np.arange(nq * bq) // 1500)[None]
+    kseg = seg[:, :nk * bk] if nk * bk <= nq * bq else np.pad(seg, ((0, 0), (0, nk * bk - nq * bq)), constant_values=7)
+    ranges = (seg.reshape(1, nq, bq).min(-1), seg.reshape(1, nq, bq).max(-1),
+              kseg.reshape(1, nk, bk).min(-1), kseg.reshape(1, nk, bk).max(-1))
+    for residuals in (False, True):
+        got = _tile_classes(np, qi, kj, bq, bk, triangle, 0, 0, ranges, residuals)
+        qmn, qmx, kmn, kmx = (r[:, x] for r, x in zip(ranges, (qi, qi, kj, kj)))
+        live = (qmx >= kmn) & (qmn <= kmx)
+        cut = ~((qmn == qmx) & (kmn == kmx) & (qmn == kmn))
+        if triangle:
+            live = live & (kj * bk <= qi * bq + bq - 1)
+            cut = cut | (kj * bk + bk - 1 > qi * bq)
+        if not residuals:
+            live = live & (qmx >= 0) & (kmx >= 0)
+        want = np.where(live, np.where(cut, 2, 1), 0)
+        np.testing.assert_array_equal(got, want)
+        plan = np.asarray(_tile_plan(jnp.asarray(got), kj))
+        assert (plan & 3 == got).all() and (plan >> 2 < nk).all()
+        assert ((plan >> 2)[got != 0] == np.broadcast_to(kj, got.shape)[got != 0]).all()

@@ -245,8 +245,8 @@ def test_sparse_decode_kernels_compile_at_keye_geometry(topo):
     assert not re.search(r"bf16\[%d,%d,8,%d\]\S* copy\(" % (pages, page, D), text), "the K/V pool leaf is copied whole"
     seq = 24576
     text = _compiled_text(
-        lambda q, k, v, m: masked_flash_attention(q, k, v, m),
-        s((1, seq, H, D)), s((1, seq, 4, D)), s((1, seq, 4, D)), s((1, seq, seq), jnp.int8))
+        lambda q, k, v, m, ok: masked_flash_attention(q, k, v, m, ok),
+        s((1, seq, H, D)), s((1, seq, 4, D)), s((1, seq, 4, D)), s((1, seq, seq), jnp.int8), s((1, seq), jnp.bool_))
     assert _kernels(text)
     text = _compiled_text(
         lambda q, w, k, ok: sparse_keep_mask_kernel(q, w, k, ok, keep),
@@ -960,6 +960,56 @@ def test_banded_flash_prefill_compiles_at_trinity_geometry(topo, seq, window):
         lambda q, k, v, ok: window_prefill_attention(q, k, v, window, impl="flash", mask=ok),
         s((1, seq, 48, 128)), s((1, seq, 8, 128)), s((1, seq, 8, 128)), s((1, seq), jnp.bool_))
     assert _kernels(text)
+
+
+# The grouped prefill forwards (PR 50) at cells 5, 6 and 7's head geometry and
+# window (``chip_smoke.GROUP_BUNDLES``, by phase): the buckets, and the
+# instruction bundles of ONE step's body in PR 49's kernels at the 16,384 bucket
+# (a KV head's group on a 512 x 512 tile; ``tests/kernels/_group_fwd_parent.py``
+# through ``chip_smoke.group_body_bundles(..., parent=True)`` on this
+# installation: the frozen kernels do not change, so neither do these)
+_GROUPED_CELLS = {
+    "keye_longdocs_closed": ("dsa", (16384, 32768), 15177),
+    "glm5_agentdocs_closed": ("glm", (16384,), 2543),
+    "trinity_mixedctx_closed": ("trinity", (16384,), 11672),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_GROUPED_CELLS))
+def test_grouped_prefill_forward_compiles_at_the_cells_geometry(topo, cell, tmp_path):
+    """The byte-masked and the banded forward in the flash forward's form: each
+    compiles inside the scoped VMEM limit the compiler grants (no
+    ``vmem_limit_bytes`` asked: Keye's group of 8 tiles its queries by 256 for
+    it), its grid is the list of the triangle's or the band's pairs with the
+    plan a (batch row, pair) table, and a step's bodies hold fewer instruction
+    bundles a query row than PR 49's one body did."""
+    from neuronx_distributed_tpu.kernels.flash_attention import (
+        _group_blocks,
+        banded_flash_attention,
+        group_tile_plan,
+        masked_flash_attention,
+    )
+
+    import chip_smoke
+
+    phase, buckets, parents_body = _GROUPED_CELLS[cell]
+    heads, window = chip_smoke.GROUP_BUNDLES[phase]
+    h, hkv, d, dv = heads
+    s = _one_chip(topo)
+    for seq in buckets:
+        qkv = (s((1, seq, h, d)), s((1, seq, hkv, d)), s((1, seq, hkv, dv)))
+        if window is None:
+            text = _compiled_text(lambda q, k, v, m, ok: masked_flash_attention(q, k, v, m, ok),
+                                  *qkv, s((1, seq, seq), jnp.int8), s((1, seq), jnp.bool_))
+        else:
+            text = _compiled_text(lambda q, k, v, ok: banded_flash_attention(q, k, v, window, ok),
+                                  *qkv, s((1, seq), jnp.bool_))
+        steps = group_tile_plan(seq, seq, h // hkv, window)[0]
+        assert _kernels(text) and f"s32[1,{steps}]" in text
+    bq = _group_blocks(buckets[0], h // hkv)[0]
+    bodies = chip_smoke.group_body_bundles(str(tmp_path), heads, window, buckets[0])
+    assert len(bodies) == (1 if window is None else 2)
+    assert all(0 < body * (512 // bq) < 0.85 * parents_body for body in bodies), (bodies, bq)
 
 
 @pytest.mark.slow

@@ -225,11 +225,33 @@ def test_a_length_that_is_no_multiple_of_a_tile_is_padded_and_cut(length):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-6)
 
 
+@pytest.mark.parametrize("length,valid_from", [(100, None), (203, None), (203, 50), (1100, 301)])
+def test_a_length_that_is_no_multiple_of_a_tile_equals_the_parents(length, valid_from):
+    """The exact-length fallback against the forward as it stood (``_group_fwd_
+    parent.py``): the keys and rows the kernel pads on the right are one more
+    case of "past the prompt's end", and the rows that exist are the parent's
+    bit for bit (1,100 tokens run as 1,152: nine tiles of 128)."""
+    import _group_fwd_parent
+
+    keys = jax.random.split(jax.random.PRNGKey(7), 3)
+    q = jax.random.normal(keys[0], (1, length, 6, 32), jnp.bfloat16)
+    k = jax.random.normal(keys[1], (1, length, 1, 32), jnp.bfloat16)
+    v = jax.random.normal(keys[2], (1, length, 1, 32), jnp.bfloat16)
+    valid = None if valid_from is None else jnp.arange(length)[None] >= valid_from
+    want = _group_fwd_parent.banded_flash_attention(q, k, v, 160, valid, block_q=128, block_k=128)
+    got = banded_flash_attention(q, k, v, 160, valid, block_q=128, block_k=128)
+    assert got.shape == q.shape
+    rows = slice(valid_from, None)
+    np.testing.assert_array_equal(np.asarray(got, np.float32)[:, rows], np.asarray(want, np.float32)[:, rows])
+
+
 def test_the_band_cuts_the_grid_to_the_key_blocks_it_can_overlap():
-    """16,384 tokens, a window of 4096, tiles of 512: ten key steps a query
-    block where a causal forward takes thirty-two."""
+    """16,384 tokens, a window of 4096, tiles of 512: the band's own list of
+    pairs, nine key blocks a query block at most (252 steps), where the
+    rectangle of ten steps a block took 320 and a causal forward's triangle
+    528."""
     q = jax.ShapeDtypeStruct((1, 16384, 6, 128), jnp.bfloat16)
     kv = jax.ShapeDtypeStruct((1, 16384, 1, 128), jnp.bfloat16)
     jaxpr = jax.make_jaxpr(lambda a, b, c: banded_flash_attention(a, b, c, window=4096))(q, kv, kv)
     (eqn,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
-    assert eqn.params["grid_mapping"].grid == (1, 1, 32, 10)
+    assert eqn.params["grid_mapping"].grid == (1, 1, 36 + 24 * 9)

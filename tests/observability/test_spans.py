@@ -186,3 +186,30 @@ def test_timeline_gets_the_same_names_and_stats(tmp_path):
     assert prefill["args"]["padded"] >= 3 and prefill["args"]["reused"] == 0
     emits = [e for e in events if e["name"] == tracing.STEP_EMIT]
     assert sum(e["args"]["delivered"] for e in emits) == 3
+
+
+@pytest.mark.parametrize("model,kinds", [
+    ("llama", {"flash"}), ("keye", {"flash", "masked"}), ("glm5", {"flash", "masked"}), ("trinity", {"flash", "band"})])
+def test_prefill_span_counts_the_tiles_of_the_kernels_the_model_runs(model, kinds):
+    """``nxd.step.prefill``'s tile stats, by the kernels' own rule: ``flash_*``
+    for every model, ``masked_*`` for one with an indexer, ``band_*`` for one
+    with window layers (a KV head a layer: Keye's group of 8 tiles its queries
+    by 256), visited = needed for a left-padded prompt; nothing for a suffix
+    prefill or an exact-length fallback."""
+    from neuronx_distributed_tpu.kernels.flash_attention import flash_tile_plan, group_tile_plan
+    from neuronx_distributed_tpu.models.afmoe import trinity_large
+    from neuronx_distributed_tpu.models.glm_moe_dsa import GlmMoeDsaConfig
+    from neuronx_distributed_tpu.models.keye_vl2 import keye_vl2_30b_a3b
+    from neuronx_distributed_tpu.serving.engine import _flash_tiles
+
+    config = {"llama": tiny_llama, "keye": keye_vl2_30b_a3b, "glm5": GlmMoeDsaConfig, "trinity": trinity_large}[model]()
+    stats = _flash_tiles(16384, 8862, config)
+    assert {name.split("_")[0] for name in stats} == kinds and len(stats) == 4 * len(kinds)
+    names = ("steps", "tiles", "edge_tiles", "needed_tiles")
+    assert tuple(stats[f"flash_{n}"] for n in names) == flash_tile_plan(16384, 8862) == (528, 171, 35, 171)
+    want = {"keye": (1056, 341, 341, 341), "glm5": (528, 171, 171, 171),
+            "trinity": group_tile_plan(16384, 8862, 6, 4096)}.get(model)
+    for kind in kinds - {"flash"}:
+        assert tuple(stats[f"{kind}_{n}"] for n in names) == want
+        assert stats[f"{kind}_tiles"] == stats[f"{kind}_needed_tiles"] < stats[f"{kind}_steps"]
+    assert _flash_tiles(16384, 0, config) == {} and _flash_tiles(9003, 9003, config) == {}

@@ -94,38 +94,44 @@ ROWS = {
     "dsa": Row(chip_smoke.DsaSize(
         model=_tiny("keye-vl2-30b-a3b-serve"), max_seq_len=512, slots=2, prompt_lens=(300, 128, 40),
         tail=32, new_tokens=12, kernel_contexts=(70, 200, 33), kernel_cursor=260,
-        kernel_calls=2, kernel_prefill=512, kernel_tol=1e-4, logit_tol=1e-3, typical_tol=1e-4,
-        selected_tol=1e-3, index_near_tie=1e-3, gap_tol=1e-3), (
+        kernel_calls=2, kernel_prefill=512, forward_bucket=512, forward_prompts=(512, 300), kernel_tol=1e-4,
+        logit_tol=1e-3, typical_tol=1e-4, selected_tol=1e-3, index_near_tie=1e-3, gap_tol=1e-3), (
         "dsa_index_kernel_matches_jnp", "dsa_bisection_selects_what_top_k_selects",
         "dsa_sparse_kernel_matches_jnp", "dsa_mask_kernel_keeps_the_einsums_columns",
+        "dsa_prefill_forward_equals_pr49s_bit_for_bit", "dsa_prefill_forward_blocks_past_the_prompt_are_zero",
         "dsa_matches_reference", "dsa_selects_the_references_columns", "dsa_resolved_sparse_fused",
         "dsa_cache_is_indexed_sized", "dsa_float8_index_keys_are_caught", "dsa_1024_kept_is_caught",
-        "kernel_dsa_programs")),
+        "kernel_dsa_programs"), chips_to_say=("_bit_for_bit",)),
     # GLM-5's structure at the CPU stand-in's size (32 columns kept, 8 of 16
     # experts held); float32, so the tolerances are a float32 matmul's summation order
     "glm": Row(chip_smoke.GlmSize(
         model=_tiny("glm-5-serve"), max_seq_len=512, slots=2, prompt_lens=(300, 128, 40),
-        tail=32, new_tokens=12, kernel_contexts=(70, 200, 20), kernel_calls=2, kernel_tol=1e-4,
-        logit_tol=1e-3, typical_tol=1e-4, selected_tol=1e-3, attn_tol=1e-4, routed_tol=1e-4, latent_tol=1e-5,
-        gap_tol=1e-3, near_tie=0.0), (
-        "glm_sparse_latent_kernel_matches_jnp", "glm_matches_reference",
+        tail=32, new_tokens=12, kernel_contexts=(70, 200, 20), kernel_calls=2, forward_bucket=512,
+        forward_prompts=(512, 300), kernel_tol=1e-4, logit_tol=1e-3, typical_tol=1e-4, selected_tol=1e-3,
+        attn_tol=1e-4, routed_tol=1e-4, latent_tol=1e-5, gap_tol=1e-3, near_tie=0.0), (
+        "glm_sparse_latent_kernel_matches_jnp", "glm_prefill_forward_equals_pr49s_bit_for_bit",
+        "glm_prefill_forward_blocks_past_the_prompt_are_zero", "glm_matches_reference",
         "glm_selects_the_references_columns", "glm_attention_routed_sum_and_cache_alone_match_reference",
         "glm_resolved_sparse_latent_fused", "glm_cache_is_a_tile_and_an_index_key",
         "glm_float8_index_keys_are_caught", "glm_1024_kept_is_caught", "glm_float8_latent_is_caught",
-        "glm_bias_in_the_weights_is_caught", "kernel_glm_programs")),
+        "glm_bias_in_the_weights_is_caught", "kernel_glm_programs"), chips_to_say=("_bit_for_bit",)),
+    # (each of dsa, glm and trinity: the prefill's grouped forward alone against
+    # PR 49's first, at a bucket of 512; on the CPU, in float32, the two may differ
+    # in the last bit, as the flash forward's bodies below)
     # ``--only trinity`` at the CPU stand-in's size (a window of 32 under
     # contexts of 200, page 8, 4 of 16 experts held); float32, so the
     # tolerances are a float32 matmul's summation order and every control is caught
     "trinity": Row(chip_smoke.TrinitySize(
         model=_tiny("trinity-large-serve"), max_seq_len=512, slots=3, page=8, prompt_lens=(200, 90, 40), tail=32,
-        new_tokens=12, pool_tokens=10, logit_tol=1e-3, typical_tol=1e-4, attn_tol=1e-4, routed_tol=1e-4,
-        cache_tol=1e-5, gap_tol=1e-3, near_tie=0.0), (
+        new_tokens=12, pool_tokens=10, forward_bucket=512, forward_prompts=(512, 300), kernel_calls=2,
+        logit_tol=1e-3, typical_tol=1e-4, attn_tol=1e-4, routed_tol=1e-4, cache_tol=1e-5, gap_tol=1e-3, near_tie=0.0), (
+        "trinity_prefill_forward_equals_pr49s_bit_for_bit", "trinity_prefill_forward_blocks_past_the_prompt_are_zero",
         "trinity_matches_reference", "trinity_window_full_routed_and_pool_alone_match_reference",
         "trinity_frees_pages_behind_the_window_and_leaks_none", "trinity_cursor_jumps_leave_gap_columns",
         "trinity_resolved_paged_walk_fused", "trinity_cache_is_k_and_v_of_every_kv_head",
         "trinity_no_window_is_caught", "trinity_half_window_is_caught", "trinity_missing_gate_is_caught",
         "trinity_rotary_on_the_full_layer_is_caught", "trinity_float8_cache_is_caught",
-        "trinity_bias_in_the_weights_is_caught", "kernel_trinity_programs")),
+        "trinity_bias_in_the_weights_is_caught", "kernel_trinity_programs"), chips_to_say=("_bit_for_bit",)),
     # ``--only zaya`` at the CPU stand-in's size (3 layers, contexts of 150 in a row of 512, page 8);
     # float32, so the tolerances are a float32 matmul's summation order and every control is caught
     "zaya": Row(chip_smoke.ZayaSize(
