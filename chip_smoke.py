@@ -1981,6 +1981,50 @@ def trinity_phase(size: TrinitySize, seed: int) -> Dict[str, bool]:
     }
 
 
+def _serve_shortest_first(tag: str, engine, prompts, new_tokens: int, seed: int):
+    """``(requests in ``prompts``' order, cursor jumps, wall seconds)``: the
+    prompts (longest first in ``prompts``) admitted SHORTEST first and an
+    engine step apart, so that each longer prompt's admission jumps the shared
+    cursor over the slots already decoding and leaves gap columns between a
+    decoding slot's next token and its predecessor; then run to the end."""
+    import jax
+
+    from neuronx_distributed_tpu.inference import GenerationConfig
+
+    gcfg = GenerationConfig(max_new_tokens=new_tokens, temperature=0.0)
+    t0, reqs, jumps = time.perf_counter(), [], 0
+    for i, prompt in enumerate(prompts[::-1]):
+        before = engine.cache.cursor
+        reqs.append(engine.submit(prompt, gcfg, key=jax.random.PRNGKey(seed + i)))
+        engine.step()
+        # past what the step's own chunk writes, under a slot already decoding
+        jumps += bool(i and engine.cache.cursor - before > engine.decode_chunk_size)
+    engine.run()
+    wall = time.perf_counter() - t0
+    if any(len(r.tokens) != new_tokens for r in reqs):
+        raise RuntimeError(f"{tag}: tokens {[len(r.tokens) for r in reqs]} of {new_tokens} (halt {engine.halt_reason!r})")
+    return reqs[::-1], jumps, wall
+
+
+def _decoded_token_gaps(tag: str, ref, prompts, reqs, gap_tol: float, near_tie: float):
+    """``(every decoded token within ``gap_tol`` of the reference's largest
+    logit but where its router's margin is under ``near_tie``, and every wrong
+    token outside it; the largest gap not excused)``, a line a request."""
+    from perfbench.references import common
+
+    ok, worst_gap = True, 0.0
+    for prompt, req in zip(prompts, reqs):
+        pad_to = -(-(len(prompt) + len(req.tokens)) // 128) * 128
+        gaps, wrong, _, margins = common.emitted_token_gaps(ref, prompt, req.tokens, pad_to)
+        fine, over, excused = common.judge_gaps(gaps, margins, gap_tol, near_tie)
+        ok = ok and fine and wrong.min() > gap_tol
+        worst_gap = max(worst_gap, float(gaps[margins >= near_tie].max(initial=0.0)))
+        log(f"{tag}: prompt {len(prompt)}: decoded tokens' largest reference-logit gap {gaps.max():.4f} ({over} of "
+            f"{len(gaps)} fail {gap_tol:g}, {excused} excused by a router margin under {near_tie:g}; wrong "
+            f"tokens' smallest gap {wrong.min():.3f})")
+    return ok, worst_gap
+
+
 @dataclasses.dataclass(frozen=True)
 class ZayaSize:
     """What ``--only zaya`` runs (defaults: the chip run, the published widths
@@ -2029,14 +2073,12 @@ def zaya_phase(size: ZayaSize, seed: int) -> Dict[str, bool]:
     import numpy as np
     from flax.core import meta
 
-    from neuronx_distributed_tpu.inference import GenerationConfig
     from neuronx_distributed_tpu.models.zaya import ZayaModel
     from neuronx_distributed_tpu.modules.attention import slot_state_bytes_per_layer
     from neuronx_distributed_tpu.parallel import mesh as mesh_lib
     from neuronx_distributed_tpu.serving import ServingEngine
     from perfbench import cca_costs
     from perfbench.families import zaya as family
-    from perfbench.references import common
     from perfbench.references.zaya import Reference
 
     mesh_lib.destroy_model_parallel()
@@ -2052,22 +2094,7 @@ def zaya_phase(size: ZayaSize, seed: int) -> Dict[str, bool]:
     engine = ServingEngine(model, params, num_slots=size.slots, kv_page_size=size.page)
     prompts = _prompts(size.prompt_lens, int(published["vocab_size"]), seed + 7)
 
-    # shortest first and an engine step apart: each longer prompt's admission
-    # jumps the shared cursor and leaves gap columns between a decoding slot's
-    # next token and its predecessor
-    gcfg = GenerationConfig(max_new_tokens=size.new_tokens, temperature=0.0)
-    t0, reqs, jumps = time.perf_counter(), [], 0
-    for i, prompt in enumerate(prompts[::-1]):
-        before = engine.cache.cursor
-        reqs.append(engine.submit(prompt, gcfg, key=jax.random.PRNGKey(seed + i)))
-        engine.step()
-        # past what the step's own chunk writes, under a slot already decoding
-        jumps += bool(i and engine.cache.cursor - before > engine.decode_chunk_size)
-    engine.run()
-    wall = time.perf_counter() - t0
-    if any(len(r.tokens) != size.new_tokens for r in reqs):
-        raise RuntimeError(f"zaya: tokens {[len(r.tokens) for r in reqs]} of {size.new_tokens} (halt {engine.halt_reason!r})")
-    reqs = reqs[::-1]
+    reqs, jumps, wall = _serve_shortest_first("zaya", engine, prompts, size.new_tokens, seed)
     resolved = dict(engine.programs.resolved)
     kernels = _ledger_kernels(engine.programs, _hot_programs(engine))
     per_token = engine.metrics.snapshot()["kv_bytes_per_token_layer"]
@@ -2081,16 +2108,7 @@ def zaya_phase(size: ZayaSize, seed: int) -> Dict[str, bool]:
     engine = None
     gc.collect()
 
-    ok, worst_gap = True, 0.0
-    for prompt, req in zip(prompts, reqs):
-        pad_to = -(-(len(prompt) + len(req.tokens)) // 128) * 128
-        gaps, wrong, _, margins = common.emitted_token_gaps(ref, prompt, req.tokens, pad_to)
-        fine, over, excused = common.judge_gaps(gaps, margins, size.gap_tol, size.near_tie)
-        ok = ok and fine and wrong.min() > size.gap_tol
-        worst_gap = max(worst_gap, float(gaps[margins >= size.near_tie].max(initial=0.0)))
-        log(f"zaya: prompt {len(prompt)}: decoded tokens' largest reference-logit gap {gaps.max():.4f} ({over} of "
-            f"{len(gaps)} fail {size.gap_tol:g}, {excused} excused by a router margin under {size.near_tie:g}; wrong "
-            f"tokens' smallest gap {wrong.min():.3f})")
+    ok, worst_gap = _decoded_token_gaps("zaya", ref, prompts, reqs, size.gap_tol, size.near_tie)
 
     backbone = ZayaModel(cfg, model.attention_impl, mode="prefill")
 
@@ -2155,6 +2173,179 @@ def zaya_phase(size: ZayaSize, seed: int) -> Dict[str, bool]:
         "zaya_float8_is_caught": caught["the whole reference in float8"],
         "zaya_no_router_state_is_caught": caught["no state mixed in"],
         "kernel_zaya_programs": all(kernels.values()) and bool(kernels),
+    }
+
+
+@dataclasses.dataclass(frozen=True)
+class SolarSize:
+    """What ``--only solar`` runs (defaults: the chip run, the published widths
+    on the benchmark configuration's cut)."""
+
+    model: object = None          # the configuration's ``model`` group; None = the benchmark configuration's
+    max_seq_len: int = 16384
+    slots: int = 4
+    page: int = 16
+    prompt_lens: Tuple[int, ...] = (3000, 1100, 300)
+    new_tokens: int = 32
+    # ONE mixer alone, on the SYSTEM's own input, which the layers after it
+    # cannot blur: the median position's |system - reference| / |reference| (L2
+    # over the hidden vector) of what layer 1's linear attention and layer 0's
+    # GQA attention add to the stream; each limit between the system's reading
+    # and the controls' (PERF.md section 6, PR 51, has the readings)
+    linear_tol: float = 0.03
+    full_tol: float = 0.03
+    # the decoded tokens' gap and the router near-tie that excuses one: the
+    # benchmark configuration's (its ``reference_check.why`` has the readings)
+    gap_tol: float = 0.15
+    near_tie: float = 0.02
+
+
+def solar_phase(size: SolarSize, seed: int) -> Dict[str, bool]:
+    """Solar-Open2-250B alone: gated delta-rule linear-attention layers whose
+    float32 state lives per slot beside the paged K/V of the gated NoPE GQA
+    layers, against the plain reference (the token-by-token recurrence), each
+    mechanism held on one block against its control. Not part of the default
+    run. The language model at its published widths on the benchmark
+    configuration's cut (``perfbench/configs/solar-open2-250b-serve.json``)
+    through a ``ServingEngine``: prompts of 3,000, 1,100 and 300 tokens are
+    prefilled through the chunked kernel (left-padded in their buckets),
+    admitted shortest first and an engine step apart so that each longer
+    prompt's admission jumps the shared cursor over the slots already decoding,
+    and 32 tokens decoded through the state in place. Against
+    ``perfbench/references/solar_open2.py``: the reference's logit of every
+    decoded token; then ONE mixer alone on the system's own input, each limit
+    between the system's reading and a control's: layer 1's linear attention
+    (| the decay off | beta without its factor 2 | the convolutions removed |
+    the output gate left out | the state kept in float8 | the whole reference
+    in float8; a state kept in bf16 is read and not judged: it is at the bf16
+    system's own rounding), layer 0's GQA attention
+    (| rotary applied | the gate left out); and the compiled decode chunk
+    copies no array of the state's size."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from flax.core import meta
+
+    from neuronx_distributed_tpu.models.solar_open2 import SolarOpen2Model
+    from neuronx_distributed_tpu.modules.attention import slot_state_bytes_per_layer
+    from neuronx_distributed_tpu.parallel import mesh as mesh_lib
+    from neuronx_distributed_tpu.serving import ServingEngine
+    from perfbench import kda_costs
+    from perfbench.families import solar_open2 as family
+    from perfbench.references.solar_open2 import Reference
+
+    mesh_lib.destroy_model_parallel()
+    published = _published(size, "solar-open2-250b-serve.json")
+    model = family.build(published, runner="serve", max_seq_len=size.max_seq_len)
+    if size.model is not None:    # the CPU rehearsal serves in float32
+        model = model.clone(config=dataclasses.replace(model.config, dtype=jnp.float32))
+    cfg = model.config
+    params = jax.jit(model.init)(jax.random.PRNGKey(seed), jnp.zeros((1, 8), jnp.int32))
+    jax.block_until_ready(params)
+    plain = meta.unbox(params)
+    ref = Reference(published, plain)
+    engine = ServingEngine(model, params, num_slots=size.slots, kv_page_size=size.page)
+    prompts = _prompts(size.prompt_lens, int(published["vocab_size"]), seed + 7)
+
+    reqs, jumps, wall = _serve_shortest_first("solar", engine, prompts, size.new_tokens, seed)
+    resolved = dict(engine.programs.resolved)
+    kernels = _ledger_kernels(engine.programs, _hot_programs(engine))
+    per_token = engine.metrics.snapshot()["kv_bytes_per_token_layer"]
+    state_bytes = slot_state_bytes_per_layer(engine.cache.cache)
+    # the decode chunk as compiled: its temporaries, and whether any instruction copies an array of one layer's
+    # state (the state rides the scan's carry and the kernel in place)
+    chunk = engine.programs.programs()["decode_chunk"].variants[0].lower().compile()
+    temp = chunk.memory_analysis().temp_size_in_bytes
+    one_state = size.slots * cfg.linear_num_heads * cfg.linear_head_dim ** 2 * 4
+    state_shape = f"f32[{size.slots},{cfg.linear_num_heads},{cfg.linear_head_dim},{cfg.linear_head_dim}]"
+    state_copies = [ln for ln in chunk.as_text().splitlines() if f"= {state_shape}" in ln and " copy(" in ln]
+    log(f"solar: {len(reqs)} requests, prompts {list(size.prompt_lens)} + {size.new_tokens} tokens in {wall:.1f}s; "
+        f"resolved {resolved}; {KERNEL} in compiled programs: {kernels}; cache {per_token:g} B a token a GQA layer, "
+        f"state {state_bytes:g} B a slot a linear layer, pool {engine.cache.nbytes / 2**30:.2f} GiB; {jumps} cursor "
+        f"jumps; the decode chunk's temporaries {temp / 2**20:.1f} MiB, one layer's state {one_state / 2**20:.1f} MiB, "
+        f"{len(state_copies)} copies of an array of its shape")
+    engine.cache.check()
+    leak_free = engine.cache.alloc.free_pages == engine.cache.alloc.num_pages - 1
+    engine = chunk = None
+    gc.collect()
+
+    ok, worst_gap = _decoded_token_gaps("solar", ref, prompts, reqs, size.gap_tol, size.near_tie)
+
+    backbone = SolarOpen2Model(cfg, model.attention_impl, mode="prefill")
+    wanted = ("layers_0", "attn", "linear_attn")
+
+    @jax.jit
+    def mixers(params, ids, mask):
+        """From the SYSTEM's prefill (the chunked kernel, a left-padded bucket):
+        what layer 0's GQA attention adds, the stream after layer 0, what layer
+        1's linear attention adds to it."""
+        _, state = backbone.apply(
+            {"params": params["params"]["model"]}, ids, padding_mask=mask, mutable=["cache", "intermediates"],
+            capture_intermediates=lambda mdl, _: mdl.name in wanted)
+        got = state["intermediates"]
+        return (got["layers_0"]["attn"]["__call__"][0], got["layers_0"]["__call__"][0][0],
+                got["layers_1"]["linear_attn"]["__call__"][0])
+
+    def rel(mine, theirs):
+        """Median over positions of |mine - theirs| / |theirs| (L2 over the last axis)."""
+        mine, theirs = (np.asarray(a, np.float32)[0] for a in (mine, theirs))
+        return float(np.median(np.linalg.norm(mine - theirs, axis=-1) / np.linalg.norm(theirs, axis=-1)))
+
+    prompt = prompts[0]
+    p = len(prompt)
+    pad = -p % 512                                        # whole tiles, LEFT-padded as the engine's buckets are
+    ids = np.pad(prompt, (pad, 0))[None]
+    mask = (np.arange(p + pad) >= pad)[None]
+    full_sys, x0_sys, linear_sys = (a[:, pad:] for a in mixers(params, ids, mask))
+    emb = ref.embed(prompt[None])
+    full_ref, linear_ref = ref.mixer_part(0, emb), ref.mixer_part(1, x0_sys)
+    readings = {"layer 0's GQA attention": (rel(full_sys, full_ref), size.full_tol),
+                "layer 1's linear attention on the system's own input": (rel(linear_sys, linear_ref), size.linear_tol)}
+    blocks_ok = all(reading <= limit for reading, limit in readings.values())
+    log(f"solar: prompt {p} (left-padded by {pad}), one mixer alone, median |system - reference| / |reference|: "
+        + "; ".join(f"{name} {reading:.5f} (limit {limit:g})" for name, (reading, limit) in readings.items()))
+    controls = (
+        ("the decay off", dict(decay=False), 1), ("beta without its factor 2", dict(beta_factor=1.0), 1),
+        ("the convolutions removed", dict(conv=False), 1), ("the output gate left out", dict(gate=False), 1),
+        ("the state kept in float8", dict(state_dtype=jnp.float8_e4m3fn), 1),
+        # read and not judged: a bf16 state is at the bf16 system's own rounding (PERF.md section 6, PR 51)
+        ("the state kept in bf16", dict(state_dtype=jnp.bfloat16), 1),
+        ("the whole reference in float8", dict(dtype=jnp.float8_e4m3fn), 1),
+        ("rotary applied", dict(rope_full=True), 0), ("the GQA gate left out", dict(gate=False), 0),
+    )
+    caught = {}
+    for name, kw, layer in controls:
+        other = Reference(published, plain, **kw)
+        if layer == 1:
+            reading, limit = rel(other.mixer_part(1, x0_sys), linear_ref), size.linear_tol
+        else:
+            reading, limit = rel(other.mixer_part(0, emb), full_ref), size.full_tol
+        caught[name] = reading > limit
+        log(f"solar: control, the reference with {name} against the plain reference, layer {layer}'s mixer: "
+            f"{reading:.5f} (limit {limit:g}): {'outside' if caught[name] else 'INSIDE'} the limit")
+    log(f"solar: largest decoded-token gap outside router near-ties {worst_gap:.4f} ({size.gap_tol:g})")
+    itemsize = jnp.dtype(cfg.dtype).itemsize
+    return {
+        "solar_matches_reference": ok,
+        "solar_two_mixers_alone_match_reference": blocks_ok,
+        "solar_cursor_jumps_leave_gap_columns": jumps >= len(prompts) - 1,
+        "solar_leaks_no_page": leak_free,
+        "solar_resolved_paged_walk_fused": {k: resolved[k] for k in ("attention", "decode_attention", "paged_attention")} == {
+            "attention": "flash", "decode_attention": "paged_walk_fused", "paged_attention": "fused"},
+        "solar_cache_is_four_kib_a_token_and_the_state_four_mib_a_slot": (
+            per_token == 2 * cfg.num_kv_heads * cfg.head_dim * itemsize
+            and state_bytes == kda_costs.slot_state_bytes(
+                heads=cfg.linear_num_heads, head_dim=cfg.linear_head_dim, taps=cfg.conv_kernel - 1, act_bytes=itemsize)),
+        "solar_decode_chunk_copies_no_array_of_the_states_size": not state_copies,
+        "solar_decay_off_is_caught": caught["the decay off"],
+        "solar_beta_without_its_factor_is_caught": caught["beta without its factor 2"],
+        "solar_no_convolution_is_caught": caught["the convolutions removed"],
+        "solar_no_output_gate_is_caught": caught["the output gate left out"],
+        "solar_float8_state_is_caught": caught["the state kept in float8"],
+        "solar_float8_is_caught": caught["the whole reference in float8"],
+        "solar_rotary_on_the_gqa_layer_is_caught": caught["rotary applied"],
+        "solar_no_gqa_gate_is_caught": caught["the GQA gate left out"],
+        "kernel_solar_programs": all(kernels.values()) and bool(kernels),
     }
 
 
@@ -2690,6 +2881,7 @@ PHASES: Dict[str, Phase] = {
     "moe": Phase(moe_phase, MoeSize(), only=True),
     "trinity": Phase(trinity_phase, TrinitySize(), only=True),
     "zaya": Phase(zaya_phase, ZayaSize(), only=True),
+    "solar": Phase(solar_phase, SolarSize(), only=True),
     "walk": Phase(walk_phase, WalkSize(), only=True),
     "flash": Phase(flash_phase, FlashSize(), only=True),
     "tp_train": Phase(tp_train_phase, TrainSize(), devices=True, chips=4, default=True),

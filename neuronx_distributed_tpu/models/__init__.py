@@ -19,7 +19,11 @@ What a causal LM returns in each ``mode`` (every ``*ForCausalLM`` here and
 Logits at every position of a context: ``mode="train"``, or, where the family
 has one, the headless ``*Model`` in ``prefill`` mode and the head's kernel
 (``params["params"]["lm_head"]["kernel"]``), as ``chip_smoke.py``'s reference
-comparisons do."""
+comparisons do.
+
+``solar_open2`` (``SolarOpen2ForCausalLM``) keeps the same contract with a
+decode window of ONE token: its linear-attention layers take a slot's token
+through a recurrent state, in order (``models/solar_open2.py``)."""
 
 from neuronx_distributed_tpu.models.afmoe import (
     AfmoeConfig,
@@ -97,6 +101,13 @@ from neuronx_distributed_tpu.models.zaya import (
     tiny_zaya,
     zaya1_8b,
 )
+from neuronx_distributed_tpu.models.solar_open2 import (
+    SolarOpen2Config,
+    SolarOpen2ForCausalLM,
+    SolarOpen2Model,
+    solar_open2_250b,
+    tiny_solar_open2,
+)
 from neuronx_distributed_tpu.models.vit import (
     ViTConfig,
     ViTForImageClassification,
@@ -122,4 +133,6 @@ __all__ = [
     "keye_vl2_30b_a3b", "tiny_keye_vl2",
     "AfmoeConfig", "AfmoeForCausalLM", "AfmoeModel", "trinity_large", "tiny_afmoe",
     "ZayaConfig", "ZayaForCausalLM", "ZayaModel", "zaya1_8b", "tiny_zaya",
+    "SolarOpen2Config", "SolarOpen2ForCausalLM", "SolarOpen2Model",
+    "solar_open2_250b", "tiny_solar_open2",
 ]

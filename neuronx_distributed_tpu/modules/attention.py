@@ -204,6 +204,17 @@ ATTN_CCA_SCOPE = "attn.cca"
 ATTN_CCA_PROJECT_SCOPE = "attn.cca.project"
 ATTN_CCA_CONV_SCOPE = "attn.cca.conv"
 ATTN_CCA_ATTEND_SCOPE = "attn.cca.attend"
+# Gated delta-rule linear attention (``models/solar_open2.py``): the whole
+# block; inside it the six projections, the short convolutions with their
+# per-slot taps, the decay, the write strength and the output gate with its
+# norm, the recurrence (``kernels/delta_rule.py``: both kernels are named
+# after it) and the output projection.
+ATTN_KDA_SCOPE = "attn.kda"
+ATTN_KDA_PROJECT_SCOPE = "attn.kda.project"
+ATTN_KDA_CONV_SCOPE = "attn.kda.conv"
+ATTN_KDA_GATE_SCOPE = "attn.kda.gate"
+ATTN_KDA_RECUR_SCOPE = "attn.kda.recur"
+ATTN_KDA_OUT_SCOPE = "attn.kda.out"
 
 
 def prefill_positions(padding_mask: jax.Array) -> jax.Array:
@@ -502,6 +513,21 @@ class JoinedKVCache(KVCache):
         self._decode_write((jnp.concatenate([k, v], axis=2),), padding_mask)
 
 
+class RecurrentStateCache:
+    """What a RECURRENT layer keeps a slot, and all it keeps: ``recur`` (B,
+    heads, d, d) float32, the state a scan over the request's tokens has
+    reached, and ``conv`` (B, taps, channels), the last ``taps`` inputs of its
+    short causal convolutions (:data:`SLOT_STATE_LEAVES`). No per-token leaf,
+    no ``kv_valid``, no ``index``: the layer grows no column, maps no page and
+    has no block table; the stack's cursor is its attention layers'. A prefill
+    leaves both at each row's last token, a decode step reads and replaces
+    them, a slot that takes no token keeps them."""
+
+    def __init__(self, module, b, heads, d, taps, channels, dtype):
+        self.recur = module.variable("cache", "recur", jnp.zeros, (b, heads, d, d), jnp.float32)
+        self.conv = module.variable("cache", "conv", jnp.zeros, (b, taps, channels), dtype)
+
+
 def cache_node_window(node) -> Optional[int]:
     """The window of the layer whose cache node (the dict holding its leaves)
     this is; ``None`` for a full-attention layer."""
@@ -571,15 +597,22 @@ def window_keep(kv_valid, q_pos, window: Optional[int]):
 # handed around in ITS order: (k, v), a latent cache's (k, k_pe), an indexed
 # cache's (kv, k_idx).
 PAGED_LEAVES = ("k", "v", "k_pe", "kv", "k_idx")
-# THE names of the per-slot STATE leaves, (..., B, width): a slot axis and NO
-# length axis: what a layer's next token needs of the request's last one and
-# no cache column holds (``JoinedKVCache(state_width=)``). Slot-shaped like
-# ``kv_valid``, so the paged transports hand them through as they are; an
+# THE names of the per-slot STATE leaves: a slot axis and NO length axis: what
+# a layer's next token needs of the request's tokens so far and no cache
+# column holds. Each name has its own rank after the slot axis and its own
+# dtype: ``state`` (..., B, width), the last token's values beside a paged
+# cache (``JoinedKVCache(state_width=)``); ``recur`` (..., B, heads, d, d)
+# float32, a recurrent layer's state at its natural rank (stored as a width it
+# would be relaid a slot a step), and ``conv`` (..., B, taps, channels), the
+# last inputs of its short convolutions (:class:`RecurrentStateCache`: a layer
+# with these and NO per-token leaf, no ``kv_valid`` and no cursor). Slot-shaped
+# like ``kv_valid``, so the paged transports hand them through as they are; an
 # admission copies the prefill row's into the slot (no roll: there is no
 # column), a freed slot's is left for the next admission to overwrite, and
 # nothing that holds a context by its pages or columns alone (a prefix block,
 # a staged or spilled context) has one: those are refused for the kind.
-SLOT_STATE_LEAVES = ("state",)
+_SLOT_STATE_RANK = {"state": 1, "recur": 3, "conv": 2}     # axes AFTER the slot axis
+SLOT_STATE_LEAVES = tuple(_SLOT_STATE_RANK)
 
 
 def cache_leaf_name(path) -> str:
@@ -594,8 +627,10 @@ def cache_batch_axis(name: str, ndim: int):
     batch axis right, so classify from the TRAILING dims."""
     if name in PAGED_LEAVES:
         return ndim - 4
-    if name == "kv_valid" or name in SLOT_STATE_LEAVES:
+    if name == "kv_valid":
         return ndim - 2
+    if name in _SLOT_STATE_RANK:
+        return ndim - 1 - _SLOT_STATE_RANK[name]
     return None
 
 
@@ -624,13 +659,15 @@ def slot_state_bytes_per_layer(cache) -> float:
     import math
 
     tree = cache["pool"] if isinstance(cache, dict) and "pool" in cache else cache
-    total, layers = 0.0, 0
+    total, layers = 0.0, {}
     for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
-        if cache_leaf_name(path) in SLOT_STATE_LEAVES:
-            lead = math.prod(leaf.shape[:-2])
-            total += lead * leaf.shape[-1] * leaf.dtype.itemsize
-            layers += lead
-    return total / layers if layers else 0.0
+        name = cache_leaf_name(path)
+        if name in SLOT_STATE_LEAVES:
+            ax = cache_batch_axis(name, leaf.ndim)
+            lead = math.prod(leaf.shape[:ax])
+            total += lead * math.prod(leaf.shape[ax + 1:]) * leaf.dtype.itemsize
+            layers[tuple(str(k) for k in path[:-1])] = lead      # a layer may hold several
+    return total / sum(layers.values()) if layers else 0.0
 
 
 def reset_cache_slot(cache, slot):
@@ -1044,6 +1081,12 @@ class fused_paged_attention_scope:
 
     def __exit__(self, *exc):
         _FUSED_PAGED_STACK.pop()
+
+
+def fused_paged_frame_active() -> bool:
+    """Whether a :class:`fused_paged_attention_scope` is open: the decode step
+    being traced is the fused paged chunk's, whose layers run their kernels."""
+    return bool(_FUSED_PAGED_STACK)
 
 
 def _fused_window_origin():

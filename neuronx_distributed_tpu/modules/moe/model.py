@@ -89,6 +89,9 @@ class MoE(nn.Module):
     # part of their weights (``RouterTopK.selection_bias``)
     router_selection_bias: bool = False
     router_selection_bias_init_std: float = 0.0
+    # a linear router's weight starts with every run of this many consecutive
+    # experts summing to zero (``routing.zero_sum_runs_lecun_normal``); 0: not
+    router_zero_sum_group: int = 0
     # ``(first, count)``: this device holds experts ``[first, first + count)``
     # of ``num_experts`` and computes their part of the routed sum alone, plus
     # the shared expert (``ExpertMLPs.held_experts``). The stats ``held_rows``
@@ -124,6 +127,8 @@ class MoE(nn.Module):
             router_options["normalize_top_k_affinities"] = False
         if self.router_kind == "mlp":
             router_options.update(state_size=self.router_state_size, eps=self.router_eps)
+        if self.router_zero_sum_group:
+            router_options["zero_sum_group"] = self.router_zero_sum_group
         if self.router_selection_bias:
             router_options.update(
                 selection_bias=True,

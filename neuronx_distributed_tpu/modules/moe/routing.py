@@ -46,6 +46,31 @@ def stratified_normal(std: float, group: int = 0):
     return init
 
 
+def zero_sum_runs_lecun_normal(group: int):
+    """``lecun_normal`` for a router's ``(hidden, E)`` weight in which every run
+    of ``group`` consecutive experts (the experts one device of an
+    expert-parallel layer holds) has columns that sum to zero, rescaled to
+    lecun's size: the logits of a run then sum to zero for EVERY input, so
+    what the inputs have in common (a mean vector over tokens, which random
+    layers produce and a trained router has learned to ignore) prefers no run
+    to another in first order. Independent columns give one device 0.9 to 1.2
+    times its share of the rows of a top-8-of-320 softmax router, another under
+    every key (the same reason as :func:`stratified_normal`'s: a benchmark that
+    holds one share would do another amount of work under every seed); with
+    zero-sum runs 0.95 to 1.06. A trained model gets there by its balance loss
+    and a deployment by where it places its experts."""
+
+    def init(key, shape, dtype=jnp.float32):
+        hidden, num = shape
+        if num % group:
+            raise ValueError(f"{num} experts do not divide into runs of {group}")
+        runs = nn.initializers.lecun_normal()(key, shape, jnp.float32).reshape(hidden, num // group, group)
+        runs = (runs - runs.mean(axis=-1, keepdims=True)) * (group / (group - 1)) ** 0.5
+        return runs.reshape(shape).astype(dtype)
+
+    return init
+
+
 class RouterOutput(NamedTuple):
     logits: jax.Array  # (T, E) fp32 pre-activation
     probs: jax.Array  # (T, E) fp32 activation output (aux-loss input)
@@ -70,11 +95,16 @@ class RouterBase(nn.Module):
     jitter_eps: float = 0.0
     dtype: Dtype = jnp.float32
     param_dtype: Dtype = jnp.float32
+    # 0: ``lecun_normal``; else :func:`zero_sum_runs_lecun_normal` over runs of
+    # this many consecutive experts (one device's share)
+    zero_sum_group: int = 0
 
     def _logits(self, x, deterministic: bool) -> jax.Array:
+        init = (zero_sum_runs_lecun_normal(self.zero_sum_group) if self.zero_sum_group
+                else nn.initializers.lecun_normal())
         weight = self.param(
             "weight",
-            nn.with_partitioning(nn.initializers.lecun_normal(), (None, None)),
+            nn.with_partitioning(init, (None, None)),
             (self.hidden_size, self.num_experts),
             self.param_dtype,
         )

@@ -249,6 +249,8 @@ from neuronx_distributed_tpu.kernels.flash_attention import flash_tile_plan, gro
 from neuronx_distributed_tpu.modules.attention import (
     cache_bytes_per_token_layer,
     cache_fingerprint,
+    PAGED_LEAVES,
+    SLOT_STATE_LEAVES,
     extract_cache_prefix,
     resolve_decode_impl,
     seed_cache_prefix,
@@ -805,7 +807,7 @@ class ServingEngine:
             prefix_cache.on_evict = self._on_prefix_evict
         self._prefix_reuses = 0  # reuse-attempt index (poison-hook schedule)
         self._window_pages_freed_seen = 0
-        self._slot_state_bytes = None   # the dispatch span's stat, read once
+        self._slot_state = None   # the dispatch span's stats, read once (_measure_slot_state)
         self._steps_seen = 0  # step() index (flip_bits("params") schedule)
         self._prefill_model, self._decode_model = serving_clones(model)
         # scheduling policy (ISSUE 16): "fifo" (default — bit-identical to
@@ -931,6 +933,7 @@ class ServingEngine:
                  "indexed": "paged_sparse_fused",
                  "indexed_latent": "paged_sparse_latent_fused",
                  "joined": "paged_walk_fused",
+                 "joined_recurrent": "paged_walk_fused",
                  }.get(cache_kind, "paged_fused")
                 if self.paged_attention == "fused"
                 else "einsum" if cache_kind != "kv"  # the one-row-a-token kinds' only other path
@@ -2487,14 +2490,36 @@ class ServingEngine:
     def _slot_state_stats(self) -> dict:
         """For a model whose layers keep per-slot state beside their pages,
         on the dispatch span: ``slot_state_bytes_per_layer``, the bytes a
-        slot's state leaves hold a layer, from the allocated leaves. Empty
-        for every other model."""
+        slot's state leaves hold a layer, from the allocated leaves, and,
+        where some layers are RECURRENT (a ``recur`` leaf and no page), how
+        many they are and how many page (``recurrent_layers`` /
+        ``paged_layers``). Empty for every other model."""
         if not getattr(self.cache, "slot_state", False):
             return {}
-        if self._slot_state_bytes is None:
-            self._slot_state_bytes = int(round(
-                slot_state_bytes_per_layer(self.cache.cache)))
-        return {"slot_state_bytes_per_layer": self._slot_state_bytes}
+        if self._slot_state is None:
+            self._slot_state = self._measure_slot_state()
+        return self._slot_state
+
+    def _measure_slot_state(self) -> dict:
+        """The dispatch span's stats from the allocated cache, read once;
+        the ``serving_slot_state_bytes`` gauge takes all slots' and all
+        layers' bytes."""
+        tree = self.cache.cache
+        tree = tree["pool"] if isinstance(tree, dict) and "pool" in tree else tree
+        recurrent, paged, total = set(), set(), 0
+        for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
+            name, layer = path[-1].key, tuple(str(k) for k in path[:-1])
+            if name in PAGED_LEAVES:
+                paged.add(layer)
+            elif name in SLOT_STATE_LEAVES:
+                total += leaf.nbytes
+                if name == "recur":
+                    recurrent.add(layer)
+        self.metrics.record_slot_state_bytes(total)
+        stats = {"slot_state_bytes_per_layer": int(round(slot_state_bytes_per_layer(tree)))}
+        if recurrent:
+            stats.update(recurrent_layers=len(recurrent), paged_layers=len(paged))
+        return stats
 
     def _sampled_slots(self) -> int:
         """The decoding slots whose request samples (``temperature != 0``),

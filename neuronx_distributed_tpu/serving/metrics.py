@@ -239,6 +239,10 @@ class ServingMetrics:
             "serving_kv_bytes_per_token_layer",
             help="bytes one token holds in one attention layer's cache",
         )
+        self._g_slot_state = self.view.gauge(
+            "serving_slot_state_bytes",
+            help="bytes every slot's per-slot state leaves hold, all layers",
+        )
         self._g_health = self.view.gauge(
             "serving_health", help="0=ok 1=degraded 2=draining 3=halted"
         )
@@ -618,6 +622,9 @@ class ServingMetrics:
     def record_kv_bytes(self, per_token_layer: float) -> None:
         self.kv_bytes_per_token_layer = float(per_token_layer)
         self._g_kv_bytes.set(per_token_layer)
+
+    def record_slot_state_bytes(self, nbytes: int) -> None:
+        self._g_slot_state.set(nbytes)
 
     def record_decode_chunk(
         self,

@@ -1156,3 +1156,88 @@ def test_zaya_engine_programs_compile_and_fit(topo):
     temp = prefill.memory_analysis().temp_size_in_bytes
     print(f"zaya prefill[{longest}]: live {live / 2**30:.2f} GiB, temporaries {temp / 2**30:.2f} GiB")
     assert temp < 1.1 * ZAYA_PREFILL_TEMP_GIB * 2**30, f"{temp / 2**30:.2f} GiB of prefill temporaries"
+
+
+# --- Solar-Open2-250B: recurrent layers whose state lives per slot, beside paged GQA ---
+
+# what the described-v5e compile of the configured depth showed for the two
+# programs' temporaries (GiB; PR 51, the configuration's ``reduced_why``)
+SOLAR_DECODE_TEMP_GIB = 0.23
+SOLAR_PREFILL_TEMP_GIB = 1.79
+SOLAR_STATE = (64, 128, 128)      # a slot's float32 state a linear layer: 4 MiB
+
+
+def test_delta_rule_kernels_compile_at_solar_geometry(topo):
+    """Both recurrences at the serve cell's shapes (64 heads of 128; 16 slots;
+    an 8,192-token prompt in chunks of 128): Mosaic takes them, the decode
+    step's state result IS its state operand (no second array of its size,
+    no temporary at all), and the prefill holds a chunk's rows and nothing a
+    prompt long."""
+    from neuronx_distributed_tpu.kernels.delta_rule import kda_chunk_prefill, kda_decode_step
+
+    s = _one_chip(topo)
+    f32 = jnp.float32
+    decode = jax.jit(kda_decode_step, donate_argnums=0).lower(
+        s((16,) + SOLAR_STATE, f32), s((16, 64, 128)), s((16, 64, 128)), s((16, 64, 128)),
+        s((16, 64, 128), f32), s((16, 64), f32)).compile()
+    assert _kernels(decode.as_text()) == 1
+    m = decode.memory_analysis()
+    state = 16 * math.prod(SOLAR_STATE) * 4
+    assert m.alias_size_in_bytes == state and m.temp_size_in_bytes < state // 16
+    prefill = jax.jit(kda_chunk_prefill).lower(
+        s((1, 8192, 64, 128)), s((1, 8192, 64, 128)), s((1, 8192, 64, 128)), s((1, 8192, 64, 128), f32),
+        s((1, 8192, 64), f32), s((1, 8192), jnp.bool_)).compile()
+    assert _kernels(prefill.as_text()) == 1
+
+
+def _solar(cfg, seq):
+    from perfbench.families import solar_open2
+
+    return solar_open2.build(cfg, runner="serve", max_seq_len=seq)
+
+
+@pytest.mark.slow
+def test_solar_open2_engine_programs_compile_and_fit(topo):
+    """The benchmark configuration's programs (``perfbench/configs/
+    solar-open2-250b-serve.json``: 8 layers, 10 of 320 experts held, the
+    vocabulary's slice, 16 slots of 32,768, page 16): the fused decode chunk
+    with the two GQA layers' pools AND the six linear layers' per-slot state
+    carried, and the longest prompt's prefill, both with Pallas kernels and
+    inside the chip's memory. The decode program copies no pool leaf, holds no
+    row-sized array and NO ARRAY OF THE STATE'S SIZE among its temporaries
+    (the state goes through the chunk's scan and the kernel in place)."""
+    import json
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    with open(os.path.join(root, "perfbench", "configs", "solar-open2-250b-serve.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(root, "perfbench", "traffic", "analysis_closed.json")) as f:
+        longest = int(json.load(f)["prompt_len"]["max"])
+    seq, slots = int(config["serving"]["max_seq_len"]), int(config["serving"]["num_slots"])
+    model = _solar(config["model"], seq)
+    engine, lower_decode, lower_prefill, shards = _engine_programs(
+        topo, 1, slots=slots, seq=seq, bucket=longest, model=model)
+    assert engine.programs.resolved == {
+        "attention": "flash", "decode_attention": "paged_walk_fused",
+        "paged_attention": "fused", "moe_decode": "held",
+    }
+    state = (slots,) + SOLAR_STATE
+    assert shards.count(state) == 6 and [s for s in shards if s != state] == [(slots * seq // 16 + 1, 16, 16, 128)] * 2
+    decode = lower_decode().compile()
+    assert _kernels(decode.as_text())
+    live = _fits(decode, 15 * 1024**3)
+    text = decode.as_text()
+    pool = "bf16[%s]" % ",".join(map(str, (slots * seq // 16 + 1, 16, 16, 128)))
+    held = "f32[%s]" % ",".join(map(str, state))
+    copies = _copies_inside_loops(text, {pool, held})
+    assert not copies, f"{len(copies)} whole-leaf copies per decode step: " + "; ".join(copies[:3])
+    assert not _arrays_of_a_views_size(text, [(slots * seq // 16 + 1, 16, 16, 128)])
+    temp = decode.memory_analysis().temp_size_in_bytes
+    print(f"solar decode: live {live / 2**30:.2f} GiB, temporaries {temp / 2**30:.2f} GiB")
+    assert temp < 1.1 * SOLAR_DECODE_TEMP_GIB * 2**30 + 2**26, f"{temp / 2**30:.2f} GiB of decode temporaries"
+    prefill = lower_prefill().compile()
+    assert _kernels(prefill.as_text())
+    live = _fits(prefill, 15 * 1024**3)
+    temp = prefill.memory_analysis().temp_size_in_bytes
+    print(f"solar prefill[{longest}]: live {live / 2**30:.2f} GiB, temporaries {temp / 2**30:.2f} GiB")
+    assert temp < 1.1 * SOLAR_PREFILL_TEMP_GIB * 2**30, f"{temp / 2**30:.2f} GiB of prefill temporaries"

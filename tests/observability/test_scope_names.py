@@ -285,3 +285,27 @@ def test_the_compressed_latent_attention_programs_carry_their_names(zaya):
     assert cca | {"moe", "moe.router", "lm_head"} <= _optimized(decode)
     assert cca | {"moe", "moe.router"} <= _traced(prefill)
     assert {"attn.cca", "attn.cca.conv"} <= _optimized(prefill)
+
+
+@pytest.fixture(scope="module")
+def solar():
+    return _programs("solar-open2-250b-serve")
+
+
+def test_the_linear_attention_programs_carry_their_names(solar):
+    """``kda_block_dev_share_pct`` finds the scope ``attn.kda``,
+    ``kda_recur_dev_share_pct`` and both rooflines ``attn.kda.recur``: the
+    decode chunk's one-token state update is a Pallas kernel called there and
+    named after it (the prefill's chunked forward likewise on the chip; off it
+    this rehearsal's prefill runs the recurrence under ``lax.scan`` in the same
+    scope); the GQA layers' walking kernel is called in ``attn.full``, their
+    gate under ``attn.gate``, as Trinity's full layers'."""
+    decode, prefill = solar["decode_chunk"], solar["prefill"]
+    assert re.search(r"HloModule (\S+?),", decode[1]).group(1) == "jit_chunk_fn"
+    assert re.search(r"HloModule (\S+?),", prefill[1]).group(1) == "jit_fn"
+    assert {path.rsplit("/", 1)[-1] for path in KERNEL_CALL.findall(decode[0])} == {"attn.kda.recur", "attn.full"}
+    kda = {"attn.kda", "attn.kda.project", "attn.kda.conv", "attn.kda.gate", "attn.kda.recur", "attn.kda.out"}
+    assert kda | {"attn.full", "attn.gate", "kv_view", "moe", "moe.router", "moe.experts", "sample", "lm_head"} <= _traced(decode)
+    assert kda | {"attn.gate", "moe", "moe.router", "lm_head"} <= _optimized(decode)
+    assert kda | {"attn.full", "attn.gate", "moe", "moe.router"} <= _traced(prefill)
+    assert {"attn.kda", "attn.kda.project", "attn.kda.recur", "attn.kda.out"} <= _optimized(prefill)

@@ -13,7 +13,10 @@ and the rotated key joined, and one index key; a share of the experts held).
 ``joined``: Trinity's (K and V joined; window and full attention layers, a
 block table and a pool a layer kind). ``joined_state``: ZAYA1's (K and V
 joined, and per-slot STATE beside the pages: what a layer's next token needs of
-the slot's last one, a slot axis and no length axis).
+the slot's last one, a slot axis and no length axis). ``joined_recurrent``:
+Solar Open 2's (GQA layers with K and V joined; between them RECURRENT layers
+that keep a float32 state and their convolutions' taps a slot and nothing
+else: no per-token leaf, no page, no block table).
 
 What is particular to ONE kind stays in that kind's file
 (``test_latent_cache.py``, ``test_indexed_cache.py``,
@@ -24,6 +27,7 @@ once a process. A new cache kind is a row of ``KINDS``."""
 
 import dataclasses
 import functools
+import math
 from typing import Callable, Dict, Optional, Tuple
 
 import jax
@@ -108,6 +112,15 @@ def _joined_state():
             zaya1_8b(num_layers=2, param_dtype=jnp.bfloat16))
 
 
+def _joined_recurrent():
+    from neuronx_distributed_tpu.models.solar_open2 import SolarOpen2ForCausalLM, solar_open2_250b, tiny_solar_open2
+    from perfbench.references.solar_open2 import Reference
+    from tests.models.test_solar_open2 import published_keys
+
+    return (SolarOpen2ForCausalLM, tiny_solar_open2(max_seq_len=256, held_experts=(4, 8)), published_keys, Reference,
+            solar_open2_250b(num_layers=2, held_experts=(0, 2), param_dtype=jnp.bfloat16))
+
+
 @dataclasses.dataclass(frozen=True)
 class Kind:
     """A cache kind's row: what builds its tiny model and reference, and what
@@ -126,9 +139,14 @@ class Kind:
     # hold, and its preempt-and-rewind at the wall, are test_window_cache.py's
     whole_pool: bool = True
     refuses: Tuple[str, ...] = ("tp",)   # see ``REFUSALS``
-    # per-slot state leaf -> its width a slot, tiny widths (a slot axis, no length axis) ...
-    state: Dict[str, int] = dataclasses.field(default_factory=dict)
+    # per-slot state leaf -> its shape a slot, tiny widths (a slot axis, no length axis) ...
+    state: Dict[str, Tuple[int, ...]] = dataclasses.field(default_factory=dict)
     published_state_bytes: int = 0       # ... and their bytes a slot a layer at the published widths in bf16
+    state_layers: Optional[int] = None   # the layers that keep them; None: the pool's layers
+
+    @property
+    def state_bytes(self) -> int:        # a slot a layer, tiny widths in float32
+        return sum(math.prod(shape) for shape in self.state.values()) * 4
 
     @property
     def bytes(self) -> int:              # a token a layer, tiny widths in float32
@@ -160,9 +178,18 @@ KINDS = {
     # half, 2 x 96 + 16 (published: 2 x 1280 + 128 values, 5376 bytes). A context is not its pages alone,
     # so whatever holds one by them is refused at construction, each by name
     "joined_state": Kind(_joined_state, "paged_walk_fused", {"kv": (2 * 2, 16)}, ("layers_0", "layers_1", "layers_2"),
-                         {"kv": (4, 128)}, 1024, prefix_transports=(), state={"state": 2 * 96 + 16},
+                         {"kv": (4, 128)}, 1024, prefix_transports=(), state={"state": (2 * 96 + 16,)},
                          published_state_bytes=5376,
                          refuses=("tp", "prefix_cache", "kv_host_pages", "draft_model", "quantize.kv", "disagg")),
+    # layers 0 and 3 of 5 are GQA layers: 2 x 2 heads of 16 a token (published in bf16: (16, 128), 4096 bytes), the
+    # only layers the pool pages. Layers 1, 2 and 4 keep a slot 4 heads' float32 state of 16 x 16 and the last 3
+    # inputs of 3 x 4 x 16 convolution channels (published: 64 x 128 x 128 x 4 + 3 x 24,576 x 2 = 4,341,760 bytes)
+    # and NO per-token leaf: they map no page and ride the chunk's carry as they are
+    "joined_recurrent": Kind(_joined_recurrent, "paged_walk_fused", {"kv": (2 * 2, 16)}, ("layers_0", "layers_3"),
+                             {"kv": (16, 128)}, 4096, prefix_transports=(),
+                             state={"recur": (4, 16, 16), "conv": (3, 192)}, published_state_bytes=4341760,
+                             state_layers=3,
+                             refuses=("tp", "prefix_cache", "kv_host_pages", "draft_model", "quantize.kv", "disagg")),
 }
 WHOLE_POOL = [name for name, row in KINDS.items() if row.whole_pool]
 
@@ -292,9 +319,9 @@ def test_cache_leaves_hold_the_kinds_values_a_token_and_nothing_else(kind):
     # per-slot state: the kind's leaves and no other, (slots, width), the same bytes in every layout
     pool = kind.stream("fused")[0].cache.cache["pool"]
     assert {path[-1].key: leaf.shape for path, leaf in jax.tree_util.tree_flatten_with_path(pool)[0]
-            if path[-1].key in SLOT_STATE_LEAVES} == {name: (2, width) for name, width in row.state.items()}
+            if path[-1].key in SLOT_STATE_LEAVES} == {name: (2,) + shape for name, shape in row.state.items()}
     for path in PATHS:
-        assert slot_state_bytes_per_layer(kind.stream(path)[0].cache.cache) == 4 * sum(row.state.values())
+        assert slot_state_bytes_per_layer(kind.stream(path)[0].cache.cache) == row.state_bytes
     assert slot_state_bytes_per_layer(cache) == row.published_state_bytes
 
 
@@ -327,8 +354,8 @@ def test_fused_chunk_carries_every_leaf(kind):
     for pair in pairs.values():
         for leaf in pair:
             assert leaf.shape in carried
-    for width in kind.kind.state.values():     # a layer's state leaf rides the carry too
-        assert carried.count((2, width)) == len(kind.kind.layers)
+    for shape in kind.kind.state.values():     # a layer's state leaves ride the carry too, at their own rank
+        assert carried.count((2,) + shape) == (kind.kind.state_layers or len(kind.kind.layers))
     # no per-token leaf as long as a row anywhere in the chunk
     rows = [v.aval.shape for e in jaxpr.jaxpr.eqns for v in e.outvars
             if len(v.aval.shape) == 4 and v.aval.shape[:2] == (2, cfg.max_seq_len)]

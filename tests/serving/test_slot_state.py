@@ -205,3 +205,111 @@ def test_every_walker_classifies_the_state_leaf_by_name(system):
             refused()
     with pytest.raises(CacheKindUnsupported, match="quantized"):
         PagedCacheManager(2, 64, PAGE, kv_quant="int8").allocate_from(cache)
+
+
+# --- a RECURRENT layer's state: ``recur`` and ``conv`` and no page at all (Solar Open 2) ----------
+
+
+@pytest.fixture(scope="module")
+def recurrent():
+    from neuronx_distributed_tpu.models.solar_open2 import SolarOpen2ForCausalLM, tiny_solar_open2
+    from perfbench.references.solar_open2 import Reference as SolarReference
+    from tests.models.test_solar_open2 import published_keys as solar_keys, weights as solar_weights
+
+    cfg = tiny_solar_open2(max_seq_len=256)
+    model = SolarOpen2ForCausalLM(cfg, attention_impl="xla")
+    params = solar_weights(model)
+    return cfg, model, params, SolarReference(solar_keys(cfg), params)
+
+
+def recurrent_states(engine):
+    tree = engine.cache.cache
+    tree = tree["pool"] if isinstance(tree, dict) and "pool" in tree else tree
+    return [np.asarray(leaf) for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]
+            if path[-1].key == "recur"]
+
+
+@pytest.mark.parametrize("mode", ["fused"])
+def test_a_preempted_recurrent_slot_comes_back_through_a_prefill_that_rebuilds_its_state(recurrent, mode):
+    """A row of 128 columns ends under two requests: the engine preempts,
+    rewinds, and each comes back by a FULL prefill of prompt + tokens emitted
+    (no snapshot of the state, no seeded row), which leaves the state the
+    recurrence reaches over that context; streams stay the reference's, and
+    the GQA layers' pages all come back."""
+    from neuronx_distributed_tpu.models.solar_open2 import SolarOpen2ForCausalLM, tiny_solar_open2
+
+    _, _, params, ref = recurrent
+    model = SolarOpen2ForCausalLM(tiny_solar_open2(max_seq_len=128), attention_impl="xla")
+    engine = engine_of(recurrent, mode, model=model, admission="eager")
+    rng = np.random.default_rng(4)
+    reqs = [submit(engine, rng, 40, 80), submit(engine, rng, 60, 60)]
+    while engine.has_work:
+        engine.step()
+        engine.cache.check()
+    assert engine.metrics.preemptions >= 1
+    ran = {n for n, e in engine.programs.snapshot(analyze=False)["by_program"].items() if e["dispatches"]}
+    assert not ran & {"paged_seed", "suffix_prefill"}
+    for prompt, req in reqs:
+        assert len(req.tokens) == req.config.max_new_tokens
+        assert gaps(ref, prompt, req).max() < LOGIT_ATOL
+    assert engine.cache.alloc.free_pages == engine.cache.alloc.num_pages - 1
+
+
+@pytest.mark.parametrize("mode", ["row", "fused"])
+def test_a_freed_recurrent_slots_state_does_not_leak_into_the_next_admission(recurrent, mode):
+    """One slot: a long request, then a short one in the same slot, admitted
+    while ANOTHER slot decodes (so the shared cursor has moved and the short
+    prompt is left-padded in its bucket). Freeing leaves the old state where
+    it is (nothing clears 4 MiB a layer); the admission overwrites it with the
+    new prompt's, whose padding columns change nothing; the short request's
+    stream is the reference's (it would not be with anything of the first
+    left in ``recur`` or ``conv``)."""
+    ref = recurrent[3]
+    engine = engine_of(recurrent, mode, slots=2)
+    rng = np.random.default_rng(2)
+    long = submit(engine, rng, 70, 14)
+    other = submit(engine, rng, 33, 60)
+    engine.step()
+    slot = long[1].slot
+    while not long[1].finished:
+        engine.step()
+    left = recurrent_states(engine)
+    assert all(np.abs(s[slot]).max() > 0 for s in left)      # nothing cleared it: nothing needs to
+    short = submit(engine, rng, 9, 20)
+    engine.step()
+    assert short[1].slot == slot
+    engine.run()
+    for prompt, req in (long, other, short):
+        assert gaps(ref, prompt, req).max() < LOGIT_ATOL
+
+
+def test_the_dispatch_span_carries_the_recurrent_layers_stats(recurrent):
+    """Dispatch: the bytes a slot's state holds a layer (both leaves), how many
+    layers are recurrent and how many page. The gauge: all slots, all layers."""
+    cfg = recurrent[0]
+    engine = engine_of(recurrent, "fused")
+    submit(engine, np.random.default_rng(5), 12, 6)
+    seen, span = [], engine._span
+
+    def spy(name, **stats):
+        if name == "nxd.step.decode.dispatch":
+            seen.append(stats)
+        return span(name, **stats)
+
+    engine._span = spy
+    engine.run()
+    h, d = cfg.linear_num_heads, cfg.linear_head_dim
+    recur, conv = h * d * d * 4, (cfg.conv_kernel - 1) * cfg.conv_channels * 4       # float32 here
+    assert seen[0]["slot_state_bytes_per_layer"] == recur + conv
+    assert (seen[0]["recurrent_layers"], seen[0]["paged_layers"]) == (3, 2)
+    assert engine.metrics.view.gauge("serving_slot_state_bytes").value == 2 * 3 * (recur + conv)
+    assert engine.prefix is None and engine.cache.slot_state
+
+
+def test_every_walker_classifies_the_recurrent_leaves_by_name():
+    """Each name has its own rank after the slot axis: ``recur`` (slots,
+    heads, d, d), ``conv`` (slots, taps, channels); neither has a length axis."""
+    assert cache_batch_axis("recur", 4) == 0 and cache_batch_axis("recur", 5) == 1      # a scanned stack's
+    assert cache_batch_axis("conv", 3) == 0 and cache_batch_axis("conv", 4) == 1
+    assert cache_length_axis("recur", 4) is None and cache_length_axis("conv", 3) is None
+    assert set(SLOT_STATE_LEAVES) == {"state", "recur", "conv"}

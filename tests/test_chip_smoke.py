@@ -142,6 +142,18 @@ ROWS = {
         "zaya_cache_is_a_kib_a_token_and_the_state_five_and_a_quarter_a_slot", "zaya_no_convolution_is_caught",
         "zaya_unshifted_value_head_is_caught", "zaya_float8_is_caught", "zaya_no_router_state_is_caught",
         "kernel_zaya_programs")),
+    # ``--only solar`` at the CPU stand-in's size (4 layers: GQA, linear, linear, GQA; contexts of 150 in a row of
+    # 512, page 8); float32, so the tolerances are a float32 matmul's summation order and every control is caught
+    "solar": Row(chip_smoke.SolarSize(
+        model=_tiny("solar-open2-250b-serve"), max_seq_len=512, slots=3, page=8, prompt_lens=(150, 70, 20),
+        new_tokens=12, linear_tol=1e-4, full_tol=1e-4, gap_tol=1e-3, near_tie=0.0), (
+        "solar_matches_reference", "solar_two_mixers_alone_match_reference", "solar_cursor_jumps_leave_gap_columns",
+        "solar_leaks_no_page", "solar_resolved_paged_walk_fused",
+        "solar_cache_is_four_kib_a_token_and_the_state_four_mib_a_slot",
+        "solar_decode_chunk_copies_no_array_of_the_states_size", "solar_decay_off_is_caught",
+        "solar_beta_without_its_factor_is_caught", "solar_no_convolution_is_caught", "solar_no_output_gate_is_caught",
+        "solar_float8_state_is_caught", "solar_float8_is_caught", "solar_rotary_on_the_gqa_layer_is_caught", "solar_no_gqa_gate_is_caught",
+        "kernel_solar_programs"), chips_to_say=("_copies_no_array_of_the_states_size",)),
     # ``--only moe`` at a tiny size: every comparison must hold; which form is
     # FASTER is the chip's to say (an interpreted kernel's time says nothing)
     "moe": Row(chip_smoke.MoeSize(
@@ -233,7 +245,7 @@ def test_default_runs_are_the_tables_rows():
     assert chip_smoke.default_run(1) == ["train", "serve", "mla", "dsa", "glm"]
     assert chip_smoke.default_run(4) == ["tp_train", "tp_serve", "remat"]
     only = [name for name, phase in chip_smoke.PHASES.items() if phase.only]
-    assert only == ["mla", "dsa", "glm", "moe", "trinity", "zaya", "walk", "flash"]
+    assert only == ["mla", "dsa", "glm", "moe", "trinity", "zaya", "solar", "walk", "flash"]
     for name in only:
         assert chip_smoke.parse_args(["--only", name]).only == name
         assert chip_smoke.PHASES[name].help and chip_smoke.PHASES[name].chips == 1
