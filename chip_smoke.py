@@ -103,20 +103,39 @@ class MoeSize:
 
 
 @dataclasses.dataclass(frozen=True)
-class WalkSize:
-    """What ``--only walk`` runs (defaults: the chip run, the Trinity cell's
-    decode attention: ``perfbench/configs/trinity-large-serve.json``'s heads,
-    ``perfbench/traffic/mixedctx_closed.json``'s eight prompt lengths)."""
+class WalkShape:
+    """One cell's decode attention as the walking kernel meets it: the heads,
+    the slots' contexts ending at a shared cursor, and the window of its
+    window layers (``None``: it has none, and only a full layer is run)."""
 
-    q_heads: int = 48
-    kv_heads: int = 8
-    head_dim: int = 128
-    window: int = 4096
-    max_seq_len: int = 32768
+    name: str
+    q_heads: int
+    kv_heads: int
+    head_dim: int
+    max_seq_len: int
+    contexts: Tuple[int, ...]
+    cursor: int
+    window: Optional[int] = None
+    window_pages: int = 0         # a window layer's pool, pages a slot
+
+
+@dataclasses.dataclass(frozen=True)
+class WalkSize:
+    """What ``--only walk`` runs (defaults: the chip run). The Trinity cell's
+    decode attention (``perfbench/configs/trinity-large-serve.json``'s heads,
+    ``perfbench/traffic/mixedctx_closed.json``'s eight prompt lengths; a page
+    of its ``(16, 128)`` leaf is 64 KB) and the ZAYA1 cell's
+    (``zaya1-8b-serve.json``: 8 query heads against 2 kv heads, 32 slots of
+    16,384 columns holding ``reasoning_closed.json``'s 2-6k tokens; a page of
+    its ``(4, 128)`` leaf is 16 KB)."""
+
+    shapes: Tuple[WalkShape, ...] = (
+        WalkShape("trinity", 48, 8, 128, 32768,
+                  tuple(n + 256 for n in (2799, 4402, 5818, 7338, 9146, 11534, 15244, 16384)), 21000,
+                  window=4096, window_pages=272),
+        WalkShape("zaya1", 8, 2, 128, 16384, tuple(2000 + 125 * i for i in range(32)), 12000),
+    )
     page: int = 16
-    window_pages: int = 272       # a window layer's pool, pages a slot
-    contexts: Tuple[int, ...] = tuple(n + 256 for n in (2799, 4402, 5818, 7338, 9146, 11534, 15244, 16384))
-    cursor: int = 21000
     calls: int = 20
     dtype: str = "bfloat16"
     # largest |kernel - float32 einsum| over the output: bf16 probabilities
@@ -494,6 +513,10 @@ class MlaSize:
     prompt_lens: Tuple[int, ...] = (24576, 8192, 2048)
     tail: int = 256               # positions of the longest prompt compared in prefill logits
     new_tokens: int = 32
+    # the kernel alone (``mla_kernel``): the slots' contexts, ending at a shared cursor 8 pages under the row's end
+    # (eight prompt lengths of ``perfbench/traffic/docs_closed.json``'s kind + 256)
+    kernel_contexts: Tuple[int, ...] = tuple(n + 256 for n in (2880, 4536, 6010, 7600, 9003, 11800, 15400, 20566))
+    kernel_calls: int = 20
     # How far the system's prefill logits may lie from the reference's, bf16
     # serving against float32, over the positions compared (the largest
     # |difference| over the vocabulary at each). ``logit_tol`` bounds the
@@ -1015,6 +1038,7 @@ def mla_phase(size: MlaSize, seed: int) -> Dict[str, bool]:
     model = family.build(published, runner="serve", max_seq_len=size.max_seq_len)
     if size.model is not None:    # the CPU rehearsal serves in float32
         model = model.clone(config=dataclasses.replace(model.config, dtype=jnp.float32))
+    mla_kernel(size, published, seed, model.config.dtype)
     params = jax.jit(model.init)(jax.random.PRNGKey(seed), jnp.zeros((1, 8), jnp.int32))
     jax.block_until_ready(params)
     engine = ServingEngine(model, params, num_slots=size.slots, kv_page_size=16)
@@ -1099,6 +1123,38 @@ def mla_phase(size: MlaSize, seed: int) -> Dict[str, bool]:
     }
 
 
+def mla_kernel(size: MlaSize, published: dict, seed: int, dtype) -> None:
+    """The paged latent decode kernel alone at the cell's shapes (8 slots of
+    the row's columns, the docs tape's contexts ending at a shared cursor):
+    ms a call and GB/s of the latent rows it needs, under a random block table
+    and under the one the serving pool deals (two leaves: a run is one copy
+    of 64 KB and one of 16 KB, where a page is 16 KB + 4 KB)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from neuronx_distributed_tpu.kernels.flash_decode import LATENT_BLOCK_TOKENS, paged_latent_decode_attention
+
+    h, d_c, d_r = (int(published[k]) for k in ("num_attention_heads", "kv_lora_rank", "qk_rope_head_dim"))
+    scale = (int(published["qk_nope_head_dim"]) + d_r) ** -0.5
+    page, length, item = 16, size.max_seq_len, jnp.dtype(dtype).itemsize
+    cur = length - 8 * page
+    ctx = _page_started(size.kernel_contexts, cur, page)
+    b, n_log = len(ctx), length // page
+    scattered, valid = _scattered_table(np.random.default_rng(seed), ctx, cur, length, page)
+    key = jax.random.split(jax.random.PRNGKey(seed), 4)
+    c_pool = jax.random.normal(key[0], (b * n_log + 1, page, 1, d_c), dtype)
+    r_pool = jax.random.normal(key[1], (b * n_log + 1, page, 1, d_r), dtype)
+    q_c, q_r = jax.random.normal(key[2], (b, 1, h, d_c), dtype), jax.random.normal(key[3], (b, 1, h, d_r), dtype)
+    ok, pos = jnp.asarray(valid), jnp.asarray([cur], jnp.int32)
+    _table_pair(
+        f"mla kernel: latent decode attention, {b} slots holding {sum(ctx)} tokens, {h} heads",
+        lambda bt: ((lambda qc, qr, c, r: paged_latent_decode_attention(
+            qc, qr, c, r, bt, pos, ok, scale=scale, page_size=page)), (q_c, q_r, c_pool, r_pool)),
+        {"random": scattered, "dealt": _dealt_tables(ctx, cur, length, page)[0]}, sum(ctx) * (d_c + d_r) * item, page,
+        page * (d_c + -(-d_r // 128) * 128) * item, min(LATENT_BLOCK_TOKENS // page, n_log), pos[0] + 1,
+        size.kernel_calls)
+
 
 # --- learned sparse attention ---------------------------------------------------------
 
@@ -1136,6 +1192,7 @@ def dsa_kernels(size: DsaSize, published: dict, seed: int, dtype) -> Dict[str, b
     import numpy as np
 
     from neuronx_distributed_tpu.kernels.flash_decode import (
+        LATENT_BLOCK_TOKENS,
         SPARSE_CHUNK_TOKENS,
         paged_gather_leaf,
         paged_index_scores,
@@ -1154,16 +1211,10 @@ def dsa_kernels(size: DsaSize, published: dict, seed: int, dtype) -> Dict[str, b
     h, hkv, d = (int(published[k]) for k in ("num_attention_heads", "num_key_value_heads", "head_dim"))
     h_i, d_i, keep = int(sa["indexer_num_heads"]), int(sa["indexer_head_dim"]), int(sa["topk"])
     page, length = 16, size.max_seq_len
-    ctx, cur = list(size.kernel_contexts), size.kernel_cursor
+    cur = size.kernel_cursor
+    ctx = _page_started(size.kernel_contexts, cur, page)
     b, n_log = len(ctx), length // page
-    rng = np.random.default_rng(seed)
-    table = np.zeros((b, n_log), np.int32)
-    valid = np.zeros((b, length), bool)
-    ids = rng.permutation(np.arange(1, b * n_log + 1))
-    for i, n in enumerate(ctx):            # contexts END at the shared cursor
-        lo, hi = (cur + 1 - n) // page, cur // page + 1
-        table[i, lo:hi] = ids[i * n_log + lo:i * n_log + hi]
-        valid[i, cur + 1 - n:cur + 1] = True
+    table, valid = _scattered_table(np.random.default_rng(seed), ctx, cur, length, page)   # contexts END at the cursor
     key = jax.random.split(jax.random.PRNGKey(seed), 6)
     pages = b * n_log + 1
     # the indexed cache's joined leaf: a token's K heads, then its V heads
@@ -1186,8 +1237,15 @@ def dsa_kernels(size: DsaSize, published: dict, seed: int, dtype) -> Dict[str, b
     score_err = float(np.abs(np.asarray(got)[ok_cols] - np.asarray(want)[ok_cols]).max())
     score_ok = score_err <= size.kernel_tol * float(np.abs(np.asarray(want)[ok_cols]).max()) and bool(
         np.isneginf(np.asarray(got)[~ok_cols]).all())
-    score_ms = _median_call_ms(score, (q_idx, w_idx, i_pool), size.kernel_calls)
     score_bytes = sum(ctx) * d_i * 2
+    # the same scores under the table the serving pool deals: runs of adjacent pages, one copy a run
+    score_ms = _table_pair(
+        f"dsa kernels: index scores over the {d_i}-wide leaf, {b} slots holding {sum(ctx)} tokens",
+        lambda bt: ((lambda qi, w, pool: paged_index_scores(qi, w, pool, bt, pos, valid, page_size=page)),
+                    (q_idx, w_idx, i_pool)),
+        {"random": table, "dealt": _dealt_tables(ctx, cur, length, page)[0]}, score_bytes, page,
+        page * -(-d_i // 128) * 128 * 2, min(LATENT_BLOCK_TOKENS // page, n_log), pos[0] + 1,
+        size.kernel_calls)["random"][0]
 
     top = lambda s: jax.lax.top_k(s, keep)   # noqa: E731
     vals, cols = jax.jit(top)(got)
@@ -1466,6 +1524,24 @@ def glm_kernel(size: GlmSize, published: dict, seed: int, dtype) -> Dict[str, bo
         f"{jnp.dtype(dtype).name}: sparse latent attention {ms:.3f} ms a call for {used / 1e6:.1f} MB held by the "
         f"selected rows ({used / ms / 1e6:.1f} GB/s; {copies} copies of {rows * lanes * 2} B, "
         f"{1e6 * ms / copies:.1f} ns each), max |kernel - float32 jnp| {err:.5f}")
+    # the index-score kernel over GLM-5's 128-wide index keys, the same contexts ending at a shared cursor: under
+    # a random table and under the one the serving pool deals (runs of adjacent pages, one copy a run)
+    from neuronx_distributed_tpu.kernels.flash_decode import LATENT_BLOCK_TOKENS, paged_index_scores
+
+    h_i, d_i = int(published["index_n_heads"]), int(published["index_head_dim"])
+    cur = length - 8 * page
+    ends = _page_started(ctx, cur, page)
+    scattered, valid = _scattered_table(rng, ends, cur, length, page)
+    key = jax.random.split(jax.random.PRNGKey(seed + 1), 3)
+    i_pool = jax.random.normal(key[0], (b * n_log + 1, page, 1, d_i), dtype)
+    q_idx, w_idx = jax.random.normal(key[1], (b, 1, h_i, d_i), dtype), jax.random.normal(key[2], (b, 1, h_i), dtype)
+    ok, pos = jnp.asarray(valid), jnp.asarray([cur], jnp.int32)
+    _table_pair(
+        f"glm kernel: index scores over the {d_i}-wide leaf, {b} slots holding {sum(ends)} tokens",
+        lambda bt: ((lambda qi, w, pool_: paged_index_scores(qi, w, pool_, bt, pos, ok, page_size=page)),
+                    (q_idx, w_idx, i_pool)),
+        {"random": scattered, "dealt": _dealt_tables(ends, cur, length, page)[0]}, sum(ends) * d_i * 2, page,
+        page * -(-d_i // 128) * 128 * 2, min(LATENT_BLOCK_TOKENS // page, n_log), pos[0] + 1, size.kernel_calls)
     return {"glm_sparse_latent_kernel_matches_jnp": err <= size.kernel_tol}
 
 
@@ -2367,13 +2443,15 @@ def _walk_listing_compile(size: WalkSize = WalkSize()) -> None:
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     one = SingleDeviceSharding(topo.devices[0])
     s = lambda shape, dtype=jnp.dtype(size.dtype): jax.ShapeDtypeStruct(shape, dtype, sharding=one)   # noqa: E731
-    b, page, n_log = len(size.contexts), size.page, size.max_seq_len // size.page
+    shape = size.shapes[0]
+    b, page, n_log = len(shape.contexts), size.page, shape.max_seq_len // size.page
 
     def walk_step(q, pool, bt, pos, ok, lo):
         return paged_walk_decode_attention(q, pool, bt, pos, kv_valid=ok, floor=lo, page_size=page)
 
     jax.jit(walk_step).lower(
-        s((b, 1, size.q_heads, size.head_dim)), s((b * size.window_pages + 1, page, 2 * size.kv_heads, size.head_dim)),
+        s((b, 1, shape.q_heads, shape.head_dim)),
+        s((b * shape.window_pages + 1, page, 2 * shape.kv_heads, shape.head_dim)),
         s((b, n_log), jnp.int32), s((1,), jnp.int32), s((b, n_log * page), jnp.bool_), s((b,), jnp.int32)).compile()
 
 
@@ -2390,7 +2468,7 @@ def walk_block_bundles(directory: str, size: WalkSize = WalkSize()) -> int:
     os.makedirs(directory, exist_ok=True)
     env = dict(os.environ, JAX_PLATFORMS="cpu", TPU_LOG_DIR="disabled",
                LIBTPU_INIT_ARGS=f"--xla_jf_dump_to={directory} --xla_jf_dump_llo_text=true")
-    code = f"import chip_smoke; chip_smoke._walk_listing_compile(chip_smoke.WalkSize(**{dataclasses.asdict(size)!r}))"
+    code = f"import chip_smoke; from chip_smoke import WalkShape, WalkSize; chip_smoke._walk_listing_compile({size!r})"
     subprocess.run([sys.executable, "-c", code], env=env, cwd=os.path.dirname(os.path.abspath(__file__)),
                    stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL, timeout=600)
     listings = [f for f in glob.glob(os.path.join(directory, "*walk_step*final_bundles.txt"))
@@ -2406,17 +2484,148 @@ def walk_block_bundles(directory: str, size: WalkSize = WalkSize()) -> int:
     return marks[end][0] - marks[start][0]
 
 
+def _dealt_tables(contexts: Sequence[int], cursor: int, max_seq_len: int, page: int,
+                  window: Optional[int] = None, chunk: int = 8):
+    """``(full table, window table or None, pool pages of each kind)`` as the
+    serving pool deals them: a ``PagedCacheManager`` (over leaves of a few
+    bytes a token) taken through the life that leaves ``contexts`` ending at
+    ``cursor``. Each slot is admitted with the first third of its context as
+    its prompt (in whole 512s) once the shared cursor reaches the prompt's end,
+    and decodes the rest in chunks of ``chunk`` columns beside the others, a
+    decode window asked for before every chunk as the engine does. Contexts
+    start on a page (``cursor + 1 - n`` a multiple of ``page``)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from neuronx_distributed_tpu.serving.paging import PagedCacheManager
+
+    mgr = PagedCacheManager(len(contexts), max_seq_len, page, window=window, window_write_cols=chunk)
+
+    def row(padded):
+        def node(w):
+            leaves = {"kv": jnp.zeros((1, max_seq_len, 2, 4), jnp.float32), "index": jnp.asarray(padded, jnp.int32),
+                      "kv_valid": jnp.arange(max_seq_len)[None] < padded}
+            return leaves if w is None else {**leaves, "window": jnp.zeros((0, w), bool)}
+        return {"full": {"attn": node(None)}, **({} if window is None else {"windowed": {"attn": node(window)}})}
+
+    starts = [cursor + 1 - n for n in contexts]
+    assert all(st % page == 0 and st >= 0 for st in starts), "contexts start on a page"
+    prompts = [max(n // 3 // 512 * 512, page) for n in contexts]
+    slot_of, active = {}, []
+
+    def decode_to(column):
+        while active and mgr.cursor < column:
+            assert mgr.ensure_decode_window(active, chunk), "the pool holds every slot's whole row"
+            mgr.update_after_decode(mgr.cache, min(chunk, column - mgr.cursor))
+
+    for i in sorted(range(len(contexts)), key=lambda i: starts[i] + prompts[i]):
+        decode_to(starts[i] + prompts[i])
+        slot_of[i] = mgr.acquire()
+        mgr.admit(row(prompts[i]), slot_of[i], prompts[i], cursor=starts[i] + prompts[i], p=prompts[i])
+        active.append(slot_of[i])
+    decode_to(cursor)
+    assert mgr.cursor == cursor and mgr.ensure_decode_window(active, chunk)
+    mgr.check()
+    order = [slot_of[i] for i in range(len(contexts))]
+    full = mgr._tables[order].copy()
+    if window is None:
+        return full, None, (mgr.alloc.num_pages, 0)
+    return full, mgr._tables_w[order].copy(), (mgr.alloc.num_pages, mgr.alloc_w.num_pages)
+
+
+def _page_started(contexts: Sequence[int], cursor: int, page: int) -> List[int]:
+    """``contexts`` that end at ``cursor``, each lengthened to START on a page
+    (what :func:`_dealt_tables` can deal)."""
+    return [cursor + 1 - (cursor + 1 - n) // page * page for n in contexts]
+
+
+def _scattered_table(rng, contexts: Sequence[int], cursor: int, length: int, page: int):
+    """``(table, valid)``: the pages under ``contexts`` ending at ``cursor``
+    drawn as a random permutation of a pool of ``slots x length / page + 1``
+    pages (no two pages of a slot adjacent), and the columns they hold."""
+    import numpy as np
+
+    b, n_log = len(contexts), length // page
+    ids, at = 1 + rng.permutation(b * n_log), 0
+    table, valid = np.zeros((b, n_log), np.int32), np.zeros((b, length), bool)
+    for i, n in enumerate(contexts):
+        lo, hi = (cursor + 1 - n) // page, cursor // page + 1
+        valid[i, cursor + 1 - n:cursor + 1], table[i, lo:hi] = True, ids[at:at + hi - lo]
+        at += hi - lo
+    return table, valid
+
+
+def _block_copies(table, live, group: int) -> int:
+    """Copies a leaf that the block-walking kernels start for ``table`` over
+    its ``live`` blocks of ``group`` pages: one a run of ``PAGE_RUN`` pages in
+    a trip whose entries all read runs (``flash_decode._trip_runs``), one a
+    page in any other (and in a tree from before runs, measured beside this
+    one: there the kernels have no ``_trip_runs``)."""
+    import numpy as np
+
+    from neuronx_distributed_tpu.kernels import flash_decode
+
+    live = np.asarray(live)
+    if not hasattr(flash_decode, "_trip_runs"):
+        return int(live.sum()) * group
+    trip = flash_decode._issue_trip(group)
+    whole = np.asarray(flash_decode._trip_runs(np.asarray(table), group)).reshape(live.shape[0], live.shape[1], -1)
+    return int((np.where(whole != 0, trip // flash_decode.PAGE_RUN, trip).sum(axis=2) * live).sum())
+
+
+def _table_pair(tag: str, call_of, tables, needed: int, page: int, page_bytes: int, group: int, bound,
+                calls: int, want=None) -> Dict[str, Tuple[float, Optional[float]]]:
+    """One block-walking kernel under each of ``tables`` (name -> block
+    table: ``random``, a permutation of the pool, no two pages of a slot
+    adjacent; ``dealt``, as the serving pool deals them, :func:`_dealt_tables`):
+    ms a call, GB/s of the ``needed`` bytes and of the bytes its blocks fetch
+    (``page_bytes`` a page: every leaf's), and the copies a leaf a call
+    starts. ``call_of(table)`` -> ``(fn, args)``; with ``want`` (``table ->
+    array``) the largest ``|result - want|`` too. ``{table name: (ms, that
+    difference or None)}``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from neuronx_distributed_tpu.kernels import flash_decode
+
+    out = {}
+    for name, table in tables.items():
+        fn, args = call_of(jnp.asarray(table))
+        err = None
+        if want is not None:
+            got = jax.jit(fn)(*args).astype(jnp.float32)
+            err = float(np.abs(np.asarray(got) - np.asarray(want(jnp.asarray(table)))).max())
+        ms = _median_call_ms(fn, args, calls)
+        live = flash_decode._latent_block_walk(jnp.asarray(table), bound, group, page)[0]
+        blocks, copies = int(np.asarray(live).sum()), _block_copies(table, live, group)
+        log(f"{tag}, {name} table: {ms:.4f} ms a call, {needed / ms / 1e6:.1f} GB/s of {needed / 1e6:.1f} MB needed; "
+            f"{blocks} blocks of {group} pages of {page_bytes} B fetched ({blocks * group * page_bytes / ms / 1e6:.1f} "
+            f"GB/s) with {copies} copies a leaf ({1e6 * ms / max(copies, 1):.1f} ns each)"
+            + ("" if err is None else f"; largest |kernel - float32 reference| {err:.5f}"))
+        out[name] = (ms, err)
+    return out
+
+
 def walk_phase(size: WalkSize, seed: int) -> Dict[str, bool]:
     """The walking GQA decode kernel alone at the Trinity cell's shapes, a
-    window layer and a full layer: against the float32 einsum, ms a call and
-    GB/s of needed and fetched bytes. Not part of the default run: it serves
+    window layer and a full layer, and at the ZAYA1 cell's, under a random
+    block table and under one the serving pool dealt: against the float32
+    einsum, ms a call, GB/s of needed and fetched bytes and the copies a call
+    starts. Not part of the default run: it serves
     nothing. ``kernels/flash_decode.paged_walk_decode_attention``, a call =
-    one layer of a decode step: 8 slots of 32,768 columns, page 16, 48 query
-    heads against 8 kv heads of 128, the tape's eight prompt lengths + 256 as
-    contexts that end at a shared cursor; once as a window layer (272 pages a
-    slot, ``floor`` set) and once as a full layer. Each against the float32
-    einsum under an index mask, then ms a call with the GB/s of the bytes the
-    call needs (``perfbench/swa_costs.py``) and of the bytes its blocks fetch.
+    one layer of a decode step: Trinity's 8 slots of 32,768 columns, page 16,
+    48 query heads against 8 kv heads of 128 (a page is 64 KB), the tape's
+    eight prompt lengths + 256 as contexts that end at a shared cursor, once
+    as a window layer (272 pages a slot, ``floor`` set) and once as a full
+    layer; ZAYA1's 32 slots of 16,384 columns, 8 query heads against 2 kv
+    heads (a page is 16 KB), a full layer. Each under two block tables: a
+    random permutation of the pool (no two pages of a slot adjacent: a copy a
+    page) and the table a ``PagedCacheManager`` deals over the slots' lives
+    (:func:`_dealt_tables`: runs of adjacent pages, one copy a run). Each
+    against the float32 einsum under an index mask, then ms a call with the
+    GB/s of the bytes the call needs (``perfbench/swa_costs.py``) and of the
+    bytes its blocks fetch.
     With ``--bundles DIR`` ``main`` first compiles the kernel for a described
     v5e in a process of its own with the compiler's listing dumped to ``DIR``,
     and prints how many instruction bundles one block's body is (no chip
@@ -2430,53 +2639,59 @@ def walk_phase(size: WalkSize, seed: int) -> Dict[str, bool]:
     from neuronx_distributed_tpu.modules.attention import _masked_gqa_attention, split_kv
     from perfbench.swa_costs import swa_decode_cost
 
-    dtype = jnp.dtype(size.dtype)
-    h, hkv, d, page, cur = size.q_heads, size.kv_heads, size.head_dim, size.page, size.cursor
-    ctx, b, n_log = list(size.contexts), len(size.contexts), size.max_seq_len // size.page
-    group = min(max(flash_decode.WALK_BLOCK_TOKENS // page, 1), n_log)
-    pos = jnp.asarray([cur], jnp.int32)
-    valid = np.zeros((b, size.max_seq_len), bool)
-    for i, n in enumerate(ctx):            # contexts END at the shared cursor
-        valid[i, cur + 1 - n:cur + 1] = True
+    dtype, page = jnp.dtype(size.dtype), size.page
     f32 = lambda a: a.astype(jnp.float32)    # noqa: E731
     checks: Dict[str, bool] = {}
-    for kind, window in (("window", size.window), ("full", None)):
-        rng = np.random.default_rng(seed)
-        lowest = [cur + 1 - (n if window is None else min(n, window)) for n in ctx]
-        held = [range(lo // page, cur // page + 1) for lo in lowest]
-        per_slot = size.window_pages if window is not None else max(len(r) for r in held)
-        assert all(len(r) <= per_slot for r in held), "a window of pages a slot does not hold the window"
-        ids = rng.permutation(np.arange(1, b * per_slot + 1))
-        table = np.zeros((b, n_log), np.int32)
-        for i, r in enumerate(held):
-            table[i, r.start:r.stop] = ids[i * per_slot:i * per_slot + len(r)]
-        keys = jax.random.split(jax.random.PRNGKey(seed), 3)
-        # the joined leaf: a token's K heads, then its V heads
-        pool = jax.random.normal(keys[0], (b * per_slot + 1, page, 2 * hkv, d), dtype)
-        q = jax.random.normal(keys[1], (b, 1, h, d), dtype)
-        floor = None if window is None else jnp.asarray(lowest, jnp.int32)
-        bt, ok = jnp.asarray(table), jnp.asarray(valid)
+    for shape in size.shapes:
+        h, hkv, d, cur = shape.q_heads, shape.kv_heads, shape.head_dim, shape.cursor
+        ctx = _page_started(shape.contexts, cur, page)
+        b, n_log = len(ctx), shape.max_seq_len // page
+        group = min(max(flash_decode.WALK_BLOCK_TOKENS // page, 1), n_log)
+        pos = jnp.asarray([cur], jnp.int32)
+        valid = np.zeros((b, shape.max_seq_len), bool)
+        for i, n in enumerate(ctx):            # contexts END at the shared cursor
+            valid[i, cur + 1 - n:cur + 1] = True
+        dealt = _dealt_tables(ctx, cur, shape.max_seq_len, page, shape.window)
+        for kind, window in (("window", shape.window), ("full", None)):
+            if kind == "window" and window is None:
+                continue
+            rng = np.random.default_rng(seed)
+            lowest = [cur + 1 - (n if window is None else min(n, window)) for n in ctx]
+            held = [range(lo // page, cur // page + 1) for lo in lowest]
+            pages = dealt[2][kind == "window"]
+            assert pages > sum(len(r) for r in held), "the pool does not hold the contexts"
+            ids = 1 + rng.permutation(pages - 1)
+            table, at = np.zeros((b, n_log), np.int32), 0
+            for i, r in enumerate(held):
+                table[i, r.start:r.stop] = ids[at:at + len(r)]
+                at += len(r)
+            keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+            # the joined leaf: a token's K heads, then its V heads
+            pool = jax.random.normal(keys[0], (pages, page, 2 * hkv, d), dtype)
+            q = jax.random.normal(keys[1], (b, 1, h, d), dtype)
+            floor = None if window is None else jnp.asarray(lowest, jnp.int32)
+            ok = jnp.asarray(valid)
+            keep = jnp.asarray((valid & (np.arange(shape.max_seq_len)[None] >= np.asarray(lowest)[:, None]))[:, None])
 
-        # every array is an ARGUMENT: closed over, the pool would be a constant of the program
-        walk = lambda qq, kvp: paged_walk_decode_attention(   # noqa: E731
-            qq, kvp, bt, pos, kv_valid=ok, floor=floor, page_size=page)
-        got = jax.jit(walk)(q, pool)
-        keep = valid & (np.arange(size.max_seq_len)[None] >= np.asarray(lowest)[:, None])
-        with jax.default_matmul_precision("highest"):
-            want = jax.jit(lambda qq, kvp: _masked_gqa_attention(
-                f32(qq), *split_kv(f32(paged_gather_leaf(kvp, bt, page))), jnp.asarray(keep[:, None])))(q, pool)
-        err = float(np.abs(np.asarray(f32(got)) - np.asarray(want)).max())
-        ms = _median_call_ms(walk, (q, pool), size.calls)
-        needed = swa_decode_cost(ctx, num_q_heads=h, num_kv_heads=hkv, head_dim=d, window=window,
-                                 act_bytes=dtype.itemsize)[1]
-        blocks = int(flash_decode._latent_block_walk(bt, pos[0] + 1, group, page)[0].sum())
-        fetched = blocks * group * page * 2 * hkv * d * dtype.itemsize
-        log(f"walk {kind}: {b} slots, contexts {min(ctx)}-{max(ctx)} ending at {cur}, {blocks} blocks of "
-            f"{group * page} tokens: {ms:.4f} ms a call, {needed / ms / 1e6:.1f} GB/s of {needed / 1e6:.1f} MB needed, "
-            f"{fetched / ms / 1e6:.1f} GB/s of {fetched / 1e6:.1f} MB fetched; largest |kernel - float32 einsum| "
-            f"{err:.5f} (limit {size.tol})")
-        checks[f"walk_{kind}_matches_the_float32_einsum"] = err <= size.tol
-        del pool, got, want
+            # every array is an ARGUMENT: closed over, the pool would be a constant of the program
+            def call_of(bt):
+                return (lambda qq, kvp: paged_walk_decode_attention(
+                    qq, kvp, bt, pos, kv_valid=ok, floor=floor, page_size=page)), (q, pool)
+
+            def want(bt):
+                with jax.default_matmul_precision("highest"):
+                    return jax.jit(lambda qq, kvp: _masked_gqa_attention(
+                        f32(qq), *split_kv(f32(paged_gather_leaf(kvp, bt, page))), keep))(q, pool)
+
+            needed = swa_decode_cost(ctx, num_q_heads=h, num_kv_heads=hkv, head_dim=d, window=window,
+                                     act_bytes=dtype.itemsize)[1]
+            got = _table_pair(
+                f"walk {shape.name} {kind}: {b} slots, contexts {min(ctx)}-{max(ctx)} ending at {cur} (limit {size.tol})",
+                call_of, {"random": table, "dealt": dealt[kind == "window"]}, needed, page,
+                page * 2 * hkv * d * dtype.itemsize, group, pos[0] + 1, size.calls, want)
+            for name, (_, err) in got.items():
+                checks[f"walk_{shape.name}_{kind}_{name}_table_matches_the_float32_einsum"] = err <= size.tol
+            del pool
     return checks
 
 

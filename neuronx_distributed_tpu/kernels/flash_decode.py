@@ -746,31 +746,52 @@ def paged_flash_decode_attention(
 # loop walks the blocks of ``LATENT_BLOCK_TOKENS`` tokens that THIS slot maps:
 # from the first block holding a mapped page to the last one at or before the
 # cursor (``span``, computed from the block table outside the kernel), not the
-# whole row. A block is one async copy a page a leaf, issued back to back
-# into one half of a two-block VMEM buffer; block ``i + 1``'s copies are in
-# flight while block ``i`` is waited on and multiplied. A block inside the
-# span whose pages are all unmapped (``live`` 0) is neither fetched nor
-# computed; a slot that maps nothing fetches nothing and writes zeros. The
-# last block of a row whose pages do not fill it re-fetches the row's last
-# page into the spare rows: their columns lie past every row position.
+# whole row. A block is one async copy a leaf for each RUN of adjacent pool
+# pages its table entries read (the serving pool deals a slot's pages four at
+# a time: :data:`PAGE_RUN`) and a copy a page elsewhere, issued back to back
+# into one part of a VMEM buffer of :data:`BLOCKS_AHEAD` + 1 blocks; the next
+# blocks' copies are in flight while block ``i`` is waited on and multiplied
+# (:func:`_walk_blocks`). A block inside the span whose pages are all unmapped
+# (``live`` 0) is neither fetched nor computed; a slot that maps nothing
+# fetches nothing and writes zeros. The last block of a row whose pages do not
+# fill it re-fetches the row's last page into the spare rows: their columns
+# lie past every row position.
 #
 # Why not a BlockSpec a page (the form this replaced): at 8 slots of 32,768
 # columns that was 1024 grid steps a call, and the pipeline's bookkeeping for
 # 32 page operands on every one of them, mapped or not, took 0.97 of the
-# call's 1.12 ms; this form takes 0.24 (v5e, PERF.md §6, PR 29). What holds
-# it now is issuing the copies (two a page of 16 tokens), which does not
-# overlap the multiply.
+# call's 1.12 ms; this form takes 0.24 (v5e, PERF.md §6, PR 29). What held
+# it then was NAMING the copies (two a page of 16 tokens: ~28 cycles of scalar
+# work each, two bounds checks among them, in the multiply's own instruction
+# stream): with one copy a run of four pages the same call takes 0.18 ms
+# under a table the pool dealt, 0.25 under one with no run (PERF.md §6, PR 52).
 
 # Tokens a block. Swept on the v5e at DeepSeek-V2-Lite's geometry (8 slots,
 # 16 heads, contexts of 3k-21k ending at a cursor of 22,000; PERF.md §6,
-# PR 29). Two blocks of 1024 x (512 + 64 padded to 128) bf16 are 2.5 MB of VMEM.
+# PR 29). Three blocks of 1024 x (512 + 64 padded to 128) bf16 are 3.75 MB of VMEM.
 LATENT_BLOCK_TOKENS = 1024
 
-# Pages a trip of the loop that issues (or waits for) a block's copies: the
-# trip's copies are unrolled, the trips are not. Rolled up page by page the
-# call takes 0.32 ms, unrolled whole 0.25 (and 0.35 s to trace and lower in
-# every process, not 0.14), 16 a trip 0.23-0.24 (v5e, PERF.md §6, PR 29).
+# Pages a trip of the loop that issues a block's copies: the trip's copies
+# are unrolled, the trips are not. Rolled up page by page the call takes 0.32
+# ms, unrolled whole 0.25 (and 0.35 s to trace and lower in every process, not
+# 0.14), 16 a trip 0.23-0.24 (v5e, PERF.md §6, PR 29). A trip is also what is
+# fetched a copy a RUN or a copy a page (:func:`_trip_runs`), so it stays a
+# multiple of :data:`PAGE_RUN`.
 _PAGES_A_TRIP = 16
+
+# Blocks whose copies are in flight while one is multiplied. Alone, a block
+# of ZAYA1's leaf (512 KB) is fetched in 0.70 us and multiplied in 0.77; with
+# one block ahead the two took 1.07 us together under a dealt table, with two
+# 1.03, and Trinity's full layer 0.515 -> 0.511 ms a call (v5e, PERF.md
+# section 6, PR 52): a block's last bytes land later than its multiply's
+# predecessor ends. Three blocks of the widest leaf that walks (Trinity's 512
+# x (16, 128) bf16) are 6 MiB of VMEM.
+BLOCKS_AHEAD = 2
+
+# Pages a RUN: adjacent pool pages under one aligned group of a block's table
+# entries are fetched with one copy (:func:`_block_page_copies`), and the
+# serving pool deals a slot's pages in such runs (``serving/paging.py``).
+PAGE_RUN = 4
 
 
 def _hbm_lanes(width: int, interpret: bool) -> Optional[int]:
@@ -787,105 +808,182 @@ def _hbm_lanes(width: int, interpret: bool) -> Optional[int]:
     return -(-width // 128) * 128
 
 
-def _block_page_copies(leaves, sem, page_id, page_size, group, wait=False):
-    """Issue one block's page copies, or with ``wait`` wait for them: for
-    each page ``g`` of the block's ``group`` and each of ``leaves`` (``(pool
-    ref, block ref, lanes)``: a ``(P, page_size, ...)`` pool left in HBM, the
-    VMEM block of ``group * page_size`` rows it lands in, :func:`_hbm_lanes`
-    of its width), one async copy of pool page ``page_id(g)`` to rows ``[g *
-    page_size, (g + 1) * page_size)``, all signalling ``sem``. A wait names
-    the same bytes whatever the page was."""
-    trip = math.gcd(group, _PAGES_A_TRIP)
+def _issue_trip(group: int) -> int:
+    """Pages a trip of the loop that issues a block's copies."""
+    return math.gcd(group, _PAGES_A_TRIP)
+
+
+def _trip_runs(block_table, group):
+    """``(B, n_blocks * trips a block)`` int32 from the block table: 1 where
+    EVERY aligned group of :data:`PAGE_RUN` entries of a trip's pages reads
+    adjacent pool pages ``p, p + 1, ...`` (``p`` not the null page): the trip
+    is fetched a copy a run. The last block's spare entries repeat the row's
+    last page, as the kernel reads them (no run)."""
+    trip = _issue_trip(group)
+    b, n_log = block_table.shape
+    n_blocks = pl.cdiv(n_log, group)
+    if trip % PAGE_RUN:
+        return jnp.zeros((b, n_blocks * (group // trip)), jnp.int32)
+    padded = jnp.pad(block_table, ((0, 0), (0, n_blocks * group - n_log)), mode="edge")
+    ids = padded.reshape(b, -1, PAGE_RUN)
+    whole = (ids[:, :, 0] != 0) & jnp.all(ids[:, :, 1:] - ids[:, :, :-1] == 1, axis=2)
+    return jnp.all(whole.reshape(b, -1, trip // PAGE_RUN), axis=2).astype(jnp.int32)
+
+
+def _block_page_copies(leaves, sem, page_id, whole, group):
+    """Issue one block's page copies: for each page ``g`` of the block's
+    ``group`` and each of ``leaves`` (``(pool ref, block ref, lanes)``: a ``(P,
+    page_size, ...)`` pool left in HBM, the VMEM block ``(group, page_size,
+    ...)`` it lands in, :func:`_hbm_lanes` of its width), pool page
+    ``page_id(g)`` goes to page ``g`` of the block, all signalling ``sem``.
+    The pool's pages are adjacent in HBM, so a trip ``t`` whose table entries
+    read runs of :data:`PAGE_RUN` adjacent pages (``whole(t)`` 1:
+    :func:`_trip_runs`, from the table as the program runs) is ONE copy a run;
+    any other trip is a copy a page. The bytes that land are the same for
+    every table.
+
+    Each side is a loop of one trip or none, not a conditional: Mosaic turns
+    a conditional this small into predicated instructions and runs BOTH
+    sides' scalar work (a described-v5e listing: 113 bundles a run, taken or
+    not, where four single copies are ~75), and naming the copies is what
+    the walk of a narrow leaf waits for."""
+    trip = _issue_trip(group)
+
+    def start(pid, g, n):
+        for pool_ref, buf_ref, lanes in leaves:
+            pages, rows = pl.ds(pid, n), pl.ds(g, n)
+            if lanes is None:
+                src, dst = pool_ref.at[pages], buf_ref.at[rows]
+            else:
+                width = pl.ds(0, lanes)
+                src, dst = pool_ref.at[pages, :, width], buf_ref.at[rows, :, width]
+            pltpu.make_async_copy(src, dst, sem).start()
 
     def some(t, carry):
-        for k in range(trip):
-            g = t * trip + k
-            rows = pl.ds(pl.multiple_of(g * page_size, page_size), page_size)
-            pid = 0 if wait else page_id(g)
-            for pool_ref, buf_ref, lanes in leaves:
-                if lanes is None:
-                    src, dst = pool_ref.at[pid], buf_ref.at[rows]
-                else:
-                    width = pl.ds(0, lanes)
-                    src, dst = pool_ref.at[pid, :, width], buf_ref.at[rows, width]
-                copy = pltpu.make_async_copy(src, dst, sem)
-                copy.wait() if wait else copy.start()
+        def a_copy_a(n):
+            def copies(_, c):
+                for k in range(trip // n):
+                    g = pl.multiple_of(t * trip + k * n, n)
+                    start(page_id(g), g, n)
+                return c
+            return copies
+
+        runs = whole(t) if trip % PAGE_RUN == 0 else 0
+        jax.lax.fori_loop(0, runs, a_copy_a(PAGE_RUN), 0)
+        jax.lax.fori_loop(0, 1 - runs, a_copy_a(1), 0)
         return carry
 
     jax.lax.fori_loop(0, group // trip, some, 0)
 
 
-def _paged_latent_kernel(bt_ref, live_ref, span_ref, pos_ref, valid_ref,
+def _wait_block(leaves, sem):
+    """ONE wait a leaf for a block's copies: a DMA semaphore counts bytes, and
+    the copies of :func:`_block_page_copies` fill each leaf's whole block
+    (runs or single pages, the null page under an unmapped entry), so a
+    descriptor over the block names exactly their bytes."""
+    for _, buf_ref, _ in leaves:
+        pltpu.make_async_copy(buf_ref, buf_ref, sem).wait()
+
+
+def _block_rows(block_ref):
+    """A fetched block ``(group, page_size, ...)`` as its tokens' rows
+    ``(group * page_size, ...)``: the pages lie one after another in VMEM."""
+    g, page_size = block_ref.shape[:2]
+    return block_ref.reshape((g * page_size,) + block_ref.shape[2:])
+
+
+def _walk_blocks(bt_ref, runs_ref, live_ref, span_ref, b, leaves, sems, group, multiply):
+    """Walk the live blocks of slot ``b``'s row of the block table
+    (``runs_ref``: its :func:`_trip_runs`; ``live_ref`` / ``span_ref``:
+    :func:`_latent_block_walk`): block ``i``'s page copies land in part ``i %
+    (BLOCKS_AHEAD + 1)`` of each of ``leaves`` (``(pool ref, VMEM ref of that
+    many blocks, lanes)``), are waited for, and ``multiply(i, *blocks)`` is
+    given the leaves' fetched blocks, while the copies of the next
+    :data:`BLOCKS_AHEAD` live blocks are in flight: block ``i + BLOCKS_AHEAD``
+    is issued before block ``i`` is waited on, and the trips before the
+    span's first block only issue. A block with no mapped page is neither
+    fetched nor multiplied. The last block of a row whose pages do not fill
+    it fetches the row's last page again into the spare rows."""
+    lo, hi = span_ref[b, 0], span_ref[b, 1]   # this slot's own blocks
+    n_log, n_blocks = bt_ref.shape[1], live_ref.shape[1]
+    trips = group // _issue_trip(group)
+
+    def part(i):
+        at = i % (BLOCKS_AHEAD + 1)
+        return tuple((pool, buf.at[at], lanes) for pool, buf, lanes in leaves), sems.at[at]
+
+    def issue(i):
+        def page_id(g):
+            page = i * group + g
+            if n_log % group:
+                page = jnp.minimum(page, n_log - 1)
+            return bt_ref[b, page]
+
+        _block_page_copies(*part(i), page_id, lambda t: runs_ref[b, i * trips + t], group)
+
+    def step(i, carry):
+        nxt = jnp.minimum(i + BLOCKS_AHEAD, n_blocks - 1)
+
+        @pl.when((i + BLOCKS_AHEAD < hi) & (live_ref[b, nxt] != 0))
+        def _prefetch():
+            issue(nxt)
+
+        @pl.when((i >= lo) & (live_ref[b, jnp.maximum(i, 0)] != 0))
+        def _body():
+            fetched, sem = part(i)
+            _wait_block(fetched, sem)
+            multiply(i, *(block for _, block, _ in fetched))
+
+        return carry
+
+    jax.lax.fori_loop(lo - BLOCKS_AHEAD, hi, step, 0)
+
+
+def _paged_latent_kernel(bt_ref, runs_ref, live_ref, span_ref, pos_ref, valid_ref,
                          qc_ref, qr_ref, c_hbm, r_hbm, o_ref, c_buf, r_buf,
                          sems, m_scr, l_scr, acc_scr, *, page_size, group,
                          r_lanes, scale, use_valid):
     b = pl.program_id(0)
-    lo, hi = span_ref[b, 0], span_ref[b, 1]   # this slot's own blocks
-    n_log, n_blocks = bt_ref.shape[1], live_ref.shape[1]
     block = group * page_size
-
-    def copies(i, wait=False):
-        def page_id(g):
-            page = i * group + g
-            if n_log % group:  # the row's last block: spare rows repeat its last page
-                page = jnp.minimum(page, n_log - 1)
-            return bt_ref[b, page]
-
-        slot = i % 2
-        leaves = ((c_hbm, c_buf.at[slot], None), (r_hbm, r_buf.at[slot], r_lanes))
-        _block_page_copies(leaves, sems.at[slot], page_id, page_size, group, wait)
 
     m_scr[:] = jnp.full_like(m_scr, NEG_INF)
     l_scr[:] = jnp.zeros_like(l_scr)
     acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def step(i, carry):
-        # block i + 1 is issued before block i is waited on; the trip before
-        # the span's first block only issues it
-        nxt = jnp.minimum(i + 1, n_blocks - 1)
+    def multiply(i, c_block, r_block):
+        # operands stay in their storage type (bf16 on the chip: the MXU's
+        # own), accumulation is float32
+        c = _block_rows(c_block)[...]                          # (T, d_c)
+        kr = _block_rows(r_block)[:, :qr_ref.shape[2]]         # (T, d_r)
+        dims = (((1,), (1,)), ((), ()))
+        s = (
+            jax.lax.dot_general(qc_ref[0], c, dims,
+                                preferred_element_type=jnp.float32)
+            + jax.lax.dot_general(qr_ref[0], kr, dims,
+                                  preferred_element_type=jnp.float32)
+        ) * scale                                      # (R, T)
+        rows = pos_ref[0, :][:, None]                  # (R, 1) slot positions
+        cols = (
+            jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + i * block
+        )
+        s = jnp.where(rows >= cols, s, NEG_INF)
+        if use_valid:
+            s = jnp.where(valid_ref[0, pl.ds(i, 1), :] != 0, s, NEG_INF)
+        m_prev = m_scr[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        ref = jnp.where(m_new > NEG_INF / 2, m_new, 0.0)
+        p = jnp.exp(s - ref)
+        alpha = jnp.exp(m_prev - ref)
+        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        # the values are the latent block already in VMEM
+        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+            p.astype(c.dtype), c, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_scr[:] = m_new
 
-        @pl.when((i + 1 < hi) & (live_ref[b, nxt] != 0))
-        def _prefetch():
-            copies(nxt)
-
-        @pl.when((i >= lo) & (live_ref[b, jnp.maximum(i, 0)] != 0))
-        def _body():
-            copies(i, wait=True)
-            slot = i % 2
-            # operands stay in their storage type (bf16 on the chip: the MXU's
-            # own), accumulation is float32
-            c = c_buf[slot]                                # (T, d_c)
-            kr = r_buf[slot][:, :qr_ref.shape[2]]          # (T, d_r)
-            dims = (((1,), (1,)), ((), ()))
-            s = (
-                jax.lax.dot_general(qc_ref[0], c, dims,
-                                    preferred_element_type=jnp.float32)
-                + jax.lax.dot_general(qr_ref[0], kr, dims,
-                                      preferred_element_type=jnp.float32)
-            ) * scale                                      # (R, T)
-            rows = pos_ref[0, :][:, None]                  # (R, 1) slot positions
-            cols = (
-                jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + i * block
-            )
-            s = jnp.where(rows >= cols, s, NEG_INF)
-            if use_valid:
-                s = jnp.where(valid_ref[0, pl.ds(i, 1), :] != 0, s, NEG_INF)
-            m_prev = m_scr[:]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            ref = jnp.where(m_new > NEG_INF / 2, m_new, 0.0)
-            p = jnp.exp(s - ref)
-            alpha = jnp.exp(m_prev - ref)
-            l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
-            # the values are the latent block already in VMEM
-            acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-                p.astype(c.dtype), c, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            m_scr[:] = m_new
-
-        return carry
-
-    jax.lax.fori_loop(lo - 1, hi, step, 0)
+    _walk_blocks(bt_ref, runs_ref, live_ref, span_ref, b,
+                 ((c_hbm, c_buf, None), (r_hbm, r_buf, r_lanes)), sems, group, multiply)
     o_ref[0] = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)).astype(o_ref.dtype)
 
 
@@ -941,7 +1039,7 @@ def _paged_latent_decode_call(qc, qr, c_pool, r_pool, block_table, rows_pos,
 
     hbm = pl.BlockSpec(memory_space=pl.ANY)   # a pool leaf, whole and unblocked
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,  # block table, live blocks, each slot's span
+        num_scalar_prefetch=4,  # block table, its runs, live blocks, each slot's span
         grid=(b,),
         in_specs=[
             pl.BlockSpec((1, r), lambda b_, *_: (0, 0)),                 # pos
@@ -951,9 +1049,9 @@ def _paged_latent_decode_call(qc, qr, c_pool, r_pool, block_table, rows_pos,
         ],
         out_specs=rows_spec(d_c),
         scratch_shapes=[
-            pltpu.VMEM((2, block, d_c), c_pool.dtype),
-            pltpu.VMEM((2, block, r_lanes or d_r), r_pool.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((BLOCKS_AHEAD + 1, group, page_size, d_c), c_pool.dtype),
+            pltpu.VMEM((BLOCKS_AHEAD + 1, group, page_size, r_lanes or d_r), r_pool.dtype),
+            pltpu.SemaphoreType.DMA((BLOCKS_AHEAD + 1,)),
             pltpu.VMEM((r, 1), jnp.float32),
             pltpu.VMEM((r, 1), jnp.float32),
             pltpu.VMEM((r, d_c), jnp.float32),
@@ -970,7 +1068,7 @@ def _paged_latent_decode_call(qc, qr, c_pool, r_pool, block_table, rows_pos,
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
-    )(block_table, live, span, rows_pos.reshape(1, r), kv_valid,
+    )(block_table, _trip_runs(block_table, group), live, span, rows_pos.reshape(1, r), kv_valid,
       qc, qr, c_pool, r_pool)
 
 
@@ -1037,12 +1135,12 @@ def paged_latent_decode_attention(
 # --- paged GQA decode that walks the blocks a slot maps ----------------------------
 #
 # The latent kernel's form (one grid step a slot, the pool left in HBM, a walk
-# over the blocks of the row that THIS slot maps, one async copy a page into
-# a two-block VMEM buffer: :func:`_latent_block_walk`,
-# :func:`_block_page_copies`) for grouped-query attention over a cache whose K
-# and V are ONE joined leaf ``(2 Hkv, D)`` a token
-# (``modules/attention.JoinedKVCache``): a page is one copy of ``page_size x
-# 2 Hkv x D`` values (64 KB at 16 tokens of 8 kv heads of 128 in bf16), and all
+# over the blocks of the row that THIS slot maps, one async copy a run of
+# pages into a VMEM buffer of a few blocks: :func:`_latent_block_walk`,
+# :func:`_walk_blocks`, :func:`_block_page_copies`) for grouped-query
+# attention over a cache whose K and V are ONE joined leaf ``(2 Hkv, D)`` a token
+# (``modules/attention.JoinedKVCache``): a page is ``page_size x 2 Hkv x D``
+# values (64 KB at 16 tokens of 8 kv heads of 128 in bf16, 16 KB at 2), and all
 # the query heads of the slot meet a block while it is in VMEM, the group of
 # each kv head against that head's K and V rows (the sparse kernel's
 # multiply). It serves both kinds of layer of a stack that mixes WINDOW and
@@ -1054,8 +1152,9 @@ def paged_latent_decode_attention(
 # up to the shared cursor, is some ten thousand grid steps a layer at eight
 # rows of 32,768 columns.
 
-# Tokens a block: two blocks of 512 x (16, 128) bf16 are 4 MiB of VMEM, and a
-# block's 32 page copies (2 MiB) last ~3,840 cycles. What the kernel issues
+# Tokens a block: a block of 512 x (16, 128) bf16 is 2 MiB of VMEM (three are
+# held: :data:`BLOCKS_AHEAD`), and its copies (2 MiB: 32 of a page, or 8 of a
+# run of four) last ~3,840 cycles. What the kernel issues
 # for a block has to stay under that, and how a kv head's (T, D) rows are taken
 # out of the fetched block decides it. Instruction bundles of one block's body
 # in the final schedule of a described-v5e compile at Trinity's geometry
@@ -1064,7 +1163,8 @@ def paged_latent_decode_attention(
 # 8,192 loads and ~37,000 shuffles for 512 matrix pushes); the block read once
 # and turned with ``jnp.swapaxes``, 2,579; each 32-bit word of a token read
 # once with a sublane stride (:func:`_block_head_rows`), 1,696, beside ~1,200
-# cycles that name the next block's copies; pages held head-major in the POOL
+# cycles that name the next block's 32 copies (~190 bundles where its table
+# reads runs: one copy a run, PERF.md section 6, PR 52); pages held head-major in the POOL
 # would need no shuffle at all, 963, at the price of a layout every
 # ``PAGED_LEAVES`` walker would have to learn.
 WALK_BLOCK_TOKENS = 512
@@ -1101,81 +1201,55 @@ def _block_head_rows(block_ref):
     return rows_of
 
 
-def _paged_walk_kernel(bt_ref, live_ref, span_ref, edge_ref, valid_ref, q_ref,
+def _paged_walk_kernel(bt_ref, runs_ref, live_ref, span_ref, edge_ref, valid_ref, q_ref,
                        kv_hbm, o_ref, buf, sems, m_scr, l_scr, acc_scr, *,
                        page_size, group, num_kv_heads, scale, use_valid):
-    """One slot: walk its live blocks, block ``i + 1``'s page copies in flight
-    while block ``i`` is multiplied. A waited block ``(T, 2 Hkv, D)`` gives up
+    """One slot: walk its live blocks (:func:`_walk_blocks`: the next blocks'
+    page copies in flight while one is multiplied). A waited block ``(T, 2
+    Hkv, D)`` gives up
     each kv head's K and V rows through :func:`_block_head_rows` (each 32-bit
     word of a token read ONCE a block, with a sublane stride: the comment at
     :data:`WALK_BLOCK_TOKENS` has the three counts), and the head's group of
     query rows meets them: scores and probabilities in float32, the operands
     in their storage type, an online softmax over the blocks."""
     b = pl.program_id(0)
-    lo, hi = span_ref[b, 0], span_ref[b, 1]   # this slot's own blocks
     floor, pos = edge_ref[b, 0], edge_ref[b, 1]
-    n_log, n_blocks = bt_ref.shape[1], live_ref.shape[1]
     block = group * page_size
-
-    def copies(i, wait=False):
-        def page_id(g):
-            page = i * group + g
-            if n_log % group:  # the row's last block: spare rows repeat its last page
-                page = jnp.minimum(page, n_log - 1)
-            return bt_ref[b, page]
-
-        slot = i % 2
-        _block_page_copies(((kv_hbm, buf.at[slot], None),), sems.at[slot],
-                           page_id, page_size, group, wait)
 
     m_scr[:] = jnp.full_like(m_scr, NEG_INF)
     l_scr[:] = jnp.zeros_like(l_scr)
     acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def step(i, carry):
-        # block i + 1 is issued before block i is waited on; the trip before
-        # the span's first block only issues it
-        nxt = jnp.minimum(i + 1, n_blocks - 1)
+    def multiply(i, kv_block):
+        rows = q_ref.shape[2]
+        cols = jax.lax.broadcasted_iota(jnp.int32, (rows, block), 1) + i * block
+        ok = (cols <= pos) & (cols >= floor)
+        if use_valid:
+            ok = ok & (valid_ref[0, pl.ds(i, 1), :] != 0)
+        rows_of = _block_head_rows(_block_rows(kv_block))
+        for h in range(num_kv_heads):
+            # operands stay in their storage type (bf16 on the chip: the
+            # MXU's own), accumulation is float32
+            k = rows_of(h)                                     # (T, D)
+            v = rows_of(num_kv_heads + h)
+            s = jax.lax.dot_general(
+                q_ref[0, h], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) * scale                                          # (G, T)
+            s = jnp.where(ok, s, NEG_INF)
+            m_prev = m_scr[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            ref = jnp.where(m_new > NEG_INF / 2, m_new, 0.0)
+            p = jnp.where(ok, jnp.exp(s - ref), 0.0)
+            alpha = jnp.exp(m_prev - ref)
+            l_scr[h] = l_scr[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
+                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+            m_scr[h] = m_new
 
-        @pl.when((i + 1 < hi) & (live_ref[b, nxt] != 0))
-        def _prefetch():
-            copies(nxt)
-
-        @pl.when((i >= lo) & (live_ref[b, jnp.maximum(i, 0)] != 0))
-        def _body():
-            copies(i, wait=True)
-            slot = i % 2
-            rows = q_ref.shape[2]
-            cols = jax.lax.broadcasted_iota(jnp.int32, (rows, block), 1) + i * block
-            ok = (cols <= pos) & (cols >= floor)
-            if use_valid:
-                ok = ok & (valid_ref[0, pl.ds(i, 1), :] != 0)
-            rows_of = _block_head_rows(buf.at[slot])
-            for h in range(num_kv_heads):
-                # operands stay in their storage type (bf16 on the chip: the
-                # MXU's own), accumulation is float32
-                k = rows_of(h)                                     # (T, D)
-                v = rows_of(num_kv_heads + h)
-                s = jax.lax.dot_general(
-                    q_ref[0, h], k, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                ) * scale                                          # (G, T)
-                s = jnp.where(ok, s, NEG_INF)
-                m_prev = m_scr[h]
-                m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-                ref = jnp.where(m_new > NEG_INF / 2, m_new, 0.0)
-                p = jnp.where(ok, jnp.exp(s - ref), 0.0)
-                alpha = jnp.exp(m_prev - ref)
-                l_scr[h] = l_scr[h] * alpha + jnp.sum(p, axis=1, keepdims=True)
-                acc_scr[h] = acc_scr[h] * alpha + jax.lax.dot_general(
-                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                m_scr[h] = m_new
-
-        return carry
-
-    jax.lax.fori_loop(lo - 1, hi, step, 0)
+    _walk_blocks(bt_ref, runs_ref, live_ref, span_ref, b, ((kv_hbm, buf, None),), sems, group, multiply)
     o_ref[0] = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)).astype(o_ref.dtype)
 
 
@@ -1249,13 +1323,13 @@ def paged_walk_decode_attention(
         vspec = pl.BlockSpec((1, n_blocks, block), lambda b_, *_: (b_, 0, 0))
     rows = pl.BlockSpec((1, hkv, g, d), lambda b_, *_: (b_, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,  # block table, live blocks, each slot's span, its (floor, position)
+        num_scalar_prefetch=5,  # block table, its runs, live blocks, each slot's span, its (floor, position)
         grid=(b,),
         in_specs=[vspec, rows, pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=rows,
         scratch_shapes=[
-            pltpu.VMEM((2, block, 2 * hkv, d), kv_pool.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((BLOCKS_AHEAD + 1, group, page_size, 2 * hkv, d), kv_pool.dtype),
+            pltpu.SemaphoreType.DMA((BLOCKS_AHEAD + 1,)),
             pltpu.VMEM((hkv, g, 1), jnp.float32),
             pltpu.VMEM((hkv, g, 1), jnp.float32),
             pltpu.VMEM((hkv, g, d), jnp.float32),
@@ -1270,7 +1344,7 @@ def paged_walk_decode_attention(
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(block_table, live, span, edge, kv_valid, q.reshape(b, hkv, g, d), kv_pool)
+    )(block_table, _trip_runs(block_table, group), live, span, edge, kv_valid, q.reshape(b, hkv, g, d), kv_pool)
     return out.reshape(b, 1, h, d)
 
 
@@ -1310,51 +1384,27 @@ SPARSE_CHUNK_TOKENS = 512
 _TOKENS_A_TRIP = 16
 
 
-def _paged_index_kernel(bt_ref, live_ref, span_ref, bound_ref, valid_ref,
+def _paged_index_kernel(bt_ref, runs_ref, live_ref, span_ref, bound_ref, valid_ref,
                         q_ref, w_ref, k_hbm, o_ref, k_buf, sems, *, page_size,
                         group, lanes):
     b = pl.program_id(0)
-    lo, hi = span_ref[b, 0], span_ref[b, 1]
-    n_log, n_blocks = bt_ref.shape[1], live_ref.shape[1]
     block = group * page_size
     d = q_ref.shape[2]
 
-    def copies(i, wait=False):
-        def page_id(g):
-            page = i * group + g
-            if n_log % group:
-                page = jnp.minimum(page, n_log - 1)
-            return bt_ref[b, page]
-
-        slot = i % 2
-        _block_page_copies(((k_hbm, k_buf.at[slot], lanes),), sems.at[slot],
-                           page_id, page_size, group, wait)
-
     o_ref[...] = jnp.full(o_ref.shape, -jnp.inf, o_ref.dtype)
 
-    def step(i, carry):
-        nxt = jnp.minimum(i + 1, n_blocks - 1)
+    def multiply(i, k_block):
+        k = _block_rows(k_block)[:, :d]                        # (T, d_i)
+        s = jax.lax.dot_general(
+            q_ref[0], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )                                                      # (H_i, T)
+        row = jnp.sum(jnp.maximum(s, 0.0) * w_ref[0], axis=0, keepdims=True)
+        cols = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1) + i * block
+        ok = (cols <= bound_ref[0]) & (valid_ref[0, pl.ds(i, 1), :] != 0)
+        o_ref[0, pl.ds(i, 1), :] = jnp.where(ok, row, -jnp.inf)
 
-        @pl.when((i + 1 < hi) & (live_ref[b, nxt] != 0))
-        def _prefetch():
-            copies(nxt)
-
-        @pl.when((i >= lo) & (live_ref[b, jnp.maximum(i, 0)] != 0))
-        def _body():
-            copies(i, wait=True)
-            k = k_buf[i % 2][:, :d]                            # (T, d_i)
-            s = jax.lax.dot_general(
-                q_ref[0], k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )                                                  # (H_i, T)
-            row = jnp.sum(jnp.maximum(s, 0.0) * w_ref[0], axis=0, keepdims=True)
-            cols = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1) + i * block
-            ok = (cols <= bound_ref[0]) & (valid_ref[0, pl.ds(i, 1), :] != 0)
-            o_ref[0, pl.ds(i, 1), :] = jnp.where(ok, row, -jnp.inf)
-
-        return carry
-
-    jax.lax.fori_loop(lo - 1, hi, step, 0)
+    _walk_blocks(bt_ref, runs_ref, live_ref, span_ref, b, ((k_hbm, k_buf, lanes),), sems, group, multiply)
 
 
 def paged_index_scores(
@@ -1402,7 +1452,7 @@ def paged_index_scores(
     ).reshape(b, n_blocks, block)
     per_slot = lambda *shape: pl.BlockSpec((1,) + shape, lambda b_, *_: (b_, 0, 0))  # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,  # block table, live blocks, spans, the row's position
+        num_scalar_prefetch=5,  # block table, its runs, live blocks, spans, the row's position
         grid=(b,),
         in_specs=[
             per_slot(n_blocks, block), per_slot(h_i, d_i), per_slot(h_i, 1),
@@ -1410,8 +1460,8 @@ def paged_index_scores(
         ],
         out_specs=per_slot(n_blocks, block),
         scratch_shapes=[
-            pltpu.VMEM((2, block, lanes or d_i), kidx_pool.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((BLOCKS_AHEAD + 1, group, page_size, lanes or d_i), kidx_pool.dtype),
+            pltpu.SemaphoreType.DMA((BLOCKS_AHEAD + 1,)),
         ],
     )
     out = pl.pallas_call(
@@ -1421,7 +1471,7 @@ def paged_index_scores(
         out_shape=jax.ShapeDtypeStruct((b, n_blocks, block), jnp.float32),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(block_table, live, span, bound, valid, q_idx[:, 0],
+    )(block_table, _trip_runs(block_table, group), live, span, bound, valid, q_idx[:, 0],
       w_idx[:, 0, :, None].astype(jnp.float32),
       kidx_pool.reshape(kidx_pool.shape[:2] + kidx_pool.shape[3:]))
     return out.reshape(b, n_blocks * block)[:, :length]

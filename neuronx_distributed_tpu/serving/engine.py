@@ -2470,9 +2470,17 @@ class ServingEngine:
             "selected_tokens": int(sum(min(n, int(topk)) for n in held)),
         }
 
+    def _page_stats(self) -> dict:
+        """For a paged cache, on the dispatch span: ``full_pages_mapped`` /
+        ``full_pages_in_runs`` (the pages the block table maps, and those of
+        them in runs of adjacent pool pages that the block-walking decode
+        kernels fetch with one copy) and, for a model with window layers, the
+        window kind's ``window_pages_mapped`` / ``window_pages_in_runs``: host
+        arithmetic on the tables, made when a table is uploaded."""
+        return dict(getattr(self.cache, "page_stats", None) or {})
+
     def _window_stats(self) -> dict:
-        """For a model with window layers, on the dispatch span: the pages
-        each kind's block table maps (host arithmetic from the two tables),
+        """For a model with window layers, on the dispatch span:
         ``ctx_tokens`` (tokens the decoding slots hold) and ``window_tokens``
         (``sum(min(held, window))``: what a window layer's decode step
         attends). Empty for every other model."""
@@ -2481,8 +2489,6 @@ class ServingEngine:
             return {}
         held = self._held_tokens()
         return {
-            "window_pages_mapped": self.cache.window_pages_mapped,
-            "full_pages_mapped": self.cache.pages_mapped,
             "ctx_tokens": int(sum(held)),
             "window_tokens": int(sum(min(n, int(window)) for n in held)),
         }
@@ -3693,7 +3699,7 @@ class ServingEngine:
             sampled_slots=sampled_slots,
             # one dict: both name ``ctx_tokens``
             **{**self._selection_stats(), **self._window_stats()},
-            **self._slot_state_stats(),
+            **self._slot_state_stats(), **self._page_stats(),
         ):
             cache_in = self.cache.take()
             attempt = self._dispatch_attempts

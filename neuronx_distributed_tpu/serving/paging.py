@@ -36,6 +36,18 @@ byte of the decode math untouched:
   it are requeued, the slot indices stay in rotation, and capacity degrades
   by one page instead of one permanent slot row.
 
+* Pages in runs: the pool leaf is ``(P, page_size, ...)``, so pool pages ``p
+  .. p + PAGE_RUN - 1`` are adjacent HBM, and the kernels that fetch a slot's
+  blocks themselves (``kernels/flash_decode._block_page_copies``) carry such
+  a run with ONE copy where the table's aligned group of entries reads it.
+  :meth:`PageAllocator.deal` makes the runs: the ``PAGE_RUN`` pages of an
+  aligned group of a slot's logical row come from one aligned group of the
+  pool, over the admissions and page crossings that ask for them. It is a
+  preference among free pages and holds nothing back (a run's pages not yet
+  asked for stay FREE), so the free count, the page-pressure wall and
+  ``check()`` are what they were; the page stays the granule of alignment,
+  sharing, pins, quarantine and spill.
+
 * Window layers (``modules/attention.JoinedKVCache(window=)``): a model whose
   stack mixes window and full attention layers gets a block table and a pool
   a layer KIND. The full kind is everything above; the window kind's pool is
@@ -61,6 +73,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from neuronx_distributed_tpu.kernels.flash_decode import PAGE_RUN
 from neuronx_distributed_tpu.modules.attention import (
     _SCALE_SUFFIX,
     PAGED_LEAVES,
@@ -163,7 +176,17 @@ class PageAllocator:
     sum), or QUARANTINED (poisoned, permanently out of circulation; a
     referenced page that gets quarantined leaves circulation when its last
     ref drops). ``copy_bytes`` counts KV bytes physically duplicated on
-    prefix reuse — the zero-copy CoW contract is that it STAYS 0."""
+    prefix reuse — the zero-copy CoW contract is that it STAYS 0.
+
+    Pages are dealt in RUNS where the pool has them (:meth:`deal`): the
+    :data:`PAGE_RUN` pages of one aligned group of an owner's logical row come
+    from one aligned group of the pool, ``p, p + 1, ...``, which the
+    block-walking decode kernels fetch with one copy
+    (``kernels/flash_decode._block_page_copies``). It is a preference among
+    FREE pages and holds nothing back: a run's pages not yet asked for stay on
+    the free list, remembered for their owner (``_dealt``) and passed over
+    while any other page is free, so whatever number of pages is free can
+    always be had."""
 
     def __init__(self, num_pages: int):
         if num_pages < 2:
@@ -171,14 +194,23 @@ class PageAllocator:
                 f"num_pages must be >= 2 (page 0 is reserved), got {num_pages}"
             )
         self.num_pages = num_pages
-        self._free: List[int] = list(range(1, num_pages))
+        self._is_free = np.ones((num_pages,), np.bool_)
+        self._is_free[0] = False
+        self._n_free = num_pages - 1
         self._refs: Dict[int, int] = {}
         self._quarantined: set = set()
+        # owner -> (the run of its logical row being dealt, that run's first pool page)
+        self._dealt: Dict[object, Tuple[int, int]] = {}
         self.copy_bytes = 0  # CoW contract: never incremented by sharing
 
     @property
+    def _free(self) -> List[int]:
+        """The free pages, ascending."""
+        return np.flatnonzero(self._is_free).tolist()
+
+    @property
     def free_pages(self) -> int:
-        return len(self._free)
+        return self._n_free
 
     @property
     def referenced_pages(self) -> int:
@@ -198,25 +230,109 @@ class PageAllocator:
         return self._refs.get(pid, 0)
 
     def alloc(self, n: int) -> List[int]:
-        """Take ``n`` pages off the free list, each born with refcount 1
-        (the caller's mapping). Raises :class:`PageExhausted` when short."""
+        """Take ``n`` pages off the free list for nobody's row in particular
+        (a staged or imported context: its pages ``0 .. n``), each born with
+        refcount 1 (the caller's mapping). Raises :class:`PageExhausted` when
+        short."""
         if n < 0:
             raise ValueError(f"cannot allocate {n} pages")
-        if n > len(self._free):
+        return self.deal([(None, j) for j in range(n)])
+
+    def deal(self, wanted: Sequence[Tuple[object, int]]) -> List[int]:
+        """One page for each ``(owner, logical page)`` of ``wanted`` (an
+        owner's pages ascending), all or none: :class:`PageExhausted` when
+        fewer are free. An owner that asks for the first page of a logical run
+        ``[PAGE_RUN r, PAGE_RUN (r + 1))`` is dealt the lowest aligned group of
+        pool pages that is free whole, and the run's later pages, in this call
+        or a later one, are that group's: ``p, p + 1, ...``. Every other page
+        (a row that starts mid-run, no group free whole, a group's page taken
+        meanwhile) is a single one: from a group already broken, else the
+        lowest whole group is broken, else a page dealt to somebody's run."""
+        n = len(wanted)
+        if n > self._n_free:
             raise PageExhausted(
-                f"need {n} pages, {len(self._free)} free "
+                f"need {n} pages, {self._n_free} free "
                 f"(capacity {self.capacity})"
             )
-        ids = [self._free.pop(0) for _ in range(n)]
-        for pid in ids:
+        groups = self.num_pages // PAGE_RUN
+        lists: Dict[str, List[int]] = {}    # found when first asked for; popped from the end
+
+        def whole_groups():
+            """Bool a group: free whole and dealt to nobody."""
+            free = self._is_free[:groups * PAGE_RUN].reshape(groups, PAGE_RUN).all(axis=1)
+            for _, first in self._dealt.values():
+                free[first // PAGE_RUN] = False
+            return free
+
+        def whole() -> Optional[int]:
+            """The first page of the lowest such group."""
+            if "whole" not in lists:
+                lists["whole"] = (np.flatnonzero(whole_groups())[::-1] * PAGE_RUN).tolist()
+            while lists["whole"]:
+                first = lists["whole"].pop()
+                if self._is_free[first:first + PAGE_RUN].all():
+                    return first
+            return None
+
+        def single() -> int:
+            if "loose" not in lists:
+                kept = whole_groups()
+                for _, first in self._dealt.values():
+                    kept[first // PAGE_RUN] = True
+                loose = self._is_free.copy()
+                loose[:groups * PAGE_RUN][np.repeat(kept, PAGE_RUN)] = False
+                lists["loose"] = np.flatnonzero(loose)[::-1].tolist()
+            while lists["loose"]:
+                pid = lists["loose"].pop()
+                if self._is_free[pid]:
+                    return pid
+            first = whole()
+            if first is not None:
+                lists["loose"] = [first + j for j in range(PAGE_RUN - 1, 0, -1)]
+                return first
+            return int(np.argmax(self._is_free))
+
+        ids = []
+        for i, (owner, page) in enumerate(wanted):
+            run, off = divmod(page, PAGE_RUN)
+            dealt = self._dealt.get(owner)
+            if dealt is not None and dealt[0] != run:   # the owner has left that run
+                del self._dealt[owner]
+                dealt = None
+            # nobody's row is not asked for again: a run only when this call takes it whole
+            if dealt is None and off == 0 and (owner is not None or i + PAGE_RUN <= n):
+                first = whole()
+                if first is not None:
+                    dealt = self._dealt[owner] = (run, first)
+            pid = None
+            if dealt is not None:
+                if self._is_free[dealt[1] + off]:
+                    pid = dealt[1] + off
+                if pid is None or off == PAGE_RUN - 1:
+                    del self._dealt[owner]
+            if pid is None:
+                pid = single()
+            self._is_free[pid] = False
+            self._n_free -= 1
             self._refs[pid] = 1
+            ids.append(pid)
+        self._dealt.pop(None, None)
         return ids
+
+    def forget(self, owner) -> None:
+        """``owner`` will not ask for the rest of the run it was being dealt."""
+        self._dealt.pop(owner, None)
 
     def ref(self, pid: int) -> None:
         """One more holder of an already-live page (CoW share / prefix pin)."""
         if pid not in self._refs:
             raise ValueError(f"page {pid} is not live (cannot ref)")
         self._refs[pid] += 1
+
+    def _to_free_list(self, pid: int) -> None:
+        if pid not in self._quarantined:
+            self._is_free[pid] = True
+            self._n_free += 1
 
     def deref(self, pid: int) -> None:
         """Drop one holder; the last drop returns the page to the free list
@@ -228,9 +344,7 @@ class PageAllocator:
             self._refs[pid] = c - 1
             return
         del self._refs[pid]
-        if pid not in self._quarantined:
-            self._free.append(pid)
-            self._free.sort()
+        self._to_free_list(pid)
 
     def quarantine(self, pid: int) -> None:
         """Pull a page out of circulation permanently (poisoned content).
@@ -240,17 +354,17 @@ class PageAllocator:
         if pid <= 0 or pid >= self.num_pages:
             raise ValueError(f"page {pid} outside pool [1, {self.num_pages})")
         self._quarantined.add(pid)
-        if pid in self._free:
-            self._free.remove(pid)
+        if self._is_free[pid]:
+            self._is_free[pid] = False
+            self._n_free -= 1
 
     def release_all(self) -> None:
         """Drop every reference (pool-loss recovery: all mappings and pins
         are void). Quarantined pages stay out of circulation."""
         for pid in list(self._refs):
             del self._refs[pid]
-            if pid not in self._quarantined:
-                self._free.append(pid)
-        self._free.sort()
+            self._to_free_list(pid)
+        self._dealt.clear()
 
 
 class PagedCacheManager:
@@ -328,6 +442,9 @@ class PagedCacheManager:
         self._gaps: List[Tuple[int, int]] = []
         self._w_lo = [0] * num_slots
         self.window_pages_freed_total = 0
+        # ``<kind>_pages_mapped`` / ``<kind>_pages_in_runs`` of the tables as
+        # last uploaded (the dispatch span's stats)
+        self.page_stats: Dict[str, int] = {}
         self.cursor_jumps_total = 0   # jumps that left gap columns in a slot's context
         self.cache = None  # {"pages": bt, "pool": tree}; lazy like the row mgr
         self.cursor = 0
@@ -648,11 +765,6 @@ class PagedCacheManager:
     def pages_mapped(self) -> int:
         return int((self._tables != 0).sum())
 
-    @property
-    def window_pages_mapped(self) -> int:
-        """Pages the window kind's table maps (0 without window layers)."""
-        return 0 if self._tables_w is None else int((self._tables_w != 0).sum())
-
     def _refuse_held_by_pages(self, what: str) -> None:
         """Refuse ``what``, which holds or moves a context by its pages alone,
         for a kind whose contexts are not their pages."""
@@ -751,11 +863,18 @@ class PagedCacheManager:
         reclaimable, so the fit math counts them as claimed (ISSUE 19)."""
         return self.alloc.free_pages + self.reclaimable_pages()
 
-    def _alloc_pages(self, n: int) -> List[int]:
-        while self.alloc.free_pages < n and self.reclaim is not None:
+    def _alloc_pages(self, n: int, slot: Optional[int] = None, at: int = 0) -> List[int]:
+        """``n`` pages of the full kind, for pages ``[at, at + n)`` of
+        ``slot``'s row (``None``: a context no slot holds yet)."""
+        return self._deal_pages([(slot, at + j) for j in range(n)])
+
+    def _deal_pages(self, wanted: Sequence[Tuple[Optional[int], int]]) -> List[int]:
+        """:meth:`PageAllocator.deal` of the full kind, after reclaiming
+        prefix entries while it is short."""
+        while self.alloc.free_pages < len(wanted) and self.reclaim is not None:
             if not self.reclaim():
                 break
-        return self.alloc.alloc(n)
+        return self.alloc.deal(wanted)
 
     # --- prefix pins (CoW) --------------------------------------------------
 
@@ -824,8 +943,23 @@ class PagedCacheManager:
 
     # --- device-state transitions -------------------------------------------
 
+    @staticmethod
+    def _count_pages(kind: str, tables) -> Dict[str, int]:
+        """``<kind>_pages_mapped`` and ``<kind>_pages_in_runs`` of a block
+        table: the entries that map a page, and those of them whose whole
+        aligned group of ``PAGE_RUN`` entries maps adjacent pool pages (what
+        the block-walking kernels fetch with one copy)."""
+        groups = tables[:, :tables.shape[1] // PAGE_RUN * PAGE_RUN].reshape(tables.shape[0], -1, PAGE_RUN)
+        first = groups[:, :, 0]
+        runs = first != 0
+        for j in range(1, PAGE_RUN):
+            runs &= groups[:, :, j] == first + j
+        return {f"{kind}_pages_mapped": int(np.count_nonzero(tables)),
+                f"{kind}_pages_in_runs": PAGE_RUN * int(np.count_nonzero(runs))}
+
     def _upload_tables(self) -> None:
         if self.cache is not None:
+            self.page_stats.update(self._count_pages("full", self._tables))
             pages = jnp.asarray(self._tables)
             if self.placement is not None:
                 # keep the uploaded table committed-replicated like the
@@ -837,6 +971,7 @@ class PagedCacheManager:
 
     def _upload_window_table(self) -> None:
         if self.cache is not None and self._tables_w is not None:
+            self.page_stats.update(self._count_pages("window", self._tables_w))
             self.cache = {**self.cache, WINDOW_PAGES: jnp.asarray(self._tables_w)}
 
     # --- the window kind ------------------------------------------------------
@@ -1054,14 +1189,16 @@ class PagedCacheManager:
         # the window kind: the pages of the columns the next query can attend
         w_lo = max(start, target - self.window + 1) // ps if self.window is not None else 0
         own_w = (
-            self.alloc_w.alloc(self.page_span(w_lo * ps, target))
+            self.alloc_w.deal([(slot, w_lo + j) for j in range(self.page_span(w_lo * ps, target))])
             if self.window is not None else []
         )
         try:
-            own = self._alloc_pages(n_own)
+            own = self._alloc_pages(n_own, slot, own_lo)
         except PageExhausted:
             for pid in own_w:
                 self.alloc_w.deref(pid)
+            if self.window is not None:
+                self.alloc_w.forget(slot)
             raise
         s0 = start // ps
         for j in range(n_sh):
@@ -1369,8 +1506,8 @@ class PagedCacheManager:
         ids_w: List[int] = []
         try:
             if need_w:
-                ids_w = self.alloc_w.alloc(len(need_w))
-            ids = self._alloc_pages(len(need))
+                ids_w = self.alloc_w.deal(need_w)
+            ids = self._deal_pages(need)
         except PageExhausted:
             for pid in ids_w:   # either kind short is the wall: take nothing
                 self.alloc_w.deref(pid)
@@ -1409,6 +1546,7 @@ class PagedCacheManager:
             for pid in row[row != 0]:
                 alloc.deref(int(pid))
             tables[slot] = 0
+            alloc.forget(slot)
         self._slot_start[slot] = None
         self._slot_target[slot] = None
         self._w_lo[slot] = 0

@@ -26,6 +26,7 @@ from neuronx_distributed_tpu.modules.attention import (
     split_kv,
     topk_mask,
 )
+from tests.kernels import page_runs
 
 PS, D, HKV, H, H_I, D_I = 16, 16, 2, 8, 4, 8
 T = LATENT_BLOCK_TOKENS
@@ -88,6 +89,32 @@ def test_index_scores_match_jnp_over_the_blocks_a_slot_maps(n_log, lens):
     assert got.shape == (b, n_log * PS) and got.dtype == jnp.float32
     np.testing.assert_array_equal(np.isneginf(np.asarray(got)), ~ok)
     np.testing.assert_allclose(np.asarray(got)[ok], np.asarray(want)[ok], atol=2e-5)
+
+
+@pytest.mark.parametrize("case", page_runs.CASES)
+def test_index_scores_with_runs_fetched_whole_are_those_of_a_copy_a_page(monkeypatch, case):
+    """The index-key leaf over every shape of block table, blocks of eight
+    pages: the scores with runs fetched whole are, bit for bit, those of a
+    copy a page."""
+    from neuronx_distributed_tpu.kernels import flash_decode
+
+    b, group = 3, 8
+    monkeypatch.setattr(flash_decode, "LATENT_BLOCK_TOKENS", group * PS)
+    n_log = 2 * group + 6 if case == "short_last_block" else 3 * group     # the third block holds 6 pages
+    cur = n_log * PS - 5
+    table = page_runs.table(case, b, n_log, [(3, n_log), (group + 1, n_log), None])
+    assert (page_runs.runs(table) > 0) == (case not in ("no_runs", "adjacent_off_the_grid"))
+    valid = jnp.asarray(np.repeat(table != 0, PS, axis=1))
+    rng = np.random.default_rng(6)
+    idx_pool = jnp.asarray(rng.standard_normal((page_runs.pool_pages(b, n_log), PS, 1, D_I)), jnp.float32)
+    _, q_idx, w_idx = _queries(rng, b)
+    score = lambda: np.asarray(paged_index_scores(   # noqa: E731
+        q_idx, w_idx, idx_pool, jnp.asarray(table), jnp.asarray([cur], jnp.int32), valid, page_size=PS))
+    got = score()
+    with page_runs.single_copies():
+        want = score()
+    np.testing.assert_array_equal(got, want)
+    assert np.isfinite(got[:2]).any() and np.isneginf(got[2]).all()
 
 
 def test_index_scores_stop_at_the_row_position():

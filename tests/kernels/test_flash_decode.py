@@ -9,6 +9,7 @@ import pytest
 
 from neuronx_distributed_tpu.kernels.flash_decode import flash_decode_attention
 from neuronx_distributed_tpu.modules.attention import decode_attention
+from tests.kernels import page_runs
 
 B, L, D = 2, 256, 32
 
@@ -171,6 +172,28 @@ def test_paged_kernel_bit_identical_to_gather_path(s, h, hkv):
         q, k_log, v_log, pos, valid, block_l=ps, interpret=True
     )
     assert np.array_equal(np.asarray(fused), np.asarray(ref))
+
+
+@pytest.mark.parametrize("case", page_runs.CASES)
+def test_paged_kernel_is_the_gather_path_on_tables_with_runs_of_adjacent_pages(case):
+    """The pool deals a slot's pages in runs of adjacent ones
+    (``serving/paging.PAGE_RUN``) for the kernels that fetch blocks
+    themselves; this one maps a page a grid step through its index map, and
+    stays the gather path bit for bit on every shape of table the runs make."""
+    from neuronx_distributed_tpu.kernels.flash_decode import paged_flash_decode_attention, paged_gather_leaf
+    ps, n_log, h, hkv = 16, 14 if case == "short_last_block" else 16, 4, 2
+    cur = n_log * ps - 5
+    table = page_runs.table(case, B, n_log, [(1, n_log), (n_log // 2, n_log)])
+    valid = jnp.asarray(np.repeat(table != 0, ps, axis=1) & (np.arange(n_log * ps) <= cur))
+    ks = jax.random.split(jax.random.PRNGKey(3), 3)
+    pages = page_runs.pool_pages(B, n_log)
+    kp, vp = (jax.random.normal(k, (pages, ps, hkv, D), jnp.float32) for k in ks[:2])
+    q = jax.random.normal(ks[2], (B, 1, h, D), jnp.float32)
+    bt, pos = jnp.asarray(table), jnp.asarray([cur], jnp.int32)
+    fused = paged_flash_decode_attention(q, kp, vp, bt, pos, valid, page_size=ps, interpret=True)
+    ref = flash_decode_attention(q, paged_gather_leaf(kp, bt, ps), paged_gather_leaf(vp, bt, ps), pos, valid,
+                                 block_l=ps, interpret=True)
+    assert np.array_equal(np.asarray(fused), np.asarray(ref)) and np.asarray(fused).any()
 
 
 def test_paged_kernel_matches_einsum_golden():

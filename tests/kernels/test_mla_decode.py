@@ -14,6 +14,7 @@ from neuronx_distributed_tpu.kernels.flash_decode import (
     paged_latent_decode_attention,
 )
 from neuronx_distributed_tpu.modules.attention import latent_decode_attention
+from tests.kernels import page_runs
 
 PS, D_C, D_R = 16, 32, 8
 SCALE = 0.21
@@ -174,3 +175,34 @@ def test_refuses_a_per_head_pool_and_a_mesh():
         paged_latent_decode_attention(
             q_c, q_r, jnp.zeros((5, PS, 2, D_C)), r_pool, table, jnp.asarray([3]),
             scale=SCALE, page_size=PS)
+
+
+@pytest.mark.parametrize("case", page_runs.CASES)
+def test_a_run_of_pages_fetched_whole_lands_as_a_copy_a_page_does(monkeypatch, case):
+    """Both leaves of the latent pool over every shape of block table, blocks
+    of eight pages: what the kernel returns with runs fetched whole (one copy
+    a leaf a run) is, bit for bit, what it returns with a copy a page."""
+    from neuronx_distributed_tpu.kernels import flash_decode
+
+    b, h, group = 3, 4, 8
+    monkeypatch.setattr(flash_decode, "LATENT_BLOCK_TOKENS", group * PS)
+    n_log = 2 * group + 6 if case == "short_last_block" else 3 * group     # the third block holds 6 pages
+    cur = n_log * PS - 5
+    spans = [(3, n_log), (group + 1, n_log), None]
+    table = page_runs.table(case, b, n_log, spans)
+    assert (page_runs.runs(table) > 0) == (case not in ("no_runs", "adjacent_off_the_grid"))
+    valid = jnp.asarray(np.repeat(table != 0, PS, axis=1) & (np.arange(n_log * PS) <= cur))
+    rng = np.random.default_rng(4)
+    pages = page_runs.pool_pages(b, n_log)
+    c_pool = jnp.asarray(rng.standard_normal((pages, PS, 1, D_C)), jnp.float32)
+    r_pool = jnp.asarray(rng.standard_normal((pages, PS, 1, D_R)), jnp.float32)
+    q_c = jnp.asarray(rng.standard_normal((b, 1, h, D_C)), jnp.float32)
+    q_r = jnp.asarray(rng.standard_normal((b, 1, h, D_R)), jnp.float32)
+    attend = lambda: np.asarray(paged_latent_decode_attention(   # noqa: E731
+        q_c, q_r, c_pool, r_pool, jnp.asarray(table), jnp.asarray([cur], jnp.int32), valid, scale=SCALE,
+        page_size=PS))
+    got = attend()
+    with page_runs.single_copies():
+        want = attend()
+    np.testing.assert_array_equal(got, want)
+    assert got[:2].any() and not got[2].any()

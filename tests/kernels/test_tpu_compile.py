@@ -201,14 +201,82 @@ def test_paged_latent_decode_compiles(topo):
     (call,) = [ln for ln in text.splitlines() if "custom-call(" in ln and KERNEL in ln]
     operands = call[call.index("custom-call(") + len("custom-call("):]
     operands = re.sub(r"/\*.*?\*/", "", operands[:operands.index(")")]).split(",")
-    # block table, live blocks, spans | positions, kv_valid, q_c, q_r | the two pool leaves
-    assert len(operands) == 9, operands
-    assert len(set(op.strip() for op in operands)) == 9, operands
+    # block table, its runs, live blocks, spans | positions, kv_valid, q_c, q_r | the two pool leaves
+    assert len(operands) == 10, operands
+    assert len(set(op.strip() for op in operands)) == 10, operands
     # two blocks of T tokens in flight (the 64-wide leaf in tiles of 128 lanes),
     # float32 accumulators for 16 rows, a slot's kv_valid row double-buffered
     vmem = (2 * LATENT_BLOCK_TOKENS * (MLA["d_c"] + 128) * 2
             + MLA["h"] * (MLA["d_c"] + 2) * 4 + 2 * seq * 4)
     assert vmem < 16 * 1024**2 / 4
+
+
+def _run_copies(jaxpr, leaf):
+    """The ``dma_start`` equations of a traced kernel (every nested jaxpr
+    walked) whose source is ``PAGE_RUN`` pages of a pool leaf ``(page, *leaf)``."""
+    from neuronx_distributed_tpu.kernels.flash_decode import PAGE_RUN
+
+    found = []
+
+    def walk(j):
+        for e in j.eqns:
+            if e.primitive.name == "dma_start":
+                tree = jax.tree.unflatten(e.params["tree"], e.invars)
+                src, transforms = tree[0], tree[1]
+                shape = src.aval.shape
+                for t in transforms:
+                    shape = t.get_indexer_shape() if hasattr(t, "get_indexer_shape") else shape
+                if tuple(shape) == (PAGE_RUN, 16) + tuple(leaf):
+                    found.append(e)
+            for sub in jax.core.jaxprs_in_params(e.params):
+                walk(sub)
+
+    for e in jaxpr.eqns:
+        if e.primitive.name == "pallas_call":
+            walk(e.params["jaxpr"])
+    return found
+
+
+@pytest.mark.parametrize("name", ["zaya1_quarter_tile", "trinity_joined", "dsv2lite_latent", "keye_index_key"])
+def test_block_walking_kernels_fetch_a_run_of_pages_with_one_copy(topo, name):
+    """The three kernels that fetch a slot's blocks themselves, at the cells'
+    leaves: ZAYA1's ``(4, 128)`` (a 16 KB page, a 64 KB run), Trinity's ``(16,
+    128)`` (a 256 KB run), DeepSeek-V2-Lite's latent of 512 lanes beside a
+    rotated key of 64 (held in tiles of 128: the copy names the tile's lanes,
+    ``_hbm_lanes``, of FOUR pages), and the 64-lane index key. Mosaic takes a
+    slice of ``PAGE_RUN`` pool pages as ONE copy's source into the block's
+    pages in VMEM, and the traced kernel starts such a copy for each leaf."""
+    from neuronx_distributed_tpu.kernels.flash_decode import (
+        paged_index_scores,
+        paged_latent_decode_attention,
+        paged_walk_decode_attention,
+    )
+
+    s = _one_chip(topo)
+    page = 16
+    pos = s((1,), jnp.int32)
+    if name in ("zaya1_quarter_tile", "trinity_joined"):
+        b, n_log, heads, rows = (32, 1024, 8, 4) if name == "zaya1_quarter_tile" else (8, 2048, 48, 16)
+        fn = lambda q, pool, bt, at, ok: paged_walk_decode_attention(q, pool, bt, at, kv_valid=ok, page_size=page)  # noqa: E731
+        args = (s((b, 1, heads, 128)), s((b * n_log + 1, page, rows, 128)))
+        leaves = [(rows, 128)]
+    elif name == "dsv2lite_latent":
+        b, n_log = 8, 2048
+        fn = lambda qc, qr, c, r, bt, at, ok: paged_latent_decode_attention(  # noqa: E731
+            qc, qr, c, r, bt, at, ok, scale=0.1147, page_size=page)
+        args = (s((b, 1, MLA["h"], MLA["d_c"])), s((b, 1, MLA["h"], MLA["d_r"])),
+                s((b * n_log + 1, page, 1, MLA["d_c"])), s((b * n_log + 1, page, 1, MLA["d_r"])))
+        leaves = [(MLA["d_c"],), (128,)]          # the 64-wide leaf's copy names its tile's 128 lanes
+    else:
+        b, n_log = 8, 2048
+        fn = lambda q, w, pool, bt, at, ok: paged_index_scores(q, w, pool, bt, at, ok, page_size=page)  # noqa: E731
+        args = (s((b, 1, 16, 64)), s((b, 1, 16)), s((b * n_log + 1, page, 1, 64)))
+        leaves = [(128,)]
+    args = args + (s((b, n_log), jnp.int32), pos, s((b, n_log * page), jnp.bool_))
+    assert _kernels(_compiled_text(fn, *args)) == 1
+    jaxpr = jax.make_jaxpr(fn)(*args)
+    for leaf in leaves:
+        assert _run_copies(jaxpr, leaf), f"no copy of a run of pages of the {leaf} leaf"
 
 
 def test_sparse_decode_kernels_compile_at_keye_geometry(topo):

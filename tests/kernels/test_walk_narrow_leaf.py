@@ -12,6 +12,7 @@ import pytest
 
 from neuronx_distributed_tpu.kernels.flash_decode import paged_gather_leaf, paged_walk_decode_attention
 from neuronx_distributed_tpu.modules.attention import _masked_gqa_attention, split_kv
+from tests.kernels import page_runs
 
 
 @pytest.mark.parametrize("dtype,tol", [("bfloat16", 0.02), ("float32", 2e-5)])
@@ -43,3 +44,32 @@ def test_walk_on_four_head_rows_a_token_is_the_einsum(dtype, tol, heads):
     got, want = np.asarray(f32(got)), np.asarray(want)
     assert np.abs(got[:3] - want[:3]).max() <= tol
     assert not got[3].any()                                   # a slot that maps nothing returns zeros
+
+
+@pytest.mark.parametrize("case", page_runs.CASES)
+def test_a_run_of_pages_fetched_whole_lands_as_a_copy_a_page_does(monkeypatch, case):
+    """The quarter-tile leaf in bf16 (a page is 16 KB: the leaf whose walk the
+    NUMBER of copies bounds) over every shape of block table, blocks of eight
+    pages: what the kernel returns with runs fetched whole is, bit for bit,
+    what it returns with a copy a page."""
+    from neuronx_distributed_tpu.kernels import flash_decode
+
+    hkv, d, page, heads, b = 2, 128, 16, 8, 3
+    monkeypatch.setattr(flash_decode, "WALK_BLOCK_TOKENS", 8 * page)
+    n_log = 22 if case == "short_last_block" else 24         # the third block holds 6 pages
+    cur = n_log * page - 5
+    spans = [(3, n_log), (n_log // 2 + 1, n_log), None]
+    table = page_runs.table(case, b, n_log, spans)
+    assert (page_runs.runs(table) > 0) == (case not in ("no_runs", "adjacent_off_the_grid"))
+    valid = np.repeat(table != 0, page, axis=1) & (np.arange(n_log * page) <= cur)
+    keys = jax.random.split(jax.random.PRNGKey(2), 2)
+    pool = jax.random.normal(keys[0], (page_runs.pool_pages(b, n_log), page, 2 * hkv, d), jnp.bfloat16)
+    q = jax.random.normal(keys[1], (b, 1, heads, d), jnp.bfloat16)
+    walk = lambda: np.asarray(paged_walk_decode_attention(   # noqa: E731
+        q, pool, jnp.asarray(table), jnp.asarray([cur], jnp.int32), kv_valid=jnp.asarray(valid), page_size=page,
+        interpret=True).astype(jnp.float32))
+    got = walk()
+    with page_runs.single_copies():
+        want = walk()
+    np.testing.assert_array_equal(got, want)
+    assert got[:2].any() and not got[2].any()

@@ -25,6 +25,7 @@ from neuronx_distributed_tpu.modules.attention import (
     window_prefill_attention,
     xla_attention,
 )
+from tests.kernels import page_runs
 
 B, H, HKV, D, PS, N_LOG = 3, 4, 2, 16, 8, 32
 
@@ -121,6 +122,32 @@ def test_the_walking_kernel_at_the_cells_head_geometries(small_blocks, hkv, g, d
     assert not np.asarray(f32(got[2])).any()   # the slot that maps nothing
 
 
+@pytest.mark.parametrize("case", page_runs.CASES)
+def test_the_walking_kernel_on_a_window_layers_table_is_the_same_with_runs_and_with_a_copy_a_page(monkeypatch, case):
+    """A window layer's table (a ``floor`` a slot, nothing mapped below its
+    page) over every shape of block table, blocks of eight pages: runs fetched
+    whole give, bit for bit, what a copy a page gives."""
+    monkeypatch.setattr(flash_decode, "WALK_BLOCK_TOKENS", 8 * PS)
+    n_log = 30 if case == "short_last_block" else N_LOG       # the fourth block holds 6 pages
+    cur = n_log * PS - 3
+    spans = [(5, n_log), (n_log // 2 + 1, n_log), None]
+    table = page_runs.table(case, B, n_log, spans)
+    mapped = np.repeat(table != 0, PS, axis=1)
+    valid = mapped & (np.arange(n_log * PS) <= cur)
+    floor = np.where(mapped.any(1), mapped.argmax(1) + 3, cur + 1).astype(np.int32)   # mid-page, above the freed pages
+    rng = np.random.default_rng(3)
+    pool = jnp.asarray(rng.standard_normal((page_runs.pool_pages(B, n_log), PS, 2 * HKV, D)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((B, 1, H, D)), jnp.float32)
+    walk = lambda: np.asarray(paged_walk_decode_attention(   # noqa: E731
+        q, pool, jnp.asarray(table), jnp.asarray([cur], jnp.int32), kv_valid=jnp.asarray(valid),
+        floor=jnp.asarray(floor), page_size=PS))
+    got = walk()
+    with page_runs.single_copies():
+        want = walk()
+    np.testing.assert_array_equal(got, want)
+    assert got[:2].any() and not got[2].any()
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["float32", "bfloat16"])
 def test_the_walking_kernel_reads_each_word_of_a_block_once_and_never_a_head_a_row_at_a_time(dtype):
     """The traced kernel's reads of its block buffer: ``2 Hkv x itemsize / 4``
@@ -135,7 +162,7 @@ def test_the_walking_kernel_reads_each_word_of_a_block_once_and_never_a_head_a_r
         shape((2, 1, hkv * g, d), dtype), shape((65, page, 2 * hkv, d), dtype), shape((2, n_log), jnp.int32),
         shape((1,), jnp.int32))
     (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
-    buffer = (2, flash_decode.WALK_BLOCK_TOKENS, 2 * hkv, d)
+    buffer = (flash_decode.BLOCKS_AHEAD + 1, flash_decode.WALK_BLOCK_TOKENS // page, page, 2 * hkv, d)   # blocks of pages: a run of them is one copy
     reads = []
 
     def walk(j):

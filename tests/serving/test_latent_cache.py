@@ -194,10 +194,13 @@ def test_mixtral_and_codegen_programs_are_the_parents(program):
 # as Mixtral's above, and anew again with PR 36's sampler branch;
 # ``deepseek.prefill``: anew with PR 49's head on the last position alone, as
 # Mixtral's and CodeGen's above; that PR left the two ``decode`` digests.
+# ``deepseek.decode.fused``: anew with PR 52's latent decode kernel, which
+# fetches a run of adjacent pages with one copy (the kernel is in the program;
+# ``gather`` and the prefill, which hold no such kernel, are as they were).
 DEEPSEEK_PARENT_PROGRAMS = {
     "deepseek.prefill": "a08844df325f29bf805134bc6c1f4c7dce7b7738457908712deb9b7d3cd39fe6",
     "deepseek.decode.gather": "277cce6135272958e7f21a7520375984ab6fcf96160e46688f9604f6fdadcc09",
-    "deepseek.decode.fused": "821885426fa3755ddf462db3230e800b98f18b5000050c355d391f46039854fe",
+    "deepseek.decode.fused": "8950beef88173af760551c358b5977d44e8cede7d6f51d6a9e10362c167d26a9",
 }
 
 

@@ -413,3 +413,181 @@ def test_conservative_admission_queues_on_page_pressure(setup):
     assert r1.state is RequestState.DONE and r2.state is RequestState.DONE
     assert eng.metrics.snapshot()["preemptions"] == 0
     eng.cache.check()
+
+
+# --- pages dealt in runs (ISSUE 52) ---------------------------------------------
+
+
+def _is_run(ids):
+    return all(b - a == 1 for a, b in zip(ids, ids[1:]))
+
+
+def test_allocator_deals_a_logical_run_from_one_group_of_adjacent_pages():
+    """The four pages of an owner's aligned logical run come from one aligned
+    group of the pool, over as many calls as the owner takes to ask for them;
+    a row that starts mid-run, and nobody's incomplete run, take single pages
+    and leave the whole groups whole."""
+    a = PageAllocator(41)                       # groups 4-7 .. 36-39; 1, 2, 3 and 40 are loose
+    ids = a.deal([("x", j) for j in range(6, 13)])             # logical 6, 7 | 8 .. 11 | 12
+    assert ids == [1, 2, 4, 5, 6, 7, 8]
+    assert a.deal([("y", 0)]) == [12]                          # another owner: the next whole group
+    assert a.deal([("x", 13)]) == [9]                          # x's run goes on where it was dealt
+    assert a.deal([("x", 14), ("x", 15), ("x", 16)]) == [10, 11, 16]
+    assert a.alloc(2) == [3, 40]                               # nobody's two pages: loose ones
+    assert a.alloc(5) == [20, 21, 22, 23, 24]                  # nobody's five: a run, and a group broken for the fifth
+    a.quarantine(13)
+    assert a.deal([("y", 1)]) == [14]                          # its page gone meanwhile: a single one, the group broken
+    rest = a.alloc(a.free_pages)
+    assert sorted(rest[-3:]) == [17, 18, 19] and a.free_pages == 0   # what is dealt to x's run goes last
+    for pid in rest:
+        a.deref(pid)
+    for pid in ids[2:6]:
+        a.deref(pid)
+    assert a.deal([("z", 0)]) == [4]                           # a freed run comes back whole
+
+
+def test_allocator_never_refuses_what_a_count_of_free_pages_grants():
+    """The run is a preference among FREE pages: with the pool down to single
+    free pages (no group whole), and with every free page dealt to somebody's
+    run, whatever number is free can be had, and one more is PageExhausted
+    with nothing taken."""
+    a = PageAllocator(65)
+    assert sorted(a.alloc(64)) == list(range(1, 65)) and a.free_pages == 0
+    for pid in range(2, 65, 4):                 # one page of every group: no run anywhere
+        a.deref(pid)
+    with pytest.raises(PageExhausted):
+        a.deal([("x", j) for j in range(17)])
+    assert a.free_pages == 16
+    assert sorted(a.deal([("x", j) for j in range(16)])) == list(range(2, 65, 4))
+    b = PageAllocator(9)
+    assert b.deal([("x", 0)]) == [4]                              # x is dealt group 4-7 and takes its first page
+    assert sorted(b.deal([("y", j) for j in range(1, 8)])) == [1, 2, 3, 5, 6, 7, 8]   # the rest of it among them
+    assert b.free_pages == 0
+    b.deref(5)
+    assert b.deal([("x", 1)]) == [5]                              # given back meanwhile: x's run goes on
+    b.deref(1)
+    assert b.deal([("x", 2)]) == [1]                              # 6 is y's now: a single page in its place
+
+
+def _bare_row(length, padded, window=None):
+    """A batch-1 prefill row of two layers with one joined leaf each (the
+    second a window layer when ``window`` is given): what an admission needs
+    of a model, without one."""
+    import jax.numpy as jnp
+
+    def node(w):
+        leaves = {"kv": jnp.ones((1, length, 2, 4), jnp.float32), "index": jnp.asarray(padded, jnp.int32),
+                  "kv_valid": jnp.arange(length)[None] < padded}
+        if w is not None:
+            leaves["window"] = jnp.zeros((0, w), bool)
+        return leaves
+
+    return {"layers_0": {"attn": node(None)}, "layers_1": {"attn": node(window)}}
+
+
+@pytest.mark.parametrize("window,sharing", [(None, False), (None, True), (480, False)],
+                         ids=["full", "full_shared_prefixes", "window"])
+def test_a_random_life_of_the_pool_leaks_nothing_and_deals_runs(window, sharing):
+    """A seeded random sequence of admissions (some onto a pinned prefix's
+    pages), decode windows, frees and preempt-and-rewinds over both kinds of
+    table: ``check()`` holds after every step, an allocation is refused only
+    when the pages are not there, nothing is copied and nothing leaks; and
+    with no sharing nine pages in ten lie in runs the kernels fetch whole."""
+    ps, slots, length, width = 4, 4, 2048, 8
+    mgr = PagedCacheManager(slots, length, ps, num_pages=1200, window=window, window_write_cols=width)
+    rng = np.random.default_rng(52)
+    rows = {padded: _bare_row(length, padded, window) for padded in (256, 512)}
+    active, pinned, shares = [], [], []
+    for step in range(160):
+        free_before = mgr.alloc.free_pages
+        roll = rng.random()
+        if roll < 0.25 and mgr.free_slots:
+            slot = mgr.acquire()
+            padded = int(rng.choice(list(rows)))
+            p = int(rng.integers(padded // 2 + 1, padded + 1))
+            shared, m = (), 0
+            if sharing and pinned and rng.random() < 0.5:
+                shared = pinned[int(rng.integers(len(pinned)))]
+                m = len(shared) * ps
+            try:
+                mgr.admit(rows[padded], slot, padded, p=p, shared_ids=shared, m_shared=min(m, p // ps * ps))
+                active.append(slot)
+                if sharing and rng.random() < 0.5:
+                    ids = mgr.slot_context_pages(slot, int(rng.integers(1, p // ps + 1)))
+                    mgr.pin_pages(ids)
+                    pinned.append(tuple(ids))
+            except PageExhausted:
+                assert free_before < padded // ps + 1
+                mgr.free(slot)
+        elif roll < 0.85 and active:
+            if mgr.cursor + width > length or not mgr.ensure_decode_window(active, width):
+                assert mgr.cursor + width > length or free_before < 3 * len(active) * (1 + (window is not None))
+                mgr.reset()                          # the wall: preempt and rewind
+                mgr.release_all_slots()
+                active.clear()
+            else:
+                mgr.update_after_decode(mgr.cache, steps=int(rng.integers(1, width + 1)))
+                if not sharing and mgr.page_stats["full_pages_mapped"] > 400:
+                    shares.append(mgr.page_stats["full_pages_in_runs"] / mgr.page_stats["full_pages_mapped"])
+                    if window is not None:
+                        shares.append(mgr.page_stats["window_pages_in_runs"] / mgr.page_stats["window_pages_mapped"])
+        elif active:
+            mgr.free(active.pop(int(rng.integers(len(active)))))
+        mgr.check()
+    for slot in active:
+        mgr.free(slot)
+    for ids in pinned:
+        mgr.unpin_pages(ids)
+    mgr.check()
+    assert mgr.alloc.free_pages == mgr.alloc.num_pages - 1 and mgr.alloc.copy_bytes == 0
+    assert not mgr.alloc._dealt and (window is None or not mgr.alloc_w._dealt)
+    if window is not None:
+        assert mgr.alloc_w.free_pages == mgr.alloc_w.num_pages - 1
+    if not sharing:
+        assert len(shares) > 10 and min(shares) >= 0.9, (len(shares), sorted(shares)[:8])
+
+
+def test_a_pool_down_to_single_free_pages_still_grants_what_is_free():
+    """Every group of the pool broken (one free page in each): an admission
+    that needs exactly the free pages gets them, the next decode window meets
+    the page-pressure wall and not an error, and the pages come back."""
+    ps, length = 4, 512
+    mgr = PagedCacheManager(2, length, ps, num_pages=129)
+    held = mgr.alloc.alloc(128)
+    singles = sorted(pid for pid in held if pid % 4 == 2)        # 32 pages, no two adjacent
+    for pid in singles:
+        mgr.alloc.deref(pid)
+    slot = mgr.acquire()
+    mgr.admit(_bare_row(length, 128), slot, 128, p=128)          # 128 tokens: 32 pages
+    assert sorted(mgr.slot_pages(slot)) == singles and mgr.alloc.free_pages == 0
+    assert mgr.page_stats == {"full_pages_mapped": 32, "full_pages_in_runs": 0}
+    assert mgr.ensure_decode_window([slot], 8) is False
+    for pid in held:
+        if pid not in singles:
+            mgr.alloc.deref(pid)
+    assert mgr.ensure_decode_window([slot], 8) is True           # two pages; every group still holds one of the slot's
+    assert len(mgr.slot_pages(slot)) == 34 and mgr.page_stats["full_pages_in_runs"] == 0
+    mgr.free(slot)
+    mgr.check()
+    assert mgr.alloc.free_pages == 128 and mgr.alloc.copy_bytes == 0
+
+
+def test_the_dispatch_span_counts_the_pages_mapped_and_those_in_runs(setup):
+    """Every paged engine, on ``nxd.step.decode.dispatch``: the pages the
+    table maps when the chunk is dispatched, and those of them in runs of
+    adjacent pool pages (here the 50-token prompt's whole groups of four)."""
+    cfg, model, params = setup
+    eng = ServingEngine(model, params, num_slots=2, decode_chunk_size=4, prefix_cache=None, kv_page_size=PS)
+    eng.submit(np.arange(1, 51, dtype=np.int32), GenerationConfig(max_new_tokens=6, temperature=0.0))
+    seen, span = [], eng._span
+
+    def spy(name, **stats):
+        if name == "nxd.step.decode.dispatch":
+            seen.append((stats, int((eng.cache._tables != 0).sum())))
+        return span(name, **stats)
+
+    eng._span = spy
+    eng.run()
+    assert seen and all(stats["full_pages_mapped"] == mapped for stats, mapped in seen)
+    assert all(4 <= stats["full_pages_in_runs"] < stats["full_pages_mapped"] for stats, _ in seen)
+    assert "window_pages_mapped" not in seen[0][0]

@@ -176,6 +176,9 @@ def test_the_dispatch_span_carries_both_tables_counts(system):
     # the full table maps every page of both contexts, the window table the window's
     assert first["full_pages_mapped"] > first["window_pages_mapped"] > 0
     assert first["window_pages_mapped"] <= 2 * engine.cache.window_pages_per_slot
+    # and, of each, the pages in runs of adjacent pool pages: the 70-token prompt's whole groups of four
+    assert first["full_pages_mapped"] > first["full_pages_in_runs"] >= 4
+    assert first["window_pages_mapped"] > first["window_pages_in_runs"] >= 4
 
 
 def test_a_model_with_one_kind_of_layer_builds_todays_tree():

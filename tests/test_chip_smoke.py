@@ -85,7 +85,8 @@ ROWS = {
     # tolerances are the rounding of a float32 matmul's summation order
     "mla": Row(chip_smoke.MlaSize(
         model=_tiny("deepseek-v2-lite-serve"), max_seq_len=512, slots=2, prompt_lens=(300, 128, 40),
-        tail=32, new_tokens=12, logit_tol=1e-3, typical_tol=1e-4, gap_tol=1e-3), (
+        tail=32, new_tokens=12, kernel_contexts=(70, 200, 33), kernel_calls=2, logit_tol=1e-3, typical_tol=1e-4,
+        gap_tol=1e-3), (
         "mla_matches_reference", "mla_resolved_latent_fused", "mla_cache_is_latent_sized",
         "mla_float8_latent_is_caught", "mla_dropped_rope_channel_is_caught", "kernel_mla_programs")),
     # Keye-VL-2.0's structure at the CPU stand-in's size (32 columns kept);
@@ -166,12 +167,15 @@ ROWS = {
         "moe_tiny_stream_wins_where_the_rule_takes_it", "moe_tiny-odd_stream_wins_where_the_rule_takes_it"),
         chips_to_say=("_wins_where_the_rule_takes_it",)),
     # ``--only walk`` at a tiny size (rows of 32 pages of 8 tokens, one block
-    # a row; a window of 40 under contexts that start mid-page): both kinds of
-    # layer against the float32 einsum. The times are the chip's to say
+    # a row; a window of 40; a second shape with no window layer): both kinds
+    # of layer against the float32 einsum, under a random block table and
+    # under the one a PagedCacheManager deals. The times are the chip's to say
     "walk": Row(chip_smoke.WalkSize(
-        q_heads=4, kv_heads=2, head_dim=16, window=40, max_seq_len=256, page=8, window_pages=8,
-        contexts=(131, 77, 30), cursor=157, calls=1, dtype="float32", tol=1e-5),
-        ("walk_full_matches_the_float32_einsum", "walk_window_matches_the_float32_einsum")),
+        shapes=(chip_smoke.WalkShape("tiny", 4, 2, 16, 256, (134, 78, 30), 157, window=40, window_pages=16),
+                chip_smoke.WalkShape("narrow", 2, 1, 16, 256, (134, 62), 157)),
+        page=8, calls=1, dtype="float32", tol=1e-5),
+        tuple(f"walk_{shape}_{table}_table_matches_the_float32_einsum"
+              for shape in ("tiny_window", "tiny_full", "narrow_full") for table in ("random", "dealt"))),
     # ``--only flash`` at a tiny size (blocks of 128; the padding's edge
     # inside a block, on a boundary and absent; GQA; a narrower value head; a
     # call that keeps residuals): every comparison. The times are the chip's.
