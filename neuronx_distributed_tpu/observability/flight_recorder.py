@@ -224,15 +224,18 @@ class StepLedger:
                 if self._watchdog is None:
                     self._start_watchdog()
         elif name == tracing.STEP_DISPATCH:
-            step.active = int(stats.get("active", 0))
-            step.sampled_slots = int(stats.get("sampled_slots", 0))
+            self._slots(step, stats)
             self._expect(step, self._chunk)
         step.stack.append((name, now))
 
     def note(self, name: str, stats: dict) -> None:
-        """Stats a span learned after it opened: a prefill's bucket, which
-        raises the step's limit before the program runs."""
+        """Stats a span learned after it opened: the slots a chunk runs for
+        (the engine counts them inside its dispatch span), and a prefill's
+        bucket, which raises the step's limit before the program runs."""
         step = self._open
+        if step is not None and name == tracing.STEP_DISPATCH:
+            self._slots(step, stats)
+            return
         if (step is None or "padded" not in stats
                 or name != tracing.STEP_PREFILL):
             return
@@ -253,6 +256,11 @@ class StepLedger:
             step.chunk = True
         elif not step.stack:
             self._open = None  # left without finish(): an exception's way out
+
+    @staticmethod
+    def _slots(step: _OpenStep, stats: dict) -> None:
+        step.active = int(stats.get("active", step.active))
+        step.sampled_slots = int(stats.get("sampled_slots", step.sampled_slots))
 
     @staticmethod
     def _expect(step: _OpenStep, median: Optional[_Median]) -> None:

@@ -46,6 +46,7 @@ from __future__ import annotations
 import contextlib
 import functools
 import hashlib
+import re
 import time
 import weakref
 from collections import OrderedDict
@@ -53,6 +54,8 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import numpy as np
+
+from neuronx_distributed_tpu.observability import tracing
 
 __all__ = [
     "UNAVAILABLE",
@@ -461,18 +464,38 @@ class _ProgramRecord:
         return None
 
 
+_NOT_IN_A_MODULE_NAME = re.compile(r"[^\w.-]")
+
+
+def module_name(fn) -> str:
+    """The name of a jitted callable's runs on the device's ``XLA Modules``
+    line, less the fingerprint in brackets: ``jit_`` and the function's name
+    as JAX spells it into the module (``chunk_fn`` gives ``jit_chunk_fn``,
+    ``<lambda>`` gives ``jit__lambda``: what is no word character becomes
+    ``_``, and underscores at the end are dropped). ``""`` for a callable
+    without a name (an AOT ``Compiled``)."""
+    name = getattr(fn, "__name__", None)
+    if not isinstance(name, str):
+        return ""
+    return ("jit_" + _NOT_IN_A_MODULE_NAME.sub("_", name)).rstrip("_")
+
+
 class LedgeredProgram:
     """Dispatch proxy over a jitted callable: counts dispatches, detects
     compiles via ``_cache_size()`` deltas, and forwards everything else
     (``_cache_size``, ``lower``, ...) to the wrapped function so existing
     compile-count properties keep working unchanged. ``last_call_compiled``
-    lets callers skip a compile-polluted wall measurement."""
+    lets callers skip a compile-polluted wall measurement. Every call runs
+    inside an ``nxd.program`` span (``tracing.PROGRAM``) whose two stats,
+    the ledger's name and :func:`module_name`, are fixed here: a trace
+    reader joins the device's runs to the calls that made them by those."""
 
     def __init__(self, ledger: "ProgramLedger", record: _ProgramRecord, fn):
         self._ledger = ledger
         self._record = record
         self._inner = fn
         self._cache_size_fn = getattr(fn, "_cache_size", None)
+        self._span_stats = {"program": record.name, "module": module_name(fn)}
         self.last_call_compiled = False
 
     @property
@@ -494,7 +517,8 @@ class LedgeredProgram:
         t0 = self._ledger._clock()
         self.last_call_compiled = False
         try:
-            out = self._inner(*args, **kwargs)
+            with tracing.span(tracing.PROGRAM, **self._span_stats):
+                out = self._inner(*args, **kwargs)
         finally:
             # compile detection must survive a RAISING dispatch: a
             # compile-then-execution-failure (OOM under HBM pressure —

@@ -9,9 +9,14 @@ request into a Perfetto flow.
 * a ``jax.profiler.TraceAnnotation(name, **stats)``. It is recorded only
   while a profiler session is open (``observability.profile_window`` or any
   ``jax.profiler.start_trace``), so the session IS the on-switch, and the
-  span lies on the device trace's clock by construction: it can be laid
-  against the ``XLA Ops`` line of the same ``.xplane.pb``. With no session
-  the annotation costs under a microsecond and records nothing.
+  span lands in the host plane of the same ``.xplane.pb`` as the device's
+  ``XLA Ops`` line. The two planes are NOT on one clock: on a v5e host the
+  host plane read +0.32..0.43 ms, +1.22..1.37 ms and +1.34..1.43 ms ahead
+  of the device plane in three traces, and the two drift apart by 10-35 us
+  a second (PR 53's chip runs; causality over the runs joined to their
+  ``nxd.program`` span bounds the offset). Whoever lays a span against
+  device ops fits that offset first (``perfbench/chunk_gaps.py``). With no
+  session the annotation costs under a microsecond and records nothing.
 * the caller's :class:`~neuronx_distributed_tpu.utils.timeline.Timeline`,
   if it has one that is enabled: a Chrome ``X`` event of the same name with
   the stats as its ``args``.
@@ -69,13 +74,22 @@ STEP_PREEMPT = "nxd.step.preempt"
 STEP_ADMIT = "nxd.step.admit"
 STEP_PREFILL = "nxd.step.prefill"
 STEP_FIRST_TOKEN = "nxd.step.prefill.first_token"
+STEP_PAGES = "nxd.step.decode.pages"
 STEP_DISPATCH = "nxd.step.decode.dispatch"
 STEP_READBACK = "nxd.step.decode.readback"
 STEP_EMIT = "nxd.step.decode.emit"
 STEP_HEALTH = "nxd.step.health"
+STEP_CLOSE = "nxd.step.close"
+# around every call of a ledgered program (observability/programs.py), in the
+# engine and in the trainer alike: a bare profiler annotation whose two stats
+# are strings fixed when the program is wrapped, ``program`` (the ledger's
+# name) and ``module`` (the name of its runs on the device's ``XLA Modules``
+# line), so that a trace reader can tell which call made which run
+PROGRAM = "nxd.program"
 SERVE_SPANS = (
     STEP, STEP_REAP, STEP_PREEMPT, STEP_ADMIT, STEP_PREFILL, STEP_FIRST_TOKEN,
-    STEP_DISPATCH, STEP_READBACK, STEP_EMIT, STEP_HEALTH,
+    STEP_PAGES, STEP_DISPATCH, STEP_READBACK, STEP_EMIT, STEP_HEALTH, STEP_CLOSE,
+    PROGRAM,
 )
 
 # Trainer.fit: TRAIN_STEP is a StepTraceAnnotation around one iteration
@@ -86,6 +100,7 @@ TRAIN_READBACK = "nxd.train.readback"
 TRAIN_CALLBACKS = "nxd.train.callbacks"
 TRAIN_SPANS = (
     TRAIN_STEP, TRAIN_FETCH, TRAIN_DISPATCH, TRAIN_READBACK, TRAIN_CALLBACKS,
+    PROGRAM,
 )
 
 

@@ -2,8 +2,9 @@
 trainer under ``observability.profile_window``, so that the profiler's
 session never opens inside a pytest worker (a worker that had traced
 in-process aborted in a later, unrelated test: tests/benchmark/
-test_rehearsal.py). Writes the trace under ``<out>/trace`` and what it
-counted to ``<out>/facts.json``."""
+test_rehearsal.py). Writes the trace under ``<out>/trace``, that of a paged
+engine's decode-only steps under ``<out>/paged`` and what it counted to
+``<out>/facts.json``."""
 
 import json
 import os
@@ -65,6 +66,19 @@ def serve(model, params):
             "tokens": [list(map(int, r.tokens)) for r in reqs]}
 
 
+def serve_paged(model, params, engine=None):
+    """One request on a PAGED engine, decode-only steps after the first: the
+    page dealing's span, and every ledgered call of the run counted by its
+    ledger. Called once to warm (returns the engine) and once traced."""
+    if engine is None:
+        engine = ServingEngine(model, params, num_slots=2, prefix_cache=None, kv_page_size=8)
+    before = {name: info.dispatches for name, info in engine.programs.programs().items()}
+    engine.submit(np.asarray([3, 5, 7, 11, 13], np.int32), GenerationConfig(max_new_tokens=40, temperature=0.0))
+    engine.run()
+    calls = {name: info.dispatches - before.get(name, 0) for name, info in engine.programs.programs().items()}
+    return engine, {name: n for name, n in calls.items() if n}
+
+
 def train(steps):
     from neuronx_distributed_tpu.parallel import mesh as mesh_lib
     from neuronx_distributed_tpu.trainer.loop import Trainer
@@ -98,6 +112,9 @@ def main(out):
     with profile_window(os.path.join(out, "trace")):
         facts["on"] = serve(model, params)
         train(3)
+    paged, _ = serve_paged(model, params)
+    with profile_window(os.path.join(out, "paged")):
+        _, facts["paged_calls"] = serve_paged(model, params, paged)
     with open(os.path.join(out, "facts.json"), "w") as f:
         json.dump(facts, f)
 

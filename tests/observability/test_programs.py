@@ -51,6 +51,61 @@ def test_wrap_counts_dispatches_and_detects_compiles():
     assert rec.compile_wall_s > 0.0
 
 
+def test_every_call_runs_inside_one_program_span_with_its_two_names(monkeypatch):
+    """``nxd.program``: one span a call through the one ``span`` primitive,
+    the ledger's name and the module's fixed when the program is wrapped, no
+    timeline and no step ledger (a bare profiler annotation); a raising call
+    closes it, and still counts its compile."""
+    from neuronx_distributed_tpu.observability import programs, tracing
+
+    seen = []
+
+    class Recording:
+        def __init__(self, name, **stats):
+            self.row = [name, stats, None]
+
+        def __enter__(self):
+            seen.append(self.row)
+            self.row[2] = "open"
+            return self
+
+        def __exit__(self, kind, *_):
+            self.row[2] = "closed" if kind is None else f"closed on {kind.__name__}"
+            return False
+
+    real = tracing.span
+    kwargs = []
+
+    def span(name, *args, **kw):
+        kwargs.append((args, {k: v for k, v in kw.items() if k in ("ledger", "annotation")}))
+        return real(name, *args, annotation=Recording, **kw)
+
+    monkeypatch.setattr(programs.tracing, "span", span)
+    led = ProgramLedger()
+
+    def chunk_fn(x, fail=False):
+        if fail:
+            raise ValueError("traced and refused")
+        return x * 2
+
+    f = led.wrap("decode_chunk", jax.jit(chunk_fn, static_argnums=(1,)))
+    f(jnp.ones(4))
+    f(jnp.ones(4))
+    with pytest.raises(ValueError):
+        f(jnp.ones(4), True)
+    stats = {"program": "decode_chunk", "module": "jit_chunk_fn"}
+    assert seen == [[tracing.PROGRAM, stats, "closed"]] * 2 + [[tracing.PROGRAM, stats, "closed on ValueError"]]
+    assert kwargs == [((), {})] * 3                     # no timeline, no step ledger
+    assert led.record("decode_chunk").dispatches == 2
+    # with no session the real primitive hands back the profiler's own annotation
+    assert type(real(tracing.PROGRAM, **stats)) is jax.profiler.TraceAnnotation
+    # the module's name as JAX spells it: what is no word character becomes "_", the last ones go
+    assert programs.module_name(jax.jit(lambda x: x)) == "jit__lambda"
+    assert programs.module_name(object()) == ""
+    g = led.wrap("lam", jax.jit(lambda x: x + 1))
+    assert "HloModule jit__lambda," in g.lower(1.0).compile().as_text()
+
+
 def test_cost_analysis_schema_on_this_container():
     """Cost analysis is AVAILABLE on this CPU (lowered.cost_analysis);
     memory analysis stays UNAVAILABLE without the opt-in — the explicit

@@ -17,7 +17,9 @@ import numpy as np
 import pytest
 
 from neuronx_distributed_tpu.inference import GenerationConfig
+from neuronx_distributed_tpu.observability.programs import module_name
 from neuronx_distributed_tpu.serving import ServingEngine
+from tests.serving.span_spy import overhear
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 TINY = os.path.join(ROOT, "tests", "benchmark", "data", "configs")
@@ -46,6 +48,9 @@ def _programs(config_name):
         if key in ("decode_chunk", "prefill"):
             lowered = info.variants[0].lower()
             out[key] = (lowered.as_text(debug_info=True), lowered.compile().as_text())
+    # what the ``nxd.program`` span around each call says its module is
+    out["modules"] = {"decode_chunk": module_name(engine._decode_chunk.__wrapped__),
+                      "prefill": module_name(next(iter(engine._prefill_fns.values())).__wrapped__)}
     return out
 
 
@@ -74,6 +79,9 @@ def test_programs_keep_their_jit_names(codegen, mixtral):
     for programs in (codegen, mixtral):
         assert re.search(r"HloModule (\S+?),", programs["decode_chunk"][1]).group(1) == "jit_chunk_fn"
         assert re.search(r"HloModule (\S+?),", programs["prefill"][1]).group(1) == "jit_fn"
+        # and the ``module`` stat of the call's ``nxd.program`` span says the same:
+        # ``perfbench/chunk_gaps.py`` joins the device's runs to their calls by it
+        assert programs["modules"] == {"decode_chunk": "jit_chunk_fn", "prefill": "jit_fn"}
 
 
 def test_a_kernel_is_still_called_in_the_attention_scope(codegen, mixtral):
@@ -159,17 +167,10 @@ def test_the_dispatch_span_carries_the_selection_stats():
         engine = ServingEngine(model, params, num_slots=2, kv_page_size=16)
         for p in prompts:
             engine.submit(np.arange(1, 1 + p, dtype=np.int32), GenerationConfig(max_new_tokens=2, temperature=0.0))
-        seen = []
-        span = engine._span
-
-        def spy(name, **stats):
-            if name == "nxd.step.decode.dispatch":
-                seen.append({k: v for k, v in stats.items() if k in ("ctx_tokens", "selected_tokens")})
-            return span(name, **stats)
-
-        engine._span = spy
+        seen = overhear(engine, "nxd.step.decode.dispatch")
         engine.run()
-        return seen[0]         # the first chunk: each slot holds its prompt and the prefill's token
+        # the first chunk: each slot holds its prompt and the prefill's token
+        return {k: v for k, v in seen[0].items() if k in ("ctx_tokens", "selected_tokens")}
 
     got = stats(KeyeVL2ForCausalLM(tiny_keye_vl2(), attention_impl="xla"), (40, 9))
     assert got == {"ctx_tokens": 41 + 10, "selected_tokens": 16 + 10}

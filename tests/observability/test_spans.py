@@ -21,7 +21,9 @@ from neuronx_distributed_tpu.utils.timeline import Timeline
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
-CHILDREN = tuple(n for n in tracing.SERVE_SPANS if n not in (tracing.STEP, tracing.STEP_FIRST_TOKEN))
+# the siblings that tile a step: not the step, not what nests in a sibling
+CHILDREN = tuple(n for n in tracing.SERVE_SPANS
+                 if n not in (tracing.STEP, tracing.STEP_FIRST_TOKEN, tracing.PROGRAM))
 
 
 def _host_events(trace_dir):
@@ -52,6 +54,7 @@ def traced(tmp_path_factory):
     assert done.returncode == 0, done.stderr[-3000:]
     with open(os.path.join(out, "facts.json")) as f:
         facts = json.load(f)
+    facts["paged_events"] = _host_events(os.path.join(out, "paged"))
     return facts, _host_events(os.path.join(out, "trace")), _host_events(os.path.join(out, "empty"))
 
 
@@ -63,7 +66,9 @@ def _inside(parent, events):
 def test_every_name_of_the_contract_is_in_the_host_plane(traced):
     facts, events, _ = traced
     assert facts["on"]["preemptions"] > 0          # the scenario reaches the wall
-    assert set(tracing.SERVE_SPANS) <= set(events)
+    # the page dealing's span is a paged engine's: the driver's second scenario
+    assert set(tracing.SERVE_SPANS) - {tracing.STEP_PAGES} <= set(events)
+    assert tracing.STEP_PAGES in facts["paged_events"]
     assert set(tracing.TRAIN_SPANS) <= set(events)
     assert len(events[tracing.STEP_PREFILL]) == facts["on"]["prefills"]
     assert len(events[tracing.STEP_READBACK]) == facts["on"]["chunks"]
@@ -74,15 +79,15 @@ def test_every_name_of_the_contract_is_in_the_host_plane(traced):
 def test_children_nest_inside_the_step_and_tile_it(traced):
     _, events, _ = traced
     steps = events[tracing.STEP]
-    for name in tracing.SERVE_SPANS[1:]:
-        for e in events[name]:
+    for name in CHILDREN + (tracing.STEP_FIRST_TOKEN,):
+        for e in events.get(name, []):               # no page dealing in the unpaged scenario
             assert sum(1 for s in steps if s[3] == e[3] and s[0] <= e[0] and e[1] <= s[1]) == 1, name
     for e in events[tracing.STEP_FIRST_TOKEN]:     # and the first token inside its prefill
         assert len([p for p in events[tracing.STEP_PREFILL] if p[0] <= e[0] and e[1] <= p[1]]) == 1
     # siblings do not overlap; in a decode-only step they cover the step
     covered = []
     for step in steps:
-        kids = sorted(k for name in CHILDREN for k in _inside(step, events[name]))
+        kids = sorted(k for name in CHILDREN for k in _inside(step, events.get(name, [])))
         for left, right in zip(kids, kids[1:]):
             assert left[1] <= right[0]
         decode_only = (_inside(step, events[tracing.STEP_READBACK])
@@ -94,6 +99,8 @@ def test_children_nest_inside_the_step_and_tile_it(traced):
     # the trainer's phases lie inside their step
     for name in tracing.TRAIN_SPANS[1:]:
         for e in events[name]:
+            if name == tracing.PROGRAM and e[2]["program"] != "train_step":
+                continue                             # the engine's calls share the name
             assert any(s[0] <= e[0] and e[1] <= s[1] for s in events[tracing.TRAIN_STEP]), name
 
 
@@ -102,7 +109,8 @@ def test_stats_are_host_ints_and_rid_links_a_request(traced):
     for name, rows in events.items():
         for _, _, stats, _ in rows:
             for key, value in stats.items():
-                assert isinstance(value, int), (name, key, value)
+                # but for nxd.program's two names, fixed when the program is wrapped
+                assert isinstance(value, str if name == tracing.PROGRAM else int), (name, key, value)
     first = {e[2]["rid"] for e in events[tracing.STEP_FIRST_TOKEN]}
     assert first == set(facts["on"]["rids"])       # a fresh request samples one first token
     prefills = events[tracing.STEP_PREFILL]
@@ -141,8 +149,55 @@ def test_stats_are_host_ints_and_rid_links_a_request(traced):
         assert 0 <= stats["cpu_us"] <= (end - start) / 1e3 + 1000 and stats["overrun_us"] == 0
     assert sum(e[2]["cpu_us"] for e in events[tracing.STEP]) > 0
     # what nothing reads is not emitted
-    for name in (tracing.STEP_REAP, tracing.STEP_PREEMPT, tracing.STEP_ADMIT, tracing.STEP_HEALTH):
+    for name in (tracing.STEP_REAP, tracing.STEP_PREEMPT, tracing.STEP_ADMIT, tracing.STEP_HEALTH,
+                 tracing.STEP_CLOSE):
         assert all(e[2] == {} for e in events[name]), name
+    assert all(e[2] == {} for e in facts["paged_events"][tracing.STEP_PAGES])
+
+
+def test_every_ledgered_call_is_one_program_span_with_its_two_names(traced):
+    """``nxd.program``: one a call of a ledgered program, the engine's and the
+    trainer's alike, with the ledger's name and the module's. The paged
+    scenario counts its ledger's dispatches while the session is open."""
+    facts, events, _ = traced
+    paged = facts["paged_events"]
+    seen = {}
+    for _, _, stats, _ in paged[tracing.PROGRAM]:
+        assert set(stats) == {"program", "module"}
+        seen[stats["program"]] = seen.get(stats["program"], 0) + 1
+    assert seen == facts["paged_calls"] and seen["decode_chunk"] >= 3
+    modules = {stats["program"].split("[")[0]: stats["module"]
+               for _, _, stats, _ in paged[tracing.PROGRAM] + events[tracing.PROGRAM]}
+    # the names the device's ``XLA Modules`` line gives those programs' runs
+    # (tests/observability/test_scope_names.py pins the first two on the compiled programs)
+    assert modules["decode_chunk"] == "jit_chunk_fn" and modules["prefill"] == "jit_fn"
+    assert modules["first_token"] == "jit_sample_row" and modules["paged_admit"] == "jit__paged_admit"
+    assert modules["train_step"] == "jit_step_fn"
+    # a chunk's call lies inside its step's dispatch span, a first token's inside its span
+    for a, b, stats, thread in paged[tracing.PROGRAM]:
+        if stats["program"] == "decode_chunk":
+            assert any(d[3] == thread and d[0] <= a and b <= d[1] for d in paged[tracing.STEP_DISPATCH])
+
+
+def test_a_decode_only_step_leaves_no_stretch_outside_a_child_span(traced):
+    """``step()`` of a paged engine, decode only: reap, pages, dispatch,
+    readback, emit, health, close, and between two of them (and before the
+    first and after the last) a few statements: under 60 us each in the
+    median over the steps, on a CPU that other tests share."""
+    facts, _, _ = traced
+    paged = facts["paged_events"]
+    order = [tracing.STEP_REAP, tracing.STEP_PAGES, tracing.STEP_DISPATCH, tracing.STEP_READBACK,
+             tracing.STEP_EMIT, tracing.STEP_HEALTH, tracing.STEP_CLOSE]
+    stretches = []
+    for step in paged[tracing.STEP]:
+        kids = sorted((k[0], k[1], name) for name in CHILDREN for k in _inside(step, paged.get(name, [])))
+        if [name for _, _, name in kids] != order:
+            continue                                 # the step that prefilled, the one that retired
+        edges = [step[0]] + [t for a, b, _ in kids for t in (a, b)] + [step[1]]
+        stretches.append([edges[i + 1] - edges[i] for i in range(0, len(edges), 2)])
+    assert len(stretches) >= 3
+    for i in range(len(order) + 1):
+        assert statistics.median(s[i] for s in stretches) < 60_000, (i, stretches)
 
 def test_no_session_no_event_and_no_extra_sync(traced):
     facts, _, empty = traced
@@ -179,7 +234,8 @@ def test_timeline_gets_the_same_names_and_stats(tmp_path):
     events = [e for e in json.loads(path.read_text())["traceEvents"] if e["ph"] == "X"]
     names = {e["name"] for e in events}
     assert names <= set(tracing.SERVE_SPANS)
-    assert set(tracing.SERVE_SPANS) - names == {tracing.STEP_PREEMPT}
+    # no preemption here, no paged cache, and nxd.program is the profiler's alone
+    assert set(tracing.SERVE_SPANS) - names == {tracing.STEP_PREEMPT, tracing.STEP_PAGES, tracing.PROGRAM}
     assert all(e["cat"] == tracing.SPAN_CATEGORY for e in events)
     (prefill,) = [e for e in events if e["name"] == tracing.STEP_PREFILL]
     assert prefill["args"]["rid"] == req.rid and prefill["args"]["ttft_us"] >= 0

@@ -33,6 +33,7 @@ from neuronx_distributed_tpu.serving import (
     RequestState,
     ServingEngine,
 )
+from tests.serving.span_spy import overhear
 
 PS = 8  # page size used throughout
 
@@ -579,15 +580,8 @@ def test_the_dispatch_span_counts_the_pages_mapped_and_those_in_runs(setup):
     cfg, model, params = setup
     eng = ServingEngine(model, params, num_slots=2, decode_chunk_size=4, prefix_cache=None, kv_page_size=PS)
     eng.submit(np.arange(1, 51, dtype=np.int32), GenerationConfig(max_new_tokens=6, temperature=0.0))
-    seen, span = [], eng._span
-
-    def spy(name, **stats):
-        if name == "nxd.step.decode.dispatch":
-            seen.append((stats, int((eng.cache._tables != 0).sum())))
-        return span(name, **stats)
-
-    eng._span = spy
+    seen = overhear(eng, "nxd.step.decode.dispatch", probe=lambda: int((eng.cache._tables != 0).sum()))
     eng.run()
-    assert seen and all(stats["full_pages_mapped"] == mapped for stats, mapped in seen)
-    assert all(4 <= stats["full_pages_in_runs"] < stats["full_pages_mapped"] for stats, _ in seen)
-    assert "window_pages_mapped" not in seen[0][0]
+    assert seen and all(stats["full_pages_mapped"] == stats["probe"] for stats in seen)
+    assert all(4 <= stats["full_pages_in_runs"] < stats["full_pages_mapped"] for stats in seen)
+    assert "window_pages_mapped" not in seen[0]

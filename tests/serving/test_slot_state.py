@@ -29,6 +29,7 @@ from neuronx_distributed_tpu.utils.fingerprint import cache_fingerprint
 
 from perfbench.references.zaya import Reference
 from tests.models.test_zaya import published_keys, weights
+from tests.serving.span_spy import overhear
 
 PAGE, CHUNK = 8, 4
 LOGIT_ATOL = 5e-5
@@ -151,14 +152,7 @@ def test_preempt_and_rewind_at_the_wall_rebuilds_the_state_by_a_prefill(system, 
 def test_the_dispatch_span_carries_the_states_bytes_and_other_models_carry_none(system):
     engine = engine_of(system, "gather")
     submit(engine, np.random.default_rng(5), 12, 6)
-    seen, span = [], engine._span
-
-    def spy(name, **stats):
-        if name == "nxd.step.decode.dispatch":
-            seen.append(stats)
-        return span(name, **stats)
-
-    engine._span = spy
+    seen = overhear(engine, "nxd.step.decode.dispatch")
     engine.run()
     cfg = system[0]
     assert seen[0]["slot_state_bytes_per_layer"] == 4 * cfg.slot_state_width       # float32 here
@@ -289,14 +283,7 @@ def test_the_dispatch_span_carries_the_recurrent_layers_stats(recurrent):
     cfg = recurrent[0]
     engine = engine_of(recurrent, "fused")
     submit(engine, np.random.default_rng(5), 12, 6)
-    seen, span = [], engine._span
-
-    def spy(name, **stats):
-        if name == "nxd.step.decode.dispatch":
-            seen.append(stats)
-        return span(name, **stats)
-
-    engine._span = spy
+    seen = overhear(engine, "nxd.step.decode.dispatch")
     engine.run()
     h, d = cfg.linear_num_heads, cfg.linear_head_dim
     recur, conv = h * d * d * 4, (cfg.conv_kernel - 1) * cfg.conv_channels * 4       # float32 here

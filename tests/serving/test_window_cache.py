@@ -26,6 +26,7 @@ from neuronx_distributed_tpu.serving.paging import (
 
 from perfbench.references.afmoe import Reference
 from tests.models.test_afmoe import published_keys, weights
+from tests.serving.span_spy import overhear
 
 WINDOW, PAGE, CHUNK = 32, 8, 4
 LOGIT_ATOL = 5e-5
@@ -162,14 +163,7 @@ def test_the_dispatch_span_carries_both_tables_counts(system):
     rng = np.random.default_rng(4)
     submit(engine, rng, 70, 6)
     submit(engine, rng, 12, 6)
-    seen, span = [], engine._span
-
-    def spy(name, **stats):
-        if name == "nxd.step.decode.dispatch":
-            seen.append(stats)
-        return span(name, **stats)
-
-    engine._span = spy
+    seen = overhear(engine, "nxd.step.decode.dispatch")
     engine.run()
     first = seen[0]     # each slot holds its prompt and the prefill's token
     assert first["ctx_tokens"] == 71 + 13 and first["window_tokens"] == 32 + 13
