@@ -94,6 +94,17 @@ class MoeSize:
         ("mixtral-8x7b", 8, 4096, 14336, 2, 16),
     )
     tokens: Tuple[int, ...] = (1, 8, 16, 32, 64, 128, 256)
+    # a prefill's expert layer: ``(name, experts, hidden, intermediate, top_k,
+    # experts held (None: all), bucket, its cell's emptiest and fullest prompt
+    # in that bucket)``; a full bucket is run besides
+    prefill: Tuple[Tuple[str, int, int, int, int, Optional[int], int, Tuple[int, int]], ...] = (
+        ("deepseek-v2-lite", 64, 2048, 1408, 6, None, 16384, (9003, 13950)),
+        ("keye-vl2", 128, 2048, 768, 8, None, 16384, (9624, 15690)),
+        ("mixtral-8x7b", 8, 4096, 14336, 2, None, 1024, (550, 826)),
+        ("glm-5-held", 256, 6144, 2048, 8, 8, 16384, (8862, 12765)),
+    )
+    prefill_calls: int = 4
+    sampled_rows: int = 256
     calls: int = 20
     dtype: str = "bfloat16"
     # the worst row's |form - float32 jnp| / |float32 jnp| (L2 over the hidden
@@ -1159,10 +1170,11 @@ def mla_kernel(size: MlaSize, published: dict, seed: int, dtype) -> None:
 # --- learned sparse attention ---------------------------------------------------------
 
 
-def _median_call_ms(fn, args, calls: int, repeats: int = 5) -> float:
-    """Median over ``repeats`` of the wall of ONE program that calls ``fn``
-    ``calls`` times (each call on inputs that depend on the last result, so
-    none is folded away), over ``calls``."""
+def _call_timer(fn, calls: int, repeats: int = 5) -> Callable[..., float]:
+    """``args -> ms``: the median over ``repeats`` of the wall of ONE program
+    that calls ``fn`` ``calls`` times (each call on inputs that depend on the
+    last result, so none is folded away), over ``calls``. The program compiles
+    once a shape, however many ``args`` are timed."""
     import jax
     import jax.numpy as jnp
 
@@ -1174,13 +1186,21 @@ def _median_call_ms(fn, args, calls: int, repeats: int = 5) -> float:
         return jax.lax.scan(body, jnp.zeros((), jnp.float32), None, length=calls)[0]
 
     run = jax.jit(many)
-    jax.block_until_ready(run(*args))
-    walls = []
-    for _ in range(repeats):
-        t0 = time.perf_counter()
+
+    def ms(*args) -> float:
         jax.block_until_ready(run(*args))
-        walls.append(time.perf_counter() - t0)
-    return 1e3 * sorted(walls)[len(walls) // 2] / calls
+        walls = []
+        for _ in range(repeats):
+            t0 = time.perf_counter()
+            jax.block_until_ready(run(*args))
+            walls.append(time.perf_counter() - t0)
+        return 1e3 * sorted(walls)[len(walls) // 2] / calls
+
+    return ms
+
+
+def _median_call_ms(fn, args, calls: int, repeats: int = 5) -> float:
+    return _call_timer(fn, calls, repeats)(*args)
 
 
 def dsa_kernels(size: DsaSize, published: dict, seed: int, dtype) -> Dict[str, bool]:
@@ -2994,6 +3014,7 @@ def moe_phase(size: MoeSize, seed: int) -> Dict[str, bool]:
     from neuronx_distributed_tpu.kernels.moe_stream import hit_experts, moe_stream_mlp
     from neuronx_distributed_tpu.modules.moe.expert_mlps import (
         MOE_STREAM_MAX_TOKENS,
+        ExpertMLPs,
         _ragged_routed_mlp,
     )
 
@@ -3066,6 +3087,75 @@ def moe_phase(size: MoeSize, seed: int) -> Dict[str, bool]:
     for name, by_rows in wins.items():
         checks[f"moe_{name}_stream_wins_where_the_rule_takes_it"] = all(
             won for rows, won in by_rows.items() if rows <= MOE_STREAM_MAX_TOKENS)
+
+    # a PREFILL's expert layer: a left-padded prompt in its bucket through the parent's
+    # form (no mask: every row routed) and through the masked form (the padded rows' slots
+    # absent), as the layer itself dispatches them
+    for name, n_e, hid, inter, k, held, bucket, prompts in size.prefill:
+        count = held or n_e
+        layer = ExpertMLPs(num_experts=n_e, hidden_size=hid, intermediate_size=inter, top_k=k, strategy="blockwise",
+                           held_experts=(0, held) if held else None, dtype=dtype, param_dtype=dtype)
+        keys = jax.random.split(jax.random.fold_in(jax.random.PRNGKey(seed), bucket), 5)
+        gate = jax.random.normal(keys[0], (count, hid, inter), dtype) * hid ** -0.5
+        up = jax.random.normal(keys[1], (count, hid, inter), dtype) * hid ** -0.5
+        down = jax.random.normal(keys[2], (count, inter, hid), dtype) * inter ** -0.5
+        x = jax.random.normal(keys[3], (bucket, hid), dtype)
+        top_w, top_e = jax.lax.top_k(jax.nn.softmax(jax.random.normal(keys[4], (bucket, n_e))), k)
+        n_slots = bucket * k
+        present = top_e < count     # the held experts are the first ``count``
+
+        def weights(g, u, d):
+            return {"params": {"gate_proj": g, "up_proj": u, "down_proj": d}}
+
+        # the routing rides as arguments: its sort is then work of the call in both forms
+        parent = lambda x_, e, w, m, g, u, d: layer.apply(weights(g, u, d), x_, e, w)  # noqa: E731
+        masked = lambda x_, e, w, m, g, u, d: layer.apply(weights(g, u, d), x_, e, w, m)  # noqa: E731
+        worst, zero, same, equal, blind, ms_of = 0.0, True, 0.0, True, True, {}
+        rng = np.random.default_rng(seed)
+        forms = {"parent": parent, "masked": masked}
+        run = {form: jax.jit(fn) for form, fn in forms.items()}      # a program a form: the mask is an argument
+        timer = {form: _call_timer(fn, size.prefill_calls) for form, fn in forms.items()}
+        for prompt in tuple(prompts) + (bucket,):
+            mask = jnp.arange(bucket) >= bucket - prompt
+            args = (x, top_e, top_w, mask, gate, up, down)
+            got, before = run["masked"](*args), run["parent"](*args)
+            # content rows that some expert here serves (a held share serves a few of them)
+            served = np.flatnonzero(np.asarray(mask & jnp.any(present, axis=1)))
+            rows = np.sort(rng.choice(served, min(size.sampled_rows, served.size), replace=False))
+            want = exact(x[rows], jnp.where(present, top_e, count)[rows], top_w[rows], gate, up, down)
+            worst = max(worst, worst_row(got[rows], want))
+            zero &= bool(jnp.all(got[:bucket - prompt] == 0))
+            same = max(same, worst_row(got[rows], f32(before[rows])))
+            equal &= bool(jnp.all(got[bucket - prompt:] == before[bucket - prompt:]))
+            # other values and another routing in the padded rows: no content row may notice
+            pad = jnp.logical_not(mask)[:, None]
+            other = run["masked"](jnp.where(pad, 3 * x, x), jnp.where(pad, jnp.roll(top_e, 1, axis=0), top_e), *args[2:])
+            blind &= bool(jnp.all(other == got))
+            ms_of[prompt] = {form: timer[form](*args) for form in forms}
+            log(f"moe prefill {name}: {prompt} of {bucket} rows ({n_slots} slots, {'held ' if held else ''}{count} experts): "
+                "ms a layer " + ", ".join(f"{form} {v:.3f}" for form, v in ms_of[prompt].items()))
+        if not held:
+            # the probe: does one ragged_dot's time follow sum(group_sizes), the rows being what they are?
+            xs = jax.random.normal(keys[3], (n_slots, hid), dtype)
+            alone, probe = _call_timer(jax.lax.ragged_dot, size.prefill_calls), {}
+            for prompt in tuple(prompts) + (bucket,):     # the groups its content rows' routing makes
+                sizes = jnp.bincount(top_e[bucket - prompt:].reshape(-1), length=n_e).astype(jnp.int32)
+                probe[prompt] = alone(xs, up, sizes)
+            log(f"moe prefill {name}: ragged_dot alone, {n_slots} x {hid} rows, group sizes summing to "
+                + ", ".join(f"{p * k}: {v:.3f} ms" for p, v in probe.items()))
+        log(f"moe prefill {name}: worst content row |masked - float32 jnp| / |float32 jnp| {worst:.4f} (limit "
+            f"{size.routed_tol}), |masked - parent's form| / |parent's form| {same:.4f} (every content row equal bit for "
+            f"bit: {equal}); padded rows zero: {zero}; blind to what the padded rows hold and route to: {blind}")
+        checks[f"moe_{name}_prefill_content_rows_match_jnp"] = worst <= size.routed_tol
+        checks[f"moe_{name}_prefill_content_rows_match_the_parents_form"] = same <= size.routed_tol
+        checks[f"moe_{name}_prefill_padded_rows_are_zero"] = zero
+        checks[f"moe_{name}_prefill_is_blind_to_its_padding"] = blind
+        # the chip's to say: a full bucket costs no more than the parent's form (2% for the clock),
+        # and the emptiest prompt costs less
+        checks[f"moe_{name}_prefill_full_bucket_costs_no_more"] = ms_of[bucket]["masked"] <= 1.02 * ms_of[bucket]["parent"]
+        checks[f"moe_{name}_prefill_emptiest_prompt_costs_less"] = (
+            ms_of[prompts[0]]["masked"] < ms_of[prompts[0]]["parent"])
+        del gate, up, down
     return checks
 
 

@@ -66,6 +66,17 @@ def pack_padded_prompt(tokens, padded_len: int, pad_side: str = "left"):
     return ids, mask
 
 
+def sown_sums(stats, names):
+    """An int32 vector: each of ``names`` summed over the layers that sowed it
+    into the ``stats`` collection (a model's ``chunk_stats`` over a decode
+    step, its ``prefill_stats`` over a prefill)."""
+    sown = jax.tree_util.tree_flatten_with_path(stats)[0]
+    return jnp.stack([
+        sum(leaf for path, leaf in sown
+            if any(getattr(k, "key", None) == name for k in path))
+        for name in names]).astype(jnp.int32)
+
+
 def serving_clones(model):
     """``(prefill, decode)`` mode clones sharing the caller's params — the
     pair every serving loop (batch `generate`, the continuous-batching
@@ -240,14 +251,6 @@ def chunked_decode_step(decode_model, chunk_size: int, max_seq_len: int,
                 mutable=["cache", "stats"] if stat_names else ["cache"],
             )
 
-        def step_stats(variables):
-            """The step's ``stat_names`` summed over the layers that sowed them."""
-            sown = jax.tree_util.tree_flatten_with_path(variables["stats"])[0]
-            return jnp.stack([
-                sum(leaf for path, leaf in sown
-                    if any(getattr(k, "key", None) == name for k in path))
-                for name in stat_names]).astype(jnp.int32)
-
         def live(carry):
             if stat_names:
                 *carry, stats = carry
@@ -278,7 +281,7 @@ def chunked_decode_step(decode_model, chunk_size: int, max_seq_len: int,
             carry = (variables["cache"], tok, keys, remaining,
                      done | finished, pools)
             if stat_names:
-                carry += (stats + step_stats(variables),)
+                carry += (stats + sown_sums(variables["stats"], stat_names),)
             return carry, (nxt, emit)
 
         def frozen(carry):

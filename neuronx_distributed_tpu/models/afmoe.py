@@ -68,7 +68,7 @@ from neuronx_distributed_tpu.modules.attention import (
     rope_frequencies,
     window_prefill_attention,
 )
-from neuronx_distributed_tpu.modules.moe import MoE
+from neuronx_distributed_tpu.modules.moe import MoE, moe_prefill_stats
 from neuronx_distributed_tpu.modules.qkv_linear import GQAQKVColumnParallelLinear
 from neuronx_distributed_tpu.modules.rms_norm import RMSNorm
 from neuronx_distributed_tpu.parallel import mesh as mesh_lib
@@ -298,7 +298,8 @@ class AfmoeDecoderLayer(nn.Module):
                 dtype=cfg.dtype,
                 param_dtype=cfg.param_dtype,
                 name="moe",
-            )(h, deterministic=self.deterministic)
+            )(h, deterministic=self.deterministic,
+              row_mask=padding_mask if self.mode == "prefill" else None)
             aux = jnp.stack([losses["load_balancing_loss"], losses["router_z_loss"]])
         return x + rms("post_mlp_norm", out), aux
 
@@ -346,7 +347,9 @@ class AfmoeForCausalLM(nn.Module):
     kernel.
 
     ``chunk_stats``: the counters a model with held experts sows into the
-    ``stats`` collection each decode step (``modules/moe.MoE``)."""
+    ``stats`` collection each decode step (``modules/moe.MoE``). ``prefill_stats``: those a
+    prefill's expert layers sow of the rows its ``padding_mask`` kept
+    (``modules/moe.moe_prefill_stats``)."""
 
     config: AfmoeConfig
     attention_impl: str = "auto"
@@ -355,6 +358,10 @@ class AfmoeForCausalLM(nn.Module):
     @property
     def chunk_stats(self) -> Tuple[str, ...]:
         return ("held_rows", "routed_rows") if self.config.held_experts is not None else ()
+
+    @property
+    def prefill_stats(self) -> Tuple[str, ...]:
+        return moe_prefill_stats(self.config)
 
     @nn.compact
     def __call__(self, input_ids, positions=None, deterministic: bool = True,

@@ -105,7 +105,8 @@ class DbrxBlock(nn.Module):
             param_dtype=cfg.param_dtype,
             quantization_config=cfg.quantization,
             name="moe",
-        )(h, deterministic=self.deterministic)
+        )(h, deterministic=self.deterministic,
+          row_mask=padding_mask if self.mode == "prefill" else None)
         x = x + moe_out
         return x, jnp.stack([aux["load_balancing_loss"], aux["router_z_loss"]])
 

@@ -67,7 +67,7 @@ from neuronx_distributed_tpu.modules.attention import (
     latent_decode_attention,
     prefill_positions,
 )
-from neuronx_distributed_tpu.modules.moe import MoE, moe_chunk_stats
+from neuronx_distributed_tpu.modules.moe import MoE, moe_chunk_stats, moe_prefill_stats
 from neuronx_distributed_tpu.modules.rms_norm import RMSNorm
 from neuronx_distributed_tpu.parallel import mesh as mesh_lib
 from neuronx_distributed_tpu.parallel.layers import (
@@ -389,7 +389,8 @@ class DeepseekV2DecoderLayer(nn.Module):
             dtype=cfg.dtype,
             param_dtype=cfg.param_dtype,
             name="moe",
-        )(h, deterministic=self.deterministic)
+        )(h, deterministic=self.deterministic,
+          row_mask=padding_mask if self.mode == "prefill" else None)
         return x + moe_out, jnp.stack(
             [aux["load_balancing_loss"], aux["router_z_loss"]])
 
@@ -437,6 +438,7 @@ class DeepseekV2ForCausalLM(nn.Module):
 
     # the expert layers' per-step counters, which a decode chunk sums
     chunk_stats = property(lambda self: moe_chunk_stats(self.config))
+    prefill_stats = property(lambda self: moe_prefill_stats(self.config))
 
     @nn.compact
     def __call__(self, input_ids, positions=None, deterministic: bool = True,

@@ -94,7 +94,7 @@ from neuronx_distributed_tpu.modules.attention import (
     rope_frequencies,
     sparse_prefill_attention,
 )
-from neuronx_distributed_tpu.modules.moe import MoE
+from neuronx_distributed_tpu.modules.moe import MoE, moe_prefill_stats
 from neuronx_distributed_tpu.modules.rms_norm import RMSNorm
 from neuronx_distributed_tpu.parallel import mesh as mesh_lib
 from neuronx_distributed_tpu.parallel.layers import (
@@ -331,7 +331,8 @@ class GlmMoeDsaDecoderLayer(nn.Module):
             dtype=cfg.dtype,
             param_dtype=cfg.param_dtype,
             name="moe",
-        )(h, deterministic=self.deterministic)
+        )(h, deterministic=self.deterministic,
+          row_mask=padding_mask if self.mode == "prefill" else None)
         return x + moe_out, jnp.stack(
             [aux["load_balancing_loss"], aux["router_z_loss"]])
 
@@ -380,7 +381,9 @@ class GlmMoeDsaForCausalLM(nn.Module):
     ``chunk_stats``: the counters a model with held experts sows into the
     ``stats`` collection each decode step (``modules/moe.MoE``), which
     ``inference/generate.chunked_decode_step`` sums over a chunk's steps and
-    layers and hands back with the chunk's tokens."""
+    layers and hands back with the chunk's tokens. ``prefill_stats``: those a
+    prefill's expert layers sow of the rows its ``padding_mask`` kept
+    (``modules/moe.moe_prefill_stats``)."""
 
     config: GlmMoeDsaConfig
     attention_impl: str = "auto"
@@ -389,6 +392,10 @@ class GlmMoeDsaForCausalLM(nn.Module):
     @property
     def chunk_stats(self) -> Tuple[str, ...]:
         return ("held_rows", "routed_rows") if self.config.held_experts is not None else ()
+
+    @property
+    def prefill_stats(self) -> Tuple[str, ...]:
+        return moe_prefill_stats(self.config)
 
     @nn.compact
     def __call__(self, input_ids, positions=None, deterministic: bool = True,

@@ -63,7 +63,7 @@ from neuronx_distributed_tpu.modules.attention import (
     prefill_positions,
     sparse_prefill_attention,
 )
-from neuronx_distributed_tpu.modules.moe import MoE, moe_chunk_stats
+from neuronx_distributed_tpu.modules.moe import MoE, moe_chunk_stats, moe_prefill_stats
 from neuronx_distributed_tpu.modules.qkv_linear import GQAQKVColumnParallelLinear
 from neuronx_distributed_tpu.modules.rms_norm import RMSNorm
 from neuronx_distributed_tpu.parallel import mesh as mesh_lib
@@ -273,7 +273,8 @@ class KeyeVL2DecoderLayer(nn.Module):
             dtype=cfg.dtype,
             param_dtype=cfg.param_dtype,
             name="moe",
-        )(h, deterministic=self.deterministic)
+        )(h, deterministic=self.deterministic,
+          row_mask=padding_mask if self.mode == "prefill" else None)
         return x + moe_out, jnp.stack(
             [aux["load_balancing_loss"], aux["router_z_loss"]])
 
@@ -325,6 +326,7 @@ class KeyeVL2ForCausalLM(nn.Module):
 
     # the expert layers' per-step counters, which a decode chunk sums
     chunk_stats = property(lambda self: moe_chunk_stats(self.config))
+    prefill_stats = property(lambda self: moe_prefill_stats(self.config))
 
     @nn.compact
     def __call__(self, input_ids, positions=None, deterministic: bool = True,

@@ -24,7 +24,7 @@ from neuronx_distributed_tpu.models.llama import (
     LlamaConfig,
     rope_frequencies,
 )
-from neuronx_distributed_tpu.modules.moe import MoE, moe_chunk_stats
+from neuronx_distributed_tpu.modules.moe import MoE, moe_chunk_stats, moe_prefill_stats
 from neuronx_distributed_tpu.modules.rms_norm import RMSNorm
 from neuronx_distributed_tpu.parallel.layers import (
     ColumnParallelLinear,
@@ -144,7 +144,8 @@ class MixtralDecoderLayer(nn.Module):
             param_dtype=cfg.param_dtype,
             quantization_config=cfg.quantization,
             name="moe",
-        )(h, deterministic=self.deterministic)
+        )(h, deterministic=self.deterministic,
+          row_mask=padding_mask if self.mode == "prefill" else None)
         x = x + moe_out
         aux_vec = jnp.stack(
             [aux["load_balancing_loss"], aux["router_z_loss"]]
@@ -239,6 +240,7 @@ class MixtralForCausalLM(nn.Module):
 
     # the expert layers' per-step counters, which a decode chunk sums
     chunk_stats = property(lambda self: moe_chunk_stats(self.config))
+    prefill_stats = property(lambda self: moe_prefill_stats(self.config))
 
     @nn.compact
     def __call__(

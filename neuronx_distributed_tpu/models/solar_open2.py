@@ -89,7 +89,7 @@ from neuronx_distributed_tpu.modules.attention import (
     fused_paged_frame_active,
     joined_decode_attention,
 )
-from neuronx_distributed_tpu.modules.moe import MoE
+from neuronx_distributed_tpu.modules.moe import MoE, moe_prefill_stats
 from neuronx_distributed_tpu.modules.rms_norm import RMSNorm
 from neuronx_distributed_tpu.parallel import mesh as mesh_lib
 from neuronx_distributed_tpu.parallel.layers import (
@@ -403,7 +403,8 @@ class SolarOpen2DecoderLayer(nn.Module):
             dtype=cfg.dtype,
             param_dtype=cfg.param_dtype,
             name="moe",
-        )(RMSNorm(cfg.hidden_size, name="pre_moe_norm", **norm)(x), deterministic=self.deterministic)
+        )(RMSNorm(cfg.hidden_size, name="pre_moe_norm", **norm)(x), deterministic=self.deterministic,
+          row_mask=padding_mask if self.mode == "prefill" else None)
         return x + out, jnp.stack([losses["load_balancing_loss"], losses["router_z_loss"]])
 
 
@@ -447,7 +448,9 @@ class SolarOpen2ForCausalLM(nn.Module):
     (logits (B, 1, V)): the contract every causal LM here keeps, stated in
     ``models/__init__.py``. ``chunk_stats``: the counters a model with held
     experts sows into the ``stats`` collection each decode step
-    (``modules/moe.MoE``)."""
+    (``modules/moe.MoE``). ``prefill_stats``: those a
+    prefill's expert layers sow of the rows its ``padding_mask`` kept
+    (``modules/moe.moe_prefill_stats``)."""
 
     config: SolarOpen2Config
     attention_impl: str = "auto"
@@ -456,6 +459,10 @@ class SolarOpen2ForCausalLM(nn.Module):
     @property
     def chunk_stats(self) -> Tuple[str, ...]:
         return ("held_rows", "routed_rows") if self.config.held_experts is not None else ()
+
+    @property
+    def prefill_stats(self) -> Tuple[str, ...]:
+        return moe_prefill_stats(self.config)
 
     def init(self, rngs, *args, **kwargs):
         """The weights are DRAWN in float32 and then rounded to

@@ -86,7 +86,7 @@ from neuronx_distributed_tpu.modules.attention import (
     rope_frequencies,
 )
 from neuronx_distributed_tpu.modules.moe import MoE
-from neuronx_distributed_tpu.modules.moe.model import moe_chunk_stats
+from neuronx_distributed_tpu.modules.moe.model import moe_chunk_stats, moe_prefill_stats
 from neuronx_distributed_tpu.modules.qkv_linear import GQAQKVColumnParallelLinear
 from neuronx_distributed_tpu.modules.rms_norm import RMSNorm
 from neuronx_distributed_tpu.parallel import mesh as mesh_lib
@@ -355,7 +355,8 @@ class ZayaDecoderLayer(nn.Module):
             param_dtype=cfg.param_dtype,
             name="moe",
         )(RMSNorm(cfg.hidden_size, name="pre_moe_norm", **norm)(x),
-          deterministic=self.deterministic, router_state=router_state)
+          deterministic=self.deterministic, router_state=router_state,
+          row_mask=padding_mask if self.mode == "prefill" else None)
         x = ResidualMerge(cfg, cfg.moe_branch_scale_init, name="moe_merge")(x, out)
         losses = jnp.stack([aux["load_balancing_loss"], aux["router_z_loss"]])
         return x, aux["router_state"], losses
@@ -402,7 +403,9 @@ class ZayaForCausalLM(nn.Module):
     applied to the LAST position alone (logits (B, 1, V)): the contract every
     causal LM here keeps, stated in ``models/__init__.py``. ``chunk_stats``: the
     counters a layer that holds every expert sows each decode step
-    (``modules/moe.MOE_CHUNK_STATS``)."""
+    (``modules/moe.MOE_CHUNK_STATS``). ``prefill_stats``: those a
+    prefill's expert layers sow of the rows its ``padding_mask`` kept
+    (``modules/moe.moe_prefill_stats``)."""
 
     config: ZayaConfig
     attention_impl: str = "auto"
@@ -411,6 +414,10 @@ class ZayaForCausalLM(nn.Module):
     @property
     def chunk_stats(self) -> Tuple[str, ...]:
         return moe_chunk_stats(self.config)
+
+    @property
+    def prefill_stats(self) -> Tuple[str, ...]:
+        return moe_prefill_stats(self.config)
 
     @nn.compact
     def __call__(self, input_ids, positions=None, deterministic: bool = True,

@@ -14,7 +14,13 @@ Layout mirrors the reference package:
 
 from neuronx_distributed_tpu.modules.moe.expert_mlps import ExpertMLPs
 from neuronx_distributed_tpu.modules.moe.loss_function import load_balancing_loss_func
-from neuronx_distributed_tpu.modules.moe.model import MOE_CHUNK_STATS, MoE, moe_chunk_stats
+from neuronx_distributed_tpu.modules.moe.model import (
+    MOE_CHUNK_STATS,
+    MOE_PREFILL_STATS,
+    MoE,
+    moe_chunk_stats,
+    moe_prefill_stats,
+)
 from neuronx_distributed_tpu.modules.moe.moe_parallel_layers import (
     ExpertFusedColumnParallelLinear,
     ExpertFusedRowParallelLinear,
@@ -28,6 +34,8 @@ from neuronx_distributed_tpu.modules.moe.token_shuffling import (
 __all__ = [
     "MOE_CHUNK_STATS",
     "moe_chunk_stats",
+    "MOE_PREFILL_STATS",
+    "moe_prefill_stats",
     "MoE",
     "ExpertMLPs",
     "RouterTopK",

@@ -37,6 +37,22 @@ Reference strategies → TPU-native formulations:
   those of experts ``[first, first + count)`` alone, and the result is the
   part of the routed sum those experts give: rows routed elsewhere add
   nothing (``_held``). Dropless and exact for any routing.
+
+A ROW MASK (``ExpertMLPs.__call__(..., row_mask)``; a prefill's
+``padding_mask``, handed down by ``MoE`` in ``prefill`` mode only): a row it
+leaves out gets ZERO from the routed experts, whatever the strategy, so a
+content row's sum is blind to what the bucket's padding routed to. The two
+mesh-free grouped-matmul forms also do no WORK for such a row. Its slots
+count as absent and sort last; the held experts' loop (``_held``) then takes
+the trips the prompt's held slots need, not the bucket's; and a layer that
+holds every expert runs ONE call (``_masked_ragged_routed_mlp``) whose
+``ragged_dot`` is told of the experts' groups alone (XLA's does not visit the
+rows past their sum) and whose combine gathers each content token's ``k``
+rows where the scatter-add would walk the padded slots too. Every other form
+(``selective``, ``all_experts``, ``capacity_factor``, the streamed kernel, the
+``shard_map`` forms) zeroes the row's affinities and is otherwise what it
+was. With no mask every path is what it was to the letter (a train step's and
+a decode step's lowered text: ``tests/modules/test_moe.py``).
 """
 
 from __future__ import annotations
@@ -70,6 +86,11 @@ MOE_STREAM_MAX_TOKENS = 256
 # the prompt
 HELD_BLOCK_ROWS = 2048
 
+# the counters a dispatch that is given a row mask sows (``ExpertMLPs.__call__``):
+# the rows it kept and the rows it was given
+MOE_PREFILL_STATS = ("moe_live_rows", "moe_rows")
+LATEST = dict(init_fn=lambda: jnp.zeros((), jnp.int32), reduce_fn=lambda _, new: new)
+
 
 def _act(name: str):
     return {"silu": jax.nn.silu, "gelu": jax.nn.gelu, "relu": jax.nn.relu}[name]
@@ -96,6 +117,12 @@ def blockwise_form(n_tokens: int, *, sharded: bool, quantized: bool) -> str:
     return "stream" if backend.on_tpu() else "ragged_dot"
 
 
+def _masked_affinities(top_w, row_mask):
+    """``top_w`` with the rows ``row_mask`` leaves out at zero: how a form
+    that cannot skip a row's work gives it nothing."""
+    return top_w if row_mask is None else jnp.where(row_mask[:, None], top_w, 0)
+
+
 def _sorted_slots(top_e, top_w, num_experts: int, dtype):
     """The dropless dispatch: ``(token_idx, group_sizes, ws)`` of the
     ``T k`` slots sorted by expert."""
@@ -107,10 +134,14 @@ def _sorted_slots(top_e, top_w, num_experts: int, dtype):
     return token_idx, group_sizes, ws
 
 
-def _ragged_routed_mlp(x, top_e, top_w, gate, up, down, act: str):
+def _ragged_routed_mlp(x, top_e, top_w, gate, up, down, act: str, row_mask=None):
     """The routed sum through the grouped matmul, mesh-free: sort the slots,
     gather their rows, three ``ragged_dot`` calls (two where ``gate`` is
-    None: no GLU), scatter-add."""
+    None: no GLU), scatter-add. With a ``row_mask`` (T,): the padded rows'
+    slots sorted last, and none of that work done for them
+    (``_masked_ragged_routed_mlp``)."""
+    if row_mask is not None:
+        return _masked_ragged_routed_mlp(x, top_e, top_w, row_mask, gate, up, down, act)
     with jax.named_scope("moe.dispatch"):
         token_idx, group_sizes, ws = _sorted_slots(
             top_e, top_w, up.shape[0], x.dtype)
@@ -120,6 +151,42 @@ def _ragged_routed_mlp(x, top_e, top_w, gate, up, down, act: str):
                           glu=gate is not None, act=act)
     with jax.named_scope("moe.combine"):
         return jnp.zeros(x.shape, ys.dtype).at[token_idx].add(ys * ws[:, None])
+
+
+def _masked_ragged_routed_mlp(x, top_e, top_w, row_mask, gate, up, down, act: str):
+    """``_ragged_routed_mlp`` for a bucket whose rows outside ``row_mask``
+    (T,) are padding: they get zero, and a content row the sum it always got,
+    bf16 add for bf16 add. The padded rows' slots get a group of their own
+    past the last expert and sort LAST; ``group_sizes`` names the experts'
+    groups alone, and ``ragged_dot`` does not visit the rows past their sum
+    (its time is that of a call on the content slots: ``chip_smoke.py --only
+    moe``, PERF.md section 6, PR 55). The combine reads content rows only: a
+    token's ``k`` weighted rows are GATHERED from their sorted positions and
+    added one by one in the order the scatter-add met them (by expert), where
+    a scatter-add over the ``T k`` slots would sort, gather and walk the
+    padded slots too (11.5 ms of a 30.7 ms layer at Keye's shape)."""
+    E = up.shape[0]
+    T, k = top_e.shape
+    with jax.named_scope("moe.dispatch"):
+        key = jnp.where(row_mask[:, None], top_e, E).reshape(-1)
+        order = jnp.argsort(key, stable=True)      # content slots first, by expert
+        group_sizes = jnp.bincount(key, length=E + 1)[:E].astype(jnp.int32)
+        ws = top_w.reshape(-1)[order].astype(x.dtype)
+        xs = x[order // k]
+    with jax.named_scope("moe.experts"):
+        ys = _grouped_mlp(xs, gate, up, down, group_sizes,
+                          glu=gate is not None, act=act)
+    with jax.named_scope("moe.combine"):
+        # what the rows past the last group hold is ragged_dot's to say
+        live = (jnp.arange(T * k) < jnp.sum(group_sizes))[:, None]
+        weighted = jnp.where(live, ys * ws[:, None], 0)
+        # each token's sorted positions, ascending: its experts in their order
+        # (a padded token's all lie past the last group: it sums zeros)
+        at = jnp.sort(jnp.argsort(order).reshape(T, k), axis=1)
+        out = jnp.zeros(x.shape, ys.dtype)
+        for j in range(k):
+            out = out + weighted[at[:, j]]
+        return out
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
@@ -350,15 +417,25 @@ class ExpertMLPs(nn.Module):
         return "blockwise"
 
     @nn.compact
-    def __call__(self, x: jax.Array, top_e: jax.Array, top_w: jax.Array) -> jax.Array:
+    def __call__(self, x: jax.Array, top_e: jax.Array, top_w: jax.Array,
+                 row_mask: Optional[jax.Array] = None) -> jax.Array:
         """``x (T, H)`` tokens, ``top_e (T, k)`` expert ids, ``top_w (T, k)``
-        affinities → ``(T, H)`` combined expert outputs."""
+        affinities → ``(T, H)`` combined expert outputs. ``row_mask`` (T,)
+        bool: a row it leaves out (a prefill bucket's padding) gets ZERO,
+        whatever it routed to; the held experts' loop and the mesh-free
+        grouped matmul do no work for it, every other form zeroes its
+        affinities. ``None``: every row counts."""
         gate, up, down = self._params()
+        if row_mask is not None and not self.is_initializing():
+            # for whoever collects them (``MOE_PREFILL_STATS``): the rows this
+            # dispatch kept, of the rows it was given
+            self.sow("stats", "moe_live_rows", jnp.sum(row_mask, dtype=jnp.int32), **LATEST)
+            self.sow("stats", "moe_rows", jnp.asarray(row_mask.size, jnp.int32), **LATEST)
         if self.held_experts is not None:
             x = x.astype(self.dtype)
             return self._held(
                 x, top_e, top_w, None if gate is None else gate.astype(self.dtype),
-                up.astype(self.dtype), down.astype(self.dtype))
+                up.astype(self.dtype), down.astype(self.dtype), row_mask)
         strategy = self._resolve_strategy(n_tokens=x.shape[0])
         if self.strategy == "auto" and not self.is_initializing():
             from neuronx_distributed_tpu.utils.logger import get_logger
@@ -374,6 +451,9 @@ class ExpertMLPs(nn.Module):
         x = x.astype(self.dtype)
         gate = None if gate is None else gate.astype(self.dtype)
         up, down = up.astype(self.dtype), down.astype(self.dtype)
+        if strategy == "blockwise":
+            return self._blockwise(x, top_e, top_w, gate, up, down, row_mask)
+        top_w = _masked_affinities(top_w, row_mask)
         # the dense strategies fold dispatch and combine into their einsums:
         # one scope; the sparse ones below name their three phases
         if strategy == "all_experts":
@@ -382,8 +462,6 @@ class ExpertMLPs(nn.Module):
         if strategy == "capacity_factor":
             with jax.named_scope("moe.experts"):
                 return self._capacity_factor(x, top_e, top_w, gate, up, down)
-        if strategy == "blockwise":
-            return self._blockwise(x, top_e, top_w, gate, up, down)
         if strategy == "selective":
             return self._selective(x, top_e, top_w, gate, up, down)
         raise ValueError(f"unknown expert strategy {strategy!r}")
@@ -397,7 +475,7 @@ class ExpertMLPs(nn.Module):
         local = top_e - first
         return local, (local >= 0) & (local < count)
 
-    def _held(self, x, top_e, top_w, gate, up, down):
+    def _held(self, x, top_e, top_w, gate, up, down, row_mask=None):
         """The held experts' part of ``sum_i w_i expert_i(x)``: ``x`` (T, H),
         ``top_e``/``top_w`` (T, k) over ALL ``num_experts``. Dropless and
         exact for any routing (every slot routed here is computed, however
@@ -406,10 +484,13 @@ class ExpertMLPs(nn.Module):
         ``HELD_BLOCK_ROWS`` sorted rows a trip through the grouped matmul
         (``ragged_dot``, which reads the weights of the experts that have
         rows) for as many trips as HELD rows need: none where nobody chose a
-        held expert. The gathered tokens are ``HELD_BLOCK_ROWS x H``, never
-        ``T x k x H`` (1.6 GB at 16,384 tokens of 6144 top-8); with ``count /
-        num_experts`` of the slots held a prompt takes ``T k count /
-        (num_experts HELD_BLOCK_ROWS)`` trips, rounded up.
+        held expert. A slot of a row that ``row_mask`` (T,) leaves out (a
+        prefill bucket's padding) counts as absent too, so the trips follow
+        the prompt and not its bucket. The gathered tokens are
+        ``HELD_BLOCK_ROWS x H``, never ``T x k x H`` (1.6 GB at 16,384 tokens
+        of 6144 top-8); with ``count / num_experts`` of the slots held a
+        prompt takes ``P k count / (num_experts HELD_BLOCK_ROWS)`` trips,
+        rounded up.
         """
         count = self.held_experts[1]
         if mesh_lib.model_parallel_is_initialized() and (
@@ -423,6 +504,8 @@ class ExpertMLPs(nn.Module):
         T, H = x.shape
         k = self.top_k
         local, held = self.held_slots(top_e)
+        if row_mask is not None:
+            held = held & row_mask[:, None]
         N = T * k
         block = min(HELD_BLOCK_ROWS, N)
         with jax.named_scope("moe.dispatch"):
@@ -545,7 +628,7 @@ class ExpertMLPs(nn.Module):
 
     # --- strategy: blockwise dropless (reference expert_mlps.py:346) ----------
 
-    def _blockwise(self, x, top_e, top_w, gate, up, down):
+    def _blockwise(self, x, top_e, top_w, gate, up, down, row_mask=None):
         T, H = x.shape
         k, E = self.top_k, self.num_experts
 
@@ -554,6 +637,8 @@ class ExpertMLPs(nn.Module):
         ep = mesh_lib.get_expert_model_parallel_size() if initialized else 1
 
         if tp > 1 or ep > 1:
+            # the shard_map forms keep their rows and zero what the mask leaves out
+            top_w = _masked_affinities(top_w, row_mask)
             if E % max(ep, 1) != 0:
                 raise ValueError(f"num_experts {E} not divisible by ep {ep}")
             mesh = mesh_lib.get_mesh()
@@ -623,8 +708,10 @@ class ExpertMLPs(nn.Module):
         form = blockwise_form(
             T, sharded=initialized and mesh_lib.get_mesh().size > 1,
             quantized=self.quantization_config is not None)
-        routed = _streamed_routed_mlp if form == "stream" else _ragged_routed_mlp
-        return routed(x, top_e, top_w, gate, up, down, self.hidden_act)
+        if form == "stream":
+            return _streamed_routed_mlp(
+                x, top_e, _masked_affinities(top_w, row_mask), gate, up, down, self.hidden_act)
+        return _ragged_routed_mlp(x, top_e, top_w, gate, up, down, self.hidden_act, row_mask)
 
 
 def decode_form(config, n_tokens: int, *, sharded: bool) -> str:

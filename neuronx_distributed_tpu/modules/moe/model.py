@@ -24,7 +24,11 @@ from jax.sharding import PartitionSpec as P
 
 from neuronx_distributed_tpu.kernels.moe_stream import hit_mask
 from neuronx_distributed_tpu.modules.attention import ParallelMLP
-from neuronx_distributed_tpu.modules.moe.expert_mlps import ExpertMLPs
+from neuronx_distributed_tpu.modules.moe.expert_mlps import (
+    LATEST,
+    MOE_PREFILL_STATS,
+    ExpertMLPs,
+)
 from neuronx_distributed_tpu.modules.moe.loss_function import (
     load_balancing_loss_func,
     router_z_loss_func,
@@ -49,6 +53,13 @@ def moe_chunk_stats(config) -> Tuple[str, ...]:
     ``inference/generate.chunked_decode_step`` sums over a chunk's steps and
     layers; scanned layers carry no ``stats`` collection."""
     return () if config.scan_layers else MOE_CHUNK_STATS
+
+
+def moe_prefill_stats(config) -> Tuple[str, ...]:
+    """What a model of such layers names as its ``prefill_stats``: the counters
+    a layer that is given a ``row_mask`` sows, which the engine's prefill
+    program sums over the layers (``serving/engine.py`` ``_prefill_fn``)."""
+    return () if config.scan_layers else MOE_PREFILL_STATS
 
 
 class MoE(nn.Module):
@@ -106,11 +117,16 @@ class MoE(nn.Module):
     def __call__(
         self, x: jax.Array, deterministic: bool = True,
         router_state: Optional[jax.Array] = None,
+        row_mask: Optional[jax.Array] = None,
     ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
         """``router_state`` (B, S, ``router_state_size``): the previous
         layer's router state for a router that keeps one (``router_kind=
         "mlp"``; ``None``: the first such layer); this layer's comes back as
-        ``aux["router_state"]``."""
+        ``aux["router_state"]``. ``row_mask`` (B, S) bool: the rows that hold
+        content (a prefill's ``padding_mask``); the router and the shared
+        expert see every row, the ROUTED experts give the others zero and,
+        where the form can, do no work for them (``ExpertMLPs.__call__``).
+        ``None``: every row counts."""
         B, S, H = x.shape
         if self.sequence_parallel_enabled:
             # exit SP: routing needs the full sequence per data shard
@@ -150,6 +166,10 @@ class MoE(nn.Module):
         # named scopes (with the modules' own: this block is ``moe``) so a
         # device trace can be cut into router / dispatch / experts / combine
         stateful = self.router_kind == "mlp"
+        if row_mask is not None:
+            row_mask = row_mask.reshape(B * S).astype(bool)
+            if perm is not None:
+                row_mask = row_mask[perm]
         if router_state is not None and (not stateful or perm is not None):
             raise ValueError(
                 "router_state is the mlp router's, on tokens in their order: "
@@ -181,19 +201,18 @@ class MoE(nn.Module):
             held_experts=self.held_experts,
             name="experts",
         )
-        out = experts(tokens, route.top_e, top_w)
-        latest = dict(init_fn=lambda: jnp.zeros((), jnp.int32),
-                      reduce_fn=lambda _, new: new)
+        out = experts(tokens, route.top_e, top_w, row_mask)
         if self.held_experts is not None and not self.is_initializing():
             held = experts.held_slots(route.top_e)[1]
-            self.sow("stats", "held_rows", jnp.sum(held, dtype=jnp.int32), **latest)
-            self.sow("stats", "routed_rows", jnp.asarray(held.size, jnp.int32), **latest)
+            self.sow("stats", "held_rows", jnp.sum(held, dtype=jnp.int32), **LATEST)
+            self.sow("stats", "routed_rows", jnp.asarray(held.size, jnp.int32), **LATEST)
         elif self.is_mutable_collection("stats") and not self.is_initializing():
             # only for whoever collects them: a program that does not (a
-            # prefill, a train step) is what it was
+            # train step) is what it was, and a prefill returns
+            # ``MOE_PREFILL_STATS`` alone, so these two are dead code there
             hit = hit_mask(route.top_e, self.num_experts)
-            self.sow("stats", "hit_experts", jnp.sum(hit, dtype=jnp.int32), **latest)
-            self.sow("stats", "routed_rows", jnp.asarray(route.top_e.size, jnp.int32), **latest)
+            self.sow("stats", "hit_experts", jnp.sum(hit, dtype=jnp.int32), **LATEST)
+            self.sow("stats", "routed_rows", jnp.asarray(route.top_e.size, jnp.int32), **LATEST)
 
         if self.shared_intermediate_size is not None:
             with jax.named_scope("moe.shared"):

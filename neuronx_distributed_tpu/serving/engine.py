@@ -237,6 +237,7 @@ from neuronx_distributed_tpu.inference.generate import (
     chunked_decode_step,
     pack_padded_prompt,
     serving_clones,
+    sown_sums,
     suffix_prefill_step,
     validate_generate_args,
 )
@@ -2824,17 +2825,25 @@ class ServingEngine:
         if fn is None:
             prefill = self._prefill_model
             head_rows = self._prefill_head_rows
+            # past the two: the model's own per-prefill counters, summed over
+            # its layers (``prefill_stats``; most models: none)
+            stat_names = tuple(getattr(prefill, "prefill_stats", ()))
 
             @jax.jit
             def fn(params, ids, mask):
                 out, variables = prefill.apply(
-                    params, ids, padding_mask=mask, mutable=["cache"]
+                    params, ids, padding_mask=mask,
+                    mutable=["cache", "stats"] if stat_names else ["cache"],
                 )
                 logits = unwrap_logits(out)
                 # runs when the bucket's program is traced, not when it
                 # runs: the shape is the program's own, and costs nothing
                 head_rows[ids.shape[1]] = logits.shape[1]
-                return logits[0, -1], variables["cache"]
+                stats = (
+                    (sown_sums(variables["stats"], stat_names),)
+                    if stat_names else ()
+                )
+                return (logits[0, -1], variables["cache"]) + stats
 
             fn = self.programs.wrap(
                 f"prefill[{padded_len}]", self._comms_scoped(fn)
@@ -2898,6 +2907,7 @@ class ServingEngine:
             padded, p if plan is None else 0, getattr(self.model, "config", None)))
         call = self._prefill_calls
         self._prefill_calls += 1
+        model_stats = ()
         t0 = self._clock()
         try:
             try:
@@ -2934,7 +2944,7 @@ class ServingEngine:
                     )
                 else:
                     ids, mask = pack_padded_prompt(ctx, padded)
-                    logits, row_cache = self._prefill_fn(padded)(
+                    logits, row_cache, *model_stats = self._prefill_fn(padded)(
                         self._params, jnp.asarray(ids), jnp.asarray(mask)
                     )
                     # a program restored without a trace reports none
@@ -3051,17 +3061,20 @@ class ServingEngine:
                     m_shared // self._page_size
                 )
             self._remember_prefix_paged(ctx, p, slot, matched=m_shared)
-        self._bind_slot(req, slot, logits, now, sp)
+        self._bind_slot(req, slot, logits, now, sp, model_stats)
 
     def _bind_slot(self, req: Request, slot: int, logits, now: float,
-                   sp=None) -> None:
+                   sp=None, model_stats=()) -> None:
         """The admission tail shared by coupled prefill and the
         disaggregated page-table handoff: record the admit (``now``: when
         this request's prefill, or its handoff, started), sample the first
         token off ``logits`` (fresh requests only — one explicit device_get
         of the token+key pair), and activate the slot's device-resident
         state. The first token is stamped AFTER that readback, which is
-        where the host waits for the prefill: TTFT includes the prefill."""
+        where the host waits for the prefill: TTFT includes the prefill.
+        ``model_stats``: the prefill program's own counters (its model's
+        ``prefill_stats``; most models: none), which ride that readback and
+        become its span's stats."""
         self.metrics.record_admit(req, now)
         if req.admit_time is None:
             req.admit_time = now
@@ -3074,12 +3087,18 @@ class ServingEngine:
             # tests/serving/test_host_sync.py pins the count at 1)
             carry, sub = jax.random.split(jnp.asarray(req.key))
             temp, topk, topp = _config_sentinels(req.config)
-            with self._span(tracing.STEP_FIRST_TOKEN, rid=req.rid):
+            with self._span(tracing.STEP_FIRST_TOKEN, rid=req.rid) as ft:
                 # graftlint: ok[GL02] the admission path's single documented
                 # sync: first token + advanced request key in one readback
-                tok0_h, carry_h = jax.device_get(
-                    (self._first_token(logits, sub, temp, topk, topp), carry)
+                tok0_h, carry_h, *model_stats = jax.device_get(
+                    (self._first_token(logits, sub, temp, topk, topp), carry,
+                     *model_stats)
                 )
+                if model_stats:
+                    ft.set_metadata(**{
+                        name: int(value) for name, value in zip(
+                            self._prefill_model.prefill_stats, model_stats[0])
+                    })
             tok0 = int(tok0_h)
             req.key = np.asarray(carry_h, np.uint32)
             self.tracer.step(req.rid, "first_token")

@@ -730,3 +730,212 @@ def test_moe_passes_the_router_state_through_and_every_other_caller_is_what_it_w
     assert named == unnamed and "router_state" not in plain.apply(params, x)[1]
     with pytest.raises(ValueError, match="router_state is the mlp router's"):
         plain.apply(params, x, router_state=state)
+
+
+# --- a prefill's padded rows get no expert (PR 55) ---------------------------------
+
+# form -> (ExpertMLPs options, (tp, ep) of the CPU mesh or None)
+ROW_MASK_FORMS = {
+    "blockwise": (dict(num_experts=8, strategy="blockwise"), None),
+    "held": (dict(num_experts=16, held_experts=(4, 8)), None),
+    "selective": (dict(num_experts=8, strategy="selective"), None),
+    "all_experts": (dict(num_experts=8, strategy="all_experts"), None),
+    # room for every slot: nothing is dropped, so a row's sum is its k experts'
+    "capacity_factor": (dict(num_experts=8, strategy="capacity_factor", capacity_factor=8.0), None),
+    "blockwise_tp2": (dict(num_experts=8, strategy="blockwise"), (2, 1)),
+    "blockwise_ep2": (dict(num_experts=8, strategy="blockwise"), (1, 2)),
+}
+
+
+def _padded_bucket(options, rows=40, padding=13, k=4, seed=0):
+    """A left-padded bucket: ``(layer, x, top_e, top_w, mask)``; the padded rows
+    hold LARGE values and route like content."""
+    layer = ExpertMLPs(hidden_size=H, intermediate_size=I, top_k=k, dtype=jnp.float32, **options)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    mask = jnp.arange(rows) >= padding
+    x = jax.random.normal(keys[0], (rows, H), jnp.float32) * jnp.where(mask, 1.0, 50.0)[:, None]
+    top_w, top_e = jax.lax.top_k(jax.nn.softmax(jax.random.normal(keys[1], (rows, options["num_experts"]))), k)
+    return layer, x, top_e.astype(jnp.int32), top_w, mask
+
+
+@pytest.mark.parametrize("form", list(ROW_MASK_FORMS))
+def test_masked_rows_get_nothing_and_content_rows_are_blind_to_them(form, monkeypatch):
+    """The contract of ``row_mask``, every form: a row it leaves out comes back
+    ZERO, and a content row is what the same call on the content rows ALONE
+    gives (a bucket of their own with nothing masked; float32: an equality,
+    to the last bit where the rows go through the same matmuls and adds one
+    by one; the dense forms contract over a different number of zeros), and
+    what the unmasked form gives it to a float32 add's rounding."""
+    from neuronx_distributed_tpu.modules.moe import expert_mlps
+
+    options, mesh = ROW_MASK_FORMS[form]
+    monkeypatch.setattr(expert_mlps, "HELD_BLOCK_ROWS", 16)
+    layer, x, top_e, top_w, mask = _padded_bucket(options)
+    params = layer.init(jax.random.PRNGKey(3), x, top_e, top_w)
+    if mesh is not None:
+        mesh_lib.initialize_model_parallel(tensor_model_parallel_size=mesh[0], expert_model_parallel_size=mesh[1])
+    got = np.asarray(jax.jit(lambda p, *a: layer.apply(p, *a))(params, x, top_e, top_w, mask))
+    keep = np.asarray(mask)
+    assert not got[~keep].any() and got[keep].any()
+    alone = np.asarray(jax.jit(lambda p, *a: layer.apply(p, *a))(
+        params, x[keep], top_e[keep], top_w[keep], jnp.ones((int(keep.sum()),), bool)))
+    if form in ("blockwise", "held", "selective"):
+        np.testing.assert_array_equal(got[keep], alone)
+    else:
+        np.testing.assert_allclose(got[keep], alone, rtol=1e-5, atol=1e-6)
+    # and with no mask the padded rows are routed as they always were
+    unmasked = np.asarray(jax.jit(lambda p, *a: layer.apply(p, *a))(params, x, top_e, top_w))
+    assert unmasked[~keep].any()
+    np.testing.assert_allclose(unmasked[keep], got[keep], rtol=1e-5, atol=1e-6)
+
+
+def test_the_held_loops_trips_follow_the_live_rows(monkeypatch):
+    """A bucket half padding takes half the trips of a full one: 64 slots, all
+    routed to held experts, in trips of 16."""
+    from neuronx_distributed_tpu.modules.moe import expert_mlps
+
+    monkeypatch.setattr(expert_mlps, "HELD_BLOCK_ROWS", 16)
+    trips, real = [], expert_mlps._grouped_mlp
+
+    def counted(*a, **kw):
+        jax.debug.callback(lambda: trips.append(1))
+        return real(*a, **kw)
+
+    monkeypatch.setattr(expert_mlps, "_grouped_mlp", counted)
+    layer, x, top_e, top_w, _ = _padded_bucket(dict(num_experts=8, held_experts=(0, 8)), rows=32, k=2)
+    params = layer.init(jax.random.PRNGKey(3), x, top_e, top_w)
+    taken = {}
+    for live in (32, 16, 5, 0):
+        del trips[:]
+        jax.block_until_ready(layer.apply(params, x, top_e, top_w, jnp.arange(32) >= 32 - live))
+        jax.effects_barrier()
+        taken[live] = len(trips)
+    assert taken == {32: 4, 16: 2, 5: 1, 0: 0}
+
+
+def test_the_masked_grouped_matmul_is_told_of_the_content_slots_alone(monkeypatch):
+    """The mesh-free blockwise form: one call, the padded rows' slots past the
+    last group: ``group_sizes`` sums to the content slots."""
+    from neuronx_distributed_tpu.modules.moe import expert_mlps
+
+    seen, real = [], expert_mlps._grouped_mlp
+
+    def watched(xs, gate, up, down, sizes, **kw):
+        jax.debug.callback(lambda s: seen.append(int(s.sum())), sizes)
+        return real(xs, gate, up, down, sizes, **kw)
+
+    monkeypatch.setattr(expert_mlps, "_grouped_mlp", watched)
+    layer, x, top_e, top_w, mask = _padded_bucket(dict(num_experts=8, strategy="blockwise"), rows=40, padding=13, k=4)
+    params = layer.init(jax.random.PRNGKey(3), x, top_e, top_w)
+    del seen[:]
+    fn = jax.jit(lambda p, *a: layer.apply(p, *a))
+    jax.block_until_ready(fn(params, x, top_e, top_w, mask))
+    jax.effects_barrier()
+    assert seen == [27 * 4]
+    assert "while" not in fn.lower(params, x, top_e, top_w, mask).as_text()     # one call, no loop
+
+
+def _parent_ragged_routed_mlp(x, top_e, top_w, gate, up, down, act, row_mask=None):
+    """``expert_mlps._ragged_routed_mlp`` as PR 54 left it."""
+    from neuronx_distributed_tpu.modules.moe.expert_mlps import _grouped_mlp, _sorted_slots
+
+    with jax.named_scope("moe.dispatch"):
+        token_idx, group_sizes, ws = _sorted_slots(top_e, top_w, up.shape[0], x.dtype)
+        xs = x[token_idx]
+    with jax.named_scope("moe.experts"):
+        ys = _grouped_mlp(xs, gate, up, down, group_sizes, glu=gate is not None, act=act)
+    with jax.named_scope("moe.combine"):
+        return jnp.zeros(x.shape, ys.dtype).at[token_idx].add(ys * ws[:, None])
+
+
+def _parent_held(self, x, top_e, top_w, gate, up, down, row_mask=None):
+    """``ExpertMLPs._held`` as PR 54 left it (its loop written out)."""
+    from neuronx_distributed_tpu.modules.moe.expert_mlps import HELD_BLOCK_ROWS, _grouped_mlp
+
+    assert row_mask is None
+    count = self.held_experts[1]
+    T, H_ = x.shape
+    k = self.top_k
+    local, held = self.held_slots(top_e)
+    N = T * k
+    block = min(HELD_BLOCK_ROWS, N)
+    with jax.named_scope("moe.dispatch"):
+        key = jnp.where(held, local, count).reshape(-1)
+        order = jnp.argsort(key, stable=True)
+        token_idx = order // k
+        sizes = jnp.bincount(key, length=count + 1)[:count].astype(jnp.int32)
+        ends = jnp.cumsum(sizes)
+        n_held = ends[-1]
+        ws = top_w.reshape(-1)[order].astype(x.dtype)
+        pad = -N % block
+        token_idx = jnp.pad(token_idx, (0, pad))
+        ws = jnp.pad(ws, (0, pad))
+
+    def trip(state):
+        i, out = state
+        lo = i * block
+        with jax.named_scope("moe.dispatch"):
+            idx = jax.lax.dynamic_slice_in_dim(token_idx, lo, block)
+            w = jax.lax.dynamic_slice_in_dim(ws, lo, block)
+            part = jnp.clip(ends, lo, lo + block) - jnp.clip(ends - sizes, lo, lo + block)
+            rows = x[idx]
+        with jax.named_scope("moe.experts"):
+            ys = _grouped_mlp(rows, gate if gate is not None else up, up, down,
+                              part.astype(jnp.int32), glu=self.glu_mlp, act=self.hidden_act)
+        with jax.named_scope("moe.combine"):
+            live = (lo + jnp.arange(block) < n_held)[:, None]
+            out = out.at[idx].add(jnp.where(live, ys * w[:, None], 0))
+        return i + 1, out
+
+    _, out = jax.lax.while_loop(
+        lambda state: state[0] * block < n_held, trip,
+        (jnp.zeros((), jnp.int32), jnp.zeros((T, H_), x.dtype)))
+    return out
+
+
+@pytest.mark.parametrize("step", ["train_blockwise", "decode_blockwise", "decode_held", "decode_selective"])
+def test_without_a_mask_a_step_lowers_to_the_parents_text(step, monkeypatch):
+    """``row_mask=None`` (a train step, a decode step) is the parent's program
+    text for text: the layer through this tree's forms and through PR 54's,
+    kept above."""
+    from neuronx_distributed_tpu.modules.moe import expert_mlps
+
+    held = (4, 8) if step == "decode_held" else None
+    layer = MoE(num_experts=16, hidden_size=H, intermediate_size=I, top_k=4, shared_intermediate_size=I,
+                expert_strategy="selective" if step == "decode_selective" else "blockwise",
+                held_experts=held, dtype=jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 24, H) if step.startswith("train") else (6, 1, H), jnp.float32)
+    params = layer.init(jax.random.PRNGKey(1), x)
+
+    def text():
+        if step.startswith("train"):
+            fn = jax.grad(lambda p, x_: jnp.sum(layer.apply(p, x_, deterministic=False)[0]))
+        else:
+            fn = lambda p, x_: layer.apply(p, x_, mutable=["stats"])  # noqa: E731
+        return jax.jit(fn).lower(params, x).as_text()
+
+    mine = text()
+    monkeypatch.setattr(expert_mlps, "_ragged_routed_mlp", _parent_ragged_routed_mlp)
+    monkeypatch.setattr(expert_mlps.ExpertMLPs, "_held", _parent_held)
+    assert mine == text()
+    assert ("while" in mine) == (step == "decode_held")
+
+
+def test_moe_hands_the_row_mask_to_the_routed_experts_alone_and_sows_what_it_kept():
+    """The router and the shared expert see every row; the routed sum of a
+    masked row is zero; ``moe_live_rows`` / ``moe_rows`` are sown for whoever
+    collects ``stats``, and only where a mask is given."""
+    layer = MoE(num_experts=8, hidden_size=H, intermediate_size=I, top_k=2, shared_intermediate_size=I,
+                expert_strategy="blockwise", dtype=jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(0), (1, 24, H), jnp.float32)
+    mask = (jnp.arange(24) >= 9)[None]
+    params = layer.init(jax.random.PRNGKey(1), x)
+    (out, _), sown = layer.apply(params, x, row_mask=mask, mutable=["stats"])
+    (plain, _), unmasked = layer.apply(params, x, mutable=["stats"])
+    shared = _shared_only(layer, params, x)
+    np.testing.assert_array_equal(np.asarray(out[0, :9]), np.asarray(shared[0, :9]))
+    np.testing.assert_allclose(np.asarray(out[0, 9:]), np.asarray(plain[0, 9:]), rtol=1e-5, atol=1e-6)
+    assert np.abs(np.asarray(plain[0, :9] - shared[0, :9])).max() > 1e-3
+    stats = sown["stats"]["experts"]
+    assert int(stats["moe_live_rows"]) == 15 and int(stats["moe_rows"]) == 24
+    assert "experts" not in unmasked["stats"]

@@ -90,9 +90,13 @@ def test_page_fingerprints_see_both_leaves_and_a_freed_slot_attends_nothing(setu
 # two ``decode`` digests anew, here and in GLM-5's below; both ``prefill``
 # digests stand. PR 52 took the two ``decode.fused`` digests anew: the
 # index-score kernel in them fetches a run of adjacent pages with one copy
-# (``gather`` and the prefills hold no such kernel and stand).
+# (``gather`` and the prefills hold no such kernel and stand). PR 55 took both
+# ``prefill`` digests anew, here and in GLM-5's below: a prefill hands its
+# ``padding_mask`` to its expert layers as their row mask (the padded rows'
+# slots count as absent); all four ``decode`` digests stand (a decode step
+# passes no mask).
 KEYE_PARENT_PROGRAMS = {
-    "keye.prefill": "b6cab9cad2a08e38110d3c0b904d73989f7f803088075fe75bfdb6c9d3465882",
+    "keye.prefill": "a632140741590a63e5ecb3ca0536e769735af79774348ecb674d35fdefa91ee8",
     "keye.decode.gather": "745243e191fa1af397416893de4bfb8335a4040b9dd4c285bd488fb3be53102b",
     "keye.decode.fused": "8e8214201d09ea8949783edf8ea7baa35303e75ea284bf806e4b8b7406dbc9f1",
 }
@@ -117,7 +121,7 @@ def test_keye_programs_are_the_parents(keye_program_texts, program):
 # experts it holds sows and multiplies what it did. (The two ``decode`` digests:
 # anew with PR 36's sampler branch, see Keye's above.)
 GLM_PARENT_PROGRAMS = {
-    "glm.prefill": "212888961eaff3339697e55a3b1de353d6252f85a2c75980d5c7e71537802798",
+    "glm.prefill": "fb584abfb098ed77ffb847b4f062e301607636f4d7ae0ec4b3c08c7457f6f5db",
     "glm.decode.gather": "cff08709ba7f506ae7f3a9aab3660044fd2a0b55a54ae56da5ca2a99c10ea39b",
     "glm.decode.fused": "20cacc2f819978158c65edc1e186d67d3340e1b89c362f958c3925217c8309f4",
 }

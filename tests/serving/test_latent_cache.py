@@ -104,9 +104,13 @@ def test_a_kv_cache_reads_its_own_bytes():
 # digests as they are (a prefill samples nothing). Both ``prefill`` digests were
 # taken anew by the PR that applies a prefill's head to the last position alone
 # in every model (PR 49: one slice in front of the head, logits of one row);
-# that PR left all four ``decode`` digests as they are.
+# that PR left all four ``decode`` digests as they are. ``mixtral.prefill`` was
+# taken anew by the PR that hands a prefill's ``padding_mask`` to its expert
+# layers as their row mask (PR 55: the padded rows' slots sort last and get no
+# expert); that PR left Mixtral's two ``decode`` digests (a decode step passes
+# no mask) and CodeGen's three (no expert layer) as they are.
 PARENT_PROGRAMS = {
-    "mixtral.prefill": "23b127972724c2b21a1bd6c517d8ab95382f6442d3dd03bcaeea89180ab57e5e",
+    "mixtral.prefill": "940c6b4d2de17d46dda5c3a62c0dea9252a021a744badb942132a5ee722a9c18",
     "mixtral.decode.gather": "3dd48b7bb2d393f00deb7568a1c77dc997d3743775d913a32f7f90d29d1f5f83",
     "mixtral.decode.fused": "3d166644826da274dc5f22bb35072967bd480d62c1aeb3903b898c56b6e9feb0",
     "codegen.prefill": "0b3585bbf36fd58d4a6ad7a7b57a066ce3bcbbacb5b96577efd67747f619d91b",
@@ -197,8 +201,10 @@ def test_mixtral_and_codegen_programs_are_the_parents(program):
 # ``deepseek.decode.fused``: anew with PR 52's latent decode kernel, which
 # fetches a run of adjacent pages with one copy (the kernel is in the program;
 # ``gather`` and the prefill, which hold no such kernel, are as they were).
+# ``deepseek.prefill``: anew with PR 55's row mask, as Mixtral's above; that PR
+# left the two ``decode`` digests.
 DEEPSEEK_PARENT_PROGRAMS = {
-    "deepseek.prefill": "a08844df325f29bf805134bc6c1f4c7dce7b7738457908712deb9b7d3cd39fe6",
+    "deepseek.prefill": "5997180a69883294c11f38add31c8780e4b8669c7052ba10c23694ddb52b7c11",
     "deepseek.decode.gather": "277cce6135272958e7f21a7520375984ab6fcf96160e46688f9604f6fdadcc09",
     "deepseek.decode.fused": "8950beef88173af760551c358b5977d44e8cede7d6f51d6a9e10362c167d26a9",
 }

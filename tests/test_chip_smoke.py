@@ -159,13 +159,24 @@ ROWS = {
     # FASTER is the chip's to say (an interpreted kernel's time says nothing)
     "moe": Row(chip_smoke.MoeSize(
         shapes=(("tiny", 8, 128, 256, 2, 8), ("tiny-odd", 16, 128, 384, 4, 4)),
-        tokens=(1, 8), calls=1, dtype="float32", routed_tol=1e-4), (
+        tokens=(1, 8), calls=1, dtype="float32", routed_tol=1e-4,
+        # a prefill's layer: every expert held, and 4 of 16 held
+        prefill=(("tiny", 8, 128, 256, 2, None, 64, (20, 50)), ("tiny-held", 16, 128, 384, 4, 4, 64, (20, 50))),
+        prefill_calls=1, sampled_rows=16), (
         "moe_tiny_stream_matches_jnp", "moe_tiny_ragged_dot_matches_jnp",
         "moe_tiny_float8_weights_are_caught", "moe_tiny_a_dropped_expert_is_caught",
         "moe_tiny-odd_stream_matches_jnp", "moe_tiny-odd_ragged_dot_matches_jnp",
         "moe_tiny-odd_float8_weights_are_caught", "moe_tiny-odd_a_dropped_expert_is_caught",
-        "moe_tiny_stream_wins_where_the_rule_takes_it", "moe_tiny-odd_stream_wins_where_the_rule_takes_it"),
-        chips_to_say=("_wins_where_the_rule_takes_it",)),
+        "moe_tiny_stream_wins_where_the_rule_takes_it", "moe_tiny-odd_stream_wins_where_the_rule_takes_it",
+        "moe_tiny_prefill_content_rows_match_jnp", "moe_tiny_prefill_content_rows_match_the_parents_form",
+        "moe_tiny_prefill_padded_rows_are_zero", "moe_tiny_prefill_is_blind_to_its_padding",
+        "moe_tiny_prefill_full_bucket_costs_no_more",
+        "moe_tiny_prefill_emptiest_prompt_costs_less",
+        "moe_tiny-held_prefill_content_rows_match_jnp", "moe_tiny-held_prefill_content_rows_match_the_parents_form",
+        "moe_tiny-held_prefill_padded_rows_are_zero", "moe_tiny-held_prefill_is_blind_to_its_padding",
+        "moe_tiny-held_prefill_full_bucket_costs_no_more",
+        "moe_tiny-held_prefill_emptiest_prompt_costs_less"),
+        chips_to_say=("_wins_where_the_rule_takes_it", "_costs_no_more", "_costs_less")),
     # ``--only walk`` at a tiny size (rows of 32 pages of 8 tokens, one block
     # a row; a window of 40; a second shape with no window layer): both kinds
     # of layer against the float32 einsum, under a random block table and
