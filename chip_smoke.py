@@ -2114,7 +2114,8 @@ def _decoded_token_gaps(tag: str, ref, prompts, reqs, gap_tol: float, near_tie: 
         gaps, wrong, _, margins = common.emitted_token_gaps(ref, prompt, req.tokens, pad_to)
         fine, over, excused = common.judge_gaps(gaps, margins, gap_tol, near_tie)
         ok = ok and fine and wrong.min() > gap_tol
-        worst_gap = max(worst_gap, float(gaps[margins >= near_tie].max(initial=0.0)))
+        judged = gaps if margins is None else gaps[margins >= near_tie]    # a reference without a router excuses nothing
+        worst_gap = max(worst_gap, float(judged.max(initial=0.0)))
         log(f"{tag}: prompt {len(prompt)}: decoded tokens' largest reference-logit gap {gaps.max():.4f} ({over} of "
             f"{len(gaps)} fail {gap_tol:g}, {excused} excused by a router margin under {near_tie:g}; wrong "
             f"tokens' smallest gap {wrong.min():.3f})")
@@ -2445,6 +2446,112 @@ def solar_phase(size: SolarSize, seed: int) -> Dict[str, bool]:
     }
 
 
+@dataclasses.dataclass(frozen=True)
+class OuroSize:
+    """What ``--only ouro`` runs (defaults: the chip run, the published widths
+    on the benchmark configuration's cut)."""
+
+    model: object = None          # the configuration's ``model`` group; None = the benchmark configuration's
+    # the benchmark cell's rows and slots: a prefill's row has its BUCKET's
+    # columns (``bucket_prefill_rows``), so beside this pool there is room for
+    # a prompt's and for a prefix hit's seeded row
+    max_seq_len: int = 6144
+    slots: int = 2
+    page: int = 16
+    prompt_lens: Tuple[int, ...] = (900, 300)
+    shared_tokens: int = 512      # of the longest prompt, sent again under another tail: a prefix hit
+    new_tokens: int = 48
+    # the decoded tokens' gap: the benchmark configuration's (its ``reference_check.why`` has the readings)
+    gap_tol: float = 0.45
+
+
+def ouro_phase(size: OuroSize, seed: int) -> Dict[str, bool]:
+    """Ouro-2.6B alone: a stack run four times over ONE set of weights, each
+    pass on a K/V cache node of its own under one block table, against the
+    plain reference's full forward of every pass. Not part of the default run.
+    The language model at its published widths on the benchmark configuration's
+    cut (``perfbench/configs/ouro-2.6b-serve.json``) through a
+    ``ServingEngine`` with its defaults (paged, fused transport, prefix cache
+    on) at the benchmark cell's rows (2 slots of 6,144 columns: the pool is 9.0
+    GiB): prompts of 900 and 300 tokens are prefilled through the flash forward
+    (rolled over the passes; its output a row of the bucket's columns),
+    admitted shortest first an engine step apart (a cursor jump under the
+    decoding slot), and 48 tokens decoded through the walking kernel at 32 head
+    rows a token; then the longest prompt's first 512 tokens under another tail:
+    a prefix hit that maps every pass's pages and copies no byte. Against
+    ``perfbench/references/ouro.py``: the reference's logit of every decoded
+    token; the same tokens judged by the reference whose passes SHARE the first
+    pass's cache and by the whole reference in float8 must fail; the pool
+    counts a node a layer a pass."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from flax.core import meta
+
+    from neuronx_distributed_tpu.inference import GenerationConfig
+    from neuronx_distributed_tpu.parallel import mesh as mesh_lib
+    from neuronx_distributed_tpu.serving import ServingEngine
+    from perfbench.families import ouro as family
+    from perfbench.references.ouro import Reference
+
+    mesh_lib.destroy_model_parallel()
+    published = _published(size, "ouro-2.6b-serve.json")
+    model = family.build(published, runner="serve", max_seq_len=size.max_seq_len)
+    if size.model is not None:    # the CPU rehearsal serves in float32
+        model = model.clone(config=dataclasses.replace(model.config, dtype=jnp.float32))
+    cfg = model.config
+    params = jax.jit(model.init)(jax.random.PRNGKey(seed), jnp.zeros((1, 8), jnp.int32))
+    jax.block_until_ready(params)
+    plain = meta.unbox(params)
+    engine = ServingEngine(model, params, num_slots=size.slots, kv_page_size=size.page)
+    prompts = _prompts(size.prompt_lens, int(published["vocab_size"]), seed + 7)
+    reqs, jumps, wall = _serve_shortest_first("ouro", engine, prompts, size.new_tokens, seed)
+    # the longest prompt's head again, under another tail
+    again = np.concatenate([prompts[0][:size.shared_tokens], _prompts((37,), int(published["vocab_size"]), seed + 11)[0]])
+    hit = engine.submit(again, GenerationConfig(max_new_tokens=size.new_tokens, temperature=0.0),
+                        key=jax.random.PRNGKey(seed + 99))
+    engine.run()
+    snap = engine.metrics.snapshot()
+    resolved = dict(engine.programs.resolved)
+    kernels = _ledger_kernels(engine.programs, _hot_programs(engine))
+    log(f"ouro: {len(reqs) + 1} requests, prompts {list(size.prompt_lens)} + {len(again)} (its first {size.shared_tokens} "
+        f"shared) + {size.new_tokens} tokens in {wall:.1f}s; resolved {resolved}; {KERNEL} in compiled programs: "
+        f"{kernels}; {snap['kv_cache_nodes']} cache nodes over {cfg.num_layers} weight layers, "
+        f"{snap['kv_cache_nodes'] * snap['kv_bytes_per_token_layer']:g} B a token, pool {engine.cache.nbytes / 2**30:.2f} GiB; {jumps} cursor jumps; "
+        f"prefix hits {snap['prefix_hits']}, pages shared {snap['prefix_pages_shared']}, bytes copied "
+        f"{engine.cache.alloc.copy_bytes}")
+    engine.cache.check()
+    copied = engine.cache.alloc.copy_bytes
+    engine = None
+    gc.collect()
+
+    served_prompts, served = prompts + [again], reqs + [hit]
+
+    def judged(tag, ref):
+        """Every decoded token within ``gap_tol`` of ``ref``'s largest logit (and every wrong token outside it)."""
+        return _decoded_token_gaps(f"ouro: {tag}", ref, served_prompts, served, size.gap_tol, 0.0)
+
+    ok, worst = judged("the plain reference", Reference(published, plain))
+    shared_ok, _ = judged("control, passes that share the first pass's cache", Reference(published, plain, shared_cache=True))
+    float8_ok, _ = judged("control, the whole reference in float8", Reference(published, plain, dtype=jnp.float8_e4m3fn))
+    log(f"ouro: largest decoded-token gap {worst:.4f} ({size.gap_tol:g})")
+    itemsize = jnp.dtype(cfg.dtype).itemsize
+    return {
+        "ouro_matches_reference": ok,
+        "ouro_shared_cache_is_caught": not shared_ok,
+        "ouro_float8_is_caught": not float8_ok,
+        "ouro_cursor_jump_leaves_gap_columns": jumps >= len(prompts) - 1,
+        "ouro_prefix_hit_maps_every_passes_pages_and_copies_nothing": (
+            snap["prefix_hits"] >= 1 and snap["prefix_pages_shared"] >= size.shared_tokens // size.page - 1 and copied == 0),
+        "ouro_resolved_paged_walk_fused": resolved == {
+            "attention": "flash", "decode_attention": "paged_walk_fused", "paged_attention": "fused"},
+        "ouro_cache_is_a_node_a_layer_a_pass": (
+            snap["kv_cache_nodes"] == cfg.num_layers * cfg.total_ut_steps
+            and snap["kv_bytes_per_token_layer"] == 2 * cfg.num_kv_heads * cfg.head_dim * itemsize),
+        "kernel_ouro_programs": all(kernels.values()) and bool(kernels),
+    }
+
+
 def _walk_listing_compile(size: WalkSize = WalkSize()) -> None:
     """Compile the walking kernel at ``size`` for a described v5e. Run in a
     process of its own with ``LIBTPU_INIT_ARGS`` naming the dump directory
@@ -2666,7 +2773,8 @@ def walk_phase(size: WalkSize, seed: int) -> Dict[str, bool]:
         h, hkv, d, cur = shape.q_heads, shape.kv_heads, shape.head_dim, shape.cursor
         ctx = _page_started(shape.contexts, cur, page)
         b, n_log = len(ctx), shape.max_seq_len // page
-        group = min(max(flash_decode.WALK_BLOCK_TOKENS // page, 1), n_log)
+        group = min(max(flash_decode.walk_block_tokens(
+            2 * hkv * d * dtype.itemsize, page, flash_decode.walk_row_heads(h, hkv, dtype.itemsize)) // page, 1), n_log)
         pos = jnp.asarray([cur], jnp.int32)
         valid = np.zeros((b, shape.max_seq_len), bool)
         for i, n in enumerate(ctx):            # contexts END at the shared cursor
@@ -3187,6 +3295,7 @@ PHASES: Dict[str, Phase] = {
     "trinity": Phase(trinity_phase, TrinitySize(), only=True),
     "zaya": Phase(zaya_phase, ZayaSize(), only=True),
     "solar": Phase(solar_phase, SolarSize(), only=True),
+    "ouro": Phase(ouro_phase, OuroSize(), only=True),
     "walk": Phase(walk_phase, WalkSize(), only=True),
     "flash": Phase(flash_phase, FlashSize(), only=True),
     "tp_train": Phase(tp_train_phase, TrainSize(), devices=True, chips=4, default=True),

@@ -1168,6 +1168,45 @@ def paged_latent_decode_attention(
 # would need no shuffle at all, 963, at the price of a layout every
 # ``PAGED_LEAVES`` walker would have to learn.
 WALK_BLOCK_TOKENS = 512
+# ... and the bytes a block may hold: the block is sized by a TOKEN's bytes.
+# 512 tokens of (16, 128) bf16 (Trinity's and Solar Open 2's GQA layers) are
+# these 2 MiB, and a narrower leaf (ZAYA1's (4, 128)) keeps its 512 tokens;
+# a token of 16 + 16 heads of 128 (32 head rows, 8 KiB) fits 256, so that the
+# :data:`BLOCKS_AHEAD` + 1 blocks held stay 6 MiB of VMEM where 512 tokens
+# would hold 12 of the 16 a kernel may scope on a v5e beside its operands'
+# double buffers.
+WALK_BLOCK_BYTES = 2 << 20
+# Score columns a block of the ROW kernel (:func:`_paged_walk_row_kernel`, one
+# query row a kv head): its scores are ``(Hkv, T Hkv)`` float32, and 16 heads
+# at 2,048 columns are 32 registers of the file's 64: Ouro's 16 heads take
+# 128 tokens a block. ms a call at 2 slots of ~790 / ~1,550 / ~3,000 tokens
+# each under a dealt table (v5e, PERF.md section 6, PR 56): blocks of 128
+# tokens 0.062 / 0.082 / 0.112, of 256 0.072 / 0.093 / 0.122, of 512 0.076 /
+# 0.101 / 0.131 (a third block ahead: no change); all three stream 11-12 ns a
+# token (8 KiB: 89% of the HBM peak) and differ in what a slot's walk costs
+# before its first block has landed and past its last token.
+ROW_BLOCK_COLUMNS = 2048
+
+
+def walk_row_heads(num_q_heads: int, num_kv_heads: int, itemsize: int) -> int:
+    """``num_kv_heads`` where the walking kernel takes its ROW form
+    (:func:`_paged_walk_row_kernel`), else 0: one query row a kv head, and a
+    token's K head rows fill whole tiles (8 sublanes of 32 bits), so the
+    fetched block is multiplied as it lies."""
+    return num_kv_heads if num_q_heads == num_kv_heads and num_kv_heads * itemsize % 32 == 0 else 0
+
+
+def walk_block_tokens(token_bytes: int, page_size: int, row_heads: int = 0) -> int:
+    """Tokens a block of the walking kernel holds for a leaf of
+    ``token_bytes`` a token: :data:`WALK_BLOCK_TOKENS`, or the whole pages
+    that fit :data:`WALK_BLOCK_BYTES` where a token is wider than 4 KiB; for
+    the row kernel over ``row_heads`` kv heads, no more than the whole pages
+    whose scores are :data:`ROW_BLOCK_COLUMNS` columns."""
+    fit = WALK_BLOCK_BYTES // max(int(token_bytes), 1) // page_size * page_size
+    tokens = min(WALK_BLOCK_TOKENS, fit)
+    if row_heads:
+        tokens = min(tokens, ROW_BLOCK_COLUMNS // row_heads // page_size * page_size)
+    return max(tokens, page_size)
 
 
 def _block_head_rows(block_ref):
@@ -1253,6 +1292,65 @@ def _paged_walk_kernel(bt_ref, runs_ref, live_ref, span_ref, edge_ref, valid_ref
     o_ref[0] = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)).astype(o_ref.dtype)
 
 
+def _paged_walk_row_kernel(bt_ref, runs_ref, live_ref, span_ref, edge_ref, valid_ref, q_ref,
+                           kv_hbm, o_ref, buf, sems, m_scr, l_scr, acc_scr, *,
+                           page_size, group, num_kv_heads, scale, use_valid):
+    """:func:`_paged_walk_kernel` where every kv head has ONE query row (MHA:
+    ``q_ref`` is ``(1, Hkv, D)``). Head by head, each head's ``(T, D)`` rows
+    have to be taken out of the fetched block (a token's heads lie side by
+    side: :func:`_block_head_rows`, four partial loads a register at 32 head
+    rows a token) and a row of scores is one sublane of eight in every
+    register it touches, each head's maximum, exponential and rescale waiting
+    for its own product. Here the block is multiplied AS IT LIES: its K rows
+    ``(T Hkv, D)``, token-major, meet all ``Hkv`` query rows in one product,
+    ``(Hkv, T Hkv)``, of which row ``h`` keeps the columns of its own head
+    (column ``c`` is token ``c // Hkv``, head ``c % Hkv``; the others are
+    masked like a column past the cursor), the softmax runs once over whole
+    registers, and the masked probabilities meet the block's V rows in one
+    product: a zero where the head is another's adds nothing. The matrix
+    unit does ``Hkv`` times the needed products, which it has room for beside
+    a block's copies; no register is shuffled, as long as a token's ``Hkv``
+    K rows are whole tiles (the caller's condition). ``valid_ref`` holds a
+    token's validity once a head (the caller repeats it)."""
+    b = pl.program_id(0)
+    floor, pos = edge_ref[b, 0], edge_ref[b, 1]
+    block = group * page_size
+    hkv = num_kv_heads
+
+    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    def multiply(i, kv_block):
+        shape = (hkv, block * hkv)
+        col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        token = col // hkv + i * block
+        ok = (col % hkv == jax.lax.broadcasted_iota(jnp.int32, shape, 0)) & (token <= pos) & (token >= floor)
+        if use_valid:
+            ok = ok & (valid_ref[0, pl.ds(i, 1), :] != 0)
+        rows = _block_rows(kv_block)                           # (T, 2 Hkv, D)
+        d = rows.shape[-1]
+        k = rows[:, :hkv, :].reshape(block * hkv, d)
+        v = rows[:, hkv:, :].reshape(block * hkv, d)
+        s = jax.lax.dot_general(
+            q_ref[0], k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        ) * scale                                              # (Hkv, T Hkv)
+        s = jnp.where(ok, s, NEG_INF)
+        m_prev = m_scr[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        ref = jnp.where(m_new > NEG_INF / 2, m_new, 0.0)
+        p = jnp.where(ok, jnp.exp(s - ref), 0.0)
+        alpha = jnp.exp(m_prev - ref)
+        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        )
+        m_scr[:] = m_new
+
+    _walk_blocks(bt_ref, runs_ref, live_ref, span_ref, b, ((kv_hbm, buf, None),), sems, group, multiply)
+    o_ref[0] = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)).astype(o_ref.dtype)
+
+
 def paged_walk_decode_attention(
     q: jax.Array,
     kv_pool: jax.Array,
@@ -1273,13 +1371,17 @@ def paged_walk_decode_attention(
     sqrt(D)``, times ``v``: (B, 1, H, D).
 
     The pool is not copied, gathered or blocked: the kernel reads each slot's
-    pages out of HBM itself, :data:`WALK_BLOCK_TOKENS` tokens at a time, and
+    pages out of HBM itself, :func:`walk_block_tokens` tokens at a time, and
     only between the first and the last block in which the block table maps
     a page at or before ``q_pos``. A block there with no mapped page is
     skipped; a slot that maps nothing returns zeros. Inside a fetched block
     an unmapped page reads the null page 0, which ``kv_valid`` or ``floor``
     keeps out of the result (a page the window's manager freed lies below
-    ``floor``). The kernel or nothing (interpreted only in tests); no mesh."""
+    ``floor``). Where every kv head has ONE query row (``H == Hkv``) and a
+    token's K head rows fill whole tiles, the block is multiplied as it lies
+    (:func:`_paged_walk_row_kernel`); otherwise each kv head's rows are taken
+    out of it for that head's group of query rows (:func:`_paged_walk_kernel`).
+    The kernel or nothing (interpreted only in tests); no mesh."""
     from neuronx_distributed_tpu.parallel import mesh as mesh_lib
 
     if mesh_lib.model_parallel_is_initialized():
@@ -1302,7 +1404,10 @@ def paged_walk_decode_attention(
     hkv = kv_pool.shape[2] // 2
     g = h // hkv
     n_log = block_table.shape[1]
-    group = min(max(WALK_BLOCK_TOKENS // page_size, 1), n_log)
+    token_bytes = kv_pool.shape[2] * d * kv_pool.dtype.itemsize
+    row_heads = walk_row_heads(h, hkv, kv_pool.dtype.itemsize)
+    row_form = row_heads > 0
+    group = min(max(walk_block_tokens(token_bytes, page_size, row_heads) // page_size, 1), n_log)
     block = group * page_size
     block_table = block_table.astype(jnp.int32)
     pos = jnp.reshape(q_pos, (-1,))[0].astype(jnp.int32)
@@ -1320,8 +1425,11 @@ def paged_walk_decode_attention(
             kv_valid.astype(jnp.int32),
             ((0, 0), (0, n_blocks * block - kv_valid.shape[1])),
         ).reshape(b, n_blocks, block)
-        vspec = pl.BlockSpec((1, n_blocks, block), lambda b_, *_: (b_, 0, 0))
-    rows = pl.BlockSpec((1, hkv, g, d), lambda b_, *_: (b_, 0, 0, 0))
+        if row_form:    # once a head, as the row kernel's columns lie: a token's heads side by side
+            kv_valid = jnp.repeat(kv_valid, hkv, axis=2)
+        vspec = pl.BlockSpec((1,) + kv_valid.shape[1:], lambda b_, *_: (b_, 0, 0))
+    heads = (hkv,) if row_form else (hkv, g)    # the row kernel's query rows are ONE (Hkv, D) array
+    rows = pl.BlockSpec((1, *heads, d), lambda b_, *_: (b_,) + (0,) * (len(heads) + 1))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=5,  # block table, its runs, live blocks, each slot's span, its (floor, position)
         grid=(b,),
@@ -1330,21 +1438,21 @@ def paged_walk_decode_attention(
         scratch_shapes=[
             pltpu.VMEM((BLOCKS_AHEAD + 1, group, page_size, 2 * hkv, d), kv_pool.dtype),
             pltpu.SemaphoreType.DMA((BLOCKS_AHEAD + 1,)),
-            pltpu.VMEM((hkv, g, 1), jnp.float32),
-            pltpu.VMEM((hkv, g, 1), jnp.float32),
-            pltpu.VMEM((hkv, g, d), jnp.float32),
+            pltpu.VMEM((*heads, 1), jnp.float32),
+            pltpu.VMEM((*heads, 1), jnp.float32),
+            pltpu.VMEM((*heads, d), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(
-            _paged_walk_kernel, page_size=page_size, group=group, num_kv_heads=hkv,
-            scale=1.0 / (d ** 0.5), use_valid=use_valid,
+            _paged_walk_row_kernel if row_form else _paged_walk_kernel, page_size=page_size, group=group,
+            num_kv_heads=hkv, scale=1.0 / (d ** 0.5), use_valid=use_valid,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, *heads, d), q.dtype),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(block_table, _trip_runs(block_table, group), live, span, edge, kv_valid, q.reshape(b, hkv, g, d), kv_pool)
+    )(block_table, _trip_runs(block_table, group), live, span, edge, kv_valid, q.reshape(b, *heads, d), kv_pool)
     return out.reshape(b, 1, h, d)
 
 

@@ -23,7 +23,17 @@ comparisons do.
 
 ``solar_open2`` (``SolarOpen2ForCausalLM``) keeps the same contract with a
 decode window of ONE token: its linear-attention layers take a slot's token
-through a recurrent state, in order (``models/solar_open2.py``)."""
+through a recurrent state, in order (``models/solar_open2.py``).
+
+``ouro`` (``OuroForCausalLM``) is a LOOPED stack: its layers run
+``total_ut_steps`` times over one set of weights, each pass on a K/V cache
+node of its own (``layers_<i>/attn/pass_<t>``), and the final norm closes
+every pass; ``train`` also returns the passes' exit distribution, the served
+modes return the last pass's logits; its prefill is one scan over the passes
+and, under the paged engine, gives out a row of the prompt bucket's columns
+(``OuroConfig.bucket_prefill_rows``). Same contract, with a decode window of
+ONE token on the paged engine's fused path: the walking kernel reads one query
+row a slot (``models/ouro.py``)."""
 
 from neuronx_distributed_tpu.models.afmoe import (
     AfmoeConfig,
@@ -108,6 +118,13 @@ from neuronx_distributed_tpu.models.solar_open2 import (
     solar_open2_250b,
     tiny_solar_open2,
 )
+from neuronx_distributed_tpu.models.ouro import (
+    OuroConfig,
+    OuroForCausalLM,
+    OuroModel,
+    ouro_2_6b,
+    tiny_ouro,
+)
 from neuronx_distributed_tpu.models.vit import (
     ViTConfig,
     ViTForImageClassification,
@@ -135,4 +152,5 @@ __all__ = [
     "ZayaConfig", "ZayaForCausalLM", "ZayaModel", "zaya1_8b", "tiny_zaya",
     "SolarOpen2Config", "SolarOpen2ForCausalLM", "SolarOpen2Model",
     "solar_open2_250b", "tiny_solar_open2",
+    "OuroConfig", "OuroForCausalLM", "OuroModel", "ouro_2_6b", "tiny_ouro",
 ]

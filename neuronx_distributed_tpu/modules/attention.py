@@ -215,6 +215,14 @@ ATTN_KDA_CONV_SCOPE = "attn.kda.conv"
 ATTN_KDA_GATE_SCOPE = "attn.kda.gate"
 ATTN_KDA_RECUR_SCOPE = "attn.kda.recur"
 ATTN_KDA_OUT_SCOPE = "attn.kda.out"
+# A stack that is RUN more than once over one set of weights
+# (``models/ouro.py``): each pass of the stack, its layers' attention
+# (``attn.full``) inside it and its closing norm.
+LOOP_PASS_SCOPE = "loop.pass"
+# ... and the name of pass ``t``'s cache node under a layer of such a stack,
+# ``<LOOP_PASS_NODE><t>``: the layer's parameters exist once, its K/V once a
+# pass (:func:`_execution_order` reads the pass from the name).
+LOOP_PASS_NODE = "pass_"
 
 
 def prefill_positions(padding_mask: jax.Array) -> jax.Array:
@@ -686,16 +694,15 @@ def reset_cache_slot(cache, slot):
     return jax.tree_util.tree_map_with_path(fn, cache)
 
 
-def cache_bytes_per_token_layer(cache) -> float:
-    """Bytes ONE token holds in ONE attention layer of a cache tree (a row
-    collection or a paged ``{"pages", "pool"}`` pytree), from the allocated
-    per-token storage leaves (:data:`PAGED_LEAVES`; a quantized pool's scale
-    siblings count at their share of a page), averaged over the layers:
-    ``2 * Hkv * D * itemsize`` for a ``k``/``v`` cache, ``(d_latent + d_rope)
-    * itemsize`` for a latent one (1152 at DeepSeek-V2's widths in bf16;
-    twice the latent would read 2176), ``(2 * Hkv * D + d_index) * itemsize``
-    for an indexed one (2176 at Keye-VL-2.0's widths: 4 kv heads of 128 and
-    one index key of 64; 2304 would be the index key padded to 128 lanes)."""
+def cache_token_bytes(cache):
+    """``(bytes, nodes)``: the bytes ONE token holds over ALL the attention
+    nodes of a cache tree (a row collection or a paged ``{"pages", "pool"}``
+    pytree) and how many nodes they are, from the allocated per-token storage
+    leaves (:data:`PAGED_LEAVES`; a quantized pool's scale siblings count at
+    their share of a page). A node is what one attention call of a model step
+    writes and attends: a layer's, or, for a stack run more than once over one
+    set of weights, a layer's in ONE pass (``T x L`` nodes: what the pool
+    really holds a token is ``T`` times what the weight layers suggest)."""
     import math
 
     tree = cache["pool"] if isinstance(cache, dict) and "pool" in cache else cache
@@ -711,8 +718,19 @@ def cache_bytes_per_token_layer(cache) -> float:
             per_token /= cache_node_at(tree, path[:-1])[base].shape[-3]
         total += lead * per_token
         layers.add((tuple(str(k) for k in path[:-1]), lead))
-    n_layers = sum(lead for _, lead in layers)
-    return total / n_layers if n_layers else 0.0
+    return total, sum(lead for _, lead in layers)
+
+
+def cache_bytes_per_token_layer(cache) -> float:
+    """Bytes ONE token holds in ONE attention node of a cache tree
+    (:func:`cache_token_bytes`, averaged over the nodes): ``2 * Hkv * D *
+    itemsize`` for a ``k``/``v`` cache, ``(d_latent + d_rope) * itemsize`` for
+    a latent one (1152 at DeepSeek-V2's widths in bf16; twice the latent would
+    read 2176), ``(2 * Hkv * D + d_index) * itemsize`` for an indexed one
+    (2176 at Keye-VL-2.0's widths: 4 kv heads of 128 and one index key of 64;
+    2304 would be the index key padded to 128 lanes)."""
+    total, nodes = cache_token_bytes(cache)
+    return total / nodes if nodes else 0.0
 
 
 def cache_cursor(cache):
@@ -1125,12 +1143,22 @@ def fused_chunk_window(paged, page_size: int, start_col, chunk_size: int):
 
 
 def _execution_order(layers):
-    """Layer tree paths (key tuples) in MODEL EXECUTION order — natural
-    sort, so ``layers_10`` follows ``layers_9`` (lexicographic flatten order
-    would interleave them and hand layer 2 another layer's pages). The one
-    ordering assumption of the fused transport: sequential-layer models
-    name their layers with their execution index, which every family in
-    this repo does."""
+    """Cache-node tree paths (key tuples) in MODEL EXECUTION order: the order
+    in which the attention calls of one model step take their nodes, which is
+    the one ordering assumption of the fused transport. The rule, stated here
+    and nowhere else:
+
+    * a node's path names its layer with the layer's execution index, and the
+      layers run in NATURAL order of their paths (``layers_10`` follows
+      ``layers_9``; lexicographic flatten order would interleave them and hand
+      layer 2 another layer's pages). Every sequential-layer family in this
+      repo names its layers so.
+    * a stack that is run more than once over one set of weights keeps, under
+      each layer, one node a pass, named ``<LOOP_PASS_NODE><t>``
+      (``models/ouro.py``). Such a stack runs PASS-MAJOR, pass 0's layers in
+      their order and then pass 1's, so the pass is the outermost key. A path
+      without such a component is in pass 0 of a stack run once: every other
+      family's order is what the first rule alone gives."""
     import re
 
     def natural(keys):
@@ -1143,7 +1171,11 @@ def _execution_order(layers):
             for k in keys
         )
 
-    return sorted(layers, key=natural)
+    def passes(keys):
+        named = (re.fullmatch(LOOP_PASS_NODE + r"(\d+)", str(k)) for k in keys)
+        return tuple(int(m.group(1)) for m in named if m)
+
+    return sorted(layers, key=lambda keys: (passes(keys), natural(keys)))
 
 
 def ordered_kv_pool_pairs(pool):

@@ -248,7 +248,7 @@ from neuronx_distributed_tpu.inference.utils import unwrap_logits
 from neuronx_distributed_tpu.kernels import backend
 from neuronx_distributed_tpu.kernels.flash_attention import flash_tile_plan, group_tile_plan
 from neuronx_distributed_tpu.modules.attention import (
-    cache_bytes_per_token_layer,
+    cache_token_bytes,
     cache_fingerprint,
     PAGED_LEAVES,
     SLOT_STATE_LEAVES,
@@ -663,6 +663,18 @@ class ServingEngine:
         # no page, so the same holders lack it at a context's end
         kv_slot_state = bool(getattr(
             getattr(model, "config", None), "kv_cache_slot_state", False
+        ))
+        # a prefill's row (its OUTPUT, beside the pool until the admission has
+        # dealt it into pages) has the cache's columns by every model's
+        # construction, ``max_seq_len``; a model whose config says
+        # ``bucket_prefill_rows`` is cloned a bucket with ``max_seq_len`` the
+        # bucket's, so the row has the bucket's columns and the paged
+        # admission cuts its pages out of that (serving/paging.py
+        # ``_paged_admit``). With few slots and many cache nodes a whole row
+        # is half the pool again. Paged caches only: a row-per-slot cache
+        # rolls whole rows
+        self._bucket_rows = kv_page_size is not None and bool(getattr(
+            getattr(model, "config", None), "bucket_prefill_rows", False
         ))
         if (kv_window is not None and kv_page_size is not None) or kv_slot_state:
             from neuronx_distributed_tpu.serving.paging import (
@@ -2441,15 +2453,20 @@ class ServingEngine:
         )
         logger.warning("slow_step %s", json.dumps(event))
 
-    def _kv_bytes_per_token_layer(self) -> int:
-        """Bytes a token holds per attention layer, from the allocated
-        cache leaves (whole bytes: a span's stats are host ints); the
-        ``serving_kv_bytes_per_token_layer`` gauge carries the same."""
-        if not self.metrics.kv_bytes_per_token_layer:
-            self.metrics.record_kv_bytes(
-                cache_bytes_per_token_layer(self.cache.cache)
-            )
-        return int(round(self.metrics.kv_bytes_per_token_layer))
+    def _kv_bytes_stats(self) -> dict:
+        """For the dispatch span, from the allocated cache leaves (whole
+        bytes: a span's stats are host ints): ``kv_bytes_per_token_layer``,
+        the bytes a token holds per cache node, and ``kv_cache_nodes``, the
+        nodes (one an attention layer, and for a stack run more than once
+        over one set of weights one a layer a PASS): their product is what a
+        token holds. The ``serving_kv_*`` gauges carry the same."""
+        if not self.metrics.kv_cache_nodes:
+            self.metrics.record_kv_bytes(*cache_token_bytes(self.cache.cache))
+        m = self.metrics
+        return {
+            "kv_bytes_per_token_layer": int(round(m.kv_bytes_per_token_layer)),
+            "kv_cache_nodes": int(m.kv_cache_nodes),
+        }
 
     def _held_tokens(self) -> List[int]:
         """Tokens each decoding slot holds (its prompt and what it emitted)."""
@@ -2824,6 +2841,10 @@ class ServingEngine:
         fn = self._prefill_fns.get(padded_len)
         if fn is None:
             prefill = self._prefill_model
+            if self._bucket_rows:
+                # the row this program gives out has the bucket's columns
+                prefill = prefill.clone(config=dataclasses.replace(
+                    prefill.config, max_seq_len=padded_len))
             head_rows = self._prefill_head_rows
             # past the two: the model's own per-prefill counters, summed over
             # its layers (``prefill_stats``; most models: none)
@@ -2923,6 +2944,9 @@ class ServingEngine:
                         row = self.cache.seed_row(
                             entry.page_ids[:m_use // self._page_size],
                             m_use, padded - p,
+                            # the chunk (under 2 s) is written at padded - s
+                            length=min(2 * padded, self.max_seq_len)
+                            if self._bucket_rows else None,
                         )
                     else:
                         # seed a fresh row from the stored prefix COPY (the
@@ -3535,7 +3559,7 @@ class ServingEngine:
             sampled_slots = self._sampled_slots()
             sp.set_metadata(
                 active=active_at_dispatch, sampled_slots=sampled_slots,
-                kv_bytes_per_token_layer=self._kv_bytes_per_token_layer(),
+                **self._kv_bytes_stats(),
                 cursor=int(self.cache.cursor), row_columns=self.max_seq_len,
                 **self._selection_stats(),
             )
@@ -3729,7 +3753,7 @@ class ServingEngine:
             sampled_slots = self._sampled_slots()
             sp.set_metadata(
                 active=active_at_dispatch, sampled_slots=sampled_slots,
-                kv_bytes_per_token_layer=self._kv_bytes_per_token_layer(),
+                **self._kv_bytes_stats(),
                 cursor=int(self.cache.cursor), row_columns=self.max_seq_len,
                 # one dict: both name ``ctx_tokens``
                 **{**self._selection_stats(), **self._window_stats()},

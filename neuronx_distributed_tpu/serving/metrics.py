@@ -239,6 +239,10 @@ class ServingMetrics:
             "serving_kv_bytes_per_token_layer",
             help="bytes one token holds in one attention layer's cache",
         )
+        self._g_kv_nodes = self.view.gauge(
+            "serving_kv_cache_nodes",
+            help="attention nodes of the cache: one a layer, one a layer a pass for a looped stack",
+        )
         self._g_slot_state = self.view.gauge(
             "serving_slot_state_bytes",
             help="bytes every slot's per-slot state leaves hold, all layers",
@@ -295,6 +299,7 @@ class ServingMetrics:
         # bytes a token holds per attention layer, from the allocated cache
         # leaves (0 until the first admission allocates them)
         self.kv_bytes_per_token_layer = 0.0
+        self.kv_cache_nodes = 0
         # device-efficiency ledgers (ISSUE 12): attached weakly by the
         # engine so snapshot() can carry "programs"/"hbm" without a kept
         # metrics object pinning a retired engine's ledgers
@@ -619,9 +624,13 @@ class ServingMetrics:
         """Single-step accounting — the chunk-size-1 special case."""
         self.record_decode_chunk(active_slots, 1, cursor, active_slots)
 
-    def record_kv_bytes(self, per_token_layer: float) -> None:
-        self.kv_bytes_per_token_layer = float(per_token_layer)
-        self._g_kv_bytes.set(per_token_layer)
+    def record_kv_bytes(self, per_token: float, nodes: int) -> None:
+        """What a token holds over all ``nodes`` attention nodes of the cache
+        (``modules/attention.cache_token_bytes``)."""
+        self.kv_cache_nodes = int(nodes)
+        self.kv_bytes_per_token_layer = per_token / nodes if nodes else 0.0
+        self._g_kv_bytes.set(self.kv_bytes_per_token_layer)
+        self._g_kv_nodes.set(nodes)
 
     def record_slot_state_bytes(self, nbytes: int) -> None:
         self._g_slot_state.set(nbytes)
@@ -826,6 +835,7 @@ class ServingMetrics:
             "health": self.health,
             "cursor_high_water": self.cursor_high_water,
             "kv_bytes_per_token_layer": self.kv_bytes_per_token_layer,
+            "kv_cache_nodes": self.kv_cache_nodes,
             "mean_occupancy": self.mean_occupancy,
             "mean_ttft": _mean(ttfts),
             "max_ttft": max(ttfts) if ttfts else 0.0,

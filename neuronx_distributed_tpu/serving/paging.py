@@ -480,9 +480,22 @@ class PagedCacheManager:
                 row_leaf = cache_node_at(row, path[:-1])[base]
                 r_ax = row_leaf.ndim - 4  # row batch axis
                 col = r_ax + 1
-                rolled = jnp.roll(row_leaf, shift, axis=col)
                 lead = row_leaf.shape[:r_ax]
                 tail = row_leaf.shape[col + 1:]
+                if row_leaf.shape[col] != n_log * ps:
+                    # a SHORT row (the prefill bucket's columns, not the
+                    # cache's: the engine's ``bucket_prefill_rows``): the
+                    # window's columns are cut out of the row laid between
+                    # zeros, and no whole-row copy exists
+                    w = n_adm * ps
+                    pad = [(0, 0)] * row_leaf.ndim
+                    pad[col] = (w, w)
+                    win = jax.lax.dynamic_slice_in_dim(
+                        jnp.pad(row_leaf, pad), w + lo_page * ps - shift, w,
+                        axis=col,
+                    )
+                    return win.reshape(lead + (n_adm, ps) + tail)
+                rolled = jnp.roll(row_leaf, shift, axis=col)
                 pg = rolled.reshape(lead + (1, n_log, ps) + tail)
                 win = jax.lax.dynamic_slice_in_dim(
                     pg, lo_page, n_adm, axis=r_ax + 1
@@ -506,6 +519,11 @@ class PagedCacheManager:
                 ax = cache_batch_axis(name, pool_leaf.ndim)
                 if name == "kv_valid":
                     row_leaf = cache_node_at(row, path[:-1])[name]
+                    short = n_log * ps - row_leaf.shape[ax + 1]
+                    if short > 0:  # a short row: invalid past its end
+                        pad = [(0, 0)] * row_leaf.ndim
+                        pad[ax + 1] = (0, short)
+                        row_leaf = jnp.pad(row_leaf, pad)
                     rolled = jnp.roll(row_leaf, shift, axis=ax + 1)
                     return jax.lax.dynamic_update_slice_in_dim(
                         pool_leaf, rolled, slot, axis=ax
@@ -519,8 +537,10 @@ class PagedCacheManager:
             pool = jax.tree_util.tree_map_with_path(fn, pool_in)
             return with_pool(paged, pool)
 
-        def _seed_from_pages(pool, ids, m, start):
-            """Batch-1 row whose columns [start, start+m) hold the first
+        def _seed_from_pages(pool, ids, m, start, length=max_seq_len):
+            """Batch-1 row of ``length`` columns (static; the cache's, or
+            a short row's: ``_paged_admit``) whose columns [start, start+m)
+            hold the first
             ``m`` tokens of the shared pages ``ids`` — the zero-copy twin
             of ``seed_cache_prefix`` on a stored entry COPY. The pool is
             READ (never donated, never aliased into the result): the
@@ -562,9 +582,7 @@ class PagedCacheManager:
                 _rebuild_tree,
             )
 
-            return seed_cache_prefix(
-                _rebuild_tree(items), m, start, max_seq_len
-            )
+            return seed_cache_prefix(_rebuild_tree(items), m, start, length)
 
         def _stage_context(paged, row, shift, ids):
             """Disaggregated handoff, write half (ISSUE 14): scatter a
@@ -665,7 +683,7 @@ class PagedCacheManager:
         # the module-level reset helpers need per_instance for the same
         # pjit-cache-per-function-object reason as SlotCacheManager
         self._admit_fn = jax.jit(_paged_admit, donate_argnums=(0,))
-        self._seed_fn = jax.jit(_seed_from_pages)
+        self._seed_fn = jax.jit(_seed_from_pages, static_argnums=(4,))
         self._free_fn = jax.jit(per_instance(reset_cache_slot), donate_argnums=(0,))
         self._reset_fn = jax.jit(per_instance(reset_cache), donate_argnums=(0,))
         self._stage_fn = jax.jit(_stage_context, donate_argnums=(0,))
@@ -1239,11 +1257,13 @@ class PagedCacheManager:
         # the jump may have carried the other slots' windows past pages
         self._free_behind_window()
 
-    def seed_row(self, page_ids: Sequence[int], m: int, start: int):
+    def seed_row(self, page_ids: Sequence[int], m: int, start: int,
+                 length: Optional[int] = None):
         """Batch-1 row whose columns [start, start+m) read the shared pages
         — the zero-copy prefix hit's suffix-prefill substrate. Pool pages
         are gathered for COMPUTE only (nothing allocated, nothing written;
-        ``PageAllocator.copy_bytes`` untouched)."""
+        ``PageAllocator.copy_bytes`` untouched). ``length``: the row's
+        columns where it is shorter than the cache's (``admit`` takes it)."""
         self._refuse_held_by_pages("a row seeded from shared pages")
         if self.cache is None:
             raise RuntimeError("no cache allocated yet (nothing to seed from)")
@@ -1252,6 +1272,7 @@ class PagedCacheManager:
             jnp.asarray(np.asarray(page_ids, np.int32)),
             jnp.asarray(m, jnp.int32),
             jnp.asarray(start, jnp.int32),
+            *(() if length is None else (int(length),)),
         )
 
     # --- disaggregated prefill/decode handoff (ISSUE 14) --------------------

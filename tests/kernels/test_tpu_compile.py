@@ -1309,3 +1309,106 @@ def test_solar_open2_engine_programs_compile_and_fit(topo):
     temp = prefill.memory_analysis().temp_size_in_bytes
     print(f"solar prefill[{longest}]: live {live / 2**30:.2f} GiB, temporaries {temp / 2**30:.2f} GiB")
     assert temp < 1.1 * SOLAR_PREFILL_TEMP_GIB * 2**30, f"{temp / 2**30:.2f} GiB of prefill temporaries"
+
+
+# --- Ouro-2.6B: a stack run four times over one set of weights, a K/V cache a pass --
+
+
+def _ouro(cfg, seq):
+    from perfbench.families import ouro
+
+    return ouro.build(cfg, runner="serve", max_seq_len=seq)
+
+
+def test_walking_decode_kernel_compiles_at_ouro_geometry(topo):
+    """Ouro's decode attention at the serve cell's shapes (2 slots of 6,144
+    columns, page 16, 16 query heads against 16 kv heads of 128: MHA, K and V
+    one joined leaf of (32, 128) a token, 8 KiB): the kernel that walks the
+    blocks a slot maps, in its form for one query row a kv head (the block
+    multiplied as it lies: 128 tokens a block, 2,048 score columns; by a
+    token's bytes alone it would be 256, three blocks of 2 MiB where 512
+    tokens would hold 12 MiB of the scoped VMEM), and the window pages'
+    copies into the pool. Neither may copy its pool leaf
+    whole. Trinity's and ZAYA1's leaves keep 512 tokens a block."""
+    from neuronx_distributed_tpu.kernels.flash_decode import (
+        paged_scatter_window_pages_dma,
+        paged_walk_decode_attention,
+        walk_block_tokens,
+    )
+
+    assert walk_block_tokens(32 * 128 * 2, 16) == 256 and walk_block_tokens(32 * 128 * 2, 16, row_heads=16) == 128
+    assert walk_block_tokens(16 * 128 * 2, 16) == walk_block_tokens(4 * 128 * 2, 16) == 512
+    s = _one_chip(topo)
+    b, n_log, page = 2, 384, 16
+    pages = b * n_log + 1
+    table, valid = s((b, n_log), jnp.int32), s((b, n_log * page), jnp.bool_)
+
+    def step(q, pool, win, bt, pos, ok):
+        pool = paged_scatter_window_pages_dma(pool, win, bt, pos[0] // page)
+        return paged_walk_decode_attention(q, pool, bt, pos, kv_valid=ok, page_size=page), pool
+
+    text = jax.jit(step, donate_argnums=(1,)).lower(
+        s((b, 1, 16, 128)), s((pages, page, 32, 128)), s((b, 2 * page, 32, 128)), table,
+        s((1,), jnp.int32), valid).compile().as_text()
+    assert _kernels(text) >= 2
+    assert not re.search(r"bf16\[%d,%d,32,128\]\S* copy\(" % (pages, page), text), "the joined pool leaf is copied whole"
+
+
+@pytest.mark.slow
+def test_ouro_engine_programs_compile_and_fit(topo):
+    """The benchmark configuration's programs (``perfbench/configs/
+    ouro-2.6b-serve.json``: its layers x 4 passes, its slots and rows, page
+    16): the fused decode chunk with every cache node's pool carried (one a
+    layer a pass, one block table) and the longest prompt's prefill through the
+    flash forward, both with Pallas kernels and inside the chip's memory: a
+    layer body a node unrolled in the chunk, the prefill ROLLED over the passes
+    (a layer body a layer) and its output a row of the BUCKET's columns. The
+    decode program holds no row-sized array and copies no pool leaf; the
+    weights are held ONCE (the arguments are the pool and one stack's weights,
+    not four)."""
+    import json
+    import time
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    with open(os.path.join(root, "perfbench", "configs", "ouro-2.6b-serve.json")) as f:
+        config = json.load(f)
+    with open(os.path.join(root, "perfbench", "traffic", "worked_closed.json")) as f:
+        longest = int(json.load(f)["prompt_len"]["max"])
+    seq, slots = int(config["serving"]["max_seq_len"]), int(config["serving"]["num_slots"])
+    layers, passes = int(config["model"]["num_hidden_layers"]), int(config["model"]["total_ut_steps"])
+    engine, lower_decode, lower_prefill, pool_shards = _engine_programs(
+        topo, 1, slots=slots, seq=seq, bucket=longest, model=_ouro(config["model"], seq))
+    assert engine.programs.resolved == {
+        "attention": "flash", "decode_attention": "paged_walk_fused", "paged_attention": "fused"}
+    leaf = (slots * seq // 16 + 1, 16, 32, 128)
+    assert pool_shards == [leaf] * (layers * passes)
+    t0 = time.perf_counter()
+    lowered = lower_decode()
+    t_lower = time.perf_counter() - t0
+    decode = lowered.compile()
+    t_decode = time.perf_counter() - t0
+    text = decode.as_text()
+    assert _kernels(text) >= 2 * layers * passes            # a walk and a window copy a node
+    live = _fits(decode, 15 * 1024**3)
+    m = decode.memory_analysis()
+    beside = m.argument_size_in_bytes - layers * passes * math.prod(leaf) * 2
+    g = config["model"]
+    held = 2 * (layers * (4 * g["hidden_size"] ** 2 + 3 * g["hidden_size"] * g["intermediate_size"])
+                + 2 * g["vocab_size"] * g["hidden_size"])
+    assert held < beside < 1.02 * held, (
+        f"{beside / 1e9:.2f} GB of arguments beside the pool, {held / 1e9:.2f} GB of weights: the stack is held once")
+    shape = "bf16[%s]" % ",".join(map(str, leaf))
+    copies = _copies_inside_loops(text, {shape})
+    assert not copies, f"{len(copies)} whole-pool copies per decode step: " + "; ".join(copies[:3])
+    assert not _arrays_of_a_views_size(text, [leaf])
+    print(f"ouro decode: traced and lowered in {t_lower:.0f} s (every process pays that), compiled in {t_decode:.0f} s, live {live / 2**30:.2f} GiB, "
+          f"temporaries {m.temp_size_in_bytes / 2**30:.2f} GiB")
+    t0 = time.perf_counter()
+    prefill = lower_prefill().compile()
+    t_prefill = time.perf_counter() - t0
+    assert layers <= _kernels(prefill.as_text()) < 2 * layers              # the passes are one loop
+    live = _fits(prefill, 15 * 1024**3)
+    row = layers * passes * longest * 32 * 128 * 2                          # the bucket's columns of every node
+    assert row <= prefill.memory_analysis().output_size_in_bytes < 1.01 * row + 2**20
+    print(f"ouro prefill[{longest}]: compiled in {t_prefill:.0f} s, live {live / 2**30:.2f} GiB, "
+          f"temporaries {prefill.memory_analysis().temp_size_in_bytes / 2**30:.2f} GiB")

@@ -162,7 +162,10 @@ def test_the_walking_kernel_reads_each_word_of_a_block_once_and_never_a_head_a_r
         shape((2, 1, hkv * g, d), dtype), shape((65, page, 2 * hkv, d), dtype), shape((2, n_log), jnp.int32),
         shape((1,), jnp.int32))
     (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
-    buffer = (flash_decode.BLOCKS_AHEAD + 1, flash_decode.WALK_BLOCK_TOKENS // page, page, 2 * hkv, d)   # blocks of pages: a run of them is one copy
+    # a block is sized by a token's bytes: 512 tokens of Trinity's 16 rows in bf16, 256 in float32
+    block = flash_decode.walk_block_tokens(2 * hkv * d * jnp.dtype(dtype).itemsize, page)
+    assert block == (flash_decode.WALK_BLOCK_TOKENS if dtype == jnp.bfloat16 else flash_decode.WALK_BLOCK_TOKENS // 2)
+    buffer = (flash_decode.BLOCKS_AHEAD + 1, block // page, page, 2 * hkv, d)   # blocks of pages: a run of them is one copy
     reads = []
 
     def walk(j):
@@ -176,9 +179,9 @@ def test_the_walking_kernel_reads_each_word_of_a_block_once_and_never_a_head_a_r
     words_a_token = 2 * hkv * jnp.dtype(dtype).itemsize // 4
     assert len(reads) == words_a_token
     for aval, transforms in reads:
-        assert aval.shape == (flash_decode.WALK_BLOCK_TOKENS, d) and aval.dtype == jnp.uint32
+        assert aval.shape == (block, d) and aval.dtype == jnp.uint32
         rows = transforms[-1].indices[0]
-        assert (rows.size, rows.stride) == (flash_decode.WALK_BLOCK_TOKENS, words_a_token)
+        assert (rows.size, rows.stride) == (block, words_a_token)
     assert sorted(t[-1].indices[0].start for _, t in reads) == list(range(words_a_token))
 
 

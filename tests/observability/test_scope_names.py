@@ -310,3 +310,36 @@ def test_the_linear_attention_programs_carry_their_names(solar):
     assert kda | {"attn.gate", "moe", "moe.router", "lm_head"} <= _optimized(decode)
     assert kda | {"attn.full", "attn.gate", "moe", "moe.router"} <= _traced(prefill)
     assert {"attn.kda", "attn.kda.project", "attn.kda.recur", "attn.kda.out"} <= _optimized(prefill)
+
+
+@pytest.fixture(scope="module")
+def ouro():
+    return _programs("ouro-2.6b-serve")
+
+
+def test_the_looped_stacks_programs_carry_their_names(ouro):
+    """``loop_pass_dev_share_pct`` and ``loop_weight_stream_roofline`` find the
+    scope ``loop.pass`` around every pass of the stack (the constants are
+    ``modules/attention.py``'s, beside the other ``*_SCOPE`` names), and
+    ``attn.full`` INSIDE it, innermost: the walking kernel and the window
+    pages' copies are called there and named after it, under the pass's cache
+    node ``pass_<t>`` (``full_attn_dev_share_pct``, ``swa_decode_roofline``);
+    what lies under ``loop.pass`` and outside ``attn.full`` is the weight
+    stream."""
+    from neuronx_distributed_tpu.modules.attention import ATTN_FULL_SCOPE, LOOP_PASS_NODE, LOOP_PASS_SCOPE
+
+    assert (LOOP_PASS_SCOPE, ATTN_FULL_SCOPE, LOOP_PASS_NODE) == ("loop.pass", "attn.full", "pass_")
+    decode, prefill = ouro["decode_chunk"], ouro["prefill"]
+    assert re.search(r"HloModule (\S+?),", decode[1]).group(1) == "jit_chunk_fn"
+    assert re.search(r"HloModule (\S+?),", prefill[1]).group(1) == "jit_fn"
+    calls = KERNEL_CALL.findall(decode[0])
+    assert {path.rsplit("/", 1)[-1] for path in calls} == {"attn.full"}
+    # 2 layers x 3 passes: every node's kernels under loop.pass and its own pass node
+    assert len(calls) >= 6 and all("loop.pass" in path.split("/") for path in calls)
+    assert {part for path in calls for part in path.split("/") if part.startswith("pass_")} == {"pass_0", "pass_1", "pass_2"}
+    names = {"loop.pass", "attn.full", "kv_view", "mlp", "lm_head", "sample"}
+    assert names <= _traced(decode) and {"loop.pass", "mlp", "lm_head"} <= _optimized(decode)
+    assert {"loop.pass", "attn.full", "mlp"} <= _traced(prefill) and {"loop.pass", "mlp"} <= _optimized(prefill)
+    # the head and the sampler lie outside the passes
+    outside = [path for path in LOCATION.findall(decode[0]) if "lm_head" in path.split("/")]
+    assert outside and not any("loop.pass" in path.split("/") for path in outside)

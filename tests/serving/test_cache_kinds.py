@@ -16,7 +16,9 @@ joined, and per-slot STATE beside the pages: what a layer's next token needs of
 the slot's last one, a slot axis and no length axis). ``joined_recurrent``:
 Solar Open 2's (GQA layers with K and V joined; between them RECURRENT layers
 that keep a float32 state and their convolutions' taps a slot and nothing
-else: no per-token leaf, no page, no block table).
+else: no per-token leaf, no page, no block table). ``looped``: Ouro's (K and V
+joined, no window; the stack run three times over one set of weights, a cache
+node a layer a PASS under one block table, taken pass-major).
 
 What is particular to ONE kind stays in that kind's file
 (``test_latent_cache.py``, ``test_indexed_cache.py``,
@@ -121,6 +123,15 @@ def _joined_recurrent():
             solar_open2_250b(num_layers=2, held_experts=(0, 2), param_dtype=jnp.bfloat16))
 
 
+def _looped():
+    from neuronx_distributed_tpu.models.ouro import OuroForCausalLM, ouro_2_6b, tiny_ouro
+    from perfbench.references.ouro import Reference
+    from tests.models.test_ouro import published_keys
+
+    return (OuroForCausalLM, tiny_ouro(max_seq_len=256), published_keys, Reference,
+            ouro_2_6b(num_layers=2, param_dtype=jnp.bfloat16))
+
+
 @dataclasses.dataclass(frozen=True)
 class Kind:
     """A cache kind's row: what builds its tiny model and reference, and what
@@ -129,7 +140,7 @@ class Kind:
     parts: Callable                      # -> (model class, tiny config, published keys of a config, Reference, published-width config)
     fused: str                           # what ``paged_attention="fused"`` resolves the decode attention to
     leaves: Dict[str, Tuple[int, int]]   # per-token leaf -> its (rows, lanes) a token, tiny widths
-    layers: Tuple[str, ...] = ()         # the pool's layers in execution order
+    layers: Tuple[str, ...] = ()         # the pool's nodes in execution order (``node_name``)
     published_leaves: Optional[Dict[str, Tuple[int, int]]] = None   # the same at the published widths, bf16 ...
     published_bytes: int = 0             # ... and their bytes a token a layer
     page: int = 16
@@ -190,6 +201,11 @@ KINDS = {
                              state={"recur": (4, 16, 16), "conv": (3, 192)}, published_state_bytes=4341760,
                              state_layers=3,
                              refuses=("tp", "prefix_cache", "kv_host_pages", "draft_model", "quantize.kv", "disagg")),
+    # Ouro's: K and V joined, NO window and no per-slot state, so a context is its pages and prefixes are shared;
+    # 2 layers run 3 times over one set of weights, a node a layer a PASS, taken pass-major (published in bf16: 16 +
+    # 16 heads of 128, (32, 128), 8192 bytes a token a node)
+    "looped": Kind(_looped, "paged_walk_fused", {"kv": (2 * 4, 16)},
+                   tuple(f"layers_{i}/pass_{t}" for t in range(3) for i in range(2)), {"kv": (32, 128)}, 8192),
 }
 WHOLE_POOL = [name for name, row in KINDS.items() if row.whole_pool]
 
@@ -254,6 +270,11 @@ def largest_gap(ref, prompts, streams):
         assert controls.min() > 100 * TOLERANCE      # the check is able to fail
         worst = max(worst, float(gaps.max()))
     return worst
+
+
+def node_name(path):
+    """A cache node's name in a row's ``layers``: its layer, and its pass where the stack is run more than once."""
+    return "/".join(k for k in path if k.startswith(("layers_", "pass_")))
 
 
 def paged_leaves(tree):
@@ -343,7 +364,7 @@ def test_fused_chunk_carries_every_leaf(kind):
 
     paged = jax.eval_shape(pool_of, row)
     pairs = ordered_kv_pool_pairs(paged["pool"])
-    assert [layer[-2] for layer in pairs] == list(kind.kind.layers)
+    assert [node_name(layer) for layer in pairs] == list(kind.kind.layers)
     assert all([leaf.shape[-2:] for leaf in pair] == list(kind.kind.leaves.values()) for pair in pairs.values())
     state = jax.eval_shape(ServingEngine(model, params, num_slots=2, kv_page_size=page)._fresh_slot_state)
     jaxpr = jax.make_jaxpr(chunked_decode_step(decode, 4, cfg.max_seq_len, page_size=page,

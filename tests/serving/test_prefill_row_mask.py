@@ -30,7 +30,7 @@ def _serve(model, params, prompt, path):
     return list(req.tokens), firsts, prefills
 
 
-@pytest.mark.parametrize("kind", list(KINDS))
+@pytest.mark.parametrize("kind", [name for name in KINDS if name != "looped"])     # Ouro has no expert layer
 def test_one_prompt_gives_one_stream_whatever_its_bucket_and_the_span_counts_the_rows(kind, tmp_path):
     b = built(kind)
     prompt = np.random.default_rng(1).integers(1, b.cfg.vocab_size, size=PROMPT).astype(np.int32)

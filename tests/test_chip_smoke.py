@@ -155,6 +155,14 @@ ROWS = {
         "solar_beta_without_its_factor_is_caught", "solar_no_convolution_is_caught", "solar_no_output_gate_is_caught",
         "solar_float8_state_is_caught", "solar_float8_is_caught", "solar_rotary_on_the_gqa_layer_is_caught", "solar_no_gqa_gate_is_caught",
         "kernel_solar_programs"), chips_to_say=("_copies_no_array_of_the_states_size",)),
+    # ``--only ouro`` at the CPU stand-in's size (2 layers x 3 passes, MHA; contexts of 150 in a row of 512, page 8,
+    # a prefix of 64 tokens shared); float32, so the tolerance is a float32 matmul's summation order
+    "ouro": Row(chip_smoke.OuroSize(
+        model=_tiny("ouro-2.6b-serve"), max_seq_len=512, slots=2, page=8, prompt_lens=(150, 40), shared_tokens=64,
+        new_tokens=12, gap_tol=1e-3), (
+        "ouro_matches_reference", "ouro_shared_cache_is_caught", "ouro_float8_is_caught",
+        "ouro_cursor_jump_leaves_gap_columns", "ouro_prefix_hit_maps_every_passes_pages_and_copies_nothing",
+        "ouro_resolved_paged_walk_fused", "ouro_cache_is_a_node_a_layer_a_pass", "kernel_ouro_programs")),
     # ``--only moe`` at a tiny size: every comparison must hold; which form is
     # FASTER is the chip's to say (an interpreted kernel's time says nothing)
     "moe": Row(chip_smoke.MoeSize(
@@ -260,7 +268,7 @@ def test_default_runs_are_the_tables_rows():
     assert chip_smoke.default_run(1) == ["train", "serve", "mla", "dsa", "glm"]
     assert chip_smoke.default_run(4) == ["tp_train", "tp_serve", "remat"]
     only = [name for name, phase in chip_smoke.PHASES.items() if phase.only]
-    assert only == ["mla", "dsa", "glm", "moe", "trinity", "zaya", "solar", "walk", "flash"]
+    assert only == ["mla", "dsa", "glm", "moe", "trinity", "zaya", "solar", "ouro", "walk", "flash"]
     for name in only:
         assert chip_smoke.parse_args(["--only", name]).only == name
         assert chip_smoke.PHASES[name].help and chip_smoke.PHASES[name].chips == 1
