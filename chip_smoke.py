@@ -12,7 +12,7 @@ compared with. The default run is the table's ``default`` rows of one chip in
 order (``train``, ``serve``, ``mla``, ``dsa``, ``glm``); ``--only NAME`` runs
 one row alone, among them the rows no default run includes (they serve
 nothing, or repeat what a benchmark cell holds): ``moe``, ``trinity``,
-``walk``, ``flash``; ``--chips 4`` runs only the four-chip path and what it is
+``walk``, ``runahead``, ``flash``; ``--chips 4`` runs only the four-chip path and what it is
 compared with, the table's ``default`` rows of four chips (``tp_train``,
 ``tp_serve``, ``remat``).
 
@@ -2552,6 +2552,212 @@ def ouro_phase(size: OuroSize, seed: int) -> Dict[str, bool]:
     }
 
 
+@dataclasses.dataclass(frozen=True)
+class RunAheadSize:
+    """What ``--only runahead`` runs (defaults: the chip run, the Trinity
+    cell's engine: the benchmark's shortest decode step, so the cell in which
+    the host's turn between two chunks weighs most)."""
+
+    model: object = None          # the configuration's ``model`` group; None = the benchmark configuration's
+    config: str = "trinity-large-serve.json"
+    max_seq_len: int = 32768
+    slots: int = 8
+    page: int = 16
+    prompt_len: int = 2048
+    probe_pairs: int = 6          # chained pairs of the decode chunk, traced
+    requests: int = 24            # three a slot: a queue stands behind the full slots
+    answers: Tuple[int, int] = (400, 1200)   # spread evenly between: budgets end in different chunks
+    # the second call returns while the first still runs: both calls back in
+    # under this share of the first chunk's wall, and the device's hole between
+    # the two runs of a pair under this share of the hole a host turn leaves
+    call_share: float = 0.5
+    hole_share: float = 0.25
+    trace: bool = True            # the CPU rehearsal opens no profiler session (a pytest worker must not)
+
+
+def _chunk_runs(trace_dir: str) -> List[Tuple[int, int]]:
+    """``(start ns, end ns)`` of every run of the decode chunk's module on
+    device 0 of the trace under ``trace_dir``, in time order."""
+    from perfbench import xplane
+
+    runs = []
+    for plane in xplane.read_planes(xplane.find_xplane(trace_dir)):
+        if plane.name != "/device:TPU:0":
+            continue
+        for line in plane.lines:
+            if line.name == xplane.MODULES_LINE:
+                runs += [(a, b) for a, b, name, _ in xplane._events(line)
+                         if xplane.module_base(name) == "jit_chunk_fn"]
+    return sorted(runs)
+
+
+def runahead_probe(engine, size: RunAheadSize) -> Dict[str, bool]:
+    """Two chained calls of the engine's donated decode chunk, ``probe_pairs``
+    times under one trace: the second call takes the first's output cache and
+    state, not yet computed, donated. Both must be back on the host while the
+    first still runs, and the device must leave no hole between the two runs
+    (held against the hole the host's turn between two PAIRS leaves in the same
+    trace: block, install, deal pages, call). The engine's slots all decode
+    when this is called; its requests' streams are not kept up (the caller
+    cancels them)."""
+    import shutil
+    import tempfile
+
+    import jax
+    import numpy as np
+
+    from neuronx_distributed_tpu.observability import profile_window
+
+    fn, chunk = engine._nonspec_chunk(), engine.decode_chunk_size
+    calls, firsts = [], []
+    trace_dir = tempfile.mkdtemp(prefix="runahead_probe_")
+    try:
+        with profile_window(trace_dir if size.trace else None):
+            for _ in range(size.probe_pairs):
+                if not engine.cache.ensure_decode_window(np.flatnonzero(engine._active), 2 * chunk):
+                    raise RuntimeError("runahead: the pool cannot back two write windows")
+                cache = engine.cache.take()
+                t0 = time.perf_counter()
+                one = fn(engine._params, cache, engine._state)
+                two = fn(engine._params, one[0], one[1])
+                t2 = time.perf_counter()
+                jax.block_until_ready(one[2])
+                t3 = time.perf_counter()
+                jax.block_until_ready(two[2])
+                calls.append(t2 - t0)
+                firsts.append(t3 - t0)
+                engine._state = two[1]
+                engine.cache.update_after_decode(two[0], int(one[4]) + int(two[4]))
+        runs = _chunk_runs(trace_dir) if size.trace else []
+    finally:
+        shutil.rmtree(trace_dir, ignore_errors=True)
+    calls_back = max(c / f for c, f in zip(calls, firsts)) < size.call_share
+    if not size.trace:
+        return {"runahead_second_call_returns_while_the_first_runs_on_the_chips_clock": calls_back,
+                "runahead_device_leaves_no_hole_between_chained_chunks_on_the_chips_clock": False}
+    if len(runs) != 2 * size.probe_pairs:
+        raise RuntimeError(f"runahead: {len(runs)} runs of the decode chunk in the trace, not {2 * size.probe_pairs}")
+    holes = [1e-6 * (runs[i + 1][0] - runs[i][1]) for i in range(len(runs) - 1)]
+    chained, turns = holes[0::2], holes[1::2]
+    run_ms = float(np.median([1e-6 * (b - a) for a, b in runs]))
+    log(f"runahead probe: {size.probe_pairs} chained pairs of the donated decode chunk ({run_ms:.2f} ms a run on the "
+        f"device): both calls back on the host after {1e3 * np.median(calls):.2f} ms (largest {1e3 * max(calls):.2f}), "
+        f"the first chunk's readback after {1e3 * np.median(firsts):.2f} ms; the device's hole between the two runs of "
+        f"a pair, ms: median {np.median(chained):.3f}, largest {max(chained):.3f}; between two pairs (the host blocks, "
+        f"installs, deals pages and calls): median {np.median(turns):.3f}, smallest {min(turns):.3f}")
+    return {
+        "runahead_second_call_returns_while_the_first_runs_on_the_chips_clock": calls_back,
+        "runahead_device_leaves_no_hole_between_chained_chunks_on_the_chips_clock": (
+            max(chained) < size.hole_share * min(turns)),
+    }
+
+
+def _one_chip_engine(size: RunAheadSize, seed: int):
+    """``(engine, vocabulary)``: the configuration's engine as the benchmark
+    builds it (``perfbench/runners/serve.py::build``), at ``size``'s rows."""
+    from perfbench.runners import serve
+    from perfbench.spans import Spans
+
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)), "perfbench", "configs", size.config)) as f:
+        config = json.load(f)
+    config["model"] = _published(size, size.config)
+    config["serving"] = {"num_slots": size.slots, "max_seq_len": size.max_seq_len, "kv_page_size": size.page}
+    engine, _, _, vocab = serve.build(config, seed, Spans(), log)
+    return engine, vocab
+
+
+def runahead_phase(size: RunAheadSize, seed: int) -> Dict[str, bool]:
+    """The decode chunk run ahead by one, on one cell's engine (Trinity's: the
+    shortest step): first the probe, two chained calls of the donated chunk
+    under a trace (both back on the host while the first runs, no hole on the
+    device between them); then the same closed traffic twice through the one
+    engine, one chunk at a time (the rule patched to say no, from here) and as
+    the engine decides: 24 requests of 2,048-token prompts and 400-1,200-token
+    answers over 8 slots, a few hundred chunks, with the share of chunks run
+    ahead, the chunk's wall and the device's idle share of a traced stretch
+    side by side. Every request's tokens and final key must be equal between
+    the two passes, and the pages' invariant hold. Not part of the default
+    run."""
+    import shutil
+    import tempfile
+
+    import jax
+    import numpy as np
+
+    from neuronx_distributed_tpu.inference import GenerationConfig
+    from neuronx_distributed_tpu.observability import profile_window
+    from perfbench import xplane
+
+    engine, vocab = _one_chip_engine(size, seed)
+    prompts = _prompts((size.prompt_len,) * size.requests, vocab, seed + 11)
+    lo, hi = size.answers
+    answers = [lo + (hi - lo) * i // max(size.requests - 1, 1) for i in range(size.requests)]
+
+    # --- the probe: every slot decoding, nothing queued
+    long_answer = GenerationConfig(max_new_tokens=hi, temperature=0.0)
+    probed = [engine.submit(p, long_answer, key=jax.random.PRNGKey(seed + i)) for i, p in enumerate(prompts[:size.slots])]
+    while not engine._active.all():
+        engine.step()
+    checks = runahead_probe(engine, size)
+    for r in probed:
+        engine.cancel(r.rid)
+    engine.run()
+    engine.step()
+
+    def serve(ahead: bool):
+        """One pass: ``(requests, chunk walls in s, share run ahead, late-found ends, idle share of a traced stretch)``."""
+        if not ahead:
+            engine._can_run_ahead = lambda: False      # one chunk at a time: the rule says no
+        before = engine.metrics.snapshot(analyze_programs=False)
+        reqs = [engine.submit(p, GenerationConfig(max_new_tokens=n, temperature=0.0), key=jax.random.PRNGKey(seed + i))
+                for i, (p, n) in enumerate(zip(prompts, answers))]
+        walls, steps, idle = [], 0, None
+        trace_dir = tempfile.mkdtemp(prefix="runahead_pass_")
+        try:
+            while engine.has_work:
+                traced = size.trace and steps == 4 * size.slots          # past the first wave of prefills: 40 steps under a trace
+                with profile_window(trace_dir if traced else None):
+                    for _ in range(40 if traced else 1):
+                        prefills, chunks = engine.metrics.prefills, engine.metrics.chunks
+                        t0 = time.perf_counter()
+                        engine.step()
+                        if engine.metrics.prefills == prefills and engine.metrics.chunks > chunks:
+                            walls.append(time.perf_counter() - t0)
+                        steps += 1
+                if traced:
+                    got = xplane.reduce_trace(trace_dir, require_device=size.model is None)
+                    idle = 1.0 - got["busy_s"] / got["window_s"] if got["devices"] else None
+        finally:
+            shutil.rmtree(trace_dir, ignore_errors=True)
+            engine.__dict__.pop("_can_run_ahead", None)
+        engine.step()
+        after = engine.metrics.snapshot(analyze_programs=False)
+        chunks = after["chunks"] - before["chunks"]
+        ran = after["chunks_run_ahead"] - before["chunks_run_ahead"]
+        late = after["late_found_ends"] - before["late_found_ends"]
+        return reqs, walls, ran / max(chunks, 1), late, idle, chunks
+
+    passes = {}
+    for name, ahead in (("one at a time", False), ("run ahead", True)):
+        reqs, walls, share, late, idle, chunks = passes[name] = serve(ahead)
+        engine.cache.check()
+        said = "not traced" if idle is None else f"{100.0 * idle:.2f}% of 40 traced steps"
+        log(f"runahead, {name}: {chunks} chunks, {100.0 * share:.1f}% run ahead, {late} ran over a slot that had ended; "
+            f"a decode-only step's wall {1e3 * float(np.median(walls)):.2f} ms (median of {len(walls)}); device idle {said}")
+    plain, ahead = passes["one at a time"], passes["run ahead"]
+    same = all(a.tokens == b.tokens and np.array_equal(a.key, b.key) and len(a.tokens) == n
+               for a, b, n in zip(plain[0], ahead[0], answers))
+    leak_free = engine.cache.alloc.free_pages == engine.cache.alloc.num_pages - 1
+    checks.update({
+        "runahead_streams_equal_one_chunk_at_a_time": same,
+        "runahead_engages_with_every_slot_held": ahead[2] > 0.5 and plain[2] == 0.0,
+        "runahead_leaks_no_page": leak_free,
+        # only the chip's clock can say
+        "runahead_chunk_wall_is_shorter_on_the_chips_clock": float(np.median(ahead[1])) < float(np.median(plain[1])),
+    })
+    return checks
+
+
 def _walk_listing_compile(size: WalkSize = WalkSize()) -> None:
     """Compile the walking kernel at ``size`` for a described v5e. Run in a
     process of its own with ``LIBTPU_INIT_ARGS`` naming the dump directory
@@ -3297,6 +3503,7 @@ PHASES: Dict[str, Phase] = {
     "solar": Phase(solar_phase, SolarSize(), only=True),
     "ouro": Phase(ouro_phase, OuroSize(), only=True),
     "walk": Phase(walk_phase, WalkSize(), only=True),
+    "runahead": Phase(runahead_phase, RunAheadSize(), only=True),
     "flash": Phase(flash_phase, FlashSize(), only=True),
     "tp_train": Phase(tp_train_phase, TrainSize(), devices=True, chips=4, default=True),
     "tp_serve": Phase(tp_serve_phase, TP_SERVE, devices=True, chips=4, default=True),

@@ -145,8 +145,8 @@ class _OpenStep:
     ``samples`` appended to) by the watchdog."""
 
     __slots__ = ("ordinal", "ident", "t0", "cpu0", "process_cpu0", "stack",
-                 "phases", "prefills", "bucket", "chunk", "active",
-                 "sampled_slots", "expected", "cold", "samples")
+                 "phases", "prefills", "bucket", "chunk", "chunk_expected",
+                 "active", "sampled_slots", "expected", "cold", "samples")
 
     def __init__(self, ordinal: int, t0: float):
         self.ordinal = ordinal
@@ -159,7 +159,8 @@ class _OpenStep:
         self.prefills: list = []    # (bucket, wall) of each prefill
         self.bucket = None          # of the prefill in flight
         self.chunk = False          # a chunk was read back
-        self.active = 0             # slots the chunk was dispatched for
+        self.chunk_expected = False  # its wall is in ``expected``
+        self.active = 0             # slots the chunk read back was dispatched for
         self.sampled_slots = 0      # those of them whose request samples
         self.expected = 0.0         # grows as the step's parts are entered
         self.cold = False           # a part with no history: no verdict
@@ -197,6 +198,7 @@ class StepLedger:
         self._ordinal = 0
         self._open: Optional[_OpenStep] = None
         self._chunk = _Median()
+        self._ahead_slots = (0, 0)   # of a chunk called ahead: the next step's
         self._buckets: Dict[Any, _Median] = {}
         self.overruns = 0
         self.overrun_seconds = 0.0   # excess wall of the overrun steps
@@ -218,14 +220,23 @@ class StepLedger:
             if name != tracing.STEP:
                 return  # a phase outside step() (a drain's preemption)
             step = self._open = _OpenStep(self._ordinal, now)
+            # the chunk this step reads back, if the last one called it
+            step.active, step.sampled_slots = self._ahead_slots
+            self._ahead_slots = (0, 0)
             self._ordinal += 1
             self._last_begin = now
             with self._lock:
                 if self._watchdog is None:
                     self._start_watchdog()
-        elif name == tracing.STEP_DISPATCH:
+        elif name in (tracing.STEP_DISPATCH, tracing.STEP_READBACK):
+            # ONE chunk's wall a step, at whichever opens first: a step
+            # reads back one chunk, and may have called the next before it
+            # (the engine runs ahead) or have called none (it read back the
+            # last one run ahead)
             self._slots(step, stats)
-            self._expect(step, self._chunk)
+            if not step.chunk_expected:
+                step.chunk_expected = True
+                self._expect(step, self._chunk)
         step.stack.append((name, now))
 
     def note(self, name: str, stats: dict) -> None:
@@ -257,10 +268,17 @@ class StepLedger:
         elif not step.stack:
             self._open = None  # left without finish(): an exception's way out
 
-    @staticmethod
-    def _slots(step: _OpenStep, stats: dict) -> None:
-        step.active = int(stats.get("active", step.active))
-        step.sampled_slots = int(stats.get("sampled_slots", step.sampled_slots))
+    def _slots(self, step: _OpenStep, stats: dict) -> None:
+        """The record is of the chunk the step READS BACK: the slots of one
+        the engine called AHEAD of it (stat ``ahead`` 1) go to the record
+        of the step after."""
+        if "active" not in stats:
+            return
+        slots = (int(stats["active"]), int(stats.get("sampled_slots", 0)))
+        if stats.get("ahead"):
+            self._ahead_slots = slots
+        else:
+            step.active, step.sampled_slots = slots
 
     @staticmethod
     def _expect(step: _OpenStep, median: Optional[_Median]) -> None:

@@ -271,6 +271,11 @@ class SlotCacheManager:
         many write columns the fused chunk actually consumed (its on-device
         clamp stops the cursor early when every slot froze)."""
         self.cache = new_cache
+        self.advance(steps)
+
+    def advance(self, steps: int) -> None:
+        """Move the cursor by the ``steps`` columns of a chunk whose output
+        the manager already holds (``PagedCacheManager.advance``)."""
         self.cursor += steps
 
     def reset(self) -> None:

@@ -35,6 +35,34 @@ Decode hot path — device-resident between admission events:
   per-slot counts. Chunk boundaries are the admission/cancellation points,
   so larger chunks trade a little TTFT/cancel latency for per-token host
   overhead amortized ``chunk``-fold.
+* RUN AHEAD BY ONE CHUNK: chunk N+1 needs nothing of chunk N's readback to
+  be CALLED (it takes N's output cache and state as they are, not yet
+  computed, donated as ever), so the engine calls N+1 first, only then
+  blocks on N's readback, and emits N's tokens while the device runs N+1:
+  still one sync a chunk and one chunk read back a ``step()``, at most TWO
+  chunks on the device, the host's bookkeeping ahead of the device by one
+  chunk. It does so only when the boundary after N can bring the host
+  nothing that N+1 would have to wait for, decided at the moment of the
+  call from what the engine observes (``_can_run_ahead``; no option sets
+  it): every usable slot held and no slot's budget ending inside N (so no
+  admission can happen there: the host knows every budget); no draft
+  model, no armed fault injector, health ``OK`` (every recovery,
+  quarantine and page-poison path keeps its one-chunk-at-a-time order); no
+  handoff from outside, host tier or preempting policy; room under the
+  cursor for both write windows, and a pool that backs N+1's at the
+  projected cursor. Otherwise the step is one chunk at a time to the
+  letter: an open loop with a slot free never runs ahead, and an arriving
+  request waits for the running chunk at most. What the rule cannot see,
+  an EOS, an ``on_token`` cancel or a deadline found at N's emit, is
+  honoured there as ever; the slot rides N+1 frozen (the EOS: the device
+  froze it in N) or its N+1 tokens are discarded as the rest of a block is,
+  and the slot is found free ONE CHUNK LATE: the bounded cost, counted
+  (``serving_decode_late_found_ends``). While a chunk is unread a step does
+  nothing at the boundary but retire cancels and deadlines and read it
+  back; admission, preemption and the walls wait for the step that finds
+  nothing unread. Streams are bit for bit those of one chunk at a time:
+  same program, same inputs, same order of device work for every slot that
+  still emits.
 
 Prefix-cache KV reuse — the admission-path optimization for shared-prompt
 traffic (system prompts, few-shot templates, multi-turn histories):
@@ -205,8 +233,18 @@ Cache capacity: all slots share one write cursor (see
 ``serving/cache_manager.py``), which advances every decode step while ANY
 slot is active. The fused chunk clamps itself against ``max_seq_len`` on
 device and stops advancing once every slot froze, so the cursor lands
-exactly where ``used`` single steps would have left it. Admission guards
-against running past ``max_seq_len``:
+exactly where ``used`` single steps would have left it. The HOST's cursor
+and pages may run one chunk ahead of the device, which runs in order: a
+chunk's output cache is the manager's from its call, and where the next
+chunk is called before its readback its columns are PROJECTED at the
+chunk's size (the next window's pages dealt and uploaded, a window kind's
+pages freed behind, on that projection) and settled when ``used`` arrives
+(short only when every slot froze, and then the chunk called ahead executes
+no step: the device's cursor is right by construction). Every device
+operation the host enqueues on the strength of bookkeeping it did early (a
+freed page dealt again, the validity clear of a retired slot, an admit's
+row copy) is enqueued AFTER the chunk called ahead and so runs after it.
+Admission guards against running past ``max_seq_len``:
 
 * ``admission="conservative"`` (default) — admit only when the request's
   whole remaining generation fits under the cursor; requests queue
@@ -515,6 +553,27 @@ class _TraceScope:
 
     def __getattr__(self, name):
         return getattr(self._fn, name)
+
+
+@dataclasses.dataclass
+class _Chunk:
+    """A decode chunk that was called and is not read back yet."""
+
+    outputs: tuple            # on the device: toks, counts, used, the key snapshot, the model's stats
+    active: int               # slots decoding at its call
+    t0: float                 # its call, and the call's return
+    t1: float
+    compiled: bool            # the call compiled: its wall is no chunk's
+    # the rule's word on calling the next chunk before this one is read
+    # back (``_can_run_ahead``), taken inside a span of the step that reads
+    # it: this chunk's dispatch span or, called a step earlier, the reap span
+    followed: bool
+    # the slots' requests at the call of a chunk called AHEAD of the one
+    # before it (``None`` for a chunk called with nothing unread)
+    reqs: Optional[tuple] = None
+    # columns the host's cursor went ahead by when the NEXT chunk was called
+    # on this one's projection (0: the cursor waits for the readback)
+    projected: int = 0
 
 
 class ServingEngine:
@@ -981,6 +1040,11 @@ class ServingEngine:
         # the DisaggregatedServer pulls from the queue, prefills on its
         # workers, and hands contexts back through admit_staged()
         self.external_prefill = False
+        # the decode chunk called and not yet read back when a step ends
+        # (run ahead: module docstring, "Decode hot path"), and when the
+        # last chunk was read back
+        self._in_flight: Optional[_Chunk] = None
+        self._last_readback_t = 0.0
         # fault-tolerance state machine
         self._halted = False
         self._halt_reason: Optional[str] = None
@@ -1388,12 +1452,13 @@ class ServingEngine:
             target = self.cache.aligned_target(max(proj, p), p)
         return padded, target
 
-    def _chunk_width_cols(self, active) -> int:
+    def _chunk_width_cols(self, active, unread: int = 0) -> int:
         """Columns the next chunk can actually WRITE: the fused chunk
         freezes a slot when its budget runs out, so no more than the
         largest remaining generation among active slots ever executes
         (steps on the plain path, rounds — gamma columns each — on the
-        speculative path). Clamping the page demand to this keeps the
+        speculative path; less the ``unread`` tokens a chunk still on the
+        device takes of every budget). Clamping the page demand to this keeps the
         per-chunk window consistent with the admission/door accounting,
         which sizes requests by their REMAINING tokens — an unclamped full
         chunk window could demand pages the door check never charged and
@@ -1406,14 +1471,17 @@ class ServingEngine:
             ),
             default=self.decode_chunk_size,
         )
-        return min(self.decode_chunk_size, max(max_rem, 1)) * self._round_cols
+        return min(
+            self.decode_chunk_size, max(max_rem - unread, 1)
+        ) * self._round_cols
 
-    def _ensure_decode_pages(self) -> bool:
+    def _ensure_decode_pages(self, unread: int = 0) -> bool:
         """Map pool pages under every active slot's next write window (both
         caches on a speculative engine). False = the page-pressure wall:
-        the caller preempts-and-rewinds, exactly like the cursor wall."""
+        the caller preempts-and-rewinds, exactly like the cursor wall (or,
+        called ahead of an ``unread`` chunk, goes back to one at a time)."""
         active = np.flatnonzero(self._active)
-        width = self._chunk_width_cols(active)
+        width = self._chunk_width_cols(active, unread)
         if not self.cache.ensure_decode_window(active, width):
             return False
         if self.draft_cache is not None and not (
@@ -2082,7 +2150,11 @@ class ServingEngine:
         # back to the queue with their host-current tokens/keys so an
         # operator handing off scheduler.requests loses nothing
         requeued = self._vacate_active()
-        if requeued:
+        # a chunk still on the device is dropped unread: every stream is
+        # host-current through the chunk before it, and the cache it wrote
+        # is rewound with the rest
+        unread, self._in_flight = self._in_flight, None
+        if requeued or unread is not None:
             self.scheduler.requeue_front(requeued)
             self.cache.release_all_slots()
             self.cache.reset()
@@ -2160,6 +2232,8 @@ class ServingEngine:
             # requeued work survives in the queue for inspection/handoff,
             # but a halted engine makes no progress — run() must exit
             return False
+        if self._in_flight is not None:
+            return True  # a chunk on the device is read back by a step
         if self._draining:
             return any(self._active) or any(
                 r.admit_time is not None
@@ -2468,15 +2542,16 @@ class ServingEngine:
             "kv_cache_nodes": int(m.kv_cache_nodes),
         }
 
-    def _held_tokens(self) -> List[int]:
-        """Tokens each decoding slot holds (its prompt and what it emitted)."""
+    def _held_tokens(self, unread: int = 0) -> List[int]:
+        """Tokens each decoding slot holds (its prompt, what it emitted and
+        the ``unread`` tokens of a chunk still on the device)."""
         return [
-            len(r.prompt) + len(r.tokens)
+            len(r.prompt) + len(r.tokens) + unread
             for s, r in enumerate(self._slot_req)
             if r is not None and self._active[s]
         ]
 
-    def _selection_stats(self) -> dict:
+    def _selection_stats(self, unread: int = 0) -> dict:
         """``ctx_tokens`` (tokens the decoding slots hold) and
         ``selected_tokens`` (``sum(min(held, topk))``: what a sparse-attention
         model's decode step attends) for the dispatch span: host arithmetic
@@ -2485,7 +2560,7 @@ class ServingEngine:
         topk = getattr(getattr(self.model, "config", None), "index_topk", None)
         if topk is None:
             return {}
-        held = self._held_tokens()
+        held = self._held_tokens(unread)
         return {
             "ctx_tokens": int(sum(held)),
             "selected_tokens": int(sum(min(n, int(topk)) for n in held)),
@@ -2500,7 +2575,7 @@ class ServingEngine:
         arithmetic on the tables, made when a table is uploaded."""
         return dict(getattr(self.cache, "page_stats", None) or {})
 
-    def _window_stats(self) -> dict:
+    def _window_stats(self, unread: int = 0) -> dict:
         """For a model with window layers, on the dispatch span:
         ``ctx_tokens`` (tokens the decoding slots hold) and ``window_tokens``
         (``sum(min(held, window))``: what a window layer's decode step
@@ -2508,7 +2583,7 @@ class ServingEngine:
         window = getattr(self.cache, "window", None)
         if window is None:
             return {}
-        held = self._held_tokens()
+        held = self._held_tokens(unread)
         return {
             "ctx_tokens": int(sum(held)),
             "window_tokens": int(sum(min(n, int(window)) for n in held)),
@@ -2572,10 +2647,21 @@ class ServingEngine:
         with self._span(tracing.STEP_REAP):
             self._reap_cancelled(now)
             self._shed_expired(now)
-            wall = any(self._active) and (
+            # a chunk run ahead is on the device: this step reads it back
+            # and does nothing else at the boundary. The rule that let it
+            # run saw nothing due here (_can_run_ahead); what no rule can
+            # see (an EOS, a cancel, a deadline: retired above, or at the
+            # last emit) waits one chunk for its free slot, until the step
+            # that finds nothing unread
+            unread = self._in_flight is not None
+            if unread:
+                self._in_flight.followed = self._can_run_ahead()
+            wall = not unread and any(self._active) and (
                 self.cache.cursor + self._round_cols > self.max_seq_len
             )
-            if not wall and not any(self._active) and self.cache.cursor > 0:
+            if not (unread or wall or any(self._active)) and (
+                self.cache.cursor > 0
+            ):
                 self._rewind_drained()
         # one more dispatch needs _round_cols columns (gamma per
         # speculative round, 1 per plain step); preempt-and-rewind when the
@@ -2585,17 +2671,18 @@ class ServingEngine:
         # preemption machinery keeps streams bit-identical either way
         if wall:
             self._preempt_all()
-        # SLO-driven preemption (ISSUE 16): when the slot set is full and
-        # an under-attaining tenant's work is waiting, the policy may
-        # nominate victims (FIFO never does) — vacated through the same
-        # host bookkeeping as quarantine-requeue, so streams stay
-        # bit-identical and the freed slots admit below in THIS step
-        victims = self.policy.victims(now)
-        if victims:
-            with self._span(tracing.STEP_PREEMPT):
-                self._preempt_victims(victims, now)
-        self._admit(now)
-        if not self._halted and any(self._active):
+        if not unread:
+            # SLO-driven preemption (ISSUE 16): when the slot set is full
+            # and an under-attaining tenant's work is waiting, the policy
+            # may nominate victims (FIFO never does) — vacated through the
+            # same host bookkeeping as quarantine-requeue, so streams stay
+            # bit-identical and the freed slots admit below in THIS step
+            victims = self.policy.victims(now)
+            if victims:
+                with self._span(tracing.STEP_PREEMPT):
+                    self._preempt_victims(victims, now)
+            self._admit(now)
+        if not self._halted and (unread or any(self._active)):
             self._decode()
         with self._span(tracing.STEP_HEALTH):
             if self.timeline is not None:
@@ -3507,19 +3594,19 @@ class ServingEngine:
         and the next admission/free event no per-slot host state moves. A
         failed dispatch routes through the recovery state machine instead of
         crashing the loop."""
-        if self._page_size is not None:
-            # the page dealing: both kinds' counts, the deal, the block
-            # tables' upload (the draft cache's too)
-            with self._span(tracing.STEP_PAGES):
-                backed = self._ensure_decode_pages()
-            if not backed:
-                # page-pressure wall: the pool cannot back every active
-                # slot's next write window even after reclaiming prefix
-                # entries — preempt-and-rewind, the cursor wall's exact
-                # remedy (frees every slot mapping; re-admission repacks
-                # from column 0)
-                self._preempt_all()
-                return
+        if self._in_flight is not None:
+            # a chunk run ahead is on the device, its pages dealt at its
+            # call: this step reads it back (and may call the next first)
+            self._decode_plain()
+            return
+        if not self._deal_decode_pages():
+            # page-pressure wall: the pool cannot back every active
+            # slot's next write window even after reclaiming prefix
+            # entries — preempt-and-rewind, the cursor wall's exact
+            # remedy (frees every slot mapping; re-admission repacks
+            # from column 0)
+            self._preempt_all()
+            return
         if self.draft_model is not None:
             self._decode_spec()
         else:
@@ -3742,10 +3829,116 @@ class ServingEngine:
             self._preempt_all()
         self._sync_health()
 
+    def _deal_decode_pages(self, unread: int = 0) -> bool:
+        """Before a chunk's call: the host's cursor goes ahead by the
+        ``unread`` columns of a chunk still on the device (its projection),
+        and a paged engine deals the write window's pages under their span:
+        both kinds' counts, the deal, the block tables' upload (the draft
+        cache's too; ``unread`` as :meth:`_chunk_width_cols`)."""
+        if self._page_size is None:
+            self.cache.advance(unread)
+            return True
+        with self._span(tracing.STEP_PAGES):
+            if unread:
+                self.cache.advance(unread)
+            return self._ensure_decode_pages(unread)
+
     def _decode_plain(self) -> None:
-        """The non-speculative fused chunk (the pre-ISSUE-9 `_decode` body;
-        also the speculative engine's fallback program)."""
-        fault = None
+        """The non-speculative fused chunk (also the speculative engine's
+        fallback program): ONE chunk is read back a call. With no chunk in
+        flight it calls one; where the boundary after the chunk in flight
+        can bring the host nothing to do (:meth:`_can_run_ahead`, asked
+        inside a span of this step, and the pool backing the next write
+        window at the projected cursor) it calls
+        the NEXT chunk before it blocks on this one's readback, and emits
+        this one's tokens while the device runs the next (module docstring,
+        "Decode hot path")."""
+        chunk, self._in_flight = self._in_flight, None
+        if chunk is None:
+            chunk = self._dispatch_chunk(None)
+            if isinstance(chunk, Exception):
+                self._recover_dispatch(self.cache.take(), chunk)
+                return
+        ahead = self._dispatch_ahead(chunk) if chunk.followed else None
+        with self._span(tracing.STEP_READBACK) as sp:
+            # THE one host sync per chunk: the (chunk, slots) token block,
+            # the per-slot valid-prefix lengths, the executed step count —
+            # and the post-chunk key SNAPSHOT (frozen at each slot's finish
+            # step). The snapshot is a chunk OUTPUT, not the state leaf:
+            # device_get on the leaf would cache a host value on it and
+            # silently demote the next chunk's keys donation to a copy
+            # graftlint: ok[GL02] THE one per-chunk sync of the fused decode
+            # contract (tests/serving/test_decode_chunking.py pins it at 1)
+            toks, counts, used, chunk_keys, *model_stats = jax.device_get(
+                chunk.outputs
+            )
+            sp.set_metadata(steps=int(used), **{
+                name: int(value) for name, value in zip(
+                    getattr(self._decode_model, "chunk_stats", ()),
+                    model_stats[0] if model_stats else ())
+            })
+        t2 = self._clock()
+        with self._span(tracing.STEP_EMIT) as sp:
+            self._emit_chunk(sp, chunk, toks, counts, used, chunk_keys, t2)
+            # the donated tree's husks go here, inside the span: released
+            # with this frame they took 0.11 ms of a step under no span
+            del chunk
+        if isinstance(ahead, Exception):
+            # the call ahead FAILED: the chunk before it is emitted, every
+            # stream host-current through it, and recovery is today's
+            self._recover_dispatch(self.cache.take(), ahead)
+        elif not self._halted:   # a halt inside the emit dropped it
+            self._in_flight = ahead
+
+    def _can_run_ahead(self) -> bool:
+        """Whether the boundary after the chunk now on the device can bring
+        the host nothing the next chunk would have to wait for, from what
+        the engine can observe at the moment of the call (module docstring,
+        "Decode hot path", has the rule and what it costs)."""
+        chunk = self.decode_chunk_size
+        return (
+            # the plain path with nothing armed: every recovery, quarantine
+            # and page-poison path keeps its one-chunk-at-a-time order
+            self.draft_model is None and self._faults is None
+            and self.health() is EngineHealth.OK
+            # nothing else is due at the boundary: no handoff from outside,
+            # no tier to move pages to or from, no policy that preempts
+            and not self.external_prefill and self.tier is None
+            and not self.policy.preempts
+            # no admission: every usable slot is held, and no budget ends
+            # inside the chunk in flight (the host knows every budget)
+            and self.cache.free_slots == 0
+            and all(
+                r.remaining_new_tokens > chunk and not r.finished
+                for r in self._slot_req if r is not None
+            )
+            # room under the cursor for both write windows
+            and self.cache.cursor + 2 * chunk <= self.max_seq_len
+        )
+
+    def _dispatch_ahead(self, unread: "_Chunk"):
+        """Call the chunk AFTER ``unread`` before ``unread`` is read back:
+        the host's cursor goes ahead of the device by ``unread``'s columns
+        (projected at the chunk's size; :meth:`_emit_chunk` settles the
+        difference), the next window's pages are dealt and uploaded on that
+        projection, and the call takes ``unread``'s output cache and state
+        as they are, not yet computed. Returns the new chunk; ``None`` where
+        the pool cannot back the window (the engine goes back to one chunk
+        at a time, and the wall's remedy is the next step's); the exception
+        of a call that failed."""
+        unread.projected = self.decode_chunk_size
+        if not self._deal_decode_pages(unread.projected):
+            return None
+        return self._dispatch_chunk(unread)
+
+    def _dispatch_chunk(self, unread: Optional["_Chunk"]):
+        """One call of the donated chunk program under its span; its output
+        cache goes back to the manager at once (the cursor moves when the
+        chunk's columns are known, or projected). ``unread``: the chunk on
+        the device that this one is called ahead of. A call that raises
+        comes back as its exception, the manager holding the input tree
+        again: the caller recovers (after it has emitted ``unread``)."""
+        unread_tokens = 0 if unread is None else unread.projected
         with self._span(tracing.STEP_DISPATCH) as sp:
             # as in _decode_spec: the span's stats are made inside it
             t0 = self._clock()
@@ -3753,10 +3946,12 @@ class ServingEngine:
             sampled_slots = self._sampled_slots()
             sp.set_metadata(
                 active=active_at_dispatch, sampled_slots=sampled_slots,
+                ahead=int(unread is not None),
                 **self._kv_bytes_stats(),
                 cursor=int(self.cache.cursor), row_columns=self.max_seq_len,
                 # one dict: both name ``ctx_tokens``
-                **{**self._selection_stats(), **self._window_stats()},
+                **{**self._selection_stats(unread_tokens),
+                   **self._window_stats(unread_tokens)},
                 **self._slot_state_stats(), **self._page_stats(),
             )
             cache_in = self.cache.take()
@@ -3771,52 +3966,32 @@ class ServingEngine:
                  key_snap, *model_stats) = self._nonspec_chunk()(
                     self._params, cache_in, self._state
                 )
-            except Exception as e:
-                fault = e
-            except BaseException:
-                # KeyboardInterrupt/SystemExit are the operator's, not
-                # faults: restore the reference (a consumed buffer fails
-                # loudly on next use) and re-raise
+            except BaseException as e:
+                # the reference goes back either way (a consumed buffer
+                # fails loudly on next use); KeyboardInterrupt/SystemExit
+                # are the operator's, not faults
                 self.cache.restore(cache_in)
-                raise
-        if fault is not None:
-            self._recover_dispatch(cache_in, fault)
-            return
-        t1 = self._clock()
-        self._consecutive_dispatch_failures = 0
-        self._chunks_since_failure += 1
-        self.metrics.record_chunk_dispatch(sampled_slots)
-        with self._span(tracing.STEP_READBACK) as sp:
-            # THE one host sync per chunk: the (chunk, slots) token block,
-            # the per-slot valid-prefix lengths, the executed step count —
-            # and the post-chunk key SNAPSHOT (frozen at each slot's finish
-            # step). The snapshot is a chunk OUTPUT, not the state leaf:
-            # device_get on the leaf would cache a host value on it and
-            # silently demote the next chunk's keys donation to a copy
-            # graftlint: ok[GL02] THE one per-chunk sync of the fused decode
-            # contract (tests/serving/test_decode_chunking.py pins it at 1)
-            toks, counts, used, chunk_keys, *model_stats = jax.device_get(
-                (toks, counts, used, key_snap, *model_stats)
+                if not isinstance(e, Exception):
+                    raise
+                return e
+            self.cache.restore(new_cache)
+            self._consecutive_dispatch_failures = 0
+            self._chunks_since_failure += 1
+            self.metrics.record_chunk_dispatch(
+                sampled_slots, ahead=unread is not None)
+            return _Chunk(
+                outputs=(toks, counts, used, key_snap, *model_stats),
+                active=active_at_dispatch, t0=t0, t1=self._clock(),
+                compiled=self._decode_chunk.last_call_compiled,
+                followed=unread is None and self._can_run_ahead(),
+                reqs=tuple(self._slot_req) if unread is not None else None,
             )
-            sp.set_metadata(steps=int(used), **{
-                name: int(value) for name, value in zip(
-                    getattr(self._decode_model, "chunk_stats", ()),
-                    model_stats[0] if model_stats else ())
-            })
-        t2 = self._clock()
-        with self._span(tracing.STEP_EMIT) as sp:
-            self._emit_chunk(
-                sp, new_cache, toks, counts, used, chunk_keys,
-                active_at_dispatch, t0, t1, t2,
-            )
-            # the donated tree's husks go here, inside the span: released
-            # with this frame they took 0.11 ms of a step under no span
-            del cache_in, key_snap
 
-    def _emit_chunk(self, sp, new_cache, toks, counts, used, chunk_keys,
-                    active_at_dispatch, t0, t1, t2) -> None:
-        """What follows the chunk's readback: validation, the key mirror,
-        every token to its stream, retirement, the metrics."""
+    def _emit_chunk(self, sp, chunk: "_Chunk", toks, counts, used,
+                    chunk_keys, t2: float) -> None:
+        """What follows the chunk's readback (at ``t2``): the cursor, the
+        validation, the key mirror, every token to its stream, retirement,
+        the metrics."""
         tl = self.timeline
         readback = self._readbacks
         self._readbacks += 1
@@ -3827,8 +4002,22 @@ class ServingEngine:
         # the executed step count drives cursor arithmetic — clamp it to the
         # chunk bound so corrupted output can never run the cursor away
         used = max(0, min(int(used), self.decode_chunk_size))
-        self.cache.update_after_decode(new_cache, used)
+        # the chunk's output is the manager's since its call. Its columns:
+        # all of them now or, where the next chunk was called on their
+        # projection, the difference (``used`` is short only when every
+        # slot froze, and then the chunk called ahead executes no step)
+        if used != chunk.projected:
+            self.cache.advance(used - chunk.projected)
         self._count_window_pages_freed()
+        late_end = chunk.reqs is not None and any(
+            r is not None and self._slot_req[s] is not r
+            for s, r in enumerate(chunk.reqs)
+        )
+        if late_end:
+            # called ahead over a slot whose request had ended (an EOS, a
+            # cancel or a deadline found at the boundary it ran over): that
+            # slot rode this chunk frozen, or its tokens are discarded here
+            self.metrics.record_late_found_end()
         # validate the block BEFORE any token reaches a stream: a poisoned
         # slot is quarantined and its chunk discarded; neighbors proceed
         bad = _validate_readback(
@@ -3869,14 +4058,20 @@ class ServingEngine:
         # device tokens never inflate decode_tokens / chunk tok/s
         if tl is not None:
             tl.counter("chunk_tokens", delivered, "serving")
+        # the chunk's own clocks: a chunk called before the one before it
+        # was read back had the device only from that readback on, so its
+        # wall runs from the later of its call and that readback
+        t0 = max(chunk.t0, self._last_readback_t)
+        t1 = max(chunk.t1, t0)
+        self._last_readback_t = t2
         self.metrics.record_decode_chunk(
-            delivered, used, self.cache.cursor, active_at_dispatch,
+            delivered, used, self.cache.cursor, chunk.active,
             dispatch_s=t1 - t0, readback_s=t2 - t1,
         )
-        sp.set_metadata(delivered=delivered)
+        sp.set_metadata(delivered=delivered, late_end=int(late_end))
         # roofline feed (see _decode_spec): measured chunk wall, compile
         # chunks excluded
-        if not self._decode_chunk.last_call_compiled:
+        if not chunk.compiled:
             self.programs.observe_wall("decode_chunk", t2 - t0)
 
     def _count_window_pages_freed(self) -> None:

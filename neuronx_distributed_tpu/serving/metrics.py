@@ -122,6 +122,12 @@ _COUNTERS = (
     # the two read equal on all-greedy traffic
     ("chunks_dispatched", "serving_decode_chunks_dispatched", True),
     ("greedy_chunks_dispatched", "serving_greedy_chunks_dispatched", True),
+    # chunks called before the chunk before them was read back (two on the
+    # device: ``ahead`` 1 on the dispatch span), and those of them that ran
+    # over a slot whose request had ended at the boundary they ran over (an
+    # EOS, a cancel, a deadline: found one chunk late, the rule's bounded cost)
+    ("chunks_run_ahead", "serving_decode_chunks_run_ahead", True),
+    ("late_found_ends", "serving_decode_late_found_ends", True),
     # pages of the window kind's pool given back behind a slot's window (a
     # model with window layers: serving/paging.py)
     ("window_pages_freed", "serving_window_pages_freed", True),
@@ -458,12 +464,20 @@ class ServingMetrics:
         self._inc("step_overruns")
         self._inc("step_overrun_s", excess_s)
 
-    def record_chunk_dispatch(self, sampled_slots: int) -> None:
+    def record_chunk_dispatch(self, sampled_slots: int, ahead: bool = False) -> None:
         """A decode chunk went to the device with ``sampled_slots`` of its
-        active slots asking ``temperature != 0``."""
+        active slots asking ``temperature != 0``; ``ahead``: before the
+        chunk before it was read back."""
         self._inc("chunks_dispatched")
         if not sampled_slots:
             self._inc("greedy_chunks_dispatched")
+        if ahead:
+            self._inc("chunks_run_ahead")
+
+    def record_late_found_end(self) -> None:
+        """A chunk run ahead was read back over a slot whose request had
+        ended at the boundary it ran over."""
+        self._inc("late_found_ends")
 
     def record_window_pages_freed(self, n: int) -> None:
         """``n`` pages of the window kind went back to its allocator."""
@@ -774,6 +788,12 @@ class ServingMetrics:
             "chunks": self.chunks,
             "chunks_dispatched": self.chunks_dispatched,
             "greedy_chunks_dispatched": self.greedy_chunks_dispatched,
+            "chunks_run_ahead": self.chunks_run_ahead,
+            "run_ahead_share": (
+                self.chunks_run_ahead / self.chunks_dispatched
+                if self.chunks_dispatched else 0.0
+            ),
+            "late_found_ends": self.late_found_ends,
             "window_pages_freed": self.window_pages_freed,
             "decode_dispatch_s": self.decode_dispatch_s,
             "decode_readback_s": self.decode_readback_s,

@@ -1623,6 +1623,14 @@ class PagedCacheManager:
 
     def update_after_decode(self, new_cache, steps: int = 1) -> None:
         self.cache = new_cache
+        self.advance(steps)
+
+    def advance(self, steps: int) -> None:
+        """The cursor moves by the ``steps`` columns of a chunk whose output
+        the manager already holds (the engine gives a chunk's output back at
+        its call; its columns follow when read back, or PROJECTED before the
+        next chunk's call with the difference, <= 0, settled at the readback:
+        serving/engine.py, "Decode hot path")."""
         self.cursor += steps
         self._free_behind_window()
 

@@ -198,6 +198,10 @@ class SloPolicy(SchedulingPolicy):
 
     # --- preemption ---------------------------------------------------------
 
+    @property
+    def preempts(self) -> bool:
+        return bool(self.config.preempt)
+
     def victims(self, now: float) -> List["Request"]:
         cfg = self.config
         eng = self._engine

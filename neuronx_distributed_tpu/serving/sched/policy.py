@@ -120,6 +120,13 @@ class SchedulingPolicy:
         host-current, streams bit-identical). Default: never."""
         return []
 
+    @property
+    def preempts(self) -> bool:
+        """Whether :meth:`victims` can ever name one: the engine runs a
+        decode chunk ahead only over boundaries at which nothing is due,
+        and cannot ask ``victims`` twice."""
+        return False
+
     def on_tokens(self, tenant: str, n: int) -> None:
         """Decode-token accounting hook (host ints the loop already owns);
         the fairness layer charges tenant budgets here."""

@@ -423,6 +423,40 @@ def test_preemption_and_resume_give_the_undisturbed_stream(kind):
     eng.cache.check()
 
 
+@kinds()
+def test_a_chunk_run_ahead_gives_the_stream_of_one_chunk_at_a_time(kind):
+    """Every slot held and a queue behind them, on the fused path the cells
+    run: the engine calls the next chunk before it reads the last one back
+    (``serving/engine.py``, "Decode hot path"), above the cache. Whatever the
+    kind keeps (window pages freed behind a PROJECTED cursor, a slot's state, a
+    recurrent layer's, a node a layer a pass), every request's tokens and final
+    key are those of the same engine held to one chunk at a time (the rule
+    patched to say no, from here), greedy and sampled, and the pages' invariant
+    holds with every page back."""
+    answers = (33, 21, 40, 26)
+
+    def run(ahead):
+        eng = ServingEngine(kind.model, kind.params, decode_chunk_size=4, num_slots=2, prefix_cache=None,
+                            kv_page_size=kind.kind.page, paged_attention="fused")
+        if not ahead:
+            eng._can_run_ahead = lambda: False
+        reqs = [eng.submit(p, GenerationConfig(max_new_tokens=n, temperature=0.7 if i % 2 else 0.0,
+                                               top_k=20 if i % 2 else None), key=jax.random.PRNGKey(i))
+                for i, (p, n) in enumerate(zip(kind.prompts, answers))]
+        eng.run()
+        eng.cache.check()
+        assert eng._in_flight is None and eng.cache.alloc.free_pages == eng.cache.alloc.num_pages - 1
+        return eng, [(list(r.tokens), r.key.tolist()) for r in reqs]
+
+    plain, want = run(False)
+    eng, got = run(True)
+    assert got == want and [len(t) for t, _ in got] == list(answers)
+    m = eng.metrics
+    assert plain.metrics.chunks_run_ahead == 0 < m.chunks_run_ahead < m.chunks == plain.metrics.chunks
+    if kind.kind.whole_pool is False:   # the window kind: the same pages went back behind the cursor, a chunk early
+        assert eng.cache.window_pages_freed_total == plain.cache.window_pages_freed_total > 0
+
+
 @pytest.mark.parametrize("kind, what", [(name, what) for name, row in KINDS.items() for what in row.refuses],
                          indirect=["kind"])
 def test_a_kind_refuses_by_name_what_it_cannot_have(kind, what):

@@ -36,7 +36,8 @@ def test_the_chunk_reads_back_the_rows_its_held_experts_computed(setup):
     cfg, model, params, prompts, _ = setup
     eng = ServingEngine(model, params, decode_chunk_size=4, num_slots=2, kv_page_size=PS, prefix_cache=None)
     fn = eng._nonspec_chunk()
-    gcfg = GenerationConfig(max_new_tokens=6, temperature=0.0)
+    # budgets past the chunk the admitting step reads back and the one it may have called ahead
+    gcfg = GenerationConfig(max_new_tokens=16, temperature=0.0)
     for i, p in enumerate(prompts[:2]):
         eng.submit(p, gcfg, key=jax.random.PRNGKey(i))
     while eng.has_work and not any(eng._active):

@@ -195,6 +195,19 @@ ROWS = {
         page=8, calls=1, dtype="float32", tol=1e-5),
         tuple(f"walk_{shape}_{table}_table_matches_the_float32_einsum"
               for shape in ("tiny_window", "tiny_full", "narrow_full") for table in ("random", "dealt"))),
+    # ``--only runahead`` on the Trinity stand-in (window pages freed behind a
+    # projected cursor): the probe's two chained calls with no profiler session
+    # (a pytest worker opens none), then both passes, 6 requests over 2 slots.
+    # What only the chip's clock says: that the second call comes back while the
+    # first runs, that the device leaves no hole, and the shorter wall
+    "runahead": Row(chip_smoke.RunAheadSize(
+        model=_tiny("trinity-large-serve"), max_seq_len=512, slots=2, prompt_len=40, probe_pairs=2, requests=6,
+        answers=(24, 64), trace=False), (
+        "runahead_second_call_returns_while_the_first_runs_on_the_chips_clock",
+        "runahead_device_leaves_no_hole_between_chained_chunks_on_the_chips_clock",
+        "runahead_streams_equal_one_chunk_at_a_time", "runahead_engages_with_every_slot_held",
+        "runahead_leaks_no_page", "runahead_chunk_wall_is_shorter_on_the_chips_clock"),
+        chips_to_say=("_on_the_chips_clock",)),
     # ``--only flash`` at a tiny size (blocks of 128; the padding's edge
     # inside a block, on a boundary and absent; GQA; a narrower value head; a
     # call that keeps residuals): every comparison. The times are the chip's.
@@ -268,7 +281,7 @@ def test_default_runs_are_the_tables_rows():
     assert chip_smoke.default_run(1) == ["train", "serve", "mla", "dsa", "glm"]
     assert chip_smoke.default_run(4) == ["tp_train", "tp_serve", "remat"]
     only = [name for name, phase in chip_smoke.PHASES.items() if phase.only]
-    assert only == ["mla", "dsa", "glm", "moe", "trinity", "zaya", "solar", "ouro", "walk", "flash"]
+    assert only == ["mla", "dsa", "glm", "moe", "trinity", "zaya", "solar", "ouro", "walk", "runahead", "flash"]
     for name in only:
         assert chip_smoke.parse_args(["--only", name]).only == name
         assert chip_smoke.PHASES[name].help and chip_smoke.PHASES[name].chips == 1
